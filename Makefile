@@ -1,7 +1,7 @@
 # Local entry points for the CI stages defined in ci.yaml.
 PY ?= python
 
-.PHONY: test quick build dist convergence dist-smoke elastic-smoke serve-smoke frontdoor-smoke decode-smoke spmd-smoke mesh-smoke kernels-smoke data-smoke obs-smoke chaos-smoke step-profile ci-quick ci-full docs bench hygiene lint lockcheck racecheck
+.PHONY: test quick build dist convergence dist-smoke elastic-smoke serve-smoke frontdoor-smoke decode-smoke spmd-smoke mesh-smoke kernels-smoke data-smoke obs-smoke chaos-smoke step-profile ci-quick ci-full docs bench chip-smoke hygiene lint lockcheck racecheck
 
 # fail if any binary / scratch artifact is tracked (ci.yaml per-change
 # `hygiene` stage; the lazy builder regenerates *.so)
@@ -137,7 +137,9 @@ mesh-smoke:
 		$(PY) -m pytest tests/test_dist_mesh.py -q
 
 # Pallas kernel plane + remat policy gate, deterministic on CPU: every
-# kernel's REAL body runs in interpret mode (fused softmax/xent, RMSNorm,
+# kernel's REAL body runs in interpret mode — CPU parity only; whether
+# the chip's compiler accepts the kernels is tests/test_chip_compile.py,
+# whether they run is `make chip-smoke` — (fused softmax/xent, RMSNorm,
 # LayerNorm, flash attention) pinned against the plain XLA lowering —
 # forward AND gradients — plus the MXNET_PALLAS=0 bit-for-bit escape
 # hatch, the dispatch-fingerprint cache keys, the remat policies'
@@ -219,3 +221,9 @@ ci-full: build dist convergence quick docs-check
 
 bench:
 	$(PY) bench.py
+
+# needs one TPU chip and fails without one: Module.fit of ResNet-50 and
+# the transformer LM, then the LM behind the generation engine, each
+# held to its dense XLA twin (--chips 4: the data-parallel path)
+chip-smoke:
+	$(PY) chip_smoke.py

@@ -96,6 +96,11 @@ def fit(args, network, data_loader, arg_params=None, aux_params=None,
     ``arg_params``/``aux_params`` seed the parameters when no
     ``--load-epoch`` checkpoint overrides them (the fine-tune entry
     point passes the surgically transferred backbone this way)."""
+    mx.base.use_compile_cache()
+    if getattr(args, "benchmark", 0):
+        # --gpus picks tpu(i), which resolves to host devices where
+        # there is no accelerator: a benchmark must not pass for that
+        mx.context.require_tpu("--benchmark 1")
     kv = mx.create_kvstore(args.kv_store)
     head = "%(asctime)-15s Node[" + str(kv.rank) + "] %(message)s"
     logging.basicConfig(level=logging.INFO, format=head)

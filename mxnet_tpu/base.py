@@ -20,6 +20,7 @@ __all__ = [
     "env_registry",
     "register_env",
     "get_env",
+    "use_compile_cache",
     "atomic_write",
     "hot_path",
     "string_types",
@@ -83,6 +84,27 @@ def get_env(name, default=None):
     if var is not None:
         return var.get()
     return os.environ.get(name, default)
+
+
+def use_compile_cache():
+    """Point JAX's persistent compilation cache at a fixed place and
+    return it.  Entry points call this (``chip_smoke.py``, ``bench.py``,
+    the tools, the examples' ``fit``) — never ``import mxnet_tpu``, so
+    the tests stay cache-free.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and nothing
+    is configured in code.  Unset: ``<checkout>/.jax_cache``.  The path
+    is part of the cache key, so it is never made from a temp name, a
+    pid or the time — a directory that moves never hits."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    import jax
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # Core runtime knobs, mirroring the reference's documented set where the

@@ -8,6 +8,7 @@ training scripts run unchanged (on this stack "the accelerator" is the TPU).
 """
 from __future__ import annotations
 
+import os
 import threading
 
 import jax
@@ -15,7 +16,7 @@ import jax
 from .base import MXNetError
 
 __all__ = ["Context", "cpu", "tpu", "gpu", "cpu_pinned", "current_context",
-           "num_devices"]
+           "num_devices", "require_tpu"]
 
 
 class Context:
@@ -76,14 +77,25 @@ class Context:
         'gpu' and 'tpu' both resolve to the accelerator platform when one is
         present (the reference's device layer is swappable — base.h keeps the
         'gpu' name for whatever the accelerator is; here it is the TPU).
+        An accelerator ``device_id`` past the last device raises: asking
+        for more chips than exist must not train four times on chip 0.
+        ``cpu(i)`` keeps wrapping — reference scripts and the tests use
+        ``cpu(1)``, ``cpu(2)`` as distinct labels for host memory on a
+        one-device host.
         """
         if self.device_type in ("cpu", "cpu_pinned"):
             devs = _platform_devices("cpu")
-        else:
-            devs = _accelerator_devices()
-        if not devs:
-            raise MXNetError("no devices available for context %s" % self)
-        return devs[self.device_id % len(devs)]
+            if not devs:
+                raise MXNetError("no devices available for context %s"
+                                 % self)
+            return devs[self.device_id % len(devs)]
+        devs = _accelerator_devices()
+        if not 0 <= self.device_id < len(devs):
+            raise MXNetError(
+                "context %s: device_id out of range, %d %s device(s) "
+                "available" % (self, len(devs),
+                               devs[0].platform if devs else "accelerator"))
+        return devs[self.device_id]
 
 
 def _platform_devices(platform):
@@ -110,6 +122,24 @@ def _accelerator_devices():
         devs = [d for d in jax.local_devices() if d.platform != "cpu"]
         _ACCEL_CACHE = devs if devs else list(jax.local_devices())
     return _ACCEL_CACHE
+
+
+def require_tpu(what):
+    """The first JAX device, which must be a TPU for ``what`` to go on.
+
+    ``tpu(i)`` resolves to host devices where there is no accelerator
+    (above), which suits the tests and must never pass for a
+    measurement: whatever reports a rate calls this first.  The CPU is
+    accepted only when asked for by name — ``JAX_PLATFORMS=cpu``, a
+    plumbing run."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and \
+            "cpu" not in os.environ.get("JAX_PLATFORMS", ""):
+        raise MXNetError(
+            "%s measures on a TPU and JAX reports %r; set "
+            "JAX_PLATFORMS=cpu to ask for a CPU plumbing run"
+            % (what, dev.platform))
+    return dev
 
 
 def cpu(device_id=0):

@@ -30,13 +30,18 @@ PEAK_BF16_FLOPS = [("v6e", 918e12), ("v6", 918e12), ("v5p", 459e12),
 
 
 def peak_bf16_flops(device_kind):
-    """Table peak bf16 FLOP/s for a PJRT device_kind (None if unknown —
-    CPU rows report the FLOP rate without an MFU claim)."""
+    """Table peak bf16 FLOP/s for a PJRT device_kind.  The CPU platform
+    gets None (its rows report the FLOP rate without an MFU claim); an
+    accelerator that is not in the table is an error, not a default."""
     k = str(device_kind).lower().replace("_", " ")
+    if k == "cpu":
+        return None
     for key, val in PEAK_BF16_FLOPS:
         if key in k:
             return val
-    return None
+    raise ValueError("device_kind %r is not in the peak-FLOPs table; add "
+                     "its peak bf16 FLOP/s to mxnet_tpu/flops.py"
+                     % (device_kind,))
 
 
 def compiled_cost(fn, *args, **kwargs):
@@ -52,11 +57,7 @@ def compiled_cost(fn, *args, **kwargs):
         return None
     out = {"flops": None}
     try:
-        ca = compiled.cost_analysis()
-        # jax < 0.5 returns [dict], newer returns dict
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        flops = ca.get("flops")
+        flops = compiled.cost_analysis().get("flops")
         if flops is not None and float(flops) > 0:
             out["flops"] = float(flops)
     except Exception:

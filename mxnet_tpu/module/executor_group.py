@@ -282,21 +282,15 @@ class DataParallelExecutorGroup:
         if self._spmd is not None:
             # recompile at the new shapes over the SAME device-resident
             # state (share_state_with: the program cache makes this one
-            # lookup when the shape was seen before); shapes the single
-            # program cannot express fall back to replication
+            # lookup when the shape was seen before); a batch the mesh
+            # does not divide falls back to replication
             batch0 = data_shapes[0].shape[
                 DataDesc.get_batch_axis(getattr(data_shapes[0], "layout",
                                                 "NCHW"))]
-            new = None
             if batch0 % len(self.contexts) == 0:
-                try:
-                    new = self._build_spmd_trainer(
-                        data_shapes, label_shapes, self._spmd.optimizer,
-                        share_state_with=self._spmd)
-                except Exception as e:
-                    self.logger.info("SPMD reshape recompile failed "
-                                     "(%s)", e)
-            if new is not None:
+                new = self._build_spmd_trainer(
+                    data_shapes, label_shapes, self._spmd.optimizer,
+                    share_state_with=self._spmd)
                 self._spmd.clear_placement_cache()
                 self._spmd = new
                 self._spmd_batch = None
@@ -349,16 +343,11 @@ class DataParallelExecutorGroup:
     def enable_spmd(self, optimizer, arg_params, aux_params):
         """Route this group's training through the one SPMD step
         program, seeding the device-resident state from the given host
-        params.  Returns True on success; False leaves the classic
-        replication machinery untouched (caller keeps the host-updater
-        path)."""
-        try:
-            trainer = self._build_spmd_trainer(
-                self.data_shapes, self.label_shapes, optimizer)
-        except Exception as e:
-            self.logger.info("SPMD step program unavailable (%s); "
-                             "keeping per-device replication", e)
-            return False
+        params.  Module qualifies the setup first
+        (``_spmd_optimizer``); a mesh or compile error here is a bug to
+        see and propagates."""
+        trainer = self._build_spmd_trainer(
+            self.data_shapes, self.label_shapes, optimizer)
         if self._spmd is not None:
             # force re-init: retire the previous trainer's pinned
             # input-placement buffers before swapping it out
@@ -367,7 +356,6 @@ class DataParallelExecutorGroup:
         self._spmd = trainer
         self._spmd_batch = None
         self._spmd_outputs = None
-        return True
 
     def disable_spmd(self, reason):
         """Leave the SPMD step program: reload the per-exec param/aux

@@ -2,24 +2,29 @@
 
 The reference's runtime core is C++ behind a ctypes ABI
 (``src/c_api/c_api.cc`` → ``python/mxnet/base.py``).  Same structure here:
-``src/*.cc`` compiles into ``libmxtpu.so`` (lazily, with g++ — no external
-deps, cached by source mtime) and this module is the typed ctypes facade.
-If no toolchain is available the callers fall back to pure-Python paths.
+``src/*.cc`` compiles into ``libmxtpu.<hash>.so`` (lazily, with g++ — no
+external deps) and this module is the typed ctypes facade.  The file name
+carries a hash of the sources, so a binary lying on disk that was built
+from other sources (the ``.so`` is ignored by git, but a copy of the disk
+takes it along) is never loaded.  If no toolchain is available the callers
+fall back to pure-Python paths; :func:`status` says which happened.
 """
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "src")
-_LIB_PATH = os.path.join(_HERE, "libmxtpu.so")
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
+_status = "not loaded"
 
 
 def _sources():
@@ -27,21 +32,27 @@ def _sources():
                   if f.endswith(".cc"))
 
 
-def _needs_build():
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    return any(os.path.getmtime(s) > lib_mtime for s in _sources())
+def _lib_path():
+    """``libmxtpu.<hash of src/*.cc>.so`` beside this file."""
+    h = hashlib.sha256()
+    for src in _sources():
+        h.update(os.path.basename(src).encode())
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return os.path.join(_HERE, "libmxtpu.%s.so" % h.hexdigest()[:16])
 
 
-def _build():
+def _build(path):
     # build to a temp name + atomic rename: concurrent first-use from
     # several processes must never CDLL a half-written .so
-    tmp = "%s.%d.tmp" % (_LIB_PATH, os.getpid())
+    tmp = "%s.%d.tmp" % (path, os.getpid())
     cmd = ["g++", "-std=c++17", "-O2", "-fPIC", "-shared", "-pthread",
            "-o", tmp] + _sources()
     subprocess.run(cmd, check=True, capture_output=True)
-    os.replace(tmp, _LIB_PATH)
+    os.replace(tmp, path)
+    for stale in glob.glob(os.path.join(_HERE, "libmxtpu*.so")):
+        if stale != path:
+            os.unlink(stale)
 
 
 def _declare(lib):
@@ -98,22 +109,35 @@ MXT_FN = ctypes.CFUNCTYPE(None, ctypes.c_void_p)
 
 def lib():
     """The loaded native library, or None (no toolchain / build failure)."""
-    global _lib, _tried
+    global _lib, _tried, _status
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
         try:
-            if _needs_build():
-                _build()
-            _lib = _declare(ctypes.CDLL(_LIB_PATH))
-        except (OSError, subprocess.CalledProcessError):
+            path = _lib_path()
+            built = not os.path.exists(path)
+            if built:
+                _build(path)
+            _lib = _declare(ctypes.CDLL(path))
+            _status = "built" if built else "loaded"
+        except (OSError, subprocess.CalledProcessError) as e:
             _lib = None
+            _status = "unavailable (%s: %s)" % (type(e).__name__, e)
         return _lib
 
 
 def available():
     return lib() is not None
+
+
+def status():
+    """How the native runtime came up in this process: ``"built"`` (compiled
+    just now from ``src/*.cc``), ``"loaded"`` (a binary with the sources'
+    hash was on disk) or ``"unavailable (<why>)"`` (callers are on their
+    pure-Python paths)."""
+    lib()
+    return _status
 
 
 def storage_stats():

@@ -105,7 +105,7 @@ def sdp_attention_paged(query, k_pool, v_pool, tables, positions,
         scale = 1.0 / (d ** 0.5)
     from ..pallas_ops import dispatch as _pd
     if _pd.use_attention_paged("DotProductAttentionPaged", b, h, lq,
-                               t * bs, d, query.dtype):
+                               t * bs, d, query.dtype, bs):
         from ..pallas_ops.paged_attention import flash_attention_paged
         return flash_attention_paged(
             query, k_pool, v_pool, tables, positions, bs, scale=scale,

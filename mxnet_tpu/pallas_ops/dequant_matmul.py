@@ -44,7 +44,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from ..base import MXNetError, get_env
-from .flash_attention import _VMEM, divisor_block, pltpu
+from .flash_attention import (_SEQ_GRID, _vmem_spec as _spec,
+                              divisor_block, pltpu)
 
 __all__ = ["quantize_int8", "dequantize_int8", "QuantizedWeight",
            "dequant_matmul", "dequant_matmul_dense"]
@@ -175,35 +176,20 @@ def _dqmm_pallas(x, codes, scales, block_m, block_n, block_k, interpret):
     srow = jnp.broadcast_to(jnp.asarray(scales, jnp.float32).reshape(-1),
                             (N,)).reshape(1, N)
 
-    def _spec(shape, index_map):
-        if _VMEM is not None:
-            return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
-        return pl.BlockSpec(shape, index_map)  # pragma: no cover
-
     in_specs = [
         _spec((bm, bk), lambda i, j, k: (i, k)),   # x tile
         _spec((bn, bk), lambda i, j, k: (j, k)),   # int8 code tile
         _spec((1, bn), lambda i, j, k: (0, j)),    # row scales
     ]
-    out_specs = _spec((bm, bn), lambda i, j, k: (i, j))
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
-        _params_cls = getattr(pltpu, "CompilerParams", None) or \
-            pltpu.TPUCompilerParams
-        params = dict(compiler_params=_params_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary")))
-    else:  # pragma: no cover
-        scratch = [pl.MemoryRef((bm, bn), jnp.float32)]
-        params = {}
     return pl.pallas_call(
         functools.partial(_dqmm_kernel, nk=nk),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         grid=(M // bm, N // bn, nk),
         in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
+        out_specs=_spec((bm, bn), lambda i, j, k: (i, j)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        **params)(x, codes, srow)
+        compiler_params=_SEQ_GRID)(x, codes, srow)
 
 
 def dequant_matmul_dense(x, codes, scales):

@@ -44,7 +44,8 @@ import contextlib
 import threading
 
 from ..base import get_env
-from .flash_attention import _on_tpu, pltpu
+from .flash_attention import _on_tpu, divisor_block, mosaic_block_ok
+from .softmax_xent import row_block
 
 __all__ = ["mode", "kernels_active", "interpret_mode", "block_rows",
            "block_seq", "fingerprint", "overriding", "use_rowwise",
@@ -80,9 +81,12 @@ def overriding(fp):
     finally:
         _override.fp = prev
 
-# one (block_rows, width) fp32 tile must fit VMEM (~16 MB/core) with
-# headroom for the kernel's other operands and Mosaic's double buffering
-_VMEM_TILE_BUDGET = 4 * 1024 * 1024
+# one (block_rows, width) fp32 tile, with the kernel's other operands
+# and Mosaic's double buffering on top, must fit the 16 MiB of VMEM a
+# v5e kernel may use: the backward kernels compile for the chip with a
+# 2 MiB tile (8 x 65536) and run out of VMEM with a 4 MiB one
+# (tests/test_chip_compile.py)
+_VMEM_TILE_BUDGET = 2 * 1024 * 1024
 _FLOAT_DTYPES = ("float32", "bfloat16", "float16")
 
 
@@ -159,8 +163,10 @@ def eligible_rowwise(rows, width, dtype):
     * width >= 2 (degenerate single-class rows stay with XLA);
     * one fp32 tile within the VMEM budget at SOME divisor block size
       (row_block degrades the block, so rows never disqualify);
-    * compiled Mosaic additionally wants the lane dimension aligned:
-      width % 128 == 0 off-interpret (interpret mode takes any width).
+    * compiled Mosaic additionally wants the lane dimension aligned
+      (width % 128 == 0) and a row block it can tile — a multiple of 8
+      or all the rows (``mosaic_block_ok``): 12 rows would tile by 6 and
+      are left to XLA.  Interpret mode takes any width and block.
     """
     if str(dtype) not in _FLOAT_DTYPES:
         return False
@@ -169,16 +175,18 @@ def eligible_rowwise(rows, width, dtype):
         return False
     if width * 4 > _VMEM_TILE_BUDGET:  # even a 1-row tile would not fit
         return False
-    if not interpret_mode() and width % 128 != 0:
-        return False
-    return True
+    if interpret_mode():
+        return True
+    return width % 128 == 0 and mosaic_block_ok(
+        row_block(rows, row_block_for(rows, width)), rows)
 
 
 def eligible_attention(b, h, lq, lk, d, dtype):
     """May a [B, H, L, D] attention pattern run as the flash kernel?
 
     Sequence lengths must tile exactly by the (clamped) block size —
-    flash_attention asserts divisibility; head dim is kept within one
+    flash_attention asserts divisibility — and compiled Mosaic must
+    accept that block (``mosaic_block_ok``); head dim is kept within one
     VMEM-friendly tile.
     """
     if str(dtype) not in _FLOAT_DTYPES:
@@ -187,9 +195,24 @@ def eligible_attention(b, h, lq, lk, d, dtype):
     for length in (int(lq), int(lk)):
         if length < 1 or length % min(bs, length) != 0:
             return False
+        if not interpret_mode() and not mosaic_block_ok(min(bs, length),
+                                                        length):
+            return False
     if int(d) < 1 or int(d) > 512:
         return False
     return int(b) >= 1 and int(h) >= 1
+
+
+def _decode_attention_ok(b, h, lq, lk, d, dtype):
+    """The rules the offset and paged decode kernels share: a floating
+    dtype, non-empty shapes, head dim within one VMEM-friendly tile, and
+    — compiled — a Q block Mosaic tiles."""
+    if str(dtype) not in _FLOAT_DTYPES:
+        return False
+    if min(int(b), int(h), int(lq), int(lk)) < 1 or not 1 <= int(d) <= 512:
+        return False
+    return interpret_mode() or mosaic_block_ok(
+        divisor_block(lq, block_seq()), int(lq))
 
 
 def eligible_attention_offset(b, h, lq, lk, d, dtype):
@@ -200,32 +223,31 @@ def eligible_attention_offset(b, h, lq, lk, d, dtype):
     its blocks to *divisors* of the sequence lengths
     (``flash_attention.divisor_block``), so KV-cache bucket lengths
     (multiples of ``MXNET_SERVE_KV_BLOCK``, not of the configured
-    sequence block) never disqualify.  Only dtype/head-dim rules remain.
+    sequence block) never disqualify in interpret mode.  Compiled Mosaic
+    must accept the divisor it lands on (``mosaic_block_ok``): a
+    1088-token cache tiles by 64, a 300-token one has no divisor that is
+    a multiple of 8 and is left to XLA.
     """
-    if str(dtype) not in _FLOAT_DTYPES:
+    if not _decode_attention_ok(b, h, lq, lk, d, dtype):
         return False
-    if int(lq) < 1 or int(lk) < 1:
-        return False
-    if int(d) < 1 or int(d) > 512:
-        return False
-    return int(b) >= 1 and int(h) >= 1
+    return interpret_mode() or mosaic_block_ok(
+        divisor_block(lk, block_seq()), int(lk))
 
 
-def eligible_attention_paged(b, h, lq, lk, d, dtype):
+def eligible_attention_paged(b, h, lq, lk, d, dtype, block_size):
     """May a paged-KV attention pattern (block tables over a global
     pool) run as ``flash_attention_paged``?
 
-    The offset rules (:func:`eligible_attention_offset`) plus one
-    structural requirement: the kernel's block tables ride as
-    scalar-prefetch operands (``pltpu.PrefetchScalarGridSpec``), so the
-    Pallas TPU backend module must be importable — pure-CPU jaxlib
-    builds without it keep the gather-based dense twin
-    (``paged_attention_reference``).  ``lk`` is the logical length the
-    table addresses (table width × block size).
+    ``lk`` is the logical length the table addresses (table width ×
+    block size).  The K/V tile is one ``block_size``-token pool block
+    and the Q tile a divisor of ``lq``; compiled Mosaic must accept both
+    (``mosaic_block_ok`` — ``MXNET_SERVE_KV_BLOCK=4`` is left to XLA).
     """
-    if pltpu is None:  # pragma: no cover - present on this jaxlib
+    bs = int(block_size)
+    if bs < 1 or not _decode_attention_ok(b, h, lq, lk, d, dtype):
         return False
-    return eligible_attention_offset(b, h, lq, lk, d, dtype)
+    # the pool axis is num_blocks * bs: a block is never "the whole axis"
+    return interpret_mode() or bs % 8 == 0
 
 
 def eligible_dequant_matmul(m, n, k, dtype):
@@ -236,8 +258,11 @@ def eligible_dequant_matmul(m, n, k, dtype):
     (``flash_attention.divisor_block``), so odd shapes never disqualify
     — only the activation dtype, a nontrivial reduction (k >= 2; a
     single-column "matmul" stays with XLA) and the VMEM tile budget
-    remain.  Compiled Mosaic additionally wants the lane dimension
-    aligned: k % 128 == 0 off-interpret (int8 codes tile at (32, 128)).
+    remain.  Compiled Mosaic additionally wants the lane dimensions
+    aligned off-interpret: k % 128 == 0 (int8 codes tile at (32, 128)),
+    an ``n`` block that is a multiple of 128 or all of ``n`` (it is the
+    lane dim of the scale row and of the output), and an ``m`` block it
+    can tile (``mosaic_block_ok``).
     """
     if str(dtype) not in _FLOAT_DTYPES:
         return False
@@ -252,9 +277,11 @@ def eligible_dequant_matmul(m, n, k, dtype):
     # must still account for it
     if 4 * (bm * bk + bn * bk + bm * bn) > _VMEM_TILE_BUDGET:
         return False
-    if not interpret_mode() and k % 128 != 0:
-        return False
-    return True
+    if interpret_mode():
+        return True
+    bn = divisor_block(n, bs)
+    return (k % 128 == 0 and (bn == n or bn % 128 == 0)
+            and mosaic_block_ok(divisor_block(m, bs), m))
 
 
 # ---------------------------------------------------------------------------
@@ -309,12 +336,11 @@ def use_attention(kind, b, h, lq, lk, d, dtype, offset=False):
     return True
 
 
-def use_attention_paged(kind, b, h, lq, lk, d, dtype):
+def use_attention_paged(kind, b, h, lq, lk, d, dtype, block_size):
     """Route decision for a paged-KV attention pattern; counts a route
     when taken."""
-    if not kernels_active() or not eligible_attention_paged(b, h, lq,
-                                                            lk, d,
-                                                            dtype):
+    if not kernels_active() or not eligible_attention_paged(
+            b, h, lq, lk, d, dtype, block_size):
         return False
     _note(kind)
     return True

@@ -22,41 +22,61 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pallas TPU backend (absent on pure-CPU jaxlib builds)
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "flash_attention_offset", "divisor_block",
-           "_on_tpu", "_VMEM", "pltpu"]
+           "mosaic_block_ok", "_on_tpu"]
 
 _NEG = -1e30
+_SUBLANES = 8  # Mosaic tiles the second-to-last block dim in 8-row units
 
 
 def divisor_block(length, bound):
-    """Largest block size <= ``bound`` that divides ``length`` exactly.
+    """Largest block size <= ``bound`` that divides ``length`` exactly,
+    preferring a multiple of 8 when one exists.
 
     The decode-path kernels tile over KV caches whose lengths are
     multiples of ``MXNET_SERVE_KV_BLOCK``, not of the configured
     sequence block — degrading the block to a divisor (instead of
     failing the divisibility assert) keeps every cache bucket eligible.
+    Mosaic only accepts a second-to-last block dim that is a multiple of
+    8 or the whole axis (:func:`mosaic_block_ok`), so a 1088-token cache
+    tiles by 64, not by its largest divisor 68.
     """
-    length, bound = int(length), max(1, int(bound))
-    b = min(length, bound)
-    while length % b:
-        b -= 1
-    return b
+    length = int(length)
+    divisors = [b for b in range(min(length, max(1, int(bound))), 0, -1)
+                if length % b == 0]
+    return next((b for b in divisors if mosaic_block_ok(b, length)),
+                divisors[0])
+
+
+def mosaic_block_ok(block, length):
+    """Does the chip's compiler accept ``block`` as the second-to-last
+    block dim over an axis of ``length``?  (A multiple of 8, or the
+    whole axis.)  Interpret mode takes any block; the eligibility rules
+    in :mod:`.dispatch` ask this only when kernels compile."""
+    return block == length or block % _SUBLANES == 0
+
+
+def _vmem_spec(shape, index_map):
+    return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
+
+
+def _resolve_interpret(interpret):
+    """``None`` selects by platform: compiled Mosaic on a TPU, Pallas
+    interpret mode elsewhere — a direct caller on a chip never gets the
+    interpreter by omission."""
+    return (not _on_tpu()) if interpret is None else bool(interpret)
+
+
+_SEQ_GRID = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _on_tpu():
     """True when the default jax backend is a TPU (shared probe — rtc.py
     and parallel/sp.py import this rather than re-implementing it)."""
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -113,6 +133,14 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                     jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
 
 
+def _softmax_scratch(block_q, d):
+    """VMEM scratch of the online-softmax state: running max, running
+    sum and the fp32 accumulator."""
+    return [pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32)]
+
+
 def _fa_forward(q, k, v, causal, scale, block_q, block_k, interpret):
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
@@ -128,41 +156,20 @@ def _fa_forward(q, k, v, causal, scale, block_q, block_k, interpret):
     kernel = functools.partial(_fa_kernel, scale=scale, block_q=block_q,
                                block_k=block_k, causal=causal, nk=nk)
 
-    def _spec(shape, index_map):
-        if _VMEM is not None:
-            return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
-        return pl.BlockSpec(shape, index_map)  # pragma: no cover
-
     in_specs = [
-        _spec((1, block_q, D), lambda b, i, j: (b, i, 0)),   # Q tile
-        _spec((1, block_k, D), lambda b, i, j: (b, j, 0)),   # K tile
-        _spec((1, block_k, D), lambda b, i, j: (b, j, 0)),   # V tile
+        _vmem_spec((1, block_q, D), lambda b, i, j: (b, i, 0)),   # Q tile
+        _vmem_spec((1, block_k, D), lambda b, i, j: (b, j, 0)),   # K tile
+        _vmem_spec((1, block_k, D), lambda b, i, j: (b, j, 0)),   # V tile
     ]
-    out_specs = _spec((1, block_q, D), lambda b, i, j: (b, i, 0))
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((block_q, 1), jnp.float32),
-                   pltpu.VMEM((block_q, 1), jnp.float32),
-                   pltpu.VMEM((block_q, D), jnp.float32)]
-        # renamed across jax releases: TPUCompilerParams (<=0.4.x) ->
-        # CompilerParams (newer)
-        _params_cls = getattr(pltpu, "CompilerParams", None) or \
-            pltpu.TPUCompilerParams
-        params = dict(compiler_params=_params_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary")))
-    else:  # pragma: no cover
-        scratch = [pl.MemoryRef((block_q, 1), jnp.float32),
-                   pl.MemoryRef((block_q, 1), jnp.float32),
-                   pl.MemoryRef((block_q, D), jnp.float32)]
-        params = {}
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype),
         grid=(B * H, Lq // block_q, nk),
         in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
+        out_specs=_vmem_spec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+        scratch_shapes=_softmax_scratch(block_q, D),
         interpret=interpret,
-        **params)(qr, kr, vr)
+        compiler_params=_SEQ_GRID)(qr, kr, vr)
     return out.reshape(B, H, Lq, D)
 
 
@@ -198,10 +205,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
     Pallas interpret mode elsewhere (slow but exact — for tests)."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    if interpret is None:
-        interpret = not _on_tpu()
     return _flash(q, k, v, causal, float(scale), int(block_q),
-                  int(block_k), bool(interpret))
+                  int(block_k), _resolve_interpret(interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -224,7 +229,7 @@ def _fa_offset_kernel(ofs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     """One (batch·head, q-block, k-block) grid cell, offset-causal."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    ofs = ofs_ref[0]
+    ofs = ofs_ref[pl.program_id(0)]
 
     @pl.when(ki == 0)
     def _init():
@@ -279,57 +284,33 @@ def flash_attention_offset(q, k, v, offsets, scale=None, block_q=128,
     Lk = k.shape[2]
     if scale is None:
         scale = 1.0 / (D ** 0.5)
-    if interpret is None:
-        interpret = not _on_tpu()
     block_q = divisor_block(Lq, block_q)
     block_k = divisor_block(Lk, block_k)
     nk = Lk // block_k
     qr = q.reshape(B * H, Lq, D)
     kr = k.reshape(B * H, Lk, D)
     vr = v.reshape(B * H, Lk, D)
-    # one offset scalar per grid row: repeat per head
+    # one offset scalar per grid row (repeat per head), prefetched to
+    # SMEM whole — the way flash_attention_paged carries its tables;
+    # Mosaic refuses a (1,)-blocked rank-1 SMEM operand
     ofs = jnp.repeat(jnp.asarray(offsets, jnp.int32).reshape(B), H)
 
     kernel = functools.partial(_fa_offset_kernel, scale=float(scale),
                                block_q=block_q, block_k=block_k, nk=nk)
-
-    def _spec(shape, index_map):
-        if _VMEM is not None:
-            return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
-        return pl.BlockSpec(shape, index_map)  # pragma: no cover
-
-    if pltpu is not None:
-        ofs_spec = pl.BlockSpec((1,), lambda b, i, j: (b,),
-                                memory_space=pltpu.SMEM)
-    else:  # pragma: no cover
-        ofs_spec = pl.BlockSpec((1,), lambda b, i, j: (b,))
-    in_specs = [
-        ofs_spec,                                            # offset
-        _spec((1, block_q, D), lambda b, i, j: (b, i, 0)),   # Q tile
-        _spec((1, block_k, D), lambda b, i, j: (b, j, 0)),   # K tile
-        _spec((1, block_k, D), lambda b, i, j: (b, j, 0)),   # V tile
-    ]
-    out_specs = _spec((1, block_q, D), lambda b, i, j: (b, i, 0))
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((block_q, 1), jnp.float32),
-                   pltpu.VMEM((block_q, 1), jnp.float32),
-                   pltpu.VMEM((block_q, D), jnp.float32)]
-        _params_cls = getattr(pltpu, "CompilerParams", None) or \
-            pltpu.TPUCompilerParams
-        params = dict(compiler_params=_params_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary")))
-    else:  # pragma: no cover
-        scratch = [pl.MemoryRef((block_q, 1), jnp.float32),
-                   pl.MemoryRef((block_q, 1), jnp.float32),
-                   pl.MemoryRef((block_q, D), jnp.float32)]
-        params = {}
+    q_map = lambda b, i, j, ofs: (b, i, 0)
+    kv_map = lambda b, i, j, ofs: (b, j, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B * H, Lq // block_q, nk),
+        in_specs=[_vmem_spec((1, block_q, D), q_map),        # Q tile
+                  _vmem_spec((1, block_k, D), kv_map),       # K tile
+                  _vmem_spec((1, block_k, D), kv_map)],      # V tile
+        out_specs=_vmem_spec((1, block_q, D), q_map),
+        scratch_shapes=_softmax_scratch(block_q, D))
     out = pl.pallas_call(
         kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype),
-        grid=(B * H, Lq // block_q, nk),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-        interpret=interpret,
-        **params)(ofs, qr, kr, vr)
+        interpret=_resolve_interpret(interpret),
+        compiler_params=_SEQ_GRID)(ofs, qr, kr, vr)
     return out.reshape(B, H, Lq, D)

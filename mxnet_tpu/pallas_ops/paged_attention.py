@@ -31,7 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .flash_attention import _VMEM, _on_tpu, divisor_block, pltpu
+from .flash_attention import (_resolve_interpret, _softmax_scratch,
+                              _vmem_spec as _spec, divisor_block, pltpu)
 
 __all__ = ["flash_attention_paged", "paged_attention_reference"]
 
@@ -131,12 +132,8 @@ def flash_attention_paged(q, k_pool, v_pool, tables, positions,
 
     The tables/positions ride as scalar-prefetch operands so BlockSpec
     index maps can gather physical tiles; blocks a sequence cannot see
-    are skipped dynamically like ``flash_attention_offset``.  Requires
-    the Pallas TPU backend module (``PrefetchScalarGridSpec``) — callers
-    gate on ``dispatch.eligible_attention_paged``.  Forward-only."""
-    if pltpu is None:  # pragma: no cover - eligibility gates this
-        raise RuntimeError("flash_attention_paged needs pallas.tpu "
-                           "(PrefetchScalarGridSpec)")
+    are skipped dynamically like ``flash_attention_offset``.
+    Forward-only."""
     B, H, Lq, D = q.shape
     T = tables.shape[1]
     bs = int(block_size)
@@ -145,8 +142,6 @@ def flash_attention_paged(q, k_pool, v_pool, tables, positions,
         "pool length must be a multiple of block_size"
     if scale is None:
         scale = 1.0 / (D ** 0.5)
-    if interpret is None:
-        interpret = not _on_tpu()
     block_q = divisor_block(Lq, block_q)
     tbl = jnp.asarray(tables, jnp.int32)
     pos = jnp.asarray(positions, jnp.int32).reshape(B)
@@ -155,11 +150,6 @@ def flash_attention_paged(q, k_pool, v_pool, tables, positions,
     kernel = functools.partial(_paged_kernel, scale=float(scale),
                                block_q=block_q, block_size=bs, nt=T,
                                int8=int8)
-
-    def _spec(shape, index_map):
-        if _VMEM is not None:
-            return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
-        return pl.BlockSpec(shape, index_map)  # pragma: no cover
 
     if int8:
         sk = jnp.asarray(kv_scales[0], jnp.float32)
@@ -183,17 +173,13 @@ def flash_attention_paged(q, k_pool, v_pool, tables, positions,
             _spec((1, bs, D), kv_map),
         ],
         out_specs=_spec((1, 1, block_q, D), q_map),
-        scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
-                        pltpu.VMEM((block_q, 1), jnp.float32),
-                        pltpu.VMEM((block_q, D), jnp.float32)])
-    _params_cls = getattr(pltpu, "CompilerParams", None) or \
-        pltpu.TPUCompilerParams
+        scratch_shapes=_softmax_scratch(block_q, D))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Lq, D), q.dtype),
-        interpret=interpret,
-        compiler_params=_params_cls(
+        interpret=_resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")))(*scalars, q, k_pool,
                                                 v_pool)

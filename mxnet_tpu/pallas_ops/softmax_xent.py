@@ -20,9 +20,9 @@ intermediate larger than the kernel's own output exist:
   tile); the backward recomputes the row softmax blockwise and writes
   ``(softmax(x) - onehot) * g`` straight into the gradient.
 
-All three follow flash_attention's pattern: compiled Mosaic on TPU,
-Pallas interpret mode elsewhere — the quick tier runs the real kernel
-bodies on CPU.  Routing lives in :mod:`.dispatch`; nothing here reads
+All three follow flash_attention's pattern: ``interpret=None`` compiles
+through Mosaic on a TPU and interprets elsewhere — the quick tier runs
+the real kernel bodies on CPU.  Routing lives in :mod:`.dispatch`; nothing here reads
 environment state.
 """
 from __future__ import annotations
@@ -33,32 +33,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .flash_attention import _VMEM
+from .flash_attention import (_resolve_interpret, _vmem_spec as _spec,
+                              divisor_block)
 
 __all__ = ["fused_softmax", "softmax_output_head", "softmax_xent_loss",
            "row_block"]
 
 
-def row_block(rows, bound):
-    """Largest row-block size <= ``bound`` that divides ``rows`` (Pallas
-    grids need exact tiling; a non-dividing bound degrades gracefully
-    instead of failing eligibility)."""
-    b = max(1, min(int(bound), int(rows)))
-    while rows % b:
-        b -= 1
-    return b
-
-
-def _spec(shape, index_map):
-    if _VMEM is not None:
-        return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
-    return pl.BlockSpec(shape, index_map)  # pragma: no cover
+# Row-block size <= ``bound`` that divides ``rows`` (Pallas grids need
+# exact tiling; a non-dividing bound degrades gracefully): the sequence
+# kernels' divisor rule, under the name the row-wise kernels use.
+row_block = divisor_block
 
 
 def _grid_call(kernel, outs, grid, in_specs, out_specs, interpret, *args):
     return pl.pallas_call(kernel, out_shape=outs, grid=grid,
                           in_specs=in_specs, out_specs=out_specs,
-                          interpret=interpret)(*args)
+                          interpret=_resolve_interpret(interpret))(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -135,7 +126,7 @@ def _rows_call(kernel, x, extras, out_shapes, block_rows, interpret):
 # fused_softmax: row softmax with a kernel vjp
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
-def fused_softmax(x, block_rows=8, interpret=True):
+def fused_softmax(x, block_rows=8, interpret=None):
     """Row softmax of a 2D array as one VMEM-blocked kernel."""
     return _rows_call(_softmax_fwd_kernel, x, (),
                       ((x.shape[1], x.dtype),), block_rows, interpret)
@@ -160,7 +151,7 @@ fused_softmax.defvjp(_fused_softmax_fwd, _fused_softmax_bwd)
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
 def softmax_output_head(data, label, scale=1.0, block_rows=8,
-                        interpret=True):
+                        interpret=None):
     """SoftmaxOutput contract: forward = softmax probabilities, backward
     = implicit loss gradient ``(p - onehot(label)) * scale`` regardless
     of the incoming head cotangent (reference softmax_output.cc)."""
@@ -191,7 +182,7 @@ softmax_output_head.defvjp(_head_fwd, _head_bwd)
 # softmax_xent_loss: per-row cross entropy, probabilities never built
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def softmax_xent_loss(logits, label, block_rows=8, interpret=True):
+def softmax_xent_loss(logits, label, block_rows=8, interpret=None):
     """Per-row softmax cross-entropy ``logsumexp(x) - x[label]`` of 2D
     logits; returns shape ``(rows,)`` float32.  Neither pass materializes
     the [rows, classes] probability tensor in HBM."""

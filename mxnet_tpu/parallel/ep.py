@@ -13,9 +13,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
-from .mesh import axis_size as _axis_size
 
 __all__ = ["moe_ffn", "top1_dispatch", "init_moe_params"]
 
@@ -77,7 +76,7 @@ def moe_ffn(x, params, axis_name="ep", capacity_factor=2.0,
     Returns ([T_local, D], aux_loss) — aux replicated over the named
     axes.
     """
-    ep = _axis_size(axis_name)
+    ep = jax.lax.axis_size(axis_name)
     T, D = x.shape
     e_local = params["w1"].shape[0]
     E = e_local * ep

@@ -13,18 +13,8 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["make_mesh", "local_mesh", "data_parallel_sharding", "P",
-           "NamedSharding", "axis_size", "mesh_for_contexts",
+           "NamedSharding", "mesh_for_contexts",
            "global_device_order", "distributed_init_from_env"]
-
-
-def axis_size(axis_name):
-    """Static size of a mapped mesh axis, from inside shard_map/pjit.
-
-    ``jax.lax.axis_size`` only exists on newer jax; on older releases
-    ``psum`` of a python scalar folds to the axis size statically."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 def make_mesh(axes, devices=None):

@@ -20,9 +20,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
-from .mesh import axis_size as _axis_size
 
 __all__ = ["spmd_pipeline", "pipelined", "stack_stage_params"]
 
@@ -40,7 +39,7 @@ def spmd_pipeline(stage_fn, stage_params, x, axis_name="pp",
     Returns [M, mb, ...]: outputs of the last stage (valid on every device
         after the closing broadcast).
     """
-    S = _axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     M = x.shape[0] if num_microbatches is None else num_microbatches
     assert M == x.shape[0], \
@@ -105,7 +104,7 @@ def pipelined(stage_fn, mesh, axis_name="pp", num_microbatches=4):
             lambda _: pspec, stacked_params)
         fn = shard_map(body, mesh=mesh,
                        in_specs=(in_param_specs, P()),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
         return fn(stacked_params, x)
 
     return run

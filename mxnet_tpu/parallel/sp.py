@@ -21,9 +21,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
-from .mesh import axis_size as _axis_size
 
 __all__ = ["ring_attention", "ring_self_attention", "blockwise_attention",
            "local_attention"]
@@ -123,7 +122,7 @@ def ring_attention(q, k, v, axis_name="sp", causal=False, scale=None):
     Returns [B, H, L_local, D].
     """
     B, H, Lc, D = q.shape
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     if sp == 1:
         # degenerate ring: pure local attention (flash kernel on TPU)
         return local_attention(q, k, v, causal=causal, scale=scale)
@@ -170,7 +169,7 @@ def ring_self_attention(q, k, v, mesh, axis_name="sp", batch_axis=None,
     fn = functools.partial(ring_attention, axis_name=axis_name,
                            causal=causal, scale=scale)
     sharded = shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                        out_specs=spec, check_rep=False)
+                        out_specs=spec, check_vma=False)
     sharding = NamedSharding(mesh, spec)
     q, k, v = (jax.device_put(x, sharding) for x in (q, k, v))
     return sharded(q, k, v)

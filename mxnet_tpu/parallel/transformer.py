@@ -23,12 +23,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from .sp import ring_attention
 from .ep import moe_ffn, init_moe_params
 
-from .mesh import axis_size as _axis_size
 
 __all__ = ["TransformerConfig", "init_transformer_params",
            "transformer_loss", "TransformerTrainer"]
@@ -143,7 +142,7 @@ def _block_fn(blk, x, cfg, pos0):
     if "moe" in blk:
         B, L, D = h.shape
         T = B * L
-        ep = _axis_size("tp")
+        ep = jax.lax.axis_size("tp")
         rank = jax.lax.axis_index("tp")
         if T % ep != 0:
             raise ValueError(
@@ -236,7 +235,7 @@ class TransformerTrainer:
                 # that axis; a param SHARDED over an axis comes out
                 # inflated by that axis size (the forward psum's transpose
                 # summed identical cotangents) -> divide by the size.
-                tp_size = _axis_size("tp")
+                tp_size = jax.lax.axis_size("tp")
 
                 def combine(g, spec):
                     g = jax.lax.pmean(jax.lax.pmean(g, "dp"), "sp")
@@ -259,7 +258,7 @@ class TransformerTrainer:
                 local_step, mesh=mesh,
                 in_specs=(in_param_specs, self._data_spec,
                           self._data_spec),
-                out_specs=(in_param_specs, P()), check_rep=False)
+                out_specs=(in_param_specs, P()), check_vma=False)
             return fn(params, tokens, targets)
 
         self._step = jax.jit(step, donate_argnums=(0,))

@@ -21,10 +21,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .base import MXNetError
 from .ndarray import NDArray
-from .pallas_ops.flash_attention import _VMEM, _on_tpu
+from .pallas_ops.flash_attention import _on_tpu
 
 __all__ = ["PallasKernel", "MXRtc"]
 
@@ -60,13 +61,13 @@ class PallasKernel:
             kw["grid"] = self.grid
         if self.in_specs is not None:
             kw["in_specs"] = self.in_specs
-        elif _VMEM is not None:
-            kw["in_specs"] = [pl.BlockSpec(memory_space=_VMEM)
+        else:
+            kw["in_specs"] = [pl.BlockSpec(memory_space=pltpu.VMEM)
                               for _ in in_shapes]
         if self.out_specs is not None:
             kw["out_specs"] = self.out_specs
-        elif _VMEM is not None:
-            out_sp = [pl.BlockSpec(memory_space=_VMEM)
+        else:
+            out_sp = [pl.BlockSpec(memory_space=pltpu.VMEM)
                       for _ in out_shapes]
             kw["out_specs"] = out_sp if len(out_sp) > 1 else out_sp[0]
         out_shape = [jax.ShapeDtypeStruct(s, d)

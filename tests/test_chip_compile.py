@@ -1,0 +1,231 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed beside JAX and compiles for a chip that is
+described, not attached (``/opt/skills/guides/on-chip-measurement`` §2,
+rehearsal 3).  Interpret mode hid three kernels Mosaic refuses for
+thirteen PRs; these cases compile every kernel ``chip_smoke.py`` routes
+to, at the smoke's widths, for a described ``v5e:2x2`` — about a second
+each, no chip time — plus one negative case per eligibility rule that
+was tightened to what the compiler accepts.  Nothing runs: a compile
+that passes is not a chip run.
+"""
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from mxnet_tpu.pallas_ops import dispatch, norm
+from mxnet_tpu.pallas_ops import paged_attention as pa
+from mxnet_tpu.pallas_ops import softmax_xent as sx
+
+# the package re-exports functions under these modules' names
+fa = importlib.import_module("mxnet_tpu.pallas_ops.flash_attention")
+dq = importlib.import_module("mxnet_tpu.pallas_ops.dequant_matmul")
+
+pytestmark = pytest.mark.quick
+
+F32, BF16, I8, I32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
+# chip_smoke.py's LM: 16 heads x 128, 2048 wide, vocabulary 32768,
+# batch 8 x sequence 1024; serving at batch bucket 8, 64-token blocks
+B, H, L, D, W, V, BS, T = 8, 16, 1024, 128, 2048, 32768, 64, 16
+ROWS = B * L
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """``struct(shape, dtype)`` placing operands on one described v5e
+    chip; the persistent compile cache is off around the module (such a
+    compile can be written to it but never read back without a chip)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip("cannot describe a v5e topology: %s" % e)
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield lambda shape, dtype=F32: jax.ShapeDtypeStruct(shape, dtype,
+                                                        sharding=one)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compiled_mode(monkeypatch):
+    """Eligibility as it answers on a TPU (the probe sees this CPU)."""
+    monkeypatch.setattr(dispatch, "_on_tpu", lambda: True)
+
+
+def _kernel_calls(fn, *args):
+    """Compile for the described chip; raises what the chip's compiler
+    would raise.  Returns how many Mosaic kernels the program holds."""
+    return jax.jit(fn).lower(*args).compile().as_text() \
+        .count("tpu_custom_call")
+
+
+def _grad(fn, argnums):
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(F32)),
+                    argnums=argnums)
+
+
+def _flash(q, k, v):
+    return fa.flash_attention(q, k, v, causal=True, interpret=False)
+
+
+def _offset(q, k, v, o):
+    return fa.flash_attention_offset(q, k, v, o, interpret=False)
+
+
+def _paged(bs):
+    return lambda q, k, v, t, p: pa.flash_attention_paged(
+        q, k, v, t, p, bs, interpret=False)
+
+
+def _paged_int8(q, k, v, t, p, sk, sv):
+    return pa.flash_attention_paged(q, k, v, t, p, BS, interpret=False,
+                                    kv_scales=(sk, sv))
+
+
+def _rms(x, g):
+    return norm.rms_norm(x, g, 1e-6, 8, False)
+
+
+def _ln(x, g, b):
+    return norm.layer_norm(x, g, b, 1e-5, 8, False)
+
+
+def _dqmm(x, c, s):
+    return dq._dqmm_pallas(x, c, s, 128, 128, 128, False)
+
+
+def _paged_args(s, lq, pool_dtype=F32, bs=BS, blocks=B * T + 1):
+    pool = s((H, blocks * bs, D), pool_dtype)
+    return (s((B, H, lq, D)), pool, pool, s((B, T), I32), s((B,), I32))
+
+
+# (id, fn, operand builder, Mosaic kernels expected in the program)
+CASES = [
+    ("flash-fwd", _flash, lambda s: (s((B, H, L, D)),) * 3, 1),
+    # the backward re-runs the scan twin under vjp: no kernel of its own
+    ("flash-bwd", _grad(_flash, (0, 1, 2)),
+     lambda s: (s((B, H, L, D)),) * 3, 0),
+    ("flash-fwd-bf16", _flash, lambda s: (s((B, H, L, D), BF16),) * 3, 1),
+    ("offset-decode", _offset,
+     lambda s: (s((B, H, 1, D)), s((B, H, L, D)), s((B, H, L, D)),
+                s((B,), I32)), 1),
+    # a 1088-token cache: largest divisor 68, tiled by 64
+    ("offset-1088", _offset,
+     lambda s: (s((B, H, 128, D)), s((B, H, 1088, D)),
+                s((B, H, 1088, D)), s((B,), I32)), 1),
+    ("paged-decode", _paged(BS), lambda s: _paged_args(s, 1), 1),
+    ("paged-chunk", _paged(BS), lambda s: _paged_args(s, 32), 1),
+    ("paged-bf16", _paged(BS),
+     lambda s: (s((B, H, 1, D), BF16),) + _paged_args(s, 1, BF16)[1:], 1),
+    ("paged-int8", _paged_int8,
+     lambda s: _paged_args(s, 1, I8)
+     + (s((H, B * T + 1)), s((H, B * T + 1))), 1),
+    ("softmax-fwd", lambda x: sx.fused_softmax(x, 8, False),
+     lambda s: (s((ROWS, V)),), 1),
+    ("softmax-bwd", _grad(lambda x: sx.fused_softmax(x, 8, False) ** 2,
+                          0),
+     lambda s: (s((ROWS, V)),), 2),
+    ("head-fwd", lambda x, l: sx.softmax_output_head(x, l, 1.0, 8, False),
+     lambda s: (s((ROWS, V)), s((ROWS,))), 1),
+    ("head-bwd",
+     _grad(lambda x, l: sx.softmax_output_head(x, l, 1.0, 8, False), 0),
+     lambda s: (s((ROWS, V)), s((ROWS,))), 2),
+    ("xent-fwd", lambda x, l: sx.softmax_xent_loss(x, l, 8, False),
+     lambda s: (s((ROWS, V)), s((ROWS,))), 1),
+    ("xent-bwd",
+     _grad(lambda x, l: sx.softmax_xent_loss(x, l, 8, False), 0),
+     lambda s: (s((ROWS, V)), s((ROWS,))), 1),
+    ("rms-fwd", _rms, lambda s: (s((ROWS, W)), s((W,))), 1),
+    ("rms-bwd", _grad(_rms, (0, 1)),
+     lambda s: (s((ROWS, W)), s((W,))), 1),
+    ("rms-bwd-bf16", _grad(_rms, (0, 1)),
+     lambda s: (s((ROWS, W), BF16), s((W,), BF16)), 1),
+    ("ln-fwd", _ln, lambda s: (s((ROWS, W)), s((W,)), s((W,))), 1),
+    ("ln-bwd", _grad(_ln, (0, 1, 2)),
+     lambda s: (s((ROWS, W)), s((W,)), s((W,))), 1),
+    # the VMEM tile budget's edge: an 8 x 65536 fp32 tile is 2 MiB
+    ("ln-bwd-2MiB-tile", _grad(_ln, (0, 1, 2)),
+     lambda s: (s((64, 65536)), s((65536,)), s((65536,))), 1),
+    ("dqmm-decode", _dqmm,
+     lambda s: (s((B, W)), s((4 * W, W), I8), s((4 * W,))), 1),
+    ("dqmm-chunk-vocab", _dqmm,
+     lambda s: (s((B * 32, W)), s((V, W), I8), s((V,))), 1),
+    ("dqmm-ffn2", _dqmm,
+     lambda s: (s((B, 4 * W)), s((W, 4 * W), I8), s((W,))), 1),
+]
+
+
+@pytest.mark.parametrize("fn,operands,kernels",
+                         [c[1:] for c in CASES], ids=[c[0] for c in CASES])
+def test_kernel_compiles_for_v5e(chip, fn, operands, kernels):
+    assert _kernel_calls(fn, *operands(chip)) == kernels
+
+
+def test_smoke_shapes_are_eligible(compiled_mode):
+    """What the cases above compile is what dispatch routes on a TPU."""
+    assert dispatch.eligible_attention(B, H, L, L, D, "float32")
+    assert dispatch.eligible_attention_offset(B, H, 1, 1088, D, "float32")
+    assert dispatch.eligible_attention_paged(B, H, 32, T * BS, D,
+                                             "float32", BS)
+    for rows in (B, B * 32, ROWS):
+        assert dispatch.eligible_rowwise(rows, W, "float32")
+    assert dispatch.eligible_rowwise(ROWS, V, "float32")
+    assert dispatch.eligible_rowwise(64, 65536, "float32")
+    for m, n, k in ((B, 4 * W, W), (B * 32, V, W), (B, W, 4 * W)):
+        assert dispatch.eligible_dequant_matmul(m, n, k, "float32")
+
+
+# One case per tightened rule: the compiler refuses the shape, and the
+# rule now says so first, so the op takes the XLA lowering openly.
+REFUSED = [
+    # 12 rows tile by 6: not a multiple of 8, not all the rows
+    ("rowwise-6-row-block", _rms, lambda s: (s((12, W)), s((W,))),
+     lambda: dispatch.eligible_rowwise(12, W, "float32")),
+    # an 8 x 131072 fp32 tile is 4 MiB: the backward runs out of VMEM
+    ("rowwise-4MiB-tile", _grad(_ln, (0, 1, 2)),
+     lambda s: (s((64, 131072)), s((131072,)), s((131072,))),
+     lambda: dispatch.eligible_rowwise(64, 131072, "float32")),
+    # 300 = 2^2 * 3 * 5^2 has no divisor that is a multiple of 8
+    ("offset-300-token-cache", _offset,
+     lambda s: (s((B, H, 1, D)), s((B, H, 300, D)), s((B, H, 300, D)),
+                s((B,), I32)),
+     lambda: dispatch.eligible_attention_offset(B, H, 1, 300, D,
+                                                "float32")),
+    ("paged-4-token-blocks", _paged(4),
+     lambda s: _paged_args(s, 1, bs=4),
+     lambda: dispatch.eligible_attention_paged(B, H, 1, T * 4, D,
+                                               "float32", 4)),
+    # n = 1000 tiles by 40: the scale row's lane dim is not 128-aligned
+    ("dqmm-n-1000", _dqmm,
+     lambda s: (s((B, W)), s((1000, W), I8), s((1000,))),
+     lambda: dispatch.eligible_dequant_matmul(B, 1000, W, "float32")),
+]
+
+
+@pytest.mark.parametrize("fn,operands,eligible",
+                         [c[1:] for c in REFUSED],
+                         ids=[c[0] for c in REFUSED])
+def test_refused_shape_is_ineligible(chip, compiled_mode, fn, operands,
+                                     eligible):
+    assert not eligible()
+    with pytest.raises(Exception):  # noqa: B017 — the compiler's own
+        _kernel_calls(fn, *operands(chip))
+
+
+def test_flash_block_must_be_mosaic_tileable(compiled_mode, monkeypatch):
+    """``MXNET_PALLAS_BLOCK_SEQ=100`` over 200 tokens divides exactly but
+    is no block Mosaic tiles: ineligible compiled, fine interpreted."""
+    monkeypatch.setenv("MXNET_PALLAS_BLOCK_SEQ", "100")
+    assert not dispatch.eligible_attention(2, 4, 200, 200, D, "float32")
+    monkeypatch.setattr(dispatch, "_on_tpu", lambda: False)
+    assert dispatch.eligible_attention(2, 4, 200, 200, D, "float32")

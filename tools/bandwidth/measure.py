@@ -31,7 +31,7 @@ import mxnet_tpu  # noqa: E402,F401  (applies the JAX_PLATFORMS env var)
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 
 
 def parse_args():

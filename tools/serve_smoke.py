@@ -59,9 +59,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
 
 
 def run_mode(mode, args):
@@ -236,6 +233,8 @@ def main(argv=None):
                     help="dump the full protocol result as JSON")
     args = ap.parse_args(argv)
 
+    from mxnet_tpu.base import use_compile_cache
+    use_compile_cache()
     failures = []
     ran = []
     frontdoor_only = args.kill_one or args.swap or args.http

@@ -191,6 +191,8 @@ def main(argv=None):
     args = parser.parse_args(argv)
 
     from mxnet_tpu import profiler
+    from mxnet_tpu.base import use_compile_cache
+    use_compile_cache()
 
     cost = None
     if args.trace:
