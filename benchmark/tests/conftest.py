@@ -1,0 +1,15 @@
+"""CPU by name, four virtual devices, repo root importable — set before
+JAX is imported (run as ``JAX_PLATFORMS=cpu python -m pytest
+benchmark/tests -q`` from the repo root)."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+if "xla_force_host_platform_device_count" not in os.environ.get(
+        "XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                               " --xla_force_host_platform_device_count=4")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
