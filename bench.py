@@ -271,7 +271,7 @@ def bench_fit(name, per_dev_batch, iters, warmup, chip, smoke=False):
             (t0 if seen[0] == warmup else t1)[0] = time.perf_counter()
 
     # step-phase attribution rides along: the collector is a few dict
-    # updates per batch (profiler.record_phase) — unlike the Chrome
+    # updates per batch (profiler.phase) — unlike the Chrome
     # profiler it never synchronizes dispatch, so it is safe INSIDE the
     # timed window.  The first spans include compile; the column is a
     # diagnostic shape, not a second clock.

@@ -56,11 +56,21 @@ SPAN_ENTRY_POINTS = (
     ("mxnet_tpu/module/base_module.py", "BaseModule._fit_epochs"),
     ("mxnet_tpu/parallel/dp.py", "DataParallelTrainer.step"),
     ("mxnet_tpu/serving/decode_engine.py",
+     "GenerationEngine._admit_paged"),
+    ("mxnet_tpu/serving/decode_engine.py",
      "GenerationEngine._dispatch_decode"),
     ("mxnet_tpu/serving/decode_engine.py",
      "GenerationEngine._dispatch_decode_sample"),
     ("mxnet_tpu/serving/decode_engine.py",
      "GenerationEngine._dispatch_prefill"),
+    ("mxnet_tpu/serving/decode_engine.py",
+     "GenerationEngine._paged_decode_step"),
+    ("mxnet_tpu/serving/decode_engine.py",
+     "GenerationEngine._paged_dispatch"),
+    ("mxnet_tpu/serving/decode_engine.py",
+     "GenerationEngine._paged_prefill_chunk"),
+    ("mxnet_tpu/serving/decode_engine.py",
+     "GenerationEngine._paged_tick"),
     ("mxnet_tpu/serving/frontdoor.py", "_Handler._serve_generate"),
     ("mxnet_tpu/serving/frontdoor.py", "_Handler._serve_predict"),
     ("mxnet_tpu/serving/replica_set.py", "ReplicaSet._dispatch"),
@@ -71,7 +81,7 @@ SPAN_ENTRY_POINTS = (
 # Terminal callable names that count as "emits a span".
 SPAN_EMITTERS = frozenset([
     "record",          # Profiler.record / StepPhaseCollector.record
-    "record_phase",    # profiler.record_phase step-phase seam
+    "phase",           # profiler.phase, the one span seam
     "mark_step",
     "_recorder",       # CommPipeline's injected recorder callback
     "_prof_record",    # kvstore_dist module-level helper

@@ -616,7 +616,7 @@ register_env("MXNET_TRACE_JSONL", str, "",
              "either way).")
 register_env("MXNET_METRICS", bool, True,
              "Ambient metrics instrumentation (mxnet_tpu/metrics.py): "
-             "'0' silences the record_phase histogram feed and other "
+             "'0' silences the phase() histogram feed and other "
              "ambient observation seams.  Explicitly created "
              "instruments — the counters legacy stats() trees read "
              "through — keep counting either way.")

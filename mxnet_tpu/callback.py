@@ -130,7 +130,7 @@ class MetricsLogger:
 
     ``prefixes=None`` logs the fit-loop family (``fit_``,
     ``phase_seconds`` — step counts and the per-phase latency
-    histograms the step loop feeds through ``profiler.record_phase``);
+    histograms the step loop feeds through ``profiler.phase``);
     pass e.g. ``("kvstore_",)`` to watch the data plane, or ``()`` for
     everything."""
 

@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 
@@ -199,8 +198,8 @@ class ThreadedBatchPipeline:
         (it nests inside the fit loop's ``data_wait`` phase, so it is
         reported as overlapped, not additive)."""
         faultinject.hook("data.next", kind="batch")
-        t0 = time.perf_counter_ns()
-        item = self._queue.get()
+        with profiler.phase("data_next"):
+            item = self._queue.get()
         if isinstance(item, BaseException):
             raise MXNetError("data pipeline worker failed: %r" % (item,)) \
                 from item
@@ -208,10 +207,8 @@ class ThreadedBatchPipeline:
         if state is not None:
             self._frontier = state
         if batch is _EOF:
-            profiler.record_phase("data_next", t0)
             raise StopIteration
         self.batches_consumed += 1
-        profiler.record_phase("data_next", t0)
         return batch
 
     def reset(self):

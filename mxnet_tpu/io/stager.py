@@ -22,7 +22,7 @@ pre-stager behavior (values are unchanged either way — staging only moves
 WHERE the upload happens; tests/test_input_staging.py pins exactness).
 
 Profiler: each upload records an ``h2d_stage`` span (step-phase seam,
-``profiler.record_phase``); because it runs on the producer thread it
+``profiler.phase``); because it runs on the producer thread it
 OVERLAPS the consumer's ``compute`` span — seeing the two side by side in
 a Chrome trace is the visual evidence of the overlap.
 """
@@ -31,7 +31,6 @@ from __future__ import annotations
 import copy
 import queue
 import threading
-import time
 
 import jax
 
@@ -124,22 +123,21 @@ class DeviceStager:
         """Shallow-copy the batch with its data/label arrays placed on
         device; every other attribute (pad, index, bucket_key,
         provide_*) rides along untouched."""
-        t0 = time.perf_counter_ns()
-        staged = copy.copy(batch)
-        placed = []
-        if getattr(batch, "data", None):
-            staged.data = [self._place_one(a) for a in batch.data]
-            placed += staged.data
-        if getattr(batch, "label", None):
-            staged.label = [self._place_one(a) for a in batch.label]
-            placed += staged.label
-        if placed:
-            # wait for the transfers on THIS (producer) thread: the
-            # h2d_stage span then covers the upload, not just its
-            # enqueue, and the consumer receives resident buffers — the
-            # whole point of staging
-            jax.block_until_ready([a._data for a in placed])
-        _profiler.record_phase("h2d_stage", t0)
+        with _profiler.phase("h2d_stage"):
+            staged = copy.copy(batch)
+            placed = []
+            if getattr(batch, "data", None):
+                staged.data = [self._place_one(a) for a in batch.data]
+                placed += staged.data
+            if getattr(batch, "label", None):
+                staged.label = [self._place_one(a) for a in batch.label]
+                placed += staged.label
+            if placed:
+                # wait for the transfers on THIS (producer) thread: the
+                # h2d_stage span then covers the upload, not just its
+                # enqueue, and the consumer receives resident buffers —
+                # the whole point of staging
+                jax.block_until_ready([a._data for a in placed])
         return staged
 
     def _place_one(self, arr):

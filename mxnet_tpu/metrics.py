@@ -29,7 +29,7 @@ a plain dict (:func:`snapshot` — in-process consumers,
 ``callback.MetricsLogger``, ``tools/step_profile.py --metrics``).
 
 ``MXNET_METRICS=0`` turns the *ambient* instrumentation seams off (the
-``profiler.record_phase`` histogram feed checks :func:`phase_on`);
+``profiler.phase`` histogram feed checks :func:`phase_on`);
 explicitly created instruments keep working — a stats tree reading
 through its counters must never see them vanish.
 
@@ -537,7 +537,7 @@ def drop(labels):
 
 def phase_on():
     """Whether the ambient instrumentation seams (the
-    ``profiler.record_phase`` histogram feed) observe.  Explicit
+    ``profiler.phase`` histogram feed) observe.  Explicit
     instruments ignore this — ``MXNET_METRICS=0`` silences the ambient
     feeds, it does not break stats trees reading through counters."""
     return bool(get_env("MXNET_METRICS"))

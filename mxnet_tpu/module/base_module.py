@@ -248,27 +248,26 @@ class BaseModule:
             nbatch = 0
             data_iter = iter(train_data)
             while True:
-                # step-phase attribution (profiler.record_phase is a
-                # two-lookup no-op unless a collector/trace is on):
-                # data_wait = blocked on the iterator (the stager hides
-                # source latency here), compute = step dispatch,
-                # metric_fetch = metric update incl. any host fetch.
-                t_ns = time.perf_counter_ns()
-                try:
-                    data_batch = next(data_iter)
-                except StopIteration:
+                # step-phase attribution (profiler.phase): data_wait =
+                # blocked on the iterator (the stager hides source
+                # latency here), compute = step dispatch, metric_fetch =
+                # metric update incl. any host fetch, callback = the
+                # caller's batch-end code (one that fetches waits here,
+                # not in the next data_wait).
+                with profiler.phase("data_wait") as wait:
+                    data_batch = next(data_iter, None)
+                    if data_batch is None:
+                        wait.cancel()   # the epoch's end is no wait
+                if data_batch is None:
                     break
-                profiler.record_phase("data_wait", t_ns)
                 if monitor is not None:
                     monitor.tic()
-                t_ns = time.perf_counter_ns()
-                self.prepare(data_batch)
-                self.forward_backward(data_batch)
-                self.update()
-                profiler.record_phase("compute", t_ns)
-                t_ns = time.perf_counter_ns()
-                self.update_metric(eval_metric, data_batch.label)
-                profiler.record_phase("metric_fetch", t_ns)
+                with profiler.phase("compute"):
+                    self.prepare(data_batch)
+                    self.forward_backward(data_batch)
+                    self.update()
+                with profiler.phase("metric_fetch"):
+                    self.update_metric(eval_metric, data_batch.label)
                 profiler.mark_step()
                 if monitor is not None:
                     monitor.toc_print()
@@ -276,8 +275,9 @@ class BaseModule:
                     batch_end_params = _BatchEndParam(
                         epoch=epoch, nbatch=nbatch, eval_metric=eval_metric,
                         locals=locals())
-                    for callback in _as_list(batch_end_callback):
-                        callback(batch_end_params)
+                    with profiler.phase("callback"):
+                        for callback in _as_list(batch_end_callback):
+                            callback(batch_end_params)
                 nbatch += 1
 
             for name, val in eval_metric.get_name_value():

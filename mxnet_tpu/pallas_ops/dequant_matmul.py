@@ -189,6 +189,7 @@ def _dqmm_pallas(x, codes, scales, block_m, block_n, block_k, interpret):
         out_specs=_spec((bm, bn), lambda i, j, k: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="dequant_matmul",
         compiler_params=_SEQ_GRID)(x, codes, srow)
 
 

@@ -169,6 +169,7 @@ def _fa_forward(q, k, v, causal, scale, block_q, block_k, interpret):
         out_specs=_vmem_spec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         scratch_shapes=_softmax_scratch(block_q, D),
         interpret=interpret,
+        name="flash_attention_fwd",
         compiler_params=_SEQ_GRID)(qr, kr, vr)
     return out.reshape(B, H, Lq, D)
 
@@ -312,5 +313,6 @@ def flash_attention_offset(q, k, v, offsets, scale=None, block_q=128,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype),
         interpret=_resolve_interpret(interpret),
+        name="flash_attention_offset",
         compiler_params=_SEQ_GRID)(ofs, qr, kr, vr)
     return out.reshape(B, H, Lq, D)

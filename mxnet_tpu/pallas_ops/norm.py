@@ -87,6 +87,7 @@ def rms_norm(x, gamma, eps=1e-6, block_rows=8, interpret=None):
         in_specs=[_spec((br, w), lambda i: (i, 0)),
                   _spec((1, w), lambda i: (0, 0))],
         out_specs=_spec((br, w), lambda i: (i, 0)),
+        name="rms_norm_fwd",
         interpret=_resolve_interpret(interpret))(x, gamma.reshape(1, w))
 
 
@@ -109,6 +110,7 @@ def _rms_bwd(eps, block_rows, interpret, res, dy):
         out_specs=(_spec((br, w), lambda i: (i, 0)),
                    _spec((1, w), lambda i: (0, 0))),
         compiler_params=_ACCUMULATING,
+        name="rms_norm_bwd",
         interpret=_resolve_interpret(interpret))(
             x, gamma.reshape(1, w), dy)
     return dx, dg.reshape(w).astype(gamma.dtype)
@@ -160,6 +162,7 @@ def layer_norm(x, gamma, beta, eps=1e-5, block_rows=8, interpret=None):
                   _spec((1, w), lambda i: (0, 0)),
                   _spec((1, w), lambda i: (0, 0))],
         out_specs=_spec((br, w), lambda i: (i, 0)),
+        name="layer_norm_fwd",
         interpret=_resolve_interpret(interpret))(
             x, gamma.reshape(1, w), beta.reshape(1, w))
 
@@ -186,6 +189,7 @@ def _ln_bwd(eps, block_rows, interpret, res, dy):
                    _spec((1, w), lambda i: (0, 0)),
                    _spec((1, w), lambda i: (0, 0))),
         compiler_params=_ACCUMULATING,
+        name="layer_norm_bwd",
         interpret=_resolve_interpret(interpret))(
             x, gamma.reshape(1, w), dy)
     return (dx, dg.reshape(w).astype(gamma.dtype),

@@ -179,6 +179,7 @@ def flash_attention_paged(q, k_pool, v_pool, tables, positions,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Lq, D), q.dtype),
         interpret=_resolve_interpret(interpret),
+        name="paged_attention",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")))(*scalars, q, k_pool,

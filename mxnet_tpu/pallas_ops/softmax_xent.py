@@ -47,7 +47,10 @@ row_block = divisor_block
 
 
 def _grid_call(kernel, outs, grid, in_specs, out_specs, interpret, *args):
+    # one call site, five kernels: each by its function's name
+    name = getattr(kernel, "func", kernel).__name__
     return pl.pallas_call(kernel, out_shape=outs, grid=grid,
+                          name=name.strip("_").replace("_kernel", ""),
                           in_specs=in_specs, out_specs=out_specs,
                           interpret=_resolve_interpret(interpret))(*args)
 

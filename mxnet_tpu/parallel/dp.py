@@ -11,7 +11,6 @@ meet the sharded batch (the ``psum`` that subsumes kvstore push+pull).
 """
 from __future__ import annotations
 
-import time
 
 import jax
 import jax.numpy as jnp
@@ -350,18 +349,18 @@ class DataParallelTrainer:
             rng = self._carry_rng()
         lrs, wds = self._host_hyper()
         from .. import engine as _engine
-        t_ns = time.perf_counter_ns()
-        if self._reduce_mode == "bucket":
-            self.params, self.opt_state, self.aux, outs, rng_next = \
-                self._step_bucketed(batch, lrs, wds, rng)
-        else:
-            self.params, self.opt_state, self.aux, outs, rng_next = \
-                _engine.get().dispatch(
-                    "fused_train_step", self._train_step, self.params,
-                    self.opt_state, self.aux, batch, lrs, wds, rng)
         # spmd_step attributes the sharded-program dispatch inside the
         # fit loop's "compute" phase (nested span; excluded from pct)
-        profiler.record_phase("spmd_step", t_ns)
+        with profiler.phase("spmd_step"):
+            if self._reduce_mode == "bucket":
+                self.params, self.opt_state, self.aux, outs, rng_next = \
+                    self._step_bucketed(batch, lrs, wds, rng)
+            else:
+                self.params, self.opt_state, self.aux, outs, rng_next = \
+                    _engine.get().dispatch(
+                        "fused_train_step", self._train_step,
+                        self.params, self.opt_state, self.aux, batch,
+                        lrs, wds, rng)
         self._rng_dev = rng_next
         return outs
 

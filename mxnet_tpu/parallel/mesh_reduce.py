@@ -26,7 +26,6 @@ variant pays ~``max(delay)``.
 from __future__ import annotations
 
 import threading
-import time
 
 import jax
 
@@ -83,7 +82,7 @@ class MeshCollectiveLauncher(object):
         self.overlap = bool(get_env("MXNET_MESH_OVERLAP")) \
             if overlap is None else bool(overlap)
         self._pending = []
-        self._t0 = None
+        self._window = None     # the open comm_overlap span
 
     def submit(self, bucket_id, payload, reduce_fn):
         """Launch ``reduce_fn(bucket_id, payload)`` for one bucket; the
@@ -91,8 +90,11 @@ class MeshCollectiveLauncher(object):
         ``mesh.collective`` faultinject seam first (injected latency
         lands per-collective, inside the worker thread, so overlap
         genuinely hides it)."""
-        if self._t0 is None:
-            self._t0 = time.perf_counter_ns()
+        if self._window is None:
+            # submit -> drain is one span over several calls of one
+            # thread: opened here, closed in drain()
+            self._window = profiler.phase("comm_overlap")
+            self._window.__enter__()
         launch = _Launch(bucket_id)
 
         def run():
@@ -118,12 +120,12 @@ class MeshCollectiveLauncher(object):
         order (and records the whole submit→drain window as the
         ``comm_overlap`` phase).  Re-raises the first launch error."""
         launches, self._pending = self._pending, []
-        t0, self._t0 = self._t0, None
+        window, self._window = self._window, None
         for launch in launches:
             if launch.thread is not None:
                 launch.thread.join()
-        if t0 is not None:
-            profiler.record_phase("comm_overlap", t0)
+        if window is not None:
+            window.__exit__(None, None, None)
         for launch in launches:
             if launch.error is not None:
                 raise launch.error

@@ -16,6 +16,8 @@ import json
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 from . import engine as _engine
 from . import metrics as _metrics
 from . import tracing as _tracing
@@ -23,60 +25,34 @@ from .analysis.lockcheck import make_lock
 from .base import get_env
 
 __all__ = ["profiler_set_config", "profiler_set_state", "dump_profile",
-           "Profiler", "record_phase", "mark_step", "start_step_profile",
-           "stop_step_profile", "aggregate_phase_trace", "PHASES",
+           "Profiler", "phase", "phase_totals", "mark_step",
+           "start_step_profile", "stop_step_profile",
+           "aggregate_phase_trace", "PHASES",
            "SERVE_PHASES", "GEN_SERVE_PHASES", "FRONTDOOR_PHASES"]
 
 # The per-step wall-time attribution phases of one Module.fit batch
 # (tools/step_profile.py renders them; docs/perf.md explains the
-# methodology).  ``h2d_stage`` is recorded by the DeviceStager's
-# background thread, so it OVERLAPS compute rather than adding to the
-# step — the report calls that out.  ``spmd_step`` is the sharded
-# step-program dispatch (parallel/spmd.py) recorded INSIDE the fit
-# loop's ``compute`` phase: its span against compute shows how much of
-# compute is the one-program dispatch vs frontend packing/metric glue.
+# methodology).
 PHASES = ("data_wait", "data_next", "h2d_stage", "compute",
           "metric_fetch", "spmd_step", "comm_overlap")
 
 # Phases that overlap (h2d_stage: stager thread concurrent with
-# compute) or nest inside (spmd_step: within compute; data_next: the
-# pipeline consumer seam inside the fit loop's data_wait; comm_overlap:
-# the dist_mesh bucket-collective submit→drain window inside spmd_step
-# — parallel/mesh_reduce.py) another phase — reported, but excluded
-# from the step-percentage denominator so the breakdown still sums to
-# 100%.
+# compute) or nest inside (spmd_step: the sharded step-program dispatch
+# within compute; data_next: the pipeline consumer seam inside
+# data_wait; comm_overlap: the dist_mesh submit→drain window inside
+# spmd_step) another phase — reported, but excluded from the
+# step-percentage denominator so the breakdown still sums to 100%.
 _NON_ADDITIVE_PHASES = frozenset(["h2d_stage", "spmd_step", "data_next",
                                   "comm_overlap"])
 
-# The serving engine's scheduler-cycle phases (serving/scheduler.py):
-# ``serve_wait`` (engine blocked on the request queue), ``serve_batch``
-# (continuous-batch forming — the latency-budget window) and
-# ``serve_compute`` (bucketed program dispatch + future resolution).
-# They ride the same record_phase seam, so a Chrome trace shows the
-# batcher's duty cycle and the step collector can aggregate a serving
-# window exactly like a fit window.
+# The forward batcher's cycle (serving/scheduler.py) and the front
+# door's spans (frontdoor.py, replica_set.py).  What each span brackets,
+# the generation engine's spans (serve_tick and what nests in it) and
+# who reads which: docs/architecture/observability.md, the inventory.
 SERVE_PHASES = ("serve_wait", "serve_batch", "serve_compute")
-
-# The generation engine's decode-loop phases (serving/decode_engine.py):
-# ``serve_prefill`` (one bucketed prompt batch filling the KV cache +
-# first-token logits), ``serve_decode`` (one continuous-batched decode
-# step over the donated cache) and ``serve_sample`` (the per-step
-# token materialization: the (slots,) token fetch under in-graph
-# sampling — MXNET_SERVE_SAMPLE=graph — or the (slots, vocab) logits
-# fetch + host-side shared sampler under the =host hatch; the phase's
-# footprint is the acceptance pin's evidence).  Separate tuple: the
-# forward batcher emits every SERVE_PHASES entry each cycle (pinned),
-# the decode loop emits these.
-GEN_SERVE_PHASES = ("serve_prefill", "serve_decode", "serve_sample")
-
-# The serving front door's phases (serving/frontdoor.py,
-# serving/replica_set.py): ``serve_http`` brackets one HTTP request end
-# to end on its handler thread (parse -> submit -> wait -> encode), and
-# ``serve_dispatch`` brackets one replica-set placement (pick replica,
-# cross the serve.dispatch faultinject seam, hand to the replica's
-# engine).  The engine-side SERVE_PHASES nest inside serve_http's
-# window on other threads, so a Chrome trace shows HTTP/transport
-# overhead as the gap between serve_http and serve_compute.
+GEN_SERVE_PHASES = ("serve_tick", "serve_idle", "serve_admit",
+                    "serve_prefill", "serve_decode", "serve_sample",
+                    "serve_resolve", "cow_fork")
 FRONTDOOR_PHASES = ("serve_http", "serve_dispatch")
 
 
@@ -124,34 +100,49 @@ _state = {"profiler": None, "filename": "profile.json", "jax_logdir": None}
 
 
 # ---------------------------------------------------------------------------
-# Step-phase attribution.
-#
-# Two consumers share the ``record_phase`` seam:
-# * the Chrome-trace profiler above (spans land with cat="step_phase",
-#   so a full trace shows the phases against the op spans inside them);
-# * a lightweight ``StepPhaseCollector`` that only sums durations — it
-#   never blocks dispatch (unlike the engine-seam profiler, which
-#   synchronizes every dispatched program to time execution), so
-#   bench.py can keep it on DURING a timed window without perturbing
-#   the async pipeline.
+# Step-phase attribution: ``phase()`` is the one way the program records
+# a span.  While it is open it is a ``jax.profiler.TraceAnnotation``
+# (inside a profiler session the span lies in the ``.xplane.pb`` on the
+# working thread's line, on the device trace's clock; with no session
+# it is the profiler's own no-op); when it closes it goes to
+# * the process-wide, always-on totals behind ``phase_totals()``;
+# * a ``StepPhaseCollector`` installed for a window — it only sums and
+#   never blocks dispatch (the engine-seam profiler synchronizes every
+#   dispatched program), so it can stay on DURING a timed window;
+# * the Chrome-trace profiler above (cat="step_phase"), the registry's
+#   ``phase_seconds{phase}`` histogram, the traces activated on this
+#   thread and the flight ring.
 # ---------------------------------------------------------------------------
 class StepPhaseCollector:
-    """Accumulates per-phase wall time across fit steps."""
+    """Accumulates per-phase wall time, spans and the counts the spans
+    carried."""
 
     def __init__(self):
         self.totals = {}    # phase -> ns
-        self.counts = {}    # phase -> spans
+        self.spans = {}     # phase -> spans
+        self.counts = {}    # phase -> {count name: sum}
         self.steps = 0
         self._lock = make_lock("profiler.phase_collector")
 
-    def record(self, name, dur_ns):
+    def record(self, name, dur_ns, counts=None):
         with self._lock:
             self.totals[name] = self.totals.get(name, 0) + dur_ns
-            self.counts[name] = self.counts.get(name, 0) + 1
+            self.spans[name] = self.spans.get(name, 0) + 1
+            if counts:
+                sums = self.counts.setdefault(name, {})
+                for key, n in counts.items():
+                    sums[key] = sums.get(key, 0) + n
 
     def mark_step(self):
         with self._lock:
             self.steps += 1
+
+    def snapshot(self):
+        """``{phase: {"spans": n, "ns": total, "counts": {key: sum}}}``."""
+        with self._lock:
+            return {name: {"spans": self.spans[name], "ns": ns,
+                           "counts": dict(self.counts.get(name, ()))}
+                    for name, ns in self.totals.items()}
 
     def report(self):
         """Per-step phase breakdown: {phase: {total_ms, mean_ms,
@@ -162,7 +153,7 @@ class StepPhaseCollector:
         denominator)."""
         with self._lock:
             totals = dict(self.totals)
-            counts = dict(self.counts)
+            spans = dict(self.spans)
             steps = self.steps
         denom = sum(v for k, v in totals.items()
                     if k not in _NON_ADDITIVE_PHASES)
@@ -171,11 +162,11 @@ class StepPhaseCollector:
             t = totals[name]
             phases[name] = {
                 "total_ms": round(t / 1e6, 3),
-                "mean_ms": round(t / 1e6 / max(1, counts[name]), 3),
+                "mean_ms": round(t / 1e6 / max(1, spans[name]), 3),
                 "per_step_ms": round(t / 1e6 / max(1, steps), 3),
                 "pct": round(100.0 * t / denom, 1) if denom and
                 name not in _NON_ADDITIVE_PHASES else None,
-                "spans": counts[name],
+                "spans": spans[name],
             }
         return {"steps": steps, "phases": phases,
                 "overlapped": sorted(_NON_ADDITIVE_PHASES
@@ -183,6 +174,23 @@ class StepPhaseCollector:
 
 
 _phase_state = {"collector": None}
+_lifetime = StepPhaseCollector()    # never uninstalled
+
+
+def phase_totals(since=None):
+    """Every phase's spans, nanoseconds and summed counts since the
+    process started: ``{name: {"spans": n, "ns": total, "counts":
+    {key: sum}}}``.  Always on, and kept by the process, not by the
+    engine or module that did the work.  ``since`` is an earlier
+    reading: what a window gained is ``phase_totals(since=opened)``."""
+    now = _lifetime.snapshot()
+    for name, was in (since or {}).items():
+        got = now[name]
+        got["spans"] -= was["spans"]
+        got["ns"] -= was["ns"]
+        for key, n in was["counts"].items():
+            got["counts"][key] -= n
+    return now
 
 
 def start_step_profile():
@@ -210,30 +218,64 @@ def _phase_hist(name):
         labels={"phase": name})
 
 
-def record_phase(name, start_ns, end_ns=None):
-    """Report one step-phase span to whichever sinks are active: the
-    step collector, the Chrome-trace profiler, the metrics registry's
-    per-phase histogram (``phase_seconds{phase=...}``, unless
-    ``MXNET_METRICS=0``), any traces activated on this thread
-    (tracing.on_phase — the span becomes a child of each request's
-    trace) and the flight-recorder ring.  A no-op costing a few dict/
-    env lookups when everything is off — callers may invoke it
-    unconditionally from hot loops."""
+def _span_ended(name, start_ns, end_ns, counts):
+    """A closed span to the lifetime totals and to whichever other
+    sinks are active (the early-out keeps an unobserved span at a few
+    dict/env lookups: hot loops open spans unconditionally)."""
+    _lifetime.record(name, end_ns - start_ns, counts)
     col = _phase_state["collector"]
     prof = _state["profiler"]
     mets = _metrics.phase_on()
     if col is None and prof is None and not mets \
             and not _tracing.sinks_active():
         return
-    if end_ns is None:
-        end_ns = time.perf_counter_ns()
     if col is not None:
-        col.record(name, end_ns - start_ns)
+        col.record(name, end_ns - start_ns, counts)
     if prof is not None:
         prof.record(name, start_ns, end_ns, cat="step_phase")
     if mets:
         _phase_hist(name).observe((end_ns - start_ns) / 1e9)
     _tracing.on_phase(name, start_ns, end_ns)
+
+
+class phase:
+    """``with profiler.phase(name, **counts):`` — one span of the
+    program.  ``counts`` are what the span worked on (rows, tokens,
+    blocks) and are summed in the totals; those known only once the
+    work is done are added inside the block with :meth:`add` (they
+    reach the totals, not the trace annotation, which is written when
+    the span opens).  ``labels`` go to the annotation alone (an
+    ordinal: nothing a sum means anything of)."""
+
+    __slots__ = ("name", "counts", "_annotation", "_start_ns")
+
+    def __init__(self, name, labels=None, **counts):
+        self.name = name
+        self.counts = counts
+        self._annotation = TraceAnnotation(name, **(labels or {}), **counts)
+
+    def add(self, **counts):
+        for key, n in counts.items():
+            self.counts[key] = self.counts.get(key, 0) + n
+
+    def cancel(self):
+        """The block turned out not to be this span's work (the
+        iterator was at its end, the placement did not fail): its close
+        reports to no sink.  Inside a profiler session the annotation
+        has been written all the same."""
+        self._start_ns = None
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        end_ns = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
+        if self._start_ns is not None:
+            _span_ended(self.name, self._start_ns, end_ns, self.counts)
+        return False
 
 
 def mark_step():

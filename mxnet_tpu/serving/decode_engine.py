@@ -87,7 +87,7 @@ from ..analysis import racecheck
 from ..analysis.lockcheck import make_lock
 from ..base import MXNetError, _uid, get_env, hot_path
 from .scheduler import (FutureCompleter, ServeClosed, ServeOverloaded,
-                        ServeTimeout, TIERS)
+                        ServeTimeout, TIERS, _H_QWAIT)
 
 # Aggregate generation histograms (process-wide; gated on
 # MXNET_METRICS like every ambient observation seam).  TTFT and ITL
@@ -138,19 +138,27 @@ class GenerationResult:
     — ``'eos'`` or ``'length'``; ``token_times`` — host
     ``perf_counter()`` stamps taken as each token was sampled, so
     clients (and the loadgen) derive TTFT (``token_times[0] -
-    t_submit``) and inter-token latency without streaming machinery."""
+    t_submit``) and inter-token latency without streaming machinery;
+    ``t_admit`` — the same clock when the request got its decode slot
+    (None where the result was rebuilt from a reply that lacks it)."""
 
     __slots__ = ("model", "prompt_len", "tokens", "finish_reason",
-                 "t_submit", "token_times")
+                 "t_submit", "token_times", "t_admit")
 
     def __init__(self, model, prompt_len, tokens, finish_reason,
-                 t_submit, token_times):
+                 t_submit, token_times, t_admit=None):
         self.model = model
         self.prompt_len = prompt_len
         self.tokens = tokens
         self.finish_reason = finish_reason
         self.t_submit = t_submit
         self.token_times = token_times
+        self.t_admit = t_admit
+
+    @property
+    def queue_wait_s(self):
+        """Submit -> admission into a decode slot (seconds)."""
+        return self.t_admit - self.t_submit
 
     @property
     def ttft_s(self):
@@ -200,8 +208,8 @@ class TokenStream:
 class _GenRequest:
     __slots__ = ("model", "prompt", "max_tokens", "temperature", "top_k",
                  "seed", "eos_id", "stream", "future", "deadline",
-                 "t_submit", "tokens", "token_times", "seq", "priority",
-                 "tenant", "trace", "trace_parent")
+                 "t_submit", "t_admit", "tokens", "token_times", "seq",
+                 "priority", "tenant", "trace", "trace_parent")
 
     def __init__(self, model, prompt, max_tokens, temperature, top_k,
                  seed, eos_id, stream, future, deadline, t_submit, seq,
@@ -217,6 +225,7 @@ class _GenRequest:
         self.future = future
         self.deadline = deadline
         self.t_submit = t_submit
+        self.t_admit = None
         self.tokens = []
         self.token_times = []
         self.seq = seq
@@ -872,13 +881,18 @@ class GenerationEngine:
     def _serve_loop(self):
         try:
             stopping = False
+            ticks = 0
             while True:
                 stopping = self._pump(stopping) or stopping
                 if stopping and not self._drain_on_stop:
                     self._fail_all()
                     return
-                self._admit_ready()
-                self._decode_tick()
+                if self._has_work():    # else: a draining close's last
+                    ticks += 1
+                    with _profiler.phase("serve_tick",
+                                         labels={"tick": ticks}):
+                        self._admit_ready()
+                        self._decode_tick()
                 if stopping and not self._has_work():
                     return
         finally:
@@ -920,8 +934,12 @@ class GenerationEngine:
         block = not stopping and not self._has_work()
         while True:
             try:
-                item = self._queue.get() if block \
-                    else self._queue.get_nowait()
+                if block:
+                    # idle that is the traffic's, not the engine's
+                    with _profiler.phase("serve_idle"):
+                        item = self._queue.get()
+                else:
+                    item = self._queue.get_nowait()
             except queue.Empty:
                 break
             block = False
@@ -964,6 +982,15 @@ class GenerationEngine:
         if getattr(store, "paged", False):
             self._admit_paged(model, dq, store)
             return
+        with _profiler.phase("serve_admit") as span:
+            self._admit_contiguous(model, dq, store, span)
+
+    def _note_admitted(self, r):
+        r.t_admit = time.perf_counter()
+        if _metrics.phase_on():
+            _H_QWAIT.observe(r.t_admit - r.t_submit)
+
+    def _admit_contiguous(self, model, dq, store, span):
         st = self._states.get(model)
         cap = store.max_slots()
         if self._max_active is not None:
@@ -981,10 +1008,12 @@ class GenerationEngine:
                     kind="timeouts")
             elif r.future.set_running_or_notify_cancel():
                 group.append(r)
+                self._note_admitted(r)
             else:
                 self._stats.inc("cancelled")
         if not group:
             return
+        span.add(admitted=len(group))
         toks, lens = store.pad_prompts([r.prompt for r in group])
         try:
             # one prefill serves the whole admitted group: its span
@@ -1198,122 +1227,128 @@ class GenerationEngine:
         cap = store.max_slots()
         if self._max_active is not None:
             cap = min(cap, self._max_active)
-        admitted = 0
-        while dq:
-            now = time.monotonic()
-            r = dq[0]
-            if r.deadline is not None and now > r.deadline:
+        with _profiler.phase("serve_admit") as span:
+            admitted = 0
+            while dq:
+                now = time.monotonic()
+                r = dq[0]
+                if r.deadline is not None and now > r.deadline:
+                    dq.popleft()
+                    self._fail_request(r, ServeTimeout(
+                        "generation request for %r timed out after %.1f ms "
+                        "in queue" % (model, (now - r.t_submit) * 1e3)),
+                        kind="timeouts")
+                    continue
+                if len(st.active()) >= cap:
+                    break
+                total_blocks = -(-(len(r.prompt) + r.max_tokens) // bs)
+                blocks, tail = st.prefix.match(r.prompt)
+                # a partially-filled last prompt block gets pinned by the
+                # prefix cache at registration, so the first decode write
+                # into it MUST copy-on-write-fork — one allocation past
+                # total_blocks.  A tail HIT already counts its fork target
+                # in total_blocks (the borrowed block is free).
+                fork_extra = int(len(r.prompt) % bs != 0 and tail is None)
+                needed = total_blocks - len(blocks) + fork_extra
+                if total_blocks + fork_extra > st.pool.capacity():
+                    # can never fit, even against an empty pool: shed
+                    dq.popleft()
+                    self._stats.inc("shed_pool")
+                    self._stats.inc("shed")
+                    self._fail_request(r, ServeOverloaded(
+                        "request needs %d KV blocks, past the paged "
+                        "pool's %d usable blocks — shed"
+                        % (total_blocks + fork_extra, st.pool.capacity())))
+                    continue
+                budget = (st.pool.free_count() + st.prefix.evictable() -
+                          st.reserved_total())
+                if needed > budget:
+                    break   # wait for retirements; no overtaking
                 dq.popleft()
-                self._fail_request(r, ServeTimeout(
-                    "generation request for %r timed out after %.1f ms "
-                    "in queue" % (model, (now - r.t_submit) * 1e3)),
-                    kind="timeouts")
-                continue
-            if len(st.active()) >= cap:
-                break
-            total_blocks = -(-(len(r.prompt) + r.max_tokens) // bs)
-            blocks, tail = st.prefix.match(r.prompt)
-            # a partially-filled last prompt block gets pinned by the
-            # prefix cache at registration, so the first decode write
-            # into it MUST copy-on-write-fork — one allocation past
-            # total_blocks.  A tail HIT already counts its fork target
-            # in total_blocks (the borrowed block is free).
-            fork_extra = int(len(r.prompt) % bs != 0 and tail is None)
-            needed = total_blocks - len(blocks) + fork_extra
-            if total_blocks + fork_extra > st.pool.capacity():
-                # can never fit, even against an empty pool: shed
-                dq.popleft()
-                self._stats.inc("shed_pool")
-                self._stats.inc("shed")
-                self._fail_request(r, ServeOverloaded(
-                    "request needs %d KV blocks, past the paged "
-                    "pool's %d usable blocks — shed"
-                    % (total_blocks + fork_extra, st.pool.capacity())))
-                continue
-            budget = (st.pool.free_count() + st.prefix.evictable() -
-                      st.reserved_total())
-            if needed > budget:
-                break   # wait for retirements; no overtaking
-            dq.popleft()
-            if not r.future.set_running_or_notify_cancel():
-                self._stats.inc("cancelled")
-                continue
-            slot = st.free_slot()
-            if slot is None:
-                need = len(st.active()) + 1
-                self._grow_paged_slots(st, store,
-                                       store.batch_bucket(need))
+                if not r.future.set_running_or_notify_cancel():
+                    self._stats.inc("cancelled")
+                    continue
                 slot = st.free_slot()
-            row = st.tables[slot]
-            row[:] = 0
-            for j, b in enumerate(blocks):
-                row[j] = b
-                st.pool.ref(b)
-            covered = len(blocks) * bs
-            if tail is not None:
-                row[len(blocks)] = tail
-                st.pool.ref(tail)
-                covered = len(r.prompt)
-            if covered:
-                self._stats.inc("prefix_hits")
-                self._stats.inc("prefix_hit_blocks",
-                                len(blocks) + (tail is not None))
-                self._stats.inc("prefix_hit_tokens", covered)
-                _metrics.cached_counter(
-                    "serve_prefix_hit_total",
-                    help="admissions that reused shared paged-KV "
-                         "prefix blocks").inc()
-            # shared tokens skip recomputation, but the LAST prompt
-            # token always reruns: its logits seed the first sample
-            prog = min(covered, len(r.prompt) - 1)
-            st.prog[slot] = prog
-            st.lengths[slot] = prog
-            st.decoding[slot] = False
-            st.chunks_done[slot] = 0
-            st.slots[slot] = r
-            st.next_tok[slot] = 0
-            st.temps[slot] = r.temperature
-            st.top_ks[slot] = r.top_k
-            st.resv[slot] = needed
-            keys = np.array(st.keys, np.uint32)
-            if 0 <= r.seed < 2 ** 32:
-                # byte-identical to jax.random.PRNGKey(seed) for
-                # 32-bit seeds, without paying a threefry dispatch
-                # on the admission hot path
-                keys[slot] = (0, r.seed)
-            else:
-                keys[slot] = np.asarray(jax.random.PRNGKey(r.seed))
-            st.keys = jnp.asarray(keys)
-            if st.draft is not None:
-                # the draft's KV frontier starts at the shared-prefix
-                # coverage like the target's (its pool was mirrored
-                # when those blocks were first prefilled), and its
-                # PRNG chain is an independent fold of the request
-                # seed — target and draft draws never correlate.
-                # While the auto-mode fallback has the mirror off, the
-                # adopted blocks' draft rows are unwritten: claim NO
-                # coverage so a probe's catch-up rebuilds from the
-                # prompt instead of trusting garbage
-                st.dlen[slot] = prog if st.spec_mirror() else 0
-                # salted threefry key derived on HOST: the draft's
-                # constant hi word can never equal a target key's, so
-                # the chains stay decorrelated — the jax.random
-                # fold_in this replaces cost a threefry dispatch plus
-                # a device round-trip PER ADMISSION, charged even
-                # while the fallback regime never drafts at all
-                st.dkeys[slot] = (
-                    np.uint32(0x5bec5bec),
-                    np.uint32(r.seed & 0xffffffff)
-                    ^ np.uint32(0x9e3779b9))
-            self._admit_log.append((model, r.seq))
-            admitted += 1
-        if admitted:
-            self._stats.inc("prefill_seqs", admitted)
-            self._note_cache_hwm(model, st)
-            with self._stats_lock:
-                if len(st.active()) > self._max_active_seen:
-                    self._max_active_seen = len(st.active())
-        self._paged_gauges(st)
+                if slot is None:
+                    need = len(st.active()) + 1
+                    self._grow_paged_slots(st, store,
+                                           store.batch_bucket(need))
+                    slot = st.free_slot()
+                row = st.tables[slot]
+                row[:] = 0
+                for j, b in enumerate(blocks):
+                    row[j] = b
+                    st.pool.ref(b)
+                covered = len(blocks) * bs
+                if tail is not None:
+                    row[len(blocks)] = tail
+                    st.pool.ref(tail)
+                    covered = len(r.prompt)
+                if covered:
+                    self._stats.inc("prefix_hits")
+                    self._stats.inc("prefix_hit_blocks",
+                                    len(blocks) + (tail is not None))
+                    self._stats.inc("prefix_hit_tokens", covered)
+                    _metrics.cached_counter(
+                        "serve_prefix_hit_total",
+                        help="admissions that reused shared paged-KV "
+                             "prefix blocks").inc()
+                # shared tokens skip recomputation, but the LAST prompt
+                # token always reruns: its logits seed the first sample
+                prog = min(covered, len(r.prompt) - 1)
+                st.prog[slot] = prog
+                st.lengths[slot] = prog
+                st.decoding[slot] = False
+                st.chunks_done[slot] = 0
+                st.slots[slot] = r
+                st.next_tok[slot] = 0
+                st.temps[slot] = r.temperature
+                st.top_ks[slot] = r.top_k
+                st.resv[slot] = needed
+                keys = np.array(st.keys, np.uint32)
+                if 0 <= r.seed < 2 ** 32:
+                    # byte-identical to jax.random.PRNGKey(seed) for
+                    # 32-bit seeds, without paying a threefry dispatch
+                    # on the admission hot path
+                    keys[slot] = (0, r.seed)
+                else:
+                    keys[slot] = np.asarray(jax.random.PRNGKey(r.seed))
+                st.keys = jnp.asarray(keys)
+                if st.draft is not None:
+                    # the draft's KV frontier starts at the shared-prefix
+                    # coverage like the target's (its pool was mirrored
+                    # when those blocks were first prefilled), and its
+                    # PRNG chain is an independent fold of the request
+                    # seed — target and draft draws never correlate.
+                    # While the auto-mode fallback has the mirror off, the
+                    # adopted blocks' draft rows are unwritten: claim NO
+                    # coverage so a probe's catch-up rebuilds from the
+                    # prompt instead of trusting garbage
+                    st.dlen[slot] = prog if st.spec_mirror() else 0
+                    # salted threefry key derived on HOST: the draft's
+                    # constant hi word can never equal a target key's, so
+                    # the chains stay decorrelated — the jax.random
+                    # fold_in this replaces cost a threefry dispatch plus
+                    # a device round-trip PER ADMISSION, charged even
+                    # while the fallback regime never drafts at all
+                    st.dkeys[slot] = (
+                        np.uint32(0x5bec5bec),
+                        np.uint32(r.seed & 0xffffffff)
+                        ^ np.uint32(0x9e3779b9))
+                self._admit_log.append((model, r.seq))
+                self._note_admitted(r)
+                # blocks_alloc: what admission reserved of the pool (the
+                # blocks themselves are taken as rows are written)
+                span.add(admitted=1, prefix_hit_tokens=covered,
+                         blocks_alloc=needed)
+                admitted += 1
+            if admitted:
+                self._stats.inc("prefill_seqs", admitted)
+                self._note_cache_hwm(model, st)
+                with self._stats_lock:
+                    if len(st.active()) > self._max_active_seen:
+                        self._max_active_seen = len(st.active())
+            self._paged_gauges(st)
 
     def _grow_paged_slots(self, st, store, new_bb):
         grow = new_bb - len(st.slots)
@@ -1395,70 +1430,74 @@ class GenerationEngine:
                 st.resv[i] = max(0, int(st.resv[i]) - 1)
             elif st.pool.refcount(b) > 1:
                 nb = self._paged_alloc(st)
-                if st.scales is None:
-                    st.pool_k, st.pool_v = st.store.copy_block(
-                        st.pool_k, st.pool_v, b, nb)
-                else:
-                    # int8: codes and per-block scales fork together
-                    st.pool_k, st.pool_v, sk, sv = st.store.copy_block(
-                        st.pool_k, st.pool_v, b, nb, scales=st.scales)
-                    st.scales = (sk, sv)
-                if st.draft is not None:
-                    # the draft plane shares the block TABLES, so its
-                    # pool must fork the same physical block
-                    if st.dscales is None:
-                        st.dpool_k, st.dpool_v = st.draft.copy_block(
-                            st.dpool_k, st.dpool_v, b, nb)
-                    else:
-                        (st.dpool_k, st.dpool_v, dsk,
-                         dsv) = st.draft.copy_block(
-                            st.dpool_k, st.dpool_v, b, nb,
-                            scales=st.dscales)
-                        st.dscales = (dsk, dsv)
+                with _profiler.phase("cow_fork", blocks=1):
+                    self._paged_fork(st, b, nb)
                 st.pool.deref(b)
                 st.tables[i, j] = nb
                 st.resv[i] = max(0, int(st.resv[i]) - 1)
                 self._stats.inc("cow_forks")
 
-    def _paged_dispatch(self, st, tables, toks, pos, val, do, phase):
+    @staticmethod
+    def _paged_fork(st, b, nb):
+        """Duplicate physical block ``b`` into ``nb`` in every pool."""
+        if st.scales is None:
+            st.pool_k, st.pool_v = st.store.copy_block(
+                st.pool_k, st.pool_v, b, nb)
+        else:
+            # int8: codes and per-block scales fork together
+            st.pool_k, st.pool_v, sk, sv = st.store.copy_block(
+                st.pool_k, st.pool_v, b, nb, scales=st.scales)
+            st.scales = (sk, sv)
+        if st.draft is not None:
+            # the draft plane shares the block TABLES, so its pool must
+            # fork the same physical block
+            if st.dscales is None:
+                st.dpool_k, st.dpool_v = st.draft.copy_block(
+                    st.dpool_k, st.dpool_v, b, nb)
+            else:
+                st.dpool_k, st.dpool_v, dsk, dsv = st.draft.copy_block(
+                    st.dpool_k, st.dpool_v, b, nb, scales=st.dscales)
+                st.dscales = (dsk, dsv)
+
+    def _paged_dispatch(self, st, tables, toks, pos, val, do, phase,
+                        live):
         """One unified paged step (decode OR prompt chunk — ``phase``
         names it for the profiler/traces) + one sampled token per
         ``do`` row, host-side np result.  Same graph/host sampling
-        split as the contiguous plane's ``_decode_and_sample``."""
+        split as the contiguous plane's ``_decode_and_sample``.  The
+        span carries what attention has to read: ``rows`` (``live``,
+        the slots this dispatch works for) and ``kv_tokens``, the sum
+        of their frontiers after the step."""
+        work = {"rows": len(live),
+                "kv_tokens": int((pos[live] + val[live]).sum())}
         if st.store.sample_mode == "graph":
-            t0 = time.perf_counter_ns()
-            out = st.store.run_paged_step_sample(
+            with _profiler.phase(phase, **work):
+                out = st.store.run_paged_step_sample(
+                    st.pool_k, st.pool_v, tables, toks, pos, val,
+                    st.keys, st.temps, st.top_ks, do, scales=st.scales)
+                if st.scales is None:
+                    toks_dev, st.pool_k, st.pool_v, st.keys = out
+                else:
+                    toks_dev, st.pool_k, st.pool_v, sk, sv, st.keys = out
+                    st.scales = (sk, sv)
+            with _profiler.phase("serve_sample"):
+                return self._fetch_decode(toks_dev)
+        with _profiler.phase(phase, **work):
+            out = st.store.run_paged_step(
                 st.pool_k, st.pool_v, tables, toks, pos, val,
-                st.keys, st.temps, st.top_ks, do, scales=st.scales)
+                scales=st.scales)
             if st.scales is None:
-                toks_dev, st.pool_k, st.pool_v, st.keys = out
+                logits_dev, st.pool_k, st.pool_v = out
             else:
-                toks_dev, st.pool_k, st.pool_v, sk, sv, st.keys = out
+                logits_dev, st.pool_k, st.pool_v, sk, sv = out
                 st.scales = (sk, sv)
-            _profiler.record_phase(phase, t0)
-            t0 = time.perf_counter_ns()
-            sampled = self._fetch_decode(toks_dev)
-            _profiler.record_phase("serve_sample", t0)
-            return sampled
-        t0 = time.perf_counter_ns()
-        out = st.store.run_paged_step(
-            st.pool_k, st.pool_v, tables, toks, pos, val,
-            scales=st.scales)
-        if st.scales is None:
-            logits_dev, st.pool_k, st.pool_v = out
-        else:
-            logits_dev, st.pool_k, st.pool_v, sk, sv = out
-            st.scales = (sk, sv)
-        _profiler.record_phase(phase, t0)
-        t0 = time.perf_counter_ns()
-        logits = self._fetch_decode(logits_dev)
-        from .program_store import host_sample
-        toks_out, carry = host_sample(logits, st.keys, st.temps,
-                                      st.top_ks)
-        st.keys = jnp.where(jnp.asarray(do)[:, None], carry, st.keys)
-        sampled = np.asarray(toks_out)
-        _profiler.record_phase("serve_sample", t0)
-        return sampled
+        with _profiler.phase("serve_sample"):
+            logits = self._fetch_decode(logits_dev)
+            from .program_store import host_sample
+            toks_out, carry = host_sample(logits, st.keys, st.temps,
+                                          st.top_ks)
+            st.keys = jnp.where(jnp.asarray(do)[:, None], carry, st.keys)
+            return np.asarray(toks_out)
 
     def _paged_decode_step(self, model, st, dec):
         """Advance every generating slot one token (serve_decode
@@ -1483,8 +1522,8 @@ class GenerationEngine:
             with _tracing.activate_many(
                     [(st.slots[i].trace, st.slots[i].trace_parent)
                      for i in dec]):
-                sampled = self._paged_dispatch(st, tables, toks, pos,
-                                               val, do, "serve_decode")
+                sampled = self._paged_dispatch(
+                    st, tables, toks, pos, val, do, "serve_decode", dec)
         except BaseException as e:  # noqa: BLE001 — to the futures
             exc = e if isinstance(e, MXNetError) \
                 else MXNetError("decode dispatch failed: %r" % (e,))
@@ -1496,16 +1535,18 @@ class GenerationEngine:
                 self._release_paged_slot(st, i)
                 self._fail_request(r, exc, running=True)
             return
-        for i in dec:
-            r = st.slots[i]
-            st.lengths[i] += 1
-            tok = int(sampled[i])
-            self._push_token(r, tok)
-            st.next_tok[i] = tok
-            reason = self._finished_reason(r, tok)
-            if reason:
-                self._release_paged_slot(st, i)
-                self._finish(r, reason)
+        with _profiler.phase("serve_resolve", tokens=len(dec)) as span:
+            for i in dec:
+                r = st.slots[i]
+                st.lengths[i] += 1
+                tok = int(sampled[i])
+                self._push_token(r, tok)
+                st.next_tok[i] = tok
+                reason = self._finished_reason(r, tok)
+                if reason:
+                    self._release_paged_slot(st, i)
+                    self._finish(r, reason)
+                    span.add(finished=1)
         self._stats.inc("decode_steps")
         self._stats.inc("generated_tokens", len(dec))
 
@@ -1713,23 +1754,23 @@ class GenerationEngine:
                     pos[i] = st.lengths[i]
                     val[i] = win[i] + 1
                     do[i] = True
-                t0 = time.perf_counter_ns()
-                out = st.store.run_paged_verify(
-                    st.pool_k, st.pool_v, tables, vtoks, pos, val,
-                    prop_q, st.keys, st.temps, st.top_ks, do,
-                    scales=st.scales)
-                if st.scales is None:
-                    out_dev, ne_dev, st.pool_k, st.pool_v, \
-                        st.keys = out
-                else:
-                    (out_dev, ne_dev, st.pool_k, st.pool_v, sk, sv,
-                     st.keys) = out
-                    st.scales = (sk, sv)
-                _profiler.record_phase("serve_decode", t0)
-                t0 = time.perf_counter_ns()
-                out_toks = self._fetch_decode(out_dev)
-                n_emit = self._fetch_decode(ne_dev)
-                _profiler.record_phase("serve_sample", t0)
+                with _profiler.phase(
+                        "serve_decode", rows=len(dec),
+                        kv_tokens=int((pos[dec] + val[dec]).sum())):
+                    out = st.store.run_paged_verify(
+                        st.pool_k, st.pool_v, tables, vtoks, pos, val,
+                        prop_q, st.keys, st.temps, st.top_ks, do,
+                        scales=st.scales)
+                    if st.scales is None:
+                        out_dev, ne_dev, st.pool_k, st.pool_v, \
+                            st.keys = out
+                    else:
+                        (out_dev, ne_dev, st.pool_k, st.pool_v, sk, sv,
+                         st.keys) = out
+                        st.scales = (sk, sv)
+                with _profiler.phase("serve_sample"):
+                    out_toks = self._fetch_decode(out_dev)
+                    n_emit = self._fetch_decode(ne_dev)
         except BaseException as e:  # noqa: BLE001 — to the futures
             exc = e if isinstance(e, MXNetError) \
                 else MXNetError("speculative dispatch failed: %r"
@@ -1745,32 +1786,35 @@ class GenerationEngine:
         emitted = 0
         proposed = 0
         accepted = 0
-        for i in dec:
-            r = st.slots[i]
-            ne = int(n_emit[i])
-            proposed += win[i]
-            accepted += ne - 1
-            if _metrics.phase_on():
-                _H_SPEC.observe(ne)
-            for j in range(ne):
-                tok = int(out_toks[i, j])
-                self._push_token(r, tok)
-                st.lengths[i] += 1
-                emitted += 1
-                st.next_tok[i] = tok
-                reason = self._finished_reason(r, tok)
-                if reason:
-                    # mid-window EOS: the remaining accepted tokens
-                    # are discarded with the slot
-                    self._release_paged_slot(st, i)
-                    self._finish(r, reason)
-                    break
-            else:
-                # draft KV is valid only while its tokens match the
-                # accepted stream: clamp to the new frontier after a
-                # rejection (full accept leaves a 1-token catch-up gap
-                # for the bonus token)
-                st.dlen[i] = min(int(st.dlen[i]), int(st.lengths[i]))
+        with _profiler.phase("serve_resolve") as span:
+            for i in dec:
+                r = st.slots[i]
+                ne = int(n_emit[i])
+                proposed += win[i]
+                accepted += ne - 1
+                if _metrics.phase_on():
+                    _H_SPEC.observe(ne)
+                for j in range(ne):
+                    tok = int(out_toks[i, j])
+                    self._push_token(r, tok)
+                    st.lengths[i] += 1
+                    emitted += 1
+                    st.next_tok[i] = tok
+                    reason = self._finished_reason(r, tok)
+                    if reason:
+                        # mid-window EOS: the remaining accepted tokens
+                        # are discarded with the slot
+                        self._release_paged_slot(st, i)
+                        self._finish(r, reason)
+                        span.add(finished=1)
+                        break
+                else:
+                    # draft KV is valid only while its tokens match the
+                    # accepted stream: clamp to the new frontier after a
+                    # rejection (full accept leaves a 1-token catch-up gap
+                    # for the bonus token)
+                    st.dlen[i] = min(int(st.dlen[i]), int(st.lengths[i]))
+            span.add(tokens=emitted)
         self._stats.inc("decode_steps")
         self._stats.inc("spec_steps")
         self._stats.inc("spec_proposed", proposed)
@@ -1826,7 +1870,7 @@ class GenerationEngine:
                     [(r.trace, r.trace_parent)
                      for _i, r, _p, _n in rows]):
                 sampled = self._paged_dispatch(
-                    st, tables, toks, pos, val, do, "serve_prefill")
+                    st, tables, toks, pos, val, do, "serve_prefill", pre)
                 if st.draft is not None and st.spec_mirror():
                     # mirror the chunk into the draft's KV plane
                     # (logits unfetched, discarded): same tables, same
@@ -1857,26 +1901,29 @@ class GenerationEngine:
             return
         self._stats.inc("prefills")
         self._stats.inc("prefill_chunks", len(rows))
-        for i, r, p0, ntok in rows:
-            st.prog[i] = p0 + ntok
-            st.lengths[i] = p0 + ntok
-            if st.draft is not None and st.spec_mirror():
-                st.dlen[i] = p0 + ntok
-            st.chunks_done[i] += 1
-            if p0 + ntok < len(r.prompt):
-                continue
-            if _metrics.phase_on():
-                _H_CHUNKS.observe(int(st.chunks_done[i]))
-            st.prefix.register(r.prompt, st.tables[i])
-            tok = int(sampled[i])
-            self._push_token(r, tok)
-            reason = self._finished_reason(r, tok)
-            if reason:
-                self._release_paged_slot(st, i)
-                self._finish(r, reason)
-            else:
-                st.decoding[i] = True
-                st.next_tok[i] = tok
+        with _profiler.phase("serve_resolve") as span:
+            for i, r, p0, ntok in rows:
+                st.prog[i] = p0 + ntok
+                st.lengths[i] = p0 + ntok
+                if st.draft is not None and st.spec_mirror():
+                    st.dlen[i] = p0 + ntok
+                st.chunks_done[i] += 1
+                if p0 + ntok < len(r.prompt):
+                    continue
+                if _metrics.phase_on():
+                    _H_CHUNKS.observe(int(st.chunks_done[i]))
+                st.prefix.register(r.prompt, st.tables[i])
+                tok = int(sampled[i])
+                self._push_token(r, tok)
+                span.add(tokens=1)
+                reason = self._finished_reason(r, tok)
+                if reason:
+                    self._release_paged_slot(st, i)
+                    self._finish(r, reason)
+                    span.add(finished=1)
+                else:
+                    st.decoding[i] = True
+                    st.next_tok[i] = tok
         self._note_cache_hwm(model, st)
 
     # -- decode --------------------------------------------------------
@@ -1916,20 +1963,22 @@ class GenerationEngine:
                     st.slots[i] = None
                     self._fail_request(r, exc, running=True)
                 continue
-            for i in act:
-                r = st.slots[i]
-                st.lengths[i] += 1
-                tok = int(sampled[i])
-                self._push_token(r, tok)
-                st.next_tok[i] = tok
-                reason = self._finished_reason(r, tok)
-                if reason:
-                    st.slots[i] = None
-                    st.lengths[i] = 0
-                    st.next_tok[i] = 0
-                    st.temps[i] = 0.0
-                    st.top_ks[i] = 0
-                    self._finish(r, reason)
+            with _profiler.phase("serve_resolve", tokens=len(act)) as span:
+                for i in act:
+                    r = st.slots[i]
+                    st.lengths[i] += 1
+                    tok = int(sampled[i])
+                    self._push_token(r, tok)
+                    st.next_tok[i] = tok
+                    reason = self._finished_reason(r, tok)
+                    if reason:
+                        st.slots[i] = None
+                        st.lengths[i] = 0
+                        st.next_tok[i] = 0
+                        st.temps[i] = 0.0
+                        st.top_ks[i] = 0
+                        self._finish(r, reason)
+                        span.add(finished=1)
             self._stats.inc("decode_steps")
             self._stats.inc("generated_tokens", len(act))
 
@@ -1945,19 +1994,15 @@ class GenerationEngine:
         ``serve_sample`` phase and counted in ``decode_fetch_elems``."""
         if st.store.sample_mode == "graph":
             toks_dev = self._dispatch_decode_sample(st, toks, lens)
-            t0 = time.perf_counter_ns()
-            sampled = self._fetch_decode(toks_dev)
-            _profiler.record_phase("serve_sample", t0)
-            return sampled
+            with _profiler.phase("serve_sample"):
+                return self._fetch_decode(toks_dev)
         logits_dev = self._dispatch_decode(st, toks, lens)
-        t0 = time.perf_counter_ns()
-        logits = self._fetch_decode(logits_dev)
-        from .program_store import host_sample
-        toks_out, st.keys = host_sample(logits, st.keys, st.temps,
-                                        st.top_ks)
-        sampled = np.asarray(toks_out)
-        _profiler.record_phase("serve_sample", t0)
-        return sampled
+        with _profiler.phase("serve_sample"):
+            logits = self._fetch_decode(logits_dev)
+            from .program_store import host_sample
+            toks_out, st.keys = host_sample(logits, st.keys, st.temps,
+                                            st.top_ks)
+            return np.asarray(toks_out)
 
     def _fetch_decode(self, arr):
         """THE host fetch of the decode loop — one np conversion whose
@@ -1972,10 +2017,8 @@ class GenerationEngine:
     def _dispatch_prefill(self, store, tokens, lengths):
         """Enqueue-only prompt-batch dispatch (serve_prefill phase);
         the logits fetch happens on the caller side."""
-        t0 = time.perf_counter_ns()
-        out = store.run_prefill(tokens, lengths)
-        _profiler.record_phase("serve_prefill", t0)
-        return out
+        with _profiler.phase("serve_prefill"):
+            return store.run_prefill(tokens, lengths)
 
     @hot_path
     def _dispatch_decode(self, st, tokens, lengths):
@@ -1983,10 +2026,9 @@ class GenerationEngine:
         the MXNET_SERVE_SAMPLE=host hatch).  The donated caches are
         rebound to the program's outputs before anything can read the
         consumed buffers."""
-        t0 = time.perf_counter_ns()
-        logits, st.cache_k, st.cache_v = st.store.run_decode(
-            st.cache_k, st.cache_v, tokens, lengths)
-        _profiler.record_phase("serve_decode", t0)
+        with _profiler.phase("serve_decode"):
+            logits, st.cache_k, st.cache_v = st.store.run_decode(
+                st.cache_k, st.cache_v, tokens, lengths)
         return logits
 
     @hot_path
@@ -1994,12 +2036,11 @@ class GenerationEngine:
         """Enqueue-only sampling decode dispatch (serve_decode phase):
         tokens come out sampled in-graph; the donated caches AND the
         per-slot PRNG key state are rebound to the program's outputs."""
-        t0 = time.perf_counter_ns()
-        toks, st.cache_k, st.cache_v, st.keys = \
-            st.store.run_decode_sample(st.cache_k, st.cache_v, tokens,
-                                       lengths, st.keys, st.temps,
-                                       st.top_ks)
-        _profiler.record_phase("serve_decode", t0)
+        with _profiler.phase("serve_decode"):
+            toks, st.cache_k, st.cache_v, st.keys = \
+                st.store.run_decode_sample(st.cache_k, st.cache_v, tokens,
+                                           lengths, st.keys, st.temps,
+                                           st.top_ks)
         return toks
 
     # -- retirement ----------------------------------------------------
@@ -2028,7 +2069,7 @@ class GenerationEngine:
             req.stream.close()
         res = GenerationResult(req.model, len(req.prompt),
                                list(req.tokens), reason, req.t_submit,
-                               list(req.token_times))
+                               list(req.token_times), req.t_admit)
         self._completer.resolve(req.future, res)
         self._stats.inc("finished")
 

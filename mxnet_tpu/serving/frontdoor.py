@@ -19,7 +19,8 @@ choice) so real traffic can reach it:
 * ``GET /healthz`` — liveness of the target (a balancer's probe
   surface: 200 while something can serve, 503 after).
 * ``GET /stats`` — the target's ``stats()`` dict (scheduler counters,
-  program-store compile stats, weight versions, replica/breaker state).
+  program-store compile stats, weight versions, replica/breaker state)
+  and, under ``phases``, the process's span totals.
 
 **Deadline propagation**: ``timeout_ms`` (JSON body) or the
 ``X-Mxnet-Timeout-Ms`` header rides into the engine's queue-time
@@ -251,10 +252,9 @@ class _Handler(BaseHTTPRequestHandler):
 
     def _serve_predict(self, model):
         """One forward request end to end: parse (JSON or npz), submit
-        with the propagated deadline, wait, encode.  The whole span is
+        with the propagated deadline, wait, encode.  Submit to reply is
         the ``serve_http`` profiler phase — HTTP overhead is the gap
         between it and the engine's serve_* phases."""
-        t0 = time.perf_counter_ns()
         ctype = (self.headers.get("Content-Type") or "").split(";")[0]
         body = self._read_body()
         npz = ctype == "application/x-npz"
@@ -280,7 +280,7 @@ class _Handler(BaseHTTPRequestHandler):
         tr = _tracing.start_trace("http.predict", model=model)
         status = "error"
         try:
-            with _tracing.activate(tr):
+            with _tracing.activate(tr), _profiler.phase("serve_http"):
                 try:
                     fut = self._door.target.submit(model, timeout=timeout,
                                                    priority=priority,
@@ -305,13 +305,11 @@ class _Handler(BaseHTTPRequestHandler):
                         "shapes": [list(o.shape) for o in outs],
                         "dtypes": [str(o.dtype) for o in outs],
                     })
-                _profiler.record_phase("serve_http", t0)
                 status = "ok"
         finally:
             tr.finish(status=status)
 
     def _serve_generate(self, model):
-        t0 = time.perf_counter_ns()
         try:
             payload = json.loads(self._read_body().decode("utf-8"))
             timeout = self._timeout_s(payload)
@@ -335,7 +333,7 @@ class _Handler(BaseHTTPRequestHandler):
         tr = _tracing.start_trace("http.generate", model=model)
         status = "error"
         try:
-            with _tracing.activate(tr):
+            with _tracing.activate(tr), _profiler.phase("serve_http"):
                 try:
                     fut = self._door.gen_submit(model, tokens,
                                                 timeout=timeout, **kwargs)
@@ -357,7 +355,6 @@ class _Handler(BaseHTTPRequestHandler):
                     "t_submit": res.t_submit,
                     "token_times": list(res.token_times),
                 })
-                _profiler.record_phase("serve_http", t0)
                 status = "ok"
         finally:
             tr.finish(status=status)
@@ -505,7 +502,12 @@ class HttpFrontDoor:
         with self._stats_lock:
             if self._stats_cache is None \
                     or now - self._stats_cache_t > self._stats_ttl():
-                self._stats_cache = self.target.stats()
+                # "phases": every span the process closed, by name
+                # (the engine's ticks, admissions and fetches with
+                # their counts; profiler.phase_totals)
+                self._stats_cache = dict(
+                    self.target.stats(),
+                    phases=_profiler.phase_totals())
                 self._stats_cache_t = now
             out = dict(self._stats_cache)
             out["age_ms"] = round((now - self._stats_cache_t) * 1e3, 3)

@@ -552,72 +552,78 @@ class ReplicaSet:
         the request resolves with the structured last error.  Runs on
         the submitting thread or a retry timer thread — never on an
         engine thread."""
-        with _tracing.activate(state["trace"], state["trace_parent"]):
+        with _tracing.activate(state["trace"], state["trace_parent"]), \
+                _profiler.phase("serve_dispatch"):
             self._dispatch_traced(state)
 
     def _dispatch_traced(self, state):
-        t0 = time.perf_counter_ns()
         while True:
-            t_att = time.perf_counter_ns()
-            if state["deadline"] is not None \
-                    and time.monotonic() > state["deadline"]:
-                self._resolve(state["future"], exc=ServeTimeout(
-                    "request deadline expired during replica failover "
-                    "(last error: %r)" % (state["last_exc"],)))
-                return
-            r = self._pick(state["excluded"])
-            if r is None:
-                self._resolve_no_replica(state)
-                return
-            try:
-                faultinject.hook(SEAM, kind="forward", sid=r.index,
-                                 model=state["model"])
-                if not r.alive:
-                    raise ReplicaDied("replica %d is dead" % r.index)
-                remaining = None
-                if state["deadline"] is not None:
-                    remaining = max(0.0,
-                                    state["deadline"] - time.monotonic())
-                inner = r.engine.submit(state["model"], timeout=remaining,
-                                        priority=state["priority"],
-                                        tenant=state["tenant"],
-                                        **state["inputs"])
-            except ServeOverloaded as e:
-                # this replica is at budget — others may have room.
-                # The structured shed proves the engine is ALIVE, so
-                # report success to the breaker (a consumed half-open
-                # trial slot must be released or the replica wedges
-                # out of rotation when the prober is disabled)
-                r.breaker.record_success()
-                state["excluded"].add(r.index)
-                state["last_exc"] = e
-                continue
-            except (ReplicaDied, ServeClosed, OSError) as e:
-                r.breaker.record_failure(e)
-                self._note_breaker(r)
-                state["excluded"].add(r.index)
-                state["last_exc"] = e
-                # the failed attempt leaves a span in the request's
-                # trace (we are inside its activation): a retried
-                # request's trace shows every placement it tried
-                _profiler.record_phase("serve_retry", t_att)
-                if not self._schedule_retry(state):
+            # a FAILED attempt leaves a span in the request's trace (we
+            # are inside its activation): a retried request's trace
+            # shows every placement it tried and how long each took.
+            # Every other way out of the attempt cancels the span.
+            with _profiler.phase("serve_retry") as attempt:
+                if state["deadline"] is not None \
+                        and time.monotonic() > state["deadline"]:
+                    attempt.cancel()
+                    self._resolve(state["future"], exc=ServeTimeout(
+                        "request deadline expired during replica failover "
+                        "(last error: %r)" % (state["last_exc"],)))
                     return
-                continue
-            except MXNetError as e:
-                # validation/config errors are not retryable, and this
-                # may run on a retry-timer thread — resolve, never
-                # raise.  The replica answered: healthy for the breaker
-                r.breaker.record_success()
-                self._resolve(state["future"], exc=e)
+                r = self._pick(state["excluded"])
+                if r is None:
+                    attempt.cancel()
+                    self._resolve_no_replica(state)
+                    return
+                try:
+                    faultinject.hook(SEAM, kind="forward", sid=r.index,
+                                     model=state["model"])
+                    if not r.alive:
+                        raise ReplicaDied("replica %d is dead" % r.index)
+                    remaining = None
+                    if state["deadline"] is not None:
+                        remaining = max(0.0,
+                                        state["deadline"] - time.monotonic())
+                    inner = r.engine.submit(state["model"], timeout=remaining,
+                                            priority=state["priority"],
+                                            tenant=state["tenant"],
+                                            **state["inputs"])
+                except ServeOverloaded as e:
+                    # this replica is at budget — others may have room.
+                    # The structured shed proves the engine is ALIVE, so
+                    # report success to the breaker (a consumed half-open
+                    # trial slot must be released or the replica wedges
+                    # out of rotation when the prober is disabled)
+                    attempt.cancel()
+                    r.breaker.record_success()
+                    state["excluded"].add(r.index)
+                    state["last_exc"] = e
+                    continue
+                except (ReplicaDied, ServeClosed, OSError) as e:
+                    r.breaker.record_failure(e)
+                    self._note_breaker(r)
+                    state["excluded"].add(r.index)
+                    state["last_exc"] = e
+                except MXNetError as e:
+                    # validation/config errors are not retryable, and this
+                    # may run on a retry-timer thread — resolve, never
+                    # raise.  The replica answered: healthy for the breaker
+                    attempt.cancel()
+                    r.breaker.record_success()
+                    self._resolve(state["future"], exc=e)
+                    return
+                else:
+                    attempt.cancel()
+                    with self._lock:
+                        r.inflight += 1
+                    self._stats.inc("dispatched")
+                    inner.add_done_callback(
+                        lambda f, s=state, rep=r: self._inner_done(s, rep, f))
+                    return
+            # the placement failed; its span is closed before a
+            # resolution can finish the request's trace
+            if not self._schedule_retry(state):
                 return
-            with self._lock:
-                r.inflight += 1
-            self._stats.inc("dispatched")
-            inner.add_done_callback(
-                lambda f, s=state, rep=r: self._inner_done(s, rep, f))
-            _profiler.record_phase("serve_dispatch", t0)
-            return
 
     def _schedule_retry(self, state):
         """Count one failover attempt; False = budget exhausted and the
@@ -707,12 +713,12 @@ class ReplicaSet:
             ctx = (owned, owned.root_id)
         if owned is not None:
             fut.add_done_callback(_tracing.finish_on_done(owned))
-        with _tracing.activate(ctx[0], ctx[1]):
+        with _tracing.activate(ctx[0], ctx[1]), \
+                _profiler.phase("serve_dispatch"):
             return self._submit_gen_traced(model, tokens, fut, state,
                                            **kwargs)
 
     def _submit_gen_traced(self, model, tokens, fut, state, **kwargs):
-        t0 = time.perf_counter_ns()
         while True:
             r = self._pick(state["excluded"])
             if r is None:
@@ -756,7 +762,6 @@ class ReplicaSet:
             self._stats.inc("dispatched")
             inner.add_done_callback(
                 lambda f, rep=r: self._gen_done(fut, rep, f))
-            _profiler.record_phase("serve_dispatch", t0)
             return fut
 
     def _gen_done(self, fut, r, inner):

@@ -25,7 +25,7 @@ later submits raise :class:`ServeClosed`.
 Profiler: each cycle emits ``serve_wait`` (blocked on the queue),
 ``serve_batch`` (batch forming, the latency-budget wait) and
 ``serve_compute`` (dispatch + future resolution) spans through the
-step-phase seam (``profiler.record_phase``), so a Chrome trace shows the
+step-phase seam (``profiler.phase``), so a Chrome trace shows the
 batcher's duty cycle against the op spans inside it.
 """
 from __future__ import annotations
@@ -510,9 +510,8 @@ class ServingEngine:
         """One scheduler cycle: wait for a head request, form the batch
         within the head's latency budget, dispatch it.  Returns False
         when the engine should exit (after draining)."""
-        t0 = time.perf_counter_ns()
-        head = self._take()
-        _profiler.record_phase("serve_wait", t0)
+        with _profiler.phase("serve_wait"):
+            head = self._take()
         if head is _STOP:
             self._shutdown()
             return False
@@ -529,10 +528,9 @@ class ServingEngine:
                 "serving engine closed before dispatch"))
             self._inflight_reqs = ()
             return True
-        t1 = time.perf_counter_ns()
-        reqs, rows, stop = self._collect(head)
-        self._inflight_reqs = tuple(reqs)
-        _profiler.record_phase("serve_batch", t1)
+        with _profiler.phase("serve_batch"):
+            reqs, rows, stop = self._collect(head)
+            self._inflight_reqs = tuple(reqs)
         if self._failfast():
             # close(drain=False) landed while the batch was forming:
             # fail-fast semantics apply to the whole collected batch,
@@ -649,7 +647,6 @@ class ServingEngine:
         host sync on this thread)."""
         if not reqs:
             return
-        t2 = time.perf_counter_ns()
         now = time.monotonic()
         mets = _metrics.phase_on()
         live = []
@@ -667,51 +664,51 @@ class ServingEngine:
                 self._stats.inc("cancelled")
         if not live:
             return
-        if self._dispatch_hook is not None:
-            self._dispatch_hook(model, live)
         rows = sum(r.n for r in live)
-        if len(live) == 1:
-            inputs = live[0].inputs
-        else:
-            names = live[0].inputs.keys()
-            inputs = {k: np.concatenate([r.inputs[k] for r in live])
-                      for k in names}
         # the batch's compute span belongs to EVERY member's trace:
         # activate them all, so serve_compute lands in each as a child
         # of that request's ingress span
         with _tracing.activate_many(
                 [(r.trace, r.trace_parent) for r in live]):
-            try:
-                store = self._registry.store(model)
-                outs, bucket, batch_major = store.run(inputs, n=rows,
-                                                      slice_outputs=False)
-            except BaseException as e:  # noqa: BLE001 — to the futures
-                exc = e if isinstance(e, MXNetError) \
-                    else MXNetError("serving dispatch failed: %r" % (e,))
-                _tracing.flight().record(
-                    "error", "serve_dispatch_failed", model=model,
-                    error=repr(e), requests=len(live))
-                for r in live:
-                    self._resolve(r.future, exc=exc)
-                self._stats.inc("errors", len(live))
-                return
-            # outs are bucket-shaped (pad rows still on); every request
-            # gets its rows via the shared traced-offset slicer, so no
-            # per-batch or per-offset slice program ever compiles here
-            ofs = 0
-            sliced = []
-            for r in live:
-                res = []
-                for o, bm in zip(outs, batch_major):
-                    if bm and r.n != bucket:
-                        o = _row_slice(o, ofs, r.n)
-                    res.append(o)
-                sliced.append(res)
-                ofs += r.n
-            # phase recorded BEFORE the resolutions enqueue: a resolved
+            # the span closes BEFORE the resolutions enqueue: a resolved
             # future finishes its minter's trace, and a span landing
             # after finish would be dropped from the export
-            _profiler.record_phase("serve_compute", t2)
+            with _profiler.phase("serve_compute"):
+                if self._dispatch_hook is not None:
+                    self._dispatch_hook(model, live)
+                if len(live) == 1:
+                    inputs = live[0].inputs
+                else:
+                    names = live[0].inputs.keys()
+                    inputs = {k: np.concatenate([r.inputs[k] for r in live])
+                              for k in names}
+                try:
+                    store = self._registry.store(model)
+                    outs, bucket, batch_major = store.run(
+                        inputs, n=rows, slice_outputs=False)
+                except BaseException as e:  # noqa: BLE001 — to the futures
+                    exc = e if isinstance(e, MXNetError) \
+                        else MXNetError("serving dispatch failed: %r" % (e,))
+                    _tracing.flight().record(
+                        "error", "serve_dispatch_failed", model=model,
+                        error=repr(e), requests=len(live))
+                    for r in live:
+                        self._resolve(r.future, exc=exc)
+                    self._stats.inc("errors", len(live))
+                    return
+                # outs are bucket-shaped (pad rows still on); every request
+                # gets its rows via the shared traced-offset slicer, so no
+                # per-batch or per-offset slice program ever compiles here
+                ofs = 0
+                sliced = []
+                for r in live:
+                    res = []
+                    for o, bm in zip(outs, batch_major):
+                        if bm and r.n != bucket:
+                            o = _row_slice(o, ofs, r.n)
+                        res.append(o)
+                    sliced.append(res)
+                    ofs += r.n
             for r, res in zip(live, sliced):
                 self._resolve(r.future, res)
         if mets:

@@ -8,7 +8,7 @@ dispatch, scheduler queue, engine admit, prefill/decode steps,
 completer resolution), and whichever thread is currently working on
 the request *activates* it (:func:`activate` / :func:`activate_many`
 for a batch).  The existing step-phase seam
-(``profiler.record_phase``) forwards every span to :func:`on_phase`,
+(``profiler.phase``) forwards every span to :func:`on_phase`,
 so the ``serve_http`` / ``serve_dispatch`` / ``serve_batch`` /
 ``serve_compute`` / ``serve_prefill`` / ``serve_decode`` /
 ``serve_sample`` phases become *children of one trace* instead of
@@ -258,12 +258,12 @@ def has_context():
 def sinks_active():
     """Whether :func:`on_phase` would do anything on this thread (an
     activated trace, or the flight ring listening) — the
-    ``record_phase`` early-out check."""
+    ``phase`` early-out check."""
     return has_context() or _flight_or_none() is not None
 
 
 def on_phase(name, t0_ns, t1_ns):
-    """The ``profiler.record_phase`` fan-out: attach the span to every
+    """The ``profiler.phase`` fan-out: attach the span to every
     trace in the current activation frame, and append it to the flight
     ring.  Cheap when idle (one tls read + one capacity check)."""
     fr = getattr(_tls, "frames", None)

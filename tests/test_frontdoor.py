@@ -387,11 +387,18 @@ def test_injected_die_kills_replica_not_process(fresh_faults):
     with ReplicaSet(lambda i: _registry(), n_replicas=2,
                     probe_interval=0, max_delay_ms=0) as rset:
         x = np.zeros((1, FEAT), "float32")
+        before = mx.profiler.phase_totals()
         out = rset.submit("m", data=x).result(30)
         assert out is not None
         assert len(rset.live_replicas()) == 1
         st = rset.stats()
         assert st["retries"] >= 1
+        # serve_retry is the FAILED placement, pick to exception, and
+        # nothing else: the attempt that placed the request cancels it
+        spans = mx.profiler.phase_totals(since=before)
+        assert spans["serve_retry"]["spans"] == st["retries"]
+        assert spans["serve_dispatch"]["spans"] == 1
+        assert 0 < spans["serve_retry"]["ns"] < spans["serve_dispatch"]["ns"]
         # the dead replica's engine is really gone
         dead = [r for r in rset.replicas() if not r.alive][0]
         with pytest.raises(ServeClosed):

@@ -466,7 +466,8 @@ def test_span_direct_and_one_hop_ok_missing_flagged():
                 self._emit("op")
                 return out
             def _emit(self, name):
-                record_phase(name, 0)
+                with phase(name):
+                    pass
     """, span_entry_points=manifest)
     assert rules(vs) == ["span-coverage"]
     assert "silent" in vs[0].msg
@@ -475,7 +476,8 @@ def test_span_direct_and_one_hop_ok_missing_flagged():
 def test_span_manifest_rot_flagged():
     vs = run_lint("""
         def present():
-            record_phase("x", 0)
+            with phase("x"):
+                pass
     """, span_entry_points=(("pkg/fixture.py", "absent"),))
     assert rules(vs) == ["span-coverage"]
     assert "absent" in vs[0].msg and "manifest" in vs[0].msg

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 import mxnet_tpu as mx  # noqa: F401 — package import wires the planes
-from mxnet_tpu import faultinject, metrics, tracing
+from mxnet_tpu import faultinject, metrics, profiler, tracing
 from mxnet_tpu.serving import (GenerationEngine, HttpClient,
                                HttpFrontDoor, ModelRegistry, ReplicaSet,
                                ServingEngine)
@@ -398,9 +398,13 @@ def test_stats_snapshot_is_cached_with_age(monkeypatch):
     monkeypatch.setattr(eng, "stats", counting_stats)
     monkeypatch.setenv("MXNET_SERVE_STATS_TTL_MS", "60000")
     try:
+        with profiler.phase("obs_stats_phase", rows=3):
+            pass
         s1 = client.stats()
         s2 = client.stats()
         assert walks[0] == 1               # second poll hit the cache
+        # the process's span totals ride along under "phases"
+        assert s1["phases"]["obs_stats_phase"]["counts"]["rows"] >= 3
         assert s1["age_ms"] >= 0.0
         assert s2["age_ms"] > 0.0          # and says how stale it is
         assert s2["requests"] == s1["requests"]
@@ -432,16 +436,135 @@ def test_metricslogger_callback_logs_registry(caplog):
 
 
 def test_record_phase_feeds_phase_histogram(monkeypatch):
-    from mxnet_tpu import profiler
     h = metrics.registry().histogram("phase_seconds",
                                      labels={"phase": "obs_test_phase"})
     before = h.count
-    profiler.record_phase("obs_test_phase", 0, 2_000_000)
+    with profiler.phase("obs_test_phase"):
+        pass
     assert h.count == before + 1
     # the ambient feed silences under MXNET_METRICS=0
     monkeypatch.setenv("MXNET_METRICS", "0")
-    profiler.record_phase("obs_test_phase", 0, 2_000_000)
+    with profiler.phase("obs_test_phase"):
+        pass
     assert h.count == before + 1
+
+
+# ---------------------------------------------------------------------------
+# the span seam: profiler.phase() and the lifetime totals
+# ---------------------------------------------------------------------------
+TICK_CHILDREN = ("serve_admit", "serve_decode", "serve_prefill",
+                 "serve_sample", "serve_resolve", "cow_fork")
+
+
+def test_phase_counts_nest_and_add():
+    before = profiler.phase_totals()
+    with profiler.phase("obs_outer", rows=2) as outer:
+        with profiler.phase("obs_inner", rows=3):
+            pass
+        outer.add(done=1)
+        outer.add(done=1, rows=5)
+    got = profiler.phase_totals(since=before)
+    assert got["obs_outer"]["spans"] == got["obs_inner"]["spans"] == 1
+    assert got["obs_outer"]["counts"] == {"rows": 7, "done": 2}
+    assert got["obs_inner"]["counts"] == {"rows": 3}
+    assert 0 <= got["obs_inner"]["ns"] <= got["obs_outer"]["ns"]
+    # a label names the annotation and is summed nowhere; a cancelled
+    # span reports to no sink
+    with profiler.phase("obs_outer", labels={"ordinal": 7}):
+        pass
+    with profiler.phase("obs_inner") as span:
+        span.cancel()
+    got = profiler.phase_totals(since=before)
+    assert got["obs_outer"]["spans"] == 2
+    assert got["obs_outer"]["counts"] == {"rows": 7, "done": 2}
+    assert got["obs_inner"]["spans"] == 1
+
+
+def test_serve_tick_self_time_and_kv_tokens_by_hand():
+    """Two requests through a toy paged engine: the tick's children
+    lie inside it (their time sums to no more than the ticks'), every
+    decode step and prompt chunk is one span, and ``kv_tokens`` — the
+    K/V tokens attention has to read, a row's frontier after its step —
+    equals the count made by hand, whatever order the ticks took."""
+    reg = _gen_registry()
+    chunk = reg.gen_store("lm").prefill_chunk
+    jobs = [([1, 2, 3, 4, 5], 6), ([9, 8, 7, 6, 5, 4, 3, 2, 1, 11, 12], 4)]
+    before = profiler.phase_totals()
+    eng = GenerationEngine(reg)
+    try:
+        futs = [eng.submit("lm", p, max_tokens=n) for p, n in jobs]
+        results = [f.result(120) for f in futs]
+        stats = eng.stats()
+    finally:
+        eng.close()
+    assert [len(r.tokens) for r in results] == [n for _, n in jobs]
+    got = profiler.phase_totals(since=before)
+    tick = got["serve_tick"]
+    # the ordinal labels the annotation; nothing sums it
+    assert tick["spans"] >= 1 and tick["counts"] == {}
+    assert sum(got[c]["ns"] for c in TICK_CHILDREN if c in got) \
+        <= tick["ns"]
+    assert got["serve_sample"]["ns"] < tick["ns"]
+    assert got["serve_decode"]["spans"] == stats["decode_steps"]
+    # by hand: a prompt of P tokens in chunks of `chunk` reads up to each
+    # chunk's end; the last chunk samples token 1 and each of the n-1
+    # decode steps after it reads P + j tokens
+    prefill = sum(min(end, len(p)) for p, _ in jobs
+                  for end in range(chunk, len(p) + chunk, chunk))
+    decode = sum(len(p) + j for p, n in jobs for j in range(1, n))
+    assert got["serve_prefill"]["counts"]["kv_tokens"] == prefill
+    assert got["serve_decode"]["counts"]["kv_tokens"] == decode
+    assert got["serve_decode"]["counts"]["rows"] == \
+        sum(n - 1 for _, n in jobs)
+    assert got["serve_admit"]["counts"]["admitted"] == 2
+    resolved = got["serve_resolve"]["counts"]
+    assert resolved["tokens"] == sum(n for _, n in jobs)
+    assert resolved["finished"] == 2
+    for r in results:
+        # admitted after it was submitted, before its first token
+        assert r.t_submit <= r.t_admit <= r.token_times[0]
+        assert r.queue_wait_s == r.t_admit - r.t_submit
+
+
+def test_phase_totals_survive_engine_close():
+    """A reader that runs after the driver freed the engine still finds
+    what the engine did: the totals are the process's, not the
+    engine's (whose counters ``close()`` drops from the registry)."""
+    before = profiler.phase_totals()
+    eng = GenerationEngine(_gen_registry())
+    eng.submit("lm", [3, 1, 4, 1, 5], max_tokens=3).result(120)
+    eng.close()
+    del eng
+    got = profiler.phase_totals(since=before)
+    assert got["serve_tick"]["spans"] >= 1
+    assert got["serve_decode"]["counts"]["kv_tokens"] == 6 + 7
+    assert got["serve_idle"]["spans"] >= 1   # it waited for the request
+
+
+def test_stager_thread_span_lands_in_window_collector():
+    """``h2d_stage`` is opened on the stager's producer thread; the
+    window collector the fit thread installed sums it all the same."""
+    import threading
+    import jax
+    from mxnet_tpu.io.stager import DeviceStager
+    X = np.zeros((96, 4), np.float32)
+    it = mx.io.NDArrayIter(X, np.zeros((96,), np.float32), batch_size=32)
+    dev = mx.cpu().jax_device()
+    threads = set()
+
+    def place(a):
+        threads.add(threading.get_ident())
+        return jax.device_put(a, dev)
+
+    col = profiler.start_step_profile()
+    try:
+        stager = DeviceStager(it, place, depth=2)
+        assert len(list(stager)) == 3
+        stager.close()
+    finally:
+        profiler.stop_step_profile()
+    assert threads and threading.get_ident() not in threads
+    assert col.spans["h2d_stage"] == 3 and col.totals["h2d_stage"] > 0
 
 
 def test_step_profile_metrics_mode(capsys):

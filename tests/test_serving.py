@@ -345,6 +345,12 @@ def test_engine_flush_ordering_under_seeded_loadgen():
         assert flat == order, "batch formation reordered same-model FIFO"
         assert max(len(b) for b in batches) <= 4
         assert len(batches) < 20  # actually coalesced
+        # the counters a batch leaves: pad rows are bucket - rows of
+        # every dispatch, the high-water mark is the largest batch
+        st = eng.stats()
+        assert st["padded_rows"] == sum(
+            bucket_for(len(b), BUCKETS) - len(b) for b in batches)
+        assert st["max_rows_in_batch"] == max(len(b) for b in batches)
     finally:
         eng.close()
 

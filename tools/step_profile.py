@@ -15,7 +15,7 @@ recorded by the step-phase profiler seam (``mxnet_tpu/profiler.py``):
   packing/metric glue (absent when training runs the classic
   executor-group replication path).
 
-This is the diagnostic for an MFU gap: a healthy saturated chip shows
+This is the diagnostic for a utilization gap: a healthy saturated chip shows
 ``compute`` ~100% of the step; a fat ``data_wait`` means the input
 pipeline starves the MXU (raise staging depth / decode threads), a fat
 ``metric_fetch`` means per-batch host syncs serialize dispatch.
@@ -75,51 +75,14 @@ def smoke_fit(trace_path, batches=8, batch_size=32, delay_ms=0.0):
     finally:
         profiler.profiler_set_state("stop")
     profiler.dump_profile()
-    # model FLOPs of the compiled step (cost_analysis on the fused
-    # trainer's program) — the MFU-proxy numerator reported next to the
-    # phase table; None on the executor-group fallback
-    cost = None
-    trainer = mod._one_program_trainer()
-    if trainer is not None:
-        it.reset()
-        b0 = next(iter(it))
-        cost = trainer.step_cost_analysis(b0.data[0], b0.label[0])
-    return trace_path, cost
-
-
-def add_flops_columns(report, cost):
-    """Attach model-FLOPs / MFU-proxy columns to an aggregated phase
-    report: FLOPs come from the COMPILED step program, the step clock is
-    the compute phase (the dispatch+execution span), the peak from the
-    flops.py table (None off-chip -> mfu_proxy null, rate still
-    reported)."""
-    import jax
-
-    from mxnet_tpu.flops import mfu_proxy, peak_bf16_flops
-
-    flops = (cost or {}).get("flops")
-    report["model_gflops_per_step"] = (round(flops / 1e9, 6)
-                                       if flops else None)
-    compute = report.get("phases", {}).get("compute")
-    if flops and compute and compute["per_step_ms"] > 0:
-        per_sec = 1e3 / compute["per_step_ms"]
-        report["model_gflops_per_sec"] = round(flops * per_sec / 1e9, 2)
-        dev = jax.devices()[0]
-        report["mfu_proxy"] = mfu_proxy(
-            flops, per_sec,
-            peak_bf16_flops(getattr(dev, "device_kind", dev.platform)),
-            len(jax.devices()))
-    else:
-        report["model_gflops_per_sec"] = None
-        report["mfu_proxy"] = None
-    return report
+    return trace_path
 
 
 def render_metrics(snap):
     """Human-readable registry snapshot (``--metrics``): the per-phase
     histograms (count + p50/p95/p99 ms) beside the counters/gauges —
     the AGGREGATE answer next to the phase table's per-step one, from
-    the same record_phase spans."""
+    the same phase() spans."""
     lines = ["-- metrics registry (mxnet_tpu/metrics.py snapshot) --"]
     hists = snap.get("histograms", {})
     if hists:
@@ -157,15 +120,6 @@ def render(report):
                      "on the stager thread, spmd_step nests inside "
                      "compute as the sharded-program dispatch)"
                      % ", ".join(report["overlapped"]))
-    if report.get("model_gflops_per_step") is not None:
-        mfu = report.get("mfu_proxy")
-        lines.append("model FLOPs/step: %.4g GF (compiled "
-                     "cost_analysis); compute-phase rate: %s GF/s; "
-                     "MFU proxy: %s"
-                     % (report["model_gflops_per_step"],
-                        report.get("model_gflops_per_sec"),
-                        "%.4f" % mfu if mfu is not None
-                        else "n/a (no table peak for this device)"))
     return "\n".join(lines)
 
 
@@ -194,20 +148,16 @@ def main(argv=None):
     from mxnet_tpu.base import use_compile_cache
     use_compile_cache()
 
-    cost = None
     if args.trace:
         trace = args.trace
     else:
         trace = os.path.join(tempfile.mkdtemp(prefix="mxt_step_profile_"),
                              "step_profile_trace.json")
         t0 = time.time()
-        _, cost = smoke_fit(trace, batches=args.batches,
-                            batch_size=args.batch_size,
-                            delay_ms=args.delay_ms)
+        smoke_fit(trace, batches=args.batches,
+                  batch_size=args.batch_size, delay_ms=args.delay_ms)
         print("# smoke fit done in %.1fs -> %s" % (time.time() - t0, trace))
     report = profiler.aggregate_phase_trace(trace)
-    if not args.trace:
-        add_flops_columns(report, cost)
     if args.keep_trace and not args.trace:
         import shutil
         shutil.copy(trace, args.keep_trace)
