@@ -136,8 +136,8 @@ def init_pool(spec, num_blocks, block_size, dtype="float32"):
     num_heads, num_blocks * block_size, head_dim)`` — one GLOBAL pool
     shared by every sequence, addressed through per-sequence block
     tables (:func:`paged_step_apply`).  Block 0 is conventionally the
-    reserved trash block: pad writes target it, no real table entry
-    points at it."""
+    reserved trash block: unused table entries point at it (its keys
+    are masked as future positions), no real table entry does."""
     import jax.numpy as jnp
     dh = spec["num_hidden"] // spec["num_heads"]
     shape = (spec["num_layers"], spec["num_heads"],
@@ -347,6 +347,89 @@ def decode_apply(params, cache_k, cache_v, tokens, lengths, spec):
     return logits.astype(jnp.float32), cache_k, cache_v
 
 
+def _write_plan(tables, positions, valid, Lq, block_size):
+    """Where a step's fresh K/V rows go in a layer of the pool: the
+    same for every layer, so computed once a program.
+
+    ``Lq == 1`` (decode) is one row a sequence.  A chunk is written per
+    (sequence, affected block) — at most ``A = (Lq + bs - 2) // bs + 1``
+    blocks, whatever its start.  Only the writes that land in a block
+    some table owns are live: pad rows, a block of the bound past the
+    last valid row and the rows of a slot outside the dispatch (an
+    all-zero table) would only reach the trash block 0, which nothing
+    reads unmasked, and are written nowhere.  Returns ``(live count,
+    columns)``, the columns sorted live first: the sequence and the
+    pool row the write starts at and, for a chunk, the chunk rows
+    ``[lo, hi)`` that land in the block, whose row 0 is chunk row
+    ``lo`` (negative where the chunk starts inside the block)."""
+    import jax.numpy as jnp
+
+    B, T = tables.shape
+    bs = int(block_size)
+    rows = jnp.arange(B, dtype=jnp.int32)
+    if Lq == 1:
+        phys = tables[rows, jnp.minimum(positions // bs, T - 1)]
+        live = phys != 0
+        cols = (rows, phys * bs + positions % bs)
+    else:
+        A = (Lq + bs - 2) // bs + 1
+        seq = jnp.repeat(rows, A)                           # (B*A,)
+        log = (positions // bs)[seq] + jnp.tile(
+            jnp.arange(A, dtype=jnp.int32), B)              # logical block
+        phys = tables[seq, jnp.minimum(log, T - 1)]
+        live = (log <= ((positions + valid - 1) // bs)[seq]) \
+            & (log < T) & (phys != 0)
+        cols = (seq, phys * bs, log * bs - positions[seq], valid[seq])
+    order = jnp.argsort(~live, stable=True)                 # live first
+    return (jnp.sum(live, dtype=jnp.int32),
+            tuple(col[order] for col in cols))
+
+
+def _pool_write(pool_k, pool_v, layer, k, v, plan, block_size):
+    """Write a step's fresh K/V — ``k``/``v`` ``(B, H, Lq, dh)`` — into
+    layer ``layer`` of the stacked pools by :func:`_write_plan`'s
+    ``plan``, IN PLACE on the donated arrays: ``dynamic_update_slice``s
+    in one loop over the live writes, never a ``scatter``.  The TPU
+    compiler gives a scatter on the pool a layout of its own
+    (``{3,1,2,0}``) and copies the whole pool into it and back around
+    every program (docs/architecture/decode_engine.md, "The pool stays
+    where it is").
+
+    Decode writes one ``(1, H, 1, dh)`` row a sequence; a chunk reads
+    each affected block, overlays the chunk's valid rows and writes it
+    back.  Blocks are taken in order, so a block two tables share holds
+    exactly what a row-by-row write would leave."""
+    import jax
+    import jax.numpy as jnp
+
+    B, H, Lq, dh = k.shape
+    bs = int(block_size)
+    count, cols = plan
+    fresh = (k.astype(pool_k.dtype), v.astype(pool_v.dtype))
+
+    def write(n, pools):
+        at = (layer, 0, cols[1][n], 0)
+        out = []
+        for pool, new in zip(pools, fresh):
+            new = jax.lax.dynamic_slice_in_dim(new, cols[0][n], 1, 0)
+            if Lq > 1:
+                # the block's rows are a window of bs consecutive chunk
+                # rows starting anywhere in (-bs, Lq): bs rows of margin
+                # either side make it one dynamic_slice
+                lo, hi = cols[2][n], cols[3][n]
+                new = jax.lax.dynamic_slice(
+                    jnp.pad(new, ((0, 0), (0, 0), (bs, bs), (0, 0))),
+                    (0, 0, lo + bs, 0), (1, H, bs, dh))
+                r = lo + jnp.arange(bs, dtype=jnp.int32)
+                new = jnp.where(
+                    ((r >= 0) & (r < hi))[None, None, :, None], new,
+                    jax.lax.dynamic_slice(pool, at, (1, H, bs, dh)))
+            out.append(jax.lax.dynamic_update_slice(pool, new, at))
+        return tuple(out)
+
+    return jax.lax.fori_loop(0, count, write, (pool_k, pool_v))
+
+
 def paged_step_apply(params, pool_k, pool_v, tables, tokens, positions,
                      valid, spec, block_size, scales=None,
                      all_logits=False):
@@ -363,15 +446,17 @@ def paged_step_apply(params, pool_k, pool_v, tables, tokens, positions,
     must point at a VALID pool block — conventionally the reserved
     trash block 0.
 
-    Each layer scatters the chunk's K/V to pool rows ``tables[b, p //
-    bs] * bs + p % bs`` (pad rows scatter into block 0) and attends
-    through the ``sdp_attention_paged`` door — so intra-chunk causality
+    Each layer writes the chunk's K/V to pool rows ``tables[b, p //
+    bs] * bs + p % bs`` (:func:`_pool_write`; pad rows are written
+    nowhere) and attends through the ``sdp_attention_paged`` door,
+    which takes the whole pool and the layer — so intra-chunk causality
     and pad invisibility both come from the one offset-causal mask, and
-    the pool arrays lower to in-place scatters when DONATED.  Returns
+    a DONATED pool is addressed in place from the program's entry to
+    its exit: no instruction copies, relays or slices it.  Returns
     ``(logits (B, vocab) fp32 at each row's LAST VALID position, pool_k,
     pool_v)``.  Rows whose table is all zeros (non-participating slots
-    in a fused dispatch) read/write only the trash block and yield
-    garbage logits — callers discard them.  Params may be bf16 or int8
+    in a fused dispatch) reach only the trash block and yield garbage
+    logits — callers discard them.  Params may be bf16 or int8
     ``QuantizedWeight`` pairs like :func:`prefill_apply`.
 
     ``scales`` — a ``(scale_k, scale_v)`` pair from
@@ -403,17 +488,14 @@ def paged_step_apply(params, pool_k, pool_v, tables, tokens, positions,
     tables = jnp.asarray(tables, jnp.int32)
     positions = jnp.asarray(positions, jnp.int32)
     valid = jnp.asarray(valid, jnp.int32)
-    r = jnp.arange(Lq, dtype=jnp.int32)
-    p = positions[:, None] + r[None, :]                     # (B, Lq)
-    dest = tables[jnp.arange(B)[:, None], p // bs] * bs + p % bs
-    # pad rows scatter into the trash block (their keys are never
-    # attended: every real query's mask stops at its own frontier)
-    dest = jnp.where(r[None, :] < valid[:, None], dest,
-                     p % bs).reshape(-1)                    # (B*Lq,)
     int8_kv = scales is not None
-    if int8_kv:
+    if not int8_kv:
+        plan = _write_plan(tables, positions, valid, Lq, bs)
+    else:
         scale_k, scale_v = scales
         T = tables.shape[1]
+        r = jnp.arange(Lq, dtype=jnp.int32)
+        p = positions[:, None] + r[None, :]                 # (B, Lq)
         # static bound on blocks a row's write can touch: worst case
         # the chunk starts on a block's last row
         A = (Lq + bs - 2) // bs + 1
@@ -483,21 +565,14 @@ def paged_step_apply(params, pool_k, pool_v, tables, tokens, positions,
             pool_v = pool_v.at[i].set(pv_i)
             scale_k = scale_k.at[i].set(sk_i)
             scale_v = scale_v.at[i].set(sv_i)
-            att = sdp_attention_paged(q, pool_k[i], pool_v[i], tables,
+            att = sdp_attention_paged(q, pool_k, pool_v, i, tables,
                                       positions, bs,
-                                      kv_scales=(scale_k[i],
-                                                 scale_v[i]))
+                                      kv_scales=(scale_k, scale_v))
         else:
-            # advanced-index scatter: (layer, :, rows, :) puts the
-            # indexed dimension first, so updates arrive as (B*Lq, H, dh)
-            kT = jnp.transpose(k.astype(cdt), (0, 2, 1, 3)).reshape(
-                B * Lq, H, dh)
-            vT = jnp.transpose(v.astype(cdt), (0, 2, 1, 3)).reshape(
-                B * Lq, H, dh)
-            pool_k = pool_k.at[i, :, dest, :].set(kT)
-            pool_v = pool_v.at[i, :, dest, :].set(vT)
-            att = sdp_attention_paged(q.astype(cdt), pool_k[i],
-                                      pool_v[i], tables, positions, bs)
+            pool_k, pool_v = _pool_write(pool_k, pool_v, i, k, v, plan,
+                                         bs)
+            att = sdp_attention_paged(q.astype(cdt), pool_k, pool_v, i,
+                                      tables, positions, bs)
         att = jnp.transpose(att, (0, 2, 1, 3)).reshape(-1, D)
         x = x + _mm(att.astype(x.dtype), bp["proj_weight"]).reshape(
             B, Lq, D)

@@ -80,19 +80,21 @@ def sdp_attention(query, key, value, causal=False, scale=0.0,
     return _dense_attention(query, key, value, causal, scale)
 
 
-def sdp_attention_paged(query, k_pool, v_pool, tables, positions,
+def sdp_attention_paged(query, k_pool, v_pool, layer, tables, positions,
                         block_size, scale=0.0, kv_scales=None):
     """Paged scaled-dot-product attention: [B, H, Lq, D] queries whose
     row r of sequence b sits at global position ``positions[b] + r``,
-    attending over a global block pool (``(H, num_blocks * block_size,
-    D)``) through per-sequence block tables (``(B, T)`` int32) — the
-    decode engine's paged-KV door (docs/architecture/decode_engine.md).
+    attending over layer ``layer`` (a static int) of the whole stacked
+    block pool (``(L, H, num_blocks * block_size, D)``, never a slice
+    of it: the kernel addresses the layer in place) through
+    per-sequence block tables (``(B, T)`` int32) — the decode engine's
+    paged-KV door (docs/architecture/decode_engine.md).
 
-    ``kv_scales`` — a ``(scale_k, scale_v)`` pair of ``(H, num_blocks)``
-    fp32 arrays — marks the pools as int8 codes with per-(head, block)
-    absmax scales; both lowerings dequantize through the identical
-    scale arithmetic (on-tile in the kernel, on the gathered rows in
-    the reference), so they remain numerical twins.
+    ``kv_scales`` — a ``(scale_k, scale_v)`` pair of ``(L, H,
+    num_blocks)`` fp32 arrays — marks the pools as int8 codes with
+    per-(layer, head, block) absmax scales; both lowerings dequantize
+    through the identical scale arithmetic (on-tile in the kernel, on
+    the gathered rows in the reference), so they remain numerical twins.
 
     Eligible shapes route to ``flash_attention_paged`` (scalar-prefetch
     block tables, dynamic block skip, forward-only); everything else —
@@ -108,11 +110,11 @@ def sdp_attention_paged(query, k_pool, v_pool, tables, positions,
                                t * bs, d, query.dtype, bs):
         from ..pallas_ops.paged_attention import flash_attention_paged
         return flash_attention_paged(
-            query, k_pool, v_pool, tables, positions, bs, scale=scale,
-            block_q=_pd.block_seq(), interpret=_pd.interpret_mode(),
-            kv_scales=kv_scales)
+            query, k_pool, v_pool, layer, tables, positions, bs,
+            scale=scale, block_q=_pd.block_seq(),
+            interpret=_pd.interpret_mode(), kv_scales=kv_scales)
     from ..pallas_ops.paged_attention import paged_attention_reference
-    return paged_attention_reference(query, k_pool, v_pool, tables,
+    return paged_attention_reference(query, k_pool, v_pool, layer, tables,
                                      positions, bs, scale=scale,
                                      kv_scales=kv_scales)
 

@@ -17,6 +17,13 @@ physical tile ``tables[b, j]`` from the pool.  The tables and frontiers
 ride as scalar-prefetch operands (``PrefetchScalarGridSpec``): they land
 in SMEM before the grid runs, so index maps can read them.
 
+The kernel takes the WHOLE stacked pool ``(L, H, num_blocks * bs, D)``
+and a static ``layer``: the index map reads tile ``(layer, h,
+tables[b, j], 0)`` of the array the program was handed, so no layer is
+ever cut out of the stack (a slice handed to a Pallas call is a copy of
+that layer, 135 MB at the served width;
+docs/architecture/pallas_kernels.md).
+
 ``paged_attention_reference`` is the dense XLA twin — gather the pool
 rows through the same table arithmetic, then the exact dense
 offset-causal attention of ``ops/attention._dense_attention`` — the
@@ -78,8 +85,8 @@ def _paged_kernel(*refs, scale, block_q, block_size, nt, int8):
     @pl.when(run)
     def _step():
         q = q_ref[0, 0].astype(jnp.float32)     # (BQ, D)
-        kb = k_ref[0].astype(jnp.float32)       # (BS, D)
-        vb = v_ref[0].astype(jnp.float32)
+        kb = k_ref[0, 0].astype(jnp.float32)    # (BS, D)
+        vb = v_ref[0, 0].astype(jnp.float32)
         if int8:
             phys = tbl_ref[b, ki]               # SMEM scalar read
             kb = kb * sk_ref[h, phys]
@@ -110,25 +117,27 @@ def _paged_kernel(*refs, scale, block_q, block_size, nt, int8):
                        jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
 
 
-def flash_attention_paged(q, k_pool, v_pool, tables, positions,
+def flash_attention_paged(q, k_pool, v_pool, layer, tables, positions,
                           block_size, scale=None, block_q=128,
                           interpret=None, kv_scales=None):
     """Offset-causal flash attention against a PAGED KV pool.
 
     q: (B, H, Lq, D) — query row r of sequence b sits at global
-    position ``positions[b] + r``; k_pool/v_pool: (H, num_blocks *
-    block_size, D) global pools; tables: (B, T) int32 per-sequence
+    position ``positions[b] + r``; k_pool/v_pool: the whole stacked
+    ``(L, H, num_blocks * block_size, D)`` global pools, read in place
+    at the static index ``layer``; tables: (B, T) int32 per-sequence
     block tables mapping logical block j to a physical pool block
     (entries past a sequence's frontier must point at a valid block —
     conventionally the reserved trash block 0 — their keys are masked
     either way); positions: (B,) int32 frontiers.
 
-    ``kv_scales`` — a ``(scale_k, scale_v)`` pair of ``(H, num_blocks)``
-    fp32 per-(head, physical block) absmax scales — selects the int8
-    pool layout: the pools hold int8 codes and every K/V tile is
-    dequantized ON-TILE (``codes * scale[h, tbl[b, j]]``) before the
-    unchanged fp32 online softmax, so accumulation numerics match the
-    dense twin exactly on identically-dequantized values.
+    ``kv_scales`` — a ``(scale_k, scale_v)`` pair of ``(L, H,
+    num_blocks)`` fp32 per-(layer, head, physical block) absmax scales
+    — selects the int8 pool layout: the pools hold int8 codes and every
+    K/V tile is dequantized ON-TILE (``codes * scale[layer, h,
+    tbl[b, j]]``; the layer's scales are what rides in SMEM) before
+    the unchanged fp32 online softmax, so accumulation numerics match
+    the dense twin exactly on identically-dequantized values.
 
     The tables/positions ride as scalar-prefetch operands so BlockSpec
     index maps can gather physical tiles; blocks a sequence cannot see
@@ -137,8 +146,10 @@ def flash_attention_paged(q, k_pool, v_pool, tables, positions,
     B, H, Lq, D = q.shape
     T = tables.shape[1]
     bs = int(block_size)
-    assert k_pool.shape == v_pool.shape and k_pool.shape[0] == H
-    assert k_pool.shape[1] % bs == 0, \
+    layer = int(layer)
+    assert k_pool.shape == v_pool.shape and k_pool.ndim == 4 \
+        and k_pool.shape[1] == H and 0 <= layer < k_pool.shape[0]
+    assert k_pool.shape[2] % bs == 0, \
         "pool length must be a multiple of block_size"
     if scale is None:
         scale = 1.0 / (D ** 0.5)
@@ -152,25 +163,26 @@ def flash_attention_paged(q, k_pool, v_pool, tables, positions,
                                int8=int8)
 
     if int8:
-        sk = jnp.asarray(kv_scales[0], jnp.float32)
-        sv = jnp.asarray(kv_scales[1], jnp.float32)
+        sk = jnp.asarray(kv_scales[0][layer], jnp.float32)
+        sv = jnp.asarray(kv_scales[1][layer], jnp.float32)
         scalars = (tbl, pos, sk, sv)
-        q_map = lambda b, h, i, j, tbl, pos, sk, sv: (b, h, i, 0)
-        kv_map = lambda b, h, i, j, tbl, pos, sk, sv: (h, tbl[b, j], 0)
     else:
         scalars = (tbl, pos)
-        q_map = lambda b, h, i, j, tbl, pos: (b, h, i, 0)
-        kv_map = lambda b, h, i, j, tbl, pos: (h, tbl[b, j], 0)
+    # the scalar operands stay first, tables then positions: a trace's
+    # readers know the kernel by them (benchmark/layer_metrics/)
+    q_map = lambda b, h, i, j, *_: (b, h, i, 0)
+    kv_map = lambda b, h, i, j, tbl, *_: (layer, h, tbl[b, j], 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(B, H, Lq // block_q, T),
         in_specs=[
             _spec((1, 1, block_q, D), q_map),  # Q tile
-            # k/v: fetch PHYSICAL block tbl[b, j] from the pool —
-            # the index is in units of whole (bs, D) blocks
-            _spec((1, bs, D), kv_map),
-            _spec((1, bs, D), kv_map),
+            # k/v: fetch PHYSICAL block tbl[b, j] of this layer from
+            # the stacked pool — the index is in units of whole
+            # (bs, D) blocks
+            _spec((1, 1, bs, D), kv_map),
+            _spec((1, 1, bs, D), kv_map),
         ],
         out_specs=_spec((1, 1, block_q, D), q_map),
         scratch_shapes=_softmax_scratch(block_q, D))
@@ -187,14 +199,15 @@ def flash_attention_paged(q, k_pool, v_pool, tables, positions,
     return out
 
 
-def paged_attention_reference(q, k_pool, v_pool, tables, positions,
-                              block_size, scale=None, kv_scales=None):
-    """Dense XLA twin of :func:`flash_attention_paged`: gather the pool
-    rows through the same block-table arithmetic, then the exact dense
-    offset-causal attention (same ``-1e30`` constant, fp32 accumulation)
-    — the ``MXNET_PALLAS=0`` lowering and the parity oracle.
-    ``kv_scales`` dequantizes int8 pools through the SAME per-(head,
-    physical block) scale arithmetic as the kernel."""
+def paged_attention_reference(q, k_pool, v_pool, layer, tables,
+                              positions, block_size, scale=None,
+                              kv_scales=None):
+    """Dense XLA twin of :func:`flash_attention_paged`: gather layer
+    ``layer``'s pool rows through the same block-table arithmetic, then
+    the exact dense offset-causal attention (same ``-1e30`` constant,
+    fp32 accumulation) — the ``MXNET_PALLAS=0`` lowering and the parity
+    oracle.  ``kv_scales`` dequantizes int8 pools through the SAME
+    per-(layer, head, physical block) scale arithmetic as the kernel."""
     B, H, Lq, D = q.shape
     T = tables.shape[1]
     bs = int(block_size)
@@ -206,18 +219,18 @@ def paged_attention_reference(q, k_pool, v_pool, tables, positions,
     idx = (tbl[:, :, None] * bs +
            jnp.arange(bs, dtype=jnp.int32)[None, None, :]).reshape(
                B, T * bs)
-    k = jnp.transpose(jnp.take(k_pool, idx, axis=1), (1, 0, 2, 3))
-    v = jnp.transpose(jnp.take(v_pool, idx, axis=1), (1, 0, 2, 3))
+    k = jnp.transpose(jnp.take(k_pool[layer], idx, axis=1), (1, 0, 2, 3))
+    v = jnp.transpose(jnp.take(v_pool[layer], idx, axis=1), (1, 0, 2, 3))
     if kv_scales is not None:
         # per-(head, physical block) dequant, identical to the kernel's
         # on-tile multiply: scale[h, tbl[b, j]] covers pool rows
         # j*bs..j*bs+bs-1 of that gathered block
         sck = jnp.transpose(jnp.repeat(
-            jnp.asarray(kv_scales[0], jnp.float32)[:, tbl], bs, axis=2),
-            (1, 0, 2))                                   # (B, H, T*bs)
+            jnp.asarray(kv_scales[0][layer], jnp.float32)[:, tbl], bs,
+            axis=2), (1, 0, 2))                          # (B, H, T*bs)
         scv = jnp.transpose(jnp.repeat(
-            jnp.asarray(kv_scales[1], jnp.float32)[:, tbl], bs, axis=2),
-            (1, 0, 2))
+            jnp.asarray(kv_scales[1][layer], jnp.float32)[:, tbl], bs,
+            axis=2), (1, 0, 2))
         k = k.astype(jnp.float32) * sck[..., None]
         v = v.astype(jnp.float32) * scv[..., None]
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),

@@ -282,8 +282,8 @@ class _BlockPool:
     """Host-side allocator over the paged KV pool's physical blocks.
 
     Block 0 is the reserved trash block (zero table entries point at
-    it; non-participating dispatch rows scribble there) and is never
-    allocated.  Every allocated block carries a refcount: a sequence
+    it; non-participating dispatch rows reach no other block) and is
+    never allocated.  Every allocated block carries a refcount: a sequence
     holding it in its table counts one, each prefix-cache pin counts
     one — a block frees when the last reference drops."""
 
@@ -1502,8 +1502,8 @@ class GenerationEngine:
     def _paged_decode_step(self, model, st, dec):
         """Advance every generating slot one token (serve_decode
         phase).  Slots mid-prefill (and empty slots) ride the dispatch
-        with all-zero tables — their writes land in the trash block
-        and their outputs are discarded."""
+        with all-zero tables — they reach only the trash block and
+        their outputs are discarded."""
         for i in dec:
             # the write position this step: COW-fork or allocate first
             self._paged_write_ready(st, i, [int(st.lengths[i])])

@@ -285,13 +285,18 @@ def spec_verify(logits_all, prop_toks, prop_q, keys, temps, top_ks,
 
 
 class _Program:
-    __slots__ = ("fn", "bucket", "out_batch_major", "compile_ms")
+    __slots__ = ("fn", "bucket", "out_batch_major", "compile_ms",
+                 "temp_bytes")
 
-    def __init__(self, fn, bucket, out_batch_major, compile_ms):
+    def __init__(self, fn, bucket, out_batch_major, compile_ms,
+                 temp_bytes=None):
         self.fn = fn
         self.bucket = bucket
         self.out_batch_major = out_batch_major
         self.compile_ms = compile_ms
+        # a paged step program's scratch (memory_analysis): what says
+        # that it holds no second copy of the KV pool
+        self.temp_bytes = temp_bytes
 
 
 class ProgramStore:
@@ -1332,10 +1337,10 @@ class GenerativeProgramStore:
                     "paged_step_sample_p", "paged_verify"):
             # ONE unified step program for the paged plane: lb is the
             # query length lq (1 = a decode step; prefill_chunk = one
-            # prompt chunk; spec_k+1 = a speculative verify).  Scatter-
+            # prompt chunk; spec_k+1 = a speculative verify).  Write-
             # then-attend over the global pool through (bb, table_width)
             # block tables; rows not participating in a dispatch ride
-            # with all-zero tables (writes land in the reserved trash
+            # with all-zero tables (they reach only the reserved trash
             # block 0) and their outputs are discarded host-side.  On
             # the int8 plane every kind gains the two donated scale
             # pools right after the code pools, in arguments AND
@@ -1456,7 +1461,9 @@ class GenerativeProgramStore:
                     donate_argnums=cache_donate_argnums(pool_donate)) \
                     .lower(*base).compile()
             ms = (time.perf_counter() - tic) * 1e3
-            return _Program(compiled, (bb, lb), (), ms)
+            mem = compiled.memory_analysis()
+            return _Program(compiled, (bb, lb), (), ms,
+                            getattr(mem, "temp_size_in_bytes", None))
         if kind == "prefill":
             cache_len = self.kv_bucket(lb)
 
@@ -1807,8 +1814,10 @@ class GenerativeProgramStore:
             out = dict(self._stats)
             out["size"] = len(self._programs)
             out["max_programs"] = self.max_programs
-            out["programs_resident"] = sorted(
-                (k[2], k[3], k[4]) for k in self._programs)
+            temp = sorted(((k[2], k[3], k[4], p.temp_bytes)
+                           for k, p in self._programs.items()),
+                          key=lambda row: row[:3])
+            out["programs_resident"] = [row[:3] for row in temp]
         out["generative"] = True
         out["version"] = self._version
         out["batch_buckets"] = list(self._batch_edges)
@@ -1823,6 +1832,12 @@ class GenerativeProgramStore:
             out["prefill_chunk"] = self.prefill_chunk
             out["pool_blocks"] = self.pool_blocks
             out["table_width"] = self.table_width()
+            # (kind, batch bucket, lq, scratch bytes) of each resident
+            # step program: a program that addresses the pool in place
+            # needs far less than one layer of it (cache_state's
+            # pool_bytes / 2 / num_layers); one that relays the pool
+            # holds a second pool here
+            out["program_temp_bytes"] = temp
         out["weight_bytes"] = _weight_bytes(self._params)
         state = self.cache_state
         if state is not None:

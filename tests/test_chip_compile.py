@@ -83,13 +83,14 @@ def _offset(q, k, v, o):
 
 
 def _paged(bs):
+    # the whole two-layer pool and the layer: what the step graph hands
     return lambda q, k, v, t, p: pa.flash_attention_paged(
-        q, k, v, t, p, bs, interpret=False)
+        q, k, v, 1, t, p, bs, interpret=False)
 
 
 def _paged_int8(q, k, v, t, p, sk, sv):
-    return pa.flash_attention_paged(q, k, v, t, p, BS, interpret=False,
-                                    kv_scales=(sk, sv))
+    return pa.flash_attention_paged(q, k, v, 1, t, p, BS,
+                                    interpret=False, kv_scales=(sk, sv))
 
 
 def _rms(x, g):
@@ -105,7 +106,7 @@ def _dqmm(x, c, s):
 
 
 def _paged_args(s, lq, pool_dtype=F32, bs=BS, blocks=B * T + 1):
-    pool = s((H, blocks * bs, D), pool_dtype)
+    pool = s((2, H, blocks * bs, D), pool_dtype)
     return (s((B, H, lq, D)), pool, pool, s((B, T), I32), s((B,), I32))
 
 
@@ -129,7 +130,7 @@ CASES = [
      lambda s: (s((B, H, 1, D), BF16),) + _paged_args(s, 1, BF16)[1:], 1),
     ("paged-int8", _paged_int8,
      lambda s: _paged_args(s, 1, I8)
-     + (s((H, B * T + 1)), s((H, B * T + 1))), 1),
+     + (s((2, H, B * T + 1)), s((2, H, B * T + 1))), 1),
     ("softmax-fwd", lambda x: sx.fused_softmax(x, 8, False),
      lambda s: (s((ROWS, V)),), 1),
     ("softmax-bwd", _grad(lambda x: sx.fused_softmax(x, 8, False) ** 2,
@@ -229,3 +230,57 @@ def test_flash_block_must_be_mosaic_tileable(compiled_mode, monkeypatch):
     assert not dispatch.eligible_attention(2, 4, 200, 200, D, "float32")
     monkeypatch.setattr(dispatch, "_on_tpu", lambda: False)
     assert dispatch.eligible_attention(2, 4, 200, 200, D, "float32")
+
+
+# The paged step programs at the served model's widths (16 heads x 128,
+# 2048 wide, 16 slots of 16 blocks of 64 tokens), two layers deep: the
+# KV pool is addressed in place from entry to exit.  A scatter on the
+# pool, or a layer of it sliced out for the kernel, makes the compiler
+# relay the whole pool around the program (docs/architecture/
+# decode_engine.md, "The pool stays where it is").
+SLOTS, LAYERS = 16, 2
+POOL_ROWS = (SLOTS * T + 1) * BS
+_MOVES_THE_POOL = ("copy", "slice", "scatter", "fusion")
+
+
+@pytest.mark.parametrize("lq", [1, 32], ids=["decode", "prefill-chunk"])
+def test_paged_program_leaves_the_pool_in_place(chip, compiled_mode, lq):
+    import re
+    from mxnet_tpu.models.transformer_lm import (get_symbol, lm_spec,
+                                                 paged_step_apply)
+    from mxnet_tpu.serving.program_store import sample_tokens
+
+    spec = lm_spec(num_layers=LAYERS, num_hidden=W, num_heads=H,
+                   vocab_size=V)
+    net = get_symbol(seq_len=8, **spec)
+    shapes, _, _ = net.infer_shape(data=(1, 8), softmax_label=(1, 8))
+    params = {n: chip(s) for n, s in zip(net.list_arguments(), shapes)
+              if n not in ("data", "softmax_label")}
+    pool = chip((LAYERS, H, POOL_ROWS, D))
+
+    def step(params, pk, pv, tables, tokens, positions, valid, keys,
+             temps, top_ks):
+        logits, pk, pv = paged_step_apply(params, pk, pv, tables, tokens,
+                                          positions, valid, spec, BS)
+        toks, keys = sample_tokens(logits, keys, temps, top_ks)
+        return toks, pk, pv, keys
+
+    compiled = jax.jit(step, donate_argnums=(1, 2, 7)).lower(
+        params, pool, pool, chip((SLOTS, T), I32), chip((SLOTS, lq), I32),
+        chip((SLOTS,), I32), chip((SLOTS,), I32),
+        chip((SLOTS, 2), jnp.uint32), chip((SLOTS,)),
+        chip((SLOTS,), I32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= LAYERS  # the kernel is in it
+    pool_shaped = re.compile(
+        r"f32\[(?:%d,|1,)?%d,%d,%d\]" % (LAYERS, H, POOL_ROWS, D))
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) ([a-z-]+)\(",
+                     line)
+        if m and m.group(2) in _MOVES_THE_POOL \
+                and pool_shaped.search(m.group(1)):
+            moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)
+    layer_bytes = H * POOL_ROWS * D * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes

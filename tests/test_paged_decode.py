@@ -63,17 +63,19 @@ def _generate(registry, requests):
 # ---------------------------------------------------------------------------
 # kernel parity
 # ---------------------------------------------------------------------------
-def _paged_case(seed, B, H, T, D, bs, num_blocks, positions, lq):
+def _paged_case(seed, B, H, T, D, bs, num_blocks, positions, lq,
+                layers=1):
     """One randomized paged attention case: sequences share physical
     blocks, unused table entries point at the trash block 0, and the
-    pool rows past every frontier hold junk that must never leak."""
+    pool rows past every frontier hold junk that must never leak.  The
+    pools are the whole ``(layers, H, rows, D)`` stacks the door takes."""
     import jax.numpy as jnp
     rs = np.random.RandomState(seed)
     q = jnp.asarray(rs.randn(B, H, lq, D).astype(np.float32))
     k_pool = jnp.asarray(
-        rs.randn(H, num_blocks * bs, D).astype(np.float32))
+        rs.randn(layers, H, num_blocks * bs, D).astype(np.float32))
     v_pool = jnp.asarray(
-        rs.randn(H, num_blocks * bs, D).astype(np.float32))
+        rs.randn(layers, H, num_blocks * bs, D).astype(np.float32))
     tables = np.zeros((B, T), np.int32)
     pos = np.asarray(positions, np.int32)
     nxt = 1
@@ -93,24 +95,32 @@ def _paged_case(seed, B, H, T, D, bs, num_blocks, positions, lq):
 
 @pytest.mark.skipif(pltpu is None,
                     reason="pallas TPU backend module unavailable")
-def test_paged_kernel_matches_dense_twin():
+@pytest.mark.parametrize("layer", [0, 2], ids=["layer0", "last-layer"])
+@pytest.mark.parametrize("seed,lq,positions", [(0, 1, [5, 9, 17]),
+                                               (1, 4, [0, 3, 12]),
+                                               (2, 8, [8, 1, 15])],
+                         ids=["decode", "chunk4", "chunk8"])
+def test_paged_kernel_matches_dense_twin(seed, lq, positions, layer):
     """flash_attention_paged (interpret mode) vs the gather-based dense
-    twin: ragged per-sequence offsets, partial last blocks, shared
-    physical blocks, decode (lq=1) and chunk (lq=4) query lengths."""
+    twin, both through the (whole pool, layer) door: ragged
+    per-sequence offsets, partial last blocks, shared physical blocks,
+    decode (lq=1) and chunk (lq=4, 8) query lengths, the first and the
+    last layer of a three-layer stack — and the twin on the stack
+    equals the twin on that layer alone, bit for bit."""
     from mxnet_tpu.pallas_ops.paged_attention import (
         flash_attention_paged, paged_attention_reference)
 
-    for seed, lq, positions in ((0, 1, [5, 9, 17]),
-                                (1, 4, [0, 3, 12]),
-                                (2, 8, [8, 1, 15])):
-        q, kp, vp, tbl, pos = _paged_case(
-            seed, B=3, H=2, T=4, D=8, bs=8, num_blocks=12,
-            positions=positions, lq=lq)
-        got = np.asarray(flash_attention_paged(
-            q, kp, vp, tbl, pos, 8, block_q=4, interpret=True))
-        want = np.asarray(paged_attention_reference(
-            q, kp, vp, tbl, pos, 8))
-        assert np.abs(got - want).max() < 2e-6, (seed, lq)
+    q, kp, vp, tbl, pos = _paged_case(
+        seed, B=3, H=2, T=4, D=8, bs=8, num_blocks=12,
+        positions=positions, lq=lq, layers=3)
+    got = np.asarray(flash_attention_paged(
+        q, kp, vp, layer, tbl, pos, 8, block_q=4, interpret=True))
+    want = np.asarray(paged_attention_reference(
+        q, kp, vp, layer, tbl, pos, 8))
+    assert np.abs(got - want).max() < 2e-6
+    alone = np.asarray(paged_attention_reference(
+        q, kp[layer:layer + 1], vp[layer:layer + 1], 0, tbl, pos, 8))
+    assert np.array_equal(want, alone)
 
 
 def test_paged_reference_matches_contiguous_dense():
@@ -125,11 +135,12 @@ def test_paged_reference_matches_contiguous_dense():
     q, kp, vp, tbl, pos = _paged_case(
         3, B=2, H=2, T=3, D=8, bs=8, num_blocks=8,
         positions=[6, 13], lq=2)
-    got = np.asarray(paged_attention_reference(q, kp, vp, tbl, pos, 8))
+    got = np.asarray(paged_attention_reference(q, kp, vp, 0, tbl, pos,
+                                               8))
     idx = (np.asarray(tbl)[:, :, None] * 8 +
            np.arange(8)[None, None, :]).reshape(2, -1)
-    k = jnp.asarray(np.asarray(kp)[:, idx].transpose(1, 0, 2, 3))
-    v = jnp.asarray(np.asarray(vp)[:, idx].transpose(1, 0, 2, 3))
+    k = jnp.asarray(np.asarray(kp)[0][:, idx].transpose(1, 0, 2, 3))
+    v = jnp.asarray(np.asarray(vp)[0][:, idx].transpose(1, 0, 2, 3))
     want = np.asarray(_dense_attention(
         q, k, v, True, 1.0 / 8 ** 0.5,
         q_offsets=np.asarray(pos)))
@@ -147,15 +158,93 @@ def test_paged_kernel_ignores_trash_and_junk_blocks():
     q, kp, vp, tbl, pos = _paged_case(
         4, B=2, H=2, T=3, D=8, bs=8, num_blocks=8,
         positions=[4, 10], lq=1)
-    base = np.asarray(paged_attention_reference(q, kp, vp, tbl, pos, 8))
+    base = np.asarray(paged_attention_reference(q, kp, vp, 0, tbl, pos,
+                                                8))
     kj, vj = np.asarray(kp).copy(), np.asarray(vp).copy()
     used = set(np.asarray(tbl).ravel()) - {0}
     for blk in set(range(8)) - used:  # trash block 0 + unreferenced
-        kj[:, blk * 8:(blk + 1) * 8] = 1e4
-        vj[:, blk * 8:(blk + 1) * 8] = -1e4
+        kj[:, :, blk * 8:(blk + 1) * 8] = 1e4
+        vj[:, :, blk * 8:(blk + 1) * 8] = -1e4
     got = np.asarray(paged_attention_reference(
-        jnp.asarray(q), jnp.asarray(kj), jnp.asarray(vj), tbl, pos, 8))
+        jnp.asarray(q), jnp.asarray(kj), jnp.asarray(vj), 0, tbl, pos,
+        8))
     assert np.abs(got - base).max() < 2e-6
+
+
+# ---------------------------------------------------------------------------
+# the in-place pool write
+# ---------------------------------------------------------------------------
+# (id, Lq, positions, valid, tables over 8-token blocks; block 0 trash)
+WRITE_CASES = [
+    ("decode", 1, [5, 16, 0], [1, 1, 1],
+     [[1, 0, 0, 0], [2, 3, 4, 0], [0, 0, 0, 0]]),
+    ("chunk-inside-one-block", 4, [2, 9, 0], [4, 4, 4],
+     [[1, 0, 0, 0], [2, 3, 0, 0], [4, 0, 0, 0]]),
+    # the verify program's case: K+1 rows from any position
+    ("chunk-straddles-block-edge", 5, [6, 13, 21], [5, 5, 5],
+     [[1, 2, 0, 0], [3, 4, 5, 0], [6, 7, 8, 9]]),
+    ("chunk-longer-than-a-block", 12, [7, 0, 3], [12, 12, 12],
+     [[1, 2, 3, 0], [4, 5, 0, 0], [6, 7, 0, 0]]),
+    # pad rows: written to no block a table owns (a whole block of the
+    # bound past the last valid row is the trash block's)
+    ("valid-below-lq", 8, [6, 8, 30], [3, 1, 2],
+     [[1, 2, 0, 0], [3, 4, 5, 0], [6, 7, 8, 9]]),
+    # two rows share their prefix blocks 1 and 2 and write their own
+    ("shared-prefix-blocks", 4, [16, 17, 4], [4, 3, 4],
+     [[1, 2, 3, 0], [1, 2, 4, 0], [5, 6, 0, 0]]),
+]
+
+
+@pytest.mark.parametrize("layer", [0, 2], ids=["layer0", "last-layer"])
+@pytest.mark.parametrize("lq,positions,valid,tables",
+                         [c[1:] for c in WRITE_CASES],
+                         ids=[c[0] for c in WRITE_CASES])
+def test_pool_write_matches_row_scatter(lq, positions, valid, tables,
+                                        layer):
+    """``_pool_write`` (dynamic_update_slices in a loop, in place)
+    against what the step graph did before: ``pool.at[layer, :, dest,
+    :].set(rows)`` with pad rows sent to the trash block.  Every block
+    but the trash block is bit-equal, in every layer; the blocks two
+    tables share, and every block no table's write reaches, are
+    bit-equal to what they held before the step; what the old write
+    sent to the trash block (pad rows, an all-zero table's rows) is now
+    written nowhere."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.models.transformer_lm import _pool_write, _write_plan
+
+    B, H, dh, bs, blocks, layers = 3, 2, 4, 8, 10, 3
+    rs = np.random.RandomState(lq)
+    pools = [jnp.asarray(rs.randn(layers, H, blocks * bs, dh)
+                         .astype(np.float32)) for _ in range(2)]
+    fresh = [jnp.asarray(rs.randn(B, H, lq, dh).astype(np.float32))
+             for _ in range(2)]
+    tbl = jnp.asarray(tables, jnp.int32)
+    pos = jnp.asarray(positions, jnp.int32)
+    val = jnp.asarray(valid, jnp.int32)
+
+    r = np.arange(lq)
+    p = np.asarray(positions)[:, None] + r[None, :]
+    # a pad row may lie past the table's width: jnp's gather clamps
+    col = np.minimum(p // bs, len(tables[0]) - 1)
+    dest = np.asarray(tables)[np.arange(B)[:, None], col] * bs + p % bs
+    real = r[None, :] < np.asarray(valid)[:, None]
+    dest = np.where(real, dest, p % bs).reshape(-1)
+    want = [np.asarray(pool.at[layer, :, dest, :].set(
+        jnp.transpose(f, (0, 2, 1, 3)).reshape(B * lq, H, dh)))
+        for pool, f in zip(pools, fresh)]
+
+    got = jax.jit(lambda pk, pv, k, v: _pool_write(
+        pk, pv, layer, k, v, _write_plan(tbl, pos, val, lq, bs),
+        bs))(*pools, *fresh)
+    written = set((dest[real.reshape(-1)] // bs).tolist())
+    for g, w, before in zip(got, want, pools):
+        g, before = np.asarray(g), np.asarray(before)
+        assert np.array_equal(g[:, :, bs:], w[:, :, bs:])
+        untouched = [0] + [b for b in range(blocks) if b not in written]
+        for b in untouched:
+            assert np.array_equal(g[:, :, b * bs:(b + 1) * bs],
+                                  before[:, :, b * bs:(b + 1) * bs]), b
 
 
 # ---------------------------------------------------------------------------
@@ -376,6 +465,22 @@ def test_paged_telemetry_gauges_counters_and_drop():
         eng.close()
     after = metrics.registry().render_prometheus()
     assert ("serve_kv_pool_blocks_used%s" % lbl) not in after
+
+
+def test_paged_store_reports_program_scratch(paged_registry):
+    """``stats()['program_temp_bytes']`` names every resident step
+    program with the scratch the compiler gave it — what an operator
+    holds against one layer of the pool to see that no program carries
+    a second one (docs/architecture/decode_engine.md)."""
+    st = paged_registry.gen_store("m").stats()
+    rows = st["program_temp_bytes"]
+    assert [tuple(r[:3]) for r in rows] == \
+        [tuple(r) for r in st["programs_resident"]]
+    # the warmed store: a decode and a chunk program a batch bucket
+    assert {(r[1], r[2]) for r in rows} >= {(bb, lq)
+                                            for bb in BATCH_BUCKETS
+                                            for lq in (1, 8)}
+    assert all(isinstance(r[3], int) and r[3] >= 0 for r in rows)
 
 
 # ---------------------------------------------------------------------------
