@@ -18,7 +18,10 @@ oracle:
   kernels off;
 * ``serve/lm`` — that checkpoint behind a ``GenerationEngine`` on the
   default paged plane (plus one request through ``HttpFrontDoor``),
-  fp32 and int8 weights, against one-shot dense logits.
+  fp32 and int8 weights, against one-shot dense logits;
+* ``deepseek-v3``'s two kernels (latent attention over a paged pool,
+  the held experts' grouped product) against their dense twins at the
+  published widths, so that every kernel the benchmark runs is covered.
 
 ``--chips 4`` runs only ``train/resnet50-dp``: ``Module.fit`` over
 ``[mx.tpu(i) for i in range(4)]`` with ``kvstore="device"`` at global
@@ -55,6 +58,13 @@ FULL = {
     "serve": {"prompt_lens": (17, 45, 96, 130, 300, 333, 512, 700),
               "shared_prefix": 256, "max_tokens": 32},
     "dp": {"chips": 4, "batch": 128, "steps": 5},
+    # DeepSeek-V3's published widths (benchmark/configs/deepseek-v3.json):
+    # 128 heads over a 512 + 64 latent row stored 640 wide, SwiGLU
+    # experts 7168 x 2048 of which 16 are held, 8 of 256 a token
+    "deepseek": {"heads": 128, "rank": 512, "rope": 64, "row": 640,
+                 "kv_block": 64, "contexts": (37, 1500, 4100, 6591),
+                 "chunk": 64, "hidden": 7168, "expert": 2048, "held": 16,
+                 "routed": 256, "top_k": 8, "tokens": (64, 2048)},
 }
 
 # Stated tolerances, each several times what the chip showed (PERF.md,
@@ -69,6 +79,8 @@ TOL_GRAD_NORM = 2e-3  # first-step gradient norm, on vs off, relative
 TOL_GRAD_DIFF = 1e-1  # |g_on - g_off| / |g_off|, all weights
 TOL_LOGIT = 5e-2      # serving logits vs the one-shot dense reference
 TOL_DP_LOSS = 1e-2    # per-step loss, four chips vs one, relative
+TOL_LATENT = 3e-2     # bfloat16 o_lat, kernel vs twin, of its largest value
+TOL_EXPERTS = 2e-2    # bfloat16 experts' part, grouped vs masked loop, same
 
 FUTURE_TIMEOUT_S = 600.0
 
@@ -598,12 +610,96 @@ def serve_lm(sizes, state, kernels):
     return ph.done()
 
 
+def kernels_deepseek(sizes, state, kernels):
+    """The two kernels ``deepseek-v3``'s cell runs, each against its
+    dense XLA twin at the published widths, in bfloat16: latent
+    attention over a paged pool (a decode step and a prompt chunk, ragged
+    contexts, one shared block) and the held experts' grouped product (a
+    decode step's handful of tokens and a chunk's thousands)."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import moe
+    from mxnet_tpu.ops.attention import mla_attention_paged
+    from mxnet_tpu.pallas_ops import dispatch
+    from mxnet_tpu.pallas_ops.mla_attention import mla_attention_reference
+    cfg = sizes["deepseek"]
+    ph = _Phase("kernels/deepseek-v3", dict(cfg), state)
+    rs = np.random.RandomState(state["seed"])
+    bs, H, W, rank = cfg["kv_block"], cfg["heads"], cfg["row"], cfg["rank"]
+    ctx = list(cfg["contexts"])
+    B = len(ctx)
+    T = -(-(max(ctx) + cfg["chunk"]) // bs)
+    tables = np.zeros((B, T), np.int32)
+    nxt = 2
+    for b, n in enumerate(ctx):
+        for j in range(-(-(n + cfg["chunk"]) // bs)):
+            tables[b, j] = 1 if j == 0 else nxt   # block 1 is shared
+            nxt += j > 0
+    pool = np.zeros((2, 1, nxt * bs, W), np.float32)
+    pool[..., :rank + cfg["rope"]] = rs.randn(
+        2, 1, nxt * bs, rank + cfg["rope"])
+    pool = jnp.asarray(pool, jnp.bfloat16)
+    scale = (rank / 4 + cfg["rope"]) ** -0.5
+    before = dispatch.dispatch_stats()
+    for lq in (1, cfg["chunk"]):
+        q = np.zeros((B, H, lq, W), np.float32)
+        q[..., :rank + cfg["rope"]] = rs.randn(B, H, lq,
+                                               rank + cfg["rope"]) / 4
+        q = jnp.asarray(q, jnp.bfloat16)
+        args = (q, pool, 1, jnp.asarray(tables), jnp.asarray(ctx), bs,
+                rank, scale)
+        got = jax.jit(lambda q, pool, t, p: mla_attention_paged(
+            q, pool, 1, t, p, bs, rank, scale))(q, pool, args[3], args[4])
+        want = mla_attention_reference(*args)
+        gap = float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                    - want.astype(jnp.float32))))
+        top = float(jnp.max(jnp.abs(want.astype(jnp.float32))))
+        ph.check(gap <= TOL_LATENT * top,
+                 "latent attention, %d queries a row: kernel = twin" % lq,
+                 (gap, top))
+        ph.note(**{"mla_gap_lq%d" % lq: gap, "mla_top_lq%d" % lq: top})
+    D, F, held = cfg["hidden"], cfg["expert"], cfg["held"]
+    gu = jnp.asarray(rs.randn(held, D, 2 * F) / np.sqrt(D), jnp.bfloat16)
+    down = jnp.asarray(rs.randn(held, F, D) / np.sqrt(F), jnp.bfloat16)
+    for n in cfg["tokens"]:
+        x = jnp.asarray(rs.randn(n, D), jnp.bfloat16)
+        experts = jnp.asarray(np.stack(
+            [rs.permutation(cfg["routed"])[:cfg["top_k"]]
+             for _ in range(n)]), jnp.int32)
+        weights = jnp.asarray(rs.uniform(0.1, 0.6, experts.shape),
+                              jnp.float32)
+        live = jnp.asarray(rs.uniform(size=n) < 0.9)
+        got, counts = jax.jit(moe.moe_experts)(x, gu, down, experts,
+                                               weights, live)
+        want, want_counts = jax.jit(moe.moe_experts_reference)(
+            x, gu, down, experts, weights, live)
+        gap = float(jnp.max(jnp.abs(got - want)))
+        top = float(jnp.max(jnp.abs(want)))
+        ph.check(np.array_equal(np.asarray(counts),
+                                np.asarray(want_counts)),
+                 "experts, %d tokens: the same counts" % n)
+        ph.check(gap <= TOL_EXPERTS * top,
+                 "experts, %d tokens: grouped product = masked loop" % n,
+                 (gap, top))
+        ph.note(**{"moe_gap_n%d" % n: gap, "moe_top_n%d" % n: top,
+                   "moe_assignments_n%d" % n: int(np.asarray(counts)
+                                                   .sum())})
+    if kernels:
+        routed = dispatch.dispatch_stats()
+        for kind in ("LatentAttentionPaged", "MoEExperts"):
+            ph.check(routed.get(kind, 0) > before.get(kind, 0),
+                     "%s routed to its kernel" % kind, routed)
+    return ph.done()
+
+
 # ---------------------------------------------------------------------------
 # driver
 # ---------------------------------------------------------------------------
 PHASES = {"train/resnet50": train_resnet50, "train/lm": train_lm,
-          "serve/lm": serve_lm, "train/resnet50-dp": train_resnet50_dp}
-ONE_CHIP = ("train/resnet50", "train/lm", "serve/lm")
+          "serve/lm": serve_lm, "train/resnet50-dp": train_resnet50_dp,
+          "kernels/deepseek-v3": kernels_deepseek}
+ONE_CHIP = ("train/resnet50", "train/lm", "serve/lm",
+            "kernels/deepseek-v3")
 FOUR_CHIPS = ("train/resnet50-dp",)
 
 

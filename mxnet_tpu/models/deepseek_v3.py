@@ -1,0 +1,460 @@
+"""DeepSeek-V3's decoder as a decode-mode graph for the paged serving
+plane: multi-head LATENT attention (MLA) over a latent block pool, and
+expert layers that hold this chip's SHARE of the routed experts.
+
+Source: https://huggingface.co/deepseek-ai/DeepSeek-V3 (``config.json``;
+technical report arXiv:2412.19437; the released ``inference/model.py``).
+``x`` a token's hidden row, RMSNorm everywhere, no bias but the router's
+correction bias:
+
+* **MLA.** ``c_q = RMSNorm(x W_qa)``; ``q = c_q W_qb`` -> heads x
+  (nope | rope).  ``[c_kv | k_r] = x W_kva``; ``c_kv <- RMSNorm(c_kv)``;
+  ``k_r <- RoPE(k_r)`` (one row for all heads), ``q_rope <-
+  RoPE(q_rope)``.  The cache holds ``[c_kv | k_r]`` a token a layer.
+  What runs is the ABSORBED form, for every query length alike:
+  ``q_abs = q_nope W_kvb[k]^T``, ``score = (q_abs . c_kv + q_rope . k_r)
+  s``, ``o_lat = softmax . c_kv``, ``out = (o_lat W_kvb[v]) W_o``, with
+  ``s = (nope + rope)^-0.5 m^2``, ``m = 0.1 ln(factor) + 1`` (YaRN).
+  RoPE turns adjacent pairs, frequencies YaRN-corrected.
+* **Dense layers** (the first ``first_k_dense_replace``): SwiGLU.
+* **Expert layers.** ``sigma = sigmoid(h W_g^T)`` in fp32 over ALL
+  ``router_width`` experts, group-limited top-k (``ops/moe.py``), ``y =
+  shared(h) + sum w_e expert_e(h)`` where the sum runs over the picked
+  experts among the ``n_routed_experts`` HELD here (ids ``0 .. held-1``):
+  the chip's part of an expert-parallel layer, without its exchange.
+* **Head.** RMSNorm, untied head over this chip's vocabulary slice.
+
+The multi-token-prediction module is not part of inference (report
+section 2.2) and is not here.  Norms, router scores, RoPE and the
+softmax run in fp32; products in the weights' dtype, accumulated fp32.
+"""
+from __future__ import annotations
+
+import math
+import re
+
+import numpy as np
+
+from ..base import MXNetError
+from .paged import pool_write, write_plan
+from .transformer_lm import _embed
+
+__all__ = ["serving_spec", "param_shapes", "random_params",
+           "required_params", "matmul_weights", "pack_params",
+           "quantize_params", "init_pool", "latent_width",
+           "paged_step_apply", "paged_step", "rope_frequencies",
+           "softmax_scale", "OFFERS", "AUX_COUNTERS"]
+
+# what of the serving plane this model can be put on besides the paged
+# plane with in-graph or host sampling (program_store asks)
+OFFERS = frozenset()
+# the counters a step returns beside its logits, in order
+AUX_COUNTERS = ("moe_tokens", "moe_local_assignments",
+                "moe_expert_load_max", "moe_expert_steps",
+                "moe_experts_touched")
+
+_INT_KEYS = ("num_hidden_layers", "first_k_dense_replace", "hidden_size",
+             "num_attention_heads", "q_lora_rank", "kv_lora_rank",
+             "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+             "intermediate_size", "moe_intermediate_size",
+             "n_routed_experts", "router_width", "n_shared_experts",
+             "num_experts_per_tok", "n_group", "topk_group", "vocab_size")
+_ROPE_KEYS = ("beta_fast", "beta_slow", "factor",
+              "original_max_position_embeddings")
+
+
+def serving_spec(spec):
+    """Validated architecture spec (the published ``config.json`` keys;
+    ``n_routed_experts`` counts the experts HELD here, ``router_width``
+    all the experts the router scores)."""
+    spec = dict(spec)
+    out = {"arch": "deepseek_v3"}
+    missing = [k for k in _INT_KEYS + ("rope_scaling",) if k not in spec]
+    if missing:
+        raise MXNetError("deepseek_v3 spec is missing %s" % missing)
+    for k in _INT_KEYS:
+        out[k] = int(spec[k])
+    out["routed_scaling_factor"] = float(spec.get(
+        "routed_scaling_factor", 1.0))
+    out["rms_norm_eps"] = float(spec.get("rms_norm_eps", 1e-6))
+    out["rope_theta"] = float(spec.get("rope_theta", 10000.0))
+    rope = dict(spec["rope_scaling"])
+    if [k for k in _ROPE_KEYS if k not in rope]:
+        raise MXNetError("deepseek_v3 rope_scaling needs %s"
+                         % (_ROPE_KEYS,))
+    out["rope_scaling"] = {k: float(rope[k]) for k in _ROPE_KEYS}
+    if out["router_width"] % out["n_group"] or \
+            not 0 < out["n_routed_experts"] <= out["router_width"]:
+        raise MXNetError(
+            "router_width %d must divide into n_group %d and hold the "
+            "%d experts kept here" % (out["router_width"], out["n_group"],
+                                      out["n_routed_experts"]))
+    if out["topk_group"] > out["n_group"] or out["num_experts_per_tok"] \
+            > out["topk_group"] * (out["router_width"] // out["n_group"]):
+        raise MXNetError("top-%d over %d of %d groups cannot be picked"
+                         % (out["num_experts_per_tok"], out["topk_group"],
+                            out["n_group"]))
+    if out["qk_rope_head_dim"] % 2 or not \
+            0 <= out["first_k_dense_replace"] <= out["num_hidden_layers"]:
+        raise MXNetError("deepseek_v3 spec: odd rotary width or more "
+                         "dense layers than layers")
+    return out
+
+
+def _is_dense(spec, i):
+    return i < spec["first_k_dense_replace"]
+
+
+def param_shapes(spec):
+    """name -> shape of the checkpoint's leaves: every matrix ``(out,
+    in)``, each routed expert's three matrices leaves of their own
+    (``l<i>_e<j>_gate_weight`` ...; :func:`pack_params` stacks them)."""
+    D, H = spec["hidden_size"], spec["num_attention_heads"]
+    rq, r = spec["q_lora_rank"], spec["kv_lora_rank"]
+    dn, dr, dv = (spec["qk_nope_head_dim"], spec["qk_rope_head_dim"],
+                  spec["v_head_dim"])
+    F = spec["moe_intermediate_size"]
+    out = {"embed_weight": (spec["vocab_size"], D),
+           "final_norm_gamma": (D,),
+           "head_weight": (spec["vocab_size"], D)}
+    for i in range(spec["num_hidden_layers"]):
+        p = "l%d_" % i
+        out.update({
+            p + "attn_norm_gamma": (D,), p + "q_a_weight": (rq, D),
+            p + "q_norm_gamma": (rq,),
+            p + "q_b_weight": (H * (dn + dr), rq),
+            p + "kv_a_weight": (r + dr, D), p + "kv_norm_gamma": (r,),
+            p + "kv_b_weight": (H * (dn + dv), r),
+            p + "o_weight": (D, H * dv), p + "ffn_norm_gamma": (D,)})
+        if _is_dense(spec, i):
+            I = spec["intermediate_size"]
+            out.update({p + "gate_weight": (I, D), p + "up_weight": (I, D),
+                        p + "down_weight": (D, I)})
+            continue
+        S = F * spec["n_shared_experts"]
+        out.update({p + "router_weight": (spec["router_width"], D),
+                    p + "router_bias": (spec["router_width"],),
+                    p + "shared_gate_weight": (S, D),
+                    p + "shared_up_weight": (S, D),
+                    p + "shared_down_weight": (D, S)})
+        for e in range(spec["n_routed_experts"]):
+            q = "%se%d_" % (p, e)
+            out.update({q + "gate_weight": (F, D), q + "up_weight": (F, D),
+                        q + "down_weight": (D, F)})
+    return out
+
+
+def _packed(spec, i):
+    return ("l%d_experts_gate_up" % i, "l%d_experts_down" % i)
+
+
+def required_params(spec):
+    """The leaves a step reads: the checkpoint's, with each expert
+    layer's routed experts as the two stacks of :func:`pack_params`."""
+    names = [n for n in param_shapes(spec)
+             if not re.match(r"l\d+_e\d+_", n)]
+    for i in range(spec["num_hidden_layers"]):
+        if not _is_dense(spec, i):
+            names += _packed(spec, i)
+    return names
+
+
+def matmul_weights(spec):
+    """The leaves int8 weight-only serving quantizes: every matmul
+    weight, the routed experts' stacks among them (norm scales and the
+    router's correction bias stay as they are)."""
+    return [n for n in required_params(spec)
+            if n.endswith("_weight") or "_experts_" in n]
+
+
+def pack_params(params, spec):
+    """Stack each expert layer's routed experts for the grouped
+    product, IN PLACE: ``l<i>_e<j>_gate_weight``/``_up_weight`` ``(F,
+    D)`` become ``l<i>_experts_gate_up`` ``(held, D, 2F)`` (input
+    dimension first, gate then up) and ``_down_weight`` ``(D, F)``
+    ``l<i>_experts_down`` ``(held, F, D)``.  The per-expert leaves are
+    popped from ``params`` as each stack is made: at the served size the
+    experts are 5.6 GB of a 16 GB chip, and a caller who hands over its
+    only reference never holds two copies of more than one layer's.
+    A dict that is already packed is left as it is."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def stack_t(*ws):
+        return jnp.stack([w.T for w in ws])
+
+    E = spec["n_routed_experts"]
+    for i in range(spec["num_hidden_layers"]):
+        gu, down = _packed(spec, i)
+        if _is_dense(spec, i) or gu in params:
+            continue
+        names = ["l%d_e%d_%%s_weight" % (i, e) for e in range(E)]
+        gate = stack_t(*[params.pop(n % "gate") for n in names])
+        up = stack_t(*[params.pop(n % "up") for n in names])
+        params[gu] = jnp.concatenate([gate, up], axis=2)
+        del gate, up
+        params[down] = stack_t(*[params.pop(n % "down") for n in names])
+    return params
+
+
+def quantize_params(params, spec):
+    """int8 weight-only transform of a PACKED param dict: every leaf of
+    :func:`matmul_weights` becomes a ``QuantizedWeight`` with one
+    absmax scale an output channel (a row of an ``(out, in)`` matrix, a
+    column of an expert's ``(in, out)`` stack); on the device, a leaf at
+    a time."""
+    import jax
+    import jax.numpy as jnp
+    from ..pallas_ops.dequant_matmul import QuantizedWeight
+
+    @jax.jit
+    def quant(w):
+        w = w.astype(jnp.float32)
+        # axis 1 is the input dimension of both layouts
+        absmax = jnp.max(jnp.abs(w), axis=1, keepdims=True)
+        scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
+        codes = jnp.clip(jnp.rint(w / scale), -127, 127).astype(jnp.int8)
+        return codes, (scale[:, 0] if w.ndim == 2 else scale)
+
+    which = set(matmul_weights(spec))
+    return {k: QuantizedWeight(*quant(jnp.asarray(v))) if k in which
+            else v for k, v in params.items()}
+
+
+def _plain(w, dtype):
+    """A weight as a plain array of ``dtype`` (a QuantizedWeight is
+    dequantized: the einsums over ``kv_b`` and the grouped product take
+    no code/scale pair)."""
+    import jax.numpy as jnp
+    from ..pallas_ops.dequant_matmul import QuantizedWeight
+    if isinstance(w, QuantizedWeight):
+        s = jnp.asarray(w.scales, jnp.float32)
+        s = s[:, None] if s.ndim == 1 else s
+        return (w.codes.astype(jnp.float32) * s).astype(dtype)
+    return w.astype(dtype)
+
+
+def random_params(spec, seed=0):
+    """Seeded random weights with :func:`param_shapes`' names: matrices
+    N(0, 1 / fan_in), norm scales near one, the router's bias small."""
+    rs = np.random.RandomState(seed)
+    out = {}
+    for name, shape in sorted(param_shapes(spec).items()):
+        if name == "embed_weight":
+            leaf = rs.normal(0, 1.0, shape)
+        elif name.endswith("_weight"):
+            leaf = rs.normal(0, 1.0 / math.sqrt(shape[1]), shape)
+        elif name.endswith("_gamma"):
+            leaf = 1.0 + 0.1 * rs.normal(size=shape)
+        else:
+            leaf = 0.02 * rs.normal(size=shape)
+        out[name] = np.asarray(leaf, np.float32)
+    return out
+
+
+def latent_width(spec):
+    """Width of a latent pool row: ``kv_lora_rank + qk_rope_head_dim``
+    values (576) in whole 128-lane tiles (640).  The TPU tiles the minor
+    dimension by 128, so a row-major row of 576 occupies 640 either
+    way; declared at 576, XLA keeps the pool tokens-minor instead
+    (layout ``{2,3,1,0}``) and copies all of it into row-major for the
+    kernel in every program."""
+    w = spec["kv_lora_rank"] + spec["qk_rope_head_dim"]
+    return -(-w // 128) * 128
+
+
+def init_pool(spec, num_blocks, block_size, dtype="float32"):
+    """The zeroed latent pool: ONE leaf ``(num_layers, 1, num_blocks *
+    block_size, latent_width)``, a row ``[c_kv | k_r | 0...]`` — key for
+    every head and, its first ``kv_lora_rank`` values, value too.
+    Block 0 is the reserved trash block, as in ``transformer_lm``."""
+    import jax.numpy as jnp
+    return (jnp.zeros((spec["num_hidden_layers"], 1,
+                       int(num_blocks) * int(block_size),
+                       latent_width(spec)), dtype),)
+
+
+def rope_frequencies(spec):
+    """The rotary frequencies, YaRN-corrected as the released
+    ``inference/model.py`` does (``precompute_freqs_cis``): ``(rope /
+    2,)`` float32."""
+    dim = spec["qk_rope_head_dim"]
+    base, sc = spec["rope_theta"], spec["rope_scaling"]
+    orig = sc["original_max_position_embeddings"]
+    freqs = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def correction(rotations):
+        return dim * math.log(orig / (rotations * 2 * math.pi)) / (
+            2 * math.log(base))
+
+    low = max(math.floor(correction(sc["beta_fast"])), 0)
+    high = min(math.ceil(correction(sc["beta_slow"])), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0, 1)
+    smooth = 1 - ramp
+    return (freqs / sc["factor"] * (1 - smooth) + freqs * smooth) \
+        .astype(np.float32)
+
+
+def softmax_scale(spec):
+    """``(nope + rope)^-0.5 m^2``, ``m = 0.1 ln(factor) + 1``."""
+    m = 0.1 * math.log(spec["rope_scaling"]["factor"]) + 1.0
+    return (spec["qk_nope_head_dim"] + spec["qk_rope_head_dim"]) ** -0.5 \
+        * m * m
+
+
+def _rope(x, cos, sin):
+    """Turn adjacent pairs of the last axis: x ``(..., rope)`` fp32,
+    cos/sin broadcastable ``(..., rope / 2)``."""
+    import jax.numpy as jnp
+    pair = x.reshape(x.shape[:-1] + (-1, 2))
+    a, b = pair[..., 0], pair[..., 1]
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                     axis=-1).reshape(x.shape)
+
+
+def _rms(x, gamma, eps):
+    import jax.numpy as jnp
+    from ..ops.nn import _rms_fc
+    return _rms_fc({"eps": eps}, x.astype(jnp.float32),
+                   gamma.astype(jnp.float32))
+
+
+def _mm(x2d, w, out=None):
+    """``x @ w^T`` in ``x``'s dtype, accumulated fp32 (``out``: handed
+    back in that dtype instead: router scores and logits want fp32).
+    An int8 weight is dequantized in the graph and multiplied like any
+    other: the fused ``dequant_matmul`` kernel tiles by 128 whatever
+    the widths, and at 7168 x 18432 that is 8,064 grid steps a decode
+    row block — through it a decode step of this model took 0.8 s (my
+    chip run, PR 26)."""
+    import jax.numpy as jnp
+    return jnp.matmul(x2d, _plain(w, x2d.dtype).T,
+                      preferred_element_type=out or x2d.dtype)
+
+
+def _swiglu_ffn(f, gate, up, down):
+    import jax
+    import jax.numpy as jnp
+    g = _mm(f, gate).astype(jnp.float32)
+    act = (g * jax.nn.sigmoid(g) * _mm(f, up).astype(jnp.float32))
+    return _mm(act.astype(f.dtype), down).astype(jnp.float32)
+
+
+def paged_step_apply(params, pool, tables, tokens, positions, valid, spec,
+                     block_size, all_logits=False):
+    """One PAGED step over the latent pool — ``transformer_lm.
+    paged_step_apply``'s contract with one pool leaf: tokens ``(B, Lq)``
+    (``Lq = 1`` a decode step), positions/valid ``(B,)``, tables ``(B,
+    T)`` over the pool of :func:`init_pool`; each layer writes the
+    chunk's latent rows in place (``paged.pool_write``: no scatter, no
+    slice of the pool) and attends through the ``mla_attention_paged``
+    door, ONE absorbed-form algorithm for every ``Lq``.  ``params`` is
+    a PACKED dict (:func:`pack_params`), plain or int8.
+
+    Returns ``(logits, pool, counts)``: logits ``(B, vocab)`` fp32 at
+    each row's last valid position (``all_logits``: ``(B, Lq, vocab)``),
+    and :data:`AUX_COUNTERS` as one int32 vector over the step's LIVE
+    tokens (valid rows of sequences whose table owns a block): tokens
+    routed x expert layers, picks that fell on held experts, the
+    fullest held expert's count summed over the layers, expert layers,
+    held experts that got a token summed over the layers."""
+    import jax
+    import jax.numpy as jnp
+    from ..ops.attention import mla_attention_paged
+    from ..ops.moe import moe_experts, route_grouped
+
+    L, D = spec["num_hidden_layers"], spec["hidden_size"]
+    H = spec["num_attention_heads"]
+    r, dn, dr, dv = (spec["kv_lora_rank"], spec["qk_nope_head_dim"],
+                     spec["qk_rope_head_dim"], spec["v_head_dim"])
+    eps = spec["rms_norm_eps"]
+    bs = int(block_size)
+    B, Lq = tokens.shape
+    N = B * Lq
+    W = pool.shape[3]
+    f32 = jnp.float32
+    cdt = params["final_norm_gamma"].dtype      # the weights' dtype
+    tables = jnp.asarray(tables, jnp.int32)
+    positions = jnp.asarray(positions, jnp.int32)
+    valid = jnp.asarray(valid, jnp.int32)
+    plan = write_plan(tables, positions, valid, Lq, bs)
+    rows = jnp.arange(Lq, dtype=jnp.int32)
+    live = ((tables[:, :1] != 0) & (rows[None] < valid[:, None])) \
+        .reshape(N)
+    angle = (positions[:, None] + rows[None]).astype(f32)[..., None] \
+        * jnp.asarray(rope_frequencies(spec))                # (B, Lq, dr/2)
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    scale = softmax_scale(spec)
+    counts = jnp.zeros((len(AUX_COUNTERS),), jnp.int32)
+
+    x = _embed(params["embed_weight"], tokens).astype(f32)   # (B, Lq, D)
+    for i in range(L):
+        p = {k[len("l%d_" % i):]: v for k, v in params.items()
+             if k.startswith("l%d_" % i)}
+        h = _rms(x, p["attn_norm_gamma"], eps).astype(cdt).reshape(N, D)
+        cq = _rms(_mm(h, p["q_a_weight"]), p["q_norm_gamma"], eps)
+        q = _mm(cq.astype(cdt), p["q_b_weight"]).reshape(B, Lq, H, dn + dr)
+        kv = _mm(h, p["kv_a_weight"]).astype(f32).reshape(B, Lq, r + dr)
+        latent = jnp.concatenate(
+            [_rms(kv[..., :r], p["kv_norm_gamma"], eps),
+             _rope(kv[..., r:], cos, sin),
+             jnp.zeros((B, Lq, W - r - dr), f32)], axis=-1)
+        pool, = pool_write((pool,), i, (latent[:, None],), plan, bs)
+        wkv = _plain(p["kv_b_weight"], cdt).reshape(H, dn + dv, r)
+        q_abs = jnp.einsum("blhd,hdc->bhlc", q[..., :dn], wkv[:, :dn],
+                           preferred_element_type=f32)
+        q_rope = _rope(q[..., dn:].astype(f32), cos[:, :, None],
+                       sin[:, :, None])
+        query = jnp.concatenate(
+            [q_abs, jnp.transpose(q_rope, (0, 2, 1, 3)),
+             jnp.zeros((B, H, Lq, W - r - dr), f32)], axis=-1)
+        o_lat = mla_attention_paged(query.astype(pool.dtype), pool, i,
+                                    tables, positions, bs, r, scale)
+        o = jnp.einsum("bhlc,hdc->blhd", o_lat.astype(cdt), wkv[:, dn:],
+                       preferred_element_type=f32)
+        x = x + _mm(o.astype(cdt).reshape(N, H * dv), p["o_weight"]) \
+            .astype(f32).reshape(B, Lq, D)
+
+        f = _rms(x, p["ffn_norm_gamma"], eps).astype(cdt).reshape(N, D)
+        if _is_dense(spec, i):
+            y = _swiglu_ffn(f, p["gate_weight"], p["up_weight"],
+                            p["down_weight"])
+        else:
+            scores = jax.nn.sigmoid(_mm(f, p["router_weight"], f32))
+            experts, weights = route_grouped(
+                scores, p["router_bias"].astype(f32),
+                spec["num_experts_per_tok"], spec["n_group"],
+                spec["topk_group"], spec["routed_scaling_factor"])
+            y, per = moe_experts(
+                f, _plain(p["experts_gate_up"], cdt),
+                _plain(p["experts_down"], cdt), experts, weights, live)
+            y = y + _swiglu_ffn(f, p["shared_gate_weight"],
+                                p["shared_up_weight"],
+                                p["shared_down_weight"])
+            counts = counts + jnp.stack(
+                [jnp.sum(live, dtype=jnp.int32), jnp.sum(per),
+                 jnp.max(per), jnp.int32(1),
+                 jnp.sum(per > 0, dtype=jnp.int32)])
+        x = x + y.reshape(B, Lq, D)
+    hN = _rms(x, params["final_norm_gamma"], eps).astype(cdt)
+    if all_logits:
+        logits = _mm(hN.reshape(N, D), params["head_weight"], f32).reshape(
+            B, Lq, spec["vocab_size"])
+    else:
+        logits = _mm(hN[jnp.arange(B), valid - 1],
+                     params["head_weight"], f32)
+    return logits.astype(f32), pool, counts
+
+
+def paged_step(params, pools, tables, tokens, positions, valid, spec,
+               block_size, scales=None, all_logits=False):
+    """The program store's seam: ``(logits, pool leaves, counters)``."""
+    if scales is not None:
+        raise MXNetError("deepseek_v3 has no int8 latent pool")
+    logits, pool, counts = paged_step_apply(
+        params, pools[0], tables, tokens, positions, valid, spec,
+        block_size, all_logits=all_logits)
+    return logits, (pool,), counts

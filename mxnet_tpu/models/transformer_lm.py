@@ -16,11 +16,14 @@ standard decoder recipe), ReLU FFN at 4x width.  ``data`` is a
 next-token targets of the same shape.
 """
 from .. import symbol as sym
+from .paged import pool_write, write_plan as _write_plan
 
 __all__ = ["get_symbol", "lm_spec", "random_params", "init_cache",
            "init_pool", "init_scale_pool", "prefill_apply",
            "decode_apply", "paged_step_apply", "quantize_lm_params",
-           "lm_matmul_weights"]
+           "lm_matmul_weights", "serving_spec", "required_params",
+           "pack_params", "quantize_params", "paged_step", "OFFERS",
+           "AUX_COUNTERS"]
 
 
 def _attention_block(x, seq_len, num_hidden, num_heads, name):
@@ -347,87 +350,9 @@ def decode_apply(params, cache_k, cache_v, tokens, lengths, spec):
     return logits.astype(jnp.float32), cache_k, cache_v
 
 
-def _write_plan(tables, positions, valid, Lq, block_size):
-    """Where a step's fresh K/V rows go in a layer of the pool: the
-    same for every layer, so computed once a program.
-
-    ``Lq == 1`` (decode) is one row a sequence.  A chunk is written per
-    (sequence, affected block) — at most ``A = (Lq + bs - 2) // bs + 1``
-    blocks, whatever its start.  Only the writes that land in a block
-    some table owns are live: pad rows, a block of the bound past the
-    last valid row and the rows of a slot outside the dispatch (an
-    all-zero table) would only reach the trash block 0, which nothing
-    reads unmasked, and are written nowhere.  Returns ``(live count,
-    columns)``, the columns sorted live first: the sequence and the
-    pool row the write starts at and, for a chunk, the chunk rows
-    ``[lo, hi)`` that land in the block, whose row 0 is chunk row
-    ``lo`` (negative where the chunk starts inside the block)."""
-    import jax.numpy as jnp
-
-    B, T = tables.shape
-    bs = int(block_size)
-    rows = jnp.arange(B, dtype=jnp.int32)
-    if Lq == 1:
-        phys = tables[rows, jnp.minimum(positions // bs, T - 1)]
-        live = phys != 0
-        cols = (rows, phys * bs + positions % bs)
-    else:
-        A = (Lq + bs - 2) // bs + 1
-        seq = jnp.repeat(rows, A)                           # (B*A,)
-        log = (positions // bs)[seq] + jnp.tile(
-            jnp.arange(A, dtype=jnp.int32), B)              # logical block
-        phys = tables[seq, jnp.minimum(log, T - 1)]
-        live = (log <= ((positions + valid - 1) // bs)[seq]) \
-            & (log < T) & (phys != 0)
-        cols = (seq, phys * bs, log * bs - positions[seq], valid[seq])
-    order = jnp.argsort(~live, stable=True)                 # live first
-    return (jnp.sum(live, dtype=jnp.int32),
-            tuple(col[order] for col in cols))
-
-
 def _pool_write(pool_k, pool_v, layer, k, v, plan, block_size):
-    """Write a step's fresh K/V — ``k``/``v`` ``(B, H, Lq, dh)`` — into
-    layer ``layer`` of the stacked pools by :func:`_write_plan`'s
-    ``plan``, IN PLACE on the donated arrays: ``dynamic_update_slice``s
-    in one loop over the live writes, never a ``scatter``.  The TPU
-    compiler gives a scatter on the pool a layout of its own
-    (``{3,1,2,0}``) and copies the whole pool into it and back around
-    every program (docs/architecture/decode_engine.md, "The pool stays
-    where it is").
-
-    Decode writes one ``(1, H, 1, dh)`` row a sequence; a chunk reads
-    each affected block, overlays the chunk's valid rows and writes it
-    back.  Blocks are taken in order, so a block two tables share holds
-    exactly what a row-by-row write would leave."""
-    import jax
-    import jax.numpy as jnp
-
-    B, H, Lq, dh = k.shape
-    bs = int(block_size)
-    count, cols = plan
-    fresh = (k.astype(pool_k.dtype), v.astype(pool_v.dtype))
-
-    def write(n, pools):
-        at = (layer, 0, cols[1][n], 0)
-        out = []
-        for pool, new in zip(pools, fresh):
-            new = jax.lax.dynamic_slice_in_dim(new, cols[0][n], 1, 0)
-            if Lq > 1:
-                # the block's rows are a window of bs consecutive chunk
-                # rows starting anywhere in (-bs, Lq): bs rows of margin
-                # either side make it one dynamic_slice
-                lo, hi = cols[2][n], cols[3][n]
-                new = jax.lax.dynamic_slice(
-                    jnp.pad(new, ((0, 0), (0, 0), (bs, bs), (0, 0))),
-                    (0, 0, lo + bs, 0), (1, H, bs, dh))
-                r = lo + jnp.arange(bs, dtype=jnp.int32)
-                new = jnp.where(
-                    ((r >= 0) & (r < hi))[None, None, :, None], new,
-                    jax.lax.dynamic_slice(pool, at, (1, H, bs, dh)))
-            out.append(jax.lax.dynamic_update_slice(pool, new, at))
-        return tuple(out)
-
-    return jax.lax.fori_loop(0, count, write, (pool_k, pool_v))
+    """``paged.pool_write`` over the ``(k, v)`` pool pair."""
+    return pool_write((pool_k, pool_v), layer, (k, v), plan, block_size)
 
 
 def paged_step_apply(params, pool_k, pool_v, tables, tokens, positions,
@@ -591,3 +516,55 @@ def paged_step_apply(params, pool_k, pool_v, tables, tokens, positions,
         return (logits.astype(jnp.float32), pool_k, pool_v,
                 scale_k, scale_v)
     return logits.astype(jnp.float32), pool_k, pool_v
+
+
+# ---------------------------------------------------------------------------
+# The program store's model seam (serving/program_store.py): what a
+# decode-mode model module offers under these names is all the store
+# knows of an architecture.  ``models/deepseek_v3.py`` is the other one.
+# ---------------------------------------------------------------------------
+# planes beside the paged one with in-graph or host sampling
+OFFERS = frozenset(("contiguous", "int8_kv", "draft"))
+# counters a step returns beside its logits (none here)
+AUX_COUNTERS = ()
+
+
+def serving_spec(spec):
+    return lm_spec(**dict(spec))
+
+
+def required_params(spec):
+    names = ["embed_weight", "final_ln_gamma", "final_ln_beta",
+             "pred_weight", "pred_bias"]
+    for i in range(spec["num_layers"]):
+        names += ["blk%d_%s" % (i, k) for k in
+                  ("ln1_gamma", "q_weight", "k_weight", "v_weight",
+                   "proj_weight", "ln2_gamma", "ffn1_weight",
+                   "ffn1_bias", "ffn2_weight", "ffn2_bias")]
+    return names
+
+
+def pack_params(params, spec):
+    """Nothing to restack: the symbol graph's arguments are what the
+    decode-mode graphs read."""
+    return params
+
+
+def quantize_params(params, spec):
+    """:func:`quantize_lm_params` of the floating leaves, on the host."""
+    import jax.numpy as jnp
+    import numpy as np
+    host = {k: np.asarray(v, np.float32)
+            if jnp.issubdtype(jnp.asarray(v).dtype, jnp.floating) else v
+            for k, v in params.items()}
+    return quantize_lm_params(host, spec)
+
+
+def paged_step(params, pools, tables, tokens, positions, valid, spec,
+               block_size, scales=None, all_logits=False):
+    """:func:`paged_step_apply` as ``(logits, donated leaves, None)``:
+    the ``(k, v)`` pools, then the int8 plane's scale pools."""
+    out = paged_step_apply(params, pools[0], pools[1], tables, tokens,
+                           positions, valid, spec, block_size,
+                           scales=scales, all_logits=all_logits)
+    return out[0], tuple(out[1:]), None

@@ -119,6 +119,31 @@ def sdp_attention_paged(query, k_pool, v_pool, layer, tables, positions,
                                      kv_scales=kv_scales)
 
 
+def mla_attention_paged(query, pool, layer, tables, positions,
+                        block_size, rank, scale):
+    """Paged multi-head LATENT attention in the absorbed form: ``[B, H,
+    Lq, D]`` queries ``[q_abs | q_rope]`` against layer ``layer`` (a
+    static int) of the whole stacked latent pool ``(L, 1, num_blocks *
+    block_size, D)``, whose row is key and — its first ``rank`` values
+    — value for every head alike.  Returns ``o_lat [B, H, Lq, rank]``.
+
+    Eligible shapes route to ``mla_paged_attention`` (all heads of a
+    sequence in one Q tile, a latent tile fetched once a sequence);
+    everything else — and ``MXNET_PALLAS=0`` — lowers to
+    ``mla_attention_reference``, the gather + dense twin."""
+    b, h, lq, d = query.shape
+    bs = int(block_size)
+    from ..pallas_ops import dispatch as _pd
+    from ..pallas_ops import mla_attention as _mla
+    if _pd.use_mla_paged("LatentAttentionPaged", b, h, lq,
+                         tables.shape[1] * bs, d, rank, query.dtype, bs):
+        return _mla.mla_paged_attention(
+            query, pool, layer, tables, positions, bs, rank, scale,
+            interpret=_pd.interpret_mode())
+    return _mla.mla_attention_reference(query, pool, layer, tables,
+                                        positions, bs, rank, scale)
+
+
 def _attn_fc(attrs, query, key, value):
     if query.ndim != 4:
         raise MXNetError("DotProductAttention expects [batch, heads, "

@@ -49,9 +49,11 @@ from .softmax_xent import row_block
 
 __all__ = ["mode", "kernels_active", "interpret_mode", "block_rows",
            "block_seq", "fingerprint", "overriding", "use_rowwise",
-           "use_attention", "use_attention_paged", "use_dequant_matmul",
+           "use_attention", "use_attention_paged", "use_mla_paged",
+           "use_moe_experts", "use_dequant_matmul",
            "eligible_rowwise", "eligible_attention",
            "eligible_attention_offset", "eligible_attention_paged",
+           "eligible_mla_paged", "eligible_moe_experts",
            "eligible_dequant_matmul", "dispatch_stats",
            "reset_dispatch_stats"]
 
@@ -250,6 +252,42 @@ def eligible_attention_paged(b, h, lq, lk, d, dtype, block_size):
     return interpret_mode() or bs % 8 == 0
 
 
+def eligible_mla_paged(b, h, lq, lk, d, rank, dtype, block_size):
+    """May an absorbed-form latent attention pattern (every head over
+    one shared latent row a token) run as ``mla_paged_attention``?
+
+    ``d`` is the latent row's width and ``rank`` the part of it that is
+    also the value.  The Q tile is a divisor of ``h * lq`` rows (all
+    heads and queries of a sequence flattened), the latent tile one
+    pool block.  Compiled Mosaic wants both widths in whole 128-lane
+    tiles (an unaligned minor dimension also makes XLA keep the pool
+    tokens-minor and relay it for the call: ``deepseek_v3`` pads its
+    576-value row to 640), a pool block it can tile, and a Q tile it
+    can tile.
+    """
+    bs = int(block_size)
+    if str(dtype) not in _FLOAT_DTYPES or bs < 1:
+        return False
+    if min(int(b), int(h), int(lq), int(lk)) < 1 or \
+            not 1 <= int(rank) <= int(d):
+        return False
+    if interpret_mode():
+        return True
+    rows = int(h) * int(lq)
+    return (int(d) % 128 == 0 and int(rank) % 128 == 0 and bs % 16 == 0
+            and mosaic_block_ok(divisor_block(rows, 512), rows))
+
+
+def eligible_moe_experts(n, d, f, dtype):
+    """May an expert layer's held part run as the sorted, grouped
+    product (``ops/moe.py``, ``jax.lax.ragged_dot``)?  Compiled, the
+    grouped product is a Mosaic kernel that wants both widths in whole
+    128-lane tiles; the interpreter takes any shape."""
+    if str(dtype) not in _FLOAT_DTYPES or min(int(n), int(d), int(f)) < 1:
+        return False
+    return interpret_mode() or (int(d) % 128 == 0 and int(f) % 128 == 0)
+
+
 def eligible_dequant_matmul(m, n, k, dtype):
     """May an ``x (m, k) @ dequant(codes (n, k))^T`` pattern run as the
     fused int8 dequant-matmul kernel (``dequant_matmul.py``)?
@@ -341,6 +379,25 @@ def use_attention_paged(kind, b, h, lq, lk, d, dtype, block_size):
     when taken."""
     if not kernels_active() or not eligible_attention_paged(
             b, h, lq, lk, d, dtype, block_size):
+        return False
+    _note(kind)
+    return True
+
+
+def use_mla_paged(kind, b, h, lq, lk, d, rank, dtype, block_size):
+    """Route decision for a paged latent-attention pattern; counts a
+    route when taken."""
+    if not kernels_active() or not eligible_mla_paged(
+            b, h, lq, lk, d, rank, dtype, block_size):
+        return False
+    _note(kind)
+    return True
+
+
+def use_moe_experts(kind, n, d, f, dtype):
+    """Route decision for an expert layer's grouped product; counts a
+    route when taken."""
+    if not kernels_active() or not eligible_moe_experts(n, d, f, dtype):
         return False
     _note(kind)
     return True

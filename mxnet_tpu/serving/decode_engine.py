@@ -461,7 +461,9 @@ class _PagedModelState:
         self.store = store
         self.pool = _BlockPool(store.pool_blocks)
         self.prefix = _PrefixStore(self.pool, store.kv_block)
-        self.pool_k, self.pool_v = store.new_pool()
+        # the pool is an opaque tuple of donated leaves, as the model
+        # shapes it: (k, v) for the LM, (latent,) for deepseek_v3
+        self.pools = store.new_pool()
         # int8 plane: the per-(layer, head, block) fp32 absmax scale
         # pools ride beside the code pools through every dispatch
         self.scales = (store.new_scale_pool() if store.kv_int8
@@ -488,7 +490,7 @@ class _PagedModelState:
         self.draft = draft
         self.spec_k = int(spec_k)
         if draft is not None:
-            self.dpool_k, self.dpool_v = draft.new_pool()
+            self.dpools = draft.new_pool()
             self.dscales = (draft.new_scale_pool() if draft.kv_int8
                             else None)
             self.dlen = np.zeros(0, np.int32)
@@ -502,6 +504,29 @@ class _PagedModelState:
             self.spec_probe = _SPEC_PROBE_EVERY
             self.spec_probe_every = _SPEC_PROBE_EVERY
             self.spec_forced = False
+
+    @staticmethod
+    def _split(out, head, pools, scales):
+        """A program's flat return — ``head`` leading results, the
+        pool's leaves, the int8 plane's two scale pools, the rest — as
+        ``(head + rest, leaves, scales)``."""
+        n = head + len(pools)
+        m = n + (0 if scales is None else 2)
+        return (tuple(out[:head]) + tuple(out[m:]), tuple(out[head:n]),
+                None if scales is None else tuple(out[n:m]))
+
+    def take(self, out, head=1):
+        """Rebind the target's donated leaves from a program's flat
+        return and hand back what is not a leaf."""
+        rest, self.pools, self.scales = self._split(
+            out, head, self.pools, self.scales)
+        return rest
+
+    def take_draft(self, out, head=1):
+        """:meth:`take` for the draft plane's leaves."""
+        rest, self.dpools, self.dscales = self._split(
+            out, head, self.dpools, self.dscales)
+        return rest
 
     def spec_mirror(self):
         """Whether prefill chunks mirror into the draft KV plane:
@@ -528,10 +553,8 @@ class _PagedModelState:
         # scale pools — a block is only decodable as codes+scale, so
         # the memory claim counts both (the PR-12 weight_bytes
         # discipline applied to the KV plane)
-        pool_bytes = 2 * self.pool_k.size * self.pool_k.dtype.itemsize
-        if self.scales is not None:
-            pool_bytes += 2 * self.scales[0].size * \
-                self.scales[0].dtype.itemsize
+        pool_bytes = sum(a.size * a.dtype.itemsize
+                         for a in self.pools + (self.scales or ()))
         per_block = pool_bytes // self.store.pool_blocks
         d = {"slots": len(self.slots), "active": len(act),
              "paged": True,
@@ -550,12 +573,10 @@ class _PagedModelState:
              "pool_bytes_per_token":
                  per_block / self.store.kv_block,
              "block_bytes": per_block,
-             "cache_dtype": str(self.pool_k.dtype)}
+             "cache_dtype": str(self.pools[0].dtype)}
         if self.draft is not None:
-            dbytes = 2 * self.dpool_k.size * self.dpool_k.dtype.itemsize
-            if self.dscales is not None:
-                dbytes += 2 * self.dscales[0].size * \
-                    self.dscales[0].dtype.itemsize
+            dbytes = sum(a.size * a.dtype.itemsize
+                         for a in self.dpools + (self.dscales or ()))
             d["spec_k"] = self.spec_k
             d["draft_pool_bytes"] = dbytes
             d["spec_acceptance_ema"] = round(float(self.spec_ema), 4)
@@ -637,6 +658,18 @@ class GenerationEngine:
              # step emitting 1..K+1 tokens), spec_proposed/spec_
              # accepted the draft tokens offered/accepted, spec_draft_
              # steps the draft micro-dispatches (catch-up + proposal)
+             # an expert model's routing, summed over its steps' live
+             # tokens (zero for a model without expert layers; the
+             # program returns them behind the sampled tokens):
+             # moe_tokens counts tokens x expert layers routed, moe_
+             # local_assignments the picks that fell on experts held
+             # here, moe_expert_load_max the fullest held expert's
+             # count summed over steps and layers, moe_expert_steps
+             # steps x expert layers, moe_experts_touched the held
+             # experts that got a token, summed likewise
+             "moe_tokens", "moe_local_assignments",
+             "moe_expert_load_max", "moe_expert_steps",
+             "moe_experts_touched",
              "spec_steps", "spec_proposed", "spec_accepted",
              "spec_draft_steps", "spec_fallback_steps"),
             labels=self._mlabels, help="generation engine counter")
@@ -1440,24 +1473,14 @@ class GenerationEngine:
     @staticmethod
     def _paged_fork(st, b, nb):
         """Duplicate physical block ``b`` into ``nb`` in every pool."""
-        if st.scales is None:
-            st.pool_k, st.pool_v = st.store.copy_block(
-                st.pool_k, st.pool_v, b, nb)
-        else:
-            # int8: codes and per-block scales fork together
-            st.pool_k, st.pool_v, sk, sv = st.store.copy_block(
-                st.pool_k, st.pool_v, b, nb, scales=st.scales)
-            st.scales = (sk, sv)
+        # int8: codes and per-block scales fork together
+        st.take(st.store.copy_block(*st.pools, b, nb, scales=st.scales),
+                head=0)
         if st.draft is not None:
             # the draft plane shares the block TABLES, so its pool must
             # fork the same physical block
-            if st.dscales is None:
-                st.dpool_k, st.dpool_v = st.draft.copy_block(
-                    st.dpool_k, st.dpool_v, b, nb)
-            else:
-                st.dpool_k, st.dpool_v, dsk, dsv = st.draft.copy_block(
-                    st.dpool_k, st.dpool_v, b, nb, scales=st.dscales)
-                st.dscales = (dsk, dsv)
+            st.take_draft(st.draft.copy_block(*st.dpools, b, nb,
+                                              scales=st.dscales), head=0)
 
     def _paged_dispatch(self, st, tables, toks, pos, val, do, phase,
                         live):
@@ -1466,31 +1489,29 @@ class GenerationEngine:
         ``do`` row, host-side np result.  Same graph/host sampling
         split as the contiguous plane's ``_decode_and_sample``.  The
         span carries what attention has to read: ``rows`` (``live``,
-        the slots this dispatch works for) and ``kv_tokens``, the sum
-        of their frontiers after the step."""
+        the slots this dispatch works for), ``kv_tokens``, the sum of
+        their frontiers after the step, and ``q_tokens``, the query
+        rows they bring (one each in a decode step)."""
         work = {"rows": len(live),
-                "kv_tokens": int((pos[live] + val[live]).sum())}
+                "kv_tokens": int((pos[live] + val[live]).sum()),
+                "q_tokens": int(val[live].sum())}
         if st.store.sample_mode == "graph":
             with _profiler.phase(phase, **work):
-                out = st.store.run_paged_step_sample(
-                    st.pool_k, st.pool_v, tables, toks, pos, val,
-                    st.keys, st.temps, st.top_ks, do, scales=st.scales)
-                if st.scales is None:
-                    toks_dev, st.pool_k, st.pool_v, st.keys = out
-                else:
-                    toks_dev, st.pool_k, st.pool_v, sk, sv, st.keys = out
-                    st.scales = (sk, sv)
+                toks_dev, st.keys = st.take(
+                    st.store.run_paged_step_sample(
+                        *st.pools, tables, toks, pos, val, st.keys,
+                        st.temps, st.top_ks, do, scales=st.scales))
             with _profiler.phase("serve_sample"):
-                return self._fetch_decode(toks_dev)
+                out = self._fetch_decode(toks_dev)
+            # a model's own counters ride behind the sampled tokens
+            # (store.aux_counters names them): same array, same fetch
+            for name, n in zip(st.store.aux_counters,
+                               out[len(tables):]):
+                self._stats.inc(name, int(n))
+            return out[:len(tables)]
         with _profiler.phase(phase, **work):
-            out = st.store.run_paged_step(
-                st.pool_k, st.pool_v, tables, toks, pos, val,
-                scales=st.scales)
-            if st.scales is None:
-                logits_dev, st.pool_k, st.pool_v = out
-            else:
-                logits_dev, st.pool_k, st.pool_v, sk, sv = out
-                st.scales = (sk, sv)
+            logits_dev, = st.take(st.store.run_paged_step(
+                *st.pools, tables, toks, pos, val, scales=st.scales))
         with _profiler.phase("serve_sample"):
             logits = self._fetch_decode(logits_dev)
             from .program_store import host_sample
@@ -1612,14 +1633,8 @@ class GenerationEngine:
                 tables[i] = st.tables[i]
                 pos[i] = base
                 val[i] = take
-            dout = draft.run_paged_step(
-                st.dpool_k, st.dpool_v, tables, toks, pos, val,
-                scales=st.dscales)
-            if st.dscales is None:
-                _, st.dpool_k, st.dpool_v = dout
-            else:
-                _, st.dpool_k, st.dpool_v, dsk, dsv = dout
-                st.dscales = (dsk, dsv)
+            st.take_draft(draft.run_paged_step(
+                *st.dpools, tables, toks, pos, val, scales=st.dscales))
             self._stats.inc("spec_draft_steps")
             done += chunk
         for i in dec:
@@ -1678,15 +1693,10 @@ class GenerationEngine:
                 pos[i] = idx
                 do[i] = t >= gap[i]
                 live.append(i)
-            out = draft.run_paged_step_sample_p(
-                st.dpool_k, st.dpool_v, tables, toks, pos, val,
-                st.dkeys, st.temps, st.top_ks, do, scales=st.dscales)
-            if st.dscales is None:
-                t_dev, q_dev, st.dpool_k, st.dpool_v, st.dkeys = out
-            else:
-                (t_dev, q_dev, st.dpool_k, st.dpool_v, dsk, dsv,
-                 st.dkeys) = out
-                st.dscales = (dsk, dsv)
+            t_dev, q_dev, st.dkeys = st.take_draft(
+                draft.run_paged_step_sample_p(
+                    *st.dpools, tables, toks, pos, val, st.dkeys,
+                    st.temps, st.top_ks, do, scales=st.dscales), head=2)
             sampled = self._fetch_decode(t_dev)
             q_rows.append(q_dev)
             for i in live:
@@ -1757,17 +1767,11 @@ class GenerationEngine:
                 with _profiler.phase(
                         "serve_decode", rows=len(dec),
                         kv_tokens=int((pos[dec] + val[dec]).sum())):
-                    out = st.store.run_paged_verify(
-                        st.pool_k, st.pool_v, tables, vtoks, pos, val,
-                        prop_q, st.keys, st.temps, st.top_ks, do,
-                        scales=st.scales)
-                    if st.scales is None:
-                        out_dev, ne_dev, st.pool_k, st.pool_v, \
-                            st.keys = out
-                    else:
-                        (out_dev, ne_dev, st.pool_k, st.pool_v, sk, sv,
-                         st.keys) = out
-                        st.scales = (sk, sv)
+                    out_dev, ne_dev, st.keys = st.take(
+                        st.store.run_paged_verify(
+                            *st.pools, tables, vtoks, pos, val, prop_q,
+                            st.keys, st.temps, st.top_ks, do,
+                            scales=st.scales), head=2)
                 with _profiler.phase("serve_sample"):
                     out_toks = self._fetch_decode(out_dev)
                     n_emit = self._fetch_decode(ne_dev)
@@ -1881,14 +1885,9 @@ class GenerationEngine:
                     # mirror is skipped (zero draft cost per tick); a
                     # probe's catch-up rebuilds the draft KV from the
                     # prompt instead
-                    dout = st.draft.run_paged_step(
-                        st.dpool_k, st.dpool_v, tables, toks, pos,
-                        val, scales=st.dscales)
-                    if st.dscales is None:
-                        _, st.dpool_k, st.dpool_v = dout
-                    else:
-                        _, st.dpool_k, st.dpool_v, dsk, dsv = dout
-                        st.dscales = (dsk, dsv)
+                    st.take_draft(st.draft.run_paged_step(
+                        *st.dpools, tables, toks, pos, val,
+                        scales=st.dscales))
         except BaseException as e:  # noqa: BLE001 — to the futures
             exc = e if isinstance(e, MXNetError) \
                 else MXNetError("prefill dispatch failed: %r" % (e,))

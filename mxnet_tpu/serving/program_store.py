@@ -855,6 +855,24 @@ def cache_donate_argnums(nums):
 # with both cache arguments DONATED, so the per-step update lowers to an
 # in-place dynamic_update_slice on the resident buffers.
 # ---------------------------------------------------------------------------
+# The decode-mode model modules the store can run.  A spec picks one
+# with its ``arch`` key (default: the repo's own LM); what the module
+# offers under the seam's names (``serving_spec``, ``required_params``,
+# ``pack_params``, ``quantize_params``, ``init_pool``, ``paged_step``,
+# ``OFFERS``, ``AUX_COUNTERS``; models/transformer_lm.py, bottom) is all
+# the store knows of an architecture.
+_ARCHS = ("transformer_lm", "deepseek_v3")
+
+
+def _serving_model(arch):
+    import importlib
+    arch = arch or "transformer_lm"
+    if arch not in _ARCHS:
+        raise MXNetError("unknown generative arch %r (has: %s)"
+                         % (arch, ", ".join(_ARCHS)))
+    return importlib.import_module("..models." + arch, __package__)
+
+
 class GenerativeProgramStore:
     """AOT prefill/decode programs for one autoregressive LM.
 
@@ -865,7 +883,12 @@ class GenerativeProgramStore:
         arguments (``embed_weight``, ``blk*_*``, ``final_ln_*``,
         ``pred_*``).
     spec : dict
-        ``transformer_lm.lm_spec(...)`` architecture spec.
+        ``transformer_lm.lm_spec(...)`` architecture spec, or another
+        decode-mode model's with its name under ``arch``
+        (``"deepseek_v3"``: ``models/deepseek_v3.serving_spec``; paged
+        plane only, no int8 pool).  A model that restacks leaves at
+        load (``deepseek_v3``'s routed experts) pops them from
+        ``params`` as it goes: hand it a copy to keep yours.
     batch_buckets / prompt_buckets : iterable of int, optional
         Bucket edges; default ``MXNET_SERVE_BUCKETS`` /
         ``MXNET_SERVE_PROMPT_BUCKETS``.
@@ -922,8 +945,10 @@ class GenerativeProgramStore:
                  compute_dtype=None, kv_dtype=None, sample=None,
                  paged=None, prefill_chunk=None, pool_blocks=None,
                  max_programs=None, device=None):
-        from ..models.transformer_lm import lm_spec
-        self._spec = lm_spec(**dict(spec))  # validates + canonicalizes
+        spec = dict(spec)
+        self._model = _serving_model(spec.pop("arch", None))
+        self._spec = self._model.serving_spec(spec)  # validates
+        self.aux_counters = tuple(self._model.AUX_COUNTERS)
         self.name = name
         self._device = device
         self._compute = None
@@ -947,6 +972,8 @@ class GenerativeProgramStore:
         # block granularity to hang the scales on)
         self.kv_int8 = kv == "int8"
         self.kv_dtype = jnp.dtype(kv)
+        if self.kv_int8:
+            self._need("int8_kv", "an int8 KV pool (kv_dtype='int8')")
         sm = str(sample if sample is not None
                  else get_env("MXNET_SERVE_SAMPLE") or "graph").lower()
         if sm not in ("graph", "host"):
@@ -974,6 +1001,9 @@ class GenerativeProgramStore:
         # (or paged=False) keeps the contiguous per-slot plane.
         self.paged = bool(int(get_env("MXNET_SERVE_PAGED"))
                           if paged is None else paged)
+        if not self.paged:
+            # D2: the contiguous plane is not taught new models
+            self._need("contiguous", "the contiguous plane (paged=False)")
         if self.kv_int8 and not self.paged:
             raise MXNetError(
                 "kv_dtype='int8' needs the paged KV plane (the scales "
@@ -999,14 +1029,18 @@ class GenerativeProgramStore:
                 % (nb, self.table_width()))
         self.pool_blocks = nb
         self._copy_fn = None   # lazily jitted COW block copy
-        self._copy_fn8 = None  # its int8 codes+scales twin
+        # the pool's leaves as the model shapes them: (k, v) for the
+        # LM, one latent leaf for deepseek_v3
+        self._pool_avals = tuple(jax.eval_shape(
+            lambda: self._model.init_pool(self._spec, nb, self.kv_block,
+                                          dtype=self.kv_dtype)))
 
-        missing = [k for k in self._required_params() if k not in params]
+        self._params = self._load_params(params)
+        missing = [k for k in self._required_params()
+                   if k not in self._params]
         if missing:
             raise MXNetError("generative model %r is missing params %s"
                              % (name, missing))
-
-        self._params = self._load_params(params)
         self._version = 1
 
         # one warm sweep must fit the LRU or AOT is a lie (the forward
@@ -1039,12 +1073,20 @@ class GenerativeProgramStore:
         # state, introspectable via stats()
         self.cache_state = None
 
+    def _need(self, what, wording):
+        if what not in self._model.OFFERS:
+            raise MXNetError("generative arch %r does not offer %s"
+                             % (self._model.__name__.rsplit(".", 1)[-1],
+                                wording))
+
     def _load_params(self, params):
-        """The trained weight dict through the serving dtype policy
-        (fp32 pass-through / bf16 cast / int8 matmul-weight
-        quantization) and device pinning; shared by load and
-        :meth:`swap_params` so both produce identical trees."""
+        """The trained weight dict through the model's restacking
+        (``pack_params``), the serving dtype policy (fp32 pass-through
+        / bf16 cast / int8 matmul-weight quantization) and device
+        pinning; shared by load and :meth:`swap_params` so both produce
+        identical trees."""
         device = self._device
+        params = self._model.pack_params(params, self._spec)
 
         def load(v):
             a = _as_device_array(v)
@@ -1057,13 +1099,10 @@ class GenerativeProgramStore:
             return a
 
         if self._compute == "int8":
-            from ..models.transformer_lm import quantize_lm_params
-            host = {k: np.asarray(_as_device_array(v), np.float32)
-                    if jnp.issubdtype(_as_device_array(v).dtype,
-                                      jnp.floating) else v
-                    for k, v in params.items()}
             out = {}
-            for k, v in quantize_lm_params(host, self._spec).items():
+            for k, v in self._model.quantize_params(
+                    {k: _as_device_array(v) for k, v in params.items()},
+                    self._spec).items():
                 if isinstance(v, QuantizedWeight):
                     c, s = jnp.asarray(v.codes), jnp.asarray(v.scales)
                     if device is not None:
@@ -1086,11 +1125,12 @@ class GenerativeProgramStore:
         KV cache holds old-version context); latency-sensitive
         deployments that need whole-generation pinning should drain
         before swapping.  Returns the new version."""
-        missing = [k for k in self._required_params() if k not in params]
+        new_params = self._load_params(params)
+        missing = [k for k in self._required_params()
+                   if k not in new_params]
         if missing:
             raise MXNetError("swap_params for %r is missing %s"
                              % (self.name, sorted(missing)))
-        new_params = self._load_params(params)
         old_leaves = jax.tree_util.tree_leaves(
             {k: self._params[k] for k in sorted(self._params)})
         new_leaves = jax.tree_util.tree_leaves(
@@ -1127,14 +1167,7 @@ class GenerativeProgramStore:
         return self._version
 
     def _required_params(self):
-        names = ["embed_weight", "final_ln_gamma", "final_ln_beta",
-                 "pred_weight", "pred_bias"]
-        for i in range(self._spec["num_layers"]):
-            names += ["blk%d_%s" % (i, k) for k in
-                      ("ln1_gamma", "q_weight", "k_weight", "v_weight",
-                       "proj_weight", "ln2_gamma", "ffn1_weight",
-                       "ffn1_bias", "ffn2_weight", "ffn2_bias")]
-        return names
+        return self._model.required_params(self._spec)
 
     # -- geometry ------------------------------------------------------
     @property
@@ -1205,84 +1238,65 @@ class GenerativeProgramStore:
             self.prompt_bucket(int(prompt_len))
 
     def new_cache(self, batch, cache_len):
-        from ..models.transformer_lm import init_cache
-        k, v = init_cache(self._spec, batch, cache_len,
-                          dtype=self.kv_dtype)
+        k, v = self._model.init_cache(self._spec, batch, cache_len,
+                                      dtype=self.kv_dtype)
         if self._device is not None:
             k = jax.device_put(k, self._device)
             v = jax.device_put(v, self._device)
         return k, v
 
     def new_pool(self):
-        """Zeroed paged KV pool pair, ``(num_layers, num_heads,
-        pool_blocks * kv_block, head_dim)`` each — block 0 is the
-        reserved trash block zero table entries point at."""
-        from ..models.transformer_lm import init_pool
-        k, v = init_pool(self._spec, self.pool_blocks, self.kv_block,
-                         dtype=self.kv_dtype)
-        if self._device is not None:
-            k = jax.device_put(k, self._device)
-            v = jax.device_put(v, self._device)
-        return k, v
+        """The zeroed paged pool, a tuple of the model's leaves —
+        ``(k, v)``, each ``(num_layers, num_heads, pool_blocks *
+        kv_block, head_dim)``, for the LM; one latent leaf
+        ``(num_layers, 1, pool_blocks * kv_block, width)`` for
+        ``deepseek_v3`` — block 0 is the reserved trash block zero
+        table entries point at."""
+        return self._placed(self._model.init_pool(
+            self._spec, self.pool_blocks, self.kv_block,
+            dtype=self.kv_dtype))
+
+    def _placed(self, leaves):
+        if self._device is None:
+            return tuple(leaves)
+        return tuple(jax.device_put(a, self._device) for a in leaves)
 
     def new_scale_pool(self):
         """Per-(layer, head, physical block) fp32 absmax scale pools
         for the int8 paged plane — a ``(num_layers, num_heads,
         pool_blocks)`` pair of ones riding beside :meth:`new_pool`'s
         int8 code pools as donated program state."""
-        from ..models.transformer_lm import init_scale_pool
-        sk, sv = init_scale_pool(self._spec, self.pool_blocks)
-        if self._device is not None:
-            sk = jax.device_put(sk, self._device)
-            sv = jax.device_put(sv, self._device)
-        return sk, sv
+        return self._placed(self._model.init_scale_pool(
+            self._spec, self.pool_blocks))
 
-    def copy_block(self, pool_k, pool_v, src, dst, scales=None):
-        """Copy-on-write fork: duplicate physical block ``src``'s rows
-        into block ``dst`` in both pools (one jitted program, pools
-        donated off-CPU — callers rebind to the outputs).  With
-        ``scales`` (the int8 plane's ``(scale_k, scale_v)`` pools) the
-        per-block scales fork WITH the codes — a block is only
-        decodable as codes+scale together — and the return grows to
-        ``(pool_k, pool_v, scale_k, scale_v)``."""
-        bs = self.kv_block
-        if scales is not None:
-            fn = getattr(self, "_copy_fn8", None)
-            if fn is None:
-                def copy_block(pk, pv, sk, sv, s, d):
-                    bk = jax.lax.dynamic_slice_in_dim(pk, s * bs, bs, 2)
-                    bv = jax.lax.dynamic_slice_in_dim(pv, s * bs, bs, 2)
-                    pk = jax.lax.dynamic_update_slice_in_dim(pk, bk,
-                                                             d * bs, 2)
-                    pv = jax.lax.dynamic_update_slice_in_dim(pv, bv,
-                                                             d * bs, 2)
-                    ssk = jax.lax.dynamic_slice_in_dim(sk, s, 1, 2)
-                    ssv = jax.lax.dynamic_slice_in_dim(sv, s, 1, 2)
-                    sk = jax.lax.dynamic_update_slice_in_dim(sk, ssk,
-                                                             d, 2)
-                    sv = jax.lax.dynamic_update_slice_in_dim(sv, ssv,
-                                                             d, 2)
-                    return pk, pv, sk, sv
-
-                fn = self._copy_fn8 = jax.jit(
-                    copy_block, donate_argnums=cache_donate_argnums((0, 1, 2,
-                                                             3)))
-            return fn(pool_k, pool_v, scales[0], scales[1],
-                      np.int32(src), np.int32(dst))
+    def copy_block(self, *args, scales=None):
+        """Copy-on-write fork: ``copy_block(*pool leaves, src, dst)``
+        duplicates physical block ``src``'s rows into block ``dst`` in
+        every leaf (one jitted program, leaves donated off-CPU —
+        callers rebind to the outputs).  With ``scales`` (the int8
+        plane's ``(scale_k, scale_v)`` pools) the per-block scales fork
+        WITH the codes — a block is only decodable as codes+scale
+        together — and the return grows by the two scale pools."""
+        *pools, src, dst = args
+        leaves = self._pool_args(tuple(pools), scales)
         fn = self._copy_fn
         if fn is None:
-            def copy_block(pk, pv, s, d):
-                bk = jax.lax.dynamic_slice_in_dim(pk, s * bs, bs, 2)
-                bv = jax.lax.dynamic_slice_in_dim(pv, s * bs, bs, 2)
-                pk = jax.lax.dynamic_update_slice_in_dim(pk, bk,
-                                                         d * bs, 2)
-                pv = jax.lax.dynamic_update_slice_in_dim(pv, bv,
-                                                         d * bs, 2)
-                return pk, pv
+            bs, n_leaves = self.kv_block, self.pool_leaves
+
+            def copy_block(leaves, s, d):
+                # axis 2 counts tokens in a pool leaf (bs a block) and
+                # blocks in a scale pool (one a block)
+                out = []
+                for i, leaf in enumerate(leaves):
+                    n = bs if i < n_leaves else 1
+                    out.append(jax.lax.dynamic_update_slice_in_dim(
+                        leaf, jax.lax.dynamic_slice_in_dim(
+                            leaf, s * n, n, 2), d * n, 2))
+                return tuple(out)
 
             fn = self._copy_fn = jax.jit(
-                copy_block, donate_argnums=cache_donate_argnums((0, 1)))
-        return fn(pool_k, pool_v, np.int32(src), np.int32(dst))
+                copy_block, donate_argnums=cache_donate_argnums((0,)))
+        return fn(leaves, np.int32(src), np.int32(dst))
 
     # -- compilation ---------------------------------------------------
     def _sds(self, shape, dtype):
@@ -1303,17 +1317,23 @@ class GenerativeProgramStore:
                  int(cache_len), dh)
         return self._sds(shape, self.kv_dtype)
 
-    def _pool_spec(self):
-        s = self._spec
-        dh = s["num_hidden"] // s["num_heads"]
-        shape = (s["num_layers"], s["num_heads"],
-                 self.pool_blocks * self.kv_block, dh)
-        return self._sds(shape, self.kv_dtype)
+    @property
+    def pool_leaves(self):
+        """How many leaves the model's pool has (2 for the LM's ``(k,
+        v)``, 1 for a latent pool): the run methods take that many
+        right after ``self``."""
+        return len(self._pool_avals)
 
-    def _scale_spec(self):
-        s = self._spec
-        return self._sds((s["num_layers"], s["num_heads"],
-                          self.pool_blocks), jnp.float32)
+    def _pool_spec(self):
+        """Avals of every donated pool argument: the model's leaves,
+        then the int8 plane's two scale pools."""
+        leaves = tuple(self._sds(a.shape, a.dtype)
+                       for a in self._pool_avals)
+        if self.kv_int8:
+            s = self._spec
+            leaves += (self._sds((s["num_layers"], s["num_heads"],
+                                  self.pool_blocks), jnp.float32),) * 2
+        return leaves
 
     def _key(self, kind, bb, lb):
         # (kind, batch bucket, length bucket) + the serving dtypes +
@@ -1327,9 +1347,7 @@ class GenerativeProgramStore:
                 _pallas_dispatch.fingerprint())
 
     def _compile(self, kind, bb, lb):
-        from ..models.transformer_lm import (decode_apply,
-                                             paged_step_apply,
-                                             prefill_apply)
+        model = self._model
         tic = time.perf_counter()
         spec = self._spec
         kv = self.kv_dtype
@@ -1348,11 +1366,9 @@ class GenerativeProgramStore:
             bs = self.kv_block
             tb = self.table_width()
             int8 = self.kv_int8
-            pools = ((self._pool_spec(), self._pool_spec(),
-                      self._scale_spec(), self._scale_spec())
-                     if int8 else
-                     (self._pool_spec(), self._pool_spec()))
+            pools = self._pool_spec()
             npool = len(pools)
+            nleaf = self.pool_leaves
             base = (self._param_spec(),) + pools + (
                 self._sds((bb, tb), jnp.int32),
                 self._sds((bb, int(lb)), jnp.int32),
@@ -1369,20 +1385,14 @@ class GenerativeProgramStore:
 
             def step(params, pls, tables, tokens, positions, valid,
                      all_logits=False):
-                # paged_step_apply with the pool tuple threaded through
-                # the fp/int8 layouts uniformly: returns (logits,
-                # new_pool_tuple)
-                if int8:
-                    out = paged_step_apply(
-                        params, pls[0], pls[1], tables, tokens,
-                        positions, valid, spec, bs,
-                        scales=(pls[2], pls[3]), all_logits=all_logits)
-                else:
-                    out = paged_step_apply(
-                        params, pls[0], pls[1], tables, tokens,
-                        positions, valid, spec, bs,
-                        all_logits=all_logits)
-                return out[0], tuple(out[1:])
+                # the model's paged step with the donated leaves
+                # threaded through uniformly: returns (logits, new
+                # leaves, the model's counters or None)
+                return model.paged_step(
+                    params, pls[:nleaf], tables, tokens, positions,
+                    valid, spec, bs,
+                    scales=tuple(pls[nleaf:]) if int8 else None,
+                    all_logits=all_logits)
 
             if kind in ("paged_step_sample", "paged_step_sample_p"):
                 # in-graph sampling with a per-row enable mask: a
@@ -1397,14 +1407,19 @@ class GenerativeProgramStore:
                     pls = rest[:npool]
                     (tables, tokens, positions, valid, keys, temps,
                      top_ks, do_sample) = rest[npool:]
-                    logits, new_pools = step(params, pls, tables,
-                                             tokens, positions, valid)
+                    logits, new_pools, aux = step(
+                        params, pls, tables, tokens, positions, valid)
                     if with_q:
                         toks, carry, q = sample_tokens_p(
                             logits, keys, temps, top_ks)
                     else:
                         toks, carry = sample_tokens(logits, keys,
                                                     temps, top_ks)
+                    if aux is not None:
+                        # the model's counters ride behind the tokens:
+                        # one small array, one fetch
+                        toks = jnp.concatenate(
+                            [toks, aux.astype(toks.dtype)])
                     new_keys = jnp.where(do_sample[:, None], carry,
                                          keys)
                     head = (toks, q) if with_q else (toks,)
@@ -1430,9 +1445,9 @@ class GenerativeProgramStore:
                     pls = rest[:npool]
                     (tables, tokens, positions, valid, prop_q, keys,
                      temps, top_ks, do_sample) = rest[npool:]
-                    logits_all, new_pools = step(params, pls, tables,
-                                                 tokens, positions,
-                                                 valid, all_logits=True)
+                    logits_all, new_pools, _ = step(
+                        params, pls, tables, tokens, positions, valid,
+                        all_logits=True)
                     out, n_emit, carry = spec_verify(
                         logits_all, tokens[:, 1:], prop_q, keys,
                         temps, top_ks, valid)
@@ -1451,8 +1466,8 @@ class GenerativeProgramStore:
                 def fn(params, *rest):
                     pls = rest[:npool]
                     tables, tokens, positions, valid = rest[npool:]
-                    logits, new_pools = step(params, pls, tables,
-                                             tokens, positions, valid)
+                    logits, new_pools, _ = step(
+                        params, pls, tables, tokens, positions, valid)
                     return (logits,) + new_pools
 
                 fn.__name__ = name
@@ -1468,9 +1483,9 @@ class GenerativeProgramStore:
             cache_len = self.kv_bucket(lb)
 
             def prefill(params, tokens, lengths):
-                logits, ck, cv = prefill_apply(params, tokens, lengths,
-                                               cache_len, spec,
-                                               cache_dtype=kv)
+                logits, ck, cv = model.prefill_apply(
+                    params, tokens, lengths, cache_len, spec,
+                    cache_dtype=kv)
                 first = logits[jnp.arange(bb), (lengths - 1)
                                .astype(jnp.int32)]
                 return first, ck, cv
@@ -1486,8 +1501,8 @@ class GenerativeProgramStore:
 
             def decode_sample(params, cache_k, cache_v, tokens, lengths,
                               keys, temps, top_ks):
-                logits, ck, cv = decode_apply(params, cache_k, cache_v,
-                                              tokens, lengths, spec)
+                logits, ck, cv = model.decode_apply(
+                    params, cache_k, cache_v, tokens, lengths, spec)
                 toks, new_keys = sample_tokens(logits, keys, temps,
                                                top_ks)
                 return toks, ck, cv, new_keys
@@ -1506,8 +1521,8 @@ class GenerativeProgramStore:
         else:  # decode (logits out — the MXNET_SERVE_SAMPLE=host hatch)
 
             def decode(params, cache_k, cache_v, tokens, lengths):
-                return decode_apply(params, cache_k, cache_v, tokens,
-                                    lengths, spec)
+                return model.decode_apply(params, cache_k, cache_v,
+                                          tokens, lengths, spec)
 
             args = (self._param_spec(),
                     self._cache_spec(bb, lb), self._cache_spec(bb, lb),
@@ -1570,12 +1585,20 @@ class GenerativeProgramStore:
             # lands in the trash block).
             pkind = ("paged_step_sample" if self.sample_mode == "graph"
                      else "paged_step")
+            pools = None        # ONE throwaway pool through all of them
             for bb in self._batch_edges:
                 for lq in sorted({1, self.prefill_chunk}):
                     prog = self._acquire(pkind, bb, lq)
                     out[(pkind, bb, lq)] = prog.compile_ms
                     if execute:
-                        self._exec_paged_zeros(pkind, prog, bb, lq)
+                        pools = self._exec_paged_zeros(pkind, prog, bb,
+                                                       lq, pools)
+            if execute:
+                # the copy-on-write fork is a program of the tick too:
+                # left to its first use it compiles under traffic
+                n = self.pool_leaves
+                jax.block_until_ready(self.copy_block(
+                    *pools[:n], 0, 0, scales=pools[n:] or None))
             return out
         cache_buckets = {self.kv_bucket(p) for p in self._prompt_edges}
         if kv_depth is not None:
@@ -1613,14 +1636,17 @@ class GenerativeProgramStore:
                             prog.fn(self._params, ck, cv, toks, lens))
         return out
 
-    def _exec_paged_zeros(self, kind, prog, bb, lq):
+    def _exec_paged_zeros(self, kind, prog, bb, lq, pools=None):
         """Execute one paged program once on a throwaway zero pool with
         all-zero tables (every write lands in the trash block): the
         one-time XLA executable setup must not land inside a served
-        request."""
-        pools = self.new_pool()
-        if self.kv_int8:
-            pools = pools + self.new_scale_pool()
+        request.  Returns the pool it ran on (the program's donated
+        leaves, handed back) for the next program to warm on: a pool a
+        program is 2.7 GB beside 9 GB of weights for ``deepseek_v3``."""
+        if pools is None:
+            pools = self.new_pool()
+            if self.kv_int8:
+                pools = pools + self.new_scale_pool()
         tbls = np.zeros((bb, self.table_width()), np.int32)
         toks = np.zeros((bb, lq), np.int32)
         pos = np.zeros((bb,), np.int32)
@@ -1639,7 +1665,9 @@ class GenerativeProgramStore:
                                               val) + samp
         else:
             args = (self._params,) + pools + (tbls, toks, pos, val)
-        jax.block_until_ready(prog.fn(*args))
+        out = jax.block_until_ready(prog.fn(*args))
+        head = 2 if kind in ("paged_step_sample_p", "paged_verify") else 1
+        return tuple(out[head:head + len(pools)])
 
     def warm_spec_programs(self, spec_k, draft=False, execute=True):
         """Warm the speculative-decoding program kinds ahead of
@@ -1654,6 +1682,7 @@ class GenerativeProgramStore:
             raise MXNetError(
                 "speculative decoding needs the paged plane (store %r "
                 "has paged=False)" % self.name)
+        self._need("draft", "speculative decoding")
         kinds = ([("paged_step_sample_p", 1),
                   ("paged_step", self.prefill_chunk)] if draft
                  else [("paged_verify", int(spec_k) + 1)])
@@ -1703,7 +1732,7 @@ class GenerativeProgramStore:
         return prog.fn(self._params, cache_k, cache_v, tokens, lengths,
                        keys, temps, top_ks)
 
-    def _pool_args(self, pool_k, pool_v, scales):
+    def _pool_args(self, pools, scales):
         """The pool-argument tuple of one paged dispatch: the int8
         plane threads its donated scale pools right after the code
         pools (and gets them back in the same slots of the return)."""
@@ -1712,64 +1741,58 @@ class GenerativeProgramStore:
                 raise MXNetError(
                     "int8 paged store %r needs its (scale_k, scale_v) "
                     "pools on every dispatch" % self.name)
-            return (pool_k, pool_v, scales[0], scales[1])
-        return (pool_k, pool_v)
+            return tuple(pools) + tuple(scales)
+        return tuple(pools)
+
+    def _run_paged(self, kind, args, scales):
+        """Dispatch program ``kind`` on ``args``: the pool's leaves
+        (``pool_leaves`` of them) and then the program's own arguments,
+        tables first and tokens second."""
+        n = self.pool_leaves
+        bb, lq = args[n + 1].shape
+        prog = self._acquire(kind, int(bb), int(lq))
+        return prog.fn(self._params, *(self._pool_args(args[:n], scales)
+                                       + tuple(args[n:])))
 
     @hot_path
-    def run_paged_step(self, pool_k, pool_v, tables, tokens,
-                       positions, valid, scales=None):
+    def run_paged_step(self, *args, scales=None):
         """Dispatch one logits-out paged step (the host-sampling
-        hatch and the draft's prefill mirror): ``tokens`` (bb, lq)
-        int32 — lq=1 is a decode step, lq=prefill_chunk a prompt chunk.
-        Returns ``(logits (bb, vocab) at each row's last valid
-        position, pool_k, pool_v)`` — int8 stores take and return the
-        scale pools too, ``(logits, pool_k, pool_v, scale_k,
-        scale_v)``.  The pools are consumed (donated) — callers
-        rebind."""
-        bb, lq = tokens.shape
-        prog = self._acquire("paged_step", int(bb), int(lq))
-        return prog.fn(self._params,
-                       *(self._pool_args(pool_k, pool_v, scales) +
-                         (tables, tokens, positions, valid)))
+        hatch and the draft's prefill mirror): ``run_paged_step(*pool
+        leaves, tables, tokens, positions, valid)`` with ``tokens``
+        (bb, lq) int32 — lq=1 is a decode step, lq=prefill_chunk a
+        prompt chunk.  Returns ``(logits (bb, vocab) at each row's last
+        valid position, *pool leaves)`` — ``(logits, pool_k, pool_v)``
+        for the LM; int8 stores take and return the scale pools too,
+        ``(logits, pool_k, pool_v, scale_k, scale_v)``.  The pools are
+        consumed (donated) — callers rebind."""
+        return self._run_paged('paged_step', args, scales)
 
     @hot_path
-    def run_paged_step_sample(self, pool_k, pool_v, tables, tokens,
-                              positions, valid, keys, temps, top_ks,
-                              do_sample, scales=None):
-        """Dispatch one paged step with IN-GRAPH sampling: returns
-        ``(tokens (bb,) int32, pool_k, pool_v, new_keys)`` (int8
-        stores: ``(tokens, pool_k, pool_v, scale_k, scale_v,
-        new_keys)``).  Rows with ``do_sample`` False keep their PRNG
-        keys (their sampled token is garbage the caller discards);
-        pools and keys are consumed (donated) — callers rebind."""
-        bb, lq = tokens.shape
-        prog = self._acquire("paged_step_sample", int(bb), int(lq))
-        return prog.fn(self._params,
-                       *(self._pool_args(pool_k, pool_v, scales) +
-                         (tables, tokens, positions, valid, keys,
-                          temps, top_ks, do_sample)))
+    def run_paged_step_sample(self, *args, scales=None):
+        """Dispatch one paged step with IN-GRAPH sampling:
+        ``run_paged_step_sample(*pool leaves, tables, tokens,
+        positions, valid, keys, temps, top_ks, do_sample)`` returns
+        ``(tokens (bb,) int32, *pool leaves, new_keys)`` (int8 stores:
+        the scale pools before ``new_keys``).  A model with
+        ``aux_counters`` appends them to the token vector (``(bb +
+        len(aux_counters),)``: one array, one fetch).  Rows with
+        ``do_sample`` False keep their PRNG keys (their sampled token
+        is garbage the caller discards); pools and keys are consumed
+        (donated) — callers rebind."""
+        return self._run_paged('paged_step_sample', args, scales)
 
     @hot_path
-    def run_paged_step_sample_p(self, pool_k, pool_v, tables, tokens,
-                                positions, valid, keys, temps, top_ks,
-                                do_sample, scales=None):
+    def run_paged_step_sample_p(self, *args, scales=None):
         """The DRAFT model's proposal step: one lq=1 paged step with
         in-graph sampling that also returns the proposal distribution.
         Returns ``(tokens (bb,), q (bb, vocab), pool_k, pool_v,
         new_keys)`` (int8: scale pools before new_keys).  ``q`` should
         stay device-resident — the verify program consumes it directly,
         the host never fetches a distribution."""
-        bb, lq = tokens.shape
-        prog = self._acquire("paged_step_sample_p", int(bb), int(lq))
-        return prog.fn(self._params,
-                       *(self._pool_args(pool_k, pool_v, scales) +
-                         (tables, tokens, positions, valid, keys,
-                          temps, top_ks, do_sample)))
+        return self._run_paged('paged_step_sample_p', args, scales)
 
     @hot_path
-    def run_paged_verify(self, pool_k, pool_v, tables, tokens,
-                         positions, valid, prop_q, keys, temps,
-                         top_ks, do_sample, scales=None):
+    def run_paged_verify(self, *args, scales=None):
         """The TARGET model's speculative verify: ``tokens`` (bb, K+1)
         holds each slot's pending next token followed by its K draft
         proposals, ``prop_q`` (bb, K, vocab) the draft's proposal
@@ -1781,12 +1804,7 @@ class GenerativeProgramStore:
         new_keys)`` (int8: scale pools before new_keys) — row b emits
         ``out_toks[b, :n_emit[b]]``.  Pools and keys are consumed
         (donated) — callers rebind."""
-        bb, lq = tokens.shape
-        prog = self._acquire("paged_verify", int(bb), int(lq))
-        return prog.fn(self._params,
-                       *(self._pool_args(pool_k, pool_v, scales) +
-                         (tables, tokens, positions, valid, prop_q,
-                          keys, temps, top_ks, do_sample)))
+        return self._run_paged('paged_verify', args, scales)
 
     def pad_prompts(self, prompts):
         """Host-side canonicalization: a list of token id sequences ->

@@ -108,7 +108,10 @@ class ModelRegistry:
 
         ``params`` — the ``transformer_lm`` symbol graph's trained
         argument arrays (a ``save_checkpoint``'s arg_params works
-        directly); ``spec`` — ``transformer_lm.lm_spec(...)``.  Keyword
+        directly); ``spec`` — ``transformer_lm.lm_spec(...)``, or
+        another decode-mode model's spec naming it under ``arch``
+        (``"deepseek_v3"``, paged plane only;
+        :class:`GenerativeProgramStore`).  Keyword
         args (``batch_buckets``, ``prompt_buckets``, ``kv_block``,
         ``kv_max``, ``compute_dtype``, ``kv_dtype``, ``sample``,
         ``max_programs``, ``device``) pass through to
@@ -156,6 +159,7 @@ class ModelRegistry:
                 "plane with in-graph sampling (paged=True, "
                 "sample='graph'); got paged=%s sample=%r"
                 % (target_name, target.paged, target.sample_mode))
+        target._need("draft", "speculative decoding")
         if spec_k is None:
             spec_k = int(get_env("MXNET_SERVE_SPEC_K"))
         if spec_k < 1:

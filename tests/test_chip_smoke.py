@@ -70,6 +70,21 @@ def test_smoke_plumbing_at_toy_widths():
             in serve["asserted"]
 
 
+def test_smoke_deepseek_kernels_phase_at_toy_widths():
+    """kernels/deepseek-v3 at toy widths: on the CPU the doors lower to
+    the twins, so this drives the phase's plumbing (tables with a shared
+    block, ragged contexts, counts) and not the kernels."""
+    toy = {"deepseek": {"heads": 4, "rank": 16, "rope": 8, "row": 24,
+                        "kv_block": 8, "contexts": (3, 20, 41),
+                        "chunk": 8, "hidden": 32, "expert": 16, "held": 4,
+                        "routed": 16, "top_k": 4, "tokens": (6, 40)}}
+    rec, = _load("chip_smoke").run(["kernels/deepseek-v3"], toy,
+                                   kernels=False, emit=lambda line: None)
+    assert rec["ok"] and rec["mla_gap_lq1"] == 0.0
+    assert rec["moe_assignments_n40"] > 0
+    assert "experts, 40 tokens: the same counts" in rec["asserted"]
+
+
 def test_smoke_phase_failure_propagates():
     """serve/lm without the checkpoint train/lm saves is an error, not a
     skipped phase."""
