@@ -649,10 +649,16 @@ class GenerationEngine:
              # prefix_hits counts admissions that reused shared
              # blocks, *_blocks/_tokens their sizes; cow_forks the
              # copy-on-write block duplications; prefill_chunks the
-             # chunk dispatches; shed_pool the requests too large for
-             # the pool
+             # rows chunk dispatches worked for, prefill_row_slots the
+             # rows they had (chunk_rows(slots) a dispatch: the first
+             # over the second is a chunk program's live share),
+             # prefill_rows_deferred the rows that waited a tick
+             # because more slots were in their prompt than a
+             # dispatch has rows; shed_pool the requests too large
+             # for the pool
              "prefix_hits", "prefix_hit_blocks", "prefix_hit_tokens",
-             "cow_forks", "prefill_chunks", "shed_pool",
+             "cow_forks", "prefill_chunks", "prefill_row_slots",
+             "prefill_rows_deferred", "shed_pool",
              # speculative decoding (zero without a draft attached):
              # spec_steps counts verify dispatches (each is ONE target
              # step emitting 1..K+1 tokens), spec_proposed/spec_
@@ -1429,19 +1435,24 @@ class GenerationEngine:
             st.dlen[i] = 0
 
     def _paged_tick(self, model, st):
-        """One engine tick of the paged plane: ONE decode step for the
-        generating slots, then ONE prompt chunk for the prefilling
-        slots — long prompts advance prefill_chunk tokens per tick
-        INTERLEAVED with everyone else's decode steps, so a long
-        prefill stops spiking co-running streams' inter-token
-        latency."""
+        """One engine tick of the paged plane: ONE decode step over
+        the slots for the generating ones, then ONE prompt chunk over
+        the prefilling ones, compacted to ``chunk_rows(slots)`` rows —
+        long prompts advance prefill_chunk tokens per tick INTERLEAVED
+        with everyone else's decode steps, so a long prefill stops
+        spiking co-running streams' inter-token latency.  With more
+        slots in their prompt than the chunk has rows, the ones
+        admitted first go and the rest wait a tick: by admission, not
+        by slot index, which would starve the high slots while low
+        ones refill."""
         dec = [i for i in st.active() if st.decoding[i]]
         if dec:
             if st.draft is not None and self._spec_active(st):
                 self._paged_spec_step(model, st, dec)
             else:
                 self._paged_decode_step(model, st, dec)
-        pre = [i for i in st.active() if not st.decoding[i]]
+        pre = sorted((i for i in st.active() if not st.decoding[i]),
+                     key=lambda i: st.slots[i].t_admit)
         if pre:
             self._paged_prefill_chunk(model, st, pre)
         if dec or pre:
@@ -1483,24 +1494,35 @@ class GenerationEngine:
                                               scales=st.dscales), head=0)
 
     def _paged_dispatch(self, st, tables, toks, pos, val, do, phase,
-                        live):
+                        live, slots=None, **counts):
         """One unified paged step (decode OR prompt chunk — ``phase``
         names it for the profiler/traces) + one sampled token per
         ``do`` row, host-side np result.  Same graph/host sampling
-        split as the contiguous plane's ``_decode_and_sample``.  The
-        span carries what attention has to read: ``rows`` (``live``,
-        the slots this dispatch works for), ``kv_tokens``, the sum of
-        their frontiers after the step, and ``q_tokens``, the query
-        rows they bring (one each in a decode step)."""
-        work = {"rows": len(live),
-                "kv_tokens": int((pos[live] + val[live]).sum()),
-                "q_tokens": int(val[live].sum())}
+        split as the contiguous plane's ``_decode_and_sample``.  A
+        decode step's rows are the slots; a prompt chunk's are
+        compacted, row ``k`` working for slot ``slots[k]``.  The span
+        carries what attention has to read: ``rows`` (``live``, the
+        rows of the arrays this dispatch works for), ``kv_tokens``,
+        the sum of their frontiers after the step, and ``q_tokens``,
+        the query rows they bring (one each in a decode step); the
+        caller's ``counts`` ride beside them."""
+        work = dict(counts, rows=len(live),
+                    kv_tokens=int((pos[live] + val[live]).sum()),
+                    q_tokens=int(val[live].sum()))
+        temps, top_ks = st.temps, st.top_ks
+        if slots is not None:
+            temps, top_ks = temps[slots], top_ks[slots]
         if st.store.sample_mode == "graph":
             with _profiler.phase(phase, **work):
-                toks_dev, st.keys = st.take(
-                    st.store.run_paged_step_sample(
+                if slots is None:
+                    out = st.store.run_paged_step_sample(
                         *st.pools, tables, toks, pos, val, st.keys,
-                        st.temps, st.top_ks, do, scales=st.scales))
+                        temps, top_ks, do, scales=st.scales)
+                else:
+                    out = st.store.run_paged_chunk_sample(
+                        *st.pools, tables, toks, pos, val, st.keys,
+                        temps, top_ks, do, slots, scales=st.scales)
+                toks_dev, st.keys = st.take(out)
             with _profiler.phase("serve_sample"):
                 out = self._fetch_decode(toks_dev)
             # a model's own counters ride behind the sampled tokens
@@ -1514,10 +1536,15 @@ class GenerationEngine:
                 *st.pools, tables, toks, pos, val, scales=st.scales))
         with _profiler.phase("serve_sample"):
             logits = self._fetch_decode(logits_dev)
-            from .program_store import host_sample
-            toks_out, carry = host_sample(logits, st.keys, st.temps,
-                                          st.top_ks)
-            st.keys = jnp.where(jnp.asarray(do)[:, None], carry, st.keys)
+            from .program_store import host_sample, host_sample_chunk
+            if slots is None:
+                toks_out, carry = host_sample(logits, st.keys, temps,
+                                              top_ks)
+                st.keys = jnp.where(jnp.asarray(do)[:, None], carry,
+                                    st.keys)
+            else:
+                toks_out, st.keys = host_sample_chunk(
+                    logits, st.keys, temps, top_ks, do, slots)
             return np.asarray(toks_out)
 
     def _paged_decode_step(self, model, st, dec):
@@ -1604,39 +1631,43 @@ class GenerationEngine:
         emitted), so feed them through the draft's logits-discarded
         prefill-mirror program in ``prefill_chunk``-sized dispatches
         (per-row ``valid`` masks ragged gaps), exactly like the prompt
-        mirror.  Leaves every slot at gap 0."""
+        mirror, and as wide: ``chunk_rows(slots)`` rows, the slots
+        that lag taken that many at a time.  Leaves every slot at gap
+        0."""
         draft = st.draft
-        n = len(st.slots)
+        n = draft.chunk_rows(len(st.slots))
         chunk = draft.prefill_chunk
-        done = 0
-        maxgap = max(gap[i] for i in dec)
-        while done < maxgap:
-            tables = np.zeros((n, st.tb), np.int32)
-            toks = np.zeros((n, chunk), np.int32)
-            pos = np.zeros((n,), np.int32)
-            val = np.ones((n,), np.int32)
-            for i in dec:
-                rem = gap[i] - done
-                if rem <= 0:
-                    continue
-                r = st.slots[i]
-                take = min(chunk, rem)
-                base = int(st.dlen[i]) + done
-                plen = len(r.prompt)
-                for c in range(take):
-                    # a lazily-mirrored slot catches up from inside
-                    # its prompt; past plen the replay is the emitted
-                    # stream (idx L-1 at most — index len(tokens)-2)
-                    idx = base + c
-                    toks[i, c] = (r.prompt[idx] if idx < plen
-                                  else r.tokens[idx - plen])
-                tables[i] = st.tables[i]
-                pos[i] = base
-                val[i] = take
-            st.take_draft(draft.run_paged_step(
-                *st.dpools, tables, toks, pos, val, scales=st.dscales))
-            self._stats.inc("spec_draft_steps")
-            done += chunk
+        behind = [i for i in dec if gap[i] > 0]
+        for lo in range(0, len(behind), n):
+            group = behind[lo:lo + n]
+            for done in range(0, max(gap[i] for i in group), chunk):
+                tables = np.zeros((n, st.tb), np.int32)
+                toks = np.zeros((n, chunk), np.int32)
+                pos = np.zeros((n,), np.int32)
+                val = np.ones((n,), np.int32)
+                for k, i in enumerate(group):
+                    rem = gap[i] - done
+                    if rem <= 0:
+                        continue
+                    r = st.slots[i]
+                    take = min(chunk, rem)
+                    base = int(st.dlen[i]) + done
+                    plen = len(r.prompt)
+                    for c in range(take):
+                        # a lazily-mirrored slot catches up from inside
+                        # its prompt; past plen the replay is the
+                        # emitted stream (idx L-1 at most — index
+                        # len(tokens)-2)
+                        idx = base + c
+                        toks[k, c] = (r.prompt[idx] if idx < plen
+                                      else r.tokens[idx - plen])
+                    tables[k] = st.tables[i]
+                    pos[k] = base
+                    val[k] = take
+                st.take_draft(draft.run_paged_step(
+                    *st.dpools, tables, toks, pos, val,
+                    scales=st.dscales))
+                self._stats.inc("spec_draft_steps")
         for i in dec:
             st.dlen[i] += gap[i]
             gap[i] = 0
@@ -1838,13 +1869,20 @@ class GenerationEngine:
                 accepted)
 
     def _paged_prefill_chunk(self, model, st, pre):
-        """Advance every prefilling slot one prompt chunk
-        (serve_prefill phase).  Rows finishing their prompt this
-        dispatch sample their first token (the TTFT moment), register
-        their blocks with the prefix cache and flip to decoding."""
+        """Advance the first of the prefilling slots ``pre`` (oldest
+        admission first) one prompt chunk (serve_prefill phase); the
+        rest wait a tick.  The dispatch is compacted:
+        ``chunk_rows(slots)`` rows, row ``k`` working for slot
+        ``pre[k]``; rows past the live ones ride as a decode step's
+        dead rows do (zero table, one valid token, no sampling).  Rows
+        finishing their prompt this dispatch sample their first token
+        (the TTFT moment), register their blocks with the prefix cache
+        and flip to decoding."""
         store = st.store
         bs = store.kv_block
         chunk = store.prefill_chunk
+        n = store.chunk_rows(len(st.slots))
+        pre, deferred = pre[:n], len(pre[n:])
         rows = []
         for i in pre:
             r = st.slots[i]
@@ -1857,24 +1895,27 @@ class GenerationEngine:
                      if st.tables[i, p // bs] == 0]
             self._paged_write_ready(st, i, fresh)
             rows.append((i, r, p0, ntok))
-        n = len(st.slots)
         tables = np.zeros((n, st.tb), np.int32)
         toks = np.zeros((n, chunk), np.int32)
         pos = np.zeros((n,), np.int32)
         val = np.ones((n,), np.int32)
         do = np.zeros((n,), bool)
-        for i, r, p0, ntok in rows:
-            tables[i] = st.tables[i]
-            toks[i, :ntok] = r.prompt[p0:p0 + ntok]
-            pos[i] = p0
-            val[i] = ntok
-            do[i] = (p0 + ntok == len(r.prompt))
+        slots = np.zeros((n,), np.int32)
+        for k, (i, r, p0, ntok) in enumerate(rows):
+            tables[k] = st.tables[i]
+            toks[k, :ntok] = r.prompt[p0:p0 + ntok]
+            pos[k] = p0
+            val[k] = ntok
+            do[k] = (p0 + ntok == len(r.prompt))
+            slots[k] = i
         try:
             with _tracing.activate_many(
                     [(r.trace, r.trace_parent)
                      for _i, r, _p, _n in rows]):
                 sampled = self._paged_dispatch(
-                    st, tables, toks, pos, val, do, "serve_prefill", pre)
+                    st, tables, toks, pos, val, do, "serve_prefill",
+                    np.arange(len(rows)), slots, width=n,
+                    deferred=deferred)
                 if st.draft is not None and st.spec_mirror():
                     # mirror the chunk into the draft's KV plane
                     # (logits unfetched, discarded): same tables, same
@@ -1900,8 +1941,10 @@ class GenerationEngine:
             return
         self._stats.inc("prefills")
         self._stats.inc("prefill_chunks", len(rows))
+        self._stats.inc("prefill_row_slots", n)
+        self._stats.inc("prefill_rows_deferred", deferred)
         with _profiler.phase("serve_resolve") as span:
-            for i, r, p0, ntok in rows:
+            for k, (i, r, p0, ntok) in enumerate(rows):
                 st.prog[i] = p0 + ntok
                 st.lengths[i] = p0 + ntok
                 if st.draft is not None and st.spec_mirror():
@@ -1912,7 +1955,7 @@ class GenerationEngine:
                 if _metrics.phase_on():
                     _H_CHUNKS.observe(int(st.chunks_done[i]))
                 st.prefix.register(r.prompt, st.tables[i])
-                tok = int(sampled[i])
+                tok = int(sampled[k])
                 self._push_token(r, tok)
                 span.add(tokens=1)
                 reason = self._finished_reason(r, tok)

@@ -162,10 +162,28 @@ def sample_tokens(logits, keys, temps, top_ks):
     return jnp.where(temps <= 0.0, greedy, sampled), carry
 
 
-# the host escape hatch's sampler: the same function, jitted standalone
-# (jax re-specializes per logits shape; the decode engine calls it on
-# the fetched (slots, vocab) matrix)
+def sample_chunk_rows(logits, keys, temps, top_ks, do_sample, slots):
+    """:func:`sample_tokens` for the ``R`` rows of a compacted
+    prompt-chunk dispatch.  Row ``k`` works for slot ``slots[k]``: it
+    draws with that slot's chain out of ``keys (S, 2)``, and the chain
+    advances only where ``do_sample[k]`` is set (the rows finishing
+    their prompt).  Every other slot's key comes back bit-equal: a row
+    that does not sample is sent past the slot axis and dropped by the
+    scatter, so a padding row (slot 0, ``do_sample`` False) cannot
+    collide with the live row of slot 0.  ``temps`` and ``top_ks`` are
+    per row.  Returns ``(tokens (R,) int32, new_keys (S, 2))``."""
+    keys = jnp.asarray(keys, jnp.uint32)
+    slots = jnp.asarray(slots, jnp.int32)
+    toks, carry = sample_tokens(logits, keys[slots], temps, top_ks)
+    dest = jnp.where(jnp.asarray(do_sample), slots, keys.shape[0])
+    return toks, keys.at[dest].set(carry, mode="drop")
+
+
+# the host escape hatch's samplers: the same functions, jitted
+# standalone (jax re-specializes per logits shape; the decode engine
+# calls them on the fetched (rows, vocab) matrix)
 host_sample = jax.jit(sample_tokens)
+host_sample_chunk = jax.jit(sample_chunk_rows)
 
 
 def _masked_dist(logits, temps, top_ks):
@@ -873,6 +891,127 @@ def _serving_model(arch):
     return importlib.import_module("..models." + arch, __package__)
 
 
+def chunk_rows(bb):
+    """How many rows the prompt-chunk dispatch of a ``bb``-slot bucket
+    has.  A chunk program costs by its rows, live or dead, and a
+    steady batch keeps about a fifth of its slots in their prompt; the
+    decode program stays ``bb`` wide.  A quarter of the slots, never
+    under four rows while the bucket has them (PERF.md section 6, PR 27: the
+    sweep over an eighth, a quarter and a half)."""
+    return max(min(bb, 4), bb // 4)
+
+
+PAGED_KINDS = ("paged_step", "paged_step_sample", "paged_step_sample_p",
+               "paged_chunk_sample", "paged_verify")
+
+
+def paged_program(model, spec, kind, lq, kv_block, nleaf, int8=False):
+    """The function one paged step program compiles, named as the
+    profiler's module line shows it, and the positions of the
+    arguments it donates: ``(fn, donate)``.
+
+    ONE unified step for the paged plane: ``lq`` is the query length (1
+    = a decode step; prefill_chunk = one prompt chunk; spec_k+1 = a
+    speculative verify).  Write-then-attend over the global pool
+    through ``(rows, table_width)`` block tables; rows not taking part
+    in a dispatch ride with all-zero tables (they reach only the
+    reserved trash block 0) and their outputs are discarded host-side.
+    ``fn(params, *pool leaves, tables, tokens, positions, valid,
+    *the kind's own)``; on the int8 plane every kind gains the two
+    donated scale pools right after the ``nleaf`` code pools, in
+    arguments AND returns.  ``model`` is a decode-mode model module
+    (``_serving_model``), ``spec`` its serving spec."""
+    npool = nleaf + (2 if int8 else 0)
+    pool_donate = tuple(range(1, 1 + npool))
+    name = "paged_verify" if kind == "paged_verify" else \
+        "paged_decode" if int(lq) == 1 and kind != "paged_chunk_sample" \
+        else "paged_prefill_chunk"
+
+    def step(params, pls, tables, tokens, positions, valid,
+             all_logits=False):
+        # the model's paged step with the donated leaves threaded
+        # through uniformly: returns (logits, new leaves, the model's
+        # counters or None)
+        return model.paged_step(
+            params, pls[:nleaf], tables, tokens, positions, valid, spec,
+            kv_block, scales=tuple(pls[nleaf:]) if int8 else None,
+            all_logits=all_logits)
+
+    if kind in ("paged_step_sample", "paged_step_sample_p",
+                "paged_chunk_sample"):
+        # in-graph sampling with a per-row enable mask: a chunk
+        # dispatch samples ONLY the rows finishing their prompt this
+        # tick (do_sample), everyone else's PRNG chain must not
+        # advance.  The _p variant additionally emits the proposal
+        # distribution q — the draft model's step in speculative
+        # decoding.  paged_chunk_sample is the COMPACTED prompt chunk:
+        # its rows are the slots in their prompt, fewer than the slots
+        # whose (S, 2) key chains it takes and hands back, and
+        # ``slots`` (last argument) says which slot each row works for.
+        with_q = kind == "paged_step_sample_p"
+        compact = kind == "paged_chunk_sample"
+
+        def fn(params, *rest):
+            pls = rest[:npool]
+            (tables, tokens, positions, valid, keys, temps, top_ks,
+             do_sample) = rest[npool:npool + 8]
+            logits, new_pools, aux = step(
+                params, pls, tables, tokens, positions, valid)
+            if compact:
+                toks, new_keys = sample_chunk_rows(
+                    logits, keys, temps, top_ks, do_sample,
+                    rest[npool + 8])
+            else:
+                if with_q:
+                    toks, carry, q = sample_tokens_p(
+                        logits, keys, temps, top_ks)
+                else:
+                    toks, carry = sample_tokens(logits, keys, temps,
+                                                top_ks)
+                new_keys = jnp.where(do_sample[:, None], carry, keys)
+            if aux is not None:
+                # the model's counters ride behind the tokens: one
+                # small array, one fetch
+                toks = jnp.concatenate([toks, aux.astype(toks.dtype)])
+            head = (toks, q) if with_q else (toks,)
+            return head + new_pools + (new_keys,)
+
+        donate = pool_donate + (1 + npool + 4,)
+    elif kind == "paged_verify":
+        # speculative verify: all lq=K+1 positions' logits stay
+        # in-graph, the rejection rule runs beside them (spec_verify),
+        # and the host fetch is two small integer vectors — never
+        # logits.  tokens[:, 0] is the slot's pending next token,
+        # tokens[:, 1:] the K draft proposals; prop_q is the draft's
+        # (bb, K, vocab) proposal distribution from
+        # paged_step_sample_p.
+        def fn(params, *rest):
+            pls = rest[:npool]
+            (tables, tokens, positions, valid, prop_q, keys, temps,
+             top_ks, do_sample) = rest[npool:]
+            logits_all, new_pools, _ = step(
+                params, pls, tables, tokens, positions, valid,
+                all_logits=True)
+            out, n_emit, carry = spec_verify(
+                logits_all, tokens[:, 1:], prop_q, keys, temps, top_ks,
+                valid)
+            new_keys = jnp.where(do_sample[:, None], carry, keys)
+            return (out, n_emit) + new_pools + (new_keys,)
+
+        donate = pool_donate + (1 + npool + 5,)
+    else:   # paged_step (logits out — the host-sampling hatch)
+        def fn(params, *rest):
+            pls = rest[:npool]
+            tables, tokens, positions, valid = rest[npool:]
+            logits, new_pools, _ = step(
+                params, pls, tables, tokens, positions, valid)
+            return (logits,) + new_pools
+
+        donate = pool_donate
+    fn.__name__ = name
+    return fn, donate
+
+
 class GenerativeProgramStore:
     """AOT prefill/decode programs for one autoregressive LM.
 
@@ -1045,11 +1184,10 @@ class GenerativeProgramStore:
 
         # one warm sweep must fit the LRU or AOT is a lie (the forward
         # store logs the same hazard; here we just size for it).  The
-        # paged plane's warm set is per (batch bucket, step length):
-        # one decode (lq=1) and one prefill-chunk program per bucket.
+        # paged plane's warm set is two step programs a batch bucket:
+        # the decode step and the compacted prompt chunk.
         if self.paged:
-            n_warm = (len(self._batch_edges) *
-                      len({1, self.prefill_chunk}))
+            n_warm = 2 * len(self._batch_edges)
         else:
             n_warm = (len(self._batch_edges) * len(self._prompt_edges) +
                       len(self._batch_edges) *
@@ -1212,6 +1350,18 @@ class GenerativeProgramStore:
                 "MAX (%d)" % (c, self.kv_max))
         return c
 
+    chunk_rows = staticmethod(chunk_rows)
+
+    def chunk_program(self, bb):
+        """``(kind, bucket, lq)`` of the prompt-chunk program of slot
+        bucket ``bb``, the one chunk program that bucket dispatches: in
+        graph mode a program of the bucket itself (it takes the
+        bucket's key chains), in host mode the logits-out step at the
+        chunk's width."""
+        if self.sample_mode == "graph":
+            return ("paged_chunk_sample", bb, self.prefill_chunk)
+        return ("paged_step", self.chunk_rows(bb), self.prefill_chunk)
+
     def table_width(self):
         """Block-table width of the paged plane: logical blocks needed
         to address a full kv_max-token sequence."""
@@ -1335,6 +1485,28 @@ class GenerativeProgramStore:
                                   self.pool_blocks), jnp.float32),) * 2
         return leaves
 
+    def _paged_avals(self, kind, bb, lq):
+        """``(shape, dtype)`` of a paged program's own arguments, the
+        ones after params and pools: tables, tokens, positions, valid,
+        then the kind's.  ``bb`` is the SLOT bucket.  Every kind is bb
+        rows wide but the compacted prompt chunk: ``chunk_rows(bb)``
+        rows beside the bb slots' key chains, and which slot each row
+        works for."""
+        compact = kind == "paged_chunk_sample"
+        rows = self.chunk_rows(bb) if compact else bb
+        avals = [((rows, self.table_width()), np.int32),
+                 ((rows, int(lq)), np.int32),
+                 ((rows,), np.int32), ((rows,), np.int32)]
+        if kind == "paged_verify":     # the draft's proposal densities
+            avals.append(((bb, int(lq) - 1, self._spec["vocab_size"]),
+                          np.float32))
+        if kind != "paged_step":       # keys, temps, top_ks, do_sample
+            avals += [((bb, 2), np.uint32), ((rows,), np.float32),
+                      ((rows,), np.int32), ((rows,), np.bool_)]
+        if compact:
+            avals.append(((rows,), np.int32))
+        return avals
+
     def _key(self, kind, bb, lb):
         # (kind, batch bucket, length bucket) + the serving dtypes +
         # the dispatch fingerprint (prefill/decode trace through
@@ -1351,130 +1523,16 @@ class GenerativeProgramStore:
         tic = time.perf_counter()
         spec = self._spec
         kv = self.kv_dtype
-        if kind in ("paged_step", "paged_step_sample",
-                    "paged_step_sample_p", "paged_verify"):
-            # ONE unified step program for the paged plane: lb is the
-            # query length lq (1 = a decode step; prefill_chunk = one
-            # prompt chunk; spec_k+1 = a speculative verify).  Write-
-            # then-attend over the global pool through (bb, table_width)
-            # block tables; rows not participating in a dispatch ride
-            # with all-zero tables (they reach only the reserved trash
-            # block 0) and their outputs are discarded host-side.  On
-            # the int8 plane every kind gains the two donated scale
-            # pools right after the code pools, in arguments AND
-            # returns.
-            bs = self.kv_block
-            tb = self.table_width()
-            int8 = self.kv_int8
-            pools = self._pool_spec()
-            npool = len(pools)
-            nleaf = self.pool_leaves
-            base = (self._param_spec(),) + pools + (
-                self._sds((bb, tb), jnp.int32),
-                self._sds((bb, int(lb)), jnp.int32),
-                self._sds((bb,), jnp.int32),
-                self._sds((bb,), jnp.int32))
-            samp = (self._sds((bb, 2), jnp.uint32),
-                    self._sds((bb,), jnp.float32),
-                    self._sds((bb,), jnp.int32),
-                    self._sds((bb,), jnp.bool_))
-            pool_donate = tuple(range(1, 1 + npool))
-            # its own name on the profiler's module line
-            name = "paged_verify" if kind == "paged_verify" else \
-                "paged_decode" if int(lb) == 1 else "paged_prefill_chunk"
-
-            def step(params, pls, tables, tokens, positions, valid,
-                     all_logits=False):
-                # the model's paged step with the donated leaves
-                # threaded through uniformly: returns (logits, new
-                # leaves, the model's counters or None)
-                return model.paged_step(
-                    params, pls[:nleaf], tables, tokens, positions,
-                    valid, spec, bs,
-                    scales=tuple(pls[nleaf:]) if int8 else None,
-                    all_logits=all_logits)
-
-            if kind in ("paged_step_sample", "paged_step_sample_p"):
-                # in-graph sampling with a per-row enable mask: a
-                # chunk dispatch samples ONLY the rows finishing their
-                # prompt this tick (do_sample), everyone else's PRNG
-                # chain must not advance.  The _p variant additionally
-                # emits the proposal distribution q — the draft model's
-                # step in speculative decoding.
-                with_q = kind == "paged_step_sample_p"
-
-                def fn(params, *rest):
-                    pls = rest[:npool]
-                    (tables, tokens, positions, valid, keys, temps,
-                     top_ks, do_sample) = rest[npool:]
-                    logits, new_pools, aux = step(
-                        params, pls, tables, tokens, positions, valid)
-                    if with_q:
-                        toks, carry, q = sample_tokens_p(
-                            logits, keys, temps, top_ks)
-                    else:
-                        toks, carry = sample_tokens(logits, keys,
-                                                    temps, top_ks)
-                    if aux is not None:
-                        # the model's counters ride behind the tokens:
-                        # one small array, one fetch
-                        toks = jnp.concatenate(
-                            [toks, aux.astype(toks.dtype)])
-                    new_keys = jnp.where(do_sample[:, None], carry,
-                                         keys)
-                    head = (toks, q) if with_q else (toks,)
-                    return head + new_pools + (new_keys,)
-
-                fn.__name__ = name
-                args = base + samp
-                compiled = jax.jit(
-                    fn, donate_argnums=cache_donate_argnums(
-                        pool_donate + (len(base),))) \
-                    .lower(*args).compile()
-            elif kind == "paged_verify":
-                # speculative verify: all lb=K+1 positions' logits stay
-                # in-graph, the rejection rule runs beside them
-                # (spec_verify), and the host fetch is two small
-                # integer vectors — never logits.  tokens[:, 0] is the
-                # slot's pending next token, tokens[:, 1:] the K draft
-                # proposals; prop_q is the draft's (bb, K, vocab)
-                # proposal distribution from paged_step_sample_p.
-                K = int(lb) - 1
-
-                def fn(params, *rest):
-                    pls = rest[:npool]
-                    (tables, tokens, positions, valid, prop_q, keys,
-                     temps, top_ks, do_sample) = rest[npool:]
-                    logits_all, new_pools, _ = step(
-                        params, pls, tables, tokens, positions, valid,
-                        all_logits=True)
-                    out, n_emit, carry = spec_verify(
-                        logits_all, tokens[:, 1:], prop_q, keys,
-                        temps, top_ks, valid)
-                    new_keys = jnp.where(do_sample[:, None], carry,
-                                         keys)
-                    return (out, n_emit) + new_pools + (new_keys,)
-
-                fn.__name__ = name
-                args = base + (self._sds((bb, K, spec["vocab_size"]),
-                                         jnp.float32),) + samp
-                compiled = jax.jit(
-                    fn, donate_argnums=cache_donate_argnums(
-                        pool_donate + (len(base) + 1,))) \
-                    .lower(*args).compile()
-            else:   # paged_step (logits out — the host-sampling hatch)
-                def fn(params, *rest):
-                    pls = rest[:npool]
-                    tables, tokens, positions, valid = rest[npool:]
-                    logits, new_pools, _ = step(
-                        params, pls, tables, tokens, positions, valid)
-                    return (logits,) + new_pools
-
-                fn.__name__ = name
-                compiled = jax.jit(
-                    fn,
-                    donate_argnums=cache_donate_argnums(pool_donate)) \
-                    .lower(*base).compile()
+        if kind in PAGED_KINDS:
+            args = (self._param_spec(),) + self._pool_spec() + tuple(
+                self._sds(shape, dtype)
+                for shape, dtype in self._paged_avals(kind, bb, lb))
+            fn, donate = paged_program(model, spec, kind, lb,
+                                       self.kv_block, self.pool_leaves,
+                                       self.kv_int8)
+            compiled = jax.jit(
+                fn, donate_argnums=cache_donate_argnums(donate)) \
+                .lower(*args).compile()
             ms = (time.perf_counter() - tic) * 1e3
             mem = compiled.memory_analysis()
             return _Program(compiled, (bb, lb), (), ms,
@@ -1576,10 +1634,10 @@ class GenerativeProgramStore:
         {(kind, bb, lb): compile_ms}."""
         out = {}
         if self.paged:
-            # the paged plane's whole program space: one unified step
-            # program per (batch bucket, step length) — lq=1 decode
-            # steps and lq=prefill_chunk prompt chunks.  kv_depth is
-            # moot: the table width is a store constant, so cache
+            # the paged plane's whole program space: two step
+            # programs a batch bucket — the bb-wide lq=1 decode step
+            # and the prompt chunk over chunk_rows(bb) rows.  kv_depth
+            # is moot: the table width is a store constant, so cache
             # depth never changes the program.  Warmup executes on a
             # throwaway zero pool with all-zero tables (every write
             # lands in the trash block).
@@ -1587,12 +1645,13 @@ class GenerativeProgramStore:
                      else "paged_step")
             pools = None        # ONE throwaway pool through all of them
             for bb in self._batch_edges:
-                for lq in sorted({1, self.prefill_chunk}):
-                    prog = self._acquire(pkind, bb, lq)
-                    out[(pkind, bb, lq)] = prog.compile_ms
+                for key in ((pkind, bb, 1), self.chunk_program(bb)):
+                    if key in out:      # two buckets, one chunk width
+                        continue
+                    prog = self._acquire(*key)
+                    out[key] = prog.compile_ms
                     if execute:
-                        pools = self._exec_paged_zeros(pkind, prog, bb,
-                                                       lq, pools)
+                        pools = self._exec_paged_zeros(*key, prog, pools)
             if execute:
                 # the copy-on-write fork is a program of the tick too:
                 # left to its first use it compiles under traffic
@@ -1636,7 +1695,7 @@ class GenerativeProgramStore:
                             prog.fn(self._params, ck, cv, toks, lens))
         return out
 
-    def _exec_paged_zeros(self, kind, prog, bb, lq, pools=None):
+    def _exec_paged_zeros(self, kind, bb, lq, prog, pools=None):
         """Execute one paged program once on a throwaway zero pool with
         all-zero tables (every write lands in the trash block): the
         one-time XLA executable setup must not land inside a served
@@ -1647,25 +1706,11 @@ class GenerativeProgramStore:
             pools = self.new_pool()
             if self.kv_int8:
                 pools = pools + self.new_scale_pool()
-        tbls = np.zeros((bb, self.table_width()), np.int32)
-        toks = np.zeros((bb, lq), np.int32)
-        pos = np.zeros((bb,), np.int32)
-        val = np.ones((bb,), np.int32)
-        samp = (np.zeros((bb, 2), np.uint32),
-                np.zeros((bb,), np.float32),
-                np.zeros((bb,), np.int32),
-                np.zeros((bb,), np.bool_))
-        if kind == "paged_verify":
-            q = np.zeros((bb, lq - 1, self._spec["vocab_size"]),
-                         np.float32)
-            args = (self._params,) + pools + (tbls, toks, pos, val,
-                                              q) + samp
-        elif kind in ("paged_step_sample", "paged_step_sample_p"):
-            args = (self._params,) + pools + (tbls, toks, pos,
-                                              val) + samp
-        else:
-            args = (self._params,) + pools + (tbls, toks, pos, val)
-        out = jax.block_until_ready(prog.fn(*args))
+        own = [np.zeros(shape, dtype)
+               for shape, dtype in self._paged_avals(kind, bb, lq)]
+        own[3][:] = 1       # one valid token a row
+        out = jax.block_until_ready(
+            prog.fn(self._params, *pools, *own))
         head = 2 if kind in ("paged_step_sample_p", "paged_verify") else 1
         return tuple(out[head:head + len(pools)])
 
@@ -1674,7 +1719,8 @@ class GenerativeProgramStore:
         traffic: the TARGET's verify programs (lq = spec_k + 1), or —
         ``draft=True`` — the DRAFT's proposal programs (lq=1
         ``paged_step_sample_p``) plus its logits-discarded
-        prefill-mirror chunks (lq = prefill_chunk ``paged_step``).
+        prefill-mirror chunks (lq = prefill_chunk ``paged_step``, as
+        wide as the target's compacted chunk: ``chunk_rows(bb)``).
         ``registry.add_draft_model`` warms both sides, so attaching a
         draft never compiles inside a served request.  Returns
         {(kind, bb, lq): compile_ms}."""
@@ -1683,16 +1729,19 @@ class GenerativeProgramStore:
                 "speculative decoding needs the paged plane (store %r "
                 "has paged=False)" % self.name)
         self._need("draft", "speculative decoding")
-        kinds = ([("paged_step_sample_p", 1),
-                  ("paged_step", self.prefill_chunk)] if draft
-                 else [("paged_verify", int(spec_k) + 1)])
         out = {}
         for bb in self._batch_edges:
-            for kind, lq in kinds:
-                prog = self._acquire(kind, bb, lq)
-                out[(kind, bb, lq)] = prog.compile_ms
+            keys = ([("paged_step_sample_p", bb, 1),
+                     ("paged_step", self.chunk_rows(bb),
+                      self.prefill_chunk)] if draft
+                    else [("paged_verify", bb, int(spec_k) + 1)])
+            for key in keys:
+                if key in out:
+                    continue
+                prog = self._acquire(*key)
+                out[key] = prog.compile_ms
                 if execute:
-                    self._exec_paged_zeros(kind, prog, bb, lq)
+                    self._exec_paged_zeros(*key, prog)
         return out
 
     # -- execution -----------------------------------------------------
@@ -1750,6 +1799,9 @@ class GenerativeProgramStore:
         tables first and tokens second."""
         n = self.pool_leaves
         bb, lq = args[n + 1].shape
+        if kind == "paged_chunk_sample":
+            # a program of its slot bucket: the key chains' axis
+            bb = args[n + 4].shape[0]
         prog = self._acquire(kind, int(bb), int(lq))
         return prog.fn(self._params, *(self._pool_args(args[:n], scales)
                                        + tuple(args[n:])))
@@ -1780,6 +1832,20 @@ class GenerativeProgramStore:
         is garbage the caller discards); pools and keys are consumed
         (donated) — callers rebind."""
         return self._run_paged('paged_step_sample', args, scales)
+
+    @hot_path
+    def run_paged_chunk_sample(self, *args, scales=None):
+        """Dispatch one COMPACTED prompt chunk with in-graph sampling:
+        ``run_paged_chunk_sample(*pool leaves, tables, tokens,
+        positions, valid, keys, temps, top_ks, do_sample, slots)``.
+        The dispatch arrays have ``chunk_rows(S)`` rows, row ``k``
+        working for slot ``slots[k]``; ``keys`` is all ``S`` slots'
+        ``(S, 2)`` chains.  Returns ``(tokens (rows,) int32 [+ the
+        model's counters], *pool leaves, new_keys (S, 2))``: only the
+        slots of rows with ``do_sample`` set advance their chain
+        (:func:`sample_chunk_rows`).  Pools and keys are consumed
+        (donated) — callers rebind."""
+        return self._run_paged('paged_chunk_sample', args, scales)
 
     @hot_path
     def run_paged_step_sample_p(self, *args, scales=None):

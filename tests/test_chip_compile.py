@@ -232,55 +232,148 @@ def test_flash_block_must_be_mosaic_tileable(compiled_mode, monkeypatch):
     assert dispatch.eligible_attention(2, 4, 200, 200, D, "float32")
 
 
-# The paged step programs at the served model's widths (16 heads x 128,
-# 2048 wide, 16 slots of 16 blocks of 64 tokens), two layers deep: the
-# KV pool is addressed in place from entry to exit.  A scatter on the
-# pool, or a layer of it sliced out for the kernel, makes the compiler
-# relay the whole pool around the program (docs/architecture/
-# decode_engine.md, "The pool stays where it is").
-SLOTS, LAYERS = 16, 2
-POOL_ROWS = (SLOTS * T + 1) * BS
+# The paged step programs at the served models' widths, two layers
+# deep, compiled as the store compiles them (``paged_program``): the
+# decode step over the slots and the prompt chunk over
+# ``chunk_rows(slots)`` rows beside the slots' key chains.  The KV pool
+# is addressed in place from entry to exit.  A scatter on the pool, or
+# a layer of it sliced out for the kernel, makes the compiler relay the
+# whole pool around the program (docs/architecture/decode_engine.md,
+# "The pool stays where it is").
+LAYERS = 2
 _MOVES_THE_POOL = ("copy", "slice", "scatter", "fusion")
 
 
-@pytest.mark.parametrize("lq", [1, 32], ids=["decode", "prefill-chunk"])
-def test_paged_program_leaves_the_pool_in_place(chip, compiled_mode, lq):
-    import re
-    from mxnet_tpu.models.transformer_lm import (get_symbol, lm_spec,
-                                                 paged_step_apply)
-    from mxnet_tpu.serving.program_store import sample_tokens
-
-    spec = lm_spec(num_layers=LAYERS, num_hidden=W, num_heads=H,
-                   vocab_size=V)
-    net = get_symbol(seq_len=8, **spec)
+def _lm_program(chip):
+    """``lm2048``'s widths (16 heads x 128, 2048 wide), 16 slots of 16
+    blocks of 64 tokens, chunks of 32, fp32 pools."""
+    lm = importlib.import_module("mxnet_tpu.models.transformer_lm")
+    spec = lm.lm_spec(num_layers=LAYERS, num_hidden=W, num_heads=H,
+                      vocab_size=V)
+    net = lm.get_symbol(seq_len=8, **spec)
     shapes, _, _ = net.infer_shape(data=(1, 8), softmax_label=(1, 8))
     params = {n: chip(s) for n, s in zip(net.list_arguments(), shapes)
               if n not in ("data", "softmax_label")}
-    pool = chip((LAYERS, H, POOL_ROWS, D))
+    pool = chip((LAYERS, H, (16 * T + 1) * BS, D))
+    return dict(model=lm, spec=spec, params=params, pools=(pool, pool),
+                slots=16, width=T, chunk=32, kernels=LAYERS,
+                pool_shaped=r"f32\[(?:%d,|1,)?%d,%d,%d\]"
+                % ((LAYERS,) + pool.shape[1:]))
 
-    def step(params, pk, pv, tables, tokens, positions, valid, keys,
-             temps, top_ks):
-        logits, pk, pv = paged_step_apply(params, pk, pv, tables, tokens,
-                                          positions, valid, spec, BS)
-        toks, keys = sample_tokens(logits, keys, temps, top_ks)
-        return toks, pk, pv, keys
 
-    compiled = jax.jit(step, donate_argnums=(1, 2, 7)).lower(
-        params, pool, pool, chip((SLOTS, T), I32), chip((SLOTS, lq), I32),
-        chip((SLOTS,), I32), chip((SLOTS,), I32),
-        chip((SLOTS, 2), jnp.uint32), chip((SLOTS,)),
-        chip((SLOTS,), I32)).compile()
+def _deepseek_program(chip):
+    """DeepSeek-V3's published widths, one dense and one expert layer
+    of 16 held experts, 64 slots of 104 blocks of 64 tokens, chunks of
+    32, bfloat16 weights and latent pool."""
+    from mxnet_tpu.models import deepseek_v3 as ds
+    spec = ds.serving_spec({
+        "num_hidden_layers": LAYERS, "first_k_dense_replace": 1,
+        "hidden_size": 7168, "num_attention_heads": 128,
+        "q_lora_rank": 1536, "kv_lora_rank": 512,
+        "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+        "v_head_dim": 128, "intermediate_size": 18432,
+        "moe_intermediate_size": 2048, "n_routed_experts": 16,
+        "router_width": 256, "n_shared_experts": 1,
+        "num_experts_per_tok": 8, "n_group": 8, "topk_group": 4,
+        "routed_scaling_factor": 2.5, "vocab_size": 16160,
+        "rms_norm_eps": 1e-6, "rope_theta": 10000,
+        "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 40,
+                         "mscale": 1, "mscale_all_dim": 1,
+                         "original_max_position_embeddings": 4096,
+                         "type": "yarn"}})
+    packed = jax.eval_shape(lambda: ds.pack_params(
+        {k: jnp.zeros(v, BF16)
+         for k, v in ds.param_shapes(spec).items()}, spec))
+    params = {k: chip(v.shape, v.dtype) for k, v in packed.items()}
+    pool = chip((LAYERS, 1, (64 * 104 + 1) * 64, ds.latent_width(spec)),
+                BF16)
+    return dict(model=ds, spec=spec, params=params, pools=(pool,),
+                slots=64, width=104, chunk=32, kernels=LAYERS,
+                pool_shaped=r"bf16\[(?:%d,|1,)?1,%d,%d\]"
+                % ((LAYERS,) + pool.shape[2:]))
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill-chunk"])
+@pytest.mark.parametrize("build", [_lm_program, _deepseek_program],
+                         ids=["lm2048", "deepseek-v3"])
+def test_paged_program_leaves_the_pool_in_place(chip, compiled_mode,
+                                                build, kind):
+    import re
+    from mxnet_tpu.serving.program_store import chunk_rows, paged_program
+
+    m = build(chip)
+    slots = m["slots"]
+    if kind == "decode":
+        pkind, rows, lq = "paged_step_sample", slots, 1
+    else:
+        pkind, rows, lq = "paged_chunk_sample", chunk_rows(slots), \
+            m["chunk"]
+        assert rows == slots // 4
+    fn, donate = paged_program(m["model"], m["spec"], pkind, lq, BS,
+                               len(m["pools"]))
+    args = (m["params"],) + m["pools"] + (
+        chip((rows, m["width"]), I32), chip((rows, lq), I32),
+        chip((rows,), I32), chip((rows,), I32),
+        chip((slots, 2), jnp.uint32), chip((rows,)), chip((rows,), I32),
+        chip((rows,), jnp.bool_))
+    if kind != "decode":
+        args += (chip((rows,), I32),)
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= LAYERS  # the kernel is in it
-    pool_shaped = re.compile(
-        r"f32\[(?:%d,|1,)?%d,%d,%d\]" % (LAYERS, H, POOL_ROWS, D))
+    assert fn.__name__ == "paged_" + kind.replace("-", "_")
+    # the attention kernel is in it, a call a layer
+    assert text.count("tpu_custom_call") >= m["kernels"]
+    pool_shaped = re.compile(m["pool_shaped"])
     moved = []
     for line in text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) ([a-z-]+)\(",
-                     line)
-        if m and m.group(2) in _MOVES_THE_POOL \
-                and pool_shaped.search(m.group(1)):
+        hit = re.match(r"\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) ([a-z-]+)\(",
+                       line)
+        if hit and hit.group(2) in _MOVES_THE_POOL \
+                and pool_shaped.search(hit.group(1)):
             moved.append(line.strip()[:160])
     assert not moved, "\n".join(moved)
-    layer_bytes = H * POOL_ROWS * D * 4
-    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    if build is _lm_program:
+        pool = m["pools"][0]
+        layer_bytes = pool.size // LAYERS * pool.dtype.itemsize
+        assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
+def test_warmup_compiles_the_two_programs_a_burst_dispatches():
+    """``warmup()`` compiles exactly the decode program and the
+    compacted chunk program of each slot bucket (no slot-wide chunk
+    program), and a burst that puts every slot in its prompt compiles
+    nothing after it: the benchmark's drivers make
+    ``store_compiles_after_warmup == 0`` a condition of a run.  On the
+    CPU, at rehearsal size: what is counted is programs, not time."""
+    from mxnet_tpu.models.transformer_lm import lm_spec, random_params
+    from mxnet_tpu.serving import GenerationEngine, ModelRegistry
+
+    spec = lm_spec(num_layers=2, num_hidden=32, num_heads=4,
+                   vocab_size=50)
+    for sample, chunk_kind, rows in (
+            ("graph", "paged_chunk_sample", 16),
+            ("host", "paged_step", 4)):
+        reg = ModelRegistry()
+        store = reg.add_generative_model(
+            "m", random_params(spec, seed=3), spec, batch_buckets=(16,),
+            prompt_buckets=(8,), kv_block=8, kv_max=40, paged=True,
+            prefill_chunk=4, sample=sample, warmup=False)
+        decode_kind = "paged_step_sample" if sample == "graph" \
+            else "paged_step"
+        assert store.chunk_rows(16) == 4
+        assert set(store.warmup()) == {(decode_kind, 16, 1),
+                                       (chunk_kind, rows, 4)}
+        warm = store.stats()
+        assert warm["compiles"] == 2
+        assert [tuple(r) for r in warm["programs_resident"]] == sorted(
+            [(decode_kind, 16, 1), (chunk_kind, rows, 4)])
+        eng = GenerationEngine(reg)
+        try:
+            futs = [eng.submit("m", [i, 7, 3, 19, 4, 1, 2, 3, 9],
+                               max_tokens=3) for i in range(24)]
+            assert all(len(f.result(300).tokens) == 3 for f in futs)
+            stats = eng.stats()
+        finally:
+            eng.close()
+        assert stats["prefill_rows_deferred"] > 0
+        assert store.stats()["compiles"] == 2, sample

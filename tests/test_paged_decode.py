@@ -6,6 +6,7 @@ prefill equality, pool exhaustion throttling, the MXNET_PALLAS=0 /
 paged=False escape hatches, paged telemetry, and the banked
 serving.decode.paged.* bench gates (docs/architecture/decode_engine.md).
 """
+import functools
 import json
 import os
 
@@ -481,6 +482,143 @@ def test_paged_store_reports_program_scratch(paged_registry):
                                             for bb in BATCH_BUCKETS
                                             for lq in (1, 8)}
     assert all(isinstance(r[3], int) and r[3] >= 0 for r in rows)
+
+
+# ---------------------------------------------------------------------------
+# the compacted prompt-chunk dispatch
+# ---------------------------------------------------------------------------
+# DeepSeek-V3 at rehearsal size (tests/test_deepseek_v3.py's widths): the
+# second architecture behind the store's model seam, latent pool of one
+# leaf, expert counters behind the sampled tokens
+DS_SPEC = {
+    "arch": "deepseek_v3", "num_hidden_layers": 2,
+    "first_k_dense_replace": 1, "hidden_size": 64,
+    "num_attention_heads": 4, "q_lora_rank": 24, "kv_lora_rank": 16,
+    "qk_nope_head_dim": 8, "qk_rope_head_dim": 4, "v_head_dim": 8,
+    "intermediate_size": 96, "moe_intermediate_size": 32,
+    "n_routed_experts": 4, "router_width": 16, "n_shared_experts": 1,
+    "num_experts_per_tok": 4, "n_group": 4, "topk_group": 2,
+    "routed_scaling_factor": 2.5, "vocab_size": 96, "rms_norm_eps": 1e-6,
+    "rope_theta": 10000,
+    "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 40,
+                     "mscale": 1, "mscale_all_dim": 1,
+                     "original_max_position_embeddings": 4096,
+                     "type": "yarn"}}
+COMPACT_CHUNK = 4
+
+
+@functools.lru_cache(maxsize=None)
+def _compact_registry(arch, bb):
+    """One warmed paged registry an (architecture, slot bucket), kept
+    for the module: ONE batch bucket of ``bb`` slots, so the chunk
+    dispatch is ``chunk_rows(bb)`` = 4 rows wide from the first tick."""
+    reg = ModelRegistry()
+    kw = dict(batch_buckets=(bb,), prompt_buckets=(8,),
+              kv_block=KV_BLOCK, kv_max=KV_MAX, paged=True,
+              prefill_chunk=COMPACT_CHUNK, sample="graph")
+    if arch == "lm":
+        reg.add_generative_model("m", PARAMS, SPEC, **kw)
+    else:
+        from mxnet_tpu.models import deepseek_v3 as ds
+        params = ds.random_params(ds.serving_spec(DS_SPEC), seed=5)
+        reg.add_generative_model("m", params, DS_SPEC, **kw)
+    return reg
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.9],
+                         ids=["greedy", "sampled"])
+@pytest.mark.parametrize("bb", [8, 16])
+@pytest.mark.parametrize("arch", ["lm", "deepseek_v3"])
+def test_chunk_dispatch_runs_over_the_slots_in_their_prompt(
+        arch, bb, temperature, contig_registry):
+    """A burst that puts every slot in its prompt, and more requests
+    than slots behind it, through a chunk dispatch of 4 rows: every
+    stream equals the oracle's (the LM's contiguous twin; for the
+    latent pool, which has none, the same store fed one request at a
+    time), greedy and seeded; a chunk dispatch advances exactly the key
+    chains of the rows that sampled; the rows that go are the ones
+    admitted first, so no waiting row is passed over by a later
+    admission; and the counters read what that schedule implies."""
+    from mxnet_tpu import profiler
+    reg = _compact_registry(arch, bb)
+    store = reg.gen_store("m")
+    width = store.chunk_rows(bb)
+    assert width == 4 < bb
+    rs = np.random.RandomState(bb)
+    # distinct first tokens: no prefix is shared, every prompt token is
+    # computed, 3 or 4 chunks a request; two tokens out, so the first
+    # slots refill while the high ones are still in their prompt
+    reqs = [dict(tokens=[i] + [int(t) for t in
+                               rs.randint(0, 50, 8 + i % 8)],
+                 max_tokens=2, temperature=temperature, top_k=5,
+                 seed=100 + i) for i in range(bb + 6)]
+    if arch == "lm":
+        want = _generate(contig_registry, reqs)
+    else:
+        want = [_generate(reg, [kw])[0] for kw in reqs]
+
+    eng = GenerationEngine(reg)
+    seen = []
+    dispatch = eng._paged_dispatch
+
+    def spy(st, tables, toks, pos, val, do, phase, live, slots=None,
+            **counts):
+        if phase != "serve_prefill":
+            return dispatch(st, tables, toks, pos, val, do, phase, live,
+                            slots, **counts)
+        before = np.array(st.keys)
+        waiting = [i for i in st.active() if not st.decoding[i]]
+        out = dispatch(st, tables, toks, pos, val, do, phase, live,
+                       slots, **counts)
+        seen.append(dict(
+            rows=[int(i) for i in slots[:len(live)]],
+            sampled=[int(i) for i in slots[:len(live)][do[:len(live)]]],
+            waiting={i: st.slots[i].seq for i in waiting},
+            shape=tables.shape, counts=counts, before=before,
+            after=np.array(st.keys)))
+        return out
+
+    eng._paged_dispatch = spy
+    opened = profiler.phase_totals()
+    try:
+        futs = [eng.submit("m", **kw) for kw in reqs]
+        got = [f.result(300).tokens for f in futs]
+        stats = eng.stats()
+        admitted = [seq for _m, seq in eng._admit_log]
+    finally:
+        eng.close()
+    assert got == want
+
+    assert seen and all(d["shape"] == (width, store.table_width())
+                        for d in seen)
+    deferred = 0
+    for d in seen:
+        # the chain of a slot that did not sample is bit-equal; the
+        # ones that sampled moved
+        moved = np.any(d["before"] != d["after"], axis=1)
+        assert sorted(np.nonzero(moved)[0]) == sorted(d["sampled"])
+        # oldest first by admission: the rows are the first `width` of
+        # the waiting slots in the order they were admitted
+        order = sorted(d["waiting"],
+                       key=lambda i: admitted.index(d["waiting"][i]))
+        assert d["rows"] == order[:width]
+        assert d["counts"] == {"width": width,
+                               "deferred": len(order[width:])}
+        deferred += len(order[width:])
+    # a later admission did wait behind an earlier one in a HIGHER slot
+    assert any(max(d["rows"]) > min(set(d["waiting"]) - set(d["rows"]))
+               for d in seen if len(d["waiting"]) > width)
+    assert deferred > 0
+    assert stats["prefills"] == len(seen)
+    assert stats["prefill_chunks"] == sum(len(d["rows"]) for d in seen) \
+        == sum(-(-len(kw["tokens"]) // COMPACT_CHUNK) for kw in reqs)
+    assert stats["prefill_row_slots"] == width * len(seen)
+    assert stats["prefill_rows_deferred"] == deferred
+    span = profiler.phase_totals(since=opened)["serve_prefill"]
+    assert span["spans"] == len(seen)
+    assert span["counts"]["width"] == stats["prefill_row_slots"]
+    assert span["counts"]["deferred"] == deferred
+    assert span["counts"]["rows"] == stats["prefill_chunks"]
 
 
 # ---------------------------------------------------------------------------
