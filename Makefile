@@ -1,7 +1,7 @@
 # Local entry points for the CI stages defined in ci.yaml.
 PY ?= python
 
-.PHONY: test quick build dist convergence dist-smoke elastic-smoke serve-smoke frontdoor-smoke decode-smoke spmd-smoke mesh-smoke kernels-smoke data-smoke obs-smoke chaos-smoke step-profile ci-quick ci-full docs bench chip-smoke hygiene lint lockcheck racecheck
+.PHONY: test quick build dist convergence dist-smoke elastic-smoke serve-smoke frontdoor-smoke decode-smoke spmd-smoke mesh-smoke kernels-smoke data-smoke obs-smoke chaos-smoke step-profile ci-quick ci-full docs chip-smoke hygiene lint lockcheck racecheck
 
 # fail if any binary / scratch artifact is tracked (ci.yaml per-change
 # `hygiene` stage; the lazy builder regenerates *.so)
@@ -17,7 +17,7 @@ hygiene:
 # coverage); rule catalog + suppression syntax in
 # docs/architecture/static_analysis.md.  Zero-violation gate.
 lint:
-	$(PY) tools/lint.py mxnet_tpu tools bench.py
+	$(PY) tools/lint.py mxnet_tpu tools
 
 # dynamic lock-order race detector (analysis/lockcheck.py) armed over
 # the suites that exercise all three thread pools: the device input
@@ -100,11 +100,9 @@ frontdoor-smoke:
 # (MXNET_PALLAS routed AND the =0 escape hatch), the -1e30 cache-pad
 # mask pin, the generative program store's AOT warm set, and the
 # continuous-batching GenerationEngine — greedy == reference, seeded-
-# loadgen FIFO admission, close-mid-generation drain, KV-cache growth,
-# plus the banked serving.decode.* rows (continuous >= 2x re-prefill
-# tokens/sec at no worse p99 TTFT, zero drops) — and the low-precision
-# serving plane (tests/test_quant_serving.py): int8 weight-only
-# (fused dequant-matmul vs dense twin, >= 99% greedy top-1 agreement,
+# loadgen FIFO admission, close-mid-generation drain, KV-cache growth
+# — and the low-precision serving plane
+# (tests/test_quant_serving.py): int8 weight-only (fused dequant-matmul vs dense twin, >= 99% greedy top-1 agreement,
 # ~4x weight bytes), bf16 KV decode (relaxed-tol parity, halved cache
 # bytes/slot), in-graph vs host sampling byte-identical streams and
 # the zero-logits-fetch pin
@@ -117,9 +115,8 @@ decode-smoke:
 
 # one-SPMD-step-program gate under 8 fake host devices: numerical
 # equivalence (dp8 vs single device, dp2xmp2 vs dp4, closed-form SGD),
-# the shared-program-cache pin across frontends, the MXNET_SPMD=0
-# escape hatch, and the banked + live bench ratios (sharded step
-# >= 1.5x the classic executor-group path on the smoke MLP)
+# the shared-program-cache pin across frontends and the MXNET_SPMD=0
+# escape hatch
 spmd-smoke:
 	timeout -k 10 420 env JAX_PLATFORMS=cpu \
 		XLA_FLAGS=--xla_force_host_platform_device_count=8 \
@@ -130,7 +127,7 @@ spmd-smoke:
 # bucket-reduce bit-exactness vs the fused step, live overlap >= 1.3x
 # barrier under injected collective latency, the dist_mesh program-
 # cache key, launch.py --mesh end-to-end (multi-process leg skips on
-# CPU jaxlib), and the banked >= 1.5x-vs-PS / >= 1.3x-vs-barrier pins
+# CPU jaxlib)
 mesh-smoke:
 	timeout -k 10 420 env JAX_PLATFORMS=cpu \
 		XLA_FLAGS=--xla_force_host_platform_device_count=8 \
@@ -142,9 +139,8 @@ mesh-smoke:
 # whether they run is `make chip-smoke` — (fused softmax/xent, RMSNorm,
 # LayerNorm, flash attention) pinned against the plain XLA lowering —
 # forward AND gradients — plus the MXNET_PALLAS=0 bit-for-bit escape
-# hatch, the dispatch-fingerprint cache keys, the remat policies'
-# residual-memory reduction at pinned numerics, and the banked
-# BENCH_transformer_cpu.json artifact pins
+# hatch, the dispatch-fingerprint cache keys and the remat policies'
+# residual-memory reduction at pinned numerics
 kernels-smoke:
 	timeout -k 10 420 env JAX_PLATFORMS=cpu \
 		$(PY) -m pytest tests/test_pallas_kernels.py \
@@ -153,8 +149,8 @@ kernels-smoke:
 # checkpointable-data-plane gate (docs/architecture/data_pipeline.md):
 # the state_dict/load_state round-trip property over every shipped
 # DataIter, seeded mid-epoch fit resume with a byte-identical remaining
-# stream (also under num_parts=2 sharding), the subprocess
-# SIGKILL-mid-epoch scenario, and the banked BENCH_data_cpu.json pins.
+# stream (also under num_parts=2 sharding) and the subprocess
+# SIGKILL-mid-epoch scenario.
 # The conftest thread-leak gate covers the pipeline/stager/prefetch
 # threads; hard timeout like the other smokes
 data-smoke:
@@ -169,7 +165,7 @@ data-smoke:
 # the flight-recorder postmortem after the seeded replica-die scenario
 # (artifact names the dying replica), GET /metrics Prometheus parse,
 # the cached /stats age_ms contract, stats()-reads-through-registry
-# pins, and the live + banked telemetry overhead gates
+# pins, and the live telemetry overhead smoke
 obs-smoke:
 	timeout -k 10 420 env JAX_PLATFORMS=cpu \
 		$(PY) -m pytest tests/test_observability.py -q
@@ -218,9 +214,6 @@ ci-full: build dist convergence quick docs-check
 	JAX_PLATFORMS=cpu \
 	XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 	$(PY) -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
-
-bench:
-	$(PY) bench.py
 
 # needs one TPU chip and fails without one: Module.fit of ResNet-50 and
 # the transformer LM, then the LM behind the generation engine, each

@@ -254,10 +254,9 @@ def main(argv=None):
         prog="graft-lint",
         description="Project-specific static analysis "
                     "(docs/architecture/static_analysis.md).")
-    ap.add_argument("paths", nargs="*", default=["mxnet_tpu", "tools",
-                                                 "bench.py"],
+    ap.add_argument("paths", nargs="*", default=["mxnet_tpu", "tools"],
                     help="files/directories to lint (default: "
-                         "mxnet_tpu tools bench.py)")
+                         "mxnet_tpu tools)")
     ap.add_argument("--root", default=None,
                     help="repo root (default: auto-detected from this "
                          "file's location)")

@@ -88,9 +88,9 @@ def get_env(name, default=None):
 
 def use_compile_cache():
     """Point JAX's persistent compilation cache at a fixed place and
-    return it.  Entry points call this (``chip_smoke.py``, ``bench.py``,
-    the tools, the examples' ``fit``) — never ``import mxnet_tpu``, so
-    the tests stay cache-free.
+    return it.  Entry points call this (``chip_smoke.py``,
+    ``benchmark/run.py``, the tools, the examples' ``fit``) — never
+    ``import mxnet_tpu``, so the tests stay cache-free.
 
     ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and nothing
     is configured in code.  Unset: ``<checkout>/.jax_cache``.  The path
@@ -675,9 +675,8 @@ register_env("MXNET_MESH_REDUCE", str, "bucket",
 register_env("MXNET_MESH_OVERLAP", bool, True,
              "Whether dist_mesh bucket collectives launch concurrently "
              "(overlapped, default) or serialize behind one another "
-             "(barrier semantics — the measurable-baseline escape "
-             "hatch bench row kvstore.dist_mesh.overlap compares "
-             "against).")
+             "(barrier semantics — the baseline the live overlap test "
+             "of tests/test_dist_mesh.py compares against).")
 register_env("MXNET_KVSTORE_REBALANCE", bool, False,
              "Arm the automatic load-driven PS rebalance trigger: the "
              "rank-0 dist worker samples rebalance_signal() every "

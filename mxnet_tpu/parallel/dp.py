@@ -457,18 +457,6 @@ class DataParallelTrainer:
         the step body, so never call it per step."""
         return self._train_step.lower(*self._step_args(data, label))
 
-    def step_cost_analysis(self, data, label=None):
-        """Compiled cost/memory analysis of THE fused step at this
-        trainer's shapes (``mxnet_tpu.flops.compiled_cost``): model
-        FLOPs per step from XLA's own ``cost_analysis()`` — the honest
-        numerator for an MFU claim — plus the program's temp/argument
-        bytes.  ``lower().compile()`` does not reuse the warmed jit
-        executable: this pays one fresh XLA compile, so call it once
-        per configuration as a diagnostic, never per step."""
-        from ..flops import compiled_cost
-        return compiled_cost(self._train_step,
-                             *self._step_args(data, label))
-
     def predict(self, data, rng=None):
         batch = dict(data) if isinstance(data, dict) else \
             {self.data_names[0]: data}

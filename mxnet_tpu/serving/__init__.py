@@ -30,9 +30,9 @@ prefilled sequences into the running decode batch between steps and
 retiring finished ones.
 
 :mod:`loadgen` provides the seeded open-loop load generator (deterministic
-arrival schedule, ``faultinject``-style) driving the p50/p99 + QPS bench
-rows on CPU in CI — and, for the decode plane, the tokens/sec + TTFT +
-inter-token-latency generation protocol.
+arrival schedule, ``faultinject``-style) behind the p50/p99 + QPS
+scenarios of ``tools/serve_smoke.py`` and the tests — and, for the decode
+plane, ``run_gen_loadgen``'s tokens/sec + TTFT + inter-token latency.
 
 The control plane (docs/architecture/serving.md, control-plane section)
 closes the loop over all of it: :mod:`controller`'s :class:`AutoScaler`
@@ -58,9 +58,9 @@ from .controller import AutoScaler
 from .frontdoor import HttpClient, HttpFrontDoor
 from .loadgen import (OpenLoopSchedule, autoscale_protocol,
                       chaos_protocol, failover_protocol,
-                      frontdoor_protocol, generation_protocol,
-                      latency_protocol, rolling_swap_protocol,
-                      run_gen_loadgen, run_loadgen, swap_protocol)
+                      frontdoor_protocol, latency_protocol,
+                      rolling_swap_protocol, run_gen_loadgen,
+                      run_loadgen, swap_protocol)
 
 __all__ = [
     "ProgramStore", "GenerativeProgramStore", "bucket_edges", "bucket_for",
@@ -73,7 +73,7 @@ __all__ = [
     "AutoScaler",
     "HttpFrontDoor", "HttpClient",
     "OpenLoopSchedule", "run_loadgen", "latency_protocol",
-    "run_gen_loadgen", "generation_protocol", "frontdoor_protocol",
+    "run_gen_loadgen", "frontdoor_protocol",
     "failover_protocol", "swap_protocol", "autoscale_protocol",
     "rolling_swap_protocol", "chaos_protocol",
 ]

@@ -8,21 +8,20 @@ Real arrival processes are not reproducible in CI, so — exactly like
 generator draws the whole arrival process (exponential inter-arrival
 gaps + request sizes) ONCE from a seed into a concrete
 :class:`OpenLoopSchedule`; the same seed replays the same offered load
-byte-for-byte, making the p50/p99/QPS bench rows CPU-deterministic up to
-host timing noise.
+byte-for-byte, making the p50/p99/QPS a scenario reports
+CPU-deterministic up to host timing noise.
 
 :func:`run_loadgen` drives any ``submit(i, n) -> Future`` target on the
 schedule and reports per-request latency percentiles and achieved QPS;
 completion timestamps are taken AFTER a dependent-byte host fetch
-(``test_utils.fetch_sync`` — the honest-timing discipline of bench.py)
-on a waiter thread, never on the engine thread.
+(``test_utils.fetch_sync`` — the honest-timing discipline of
+docs/perf.md) on a waiter thread, never on the engine thread.
 
-:func:`latency_protocol` is the full bench protocol shared by
-``bench.py``'s ``serving.latency.{fp32,bf16,int8}`` rows,
-``make serve-smoke`` and the tests: measure per-request
-``Predictor.forward`` closed-loop (service latency + capacity), then
-drive BOTH a per-request server and the continuous batcher under the
-same seeded open-loop schedule at a multiple of that capacity.
+:func:`latency_protocol` is the scenario ``make serve-smoke`` and the
+tests share: measure per-request ``Predictor.forward`` closed-loop
+(service latency + capacity), then drive BOTH a per-request server and
+the continuous batcher under the same seeded open-loop schedule at a
+multiple of that capacity.
 """
 from __future__ import annotations
 
@@ -35,9 +34,8 @@ import numpy as np
 from ..base import MXNetError
 
 __all__ = ["OpenLoopSchedule", "run_loadgen", "latency_protocol",
-           "run_gen_loadgen", "generation_protocol",
-           "paged_generation_protocol", "spec_generation_protocol",
-           "frontdoor_protocol", "failover_protocol", "swap_protocol",
+           "run_gen_loadgen", "frontdoor_protocol", "failover_protocol",
+           "swap_protocol",
            "observability_protocol", "autoscale_protocol",
            "rolling_swap_protocol", "chaos_protocol"]
 
@@ -323,7 +321,7 @@ def _smoke_model(feat, hidden, seed):
 
 def latency_protocol(mode="fp32", smoke=False, seed=11, offered_mult=6.0,
                      max_delay_ms=2.0, max_batch=32):
-    """The serving bench protocol (CPU-deterministic).
+    """The serving latency scenario (CPU-deterministic).
 
     1. **Per-request baseline, closed loop**: ``Predictor.forward`` +
        output fetch back-to-back over deterministic inputs — service
@@ -499,708 +497,6 @@ def run_gen_loadgen(submit, schedule, settle_s=180.0):
     }
 
 
-class _ReprefillServer:
-    """The naive generation baseline: one worker thread services a FIFO
-    queue, generating each request to completion by RE-RUNNING the full
-    prefill program over the growing sequence for every token — every
-    token re-pays attention over the whole prefix, and no two requests
-    ever share a dispatch.  Greedy sampling, same prefill programs and
-    weights as the engine, same Future/GenerationResult interface so
-    :func:`run_gen_loadgen` drives both."""
-
-    def __init__(self, store, model="m"):
-        self._store = store
-        self._model = model
-        self._q = queue.Queue()
-        self._thread = threading.Thread(target=self._work,
-                                        name="mxt-reprefill-serve",
-                                        daemon=True)
-        self._thread.start()
-
-    def submit(self, prompt, max_tokens):
-        from concurrent.futures import Future
-        fut = Future()
-        self._q.put((list(prompt), int(max_tokens), time.perf_counter(),
-                     fut))
-        return fut
-
-    def _generate(self, prompt, max_tokens, t_submit):
-        from .decode_engine import GenerationResult
-        seq = list(prompt)
-        times = []
-        for _ in range(max_tokens):
-            toks, lens = self._store.pad_prompts([seq])
-            first, _, _ = self._store.run_prefill(toks, lens)
-            tok = int(np.argmax(np.asarray(first)[0]))
-            seq.append(tok)
-            times.append(time.perf_counter())
-        return GenerationResult(self._model, len(prompt),
-                                seq[len(prompt):], "length", t_submit,
-                                times)
-
-    def _work(self):
-        while True:
-            item = self._q.get()
-            if item is None:
-                return
-            prompt, max_tokens, t_submit, fut = item
-            if not fut.set_running_or_notify_cancel():
-                continue
-            try:
-                fut.set_result(self._generate(prompt, max_tokens,
-                                              t_submit))
-            except BaseException as e:  # noqa: BLE001 — to the future
-                fut.set_exception(e)
-
-    def close(self):
-        self._q.put(None)
-        self._thread.join(60)
-
-
-def generation_protocol(smoke=False, seed=13, offered_mult=4.0,
-                        max_tokens_choices=(8, 16),
-                        lowprec=("bf16", "int8")):
-    """The decode-plane bench protocol (CPU-deterministic).
-
-    1. **Re-prefill baseline, closed loop**: generate one request at a
-       time, re-running the full forward per token — per-request
-       generation capacity ``C`` (requests/sec) of the naive
-       deployment.
-    2. **Re-prefill baseline, open loop**: the same loop behind a FIFO
-       worker, driven by a seeded schedule at ``offered_mult x C`` —
-       TTFT explodes as the queue builds.
-    3. **Continuous batching**: :class:`~.decode_engine
-       .GenerationEngine` (same weights, same prefill programs, greedy
-       sampling both sides, in-graph sampling) under the SAME schedule
-       — one decode step advances every in-flight sequence, so
-       tokens/sec scales with the batch instead of saturating at ``C``.
-    4. **Host-sampling hatch**: the engine again with
-       ``MXNET_SERVE_SAMPLE=host`` on the SAME schedule — the ITL
-       comparison behind the in-graph acceptance ("no worse than host
-       sampling", plus the per-step fetch shrinking from (slots, vocab)
-       logits to (slots,) tokens).
-    5. **Low-precision sides** (``lowprec``): ``bf16`` = bf16 weights
-       AND bf16 KV cache (cache bytes per slot halved — the engine's
-       cache high-water stats carry the evidence), ``int8`` = int8
-       weight-only through the fused dequant-matmul door (~4x less
-       resident weight memory — the store's ``weight_bytes`` stats
-       carry it), each on the SAME schedule.
-
-    Returns a dict with every side's loadgen summary (+ engine/store
-    stats), ``tokens_per_sec_vs_reprefill`` (the >= 2x acceptance
-    figure), ``ttft_p99_vs_reprefill`` and
-    ``itl_mean_vs_host_sample``."""
-    from ..models.transformer_lm import lm_spec, random_params
-    from .decode_engine import GenerationEngine
-    from .registry import ModelRegistry
-
-    # tiny-but-real LM: decode economics on CPU are dispatch-dominated,
-    # which is exactly the regime continuous batching amortizes.  ONE
-    # batch bucket (prefills and decode steps always run bucket-shaped)
-    # and kv_depth warmup keep the whole run inside the AOT-warmed
-    # program set — no mid-run compile ever lands in a served request.
-    spec = lm_spec(num_layers=2, num_hidden=64, num_heads=4,
-                   vocab_size=128)
-    params = random_params(spec, seed=seed)
-    batch_buckets = (8,)
-    prompt_buckets = (8, 16, 32)   # the re-prefill baseline's growing
-    kv_block, kv_max = 16, 48      # sequences climb the prompt buckets
-    n_closed = 4 if smoke else 8
-    n_load = 24 if smoke else 64
-    rs = np.random.RandomState(seed + 1)
-    prompts = [list(rs.randint(0, 128, rs.randint(4, 9)))
-               for _ in range(max(n_load, n_closed))]
-
-    def make_store(registry, **dtype_kwargs):
-        # this protocol measures the CONTIGUOUS decode plane (the
-        # paged plane has its own: paged_generation_protocol)
-        dtype_kwargs.setdefault("paged", False)
-        return registry.add_generative_model(
-            "m", params, spec, batch_buckets=batch_buckets,
-            prompt_buckets=prompt_buckets, kv_block=kv_block,
-            kv_max=kv_max, warmup_kv_depth=kv_max, **dtype_kwargs)
-
-    def run_engine_side(schedule, warm_schedule, **dtype_kwargs):
-        """One engine deployment (own registry/store in the requested
-        dtypes) driven over the shared seeded schedule.  Before the
-        measured run the SAME engine serves a short unbanked warm
-        schedule through the same loadgen machinery — every side
-        measures equally warm (the first side otherwise absorbs
-        process-wide one-time costs and loses ~2x on ITL, which would
-        poison the graph-vs-host and lowprec-vs-fp32 comparisons)."""
-        reg = ModelRegistry()
-        store = make_store(reg, **dtype_kwargs)
-        engine = GenerationEngine(reg)
-        try:
-            for f in [engine.submit("m", prompts[i % len(prompts)],
-                                    max_tokens=4)
-                      for i in range(batch_buckets[-1])]:
-                f.result(120)  # warm the batched decode path
-            run_gen_loadgen(
-                lambda i, mt_: engine.submit(
-                    "m", prompts[i % len(prompts)], max_tokens=mt_),
-                warm_schedule)
-            side = run_gen_loadgen(
-                lambda i, mt_: engine.submit(
-                    "m", prompts[i % len(prompts)], max_tokens=mt_),
-                schedule)
-            side["engine"] = engine.stats()
-            side["store"] = store.stats()
-        finally:
-            engine.close()
-        return side
-
-    registry = ModelRegistry()
-    store = make_store(registry)
-
-    # 1. closed-loop baseline capacity (warm: programs are pre-warmed,
-    # but the first dispatch still initializes runtime state)
-    baseline = _ReprefillServer(store)
-    try:
-        baseline.submit(prompts[0], 4).result(120)
-        mt = int(np.mean(max_tokens_choices))
-        tic = time.perf_counter()
-        for i in range(n_closed):
-            baseline.submit(prompts[i % len(prompts)], mt).result(120)
-        closed_rps = n_closed / (time.perf_counter() - tic)
-
-        # 2. open-loop baseline on the seeded schedule
-        offered = closed_rps * float(offered_mult)
-        schedule = OpenLoopSchedule(seed, n_load, offered,
-                                    gen_tokens=max_tokens_choices)
-        serial_open = run_gen_loadgen(
-            lambda i, mt_: baseline.submit(prompts[i % len(prompts)],
-                                           mt_),
-            schedule)
-    finally:
-        baseline.close()
-
-    # the unbanked per-side warm pass (run_engine_side docstring)
-    warm_schedule = OpenLoopSchedule(seed + 101, max(8, n_load // 4),
-                                     offered,
-                                     gen_tokens=max_tokens_choices)
-
-    # 3. continuous batching on the SAME schedule (in-graph sampling
-    # is the default)
-    batch = run_engine_side(schedule, warm_schedule)
-
-    # 4. the host-sampling escape hatch on the SAME schedule
-    host_side = run_engine_side(schedule, warm_schedule, sample="host")
-
-    # 5. low-precision sides on the SAME schedule
-    sides = {}
-    for mode in lowprec or ():
-        if mode == "bf16":
-            sides["bf16"] = run_engine_side(
-                schedule, warm_schedule, compute_dtype="bfloat16",
-                kv_dtype="bfloat16")
-        elif mode == "int8":
-            sides["int8"] = run_engine_side(schedule, warm_schedule,
-                                            compute_dtype="int8")
-        else:
-            raise MXNetError("unknown lowprec mode %r" % (mode,))
-
-    ratio = (batch["tokens_per_sec"] / serial_open["tokens_per_sec"]
-             if serial_open["tokens_per_sec"] else None)
-    out = {
-        "seed": seed,
-        "spec": spec,
-        "kv_block": kv_block,
-        "kv_max": kv_max,
-        "batch_buckets": list(batch_buckets),
-        "prompt_buckets": list(prompt_buckets),
-        "closed_rps": round(closed_rps, 3),
-        "offered_mult": float(offered_mult),
-        "reprefill_open": serial_open,
-        "batch": batch,
-        "host_sample": host_side,
-        "tokens_per_sec_vs_reprefill": round(ratio, 3) if ratio else None,
-        "ttft_p99_vs_reprefill": (
-            round(batch["ttft_p99_ms"] / serial_open["ttft_p99_ms"], 4)
-            if batch["ttft_p99_ms"] and serial_open["ttft_p99_ms"]
-            else None),
-        "itl_mean_vs_host_sample": (
-            round(batch["itl_mean_ms"] / host_side["itl_mean_ms"], 4)
-            if batch["itl_mean_ms"] and host_side["itl_mean_ms"]
-            else None),
-    }
-    out.update(sides)
-    return out
-
-
-def paged_generation_protocol(smoke=False, seed=29, offered_mult=3.0):
-    """The paged-KV decode protocol (CPU-deterministic): block-table
-    attention + copy-on-write prefix sharing + chunked prefill vs the
-    contiguous plane, same weights, same seeded schedules.
-
-    Sides (each engine serves a short unbanked warm schedule first,
-    like :func:`generation_protocol`):
-
-    1. **flat_contig / flat_paged** — prefix-FREE short-prompt
-       schedule on both planes: ``tokens_per_sec_vs_contiguous`` is
-       the "paged costs nothing when nothing is shared" acceptance
-       (>= 0.9x).
-    2. **prefix_contig / prefix_paged** — prefix-HEAVY schedule
-       (every prompt = one shared 96-token system prompt + a unique
-       2-token suffix).  The paged side's peak pool footprint per
-       concurrently-active sequence vs the contiguous side's
-       bytes-per-slot high water is ``seqs_per_kv_byte_vs_contiguous``
-       (the >= 2x concurrency-per-byte acceptance); prefix-hit
-       counters + ``prefill_chunk_savings`` (chunks actually
-       dispatched vs the cold cost of the same schedule) carry the
-       "prefill work provably skipped" evidence.
-    3. **mixed_chunked / mixed_unchunked** — short decode streams with
-       a UNIQUE long prompt injected every 8th request, served with
-       ``prefill_chunk=16`` vs one whole-prompt chunk: the aggregate
-       p99 inter-token latency comparison behind the chunked-prefill
-       acceptance (``itl_p99_chunked_vs_unchunked`` < 1 — long
-       prefills stop spiking co-running streams)."""
-    from ..models.transformer_lm import lm_spec, random_params
-    from .decode_engine import GenerationEngine
-    from .registry import ModelRegistry
-
-    spec = lm_spec(num_layers=2, num_hidden=64, num_heads=4,
-                   vocab_size=128)
-    params = random_params(spec, seed=seed)
-    batch_buckets = (8,)
-    kv_block = 16
-    # L * H * block * dh * fp32 * (k + v): one pool block's bytes
-    dh = spec["num_hidden"] // spec["num_heads"]
-    block_bytes = (spec["num_layers"] * spec["num_heads"] * kv_block *
-                   dh * 4 * 2)
-    # matched geometries: the flat pair compares planes at the SAME
-    # small kv_max (a fat shared kv_max would tax only the paged side,
-    # whose dense twin attends over the whole table width); the long
-    # pairs need headroom for the 98-token prompts
-    cfg_flat = dict(prompt_buckets=(8,), kv_max=32, prefill_chunk=8)
-    cfg_long = dict(prompt_buckets=(8, 112), kv_max=160)
-    n_load = 16 if smoke else 64
-    rs = np.random.RandomState(seed + 1)
-    sys_prompt = list(rs.randint(0, 128, 96))
-    short = [list(rs.randint(0, 128, rs.randint(4, 9)))
-             for _ in range(2 * n_load)]
-    prefix_heavy = [sys_prompt + list(rs.randint(0, 128, 2))
-                    for _ in range(n_load)]
-    longs = [list(rs.randint(0, 128, 98)) for _ in range(n_load)]
-
-    def run_side(schedule, warm_schedule, prompts, cfg, long_every=0,
-                 prime=False, **kwargs):
-        """One engine deployment over the shared seeded schedule;
-        ``long_every=k`` replaces every k-th request with a unique
-        long prompt at max_tokens=2 (the chunked-prefill sides);
-        ``prime=True`` completes one sequential system-prompt request
-        before the warm pass, so a paged side measures the steady
-        prefix-cache regime, not the first-wave miss storm.  Counters
-        are measured-run deltas (warm pass on the same engine — the
-        paged prefix cache deliberately PERSISTS across passes)."""
-        reg = ModelRegistry()
-        kv_max = cfg["kv_max"]
-        store = reg.add_generative_model(
-            "m", params, spec, batch_buckets=batch_buckets,
-            prompt_buckets=cfg["prompt_buckets"], kv_block=kv_block,
-            kv_max=kv_max, warmup_kv_depth=kv_max,
-            **dict({k: v for k, v in cfg.items()
-                    if k not in ("prompt_buckets", "kv_max")},
-                   **kwargs))
-        engine = GenerationEngine(reg)
-
-        def mk_submit(off):
-            # the warm pass draws from the BACK of the prompt list so
-            # a flat side's measured run shares nothing with it
-            def submit(i, mt_):
-                if long_every and i % long_every == long_every - 1:
-                    return engine.submit(
-                        "m", longs[(i + off) % len(longs)],
-                        max_tokens=2)
-                return engine.submit(
-                    "m", prompts[(i + off) % len(prompts)],
-                    max_tokens=mt_)
-            return submit
-
-        try:
-            # batched-path warm-up over BACK-half prompts (the warm
-            # pool, like the warm schedule's offset draw)
-            for f in [engine.submit(
-                    "m", short[(i + n_load) % len(short)],
-                    max_tokens=4)
-                      for i in range(batch_buckets[-1])]:
-                f.result(120)
-            if prime:
-                engine.submit("m", sys_prompt,
-                              max_tokens=2).result(120)
-            run_gen_loadgen(mk_submit(n_load), warm_schedule)
-            warm_stats = engine.stats()
-            side = run_gen_loadgen(mk_submit(0), schedule)
-            stats = engine.stats()
-            side["engine"] = stats
-            side["store"] = store.stats()
-            side["counters"] = {
-                k: stats.get(k, 0) - warm_stats.get(k, 0)
-                for k in ("prefix_hits", "prefix_hit_blocks",
-                          "prefix_hit_tokens", "cow_forks",
-                          "prefill_chunks", "prefill_seqs", "shed",
-                          "shed_pool")}
-        finally:
-            engine.close()
-        return side
-
-    # pacing anchor: closed-loop per-request capacity of the paged
-    # plane on the short prompts (both planes are far faster
-    # open-loop, so every side queues equally)
-    reg = ModelRegistry()
-    reg.add_generative_model(
-        "m", params, spec, batch_buckets=batch_buckets,
-        prompt_buckets=cfg_flat["prompt_buckets"], kv_block=kv_block,
-        kv_max=cfg_flat["kv_max"], warmup_kv_depth=cfg_flat["kv_max"],
-        paged=True, prefill_chunk=cfg_flat["prefill_chunk"])
-    anchor = GenerationEngine(reg)
-    try:
-        anchor.submit("m", short[0], max_tokens=4).result(120)
-        n_closed = 4 if smoke else 8
-        tic = time.perf_counter()
-        for i in range(n_closed):
-            anchor.submit("m", short[i % len(short)],
-                          max_tokens=12).result(120)
-        closed_rps = n_closed / (time.perf_counter() - tic)
-    finally:
-        anchor.close()
-    offered = closed_rps * float(offered_mult)
-    schedule = OpenLoopSchedule(seed, n_load, offered,
-                                gen_tokens=(8, 16))
-    warm_schedule = OpenLoopSchedule(seed + 101, max(8, n_load // 4),
-                                     offered, gen_tokens=(8, 16))
-    # the prefix pair generates 8 tokens/request: the schedule stays
-    # decode-heavy while each sequence's unique block footprint stays
-    # at the "one divergent tail" regime the sharing claim is about
-    prefix_schedule = OpenLoopSchedule(seed, n_load, offered,
-                                       gen_tokens=(8,))
-    prefix_warm = OpenLoopSchedule(seed + 101, max(8, n_load // 4),
-                                   offered, gen_tokens=(8,))
-
-    # 1. prefix-free throughput, matched geometry (warm prompts differ
-    # from measured so nothing shares)
-    flat_contig = run_side(schedule, warm_schedule, short, cfg_flat,
-                           paged=False)
-    flat_paged = run_side(schedule, warm_schedule, short, cfg_flat,
-                          paged=True)
-
-    # 2a. contiguous on the prefix-heavy schedule: its cache high
-    # water is the byte budget the paged side will be halved against
-    prefix_contig = run_side(prefix_schedule, prefix_warm,
-                             prefix_heavy, cfg_long, paged=False)
-    contig_hwm = prefix_contig["engine"].get(
-        "cache_hwm", {}).get("m", {})
-    contig_bytes = int(contig_hwm.get("cache_mb", 0.0) * 2**20)
-    contig_bytes_per_slot = contig_hwm.get("cache_bytes_per_slot")
-
-    # 2b. paged on the SAME schedule with the pool CAPPED at half the
-    # contiguous bytes: >= 2x concurrent sequences per KV byte means
-    # the same peak concurrency fits with zero pool sheds
-    tb = -(-cfg_long["kv_max"] // kv_block)
-    pool_budget = max(tb + 2,
-                      (contig_bytes // 2) // block_bytes
-                      if contig_bytes else tb + 2)
-    prefix_paged = run_side(prefix_schedule, prefix_warm,
-                            prefix_heavy, cfg_long, paged=True,
-                            prime=True, prefill_chunk=16,
-                            pool_blocks=pool_budget)
-
-    # 3. chunked prefill vs one whole-prompt chunk under mixed load
-    mixed_chunked = run_side(schedule, warm_schedule, short, cfg_long,
-                             long_every=8, paged=True,
-                             prefill_chunk=16)
-    mixed_unchunked = run_side(schedule, warm_schedule, short,
-                               cfg_long, long_every=8, paged=True,
-                               prefill_chunk=cfg_long["kv_max"])
-
-    cs = prefix_paged["store"].get("cache_state") or {}
-    paged_bytes = (cs.get("pool_blocks", 0) + 1) * block_bytes
-    max_act_paged = prefix_paged["engine"].get("max_active") or 0
-    max_act_contig = prefix_contig["engine"].get("max_active") or 1
-    hwm_blocks = cs.get("pool_blocks_hwm", 0)
-    paged_bytes_per_seq = (hwm_blocks * block_bytes /
-                           max(1, max_act_paged))
-    # concurrency per byte, paged vs contiguous, at peak
-    seqs_per_byte = (
-        round((max_act_paged / paged_bytes) /
-              (max_act_contig / contig_bytes), 3)
-        if paged_bytes and contig_bytes and max_act_contig else None)
-
-    # prefill work evidence: chunks dispatched vs the cold cost of the
-    # same measured schedule (every prompt chunked from position 0)
-    chunk = prefix_paged["store"].get("prefill_chunk") or 1
-    cold_chunks = sum(
-        -(-len(prefix_heavy[i % len(prefix_heavy)]) // chunk)
-        for i in range(schedule.n))
-    did = prefix_paged["counters"]["prefill_chunks"]
-    savings = (round(1.0 - did / cold_chunks, 4)
-               if cold_chunks else None)
-
-    return {
-        "seed": seed,
-        "spec": spec,
-        "kv_block": kv_block,
-        "kv_max_flat": cfg_flat["kv_max"],
-        "kv_max_long": cfg_long["kv_max"],
-        "batch_buckets": list(batch_buckets),
-        "closed_rps": round(closed_rps, 3),
-        "offered_mult": float(offered_mult),
-        "flat_contig": flat_contig,
-        "flat_paged": flat_paged,
-        "prefix_contig": prefix_contig,
-        "prefix_paged": prefix_paged,
-        "mixed_chunked": mixed_chunked,
-        "mixed_unchunked": mixed_unchunked,
-        "tokens_per_sec_vs_contiguous": (
-            round(flat_paged["tokens_per_sec"] /
-                  flat_contig["tokens_per_sec"], 3)
-            if flat_contig["tokens_per_sec"] else None),
-        "seqs_per_kv_byte_vs_contiguous": seqs_per_byte,
-        "paged_pool_bytes": paged_bytes,
-        "contig_cache_bytes": contig_bytes,
-        "contig_bytes_per_slot": contig_bytes_per_slot,
-        "paged_bytes_per_active_seq": int(paged_bytes_per_seq),
-        "paged_max_active": max_act_paged,
-        "contig_max_active": max_act_contig,
-        "prefill_chunk_savings": savings,
-        "prefill_chunks_dispatched": did,
-        "prefill_chunks_cold": cold_chunks,
-        "itl_p99_chunked_vs_unchunked": (
-            round(mixed_chunked["itl_p99_ms"] /
-                  mixed_unchunked["itl_p99_ms"], 4)
-            if mixed_chunked["itl_p99_ms"] and
-            mixed_unchunked["itl_p99_ms"] else None),
-    }
-
-
-def spec_generation_protocol(smoke=False, seed=31, offered_mult=3.0):
-    """The speculative-decoding bench protocol (CPU-deterministic):
-    draft-assisted decode vs the plain paged engine, same weights,
-    same seeded open-loop schedule.
-
-    Sides (each engine serves a warm pass first, on the same engine —
-    the adversarial side's acceptance EMA deliberately collapses
-    during warm-up so the measured run sees the steady fallback
-    regime):
-
-    1. **base / base_sampled** — the non-speculative paged plane,
-       greedy and seeded-sampling; the denominators.
-    2. **spec_greedy / spec_sampled** — a DRAFT-FRIENDLY draft (the
-       target's weights plus 3% relative noise — high but non-trivial
-       acceptance, both accept and reject paths exercised) attached
-       via ``add_draft_model``: ``steps_per_token_vs_base`` is the
-       headline acceptance (target program calls per emitted token
-       <= 0.6x), with the acceptance rate reported alongside.
-    3. **spec_adversarial** — an INDEPENDENT random draft that never
-       agrees with the target: acceptance collapses, the
-       ``MXNET_SERVE_SPEC=auto`` fallback engages, and
-       ``tokens_per_sec_vs_base`` is the graceful-degradation
-       acceptance (>= 0.95x — speculation must never fall off a
-       cliff).
-    4. **paged_int8** — the int8 KV pool (codes + per-(block, head)
-       scale pools) on the plain paged engine:
-       ``pool_bytes_per_token_vs_fp32`` (<= 0.3x) from
-       ``stats()['cache_state']`` plus its own throughput ratio."""
-    from ..models.transformer_lm import lm_spec, random_params
-    from .decode_engine import GenerationEngine
-    from .registry import ModelRegistry
-
-    spec = lm_spec(num_layers=2, num_hidden=64, num_heads=4,
-                   vocab_size=128)
-    params = random_params(spec, seed=seed)
-    # draft-friendly draft: the target's weights + 3% relative noise
-    # (random weights share no structure, so an independent draft
-    # can't agree with the target — the perturbed twin is the
-    # deterministic CPU stand-in for a distilled draft)
-    rs_d = np.random.RandomState(seed + 7)
-    friendly = {
-        k: v + np.asarray(0.03 * (float(np.std(v)) or 1.0) *
-                          rs_d.standard_normal(v.shape), v.dtype)
-        for k, v in params.items()}
-    adv_spec = lm_spec(num_layers=1, num_hidden=32, num_heads=2,
-                       vocab_size=128)
-    adv_params = random_params(adv_spec, seed=seed + 9)
-    batch_buckets = (8,)
-    kv_block = 16
-    spec_k = 4
-    cfg = dict(prompt_buckets=(8,), kv_max=64, prefill_chunk=8)
-    # full-mode windows must be seconds, not fractions of one: the
-    # adversarial acceptance is a tokens/sec RATIO on the same host,
-    # and sub-second measured windows put +/-15% host noise on it
-    n_load = 16 if smoke else 96
-    rs = np.random.RandomState(seed + 1)
-    prompts = [list(rs.randint(0, 128, rs.randint(4, 9)))
-               for _ in range(2 * n_load)]
-
-    def build_side(draft, temperature, kv_dtype="float32"):
-        """Construct, prime and warm one engine; measurement is a
-        separate step so sides can interleave measured passes."""
-        reg = ModelRegistry()
-        reg.add_generative_model(
-            "m", params, spec, batch_buckets=batch_buckets,
-            kv_block=kv_block, warmup_kv_depth=cfg["kv_max"],
-            paged=True, sample="graph", kv_dtype=kv_dtype, **cfg)
-        if draft == "friendly":
-            reg.add_draft_model("m", friendly, spec, spec_k=spec_k)
-        elif draft == "adversarial":
-            reg.add_draft_model("m", adv_params, adv_spec,
-                                spec_k=spec_k)
-        engine = GenerationEngine(reg)
-
-        def mk_submit(off):
-            def submit(i, mt_):
-                return engine.submit(
-                    "m", prompts[(i + off) % len(prompts)],
-                    max_tokens=mt_, temperature=temperature,
-                    top_k=(8 if temperature else 0), seed=1000 + i)
-            return submit
-
-        for f in [engine.submit("m", prompts[(i + n_load)
-                                             % len(prompts)],
-                                max_tokens=4,
-                                temperature=temperature)
-                  for i in range(batch_buckets[-1])]:
-            f.result(120)
-        run_gen_loadgen(mk_submit(n_load), warm_schedule)
-        return engine, mk_submit
-
-    def measure(engine, mk_submit):
-        """One measured pass with per-pass counter deltas."""
-        before = engine.stats()
-        cand = run_gen_loadgen(mk_submit(0), schedule)
-        stats = engine.stats()
-        cand["counters"] = {
-            k: stats.get(k, 0) - before.get(k, 0)
-            for k in ("decode_steps", "generated_tokens",
-                      "spec_steps", "spec_proposed",
-                      "spec_accepted", "spec_draft_steps",
-                      "spec_fallback_steps")}
-        cand["cache_state"] = stats["cache_state"].get("m", {})
-        cand["model"] = stats["models"].get("m", {})
-        return cand
-
-    def best(cand, side):
-        return cand if side is None or cand["tokens_per_sec"] > \
-            side["tokens_per_sec"] else side
-
-    def finish(side):
-        c = side["counters"]
-        side["steps_per_token"] = (
-            round(c["decode_steps"] / c["generated_tokens"], 4)
-            if c["generated_tokens"] else None)
-        side["acceptance_rate"] = (
-            round(c["spec_accepted"] / c["spec_proposed"], 4)
-            if c["spec_proposed"] else None)
-        return side
-
-    def run_side(draft, temperature, kv_dtype="float32"):
-        # best-of-2 measured passes: the banked acceptance is a
-        # tokens/sec RATIO between sides, and a single sub-second
-        # makespan carries +/-10% host noise — take each side's
-        # best pass so the ratio reads engine capacity, not which
-        # side drew the noisier window (counters are per-pass
-        # deltas, so the kept evidence matches the kept pass)
-        engine, mk_submit = build_side(draft, temperature, kv_dtype)
-        try:
-            side = None
-            for _ in range(2):
-                side = best(measure(engine, mk_submit), side)
-        finally:
-            engine.close()
-        return finish(side)
-
-    # pacing anchor: closed-loop per-request capacity of the plain
-    # paged plane (every side queues equally past it)
-    reg = ModelRegistry()
-    reg.add_generative_model(
-        "m", params, spec, batch_buckets=batch_buckets,
-        kv_block=kv_block, warmup_kv_depth=cfg["kv_max"], paged=True,
-        sample="graph", **cfg)
-    anchor = GenerationEngine(reg)
-    try:
-        anchor.submit("m", prompts[0], max_tokens=4).result(120)
-        n_closed = 4 if smoke else 8
-        tic = time.perf_counter()
-        for i in range(n_closed):
-            anchor.submit("m", prompts[i % len(prompts)],
-                          max_tokens=12).result(120)
-        closed_rps = n_closed / (time.perf_counter() - tic)
-    finally:
-        anchor.close()
-    offered = closed_rps * float(offered_mult)
-    schedule = OpenLoopSchedule(seed, n_load, offered,
-                                gen_tokens=(12, 24))
-    warm_schedule = OpenLoopSchedule(seed + 101, max(8, n_load // 3),
-                                     offered, gen_tokens=(12, 24))
-
-    # base and adversarial INTERLEAVE their measured passes (both
-    # engines warm, alternating A/B pairs ~1s apart): the graceful-
-    # degradation acceptance is a ratio of two sub-second makespans,
-    # and running the sides in separate time windows (tens of
-    # seconds apart, as the other sides do) lets host drift land on
-    # one side only — single-pass spread on this host is +/-30%,
-    # far above the 5% the gate has to resolve.  An idle engine
-    # parks its loop thread on an empty queue, so the bystander
-    # side costs the measured one nothing.
-    base_engine, base_mk = build_side(None, 0.0)
-    try:
-        adv_engine, adv_mk = build_side("adversarial", 0.0)
-        try:
-            base = spec_adv = None
-            for _ in range(2 if smoke else 3):
-                base = best(measure(base_engine, base_mk), base)
-                spec_adv = best(measure(adv_engine, adv_mk),
-                                spec_adv)
-        finally:
-            adv_engine.close()
-    finally:
-        base_engine.close()
-    base = finish(base)
-    spec_adv = finish(spec_adv)
-    spec_greedy = run_side("friendly", 0.0)
-    base_sampled = run_side(None, 0.7)
-    spec_sampled = run_side("friendly", 0.7)
-    paged_int8 = run_side(None, 0.0, kv_dtype="int8")
-
-    def ratio(a, b, digits=4):
-        return round(a / b, digits) if a is not None and b else None
-
-    return {
-        "seed": seed,
-        "spec": spec,
-        "draft_spec": adv_spec,
-        "spec_k": spec_k,
-        "kv_block": kv_block,
-        "kv_max": cfg["kv_max"],
-        "batch_buckets": list(batch_buckets),
-        "closed_rps": round(closed_rps, 3),
-        "offered_mult": float(offered_mult),
-        "base": base,
-        "base_sampled": base_sampled,
-        "spec_greedy": spec_greedy,
-        "spec_sampled": spec_sampled,
-        "spec_adversarial": spec_adv,
-        "paged_int8": paged_int8,
-        "steps_per_token_vs_base_greedy": ratio(
-            spec_greedy["steps_per_token"], base["steps_per_token"]),
-        "steps_per_token_vs_base_sampled": ratio(
-            spec_sampled["steps_per_token"],
-            base_sampled["steps_per_token"]),
-        "tokens_per_sec_vs_base_greedy": ratio(
-            spec_greedy["tokens_per_sec"], base["tokens_per_sec"], 3),
-        "tokens_per_sec_vs_base_sampled": ratio(
-            spec_sampled["tokens_per_sec"],
-            base_sampled["tokens_per_sec"], 3),
-        "tokens_per_sec_vs_base_adversarial": ratio(
-            spec_adv["tokens_per_sec"], base["tokens_per_sec"], 3),
-        "tokens_per_sec_vs_base_int8": ratio(
-            paged_int8["tokens_per_sec"], base["tokens_per_sec"], 3),
-        "pool_bytes_per_token_vs_fp32": ratio(
-            paged_int8["cache_state"].get("pool_bytes_per_token"),
-            base["cache_state"].get("pool_bytes_per_token")),
-    }
-
-
 # ---------------------------------------------------------------------------
 # Front-door protocols: HTTP overhead, kill-one failover, swap consistency.
 # ---------------------------------------------------------------------------
@@ -1300,8 +596,8 @@ def failover_protocol(smoke=False, seed=19, n_replicas=3,
     the least-loaded balancer, the seeded open-loop schedule offering
     a multiple of closed-loop capacity, and a seeded ``die`` at the
     ``serve.dispatch`` faultinject seam SIGKILLing whichever replica
-    serves the ``kill_frac``-th dispatch.  Acceptance (the bench row
-    and ``serve_smoke --kill-one`` gate): 100% of accepted requests
+    serves the ``kill_frac``-th dispatch.  Acceptance (the
+    ``serve_smoke --kill-one`` gate): 100% of accepted requests
     resolve (zero drops, zero hangs), the balancer converges to the
     survivors, and achieved QPS over the post-kill window (beginning
     one probe interval after the kill) recovers to >= 2/3 of the
@@ -1411,9 +707,8 @@ def failover_protocol(smoke=False, seed=19, n_replicas=3,
 
 
 def observability_protocol(smoke=False, seed=29, offered_mult=2.0):
-    """Telemetry overhead protocol (the ``serving.observability.
-    overhead`` bench row): the SAME model and the SAME seeded open-loop
-    schedule, served three times with different telemetry settings —
+    """Telemetry overhead protocol: the SAME model and the SAME seeded
+    open-loop schedule, served three times with different telemetry settings —
 
     1. **baseline** — everything off (``MXNET_METRICS=0``,
        ``MXNET_TRACE_SAMPLE=0``, ``MXNET_FLIGHT_CAPACITY=0``): the
@@ -1545,71 +840,6 @@ def observability_protocol(smoke=False, seed=29, offered_mult=2.0):
     }
 
 
-def racecheck_overhead_protocol(smoke=False, seed=43):
-    """Race-detector overhead protocol (the ``serving.observability.
-    racecheck_overhead`` bench row): closed-loop capacity of the SAME
-    forward engine with the happens-before detector OFF (the shipping
-    default) vs ARMED at runtime (``racecheck.install()`` before the
-    engine is built, so its seam locks wrap and its shared_state
-    containers track).
-
-    The OFF side is the zero-cost claim: with the detector off,
-    ``shared_state`` returns a plain SimpleNamespace, ``shared_map`` a
-    plain dict, ``make_lock`` an unwrapped ``threading.Lock``, and the
-    stdlib stays unpatched — ``tests/test_racecheck.py``'s spy test
-    pins each of those types, so the hot path cannot silently grow a
-    tracking layer.  The armed ratio is the price CI pays for the
-    ``make racecheck`` stage, banked so it is measured, not guessed."""
-    from ..analysis import racecheck
-    from .registry import ModelRegistry
-    from .scheduler import ServingEngine
-
-    sym, args = _smoke_model(512, 2048, seed)
-    feat = 512
-    rs = np.random.RandomState(seed + 1)
-    pool = [np.asarray(rs.uniform(-1, 1, (1, feat)), np.float32)
-            for _ in range(16)]
-    n_closed = 30 if smoke else 80
-
-    def run_side():
-        registry = ModelRegistry()
-        registry.add_model("m", sym,
-                           {k: v.copy() for k, v in args.items()},
-                           {}, input_shapes={"data": (1, feat)},
-                           warmup=True)
-        engine = ServingEngine(registry, max_delay_ms=2.0)
-        try:
-            for _ in range(3):
-                for f in [engine.submit("m", data=pool[i % len(pool)])
-                          for i in range(8)]:
-                    f.result(60)
-            return max(_engine_capacity(
-                lambda i: engine.submit(
-                    "m", data=pool[i % len(pool)]).result(60),
-                n_closed) for _ in range(2))
-        finally:
-            engine.close()
-
-    was_armed = racecheck.armed()
-    off_qps = run_side() if not was_armed else None
-    racecheck.install()
-    try:
-        armed_qps = run_side()
-    finally:
-        if not was_armed:
-            racecheck.uninstall()
-    if off_qps is None:          # bench launched under MXNET_RACE_CHECK=1
-        off_qps = armed_qps
-    return {
-        "seed": seed,
-        "n_closed": n_closed,
-        "off_closed_qps": round(off_qps, 2),
-        "armed_closed_qps": round(armed_qps, 2),
-        "qps_armed_vs_off": round(armed_qps / off_qps, 4)
-        if off_qps else None,
-    }
-
-
 def swap_protocol(smoke=False, seed=23):
     """Hot-swap-under-traffic bit-consistency: one engine under
     concurrent submit threads while ``swap_params`` republishes a
@@ -1737,7 +967,7 @@ def autoscale_protocol(smoke=False, seed=31, shape="diurnal",
     threads — so the replica-seconds comparison still prices live
     serving capacity.
 
-    Acceptance (the ``serving.control.autoscale`` bench rows): the
+    Acceptance (the ``chaos_campaign`` autoscale gate): the
     autoscaled side's queue-wait p95 stays under the SLO, with zero
     lost requests and strictly fewer replica-seconds than static
     max-size provisioning over the same span."""

@@ -65,9 +65,8 @@ def fetch_sync(outs):
     measures the host's dispatch rate, which once read as resnet-50
     "MFU 2.2" — 220% of chip peak.  A host fetch of bytes that
     data-depend on the computation cannot return before the device is
-    done, on any backend; every timed benchmark window starts and stops
-    on one (bench.py, benchmark_score.py, docs/perf.md "measuring
-    honestly")."""
+    done, on any backend; every timed window starts and stops on one
+    (benchmark_score.py, docs/perf.md "measuring honestly")."""
     import jax
     leaves = jax.tree_util.tree_leaves(outs)
     for leaf in leaves[:1]:
@@ -77,9 +76,9 @@ def fetch_sync(outs):
 
 def smoke_mlp(num_hidden=64, num_classes=10):
     """Tiny 2-layer softmax MLP shared by the smoke harnesses
-    (tools/step_profile.py, bench.py's io.input_staging row,
+    (tools/step_profile.py, serving/loadgen.py,
     tests/test_input_staging.py) so the smoke protocol can't drift
-    between the bench, CI, and test call sites."""
+    between the CI and test call sites."""
     from . import symbol as sym
     data = sym.Variable("data")
     h = sym.Activation(
@@ -95,7 +94,7 @@ class DelayedIter:
     ``next()`` — the faultinject-delay pattern applied to the input
     pipeline, standing in for slow decode/augmentation so input-staging
     overlap is measurable on one CPU host (tests/test_input_staging.py,
-    bench.py ``io.input_staging`` row, tools/step_profile.py)."""
+    tools/step_profile.py)."""
 
     def __init__(self, source, delay=0.02):
         self._source = source

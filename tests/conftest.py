@@ -1,8 +1,8 @@
 """Test configuration: force a virtual 8-device CPU platform.
 
 Mirrors the reference's test strategy (SURVEY.md §4): CPU contexts stand in
-for the device mesh, so multi-device/sharding tests run anywhere; the bench
-path runs on real TPU hardware separately.
+for the device mesh, so multi-device/sharding tests run anywhere; the
+benchmark (benchmark/) runs on real TPU hardware separately.
 """
 import os
 
@@ -69,7 +69,6 @@ def _seed_rngs():
 # ---------------------------------------------------------------------------
 _TIER_BY_FILE = {
     "test_train_tier.py": "convergence",
-    "test_bench_smoke.py": "convergence",
     "test_doc_snippets.py": "convergence",
     "test_deploy.py": "build",
     "test_native.py": "build",

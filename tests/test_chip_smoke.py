@@ -134,20 +134,6 @@ def test_duplicate_contexts_raise_instead_of_replicating():
         mod.fit(it, num_epoch=1)
 
 
-@pytest.mark.parametrize("kind,peak", [("TPU v5 lite", 197e12),
-                                       ("TPU v5e", 197e12),
-                                       ("cpu", None)])
-def test_peak_flops_table(kind, peak):
-    from mxnet_tpu.flops import peak_bf16_flops
-    assert peak_bf16_flops(kind) == peak
-
-
-def test_unknown_tpu_kind_raises():
-    from mxnet_tpu.flops import peak_bf16_flops
-    with pytest.raises(ValueError, match="peak-FLOPs table"):
-        peak_bf16_flops("TPU v9 mega")
-
-
 def test_rowwise_kernels_pick_interpret_by_platform():
     """``interpret`` defaults to None — by platform — so a direct caller
     on a chip cannot get the interpreter by omission; here it interprets
@@ -162,40 +148,3 @@ def test_rowwise_kernels_pick_interpret_by_platform():
     want = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6)
     np.testing.assert_allclose(norm.rms_norm(x, g), want, rtol=1e-5,
                                atol=1e-6)
-
-
-@pytest.fixture
-def bench(monkeypatch, tmp_path):
-    # main() places the compile cache: leave that to the variable here
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-    return _load("bench")
-
-
-def test_bench_fails_when_backend_init_raises(bench, monkeypatch):
-    def boom():
-        raise RuntimeError("Unable to initialize backend 'tpu'")
-
-    monkeypatch.setattr(jax, "devices", boom)
-    with pytest.raises(RuntimeError, match="Unable to initialize"):
-        bench.main()
-
-
-def test_bench_fails_off_chip_unless_cpu_is_asked_for(bench, monkeypatch):
-    monkeypatch.delenv("JAX_PLATFORMS")
-    with pytest.raises(MXNetError, match="measures on a TPU"):
-        bench.main()
-
-
-def test_bench_fails_on_an_errored_row(bench, monkeypatch, capsys):
-    def boom(*args):
-        raise ValueError("row broke")
-
-    monkeypatch.setattr(bench, "bench_calibration", boom)
-    monkeypatch.setenv("BENCH_SMOKE", "1")
-    monkeypatch.setenv("BENCH_ROWS", "calibration")
-    with pytest.raises(SystemExit, match="calibration"):
-        bench.main()
-    out = json.loads([ln for ln in capsys.readouterr().out.splitlines()
-                      if ln.startswith("{")][-1])
-    assert out["rows"][0]["unit"] == "error"
-    assert list(out)[-1] == "claim" and out["claim"] is None

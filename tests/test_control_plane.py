@@ -4,10 +4,8 @@ warm spare-registry pool (build-once scale-up, recycle-on-drain,
 spares follow hot swaps), ServeClosed carrying the dead replica's index
 through kill/close, the hot-swap vs /metrics-scrape vs in-flight
 generation race, priority-tier preemption, per-tenant quotas, bearer-
-token auth on the front door, shaped-schedule determinism, and the
-banked serving.control.* acceptance rows
+token auth on the front door and shaped-schedule determinism
 (docs/architecture/serving.md, control-plane section)."""
-import json
 import threading
 import time
 
@@ -459,7 +457,7 @@ def test_frontdoor_bearer_token_auth():
 def test_shaped_schedules_are_seed_deterministic():
     """diurnal/bursty schedules: same seed => byte-identical arrivals,
     strictly increasing; different seeds diverge; the shape tag rides
-    the schedule for the bench rows."""
+    the schedule."""
     for maker in (OpenLoopSchedule.diurnal, OpenLoopSchedule.bursty):
         a = maker(seed=7, n_requests=200)
         b = maker(seed=7, n_requests=200)
@@ -478,43 +476,3 @@ def test_shaped_schedules_are_seed_deterministic():
     mid = np.sum((d.arrivals >= span / 3.0)
                  & (d.arrivals < 2.0 * span / 3.0))
     assert mid > first
-
-
-# ---------------------------------------------------------------------------
-# banked bench rows
-# ---------------------------------------------------------------------------
-def _banked_rows():
-    import os
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "BENCH_serving_cpu.json")
-    with open(path) as f:
-        return {r["metric"]: r for r in json.load(f)["rows"]}
-
-
-def test_banked_control_plane_rows_hold_the_acceptance():
-    """BENCH_serving_cpu.json carries the serving.control.* family:
-    the autoscaler rows (scaled up AND down, p95 under the SLO, fewer
-    replica-seconds than static max-size provisioning, zero lost), the
-    rolling-swap row (zero failures, zero torn reads, all stores
-    advanced one version) and the chaos row (every gate held)."""
-    rows = _banked_rows()
-    for shape in ("diurnal", "bursty"):
-        r = rows.get("serving.control.autoscale_%s" % shape)
-        assert r is not None, \
-            "serving.control.autoscale_%s not banked" % shape
-        assert r["scaled_up"] and r["scaled_down"]
-        assert r["p95_under_slo"]
-        assert r["lost"] == 0
-        assert r["value"] is not None and r["value"] < 1.0  # vs static
-        assert r["n_peak_replicas"] > 1
-    sw = rows.get("serving.control.rolling_swap")
-    assert sw is not None, "serving.control.rolling_swap not banked"
-    assert sw["failed"] == 0 and sw["torn"] == 0
-    assert sw["old"] + sw["new"] == sw["n_requests"]
-    assert sw["replicas_swapped"] == sw["n_replicas"]
-    ch = rows.get("serving.control.chaos")
-    assert ch is not None, "serving.control.chaos not banked"
-    assert all(ch["gates"].values())
-    assert ch["lost"] == 0 and ch["n_faults"] >= 3
-    assert ch["recovery_ms"] is not None
-    assert ch["recovery_ms"] <= ch["recovery_slo_ms"]

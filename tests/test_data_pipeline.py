@@ -990,22 +990,6 @@ def test_kvstore_rank_autopartitions_train_data(tmp_path, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# banked bench artifact (BENCH_data_cpu.json)
-# ---------------------------------------------------------------------------
-def test_banked_sharded_stream_rows():
-    """The banked CPU rows exist and honor the acceptance gates: the
-    threaded pipeline beats serial decode, and mid-epoch resume costs
-    <5% of one epoch."""
-    path = os.path.join(_REPO, "BENCH_data_cpu.json")
-    with open(path) as f:
-        rows = {r["metric"]: r for r in json.load(f)["rows"]}
-    thr = rows["io.sharded_stream.throughput"]
-    assert thr["value"] > 0 and thr["speedup_vs_serial"] >= 1.3
-    res = rows["io.sharded_stream.resume_overhead"]
-    assert res["overhead_vs_epoch"] < 0.05 and res["passes"] is True
-
-
-# ---------------------------------------------------------------------------
 # subprocess SIGKILL-mid-epoch (mirrors the PR-2 server-death test)
 # ---------------------------------------------------------------------------
 def test_sigkill_mid_epoch_resume_subprocess(tmp_path):

@@ -11,8 +11,7 @@ Pins the four contracts of the "feed the MXU" pass:
   ``Module``/Executor path: fp32 master weights, checkpoint interop,
   and a loss curve tracking fp32;
 * under injected per-batch host latency the stager overlaps data
-  production with compute: fit steps/sec >= 1.5x the blocking baseline
-  (the bench.py ``io.input_staging`` row's CI gate).
+  production with compute: fit steps/sec >= 1.5x the blocking baseline.
 """
 import os
 import time
@@ -379,7 +378,7 @@ def test_stager_records_h2d_and_fit_records_phases(tmp_path, monkeypatch):
 
 
 def test_step_phase_collector_inline():
-    """The lightweight collector (bench.py's in-window instrument)
+    """The lightweight collector (an in-window instrument)
     aggregates without a trace file."""
     from mxnet_tpu import profiler
     profiler.start_step_profile()

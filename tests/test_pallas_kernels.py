@@ -6,7 +6,6 @@ forward AND gradients within tolerance, the MXNET_PALLAS=0 escape hatch
 bit-for-bit, the routing counters proving the kernel path was actually
 taken, and the cached-op/SPMD caches keyed on the dispatch fingerprint
 so an env flip can never serve a stale lowering."""
-import json
 import os
 
 import numpy as np
@@ -358,30 +357,3 @@ def test_transformer_symbol_kernels_end_to_end(monkeypatch):
         assert st.get(kind, 0) >= 1, (kind, st)
     for a, b in zip(forced, ref):
         assert_almost_equal(a, b, rtol=2e-3, atol=2e-4)
-
-
-# ---------------------------------------------------------------------------
-# Banked artifact pin (BENCH_transformer_cpu.json)
-# ---------------------------------------------------------------------------
-def test_banked_transformer_bench():
-    """The banked CPU artifact must carry (a) a transformer train row
-    measured with the kernels routed end-to-end — flash attention plus
-    the norm and loss-head kernels — and (b) a remat batch-scaling row
-    whose residual-memory reduction is real at pinned loss parity."""
-    path = os.path.join(_REPO, "BENCH_transformer_cpu.json")
-    with open(path) as f:
-        banked = json.load(f)
-    by_metric = {r["metric"]: r for r in banked["rows"]}
-    row = by_metric["transformer.train.pallas"]
-    assert row["unit"] == "samples/sec" and row["value"] > 0
-    routed = row["kernels_routed"]
-    assert routed.get("DotProductAttention", 0) >= 1
-    assert routed.get("RMSNorm", 0) >= 1
-    assert routed.get("SoftmaxOutput", 0) >= 1
-    assert by_metric["transformer.train.xla"]["value"] > 0
-    remat = by_metric["transformer.remat_batch_scaling"]
-    assert remat["unit"] == "x residual memory"
-    assert remat["value"] >= 1.1, remat
-    for cell in remat["sweep"]:
-        assert cell["residual_bytes_off"] > cell["residual_bytes_on"]
-        assert cell["loss_max_abs_diff"] < 1e-3

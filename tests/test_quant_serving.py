@@ -3,11 +3,8 @@ dequant-matmul kernel vs its dense XLA twin, >= 99% greedy top-1
 agreement, ~4x resident weight bytes), the bf16 KV decode plane
 (relaxed-tol parity incl. ragged prefill lengths, halved cache bytes
 per slot) and in-graph sampling (byte-identical token streams vs the
-MXNET_SERVE_SAMPLE=host hatch, the zero-logits-fetch pin), plus the
-banked serving.decode.{bf16,int8} / serving.latency.int8 acceptance
-rows (docs/architecture/serving.md dtype matrix)."""
-import json
-import os
+MXNET_SERVE_SAMPLE=host hatch, the zero-logits-fetch pin)
+(docs/architecture/serving.md dtype matrix)."""
 
 import jax
 import jax.numpy as jnp
@@ -496,50 +493,3 @@ def test_paged_dtype_pool_bytes_in_cache_state():
         assert cs["cache_dtype"] == ("int8" if kv == "int8" else kv)
     assert bpt["bfloat16"] * 2 == bpt["float32"]
     assert bpt["int8"] <= 0.3 * bpt["float32"]
-
-
-# ---------------------------------------------------------------------------
-# banked artifact pins
-# ---------------------------------------------------------------------------
-def _banked_rows():
-    path = os.path.join(os.path.dirname(__file__), os.pardir,
-                        "BENCH_serving_cpu.json")
-    with open(path) as f:
-        out = json.load(f)
-    return {r["metric"]: r for r in out["rows"]}, out
-
-
-def test_banked_lowprec_decode_rows_hold_acceptance():
-    """BENCH_serving_cpu.json carries the low-precision decode family:
-    bf16 halves cache bytes per slot, int8 cuts weight bytes ~4x, both
-    with zero drops; the continuous row's in-graph sampling fetches
-    tokens (not logits) and its ITL mean is no worse than the
-    host-sampling hatch on the same seeded schedule."""
-    rows, _ = _banked_rows()
-    cont = rows["serving.decode.continuous"]
-    assert cont["sample_mode"] == "graph"
-    assert cont["itl_mean_vs_host_sample"] <= 1.0
-    # token-sized per-step fetch: slots elements, far under the
-    # (slots, vocab) logits matrix the host hatch pulls
-    assert cont["decode_fetch_elems_per_step"] <= cont["max_active"]
-    b16 = rows["serving.decode.bf16"]
-    assert b16["dropped"] == 0
-    assert b16["kv_dtype"] == "bfloat16"
-    assert b16["cache_bytes_per_slot"] * 2 == \
-        b16["fp32_cache_bytes_per_slot"]
-    q8 = rows["serving.decode.int8"]
-    assert q8["dropped"] == 0
-    assert q8["compute_dtype"] == "int8"
-    assert q8["fp32_weight_bytes"] / q8["weight_bytes"] >= 3.5
-
-
-def test_banked_int8_latency_row_holds_acceptance():
-    """serving.latency.int8 banked with zero drops at the serving
-    plane's >= 3x QPS acceptance, weight bytes dominated by int8."""
-    rows, out = _banked_rows()
-    q8 = rows["serving.latency.int8"]
-    assert q8["dropped"] == 0
-    assert q8["qps_vs_per_request"] >= 3.0
-    by_dtype = q8["weight_bytes_by_dtype"]
-    assert by_dtype.get("int8", 0) > by_dtype.get("float32", 0)
-    assert out["serving"]["int8"]["qps_vs_per_request"] >= 3.0

@@ -3,7 +3,7 @@
 
 Usage::
 
-    python tools/lint.py [paths...]          # default: mxnet_tpu tools bench.py
+    python tools/lint.py [paths...]          # default: mxnet_tpu tools
     python tools/lint.py --list-rules
     python tools/lint.py --rule env-knob mxnet_tpu
 

@@ -213,7 +213,7 @@ def main(argv=None):
     ap.add_argument("--qps-floor", type=float, default=3.0,
                     help="min batcher/per-request achieved-QPS ratio")
     ap.add_argument("--full", action="store_true",
-                    help="full-size protocol (bench row scale)")
+                    help="full-size protocol (smoke=False)")
     ap.add_argument("--dtype", default="fp32",
                     choices=("fp32", "bf16", "int8", "all"),
                     help="serving dtype, or 'all' to cycle the whole "
