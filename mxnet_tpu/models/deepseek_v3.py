@@ -51,7 +51,7 @@ OFFERS = frozenset()
 # the counters a step returns beside its logits, in order
 AUX_COUNTERS = ("moe_tokens", "moe_local_assignments",
                 "moe_expert_load_max", "moe_expert_steps",
-                "moe_experts_touched")
+                "moe_experts_touched", "moe_expert_streams")
 
 _INT_KEYS = ("num_hidden_layers", "first_k_dense_replace", "hidden_size",
              "num_attention_heads", "q_lora_rank", "kv_lora_rank",
@@ -360,11 +360,13 @@ def paged_step_apply(params, pool, tables, tokens, positions, valid, spec,
     tokens (valid rows of sequences whose table owns a block): tokens
     routed x expert layers, picks that fell on held experts, the
     fullest held expert's count summed over the layers, expert layers,
-    held experts that got a token summed over the layers."""
+    held experts that got a token summed over the layers, and how
+    often the grouped product streamed an expert's weights for them
+    (``ops/moe.expert_streams``; once a touched expert is the floor)."""
     import jax
     import jax.numpy as jnp
     from ..ops.attention import mla_attention_paged
-    from ..ops.moe import moe_experts, route_grouped
+    from ..ops.moe import expert_streams, moe_experts, route_grouped
 
     L, D = spec["num_hidden_layers"], spec["hidden_size"]
     H = spec["num_attention_heads"]
@@ -437,7 +439,8 @@ def paged_step_apply(params, pool, tables, tokens, positions, valid, spec,
             counts = counts + jnp.stack(
                 [jnp.sum(live, dtype=jnp.int32), jnp.sum(per),
                  jnp.max(per), jnp.int32(1),
-                 jnp.sum(per > 0, dtype=jnp.int32)])
+                 jnp.sum(per > 0, dtype=jnp.int32),
+                 expert_streams(per, experts.size)])
         x = x + y.reshape(B, Lq, D)
     hN = _rms(x, params["final_norm_gamma"], eps).astype(cdt)
     if all_logits:

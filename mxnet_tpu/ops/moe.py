@@ -7,12 +7,13 @@ that only steers the choice, groups of experts of which the best few
 are kept).  :func:`moe_experts` computes the part of the layer's result
 that the experts HELD here give: the picks that fell on experts
 ``0 .. held-1`` are sorted by expert, multiplied as one grouped product
-over uneven counts (``jax.lax.ragged_dot``, a Mosaic kernel on the TPU),
-unsorted and summed with their routing weights.  No pick is dropped:
-there is no capacity, the grouped product takes whatever the counts
-are.  Picks on experts held elsewhere cost the sort and nothing more,
-and what those experts would have added is left out (the exchange that
-would fetch it does not exist on one chip).
+over uneven counts (``pallas_ops/grouped_matmul.py``: each expert that
+got a row streams its weights once a row tile it reaches), unsorted and
+summed with their routing weights.  No pick is dropped: there is no
+capacity, the grouped product takes whatever the counts are.  Picks on
+experts held elsewhere cost the sort and nothing more, and what those
+experts would have added is left out (the exchange that would fetch it
+does not exist on one chip).
 
 :func:`moe_experts_reference` is the dense XLA twin: every held expert
 over every token under a mask, the ``MXNET_PALLAS=0`` lowering and the
@@ -24,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["route_grouped", "moe_experts", "moe_experts_reference",
-           "expert_counts"]
+           "expert_counts", "expert_streams"]
 
 
 def route_grouped(scores, bias, top_k, n_group, topk_group, scale):
@@ -61,6 +62,15 @@ def expert_counts(experts, live, held):
         & mine[..., None], axis=(0, 1), dtype=jnp.int32)
 
 
+def expert_streams(counts, rows):
+    """How often :func:`moe_experts`' grouped product streams an
+    expert's weights for ``counts`` over ``rows`` sorted rows (tokens x
+    picks): its (row tile, expert) visits.  ``sum(counts > 0)`` is the
+    floor; more means the row tile cuts groups."""
+    from ..pallas_ops.grouped_matmul import grouped_visits
+    return grouped_visits(counts, rows)
+
+
 def _swiglu(gu):
     f = gu.shape[-1] // 2
     gate = gu[..., :f].astype(jnp.float32)
@@ -82,25 +92,25 @@ def moe_experts(x, w_gate_up, w_down, experts, weights, live):
     Eligible shapes take the sorted, grouped product; everything else —
     and ``MXNET_PALLAS=0`` — lowers to :func:`moe_experts_reference`."""
     from ..pallas_ops import dispatch as _pd
-    if not _pd.use_moe_experts("MoEExperts", x.shape[0], x.shape[1],
-                               w_down.shape[1], x.dtype):
-        return moe_experts_reference(x, w_gate_up, w_down, experts,
-                                     weights, live)
+    from ..pallas_ops.grouped_matmul import grouped_matmul
     N, D = x.shape
     held = w_gate_up.shape[0]
     K = experts.shape[1]
+    if not _pd.use_moe_experts("MoEExperts", N * K, D, w_down.shape[1],
+                               x.dtype):
+        return moe_experts_reference(x, w_gate_up, w_down, experts,
+                                     weights, live)
     mine = ((experts < held) & live[:, None]).reshape(-1)      # (N*K,)
     key = jnp.where(mine, experts.reshape(-1), held)
     order = jnp.argsort(key, stable=True)         # held experts first
     counts = expert_counts(experts, live, held)
     xs = jnp.take(x, order // K, axis=0)                       # (N*K, D)
-    gu = jax.lax.ragged_dot(xs, w_gate_up, counts,
-                            preferred_element_type=jnp.float32)
+    interpret = _pd.interpret_mode()
+    gu = grouped_matmul(xs, w_gate_up, counts, interpret=interpret)
     act = _swiglu(gu).astype(x.dtype)
-    ys = jax.lax.ragged_dot(act, w_down, counts,
-                            preferred_element_type=jnp.float32)
-    # rows past the last group belong to no expert: whatever the
-    # product left there is not part of the result
+    ys = grouped_matmul(act, w_down, counts, interpret=interpret)
+    # rows past the last group belong to no expert: the product never
+    # wrote them, and whatever they hold is not part of the result
     ys = jnp.where((jnp.arange(N * K) < jnp.sum(counts))[:, None], ys, 0)
     back = jnp.argsort(order)                     # assignment -> row
     y = jnp.take(ys, back, axis=0).reshape(N, K, D)

@@ -15,7 +15,11 @@ where explicit VMEM blocking beats XLA's default schedule:
   accumulation, scale applied once at the last K step);
 * ``paged_attention``  — block-table flash attention over the serving
   decode plane's paged KV pool (scalar-prefetch tables, dynamic block
-  skip — the gather XLA cannot re-block on its own).
+  skip — the gather XLA cannot re-block on its own);
+* ``grouped_matmul``   — an expert layer's grouped product over uneven
+  groups of sorted rows: every group with a row streams its matrix
+  once a row tile, in tiles of megabytes (a handful of rows against
+  59 MB of weights a group is all weight traffic).
 
 ``dispatch`` is the routing seam: eligible op lowerings (the registry
 ``fcompute`` layer every execution plane traces through) ask it whether

@@ -280,12 +280,22 @@ def eligible_mla_paged(b, h, lq, lk, d, rank, dtype, block_size):
 
 def eligible_moe_experts(n, d, f, dtype):
     """May an expert layer's held part run as the sorted, grouped
-    product (``ops/moe.py``, ``jax.lax.ragged_dot``)?  Compiled, the
-    grouped product is a Mosaic kernel that wants both widths in whole
-    128-lane tiles; the interpreter takes any shape."""
+    product (``ops/moe.py`` over ``grouped_matmul.py``)?  ``n`` sorted
+    rows (tokens x picks), hidden width ``d``, expert width ``f``.
+    Compiled, the kernel wants both widths in whole 128-lane tiles, a
+    row tile Mosaic can tile, and either contraction, whole, by one
+    lane tile of columns inside its weight tile; the interpreter takes
+    any shape."""
     if str(dtype) not in _FLOAT_DTYPES or min(int(n), int(d), int(f)) < 1:
         return False
-    return interpret_mode() or (int(d) % 128 == 0 and int(f) % 128 == 0)
+    if interpret_mode():
+        return True
+    from . import grouped_matmul as gm
+    size = 2 if str(dtype) in ("bfloat16", "float16") else 4
+    return (int(d) % 128 == 0 and int(f) % 128 == 0
+            and mosaic_block_ok(gm.row_tile(int(n)), int(n))
+            and gm.contraction_fits(int(d), size)
+            and gm.contraction_fits(int(f), size))
 
 
 def eligible_dequant_matmul(m, n, k, dtype):

@@ -672,10 +672,13 @@ class GenerationEngine:
              # here, moe_expert_load_max the fullest held expert's
              # count summed over steps and layers, moe_expert_steps
              # steps x expert layers, moe_experts_touched the held
-             # experts that got a token, summed likewise
+             # experts that got a token, summed likewise, moe_expert_
+             # streams how often the grouped product streamed an
+             # expert's weights for them (over touched: 1.0 is the
+             # floor, more means its row tile cuts groups)
              "moe_tokens", "moe_local_assignments",
              "moe_expert_load_max", "moe_expert_steps",
-             "moe_experts_touched",
+             "moe_experts_touched", "moe_expert_streams",
              "spec_steps", "spec_proposed", "spec_accepted",
              "spec_draft_steps", "spec_fallback_steps"),
             labels=self._mlabels, help="generation engine counter")

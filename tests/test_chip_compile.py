@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import pytest
 
 from mxnet_tpu.pallas_ops import dispatch, norm
+from mxnet_tpu.pallas_ops import grouped_matmul as gm
 from mxnet_tpu.pallas_ops import paged_attention as pa
 from mxnet_tpu.pallas_ops import softmax_xent as sx
 
@@ -105,6 +106,10 @@ def _dqmm(x, c, s):
     return dq._dqmm_pallas(x, c, s, 128, 128, 128, False)
 
 
+def _grouped(x, w, c):
+    return gm.grouped_matmul(x, w, c, interpret=False)
+
+
 def _paged_args(s, lq, pool_dtype=F32, bs=BS, blocks=B * T + 1):
     pool = s((2, H, blocks * bs, D), pool_dtype)
     return (s((B, H, lq, D)), pool, pool, s((B, T), I32), s((B,), I32))
@@ -163,6 +168,15 @@ CASES = [
      lambda s: (s((B * 32, W)), s((V, W), I8), s((V,))), 1),
     ("dqmm-ffn2", _dqmm,
      lambda s: (s((B, 4 * W)), s((W, 4 * W), I8), s((W,))), 1),
+    # deepseek-v3.serve-docqa-backlog's grouped products, 16 held
+    # experts: a chunk program's gate and up (512 tokens x 8 picks) and
+    # a decode program's down (64 x 8)
+    ("grouped-chunk-gate-up", _grouped,
+     lambda s: (s((4096, 7168), BF16), s((16, 7168, 4096), BF16),
+                s((16,), I32)), 1),
+    ("grouped-decode-down", _grouped,
+     lambda s: (s((512, 2048), BF16), s((16, 2048, 7168), BF16),
+                s((16,), I32)), 1),
 ]
 
 
@@ -184,6 +198,12 @@ def test_smoke_shapes_are_eligible(compiled_mode):
     assert dispatch.eligible_rowwise(64, 65536, "float32")
     for m, n, k in ((B, 4 * W, W), (B * 32, V, W), (B, W, 4 * W)):
         assert dispatch.eligible_dequant_matmul(m, n, k, "float32")
+    # sorted rows (tokens x 8 picks), hidden and expert width
+    for rows in (4096, 512):
+        assert dispatch.eligible_moe_experts(rows, 7168, 2048, "bfloat16")
+    # a budget, not a refusal: 32,768 values of contraction by one lane
+    # tile of columns are 8 MiB, twice the kernel's weight tile
+    assert not dispatch.eligible_moe_experts(512, 32768, 2048, "bfloat16")
 
 
 # One case per tightened rule: the compiler refuses the shape, and the
@@ -206,6 +226,11 @@ REFUSED = [
      lambda s: _paged_args(s, 1, bs=4),
      lambda: dispatch.eligible_attention_paged(B, H, 1, T * 4, D,
                                                "float32", 4)),
+    # 36 sorted rows tile by 18: not a multiple of 8, not all the rows
+    ("grouped-18-row-tile", _grouped,
+     lambda s: (s((36, 256), BF16), s((4, 256, 256), BF16),
+                s((4,), I32)),
+     lambda: dispatch.eligible_moe_experts(36, 256, 256, "bfloat16")),
     # n = 1000 tiles by 40: the scale row's lane dim is not 128-aligned
     ("dqmm-n-1000", _dqmm,
      lambda s: (s((B, W)), s((1000, W), I8), s((1000,))),
@@ -293,12 +318,9 @@ def _deepseek_program(chip):
                 % ((LAYERS,) + pool.shape[2:]))
 
 
-@pytest.mark.parametrize("kind", ["decode", "prefill-chunk"])
-@pytest.mark.parametrize("build", [_lm_program, _deepseek_program],
-                         ids=["lm2048", "deepseek-v3"])
-def test_paged_program_leaves_the_pool_in_place(chip, compiled_mode,
-                                                build, kind):
-    import re
+def _paged_program_args(build, chip, kind):
+    """``(the build, operands, program, donated)`` of a store's decode
+    or compacted prompt-chunk program for the described chip."""
     from mxnet_tpu.serving.program_store import chunk_rows, paged_program
 
     m = build(chip)
@@ -318,6 +340,17 @@ def test_paged_program_leaves_the_pool_in_place(chip, compiled_mode,
         chip((rows,), jnp.bool_))
     if kind != "decode":
         args += (chip((rows,), I32),)
+    return m, args, fn, donate
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill-chunk"])
+@pytest.mark.parametrize("build", [_lm_program, _deepseek_program],
+                         ids=["lm2048", "deepseek-v3"])
+def test_paged_program_leaves_the_pool_in_place(chip, compiled_mode,
+                                                build, kind):
+    import re
+
+    m, args, fn, donate = _paged_program_args(build, chip, kind)
     compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
     text = compiled.as_text()
     assert fn.__name__ == "paged_" + kind.replace("-", "_")
@@ -336,6 +369,37 @@ def test_paged_program_leaves_the_pool_in_place(chip, compiled_mode,
         pool = m["pools"][0]
         layer_bytes = pool.size // LAYERS * pool.dtype.itemsize
         assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill-chunk"])
+def test_expert_layer_runs_the_repos_grouped_product(chip, compiled_mode,
+                                                     kind):
+    """The ``deepseek_v3`` step compiled for the described v5e holds
+    the repo's grouped product, twice an expert layer, under the name
+    the benchmark's readers look for; no grouped product of another
+    origin (XLA's own ``ragged-dot`` is an instruction or a fusion of
+    that name, never a ``custom-call`` to ``tpu_custom_call``); and no
+    copy, transpose or fusion that hands back something of an expert
+    stack's shape: the stacks are read where they lie."""
+    import re
+    m, args, fn, donate = _paged_program_args(_deepseek_program, chip,
+                                              kind)
+    text = jax.jit(fn, donate_argnums=donate).lower(*args).compile() \
+        .as_text()
+    named = [ln for ln in text.splitlines()
+             if re.match(r"\s*(?:ROOT )?%ragged-dot\S* = ", ln)]
+    assert len(named) == 2 * (LAYERS - 1), "\n".join(named)
+    assert all("ragged-dot_grouped_matmul" in ln
+               and "tpu_custom_call" in ln for ln in named)
+    assert " ragged-dot(" not in text
+    stack = re.compile(r"bf16\[16,(?:7168,4096|2048,7168|4096,7168"
+                       r"|7168,2048)\]")
+    moved = [ln.strip()[:160] for ln in text.splitlines()
+             for hit in [re.match(
+                 r"\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) ([a-z-]+)\(", ln)]
+             if hit and hit.group(2) != "parameter"
+             and stack.search(hit.group(1))]
+    assert not moved, "\n".join(moved)
 
 
 def test_warmup_compiles_the_two_programs_a_burst_dispatches():

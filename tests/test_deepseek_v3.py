@@ -165,6 +165,9 @@ def test_engine_serves_shared_prefix_with_fork_and_counts(ref):
         stats["moe_local_assignments"] / 4
     assert 0 < stats["moe_experts_touched"] <= \
         4 * stats["moe_expert_steps"]
+    # an expert's weights are streamed once a row tile its rows reach:
+    # at these sizes (8 x 4 = 32 sorted rows, one tile) exactly once
+    assert stats["moe_expert_streams"] == stats["moe_experts_touched"]
     cs = stats["cache_state"]["ds"]
     assert cs["pool_bytes"] == 3 * reg.gen_store("ds").pool_blocks \
         * BS * ds.latent_width(SPEC) * 4
@@ -326,28 +329,79 @@ def test_mla_kernel_matches_dense_twin(lq, positions):
         assert not got[B].any()
 
 
-@pytest.mark.parametrize("routing", ["seeded", "all-to-one", "none-held"])
-def test_moe_experts_matches_dense_twin(monkeypatch, routing):
-    """The sorted, grouped product (``MXNET_PALLAS=2``) against the
-    masked loop (``MXNET_PALLAS=0``, which the door then IS): uneven
-    counts, dead rows, a routing that sends every token to one expert
-    (none dropped: that expert's count is the token count) and one that
-    picks no held expert at all."""
+def _sorted_picks(N, K, held, groups):
+    """A routing whose picks ON HELD experts, in sorted order, are the
+    given ``groups`` (rows an expert, experts ``0 .. held - 1``): token
+    ``t``'s pick ``k`` is the ``t * K + k``-th of the flattened list,
+    the rest fall on experts held elsewhere."""
+    flat = [e for e, n in enumerate(groups) for _ in range(n)]
+    assert len(flat) <= N * K
+    flat += [held + i % 4 for i in range(N * K - len(flat))]
+    return np.asarray(flat, np.int32).reshape(N, K)
+
+
+# (id, tokens, picks a token, rows each held expert gets or None for the
+# seeded routing, weights' dtype, (row tile, moe_expert_streams by hand)
+# or None).  40 x 4 = 160 sorted rows tile by 32 (``row_tile``: a 32nd
+# of the rows, at least 32), 21 x 4 = 84 by 28 and 7 x 4 = 28 by 28:
+# what ``divisor_block`` leaves of the bound where it divides nothing.
+_MOE_CASES = [
+    ("seeded", 40, 4, None, "float32", None),
+    ("all-to-one", 40, 4, None, "float32", None),
+    ("none-held", 40, 4, None, "float32", None),
+    # expert 1's 70 rows start at row 5 and reach row 74: tiles 0, 1, 2
+    # (3 visits) beside expert 0's one and expert 3's one in tile 2
+    ("spans-three-tiles", 40, 4, [5, 70, 0, 9], "float32", (32, 5)),
+    # rows 30..33 of expert 1 lie across the edge at 32: 1 + 2 + 1 + 1
+    ("straddles-an-edge", 40, 4, [30, 4, 20, 6], "float32", (32, 5)),
+    ("no-live-row", 40, 4, [0, 0, 0, 0], "float32", (32, 0)),
+    # 84 rows, tiles of 28: expert 1 has rows 0..29 (2 visits), expert
+    # 2 rows 30..69 (tiles 1 and 2)
+    ("rows-not-a-multiple-of-the-bound", 21, 4, [0, 30, 40, 0],
+     "float32", (28, 4)),
+    ("one-tile-is-the-whole-axis", 7, 4, [10, 0, 8, 9], "float32",
+     (28, 3)),
+    ("first-and-last-expert-empty", 40, 4, [0, 50, 37, 0], "float32",
+     None),
+    ("bfloat16", 40, 4, [17, 33, 2, 40], "bfloat16", None),
+]
+
+
+@pytest.mark.parametrize(
+    "routing,N,K,groups,dtype,streams",
+    _MOE_CASES, ids=[c[0] for c in _MOE_CASES])
+def test_moe_experts_matches_dense_twin(monkeypatch, routing, N, K, groups,
+                                        dtype, streams):
+    """The sorted, grouped product (``MXNET_PALLAS=2``: the repo's own
+    kernel under the interpreter) against the masked loop
+    (``MXNET_PALLAS=0``, which the door then IS): uneven counts, dead
+    rows, a routing that sends every token to one expert (none dropped:
+    that expert's count is the token count), one that picks no held
+    expert at all, and the kernel's own edges: a group over three row
+    tiles, a group across a tile edge, no live row, row counts the tile
+    bound does not divide, empty experts at either end, bfloat16
+    operands.  ``moe_expert_streams`` against a count by hand."""
     import jax.numpy as jnp
     from mxnet_tpu.ops import moe
+    from mxnet_tpu.pallas_ops.grouped_matmul import row_tile
     rs = np.random.RandomState(6)
-    N, D, F, held, K = 40, 64, 32, 4, 4
-    x = jnp.asarray(rs.randn(N, D).astype(np.float32))
-    gu = jnp.asarray(rs.randn(held, D, 2 * F).astype(np.float32) / 8)
-    down = jnp.asarray(rs.randn(held, F, D).astype(np.float32) / 6)
+    D, F, held = 64, 32, 4
+    cdt = jnp.dtype(dtype)
+    x = jnp.asarray(rs.randn(N, D).astype(np.float32)).astype(cdt)
+    gu = jnp.asarray(rs.randn(held, D, 2 * F).astype(np.float32) / 8) \
+        .astype(cdt)
+    down = jnp.asarray(rs.randn(held, F, D).astype(np.float32) / 6) \
+        .astype(cdt)
     experts = np.stack([rs.permutation(16)[:K] for _ in range(N)])
     live = np.ones(N, bool)
-    live[[3, 17, 39]] = False
-    if routing == "all-to-one":
+    if groups is not None:
+        experts = _sorted_picks(N, K, held, groups)
+    elif routing == "all-to-one":
         experts[:] = [2, 9, 12, 15]
-        live[:] = True
-    if routing == "none-held":
-        experts = experts % 12 + 4
+    else:
+        live[[3, 17, 39]] = False
+        if routing == "none-held":
+            experts = experts % 12 + 4
     weights = jnp.asarray(rs.uniform(0.1, 1, (N, K)).astype(np.float32))
     args = (x, gu, down, jnp.asarray(experts, jnp.int32), weights,
             jnp.asarray(live))
@@ -358,13 +412,33 @@ def test_moe_experts_matches_dense_twin(monkeypatch, routing):
     monkeypatch.setenv("MXNET_PALLAS", "2")
     got, counts = moe.moe_experts(*args)
     assert np.array_equal(np.asarray(counts), np.asarray(want_counts))
-    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-5
+    if groups is not None:
+        assert np.asarray(counts).tolist() == groups
+    if dtype == "bfloat16":
+        # against the twin at fp32 over the SAME rounded operands.  Both
+        # accumulate in fp32; what differs is the one rounding of the
+        # activation between the two products to bfloat16 (2**-9
+        # relative, which the twin at fp32 does not make), carried
+        # through the second contraction of F = 32 terms and the sum of
+        # K = 4 picks: 2**-9 x sqrt(F x K) of the result's scale (7
+        # here: 0.15; the reading is 2.1e-2).  An expert's rows
+        # multiplied by another expert's weights read the scale itself
+        want, _ = moe.moe_experts_reference(
+            *[a.astype(jnp.float32) for a in args[:3]], *args[3:])
+        tol = 2.0 ** -9 * np.sqrt(F * K) * np.abs(np.asarray(want)).max()
+        assert np.abs(np.asarray(got) - np.asarray(want)).max() < tol
+    else:
+        assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-5
     if routing == "all-to-one":
         assert np.asarray(counts).tolist() == [0, 0, N, 0]
         assert np.abs(np.asarray(got)).min(axis=1).max() > 0
-    if routing == "none-held":
+    if routing in ("none-held", "no-live-row"):
         assert not np.asarray(got).any()
     assert not np.asarray(got)[~live].any()
+    visits = int(moe.expert_streams(counts, N * K))
+    assert visits >= int(np.sum(np.asarray(counts) > 0))
+    if streams is not None:
+        assert (row_tile(N * K), visits) == streams
 
 
 @pytest.mark.parametrize("mode", ["0", "2"])
