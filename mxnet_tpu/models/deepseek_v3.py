@@ -175,7 +175,8 @@ def pack_params(params, spec):
     ``l<i>_experts_down`` ``(held, F, D)``.  The per-expert leaves are
     popped from ``params`` as each stack is made: at the served size the
     experts are 5.6 GB of a 16 GB chip, and a caller who hands over its
-    only reference never holds two copies of more than one layer's.
+    only reference never holds two copies of more than one layer's
+    (each layer is waited for: see below).
     A dict that is already packed is left as it is."""
     import jax
     import jax.numpy as jnp
@@ -195,6 +196,12 @@ def pack_params(params, spec):
         params[gu] = jnp.concatenate([gate, up], axis=2)
         del gate, up
         params[down] = stack_t(*[params.pop(n % "down") for n in names])
+        # dispatch is asynchronous and a result is allocated when its
+        # program is ENQUEUED: without this wait every layer's stacks
+        # are allocated before the first layer's leaves are freed (6 GB
+        # over the weights at lfm2_moe's served size, 16.47 GB of the
+        # chip's 16.91; my chip run, PR 31)
+        jax.block_until_ready((params[gu], params[down]))
     return params
 
 
@@ -204,6 +211,11 @@ def quantize_params(params, spec):
     absmax scale an output channel (a row of an ``(out, in)`` matrix, a
     column of an expert's ``(in, out)`` stack); on the device, a leaf at
     a time."""
+    return quantize_leaves(params, matmul_weights(spec))
+
+
+def quantize_leaves(params, which):
+    """:func:`quantize_params` of the leaves named ``which``."""
     import jax
     import jax.numpy as jnp
     from ..pallas_ops.dequant_matmul import QuantizedWeight
@@ -217,7 +229,7 @@ def quantize_params(params, spec):
         codes = jnp.clip(jnp.rint(w / scale), -127, 127).astype(jnp.int8)
         return codes, (scale[:, 0] if w.ndim == 2 else scale)
 
-    which = set(matmul_weights(spec))
+    which = set(which)
     return {k: QuantizedWeight(*quant(jnp.asarray(v))) if k in which
             else v for k, v in params.items()}
 
@@ -238,9 +250,15 @@ def _plain(w, dtype):
 def random_params(spec, seed=0):
     """Seeded random weights with :func:`param_shapes`' names: matrices
     N(0, 1 / fan_in), norm scales near one, the router's bias small."""
+    return random_leaves(param_shapes(spec), seed)
+
+
+def random_leaves(shapes, seed):
+    """Seeded float32 leaves for ``shapes`` (name -> shape), drawn by
+    the end of each name."""
     rs = np.random.RandomState(seed)
     out = {}
-    for name, shape in sorted(param_shapes(spec).items()):
+    for name, shape in sorted(shapes.items()):
         if name == "embed_weight":
             leaf = rs.normal(0, 1.0, shape)
         elif name.endswith("_weight"):
@@ -343,6 +361,31 @@ def _swiglu_ffn(f, gate, up, down):
     return _mm(act.astype(f.dtype), down).astype(jnp.float32)
 
 
+def expert_layer(f, p, spec, live, eps=0.0):
+    """The routed experts' part of an expert layer over the rows ``f``
+    ``(N, D)``, ``p`` the layer's leaves: sigmoid scores over
+    ``router_width`` in fp32, :func:`ops.moe.route_grouped` (``eps``:
+    what the model adds to the picked scores' sum), the held experts'
+    grouped product.  Returns ``(y (N, D) fp32, this layer's
+    AUX_COUNTERS over the live tokens)``."""
+    import jax
+    import jax.numpy as jnp
+    from ..ops.moe import expert_streams, moe_experts, route_grouped
+    f32, cdt = jnp.float32, f.dtype
+    scores = jax.nn.sigmoid(_mm(f, p["router_weight"], f32))
+    experts, weights = route_grouped(
+        scores, p["router_bias"].astype(f32),
+        spec["num_experts_per_tok"], spec["n_group"],
+        spec["topk_group"], spec["routed_scaling_factor"], eps)
+    y, per = moe_experts(
+        f, _plain(p["experts_gate_up"], cdt),
+        _plain(p["experts_down"], cdt), experts, weights, live)
+    return y, jnp.stack(
+        [jnp.sum(live, dtype=jnp.int32), jnp.sum(per), jnp.max(per),
+         jnp.int32(1), jnp.sum(per > 0, dtype=jnp.int32),
+         expert_streams(per, experts.size)])
+
+
 def paged_step_apply(params, pool, tables, tokens, positions, valid, spec,
                      block_size, all_logits=False):
     """One PAGED step over the latent pool — ``transformer_lm.
@@ -363,10 +406,8 @@ def paged_step_apply(params, pool, tables, tokens, positions, valid, spec,
     held experts that got a token summed over the layers, and how
     often the grouped product streamed an expert's weights for them
     (``ops/moe.expert_streams``; once a touched expert is the floor)."""
-    import jax
     import jax.numpy as jnp
     from ..ops.attention import mla_attention_paged
-    from ..ops.moe import expert_streams, moe_experts, route_grouped
 
     L, D = spec["num_hidden_layers"], spec["hidden_size"]
     H = spec["num_attention_heads"]
@@ -425,22 +466,11 @@ def paged_step_apply(params, pool, tables, tokens, positions, valid, spec,
             y = _swiglu_ffn(f, p["gate_weight"], p["up_weight"],
                             p["down_weight"])
         else:
-            scores = jax.nn.sigmoid(_mm(f, p["router_weight"], f32))
-            experts, weights = route_grouped(
-                scores, p["router_bias"].astype(f32),
-                spec["num_experts_per_tok"], spec["n_group"],
-                spec["topk_group"], spec["routed_scaling_factor"])
-            y, per = moe_experts(
-                f, _plain(p["experts_gate_up"], cdt),
-                _plain(p["experts_down"], cdt), experts, weights, live)
+            y, step = expert_layer(f, p, spec, live)
             y = y + _swiglu_ffn(f, p["shared_gate_weight"],
                                 p["shared_up_weight"],
                                 p["shared_down_weight"])
-            counts = counts + jnp.stack(
-                [jnp.sum(live, dtype=jnp.int32), jnp.sum(per),
-                 jnp.max(per), jnp.int32(1),
-                 jnp.sum(per > 0, dtype=jnp.int32),
-                 expert_streams(per, experts.size)])
+            counts = counts + step
         x = x + y.reshape(B, Lq, D)
     hN = _rms(x, params["final_norm_gamma"], eps).astype(cdt)
     if all_logits:

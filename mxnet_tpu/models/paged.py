@@ -5,6 +5,18 @@ A paged pool is a tuple of stacked leaves ``(L, H, num_blocks *
 block_size, d)`` — ``(k, v)`` for ``transformer_lm``, one latent leaf
 ``(L, 1, R, d)`` for ``deepseek_v3`` — addressed through per-sequence
 block tables; the write is the same for every leaf and every layer.
+
+A STATE leaf ``(L, 1, num_blocks, d)`` holds ONE ROW A BLOCK: what a
+layer keeps per sequence and not per token (``lfm2_moe``'s short
+convolution: its last two inputs), as it stood after the block's last
+written token.  It rides the same tables: the state a sequence brings
+to position ``p`` is the row of the block that holds ``p - 1``
+(:func:`state_read`), a step leaves the state after each block's last
+token it wrote in that block's row (:func:`state_write`), and so a
+prefix-cache hit at ``j`` whole blocks finds the state after token ``j
+* block_size - 1`` in the last adopted block's row, and a
+copy-on-write fork carries a block's row with its tokens
+(docs/architecture/decode_engine.md, "State beside the pool").
 """
 
 
@@ -91,3 +103,53 @@ def pool_write(pools, layer, fresh, plan, block_size):
         return tuple(out)
 
     return jax.lax.fori_loop(0, count, write, tuple(pools))
+
+
+def state_read(state, layer, tables, positions, block_size):
+    """The state each sequence brings to its first position of this
+    step: row ``tables[b, (positions[b] - 1) // bs]`` of layer
+    ``layer`` of the state leaf ``(L, 1, num_blocks, d)``, zeros for a
+    sequence at position 0 — ``(B, d)`` in the leaf's dtype.  A gather
+    of ``B`` rows; the leaf is not copied."""
+    import jax
+    import jax.numpy as jnp
+
+    B, T = tables.shape
+    before = jnp.maximum(positions - 1, 0) // int(block_size)
+    phys = tables[jnp.arange(B), jnp.minimum(before, T - 1)]
+    # one gather over the whole leaf (a ``state[layer]`` first would be
+    # a slice of the leaf's shape for the compiler to think about)
+    rows = jax.vmap(lambda b: jax.lax.dynamic_slice(
+        state, (layer, 0, b, 0), (1, 1, 1, state.shape[3])))(phys)
+    return jnp.where((positions > 0)[:, None], rows.reshape(B, -1), 0)
+
+
+def state_write(state, layer, trail, plan, Lq, block_size):
+    """Leave the state after each written block's last token in that
+    block's row of layer ``layer`` of the state leaf, IN PLACE like
+    :func:`pool_write` (one ``dynamic_update_slice`` a live write of
+    :func:`write_plan`'s ``plan``).  ``trail`` ``(B, n + Lq, w)`` is
+    the sequence of what the state is made of, the ``n`` entries a
+    sequence brought first and then the step's own: the state after
+    chunk row ``r`` is entries ``r + 1 .. r + n`` flattened, ``d = n *
+    w`` values."""
+    import jax
+    import jax.numpy as jnp
+
+    bs = int(block_size)
+    count, cols = plan
+    n = trail.shape[1] - Lq
+    trail = trail.astype(state.dtype)
+
+    def write(i, state):
+        if Lq == 1:
+            last = 0
+        else:       # the last valid chunk row that lands in the block
+            last = jnp.minimum(cols[2][i] + bs, cols[3][i]) - 1
+        new = jax.lax.dynamic_slice(
+            trail, (cols[0][i], last + 1, 0), (1, n, trail.shape[2]))
+        return jax.lax.dynamic_update_slice(
+            state, new.reshape(1, 1, 1, -1),
+            (layer, 0, cols[1][i] // bs, 0))
+
+    return jax.lax.fori_loop(0, count, write, state)

@@ -81,7 +81,7 @@ def sdp_attention(query, key, value, causal=False, scale=0.0,
 
 
 def sdp_attention_paged(query, k_pool, v_pool, layer, tables, positions,
-                        block_size, scale=0.0, kv_scales=None):
+                        block_size, scale=0.0, kv_scales=None, group=1):
     """Paged scaled-dot-product attention: [B, H, Lq, D] queries whose
     row r of sequence b sits at global position ``positions[b] + r``,
     attending over layer ``layer`` (a static int) of the whole stacked
@@ -96,6 +96,13 @@ def sdp_attention_paged(query, k_pool, v_pool, layer, tables, positions,
     through the identical scale arithmetic (on-tile in the kernel, on
     the gathered rows in the reference), so they remain numerical twins.
 
+    GROUPED-QUERY heads: a pool of fewer heads than the query's (a
+    divisor) serves ``H // pool heads`` query heads a pool head, all of
+    them in one Q tile; ``v_pool=None`` reads a pool row as key AND
+    value (``[K | V]`` rows under a query padded with zeros, the value
+    half of the result taken by the caller: ``models/lfm2_moe.py``);
+    ``group`` table entries make one grid step of the kernel.
+
     Eligible shapes route to ``flash_attention_paged`` (scalar-prefetch
     block tables, dynamic block skip, forward-only); everything else —
     and ``MXNET_PALLAS=0`` — lowers to ``paged_attention_reference``,
@@ -106,13 +113,16 @@ def sdp_attention_paged(query, k_pool, v_pool, layer, tables, positions,
     if scale <= 0.0:
         scale = 1.0 / (d ** 0.5)
     from ..pallas_ops import dispatch as _pd
-    if _pd.use_attention_paged("DotProductAttentionPaged", b, h, lq,
+    # the Q tile's rows: the query heads of a pool head, flattened
+    rows = lq * (h // k_pool.shape[1])
+    if _pd.use_attention_paged("DotProductAttentionPaged", b, h, rows,
                                t * bs, d, query.dtype, bs):
         from ..pallas_ops.paged_attention import flash_attention_paged
         return flash_attention_paged(
             query, k_pool, v_pool, layer, tables, positions, bs,
             scale=scale, block_q=_pd.block_seq(),
-            interpret=_pd.interpret_mode(), kv_scales=kv_scales)
+            interpret=_pd.interpret_mode(), kv_scales=kv_scales,
+            group=group)
     from ..pallas_ops.paged_attention import paged_attention_reference
     return paged_attention_reference(query, k_pool, v_pool, layer, tables,
                                      positions, bs, scale=scale,

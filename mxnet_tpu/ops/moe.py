@@ -28,13 +28,16 @@ __all__ = ["route_grouped", "moe_experts", "moe_experts_reference",
            "expert_counts", "expert_streams"]
 
 
-def route_grouped(scores, bias, top_k, n_group, topk_group, scale):
+def route_grouped(scores, bias, top_k, n_group, topk_group, scale,
+                  eps=0.0):
     """Group-limited top-k routing.  ``scores`` ``(N, E)`` fp32 are the
     experts' sigmoid affinities, ``bias`` ``(E,)`` the correction that
     enters the CHOICE only.  A group's score is the sum of its two
     largest choice scores; the ``topk_group`` best groups stay; the
     ``top_k`` best choice scores among them pick the experts; their
-    weights are the UNBIASED scores over their sum, times ``scale``.
+    weights are the UNBIASED scores over their sum (plus the model's
+    ``eps``, where it has one), times ``scale``.  ``n_group =
+    topk_group = 1`` is plain top-k over all experts.
     Ties go to the lower index, in groups and experts alike
     (``jax.lax.top_k``).  Returns ``(experts (N, top_k) int32, weights
     (N, top_k) fp32)``."""
@@ -49,7 +52,8 @@ def route_grouped(scores, bias, top_k, n_group, topk_group, scale):
     masked = jnp.where(jnp.repeat(kept, per, axis=1), choice, -jnp.inf)
     _, experts = jax.lax.top_k(masked, int(top_k))
     picked = jnp.take_along_axis(scores, experts, axis=1)
-    weights = picked / jnp.sum(picked, axis=-1, keepdims=True) * scale
+    total = jnp.sum(picked, axis=-1, keepdims=True)
+    weights = picked / (total + eps if eps else total) * scale
     return experts.astype(jnp.int32), weights
 
 

@@ -242,14 +242,18 @@ def eligible_attention_paged(b, h, lq, lk, d, dtype, block_size):
 
     ``lk`` is the logical length the table addresses (table width ×
     block size).  The K/V tile is one ``block_size``-token pool block
-    and the Q tile a divisor of ``lq``; compiled Mosaic must accept both
+    and the Q tile a divisor of ``lq`` (the rows of a Q tile: a
+    grouped-query caller counts the query heads of a pool head into
+    it); compiled Mosaic must accept both
     (``mosaic_block_ok`` — ``MXNET_SERVE_KV_BLOCK=4`` is left to XLA).
     """
     bs = int(block_size)
     if bs < 1 or not _decode_attention_ok(b, h, lq, lk, d, dtype):
         return False
     # the pool axis is num_blocks * bs: a block is never "the whole axis"
-    return interpret_mode() or bs % 8 == 0
+    # (a two-byte tile packs 16 rows a sublane tile)
+    return interpret_mode() or \
+        bs % (8 if str(dtype) == "float32" else 16) == 0
 
 
 def eligible_mla_paged(b, h, lq, lk, d, rank, dtype, block_size):

@@ -46,30 +46,42 @@ __all__ = ["flash_attention_paged", "paged_attention_reference"]
 _NEG = -1e30  # flash_attention._NEG: shared mask constant for parity
 
 
-def _paged_kernel(*refs, scale, block_q, block_size, nt, int8):
-    """One (batch, head, q-block, logical-block) grid cell.
+def _paged_kernel(*refs, scale, block_q, block_size, nk, int8, lq,
+                  heads, group, own_v):
+    """One (batch, pool head, q-block, group of logical blocks) grid
+    cell.
 
     ``tbl_ref``/``pos_ref`` are the scalar-prefetch operands (SMEM);
-    the k dimension walks logical blocks j — the index maps already
-    dereferenced ``tbl_ref[b, j]``, so ``k_ref``/``v_ref`` hold the
-    PHYSICAL tile.  Masking happens in logical position space.
+    the k dimension walks logical blocks, ``group`` of them a step —
+    the index maps already dereferenced ``tbl_ref[b, j]``, so the
+    ``group`` key refs (and, with ``own_v``, as many value refs) hold
+    PHYSICAL tiles.  Masking happens in logical position space.  The Q
+    tile holds the ``heads`` query heads that share this pool head,
+    flattened: row ``i`` is query ``i % lq`` of head ``i // lq``.
 
     ``int8`` adds two more scalar-prefetch operands — per-(head,
     physical block) fp32 absmax scales for the K and V pools — and the
-    tile loads dequantize on-tile (``codes * sk_ref[h, tbl_ref[b, ki]]``)
+    tile loads dequantize on-tile (``codes * sk_ref[h, tbl_ref[b, j]]``)
     before the unchanged fp32 online softmax."""
     if int8:
-        (tbl_ref, pos_ref, sk_ref, sv_ref, q_ref, k_ref, v_ref,
-         o_ref, m_ref, l_ref, acc_ref) = refs
+        tbl_ref, pos_ref, sk_ref, sv_ref, q_ref = refs[:5]
     else:
-        (tbl_ref, pos_ref, q_ref, k_ref, v_ref,
-         o_ref, m_ref, l_ref, acc_ref) = refs
+        tbl_ref, pos_ref, q_ref = refs[:3]
         sk_ref = sv_ref = None
+    tiles = refs[5 if int8 else 3:-4]
+    k_refs = tiles[:group]
+    v_refs = tiles[group:] if own_v else k_refs   # a row is key AND value
+    o_ref, m_ref, l_ref, acc_ref = refs[-4:]
     b = pl.program_id(0)
     h = pl.program_id(1)
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     ofs = pos_ref[b]
+    span = group * block_size
+    # multiply in the tiles' own dtype where query and pool share it
+    # (bfloat16 both: one MXU pass), else in fp32 as the dense twin does
+    cdt = q_ref.dtype if not int8 and q_ref.dtype == k_refs[0].dtype \
+        else jnp.float32
 
     @pl.when(ki == 0)
     def _init():
@@ -77,27 +89,41 @@ def _paged_kernel(*refs, scale, block_q, block_size, nt, int8):
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # dynamic skip: logical block ki contributes iff the last query row
-    # (global position ofs + qi*block_q + block_q - 1) can see its first
-    # key position (ki * block_size)
-    run = ofs + qi * block_q + block_q - 1 >= ki * block_size
+    # dynamic skip: the group contributes iff the tile's last query row
+    # can see its first key position (ki * span).  A tile inside one
+    # head ends at query qi*block_q % lq + block_q - 1; one that spans
+    # heads holds the chunk's last query, lq - 1
+    if heads == 1:
+        last = qi * block_q + block_q - 1
+    elif lq % block_q == 0:
+        last = jax.lax.rem(qi * block_q, lq) + block_q - 1
+    else:
+        last = lq - 1
+    run = ofs + last >= ki * span
 
     @pl.when(run)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)     # (BQ, D)
-        kb = k_ref[0, 0].astype(jnp.float32)    # (BS, D)
-        vb = v_ref[0, 0].astype(jnp.float32)
-        if int8:
-            phys = tbl_ref[b, ki]               # SMEM scalar read
-            kb = kb * sk_ref[h, phys]
-            vb = vb * sv_ref[h, phys]
+        q = q_ref[0, 0].astype(cdt)             # (BQ, D)
+
+        def tile(refs_, s_ref):
+            out = []
+            for g, ref in enumerate(refs_):
+                t = ref[0, 0].astype(cdt)       # (BS, D)
+                if int8:                        # SMEM scalar reads
+                    t = t * s_ref[h, tbl_ref[b, ki * group + g]]
+                out.append(t)
+            return out[0] if group == 1 else jnp.concatenate(out, axis=0)
+
+        kb = tile(k_refs, sk_ref)               # (span, D)
+        vb = tile(v_refs, sv_ref) if own_v else kb
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (BQ, BS)
-        qpos = ofs + qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_size), 0)
-        kpos = ki * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_size), 1)
+            preferred_element_type=jnp.float32) * scale  # (BQ, span)
+        row = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, span), 0)
+        qpos = ofs + (row if heads == 1 else jax.lax.rem(row, lq))
+        kpos = ki * span + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, span), 1)
         s = jnp.where(qpos >= kpos, s, _NEG)
         m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev,
@@ -108,9 +134,9 @@ def _paged_kernel(*refs, scale, block_q, block_size, nt, int8):
         m_ref[:] = m_new
         l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot(
-            p, vb, preferred_element_type=jnp.float32)
+            p.astype(cdt), vb, preferred_element_type=jnp.float32)
 
-    @pl.when(ki == nt - 1)
+    @pl.when(ki == nk - 1)
     def _finalize():
         l = l_ref[:]
         o_ref[0, 0] = (acc_ref[:] /
@@ -119,17 +145,30 @@ def _paged_kernel(*refs, scale, block_q, block_size, nt, int8):
 
 def flash_attention_paged(q, k_pool, v_pool, layer, tables, positions,
                           block_size, scale=None, block_q=128,
-                          interpret=None, kv_scales=None):
+                          interpret=None, kv_scales=None, group=1):
     """Offset-causal flash attention against a PAGED KV pool.
 
     q: (B, H, Lq, D) — query row r of sequence b sits at global
     position ``positions[b] + r``; k_pool/v_pool: the whole stacked
-    ``(L, H, num_blocks * block_size, D)`` global pools, read in place
+    ``(L, Hp, num_blocks * block_size, D)`` global pools, read in place
     at the static index ``layer``; tables: (B, T) int32 per-sequence
     block tables mapping logical block j to a physical pool block
     (entries past a sequence's frontier must point at a valid block —
     conventionally the reserved trash block 0 — their keys are masked
     either way); positions: (B,) int32 frontiers.
+
+    GROUPED-QUERY heads: the pool may hold fewer heads than the query,
+    ``Hp`` dividing ``H``; query head i attends pool head ``i // (H //
+    Hp)``, and the ``H // Hp`` query heads of a pool head sit in ONE Q
+    tile, so a K/V tile is fetched once for all of them.  ``v_pool=
+    None``: a pool row is key AND value (``score = q . row``, ``out =
+    softmax . row``) — a model whose head is narrower than a 128-lane
+    tile keeps ``[K | V]`` side by side in one row, hands a query that
+    is zero over the value half and takes the value half of the result
+    (``models/lfm2_moe.py``); each tile is then fetched once, not
+    twice.  ``group`` consecutive table entries make one grid step (a
+    divisor of T is taken): the pool is handed to the call that many
+    times, each operand with its own index map.
 
     ``kv_scales`` — a ``(scale_k, scale_v)`` pair of ``(L, H,
     num_blocks)`` fp32 per-(layer, head, physical block) absmax scales
@@ -147,20 +186,28 @@ def flash_attention_paged(q, k_pool, v_pool, layer, tables, positions,
     T = tables.shape[1]
     bs = int(block_size)
     layer = int(layer)
-    assert k_pool.shape == v_pool.shape and k_pool.ndim == 4 \
-        and k_pool.shape[1] == H and 0 <= layer < k_pool.shape[0]
+    own_v = v_pool is not None
+    Hp = k_pool.shape[1]
+    assert k_pool.ndim == 4 and H % Hp == 0 and k_pool.shape[3] == D \
+        and 0 <= layer < k_pool.shape[0] \
+        and (not own_v or k_pool.shape == v_pool.shape)
     assert k_pool.shape[2] % bs == 0, \
         "pool length must be a multiple of block_size"
     if scale is None:
         scale = 1.0 / (D ** 0.5)
-    block_q = divisor_block(Lq, block_q)
+    heads = H // Hp
+    rows = heads * Lq
+    block_q = divisor_block(rows, block_q)
+    group = divisor_block(T, group)
+    nk = T // group
     tbl = jnp.asarray(tables, jnp.int32)
     pos = jnp.asarray(positions, jnp.int32).reshape(B)
     int8 = kv_scales is not None
 
     kernel = functools.partial(_paged_kernel, scale=float(scale),
-                               block_q=block_q, block_size=bs, nt=T,
-                               int8=int8)
+                               block_q=block_q, block_size=bs, nk=nk,
+                               int8=int8, lq=Lq, heads=heads,
+                               group=group, own_v=own_v)
 
     if int8:
         sk = jnp.asarray(kv_scales[0][layer], jnp.float32)
@@ -171,32 +218,35 @@ def flash_attention_paged(q, k_pool, v_pool, layer, tables, positions,
     # the scalar operands stay first, tables then positions: a trace's
     # readers know the kernel by them (benchmark/layer_metrics/)
     q_map = lambda b, h, i, j, *_: (b, h, i, 0)
-    kv_map = lambda b, h, i, j, tbl, *_: (layer, h, tbl[b, j], 0)
 
+    def kv_map(g):
+        return lambda b, h, i, j, tbl, *_: (layer, h,
+                                            tbl[b, j * group + g], 0)
+
+    # k/v: fetch PHYSICAL block tbl[b, j * group + g] of this layer
+    # from the stacked pool — the index is in units of whole (bs, D)
+    # blocks
+    pools = [k_pool] * group + ([v_pool] * group if own_v else [])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(B, H, Lq // block_q, T),
-        in_specs=[
-            _spec((1, 1, block_q, D), q_map),  # Q tile
-            # k/v: fetch PHYSICAL block tbl[b, j] of this layer from
-            # the stacked pool — the index is in units of whole
-            # (bs, D) blocks
-            _spec((1, 1, bs, D), kv_map),
-            _spec((1, 1, bs, D), kv_map),
-        ],
+        grid=(B, Hp, rows // block_q, nk),
+        in_specs=[_spec((1, 1, block_q, D), q_map)] + [
+            _spec((1, 1, bs, D), kv_map(g))
+            for g in list(range(group)) * (2 if own_v else 1)],
         out_specs=_spec((1, 1, block_q, D), q_map),
         scratch_shapes=_softmax_scratch(block_q, D))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Lq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hp, rows, D), q.dtype),
         interpret=_resolve_interpret(interpret),
         name="paged_attention",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")))(*scalars, q, k_pool,
-                                                v_pool)
-    return out
+                                 "arbitrary")))(
+                                     *scalars, q.reshape(B, Hp, rows, D),
+                                     *pools)
+    return out.reshape(B, H, Lq, D)
 
 
 def paged_attention_reference(q, k_pool, v_pool, layer, tables,
@@ -207,7 +257,9 @@ def paged_attention_reference(q, k_pool, v_pool, layer, tables,
     the exact dense offset-causal attention (same ``-1e30`` constant,
     fp32 accumulation) — the ``MXNET_PALLAS=0`` lowering and the parity
     oracle.  ``kv_scales`` dequantizes int8 pools through the SAME
-    per-(layer, head, physical block) scale arithmetic as the kernel."""
+    per-(layer, head, physical block) scale arithmetic as the kernel;
+    fewer pool heads than query heads are repeated for their group, and
+    ``v_pool=None`` reads a row as key and value alike."""
     B, H, Lq, D = q.shape
     T = tables.shape[1]
     bs = int(block_size)
@@ -220,7 +272,8 @@ def paged_attention_reference(q, k_pool, v_pool, layer, tables,
            jnp.arange(bs, dtype=jnp.int32)[None, None, :]).reshape(
                B, T * bs)
     k = jnp.transpose(jnp.take(k_pool[layer], idx, axis=1), (1, 0, 2, 3))
-    v = jnp.transpose(jnp.take(v_pool[layer], idx, axis=1), (1, 0, 2, 3))
+    v = k if v_pool is None else \
+        jnp.transpose(jnp.take(v_pool[layer], idx, axis=1), (1, 0, 2, 3))
     if kv_scales is not None:
         # per-(head, physical block) dequant, identical to the kernel's
         # on-tile multiply: scale[h, tbl[b, j]] covers pool rows
@@ -233,6 +286,9 @@ def paged_attention_reference(q, k_pool, v_pool, layer, tables,
             axis=2), (1, 0, 2))
         k = k.astype(jnp.float32) * sck[..., None]
         v = v.astype(jnp.float32) * scv[..., None]
+    heads = H // k.shape[1]
+    if heads > 1:
+        k, v = jnp.repeat(k, heads, axis=1), jnp.repeat(v, heads, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     qpos = jax.lax.broadcasted_iota(jnp.int32, (Lq, T * bs), 0)

@@ -469,6 +469,12 @@ class _PagedModelState:
         self.scales = (store.new_scale_pool() if store.kv_int8
                        else None)
         self.tb = store.table_width()
+        # rows a block holds in the model's state leaves, and the
+        # leaves' bytes (0: every leaf is by token)
+        self.state_rows = store.state_rows_per_block()
+        self.state_bytes = sum(
+            int(np.prod(a.shape)) * a.dtype.itemsize
+            for a in store.state_avals)
         self.slots = []                        # _GenRequest or None
         self.tables = np.zeros((0, self.tb), np.int32)
         self.lengths = np.zeros(0, np.int32)   # KV frontier per slot
@@ -574,6 +580,13 @@ class _PagedModelState:
                  per_block / self.store.kv_block,
              "block_bytes": per_block,
              "cache_dtype": str(self.pools[0].dtype)}
+        if self.state_rows:
+            # what the model keeps per sequence lies one row a block in
+            # the pool's state leaves (models/paged.py): rows held by
+            # the allocated blocks, and the leaves' bytes (counted in
+            # pool_bytes too: one pool, one allocator)
+            d["state_rows_live"] = self.pool.used() * self.state_rows
+            d["state_bytes"] = self.state_bytes
         if self.draft is not None:
             dbytes = sum(a.size * a.dtype.itemsize
                          for a in self.dpools + (self.dscales or ()))
@@ -659,6 +672,11 @@ class GenerationEngine:
              "prefix_hits", "prefix_hit_blocks", "prefix_hit_tokens",
              "cow_forks", "prefill_chunks", "prefill_row_slots",
              "prefill_rows_deferred", "shed_pool",
+             # admissions whose per-sequence state (a model with state
+             # leaves) came from the prefix cache with the blocks;
+             # prompt_tokens_admitted is what prefix_hit_tokens is a
+             # share of
+             "state_restores", "prompt_tokens_admitted",
              # speculative decoding (zero without a draft attached):
              # spec_steps counts verify dispatches (each is ONE target
              # step emitting 1..K+1 tokens), spec_proposed/spec_
@@ -881,6 +899,10 @@ class GenerationEngine:
         out["tenant_quotas"] = dict(self._tenant_quotas)
         out["models"] = {m: st.describe()
                          for m, st in dict(self._states).items()}
+        # per-sequence state held beside the pool, over the models
+        # (zero for models whose every leaf is by token)
+        for k in ("state_rows_live", "state_bytes"):
+            out[k] = sum(d.get(k, 0) for d in out["models"].values())
         # the KV memory claims as measurable evidence (the PR-12
         # weight_bytes discipline): dtype-aware cache/pool BYTES per
         # model — int8 pools count codes + scale pools together
@@ -1285,6 +1307,13 @@ class GenerationEngine:
                     break
                 total_blocks = -(-(len(r.prompt) + r.max_tokens) // bs)
                 blocks, tail = st.prefix.match(r.prompt)
+                if st.state_rows:
+                    # a state leaf holds the state after a block's LAST
+                    # token: a hit restores it at a block boundary, and
+                    # the prompt's last token reruns from there (no
+                    # tail, and not the block that holds that token)
+                    blocks = blocks[:(len(r.prompt) - 1) // bs]
+                    tail = None
                 # a partially-filled last prompt block gets pinned by the
                 # prefix cache at registration, so the first decode write
                 # into it MUST copy-on-write-fork — one allocation past
@@ -1302,9 +1331,13 @@ class GenerationEngine:
                         "pool's %d usable blocks — shed"
                         % (total_blocks + fork_extra, st.pool.capacity())))
                     continue
-                budget = (st.pool.free_count() + st.prefix.evictable() -
-                          st.reserved_total())
-                if needed > budget:
+                # what eviction could free is counted only when the free
+                # list alone does not do: evictable() walks every pin of
+                # the prefix cache (21 ms at 2,300 pins, and the device
+                # idle meanwhile; PERF.md section 6, PR 31)
+                budget = st.pool.free_count() - st.reserved_total()
+                if needed > budget and \
+                        needed > budget + st.prefix.evictable():
                     break   # wait for retirements; no overtaking
                 dq.popleft()
                 if not r.future.set_running_or_notify_cancel():
@@ -1321,6 +1354,7 @@ class GenerationEngine:
                 for j, b in enumerate(blocks):
                     row[j] = b
                     st.pool.ref(b)
+                self._stats.inc("prompt_tokens_admitted", len(r.prompt))
                 covered = len(blocks) * bs
                 if tail is not None:
                     row[len(blocks)] = tail
@@ -1331,6 +1365,8 @@ class GenerationEngine:
                     self._stats.inc("prefix_hit_blocks",
                                     len(blocks) + (tail is not None))
                     self._stats.inc("prefix_hit_tokens", covered)
+                    if st.state_rows:
+                        self._stats.inc("state_restores")
                     _metrics.cached_counter(
                         "serve_prefix_hit_total",
                         help="admissions that reused shared paged-KV "
@@ -1382,7 +1418,9 @@ class GenerationEngine:
                 # blocks_alloc: what admission reserved of the pool (the
                 # blocks themselves are taken as rows are written)
                 span.add(admitted=1, prefix_hit_tokens=covered,
-                         blocks_alloc=needed)
+                         blocks_alloc=needed,
+                         state_restored=int(bool(covered
+                                                 and st.state_rows)))
                 admitted += 1
             if admitted:
                 self._stats.inc("prefill_seqs", admitted)

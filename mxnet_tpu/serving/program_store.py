@@ -879,7 +879,7 @@ def cache_donate_argnums(nums):
 # ``pack_params``, ``quantize_params``, ``init_pool``, ``paged_step``,
 # ``OFFERS``, ``AUX_COUNTERS``; models/transformer_lm.py, bottom) is all
 # the store knows of an architecture.
-_ARCHS = ("transformer_lm", "deepseek_v3")
+_ARCHS = ("transformer_lm", "deepseek_v3", "lfm2_moe")
 
 
 def _serving_model(arch):
@@ -1024,8 +1024,9 @@ class GenerativeProgramStore:
     spec : dict
         ``transformer_lm.lm_spec(...)`` architecture spec, or another
         decode-mode model's with its name under ``arch``
-        (``"deepseek_v3"``: ``models/deepseek_v3.serving_spec``; paged
-        plane only, no int8 pool).  A model that restacks leaves at
+        (``"deepseek_v3"``: ``models/deepseek_v3.serving_spec``,
+        ``"lfm2_moe"``: ``models/lfm2_moe.serving_spec``; paged plane
+        only, no int8 pool).  A model that restacks leaves at
         load (``deepseek_v3``'s routed experts) pops them from
         ``params`` as it goes: hand it a copy to keep yours.
     batch_buckets / prompt_buckets : iterable of int, optional
@@ -1169,7 +1170,8 @@ class GenerativeProgramStore:
         self.pool_blocks = nb
         self._copy_fn = None   # lazily jitted COW block copy
         # the pool's leaves as the model shapes them: (k, v) for the
-        # LM, one latent leaf for deepseek_v3
+        # LM, one latent leaf for deepseek_v3, [K | V] rows and the
+        # convolution state (one row a BLOCK) for lfm2_moe
         self._pool_avals = tuple(jax.eval_shape(
             lambda: self._model.init_pool(self._spec, nb, self.kv_block,
                                           dtype=self.kv_dtype)))
@@ -1431,14 +1433,14 @@ class GenerativeProgramStore:
         leaves = self._pool_args(tuple(pools), scales)
         fn = self._copy_fn
         if fn is None:
-            bs, n_leaves = self.kv_block, self.pool_leaves
+            nb = self.pool_blocks
 
             def copy_block(leaves, s, d):
-                # axis 2 counts tokens in a pool leaf (bs a block) and
-                # blocks in a scale pool (one a block)
+                # axis 2 counts a block's rows: kv_block tokens in a
+                # token leaf, one row in a state leaf or a scale pool
                 out = []
-                for i, leaf in enumerate(leaves):
-                    n = bs if i < n_leaves else 1
+                for leaf in leaves:
+                    n = leaf.shape[2] // nb
                     out.append(jax.lax.dynamic_update_slice_in_dim(
                         leaf, jax.lax.dynamic_slice_in_dim(
                             leaf, s * n, n, 2), d * n, 2))
@@ -1473,6 +1475,21 @@ class GenerativeProgramStore:
         v)``, 1 for a latent pool): the run methods take that many
         right after ``self``."""
         return len(self._pool_avals)
+
+    @property
+    def state_avals(self):
+        """Avals of the pool's STATE leaves, the ones with one row a
+        block and not ``kv_block`` (what a model keeps per sequence,
+        as it stood after the block's last token: ``models/paged.py``);
+        empty for a model whose every leaf is by token."""
+        if self.kv_block == 1:
+            return ()
+        return tuple(a for a in self._pool_avals
+                     if a.shape[2] == self.pool_blocks)
+
+    def state_rows_per_block(self):
+        """Rows a block holds in the state leaves: their layers."""
+        return sum(a.shape[0] * a.shape[1] for a in self.state_avals)
 
     def _pool_spec(self):
         """Avals of every donated pool argument: the model's leaves,

@@ -318,6 +318,38 @@ def _deepseek_program(chip):
                 % ((LAYERS,) + pool.shape[2:]))
 
 
+def _lfm2_program(chip):
+    """The cell ``lfm2-24b-a2b.serve-agent-backlog`` as its
+    configuration file deploys it: LFM2-24B-A2B's published widths, all
+    nine layers (7 convolution, 2 attention, 8 of 64 experts), 128
+    slots of 64 blocks of 64 tokens, bfloat16 weights, ``[K | V]`` rows
+    and convolution state."""
+    import json
+    from mxnet_tpu.models import lfm2_moe as lfm
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "lfm2-24b-a2b.json")) as f:
+        cfg = json.load(f)
+    deploy = cfg["deploy"]
+    spec = lfm.serving_spec({k: v for k, v in cfg["spec"].items()
+                             if k != "arch"})
+    packed = jax.eval_shape(lambda: lfm.pack_params(
+        {k: jnp.zeros(v, BF16)
+         for k, v in lfm.param_shapes(spec).items()}, spec))
+    params = {k: chip(v.shape, v.dtype) for k, v in packed.items()}
+    slots, = deploy["batch_buckets"]
+    width = deploy["kv_max"] // deploy["kv_block"]
+    assert deploy["kv_block"] == BS
+    pools = tuple(chip(a.shape, a.dtype) for a in jax.eval_shape(
+        lambda: lfm.init_pool(spec, slots * width + 1, BS, "bfloat16")))
+    return dict(model=lfm, spec=spec, params=params, pools=pools,
+                slots=slots, width=width, chunk=deploy["prefill_chunk"],
+                kernels=2 + 2 * 8,
+                pool_shaped="|".join(
+                    r"bf16\[(?:%d,|1,)?%d,%d,%d\]" % a.shape
+                    for a in pools))
+
+
 def _paged_program_args(build, chip, kind):
     """``(the build, operands, program, donated)`` of a store's decode
     or compacted prompt-chunk program for the described chip."""
@@ -344,8 +376,9 @@ def _paged_program_args(build, chip, kind):
 
 
 @pytest.mark.parametrize("kind", ["decode", "prefill-chunk"])
-@pytest.mark.parametrize("build", [_lm_program, _deepseek_program],
-                         ids=["lm2048", "deepseek-v3"])
+@pytest.mark.parametrize("build", [_lm_program, _deepseek_program,
+                                   _lfm2_program],
+                         ids=["lm2048", "deepseek-v3", "lfm2-24b-a2b"])
 def test_paged_program_leaves_the_pool_in_place(chip, compiled_mode,
                                                 build, kind):
     import re
@@ -400,6 +433,40 @@ def test_expert_layer_runs_the_repos_grouped_product(chip, compiled_mode,
              if hit and hit.group(2) != "parameter"
              and stack.search(hit.group(1))]
     assert not moved, "\n".join(moved)
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill-chunk"])
+def test_lfm2_cell_programs_fit_the_chip(chip, compiled_mode, kind):
+    """The cell's two programs at the published widths, compiled for
+    the described v5e: arguments (10.36 GB of weights, the 2.15 GB
+    ``[K | V]`` leaf, the 0.47 GB state leaf) and scratch under 15 GB
+    of the chip's 16; the grouped product eligible at both of this
+    model's width pairs and in the program twice an expert layer under
+    the name the benchmark's readers look for, none of another origin;
+    the attention kernel once an attention layer with all four query
+    heads of a KV head in its tile."""
+    import re
+    m, args, fn, donate = _paged_program_args(_lfm2_program, chip, kind)
+    rows = args[1 + len(m["pools"]) + 1].shape
+    sorted_rows = rows[0] * rows[1] * m["spec"]["num_experts_per_tok"]
+    assert dispatch.eligible_moe_experts(sorted_rows, 2048, 1536,
+                                         "bfloat16")
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
+    assert mem.temp_size_in_bytes < 1e9
+    text = compiled.as_text()
+    named = [ln for ln in text.splitlines()
+             if re.match(r"\s*(?:ROOT )?%ragged-dot\S* = ", ln)]
+    assert len(named) == 2 * 8, "\n".join(named)
+    assert all("ragged-dot_grouped_matmul" in ln
+               and "tpu_custom_call" in ln for ln in named)
+    assert " ragged-dot(" not in text
+    attn = [ln for ln in text.splitlines()
+            if re.match(r"\s*(?:ROOT )?%paged_attention\S* = ", ln)]
+    assert len(attn) == 2
+    tile = "bf16[%d,8,%d,128]" % (rows[0], 4 * rows[1])
+    assert all(tile in ln and "tpu_custom_call" in ln for ln in attn)
 
 
 def test_warmup_compiles_the_two_programs_a_burst_dispatches():
