@@ -672,6 +672,12 @@ class GenerationEngine:
              "prefix_hits", "prefix_hit_blocks", "prefix_hit_tokens",
              "cow_forks", "prefill_chunks", "prefill_row_slots",
              "prefill_rows_deferred", "shed_pool",
+             # paged dispatches (decode steps and prompt chunks) for
+             # which the sampler drew (a row with temperature > 0) and
+             # for which it also sorted the vocabulary (a sampling row
+             # whose top_k cuts it): what sample_tokens' two conds
+             # took, counted from the host's copy of their inputs
+             "sample_draw_dispatches", "sample_topk_dispatches",
              # admissions whose per-sequence state (a model with state
              # leaves) came from the prefix cache with the blocks;
              # prompt_tokens_admitted is what prefix_hit_tokens is a
@@ -1546,13 +1552,26 @@ class GenerationEngine:
         rows of the arrays this dispatch works for), ``kv_tokens``,
         the sum of their frontiers after the step, and ``q_tokens``,
         the query rows they bring (one each in a decode step); the
-        caller's ``counts`` ride beside them."""
-        work = dict(counts, rows=len(live),
-                    kv_tokens=int((pos[live] + val[live]).sum()),
-                    q_tokens=int(val[live].sum()))
+        caller's ``counts`` ride beside them, and ``sample_draw`` /
+        ``sample_topk``: whether the sampler draws, and sorts, for
+        this dispatch (``sample_tokens``' two predicates, taken from
+        the host's copy of its inputs)."""
         temps, top_ks = st.temps, st.top_ks
         if slots is not None:
             temps, top_ks = temps[slots], top_ks[slots]
+        draws = ~(temps <= 0.0)
+        sample_draw = bool(draws.any())
+        sample_topk = sample_draw and bool((draws & (top_ks > 0) & (
+            top_ks < st.store.spec["vocab_size"])).any())
+        if sample_draw:
+            self._stats.inc("sample_draw_dispatches")
+        if sample_topk:
+            self._stats.inc("sample_topk_dispatches")
+        work = dict(counts, rows=len(live),
+                    kv_tokens=int((pos[live] + val[live]).sum()),
+                    q_tokens=int(val[live].sum()),
+                    sample_draw=int(sample_draw),
+                    sample_topk=int(sample_topk))
         if st.store.sample_mode == "graph":
             with _profiler.phase(phase, **work):
                 if slots is None:

@@ -140,6 +140,16 @@ def sample_tokens(logits, keys, temps, top_ks):
     the request seed and the step index); returns ``(tokens (S,) int32,
     new_keys (S, 2))``.
 
+    A dispatch pays only for what its rows ask, decided in the program
+    from ``temps`` and ``top_ks``: the key split and the argmax always
+    run; the draw (a Gumbel for each of ``S x V`` logits) only where a
+    row samples; the top-k threshold (a sort of every row) only where
+    a sampling row's ``top_k`` cuts the vocabulary.  A branch not taken
+    computed what the final ``where`` threw away (greedy rows), or a
+    mask at the row's minimum, which masks nothing (full-vocabulary
+    rows): tokens and keys are bit-equal to running all of it always
+    (``tests/test_sampler.py`` holds them to that twin).
+
     PURE and shared: the SAME body traces into the ``decode_sample``
     program (in-graph sampling, ``MXNET_SERVE_SAMPLE=graph``) and jits
     standalone over host-fetched logits for the ``host`` escape hatch —
@@ -153,13 +163,23 @@ def sample_tokens(logits, keys, temps, top_ks):
     pairs = jax.vmap(jax.random.split)(keys)        # (S, 2, 2)
     carry, use = pairs[:, 0], pairs[:, 1]
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    z = logits / jnp.maximum(temps, 1e-6)[:, None]
-    k = jnp.clip(jnp.where(top_ks <= 0, n_vocab, top_ks), 1, n_vocab)
-    kth = jnp.take_along_axis(-jnp.sort(-z, axis=-1),
-                              (k - 1)[:, None], axis=-1)
-    z = jnp.where(z >= kth, z, -jnp.inf)
-    sampled = jax.vmap(jax.random.categorical)(use, z).astype(jnp.int32)
-    return jnp.where(temps <= 0.0, greedy, sampled), carry
+    greedy_row = temps <= 0.0
+
+    def threshold(z):
+        k = jnp.clip(jnp.where(top_ks <= 0, n_vocab, top_ks), 1, n_vocab)
+        kth = jnp.take_along_axis(-jnp.sort(-z, axis=-1),
+                                  (k - 1)[:, None], axis=-1)
+        return jnp.where(z >= kth, z, -jnp.inf)
+
+    def draw():
+        z = logits / jnp.maximum(temps, 1e-6)[:, None]
+        cuts = ~greedy_row & (top_ks > 0) & (top_ks < n_vocab)
+        z = jax.lax.cond(jnp.any(cuts), threshold, lambda z: z, z)
+        sampled = jax.vmap(jax.random.categorical)(use, z)
+        return jnp.where(greedy_row, greedy, sampled.astype(jnp.int32))
+
+    toks = jax.lax.cond(jnp.all(greedy_row), lambda: greedy, draw)
+    return toks, carry
 
 
 def sample_chunk_rows(logits, keys, temps, top_ks, do_sample, slots):

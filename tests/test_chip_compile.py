@@ -469,6 +469,46 @@ def test_lfm2_cell_programs_fit_the_chip(chip, compiled_mode, kind):
     assert all(tile in ln and "tpu_custom_call" in ln for ln in attn)
 
 
+def test_sampler_sorts_and_draws_only_in_a_conditional(chip):
+    """The sampler at ``lfm2-24b-a2b``'s decode dispatch, 128 rows of
+    65,536 logits, compiled for the described v5e: the vocabulary sort
+    is a branch computation of a ``conditional`` (of two, nested), and
+    ``ENTRY`` produces nothing of the logits' shape: no sort, no random
+    bits, no Gumbel, so a greedy dispatch runs an argmax and a key
+    split."""
+    import re
+    from mxnet_tpu.serving.program_store import sample_tokens
+
+    rows, vocab = 128, 65536
+    text = jax.jit(sample_tokens).lower(
+        chip((rows, vocab)), chip((rows, 2), jnp.uint32), chip((rows,)),
+        chip((rows,), I32)).compile().as_text()
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            bodies[name] = []
+        elif name is not None:
+            bodies[name].append(line)
+    branches = set(re.findall(
+        r"%([^\s,}]+)", " ".join(re.findall(
+            r"branch_computations=\{([^}]*)\}", text))))
+    sorts = [n for n, body in bodies.items()
+             if any(re.search(r" sort\(", ln) for ln in body)]
+    assert sorts and set(sorts) <= branches, (sorts, branches)
+    assert sum(" conditional(" in ln for ln in bodies["ENTRY"]) == 1
+    assert sum(" conditional(" in ln for body in bodies.values()
+               for ln in body) == 2
+    inst = re.compile(r"\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) ([a-z-]+)\(")
+    wide = [ln.strip()[:120] for ln in bodies["ENTRY"]
+            for hit in [inst.match(ln)]
+            if hit and "[%d,%d]" % (rows, vocab) in hit.group(1)
+            and hit.group(2) not in ("parameter", "copy-start",
+                                     "copy-done", "tuple")]
+    assert not wide, "\n".join(wide)
+
+
 def test_warmup_compiles_the_two_programs_a_burst_dispatches():
     """``warmup()`` compiles exactly the decode program and the
     compacted chunk program of each slot bucket (no slot-wide chunk

@@ -617,3 +617,57 @@ def test_chunk_dispatch_runs_over_the_slots_in_their_prompt(
     assert span["counts"]["width"] == stats["prefill_row_slots"]
     assert span["counts"]["deferred"] == deferred
     assert span["counts"]["rows"] == stats["prefill_chunks"]
+
+
+def test_sampler_counters_follow_what_the_rows_ask(paged_registry):
+    """``sample_draw_dispatches`` / ``sample_topk_dispatches`` count the
+    paged dispatches for which the in-graph sampler's two ``cond``s
+    take their costly branch: none for greedy requests (whatever their
+    ``top_k``), every dispatch of a request that samples, and the sort
+    only where its ``top_k`` cuts the vocabulary.  The spans carry the
+    same flags."""
+    from mxnet_tpu import profiler
+    vocab = SPEC["vocab_size"]
+    eng = GenerationEngine(paged_registry)
+    opened = profiler.phase_totals()
+
+    def run(**kw):
+        before = eng.stats()
+        eng.submit("m", tokens=[3, 1, 4, 1, 5, 9, 2, 6, 5, 3],
+                   max_tokens=5, **kw).result(180)
+        after = eng.stats()
+        delta = {k: after[k] - before[k]
+                 for k in ("sample_draw_dispatches",
+                           "sample_topk_dispatches", "decode_steps",
+                           "prefills")}
+        return (delta["sample_draw_dispatches"],
+                delta["sample_topk_dispatches"],
+                delta["decode_steps"] + delta["prefills"])
+
+    try:
+        futs = [eng.submit("m", tokens=[7, i, 2], max_tokens=4, top_k=k)
+                for i, k in enumerate((0, 5, vocab))]
+        for f in futs:
+            f.result(180)
+        stats = eng.stats()
+        assert stats["decode_steps"] > 0 and stats["prefills"] > 0
+        assert stats["sample_draw_dispatches"] == 0
+        assert stats["sample_topk_dispatches"] == 0
+
+        draws, sorts, dispatches = run(temperature=0.8, top_k=0, seed=1)
+        assert draws == dispatches > 0 and sorts == 0
+        draws, sorts, dispatches = run(temperature=0.8, top_k=vocab,
+                                       seed=2)
+        assert draws == dispatches > 0 and sorts == 0
+        draws, sorts, dispatches = run(temperature=0.8, top_k=5, seed=3)
+        assert draws == sorts == dispatches > 0
+        # the slot's row is greedy again once the request has left it
+        assert run() == (0, 0, dispatches)
+        stats = eng.stats()
+    finally:
+        eng.close()
+    spans = profiler.phase_totals(since=opened)
+    for flag in ("sample_draw", "sample_topk"):
+        assert sum(spans[name]["counts"][flag]
+                   for name in ("serve_decode", "serve_prefill")) \
+            == stats[flag + "_dispatches"]
