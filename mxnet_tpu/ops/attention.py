@@ -81,7 +81,8 @@ def sdp_attention(query, key, value, causal=False, scale=0.0,
 
 
 def sdp_attention_paged(query, k_pool, v_pool, layer, tables, positions,
-                        block_size, scale=0.0, kv_scales=None, group=1):
+                        block_size, scale=0.0, kv_scales=None, group=1,
+                        window=None, name="paged_attention", block_q=None):
     """Paged scaled-dot-product attention: [B, H, Lq, D] queries whose
     row r of sequence b sits at global position ``positions[b] + r``,
     attending over layer ``layer`` (a static int) of the whole stacked
@@ -103,6 +104,15 @@ def sdp_attention_paged(query, k_pool, v_pool, layer, tables, positions,
     half of the result taken by the caller: ``models/lfm2_moe.py``);
     ``group`` table entries make one grid step of the kernel.
 
+    ``window`` (static; None: the causal frontier alone): the query at
+    ``p`` sees keys ``p - window + 1 .. p``, in both lowerings; the
+    kernel walks only the blocks such a query can reach, under
+    ``name`` in a trace (``models/cohere2_moe.py``'s window layers).
+    ``block_q`` bounds the kernel's Q tile (default: the configured
+    sequence block): a model whose chunk puts many query heads on a KV
+    head asks for a taller one, since every Q tile of a KV head fetches
+    the sequence's keys and values again.
+
     Eligible shapes route to ``flash_attention_paged`` (scalar-prefetch
     block tables, dynamic block skip, forward-only); everything else —
     and ``MXNET_PALLAS=0`` — lowers to ``paged_attention_reference``,
@@ -120,13 +130,13 @@ def sdp_attention_paged(query, k_pool, v_pool, layer, tables, positions,
         from ..pallas_ops.paged_attention import flash_attention_paged
         return flash_attention_paged(
             query, k_pool, v_pool, layer, tables, positions, bs,
-            scale=scale, block_q=_pd.block_seq(),
+            scale=scale, block_q=block_q or _pd.block_seq(),
             interpret=_pd.interpret_mode(), kv_scales=kv_scales,
-            group=group)
+            group=group, window=window, name=name)
     from ..pallas_ops.paged_attention import paged_attention_reference
     return paged_attention_reference(query, k_pool, v_pool, layer, tables,
                                      positions, bs, scale=scale,
-                                     kv_scales=kv_scales)
+                                     kv_scales=kv_scales, window=window)
 
 
 def mla_attention_paged(query, pool, layer, tables, positions,

@@ -47,7 +47,7 @@ _NEG = -1e30  # flash_attention._NEG: shared mask constant for parity
 
 
 def _paged_kernel(*refs, scale, block_q, block_size, nk, int8, lq,
-                  heads, group, own_v):
+                  heads, group, own_v, window, nt):
     """One (batch, pool head, q-block, group of logical blocks) grid
     cell.
 
@@ -62,7 +62,15 @@ def _paged_kernel(*refs, scale, block_q, block_size, nk, int8, lq,
     ``int8`` adds two more scalar-prefetch operands — per-(head,
     physical block) fp32 absmax scales for the K and V pools — and the
     tile loads dequantize on-tile (``codes * sk_ref[h, tbl_ref[b, j]]``)
-    before the unchanged fp32 online softmax."""
+    before the unchanged fp32 online softmax.
+
+    ``window`` (static; None: every key at or before the query): a
+    query at ``p`` sees keys ``p - window + 1 .. p``.  The k dimension
+    then walks only the groups a dispatch row's queries can see, from
+    group :func:`_first_group` of its frontier on (the index maps took
+    the same offset), and masks the keys behind the window inside
+    them; ``nt`` is the table's width, which the walk may overshoot by
+    a group whose keys are all past the frontier."""
     if int8:
         tbl_ref, pos_ref, sk_ref, sv_ref, q_ref = refs[:5]
     else:
@@ -78,6 +86,8 @@ def _paged_kernel(*refs, scale, block_q, block_size, nk, int8, lq,
     ki = pl.program_id(3)
     ofs = pos_ref[b]
     span = group * block_size
+    # the logical group this grid step holds
+    kg = ki if window is None else _first_group(ofs, window, span) + ki
     # multiply in the tiles' own dtype where query and pool share it
     # (bfloat16 both: one MXU pass), else in fp32 as the dense twin does
     cdt = q_ref.dtype if not int8 and q_ref.dtype == k_refs[0].dtype \
@@ -90,7 +100,7 @@ def _paged_kernel(*refs, scale, block_q, block_size, nk, int8, lq,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     # dynamic skip: the group contributes iff the tile's last query row
-    # can see its first key position (ki * span).  A tile inside one
+    # can see its first key position (kg * span).  A tile inside one
     # head ends at query qi*block_q % lq + block_q - 1; one that spans
     # heads holds the chunk's last query, lq - 1
     if heads == 1:
@@ -99,7 +109,7 @@ def _paged_kernel(*refs, scale, block_q, block_size, nk, int8, lq,
         last = jax.lax.rem(qi * block_q, lq) + block_q - 1
     else:
         last = lq - 1
-    run = ofs + last >= ki * span
+    run = ofs + last >= kg * span
 
     @pl.when(run)
     def _step():
@@ -110,7 +120,8 @@ def _paged_kernel(*refs, scale, block_q, block_size, nk, int8, lq,
             for g, ref in enumerate(refs_):
                 t = ref[0, 0].astype(cdt)       # (BS, D)
                 if int8:                        # SMEM scalar reads
-                    t = t * s_ref[h, tbl_ref[b, ki * group + g]]
+                    t = t * s_ref[h, tbl_ref[b, _entry(kg, group, g,
+                                                       window, nt)]]
                 out.append(t)
             return out[0] if group == 1 else jnp.concatenate(out, axis=0)
 
@@ -122,14 +133,17 @@ def _paged_kernel(*refs, scale, block_q, block_size, nk, int8, lq,
         row = qi * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, span), 0)
         qpos = ofs + (row if heads == 1 else jax.lax.rem(row, lq))
-        kpos = ki * span + jax.lax.broadcasted_iota(
+        kpos = kg * span + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, span), 1)
-        s = jnp.where(qpos >= kpos, s, _NEG)
+        seen = qpos >= kpos
+        if window is not None:
+            seen &= kpos > qpos - window
+        s = jnp.where(seen, s, _NEG)
         m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev,
                             jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
-        p = jnp.where(qpos >= kpos, p, 0.0)
+        p = jnp.where(seen, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         m_ref[:] = m_new
         l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
@@ -143,9 +157,24 @@ def _paged_kernel(*refs, scale, block_q, block_size, nk, int8, lq,
                        jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
 
 
+def _first_group(ofs, window, span):
+    """The first group of ``span`` keys that a dispatch row whose first
+    query sits at ``ofs`` can see through ``window``."""
+    return jnp.maximum(ofs - (window - 1), 0) // span
+
+
+def _entry(kg, group, g, window, nt):
+    """The table entry of block ``g`` of logical group ``kg``; a walk
+    that starts at the window may run past the table's last entry, to
+    keys no query sees."""
+    j = kg * group + g
+    return j if window is None else jnp.minimum(j, nt - 1)
+
+
 def flash_attention_paged(q, k_pool, v_pool, layer, tables, positions,
                           block_size, scale=None, block_q=128,
-                          interpret=None, kv_scales=None, group=1):
+                          interpret=None, kv_scales=None, group=1,
+                          window=None, name="paged_attention"):
     """Offset-causal flash attention against a PAGED KV pool.
 
     q: (B, H, Lq, D) — query row r of sequence b sits at global
@@ -178,6 +207,18 @@ def flash_attention_paged(q, k_pool, v_pool, layer, tables, positions,
     the unchanged fp32 online softmax, so accumulation numerics match
     the dense twin exactly on identically-dequantized values.
 
+    ``window`` (a static int; None is the causal frontier alone): the
+    query at ``p`` sees keys ``p - window + 1 .. p``, masked in logical
+    position space.  Blocks wholly behind the window are skipped as
+    blocks past the frontier are, and more cheaply: the grid's k
+    dimension has only the ``(window + Lq - 2) // (group * block_size)
+    + 2`` groups a row's queries can reach, and starts at the group
+    that holds the first query's oldest visible key (read from the
+    row's frontier in the index maps), so table entries behind it are
+    never dereferenced and may point anywhere (the engine leaves them
+    at the trash block 0 once it has released them).  ``name`` is the
+    call's name in a trace: a model's window layers give their own.
+
     The tables/positions ride as scalar-prefetch operands so BlockSpec
     index maps can gather physical tiles; blocks a sequence cannot see
     are skipped dynamically like ``flash_attention_offset``.
@@ -200,6 +241,10 @@ def flash_attention_paged(q, k_pool, v_pool, layer, tables, positions,
     block_q = divisor_block(rows, block_q)
     group = divisor_block(T, group)
     nk = T // group
+    if window is not None:
+        window = int(window)
+        assert window >= 1
+        nk = min(nk, (window + Lq - 2) // (group * bs) + 2)
     tbl = jnp.asarray(tables, jnp.int32)
     pos = jnp.asarray(positions, jnp.int32).reshape(B)
     int8 = kv_scales is not None
@@ -207,7 +252,8 @@ def flash_attention_paged(q, k_pool, v_pool, layer, tables, positions,
     kernel = functools.partial(_paged_kernel, scale=float(scale),
                                block_q=block_q, block_size=bs, nk=nk,
                                int8=int8, lq=Lq, heads=heads,
-                               group=group, own_v=own_v)
+                               group=group, own_v=own_v, window=window,
+                               nt=T)
 
     if int8:
         sk = jnp.asarray(kv_scales[0][layer], jnp.float32)
@@ -220,8 +266,13 @@ def flash_attention_paged(q, k_pool, v_pool, layer, tables, positions,
     q_map = lambda b, h, i, j, *_: (b, h, i, 0)
 
     def kv_map(g):
-        return lambda b, h, i, j, tbl, *_: (layer, h,
-                                            tbl[b, j * group + g], 0)
+        if window is None:
+            return lambda b, h, i, j, tbl, *_: (
+                layer, h, tbl[b, j * group + g], 0)
+        return lambda b, h, i, j, tbl, pos, *_: (
+            layer, h, tbl[b, _entry(
+                _first_group(pos[b], window, group * bs) + j, group, g,
+                window, T)], 0)
 
     # k/v: fetch PHYSICAL block tbl[b, j * group + g] of this layer
     # from the stacked pool — the index is in units of whole (bs, D)
@@ -240,7 +291,7 @@ def flash_attention_paged(q, k_pool, v_pool, layer, tables, positions,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hp, rows, D), q.dtype),
         interpret=_resolve_interpret(interpret),
-        name="paged_attention",
+        name=name,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")))(
@@ -251,15 +302,16 @@ def flash_attention_paged(q, k_pool, v_pool, layer, tables, positions,
 
 def paged_attention_reference(q, k_pool, v_pool, layer, tables,
                               positions, block_size, scale=None,
-                              kv_scales=None):
+                              kv_scales=None, window=None):
     """Dense XLA twin of :func:`flash_attention_paged`: gather layer
     ``layer``'s pool rows through the same block-table arithmetic, then
     the exact dense offset-causal attention (same ``-1e30`` constant,
     fp32 accumulation) — the ``MXNET_PALLAS=0`` lowering and the parity
     oracle.  ``kv_scales`` dequantizes int8 pools through the SAME
     per-(layer, head, physical block) scale arithmetic as the kernel;
-    fewer pool heads than query heads are repeated for their group, and
-    ``v_pool=None`` reads a row as key and value alike."""
+    fewer pool heads than query heads are repeated for their group,
+    ``v_pool=None`` reads a row as key and value alike, and ``window``
+    masks the keys at or before ``query position - window``."""
     B, H, Lq, D = q.shape
     T = tables.shape[1]
     bs = int(block_size)
@@ -294,7 +346,10 @@ def paged_attention_reference(q, k_pool, v_pool, layer, tables,
     qpos = jax.lax.broadcasted_iota(jnp.int32, (Lq, T * bs), 0)
     kpos = jax.lax.broadcasted_iota(jnp.int32, (Lq, T * bs), 1)
     qglob = pos[:, None, None] + qpos
-    s = jnp.where((qglob >= kpos[None])[:, None], s, _NEG)
+    seen = qglob >= kpos[None]
+    if window is not None:
+        seen &= kpos[None] > qglob - int(window)
+    s = jnp.where(seen[:, None], s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p,
                       v.astype(jnp.float32)).astype(q.dtype)

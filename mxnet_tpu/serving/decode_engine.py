@@ -281,6 +281,10 @@ class _ModelState:
 class _BlockPool:
     """Host-side allocator over the paged KV pool's physical blocks.
 
+    A model whose pool has several CLASSES of block (``models/cohere2_
+    moe.py``: the full layers' leaves, the window layers' leaves) has
+    one allocator a class, all of them in the one cache manager
+    (:class:`_PagedModelState`).
     Block 0 is the reserved trash block (zero table entries point at
     it; non-participating dispatch rows reach no other block) and is
     never allocated.  Every allocated block carries a refcount: a sequence
@@ -292,6 +296,14 @@ class _BlockPool:
         self._free = list(range(self.num_blocks - 1, 0, -1))
         self._ref = {}
         self._hwm = 0
+        # kept as references come and go, so that no tick walks the
+        # pool to count them: the blocks the prefix cache pins, how
+        # many of those nobody else holds (a pin that would FREE its
+        # block: what eviction can reclaim), and the blocks with more
+        # than one reference
+        self._pinned = set()
+        self._pinned_once = 0
+        self._shared = 0
         # the engine thread mutates the allocator (admission /
         # retirement); stats() -> describe() reads it from client
         # threads.  The lock makes those reads coherent; the coarse
@@ -325,6 +337,21 @@ class _BlockPool:
             _ = self._rc.rev
             return self._ref.get(b, 0)
 
+    def held_once(self, blocks):
+        """How many of ``blocks`` have ONE reference (under one lock:
+        a hit of a hundred blocks is an admission's, with the device
+        idle meanwhile)."""
+        with self._lock:
+            _ = self._rc.rev
+            ref = self._ref
+            return sum(1 for b in blocks if ref.get(b, 0) == 1)
+
+    def pinned_once(self):
+        """Pinned blocks that only their pin holds."""
+        with self._lock:
+            _ = self._rc.rev
+            return self._pinned_once
+
     def alloc(self):
         """One fresh block at refcount 1, or None when exhausted."""
         with self._lock:
@@ -338,15 +365,29 @@ class _BlockPool:
                 self._hwm = used
             return b
 
-    def ref(self, b):
+    def ref(self, b, pin=False):
+        """One more reference on ``b``; ``pin``: the prefix cache's (a
+        block has at most one)."""
         with self._lock:
             self._rc.rev += 1
-            self._ref[b] += 1
+            was = self._ref[b]
+            self._ref[b] = was + 1
+            self._shared += was == 1
+            self._pinned_once -= was == 1 and b in self._pinned
+            if pin:
+                self._pinned.add(b)
 
-    def deref(self, b):
+    def deref(self, b, pin=False):
+        """Drop a reference on ``b`` (``pin``: the prefix cache's);
+        the block frees with its last."""
         with self._lock:
             self._rc.rev += 1
             r = self._ref[b] - 1
+            self._shared -= r == 1
+            self._pinned_once -= r == 0 and b in self._pinned
+            if pin:
+                self._pinned.discard(b)
+            self._pinned_once += r == 1 and b in self._pinned
             if r <= 0:
                 del self._ref[b]
                 self._free.append(b)
@@ -358,92 +399,170 @@ class _BlockPool:
         """Blocks currently referenced more than once."""
         with self._lock:
             _ = self._rc.rev
-            return sum(1 for r in self._ref.values() if r > 1)
+            return self._shared
 
 
 class _PrefixStore:
     """Copy-on-write prefix cache: exact prompt prefixes -> pinned
-    pool blocks.
+    pool blocks, a block a class of the pool.
 
-    Keys are the token tuples themselves (no hash collisions): a full
-    block j of a completed prefill registers under
-    ``tuple(prompt[:(j+1)*bs])``; a partial tail block under the WHOLE
-    prompt tuple.  Each entry pins one refcount on its block, so
-    shared prefixes survive their registering sequence's retirement.
-    Matching walks full blocks longest-prefix-first and takes the
-    tail only on an exact whole-prompt match — N requests with the
-    same system prompt pay its prefill once.  Entries whose pin is
-    the LAST reference are evictable (LRU) when the pool runs dry."""
+    An entry stands for one block of a prompt and is keyed by (the
+    entry of the block before it, this block's own tokens): the chain
+    from the root IS the token prefix, so a match is exact (no hash
+    collisions) and costs the prompt's length once, not once a block.
+    Whole blocks are registered as a prefill fills them, a partial
+    tail block at its end.  An entry pins one refcount on its block in
+    EACH class, so shared prefixes survive their registering
+    sequence's retirement — and, in a class with a window, survive the
+    sequence's own release of the block as it falls behind
+    (:meth:`GenerationEngine._release_behind`).  Matching walks whole
+    blocks and takes the tail only on an exact whole-prompt match — N
+    requests with the same system prompt pay its prefill once.
 
-    def __init__(self, pool, block_size):
-        self._pool = pool
+    Pins whose block would free are evictable, least recently used
+    first, a class at a time (:meth:`evict_one`): in a class whose
+    sequences keep every block the entry goes with its pin (a prefix
+    is of no use without its first blocks, and what hangs below it can
+    no longer be reached and is evicted in its turn); in a class with a
+    window only that class's pin goes and the entry stays.  So a hit
+    may find the chain whole but a window block gone: it is then CUT
+    to the longest prefix whose last ``window`` keys are all still
+    pinned (:meth:`match`), or refused."""
+
+    def __init__(self, pools, block_size, windows=(None,)):
+        self._pools = list(pools)
         self._bs = int(block_size)
-        self._entries = collections.OrderedDict()  # tokens -> (blk, n)
+        self._windows = tuple(windows)
+        # (parent entry's id, the block's tokens) -> [id, key, [block
+        # a class, 0: not pinned]]
+        self._entries = {}
+        # a class: id -> entry, the entries with a pin in the class,
+        # least recently used first
+        self._lru = [collections.OrderedDict() for _ in self._pools]
+        self._next_id = 1
 
     def __len__(self):
         return len(self._entries)
 
+    def _touch(self, entries):
+        """Mark ``entries`` (a chain, root first) used: the deepest
+        first, so that eviction takes a chain from its end."""
+        for e in reversed(entries):
+            for lru in self._lru:
+                if e[0] in lru:
+                    lru.move_to_end(e[0])
+
+    def first_needed(self, c, pos):
+        """The first logical block of class ``c`` a query at ``pos``
+        still sees."""
+        w = self._windows[c]
+        return 0 if w is None else max(0, (pos - w + 1) // self._bs)
+
     def match(self, prompt):
-        """Longest shared prefix of ``prompt``: ``(full_blocks, tail)``
-        — physical block ids for whole shared blocks, plus the tail
-        block on an exact whole-prompt match (else None).  Touches the
-        matched entries' LRU position; refcounts are NOT taken (the
-        caller refs what it actually adopts)."""
+        """Longest usable shared prefix of ``prompt``: ``(chain, tail,
+        cut)`` — the entries of the whole shared blocks, root first,
+        plus the tail block's entry on an exact whole-prompt match
+        (else None); an entry's ``[2]`` holds its physical block a
+        class.  In a class with a window the adopter needs the blocks
+        from :meth:`first_needed` of where it resumes on, no more;
+        where one of those is no longer pinned the hit is shortened
+        until they are (``cut`` True; the chain may come back empty).
+        Touches the walked entries' LRU position; refcounts are NOT
+        taken (the caller refs what it actually adopts)."""
         bs = self._bs
-        blocks = []
-        j = 0
-        while (j + 1) * bs <= len(prompt):
-            key = tuple(prompt[:(j + 1) * bs])
-            e = self._entries.get(key)
-            if e is None or e[1] != bs:
+        chain, pid = [], 0
+        while (len(chain) + 1) * bs <= len(prompt):
+            j = len(chain)
+            e = self._entries.get((pid, tuple(prompt[j * bs:(j + 1) * bs])))
+            if e is None:
                 break
-            self._entries.move_to_end(key)
-            blocks.append(e[0])
-            j += 1
+            chain.append(e)
+            pid = e[0]
         tail = None
-        nt = len(prompt) % bs
-        if nt and j == len(prompt) // bs:
-            e = self._entries.get(tuple(prompt))
-            if e is not None and e[1] == nt:
-                self._entries.move_to_end(tuple(prompt))
-                tail = e[0]
-        return blocks, tail
-
-    def register(self, prompt, table_row):
-        """Pin a completed prefill's blocks for future sharing (+1
-        refcount per NEW entry; prefixes already registered — possibly
-        against different physical blocks — are left alone)."""
-        bs = self._bs
-        for j in range(len(prompt) // bs):
-            key = tuple(prompt[:(j + 1) * bs])
-            if key in self._entries:
+        if len(prompt) % bs and len(chain) == len(prompt) // bs:
+            tail = self._entries.get(
+                (pid, tuple(prompt[len(chain) * bs:])))
+        self._touch(chain + ([tail] if tail else []))
+        found = (len(chain), tail)
+        for c, w in enumerate(self._windows):
+            if w is None:
                 continue
-            b = int(table_row[j])
-            self._pool.ref(b)
-            self._entries[key] = (b, bs)
-        nt = len(prompt) % bs
-        if nt:
-            key = tuple(prompt)
-            if key not in self._entries:
-                b = int(table_row[len(prompt) // bs])
-                self._pool.ref(b)
-                self._entries[key] = (b, nt)
+            # run[i]: whole entries up to i in a row with a pin here
+            run, n = [], 0
+            for e in chain:
+                n = n + 1 if e[2][c] else 0
+                run.append(n)
+            if tail is not None and not (
+                    tail[2][c] and len(chain) - self.first_needed(
+                        c, len(prompt) - 1) <= n):
+                tail = None
+            if tail is None:
+                j = len(chain)
+                while j and run[j - 1] < j - self.first_needed(
+                        c, min(j * bs, len(prompt) - 1)):
+                    j -= 1
+                chain = chain[:j]
+        return chain, tail, (len(chain), tail) != found
 
-    def evictable(self):
-        """Pins whose block would FREE on eviction (refcount 1)."""
-        return sum(1 for b, _n in self._entries.values()
-                   if self._pool.refcount(b) == 1)
+    def _pin(self, e, blocks):
+        """Pin ``blocks`` (a block a class, 0: none) under entry ``e``
+        in the classes where it has no pin."""
+        for c, b in enumerate(blocks):
+            if b and not e[2][c]:
+                self._pools[c].ref(b, pin=True)
+                e[2][c] = b
+                self._lru[c][e[0]] = e
 
-    def evict_one(self):
-        """Drop the least-recently-used pin whose block frees (blocks
-        still held by live sequences stay).  True when a block was
-        reclaimed."""
-        for key, (b, _n) in self._entries.items():
-            if self._pool.refcount(b) == 1:
-                del self._entries[key]
-                self._pool.deref(b)
-                return True
-        return False
+    def register(self, parent, tokens, blocks):
+        """Pin one block of a prefill for future sharing under the
+        entry ``parent`` (an id; 0: the prompt's first block):
+        ``tokens`` the block's own (fewer than a block's: the prompt's
+        tail), ``blocks`` its physical block in each class (+1
+        refcount a class a NEW pin; a prefix already registered —
+        possibly against other physical blocks — keeps them, and gets
+        back the pins of a class with a window that eviction took).
+        Returns the entry's id, the parent of the block after."""
+        key = (parent, tuple(tokens))
+        e = self._entries.get(key)
+        if e is None:
+            e = self._entries[key] = [self._next_id, key,
+                                      [0] * len(self._pools)]
+            self._next_id += 1
+        self._pin(e, blocks)
+        return e[0]
+
+    def evictable(self, cls=0):
+        """Pins of class ``cls`` whose block would FREE on eviction
+        (refcount 1)."""
+        return self._pools[cls].pinned_once()
+
+    def evict_one(self, cls=0):
+        """Drop the least-recently-used pin of class ``cls`` whose
+        block frees; pins on blocks that live sequences still hold are
+        passed over and count as used now, so that the next walk does
+        not meet them again.  True when a block was reclaimed."""
+        pool, lru = self._pools[cls], self._lru[cls]
+        held, found = [], None
+        for e in lru.values():
+            if pool.refcount(e[2][cls]) == 1:
+                found = e
+                break
+            held.append(e[0])
+        for i in held:
+            lru.move_to_end(i)
+        if found is None:
+            return False
+        # without its block of a class that keeps every block the
+        # entry is of no use: all its pins go
+        for c in (range(len(self._pools))
+                  if self._windows[cls] is None else (cls,)):
+            if found[2][c]:
+                self._pools[c].deref(found[2][c], pin=True)
+                found[2][c] = 0
+                del self._lru[c][found[0]]
+        if not any(found[2]):
+            del self._entries[found[1]]
+        return True
 
 
 class _PagedModelState:
@@ -459,8 +578,18 @@ class _PagedModelState:
 
     def __init__(self, store, draft=None, spec_k=0):
         self.store = store
-        self.pool = _BlockPool(store.pool_blocks)
-        self.prefix = _PrefixStore(self.pool, store.kv_block)
+        # the pool's classes of block, one allocator each: a sequence
+        # has a table a class, side by side in its row of ``tables``
+        # (``tw`` entries each), and gives the blocks of a class with a
+        # WINDOW back as they fall behind it (docs/architecture/
+        # decode_engine.md, "Classes of block")
+        self.windows = tuple(w for w, _ in store.cache_classes)
+        self.pool_of = [_BlockPool(store.pool_blocks)
+                        for _ in self.windows]
+        self.pool = self.pool_of[0]
+        self.prefix = _PrefixStore(self.pool_of, store.kv_block,
+                                   self.windows)
+        self.tw = store.class_width()
         # the pool is an opaque tuple of donated leaves, as the model
         # shapes it: (k, v) for the LM, (latent,) for deepseek_v3
         self.pools = store.new_pool()
@@ -469,6 +598,19 @@ class _PagedModelState:
         self.scales = (store.new_scale_pool() if store.kv_int8
                        else None)
         self.tb = store.table_width()
+        # the narrowest window (the spans' ``kv_tokens_window``), None
+        # where every class keeps every block
+        self.window = min((w for w in self.windows if w is not None),
+                          default=None)
+        # bytes of one block of each class, over the class's leaves
+        # (the int8 plane's scale pools with the one class it has)
+        nb = store.pool_blocks
+        self.block_bytes_of = [
+            sum(self.pools[i].size * self.pools[i].dtype.itemsize
+                for i in leaves) // nb
+            for _, leaves in store.cache_classes]
+        self.block_bytes_of[0] += sum(
+            a.size * a.dtype.itemsize for a in self.scales or ()) // nb
         # rows a block holds in the model's state leaves, and the
         # leaves' bytes (0: every leaf is by token)
         self.state_rows = store.state_rows_per_block()
@@ -484,7 +626,22 @@ class _PagedModelState:
         self.next_tok = np.zeros(0, np.int32)
         self.temps = np.zeros(0, np.float32)
         self.top_ks = np.zeros(0, np.int32)
-        self.resv = np.zeros(0, np.int32)      # reserved-unallocated
+        nc = len(self.windows)
+        # a class: blocks the slot may still take from the pool, the
+        # most it holds at once (a window bounds it), and how many
+        # logical blocks it has given back behind the window
+        self.resv = np.zeros((0, nc), np.int32)
+        self.cap = np.zeros((0, nc), np.int32)
+        self.passed = np.zeros((0, nc), np.int32)
+        # the prompt's whole blocks the prefix cache has from this
+        # slot, and the entry of the last (the parent of the next)
+        self.reg_n = np.zeros(0, np.int32)
+        self.reg_id = np.zeros(0, np.int64)
+        # the logical block the slot's last decode write was made
+        # ready in (-1: none yet): only prompt blocks are ever pinned
+        # or adopted, so that block stays the slot's own and the next
+        # write into it has nothing to allocate or fork
+        self.ready = np.zeros(0, np.int32)
         self.keys = jnp.zeros((0, 2), jnp.uint32)
         self.g_used = None                     # pool gauges (engine)
         self.g_hwm = None
@@ -550,8 +707,32 @@ class _PagedModelState:
                 return i
         return None
 
-    def reserved_total(self):
-        return int(self.resv.sum())
+    def class_rows(self, c):
+        """Class ``c``'s part of every slot's table row (a view)."""
+        return self.tables[:, c * self.tw:(c + 1) * self.tw]
+
+    def reserved(self, c):
+        """Blocks of class ``c`` the admitted slots may still take.
+        Where a window bounds what a slot holds at once, no more than
+        that less what it holds now: it gives a block back for every
+        block it takes from then on."""
+        want = self.resv[:, c]
+        if self.windows[c] is not None:
+            held = np.count_nonzero(self.class_rows(c), axis=1)
+            want = np.minimum(want, np.maximum(self.cap[:, c] - held, 0))
+        return int(want.sum())
+
+    def window_cap(self, c):
+        """The most blocks of class ``c`` a slot holds at once: its
+        window's keys and the rows of the longest dispatch, wherever
+        the block boundaries fall."""
+        rows = max(self.store.prefill_chunk, self.spec_k + 1)
+        return (self.windows[c] + rows - 2) // self.store.kv_block + 2
+
+    def bytes_used(self):
+        """Bytes behind the allocated blocks of every class."""
+        return sum(p.used() * b
+                   for p, b in zip(self.pool_of, self.block_bytes_of))
 
     def describe(self):
         act = self.active()
@@ -561,7 +742,10 @@ class _PagedModelState:
         # discipline applied to the KV plane)
         pool_bytes = sum(a.size * a.dtype.itemsize
                          for a in self.pools + (self.scales or ()))
-        per_block = pool_bytes // self.store.pool_blocks
+        per_class = self.block_bytes_of
+        per_block = sum(per_class)
+        used = [p.used() for p in self.pool_of]
+        bytes_used = self.bytes_used()
         d = {"slots": len(self.slots), "active": len(act),
              "paged": True,
              "sample_mode": self.store.sample_mode,
@@ -571,15 +755,21 @@ class _PagedModelState:
              "pool_blocks_used": self.pool.used(),
              "pool_blocks_hwm": self.pool.hwm,
              "pool_blocks_shared": self.pool.shared(),
-             "pool_blocks_reserved": self.reserved_total(),
+             "pool_blocks_reserved": self.reserved(0),
              "prefix_entries": len(self.prefix),
              "cache_mb": round(pool_bytes / 2**20, 3),
              "pool_bytes": pool_bytes,
-             "pool_bytes_used": self.pool.used() * per_block,
+             "pool_bytes_used": bytes_used,
              "pool_bytes_per_token":
                  per_block / self.store.kv_block,
              "block_bytes": per_block,
              "cache_dtype": str(self.pools[0].dtype)}
+        if len(used) > 1:
+            # a class: allocated blocks (live sequences' and the prefix
+            # cache's pins), a block's bytes, the window
+            d["pool_blocks_live"] = used
+            d["class_block_bytes"] = list(per_class)
+            d["class_windows"] = list(self.windows)
         if self.state_rows:
             # what the model keeps per sequence lies one row a block in
             # the pool's state leaves (models/paged.py): rows held by
@@ -599,8 +789,7 @@ class _PagedModelState:
             # shared prefix blocks are paid once, so prefix-heavy
             # schedules drive this far under the contiguous plane's
             # cache_bytes_per_slot
-            d["cache_bytes_per_active_seq"] = \
-                (self.pool.used() * per_block) // len(act)
+            d["cache_bytes_per_active_seq"] = bytes_used // len(act)
         return d
 
 
@@ -683,6 +872,19 @@ class GenerationEngine:
              # prompt_tokens_admitted is what prefix_hit_tokens is a
              # share of
              "state_restores", "prompt_tokens_admitted",
+             # a pool with classes of block (zero for a model of one
+             # class): window_blocks_released counts the references
+             # sequences dropped to blocks that fell behind their
+             # window, prefix_hits_cut the hits shortened or refused
+             # because a window's blocks were no longer all pinned,
+             # prefix_evictions the pins allocation took back (every
+             # model); cache_bytes_live and cache_bytes_one_table sum,
+             # a tick, the bytes the live sequences' tables hold in
+             # every class and what one table for all layers would
+             # hold for the same sequences
+             "window_blocks_released", "prefix_hits_cut",
+             "prefix_evictions", "cache_bytes_live",
+             "cache_bytes_one_table",
              # speculative decoding (zero without a draft attached):
              # spec_steps counts verify dispatches (each is ONE target
              # step emitting 1..K+1 tokens), spec_proposed/spec_
@@ -1269,21 +1471,65 @@ class GenerationEngine:
     def _paged_gauges(self, st):
         st.g_used.set(st.pool.used())
         st.g_hwm.set(st.pool.hwm)
-        st.g_bytes.set(st.pool.used() * st.describe()["block_bytes"])
+        st.g_bytes.set(st.bytes_used())
 
-    def _paged_alloc(self, st):
-        """One fresh pool block, evicting LRU prefix pins if the free
-        list is dry.  Exhaustion raises — admission reservations exist
-        to make that unreachable."""
-        b = st.pool.alloc()
-        while b is None and st.prefix.evict_one():
-            b = st.pool.alloc()
+    def _paged_alloc(self, st, c=0):
+        """One fresh pool block of class ``c``, evicting LRU prefix
+        pins if the free list is dry.  Exhaustion raises — admission
+        reservations exist to make that unreachable."""
+        pool = st.pool_of[c]
+        b = pool.alloc()
+        while b is None and st.prefix.evict_one(c):
+            self._stats.inc("prefix_evictions")
+            b = pool.alloc()
         if b is None:
             raise MXNetError(
                 "paged KV pool exhausted (%d blocks) — admission "
                 "reservations should have prevented this"
-                % st.pool.capacity())
+                % pool.capacity())
         return b
+
+    def _release_behind(self, st, i):
+        """Give back slot i's blocks that lie wholly behind its window,
+        in the classes that have one: a DEREFERENCE — a block the
+        prefix cache pins stays until the cache evicts it.  The next
+        query sits at ``lengths[i]``; the table entries go to the trash
+        block 0, which the window's kernel never reads."""
+        for c, w in enumerate(st.windows):
+            if w is None:
+                continue
+            first = st.prefix.first_needed(c, int(st.lengths[i]))
+            if first <= st.passed[i, c]:
+                continue
+            row = st.class_rows(c)[i]
+            for j in range(int(st.passed[i, c]), first):
+                if row[j]:
+                    st.pool_of[c].deref(int(row[j]))
+                    row[j] = 0
+                    self._stats.inc("window_blocks_released")
+            st.passed[i, c] = first
+
+    def _register_filled(self, st, i):
+        """Pin the whole prompt blocks slot i has filled since its last
+        registration (and, at the prompt's end, its partial tail) in
+        the prefix cache: as they fill, so that a class with a window
+        is pinned before :meth:`_release_behind` lets the block go."""
+        r = st.slots[i]
+        bs = st.store.kv_block
+        n, pid = int(st.reg_n[i]), int(st.reg_id[i])
+        done = int(st.prog[i])
+        while (n + 1) * bs <= done:
+            pid = st.prefix.register(
+                pid, r.prompt[n * bs:(n + 1) * bs],
+                [int(st.class_rows(c)[i, n])
+                 for c in range(len(st.windows))])
+            n += 1
+        if done == len(r.prompt) and n * bs < done:
+            st.prefix.register(
+                pid, r.prompt[n * bs:],
+                [int(st.class_rows(c)[i, n])
+                 for c in range(len(st.windows))])
+        st.reg_n[i], st.reg_id[i] = n, pid
 
     def _admit_paged(self, model, dq, store):
         """Paged admission: no prefill dispatch here — a slot is
@@ -1312,7 +1558,7 @@ class GenerationEngine:
                 if len(st.active()) >= cap:
                     break
                 total_blocks = -(-(len(r.prompt) + r.max_tokens) // bs)
-                blocks, tail = st.prefix.match(r.prompt)
+                blocks, tail, cut = st.prefix.match(r.prompt)
                 if st.state_rows:
                     # a state leaf holds the state after a block's LAST
                     # token: a hit restores it at a block boundary, and
@@ -1327,7 +1573,24 @@ class GenerationEngine:
                 # in total_blocks (the borrowed block is free).
                 fork_extra = int(len(r.prompt) % bs != 0 and tail is None)
                 needed = total_blocks - len(blocks) + fork_extra
-                if total_blocks + fork_extra > st.pool.capacity():
+                # shared tokens skip recomputation, but the LAST prompt
+                # token always reruns: its logits seed the first sample
+                covered = len(r.prompt) if tail is not None \
+                    else len(blocks) * bs
+                prog = min(covered, len(r.prompt) - 1)
+                # a class: the most blocks the slot holds at once (a
+                # window's keys and a dispatch's rows, and one more
+                # while a shared tail block forks), and of the hit the
+                # entries it adopts: all of them, or with a window
+                # those a query at ``prog`` still sees
+                caps, firsts = [], []
+                for c, w in enumerate(st.windows):
+                    caps.append(total_blocks + fork_extra if w is None
+                                else min(total_blocks + fork_extra,
+                                         st.window_cap(c)
+                                         + int(len(r.prompt) % bs != 0)))
+                    firsts.append(st.prefix.first_needed(c, prog))
+                if max(caps) > st.pool.capacity():
                     # can never fit, even against an empty pool: shed
                     dq.popleft()
                     self._stats.inc("shed_pool")
@@ -1335,15 +1598,23 @@ class GenerationEngine:
                     self._fail_request(r, ServeOverloaded(
                         "request needs %d KV blocks, past the paged "
                         "pool's %d usable blocks — shed"
-                        % (total_blocks + fork_extra, st.pool.capacity())))
+                        % (max(caps), st.pool.capacity())))
                     continue
-                # what eviction could free is counted only when the free
-                # list alone does not do: evictable() walks every pin of
-                # the prefix cache (21 ms at 2,300 pins, and the device
-                # idle meanwhile; PERF.md section 6, PR 31)
-                budget = st.pool.free_count() - st.reserved_total()
-                if needed > budget and \
-                        needed > budget + st.prefix.evictable():
+                # what eviction could free (the pool keeps the count: no
+                # walk over the pins, which cost 21 ms at 2,300 of them
+                # with the device idle; PERF.md section 6, PR 31) less
+                # the adopted blocks that only their pin holds, which
+                # stop being evictable
+                hit = blocks + ([tail] if tail is not None else [])
+                fits = True
+                for c, pool in enumerate(st.pool_of):
+                    budget = pool.free_count() - st.reserved(c)
+                    want = min(needed, caps[c])
+                    if want > budget and want + pool.held_once(
+                            e[2][c] for e in hit[firsts[c]:]) > \
+                            budget + st.prefix.evictable(c):
+                        fits = False
+                if not fits:
                     break   # wait for retirements; no overtaking
                 dq.popleft()
                 if not r.future.set_running_or_notify_cancel():
@@ -1355,17 +1626,17 @@ class GenerationEngine:
                     self._grow_paged_slots(st, store,
                                            store.batch_bucket(need))
                     slot = st.free_slot()
-                row = st.tables[slot]
-                row[:] = 0
-                for j, b in enumerate(blocks):
-                    row[j] = b
-                    st.pool.ref(b)
+                st.tables[slot] = 0
+                adopted = []
+                for c, pool in enumerate(st.pool_of):
+                    row = st.class_rows(c)[slot]
+                    for j in range(firsts[c], len(hit)):
+                        row[j] = hit[j][2][c]
+                        pool.ref(int(row[j]))
+                    adopted.append(max(len(hit) - firsts[c], 0))
                 self._stats.inc("prompt_tokens_admitted", len(r.prompt))
-                covered = len(blocks) * bs
-                if tail is not None:
-                    row[len(blocks)] = tail
-                    st.pool.ref(tail)
-                    covered = len(r.prompt)
+                if cut:
+                    self._stats.inc("prefix_hits_cut")
                 if covered:
                     self._stats.inc("prefix_hits")
                     self._stats.inc("prefix_hit_blocks",
@@ -1377,9 +1648,6 @@ class GenerationEngine:
                         "serve_prefix_hit_total",
                         help="admissions that reused shared paged-KV "
                              "prefix blocks").inc()
-                # shared tokens skip recomputation, but the LAST prompt
-                # token always reruns: its logits seed the first sample
-                prog = min(covered, len(r.prompt) - 1)
                 st.prog[slot] = prog
                 st.lengths[slot] = prog
                 st.decoding[slot] = False
@@ -1389,6 +1657,12 @@ class GenerationEngine:
                 st.temps[slot] = r.temperature
                 st.top_ks[slot] = r.top_k
                 st.resv[slot] = needed
+                st.cap[slot] = caps
+                st.passed[slot] = firsts
+                # what the prefix cache has of this prompt already
+                st.reg_n[slot] = len(blocks)
+                st.ready[slot] = -1
+                st.reg_id[slot] = blocks[-1][0] if blocks else 0
                 keys = np.array(st.keys, np.uint32)
                 if 0 <= r.seed < 2 ** 32:
                     # byte-identical to jax.random.PRNGKey(seed) for
@@ -1424,9 +1698,12 @@ class GenerationEngine:
                 # blocks_alloc: what admission reserved of the pool (the
                 # blocks themselves are taken as rows are written)
                 span.add(admitted=1, prefix_hit_tokens=covered,
-                         blocks_alloc=needed,
+                         prompt_tokens=len(r.prompt), blocks_alloc=needed,
                          state_restored=int(bool(covered
                                                  and st.state_rows)))
+                if len(adopted) > 1:
+                    span.add(**{"blocks_adopted_c%d" % c: n
+                                for c, n in enumerate(adopted)})
                 admitted += 1
             if admitted:
                 self._stats.inc("prefill_seqs", admitted)
@@ -1442,10 +1719,11 @@ class GenerationEngine:
         st.tables = np.concatenate(
             [st.tables, np.zeros((grow, st.tb), np.int32)])
         for name in ("lengths", "prog", "chunks_done", "next_tok",
-                     "top_ks", "resv"):
+                     "top_ks", "resv", "cap", "passed", "reg_n",
+                     "reg_id", "ready"):
             arr = getattr(st, name)
             setattr(st, name, np.concatenate(
-                [arr, np.zeros(grow, arr.dtype)]))
+                [arr, np.zeros((grow,) + arr.shape[1:], arr.dtype)]))
         st.decoding = np.concatenate(
             [st.decoding, np.zeros(grow, bool)])
         st.temps = np.concatenate(
@@ -1464,10 +1742,10 @@ class GenerationEngine:
         """Drop slot i's block references and bookkeeping (retire and
         failure paths; the prefix cache's pins keep shared blocks
         alive past this)."""
-        for j in range(st.tb):
-            b = int(st.tables[i, j])
-            if b:
-                st.pool.deref(b)
+        for c, pool in enumerate(st.pool_of):
+            for b in st.class_rows(c)[i]:
+                if b:
+                    pool.deref(int(b))
         st.tables[i, :] = 0
         st.slots[i] = None
         st.lengths[i] = 0
@@ -1478,6 +1756,11 @@ class GenerationEngine:
         st.temps[i] = 0.0
         st.top_ks[i] = 0
         st.resv[i] = 0
+        st.cap[i] = 0
+        st.passed[i] = 0
+        st.reg_n[i] = 0
+        st.reg_id[i] = 0
+        st.ready[i] = -1
         if st.draft is not None:
             st.dlen[i] = 0
 
@@ -1493,47 +1776,76 @@ class GenerationEngine:
         by slot index, which would starve the high slots while low
         ones refill."""
         dec = [i for i in st.active() if st.decoding[i]]
+        pre = sorted((i for i in st.active() if not st.decoding[i]),
+                     key=lambda i: st.slots[i].t_admit)
+        # both programs are dispatched before either's tokens are
+        # fetched: the chunk takes the pool and the key chains the
+        # decode step returns (arrays not yet computed), which rows
+        # are in their prompt does not hang on what the decode step
+        # samples, and a slot's blocks are reserved at admission.  So
+        # the device goes from one program to the next while the host
+        # resolves the first one's tokens, and not after it.
+        resolve = []
         if dec:
             if st.draft is not None and self._spec_active(st):
                 self._paged_spec_step(model, st, dec)
             else:
-                self._paged_decode_step(model, st, dec)
-        pre = sorted((i for i in st.active() if not st.decoding[i]),
-                     key=lambda i: st.slots[i].t_admit)
+                resolve.append(self._paged_decode_step(model, st, dec))
         if pre:
-            self._paged_prefill_chunk(model, st, pre)
+            resolve.append(self._paged_prefill_chunk(model, st, pre))
+        for finish in resolve:
+            if finish is not None:
+                finish()
         if dec or pre:
             self._paged_gauges(st)
+            if len(st.windows) > 1:
+                # the bytes the live sequences' tables hold a tick (a
+                # shared block once a holder), and what they would hold
+                # if every leaf rode the first class's table (the full
+                # layers': every block kept, for every layer)
+                held = [int(np.count_nonzero(st.class_rows(c)))
+                        for c in range(len(st.windows))]
+                self._stats.inc("cache_bytes_live", sum(
+                    n * b for n, b in zip(held, st.block_bytes_of)))
+                self._stats.inc("cache_bytes_one_table",
+                                held[0] * sum(st.block_bytes_of))
 
-    def _paged_write_ready(self, st, i, positions):
-        """Make slot i's table writable at ``positions``: allocate
-        entries still at 0 and copy-on-write-fork any covering block
-        someone else also references (refcount > 1 — a shared prefix
-        tail, or a block pinned by the prefix cache).  Generation
-        writes past the registered prompt MUST fork; recomputed prompt
-        positions rewrite shared blocks with bit-identical values, so
-        they are exempted by callers passing only new positions."""
+    def _paged_write_ready(self, st, i, positions, fork=True):
+        """Make slot i's tables writable at ``positions``, in every
+        class: allocate entries still at 0 and copy-on-write-fork any
+        covering block someone else also references (refcount > 1 — a
+        shared prefix tail, or a block pinned by the prefix cache).
+        Generation writes past the registered prompt MUST fork;
+        recomputed prompt positions rewrite shared blocks with
+        bit-identical values, so a prompt chunk passes ``fork=False``."""
         bs = st.store.kv_block
-        for j in sorted({p // bs for p in positions}):
-            b = int(st.tables[i, j])
-            if b == 0:
-                st.tables[i, j] = self._paged_alloc(st)
-                st.resv[i] = max(0, int(st.resv[i]) - 1)
-            elif st.pool.refcount(b) > 1:
-                nb = self._paged_alloc(st)
-                with _profiler.phase("cow_fork", blocks=1):
-                    self._paged_fork(st, b, nb)
-                st.pool.deref(b)
-                st.tables[i, j] = nb
-                st.resv[i] = max(0, int(st.resv[i]) - 1)
-                self._stats.inc("cow_forks")
+        blocks = sorted({p // bs for p in positions})
+        for c, pool in enumerate(st.pool_of):
+            row = st.class_rows(c)[i]
+            for j in blocks:
+                b = int(row[j])
+                if b == 0:
+                    row[j] = self._paged_alloc(st, c)
+                elif fork and pool.refcount(b) > 1:
+                    nb = self._paged_alloc(st, c)
+                    with _profiler.phase("cow_fork", blocks=1):
+                        self._paged_fork(st, b, nb, c)
+                    pool.deref(b)
+                    row[j] = nb
+                    # the one block more the slot was let hold for it
+                    st.cap[i, c] -= 1
+                    self._stats.inc("cow_forks")
+                else:
+                    continue
+                st.resv[i, c] = max(0, int(st.resv[i, c]) - 1)
 
     @staticmethod
-    def _paged_fork(st, b, nb):
-        """Duplicate physical block ``b`` into ``nb`` in every pool."""
+    def _paged_fork(st, b, nb, c=0):
+        """Duplicate physical block ``b`` into ``nb`` in every leaf of
+        class ``c``."""
         # int8: codes and per-block scales fork together
-        st.take(st.store.copy_block(*st.pools, b, nb, scales=st.scales),
-                head=0)
+        st.take(st.store.copy_block(*st.pools, b, nb, scales=st.scales,
+                                    cls=c), head=0)
         if st.draft is not None:
             # the draft plane shares the block TABLES, so its pool must
             # fork the same physical block
@@ -1542,9 +1854,12 @@ class GenerationEngine:
 
     def _paged_dispatch(self, st, tables, toks, pos, val, do, phase,
                         live, slots=None, **counts):
-        """One unified paged step (decode OR prompt chunk — ``phase``
-        names it for the profiler/traces) + one sampled token per
-        ``do`` row, host-side np result.  Same graph/host sampling
+        """Queue one unified paged step (decode OR prompt chunk —
+        ``phase`` names it for the profiler/traces) and hand back the
+        FETCH of its one sampled token per ``do`` row (a call that
+        blocks until the program is through and returns the host-side
+        np result): the caller may queue the next program before it
+        asks.  Same graph/host sampling
         split as the contiguous plane's ``_decode_and_sample``.  A
         decode step's rows are the slots; a prompt chunk's are
         compacted, row ``k`` working for slot ``slots[k]``.  The span
@@ -1572,6 +1887,10 @@ class GenerationEngine:
                     q_tokens=int(val[live].sum()),
                     sample_draw=int(sample_draw),
                     sample_topk=int(sample_topk))
+        if st.window is not None:
+            # what a window layer's attention reads of it
+            work["kv_tokens_window"] = int(np.minimum(
+                pos[live] + val[live], st.window).sum())
         if st.store.sample_mode == "graph":
             with _profiler.phase(phase, **work):
                 if slots is None:
@@ -1583,14 +1902,20 @@ class GenerationEngine:
                         *st.pools, tables, toks, pos, val, st.keys,
                         temps, top_ks, do, slots, scales=st.scales)
                 toks_dev, st.keys = st.take(out)
-            with _profiler.phase("serve_sample"):
-                out = self._fetch_decode(toks_dev)
-            # a model's own counters ride behind the sampled tokens
-            # (store.aux_counters names them): same array, same fetch
-            for name, n in zip(st.store.aux_counters,
-                               out[len(tables):]):
-                self._stats.inc(name, int(n))
-            return out[:len(tables)]
+
+            def fetch():
+                with _profiler.phase("serve_sample"):
+                    out = self._fetch_decode(toks_dev)
+                # a model's own counters ride behind the sampled
+                # tokens (store.aux_counters names them): same array,
+                # same fetch
+                for name, n in zip(st.store.aux_counters,
+                                   out[len(tables):]):
+                    self._stats.inc(name, int(n))
+                return out[:len(tables)]
+            return fetch
+        # the host's sampler moves the key chains itself: nothing of
+        # this dispatch is left for later
         with _profiler.phase(phase, **work):
             logits_dev, = st.take(st.store.run_paged_step(
                 *st.pools, tables, toks, pos, val, scales=st.scales))
@@ -1605,58 +1930,81 @@ class GenerationEngine:
             else:
                 toks_out, st.keys = host_sample_chunk(
                     logits, st.keys, temps, top_ks, do, slots)
-            return np.asarray(toks_out)
+            sampled = np.asarray(toks_out)
+        return lambda: sampled
+
+    def _paged_failed(self, model, st, slots, e, what):
+        """A dispatch (or its fetch) raised: to the futures of the
+        ``slots`` it worked for, whose blocks go back."""
+        exc = e if isinstance(e, MXNetError) \
+            else MXNetError("%s dispatch failed: %r" % (what, e))
+        _tracing.flight().record(
+            "error", "%s_dispatch_failed" % what, model=model,
+            error=repr(e), slots=len(slots))
+        for i in slots:
+            r = st.slots[i]
+            self._release_paged_slot(st, i)
+            self._fail_request(r, exc, running=True)
 
     def _paged_decode_step(self, model, st, dec):
         """Advance every generating slot one token (serve_decode
         phase).  Slots mid-prefill (and empty slots) ride the dispatch
         with all-zero tables — they reach only the trash block and
-        their outputs are discarded."""
-        for i in dec:
-            # the write position this step: COW-fork or allocate first
-            self._paged_write_ready(st, i, [int(st.lengths[i])])
+        their outputs are discarded.  Returns what is left to do once
+        the program is queued — fetch the tokens and resolve them — for
+        :meth:`_paged_tick` to call after it has queued the prompt
+        chunk too (None: the dispatch failed)."""
+        idx = np.asarray(dec)
+        # the write position this step: COW-fork or allocate first,
+        # for the rows that enter a block (st.ready)
+        at = st.lengths[idx] // st.store.kv_block
+        for i in idx[at != st.ready[idx]]:
+            self._paged_write_ready(st, int(i), [int(st.lengths[i])])
+        st.ready[idx] = at
         n = len(st.slots)
         tables = np.zeros((n, st.tb), np.int32)
         toks = np.zeros((n, 1), np.int32)
         pos = np.zeros((n,), np.int32)
         val = np.ones((n,), np.int32)
         do = np.zeros((n,), bool)
-        for i in dec:
-            tables[i] = st.tables[i]
-            toks[i, 0] = st.next_tok[i]
-            pos[i] = st.lengths[i]
-            do[i] = True
+        tables[idx] = st.tables[idx]
+        toks[idx, 0] = st.next_tok[idx]
+        pos[idx] = st.lengths[idx]
+        do[idx] = True
+        traces = [(st.slots[i].trace, st.slots[i].trace_parent)
+                  for i in dec]
         try:
-            with _tracing.activate_many(
-                    [(st.slots[i].trace, st.slots[i].trace_parent)
-                     for i in dec]):
-                sampled = self._paged_dispatch(
+            with _tracing.activate_many(traces):
+                fetch = self._paged_dispatch(
                     st, tables, toks, pos, val, do, "serve_decode", dec)
         except BaseException as e:  # noqa: BLE001 — to the futures
-            exc = e if isinstance(e, MXNetError) \
-                else MXNetError("decode dispatch failed: %r" % (e,))
-            _tracing.flight().record(
-                "error", "decode_dispatch_failed", model=model,
-                error=repr(e), slots=len(dec))
-            for i in dec:
-                r = st.slots[i]
-                self._release_paged_slot(st, i)
-                self._fail_request(r, exc, running=True)
-            return
-        with _profiler.phase("serve_resolve", tokens=len(dec)) as span:
-            for i in dec:
-                r = st.slots[i]
-                st.lengths[i] += 1
-                tok = int(sampled[i])
-                self._push_token(r, tok)
-                st.next_tok[i] = tok
-                reason = self._finished_reason(r, tok)
-                if reason:
-                    self._release_paged_slot(st, i)
-                    self._finish(r, reason)
-                    span.add(finished=1)
-        self._stats.inc("decode_steps")
-        self._stats.inc("generated_tokens", len(dec))
+            self._paged_failed(model, st, dec, e, "decode")
+            return None
+
+        def finish():
+            try:
+                with _tracing.activate_many(traces):
+                    sampled = fetch()
+            except BaseException as e:  # noqa: BLE001
+                self._paged_failed(model, st, dec, e, "decode")
+                return
+            with _profiler.phase("serve_resolve",
+                                 tokens=len(dec)) as span:
+                st.lengths[idx] += 1
+                st.next_tok[idx] = sampled[idx]
+                for i, tok in zip(dec, sampled[idx].tolist()):
+                    r = st.slots[i]
+                    self._push_token(r, tok)
+                    reason = self._finished_reason(r, tok)
+                    if reason:
+                        self._release_paged_slot(st, i)
+                        self._finish(r, reason)
+                        span.add(finished=1)
+                    elif st.window is not None:
+                        self._release_behind(st, i)
+            self._stats.inc("decode_steps")
+            self._stats.inc("generated_tokens", len(dec))
+        return finish
 
     def _spec_active(self, st):
         """The MXNET_SERVE_SPEC=auto degradation gate, checked once
@@ -1909,6 +2257,8 @@ class GenerationEngine:
                     # rejection (full accept leaves a 1-token catch-up gap
                     # for the bonus token)
                     st.dlen[i] = min(int(st.dlen[i]), int(st.lengths[i]))
+                    if st.window is not None:
+                        self._release_behind(st, i)
             span.add(tokens=emitted)
         self._stats.inc("decode_steps")
         self._stats.inc("spec_steps")
@@ -1937,9 +2287,9 @@ class GenerationEngine:
         dead rows do (zero table, one valid token, no sampling).  Rows
         finishing their prompt this dispatch sample their first token
         (the TTFT moment), register their blocks with the prefix cache
-        and flip to decoding."""
+        and flip to decoding.  Returns what is left once the program
+        is queued, as :meth:`_paged_decode_step` does."""
         store = st.store
-        bs = store.kv_block
         chunk = store.prefill_chunk
         n = store.chunk_rows(len(st.slots))
         pre, deferred = pre[:n], len(pre[n:])
@@ -1948,12 +2298,11 @@ class GenerationEngine:
             r = st.slots[i]
             p0 = int(st.prog[i])
             ntok = min(chunk, len(r.prompt) - p0)
-            # blocks covering NEW positions only: recomputed shared
-            # positions rewrite shared blocks with identical values
-            # (same tokens, same prefix) and must not fork
-            fresh = [p for p in range(p0, p0 + ntok)
-                     if st.tables[i, p // bs] == 0]
-            self._paged_write_ready(st, i, fresh)
+            # new blocks only: recomputed shared positions rewrite
+            # shared blocks with identical values (same tokens, same
+            # prefix) and must not fork
+            self._paged_write_ready(st, i, range(p0, p0 + ntok),
+                                    fork=False)
             rows.append((i, r, p0, ntok))
         tables = np.zeros((n, st.tb), np.int32)
         toks = np.zeros((n, chunk), np.int32)
@@ -1968,11 +2317,11 @@ class GenerationEngine:
             val[k] = ntok
             do[k] = (p0 + ntok == len(r.prompt))
             slots[k] = i
+        traces = [(r.trace, r.trace_parent) for _i, r, _p, _n in rows]
+        live = [i for i, _r, _p, _n in rows]
         try:
-            with _tracing.activate_many(
-                    [(r.trace, r.trace_parent)
-                     for _i, r, _p, _n in rows]):
-                sampled = self._paged_dispatch(
+            with _tracing.activate_many(traces):
+                fetch = self._paged_dispatch(
                     st, tables, toks, pos, val, do, "serve_prefill",
                     np.arange(len(rows)), slots, width=n,
                     deferred=deferred)
@@ -1990,43 +2339,49 @@ class GenerationEngine:
                         *st.dpools, tables, toks, pos, val,
                         scales=st.dscales))
         except BaseException as e:  # noqa: BLE001 — to the futures
-            exc = e if isinstance(e, MXNetError) \
-                else MXNetError("prefill dispatch failed: %r" % (e,))
-            _tracing.flight().record(
-                "error", "prefill_dispatch_failed", model=model,
-                error=repr(e), requests=len(rows))
-            for i, r, _p0, _ntok in rows:
-                self._release_paged_slot(st, i)
-                self._fail_request(r, exc, running=True)
-            return
-        self._stats.inc("prefills")
-        self._stats.inc("prefill_chunks", len(rows))
-        self._stats.inc("prefill_row_slots", n)
-        self._stats.inc("prefill_rows_deferred", deferred)
-        with _profiler.phase("serve_resolve") as span:
-            for k, (i, r, p0, ntok) in enumerate(rows):
-                st.prog[i] = p0 + ntok
-                st.lengths[i] = p0 + ntok
-                if st.draft is not None and st.spec_mirror():
-                    st.dlen[i] = p0 + ntok
-                st.chunks_done[i] += 1
-                if p0 + ntok < len(r.prompt):
-                    continue
-                if _metrics.phase_on():
-                    _H_CHUNKS.observe(int(st.chunks_done[i]))
-                st.prefix.register(r.prompt, st.tables[i])
-                tok = int(sampled[k])
-                self._push_token(r, tok)
-                span.add(tokens=1)
-                reason = self._finished_reason(r, tok)
-                if reason:
-                    self._release_paged_slot(st, i)
-                    self._finish(r, reason)
-                    span.add(finished=1)
-                else:
-                    st.decoding[i] = True
-                    st.next_tok[i] = tok
-        self._note_cache_hwm(model, st)
+            self._paged_failed(model, st, live, e, "prefill")
+            return None
+
+        def finish():
+            try:
+                with _tracing.activate_many(traces):
+                    sampled = fetch()
+            except BaseException as e:  # noqa: BLE001
+                self._paged_failed(model, st, live, e, "prefill")
+                return
+            self._stats.inc("prefills")
+            self._stats.inc("prefill_chunks", len(rows))
+            self._stats.inc("prefill_row_slots", n)
+            self._stats.inc("prefill_rows_deferred", deferred)
+            with _profiler.phase("serve_resolve") as span:
+                for k, (i, r, p0, ntok) in enumerate(rows):
+                    st.prog[i] = p0 + ntok
+                    st.lengths[i] = p0 + ntok
+                    if st.draft is not None and st.spec_mirror():
+                        st.dlen[i] = p0 + ntok
+                    st.chunks_done[i] += 1
+                    self._register_filled(st, i)
+                    if p0 + ntok < len(r.prompt):
+                        if st.window is not None:
+                            self._release_behind(st, i)
+                        continue
+                    if _metrics.phase_on():
+                        _H_CHUNKS.observe(int(st.chunks_done[i]))
+                    tok = int(sampled[k])
+                    self._push_token(r, tok)
+                    span.add(tokens=1)
+                    reason = self._finished_reason(r, tok)
+                    if reason:
+                        self._release_paged_slot(st, i)
+                        self._finish(r, reason)
+                        span.add(finished=1)
+                    else:
+                        st.decoding[i] = True
+                        st.next_tok[i] = tok
+                        if st.window is not None:
+                            self._release_behind(st, i)
+            self._note_cache_hwm(model, st)
+        return finish
 
     # -- decode --------------------------------------------------------
     def _decode_tick(self):

@@ -897,9 +897,10 @@ def cache_donate_argnums(nums):
 # with its ``arch`` key (default: the repo's own LM); what the module
 # offers under the seam's names (``serving_spec``, ``required_params``,
 # ``pack_params``, ``quantize_params``, ``init_pool``, ``paged_step``,
-# ``OFFERS``, ``AUX_COUNTERS``; models/transformer_lm.py, bottom) is all
-# the store knows of an architecture.
-_ARCHS = ("transformer_lm", "deepseek_v3", "lfm2_moe")
+# ``OFFERS``, ``AUX_COUNTERS``; models/transformer_lm.py, bottom; and,
+# where the pool's blocks come in more than one class, ``cache_classes``:
+# models/cohere2_moe.py) is all the store knows of an architecture.
+_ARCHS = ("transformer_lm", "deepseek_v3", "lfm2_moe", "cohere2_moe")
 
 
 def _serving_model(arch):
@@ -933,7 +934,9 @@ def paged_program(model, spec, kind, lq, kv_block, nleaf, int8=False):
     ONE unified step for the paged plane: ``lq`` is the query length (1
     = a decode step; prefill_chunk = one prompt chunk; spec_k+1 = a
     speculative verify).  Write-then-attend over the global pool
-    through ``(rows, table_width)`` block tables; rows not taking part
+    through ``(rows, table_width)`` block tables (a model with several
+    classes of block takes a table a class, side by side in that one
+    array: ``GenerativeProgramStore.table_width``); rows not taking part
     in a dispatch ride with all-zero tables (they reach only the
     reserved trash block 0) and their outputs are discarded host-side.
     ``fn(params, *pool leaves, tables, tokens, positions, valid,
@@ -1180,21 +1183,30 @@ class GenerativeProgramStore:
         if nb <= 0:
             # auto: the largest batch bucket at full kv_max depth,
             # plus the reserved trash block 0
-            nb = self._batch_edges[-1] * self.table_width() + 1
-        if self.paged and nb < self.table_width() + 1:
+            nb = self._batch_edges[-1] * self.class_width() + 1
+        if self.paged and nb < self.class_width() + 1:
             raise MXNetError(
                 "paged KV pool of %d blocks cannot hold one full-"
                 "depth sequence (%d blocks + the reserved trash "
                 "block); raise MXNET_SERVE_KV_POOL_BLOCKS"
-                % (nb, self.table_width()))
+                % (nb, self.class_width()))
         self.pool_blocks = nb
-        self._copy_fn = None   # lazily jitted COW block copy
+        self._copy_fn = {}     # lazily jitted COW block copy, a class
         # the pool's leaves as the model shapes them: (k, v) for the
         # LM, one latent leaf for deepseek_v3, [K | V] rows and the
         # convolution state (one row a BLOCK) for lfm2_moe
         self._pool_avals = tuple(jax.eval_shape(
             lambda: self._model.init_pool(self._spec, nb, self.kv_block,
                                           dtype=self.kv_dtype)))
+        # the pool's CLASSES of block, ``(window, leaves)`` each: a
+        # model's blocks are all of one class (every leaf rides one
+        # table, a sequence keeps every block) unless it says otherwise
+        # (``cache_classes``: models/cohere2_moe.py).  ``pool_blocks``
+        # counts the blocks of EACH class
+        classes = getattr(self._model, "cache_classes", None)
+        self.cache_classes = tuple(
+            (w, tuple(leaves)) for w, leaves in classes(self._spec)) \
+            if classes else ((None, tuple(range(len(self._pool_avals)))),)
 
         self._params = self._load_params(params)
         missing = [k for k in self._required_params()
@@ -1384,10 +1396,17 @@ class GenerativeProgramStore:
             return ("paged_chunk_sample", bb, self.prefill_chunk)
         return ("paged_step", self.chunk_rows(bb), self.prefill_chunk)
 
-    def table_width(self):
-        """Block-table width of the paged plane: logical blocks needed
+    def class_width(self):
+        """Table entries of ONE class of block: logical blocks needed
         to address a full kv_max-token sequence."""
         return -(-self.kv_max // self.kv_block)
+
+    def table_width(self):
+        """Block-table width of the paged plane: :meth:`class_width`
+        entries for each of the model's classes of block, side by side
+        (one class, so that many entries, for every model but one that
+        names ``cache_classes``)."""
+        return len(self.cache_classes) * self.class_width()
 
     def validate_request(self, prompt_len, max_tokens):
         """Reject at submit anything whose cache could outgrow kv_max
@@ -1441,32 +1460,38 @@ class GenerativeProgramStore:
         return self._placed(self._model.init_scale_pool(
             self._spec, self.pool_blocks))
 
-    def copy_block(self, *args, scales=None):
+    def copy_block(self, *args, scales=None, cls=0):
         """Copy-on-write fork: ``copy_block(*pool leaves, src, dst)``
         duplicates physical block ``src``'s rows into block ``dst`` in
-        every leaf (one jitted program, leaves donated off-CPU —
-        callers rebind to the outputs).  With ``scales`` (the int8
-        plane's ``(scale_k, scale_v)`` pools) the per-block scales fork
-        WITH the codes — a block is only decodable as codes+scale
+        every leaf of block class ``cls`` (one jitted program a class,
+        leaves donated off-CPU — callers rebind to the outputs; a
+        model of one class has every leaf in it).  With ``scales`` (the
+        int8 plane's ``(scale_k, scale_v)`` pools) the per-block scales
+        fork WITH the codes — a block is only decodable as codes+scale
         together — and the return grows by the two scale pools."""
         *pools, src, dst = args
         leaves = self._pool_args(tuple(pools), scales)
-        fn = self._copy_fn
+        fn = self._copy_fn.get(cls)
         if fn is None:
             nb = self.pool_blocks
+            # the class's leaves, and the scale pools behind them
+            mine = set(self.cache_classes[cls][1]) | set(
+                range(len(pools), len(leaves)))
 
             def copy_block(leaves, s, d):
                 # axis 2 counts a block's rows: kv_block tokens in a
                 # token leaf, one row in a state leaf or a scale pool
                 out = []
-                for leaf in leaves:
-                    n = leaf.shape[2] // nb
-                    out.append(jax.lax.dynamic_update_slice_in_dim(
-                        leaf, jax.lax.dynamic_slice_in_dim(
-                            leaf, s * n, n, 2), d * n, 2))
+                for i, leaf in enumerate(leaves):
+                    if i in mine:
+                        n = leaf.shape[2] // nb
+                        leaf = jax.lax.dynamic_update_slice_in_dim(
+                            leaf, jax.lax.dynamic_slice_in_dim(
+                                leaf, s * n, n, 2), d * n, 2)
+                    out.append(leaf)
                 return tuple(out)
 
-            fn = self._copy_fn = jax.jit(
+            fn = self._copy_fn[cls] = jax.jit(
                 copy_block, donate_argnums=cache_donate_argnums((0,)))
         return fn(leaves, np.int32(src), np.int32(dst))
 
@@ -1693,8 +1718,10 @@ class GenerativeProgramStore:
                 # the copy-on-write fork is a program of the tick too:
                 # left to its first use it compiles under traffic
                 n = self.pool_leaves
-                jax.block_until_ready(self.copy_block(
-                    *pools[:n], 0, 0, scales=pools[n:] or None))
+                for c in range(len(self.cache_classes)):
+                    pools = jax.block_until_ready(self.copy_block(
+                        *pools[:n], 0, 0, scales=pools[n:] or None,
+                        cls=c))
             return out
         cache_buckets = {self.kv_bucket(p) for p in self._prompt_edges}
         if kv_depth is not None:
@@ -1953,6 +1980,7 @@ class GenerativeProgramStore:
             out["prefill_chunk"] = self.prefill_chunk
             out["pool_blocks"] = self.pool_blocks
             out["table_width"] = self.table_width()
+            out["cache_classes"] = len(self.cache_classes)
             # (kind, batch bucket, lq, scratch bytes) of each resident
             # step program: a program that addresses the pool in place
             # needs far less than one layer of it (cache_state's

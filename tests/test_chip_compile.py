@@ -350,6 +350,40 @@ def _lfm2_program(chip):
                     for a in pools))
 
 
+def _cohere2_program(chip):
+    """The cell ``command-a-plus.serve-ragmix-backlog`` as its
+    configuration file deploys it: Command A+'s published widths, one
+    period (three window layers, one full), 16 of 128 experts, 64 slots
+    of 256 blocks of 64 tokens in EACH of the two classes of block (a
+    table a class, side by side), bfloat16 weights and ``K``/``V``
+    rows."""
+    import json
+    from mxnet_tpu.models import cohere2_moe as co
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "command-a-plus.json")) as f:
+        cfg = json.load(f)
+    deploy = cfg["deploy"]
+    spec = co.serving_spec(cfg["spec"])
+    packed = jax.eval_shape(lambda: co.pack_params(
+        {k: jnp.zeros(v, BF16)
+         for k, v in co.param_shapes(spec).items()}, spec))
+    params = {k: chip(v.shape, v.dtype) for k, v in packed.items()}
+    slots, = deploy["batch_buckets"]
+    assert deploy["kv_block"] == BS
+    pools = tuple(chip(a.shape, a.dtype) for a in jax.eval_shape(
+        lambda: co.init_pool(spec, deploy["pool_blocks"], BS,
+                             "bfloat16")))
+    return dict(model=co, spec=spec, params=params, pools=pools,
+                slots=slots, chunk=deploy["prefill_chunk"],
+                width=len(co.cache_classes(spec))
+                * (deploy["kv_max"] // BS),
+                kernels=4 + 2 * 4,
+                pool_shaped="|".join(
+                    r"bf16\[(?:%d,|1,)?%d,%d,%d\]" % a.shape
+                    for a in pools))
+
+
 def _paged_program_args(build, chip, kind):
     """``(the build, operands, program, donated)`` of a store's decode
     or compacted prompt-chunk program for the described chip."""
@@ -377,8 +411,9 @@ def _paged_program_args(build, chip, kind):
 
 @pytest.mark.parametrize("kind", ["decode", "prefill-chunk"])
 @pytest.mark.parametrize("build", [_lm_program, _deepseek_program,
-                                   _lfm2_program],
-                         ids=["lm2048", "deepseek-v3", "lfm2-24b-a2b"])
+                                   _lfm2_program, _cohere2_program],
+                         ids=["lm2048", "deepseek-v3", "lfm2-24b-a2b",
+                              "command-a-plus"])
 def test_paged_program_leaves_the_pool_in_place(chip, compiled_mode,
                                                 build, kind):
     import re
@@ -467,6 +502,52 @@ def test_lfm2_cell_programs_fit_the_chip(chip, compiled_mode, kind):
     assert len(attn) == 2
     tile = "bf16[%d,8,%d,128]" % (rows[0], 4 * rows[1])
     assert all(tile in ln and "tpu_custom_call" in ln for ln in attn)
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill-chunk"])
+def test_command_a_plus_cell_programs_fit_the_chip(chip, compiled_mode,
+                                                   kind):
+    """The cell's two programs at the published widths, compiled for
+    the described v5e: arguments (9.47 GB of weights, the full class's
+    0.81 GB of ``K`` and ``V`` rows, the window class's 2.42 GB) and
+    scratch under 15 GB of the chip's 16; the grouped product in the
+    program twice a layer under the name the benchmark's readers look
+    for; the attention kernel once a layer with all sixteen query heads
+    of a KV head in its tile: the full layer's under the name
+    ``kernel.gqa_attn_*`` read, the three window layers' under their
+    own, and these walk 5 (a decode step) or 6 (a chunk) groups of 16
+    blocks where the full layer's walks the table's 16.  Read here:
+    12.69 GB of arguments, 0.15 GB (decode) and 0.20 GB (a chunk of 32;
+    0.47 GB at 64) of scratch."""
+    import re
+    m, args, fn, donate = _paged_program_args(_cohere2_program, chip,
+                                              kind)
+    rows = args[1 + len(m["pools"]) + 1].shape
+    assert args[1 + len(m["pools"])].shape == (rows[0], 2 * 256)
+    sorted_rows = rows[0] * rows[1] * m["spec"]["num_experts_per_tok"]
+    assert dispatch.eligible_moe_experts(sorted_rows, 4096, 4096,
+                                         "bfloat16")
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
+    assert mem.temp_size_in_bytes < 1e9
+    text = compiled.as_text()
+    named = [ln for ln in text.splitlines()
+             if re.match(r"\s*(?:ROOT )?%ragged-dot\S* = ", ln)]
+    assert len(named) == 2 * 4, "\n".join(named)
+    assert all("ragged-dot_grouped_matmul" in ln
+               and "tpu_custom_call" in ln for ln in named)
+    assert " ragged-dot(" not in text
+    tile = "bf16[%d,8,%d,128]" % (rows[0], 16 * rows[1])
+    for name, calls in (("paged_attention", 1),
+                        ("window_paged_attention", 3)):
+        attn = [ln for ln in text.splitlines() if re.match(
+            r"\s*(?:ROOT )?%%%s\S* = " % name, ln)]
+        assert len(attn) == calls, (name, len(attn))
+        assert all(tile in ln and "tpu_custom_call" in ln for ln in attn)
+    print("command-a-plus %s: arguments %.2f GB, scratch %.2f GB"
+          % (kind, mem.argument_size_in_bytes / 1e9,
+             mem.temp_size_in_bytes / 1e9))
 
 
 def test_sampler_sorts_and_draws_only_in_a_conditional(chip):

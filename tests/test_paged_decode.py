@@ -671,3 +671,141 @@ def test_sampler_counters_follow_what_the_rows_ask(paged_registry):
         assert sum(spans[name]["counts"][flag]
                    for name in ("serve_decode", "serve_prefill")) \
             == stats[flag + "_dispatches"]
+
+
+# ---------------------------------------------------------------------------
+# a tick queues both programs before it fetches either's tokens
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("sample,ahead", [("graph", True), ("host", False)])
+def test_tick_queues_the_chunk_before_it_fetches_the_step(
+        contig_registry, sample, ahead):
+    """With the sampler in the graph a tick that has both a decode
+    step and a prompt chunk queues the chunk (on the pool and the key
+    chains the step returns, not yet computed) BEFORE it fetches the
+    step's tokens, so the device goes from one program to the next
+    while the host resolves; the host's sampler moves the key chains
+    itself, so there each program is fetched before the next is
+    queued.  Either way the streams are the contiguous plane's."""
+    reg = ModelRegistry()
+    store = _add_model(reg, paged=True, prefill_chunk=8, sample=sample)
+    rs = np.random.RandomState(5)
+    # the second prompt is still in its chunks while the first decodes
+    reqs = [dict(tokens=[int(t) for t in rs.randint(0, 50, n)],
+                 max_tokens=6, temperature=0.7, top_k=5, seed=40 + n)
+            for n in (3, 24, 20)]
+    want = _generate(contig_registry, reqs)
+    eng = GenerationEngine(reg)
+    log = []
+
+    def spied(name, fn):
+        def call(*a, **kw):
+            log.append(name)
+            return fn(*a, **kw)
+        return call
+
+    for name in ("run_paged_step_sample", "run_paged_chunk_sample",
+                 "run_paged_step"):
+        setattr(store, name, spied(
+            "chunk" if "chunk" in name else "step", getattr(store, name)))
+    eng._fetch_decode = spied("fetch", eng._fetch_decode)
+    try:
+        futs = [eng.submit("m", **kw) for kw in reqs]
+        got = [f.result(180).tokens for f in futs]
+    finally:
+        eng.close()
+    assert got == want
+    seq = " ".join(log)
+    if ahead:
+        assert "step chunk fetch fetch" in seq
+        assert "step fetch chunk" not in seq
+    else:
+        # one program's name here (the logits program), chunk or step
+        assert "step step" not in seq and "fetch fetch" not in seq
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_block_pool_keeps_the_counts_a_walk_would_give(seed):
+    """``pinned_once`` (what the prefix cache's eviction can reclaim)
+    and ``shared`` are kept as references come and go; after every
+    move of a random schedule of allocations, adoptions, pins, releases
+    and evictions they are what a walk over the pool counts."""
+    from mxnet_tpu.serving.decode_engine import _BlockPool
+    rs = np.random.RandomState(seed)
+    pool = _BlockPool(24)
+    held, pins = [], set()          # sequences' references; pinned blocks
+    for _ in range(600):
+        move = rs.randint(5)
+        if move == 0:
+            b = pool.alloc()
+            if b is not None:
+                held.append(b)
+        elif move == 1 and held:    # another sequence adopts a block
+            b = held[rs.randint(len(held))]
+            pool.ref(b)
+            held.append(b)
+        elif move == 2 and held:    # the prefix cache pins a held block
+            b = held[rs.randint(len(held))]
+            if b not in pins:
+                pool.ref(b, pin=True)
+                pins.add(b)
+        elif move == 3 and held:    # a sequence lets a block go
+            pool.deref(held.pop(rs.randint(len(held))))
+        elif move == 4 and pins:    # eviction, held by others or not
+            b = sorted(pins)[rs.randint(len(pins))]
+            pins.discard(b)
+            pool.deref(b, pin=True)
+        counts = {b: held.count(b) + (b in pins)
+                  for b in set(held) | pins}
+        assert pool.used() == len(counts)
+        assert all(pool.refcount(b) == n for b, n in counts.items())
+        assert pool.shared() == sum(n > 1 for n in counts.values())
+        assert pool.pinned_once() == sum(counts[b] == 1 for b in pins)
+
+
+def test_a_fetch_that_raises_fails_its_rows_and_no_others():
+    """Both programs of a tick are in flight when the decode step's
+    fetch raises: the rows it worked for get the error and give their
+    blocks back, the chunk queued behind it still resolves for the row
+    in its prompt, and the engine serves on."""
+    reg = ModelRegistry()
+    store = _add_model(reg, paged=True, prefill_chunk=8)
+    rs = np.random.RandomState(9)
+    short = [int(t) for t in rs.randint(0, 50, 3)]
+    long_ = [int(t) for t in rs.randint(0, 50, 24)]
+    eng = GenerationEngine(reg)
+    log = []
+
+    def spied(name, fn):
+        def call(*a, **kw):
+            log.append(name)
+            return fn(*a, **kw)
+        return call
+
+    store.run_paged_step_sample = spied("step",
+                                        store.run_paged_step_sample)
+    store.run_paged_chunk_sample = spied("chunk",
+                                         store.run_paged_chunk_sample)
+    fetch = eng._fetch_decode
+
+    def flaky(arr):
+        # the first tick that has a step AND a chunk in flight: the
+        # fetch that follows is the step's
+        if log[-2:] == ["step", "chunk"] and "lost" not in log:
+            log.append("lost")
+            raise RuntimeError("lost the device")
+        return fetch(arr)
+
+    eng._fetch_decode = flaky
+    try:
+        a = eng.submit("m", short, max_tokens=6)
+        b = eng.submit("m", long_, max_tokens=4)
+        with pytest.raises(MXNetError, match="decode dispatch failed"):
+            a.result(180)
+        assert len(b.result(180).tokens) == 4
+        again = eng.submit("m", short, max_tokens=3).result(180)
+        assert len(again.tokens) == 3
+        st = eng._states["m"]
+        assert not st.tables.any() and not st.resv.any()
+        assert eng.stats()["errors"] == 1
+    finally:
+        eng.close()
