@@ -468,18 +468,24 @@ def _one_shot_logits(params, spec, seqs, kv_block, kernels):
 def _generate(ph, registry, name, prompts, max_tokens, tag):
     """Answer ``prompts`` in process, then prompts[0] again over HTTP on
     127.0.0.1 port 0.  Returns (results, the HTTP result, seconds the
-    in-process batch took)."""
+    in-process batch took, seconds of them the device had nothing
+    queued: the engine's own count, ``phases.device_starved`` of
+    ``GET /stats``)."""
+    from mxnet_tpu import profiler
     from mxnet_tpu.serving import GenerationEngine
     from mxnet_tpu.serving.frontdoor import HttpClient, HttpFrontDoor
     store = registry.gen_store(name)
     warm = store.stats()["compiles"]
     engine = GenerationEngine(registry)
     try:
+        opened = profiler.phase_totals()
         t0 = time.perf_counter()
         futures = [engine.submit(name, p, max_tokens=max_tokens)
                    for p in prompts]
         results = [f.result(FUTURE_TIMEOUT_S) for f in futures]
         run_s = time.perf_counter() - t0
+        starved_s = 1e-9 * profiler.phase_totals(since=opened).get(
+            "device_starved", {"ns": 0})["ns"]
         with HttpFrontDoor(engine, port=0, gen_target=engine) as door:
             client = HttpClient(door.address, threads=1)
             try:
@@ -502,7 +508,7 @@ def _generate(ph, registry, name, prompts, max_tokens, tag):
     ph.check(store.stats()["compiles"] == warm,
              "%s: zero compilations after warm-up" % tag,
              (warm, store.stats()["compiles"]))
-    return results, over_http, run_s
+    return results, over_http, run_s, starved_s
 
 
 def _greedy_margin(gen, ref):
@@ -531,8 +537,8 @@ def _serve_side(ph, tag, checkpoint, weights, spec, prompts, cfg, kernels,
         compute_dtype=compute_dtype)
     load_s = time.perf_counter() - t0
     routed = dispatch.dispatch_stats()
-    results, over_http, run_s = _generate(ph, registry, "lm", prompts,
-                                          max_tokens, tag)
+    results, over_http, run_s, starved_s = _generate(
+        ph, registry, "lm", prompts, max_tokens, tag)
     if kernels:
         kinds = ["DotProductAttentionPaged", "RMSNorm", "LayerNorm"]
         if compute_dtype == "int8":
@@ -570,7 +576,9 @@ def _serve_side(ph, tag, checkpoint, weights, spec, prompts, cfg, kernels,
                                ref[0][split:split + 1]) <= TOL_LOGIT,
              "%s: the HTTP answer equals the in-process one" % tag, split)
     side = {"load_and_warm_s": round(load_s, 2),
-            "run_s": round(run_s, 3), "routed": routed,
+            "run_s": round(run_s, 3),
+            "device_starved_share": round(starved_s / run_s, 4),
+            "routed": routed,
             "argmax_margin": margin, "argmax_exact": exact,
             "tokens": max_tokens * len(prompts),
             "http_equal": split is None}

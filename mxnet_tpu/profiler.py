@@ -27,7 +27,7 @@ from .base import get_env
 __all__ = ["profiler_set_config", "profiler_set_state", "dump_profile",
            "Profiler", "phase", "phase_totals", "mark_step",
            "start_step_profile", "stop_step_profile",
-           "aggregate_phase_trace", "PHASES",
+           "aggregate_phase_trace", "PHASES", "StarvedClock",
            "SERVE_PHASES", "GEN_SERVE_PHASES", "FRONTDOOR_PHASES"]
 
 # The per-step wall-time attribution phases of one Module.fit batch
@@ -51,8 +51,9 @@ _NON_ADDITIVE_PHASES = frozenset(["h2d_stage", "spmd_step", "data_next",
 # who reads which: docs/architecture/observability.md, the inventory.
 SERVE_PHASES = ("serve_wait", "serve_batch", "serve_compute")
 GEN_SERVE_PHASES = ("serve_tick", "serve_idle", "serve_admit",
-                    "serve_prefill", "serve_decode", "serve_sample",
-                    "serve_resolve", "cow_fork")
+                    "serve_prepare", "serve_prefill", "serve_decode",
+                    "serve_sample", "serve_resolve", "cow_fork",
+                    "device_starved", "device_launch")
 FRONTDOOR_PHASES = ("serve_http", "serve_dispatch")
 
 
@@ -218,10 +219,12 @@ def _phase_hist(name):
         labels={"phase": name})
 
 
-def _span_ended(name, start_ns, end_ns, counts):
+def _span_ended(name, start_ns, end_ns, counts, requests=True):
     """A closed span to the lifetime totals and to whichever other
     sinks are active (the early-out keeps an unobserved span at a few
-    dict/env lookups: hot loops open spans unconditionally)."""
+    dict/env lookups: hot loops open spans unconditionally).
+    ``requests=False``: not to the request traces (a span of the
+    device's, not of the requests the thread works for just then)."""
     _lifetime.record(name, end_ns - start_ns, counts)
     col = _phase_state["collector"]
     prof = _state["profiler"]
@@ -235,7 +238,124 @@ def _span_ended(name, start_ns, end_ns, counts):
         prof.record(name, start_ns, end_ns, cat="step_phase")
     if mets:
         _phase_hist(name).observe((end_ns - start_ns) / 1e9)
-    _tracing.on_phase(name, start_ns, end_ns)
+    if requests:
+        _tracing.on_phase(name, start_ns, end_ns)
+
+
+class StarvedClock:
+    """For how long the device had NOTHING QUEUED, by the one thread
+    that queues its work and fetches its results (the generation
+    engine's loop).  Programs run in order on one stream, so when the
+    fetch of dispatch ``k`` returns every dispatch up to ``k`` is
+    through: the device is starved from the moment the newest dispatch
+    has been fetched (:meth:`fetched`) until the next dispatch call is
+    ENTERED (:meth:`launching`).  It can only under-read a trace's
+    idle share: the device was through before the fetch returned, and
+    starts somewhere inside the dispatch call (PERF.md section 6,
+    PR 36).  A call entered with nothing queued is timed too, to its
+    return (:meth:`dispatched`): the span ``device_launch``, an upper
+    bound of what the launch adds to the device's gap.
+
+    Each interval, when it closes, is a span ``device_starved`` of
+    every sink a ``phase()`` reaches but the request traces, and while
+    open a ``TraceAnnotation`` on the thread's line of a profiler
+    session.  Installed on its thread (:meth:`install`), every
+    ``phase()`` that closes there gains the count ``starved_ns``: the
+    part of its interval the clock ran.  A wait that is not the
+    engine's to shorten (an empty queue) stops the clock:
+    :meth:`pause`, :meth:`resume`.
+
+    One thread's: no lock, and no clock read but the one stamp each
+    call takes (``now_ns``: a test's hand-made stamp)."""
+
+    __slots__ = ("queued", "through", "since_ns", "closed_ns",
+                 "_entered_ns", "_annotation")
+
+    def __init__(self):
+        self.queued = 0         # dispatches made
+        self.through = 0        # the newest of them known to be through
+        self.since_ns = None    # the open interval's start
+        self.closed_ns = 0      # what the closed intervals sum to
+        self._entered_ns = None     # the launch that closed the last
+        self._annotation = None
+
+    def install(self):
+        """This thread's clock from here on (a thread has one)."""
+        _thread.starved = self
+        return self
+
+    def _close(self, now_ns):
+        """End the open interval, if one is; returns its end."""
+        if self.since_ns is None:
+            return None
+        if now_ns is None:
+            now_ns = time.perf_counter_ns()
+        since_ns, self.since_ns = self.since_ns, None
+        self.closed_ns += now_ns - since_ns
+        self._annotation.__exit__(None, None, None)
+        _span_ended("device_starved", since_ns, now_ns, {},
+                    requests=False)
+        return now_ns
+
+    def _open(self, now_ns):
+        if self.through >= self.queued and self.since_ns is None:
+            self.since_ns = time.perf_counter_ns() if now_ns is None \
+                else now_ns
+            # (a TraceMe runs from its construction)
+            self._annotation = TraceAnnotation("device_starved")
+
+    def launching(self, now_ns=None):
+        """A dispatch call is about to be made: the device's wait for
+        the HOST'S other work ends here."""
+        self._entered_ns = self._close(now_ns)
+
+    def dispatched(self, now_ns=None):
+        """The dispatch call returned: the device has work.  Returns
+        the dispatch's number, for the :meth:`fetched` of its result."""
+        self.queued += 1
+        entered_ns = self._entered_ns
+        if entered_ns is None:
+            # a caller that told of no launching(): the interval ends
+            # here at the latest
+            self._close(now_ns)
+        else:
+            self._entered_ns = None
+            _span_ended("device_launch", entered_ns,
+                        time.perf_counter_ns() if now_ns is None
+                        else now_ns, {}, requests=False)
+        return self.queued
+
+    def fetched(self, number=None, now_ns=None):
+        """The fetch of dispatch ``number``'s result returned (None:
+        the newest's, a fetch that follows its dispatch at once)."""
+        if number is None:
+            number = self.queued
+        if number > self.through:
+            self.through = number
+        self._open(now_ns)
+
+    def pause(self, now_ns=None):
+        """A wait that is the traffic's opens: not starvation."""
+        self._close(now_ns)
+
+    def resume(self, now_ns=None):
+        """The wait returned; starved again if nothing is in flight."""
+        self._open(now_ns)
+
+    def read(self, at_ns):
+        """Starved nanoseconds up to the stamp ``at_ns``."""
+        if self.since_ns is None:
+            return self.closed_ns
+        return self.closed_ns + at_ns - self.since_ns
+
+
+class _PerThread(threading.local):
+    # the thread's StarvedClock; a default every thread finds (a miss
+    # on a plain threading.local raises inside getattr: 0.5 us)
+    starved = None
+
+
+_thread = _PerThread()
 
 
 class phase:
@@ -245,9 +365,11 @@ class phase:
     work is done are added inside the block with :meth:`add` (they
     reach the totals, not the trace annotation, which is written when
     the span opens).  ``labels`` go to the annotation alone (an
-    ordinal: nothing a sum means anything of)."""
+    ordinal: nothing a sum means anything of).  On a thread that has
+    a :class:`StarvedClock` the span's close adds ``starved_ns``."""
 
-    __slots__ = ("name", "counts", "_annotation", "_start_ns")
+    __slots__ = ("name", "counts", "_annotation", "_start_ns",
+                 "_clock", "_starved_ns")
 
     def __init__(self, name, labels=None, **counts):
         self.name = name
@@ -268,12 +390,26 @@ class phase:
     def __enter__(self):
         self._annotation.__enter__()
         self._start_ns = time.perf_counter_ns()
+        clock = self._clock = _thread.starved
+        if clock is not None:
+            # clock.read(start), written out here and below for what
+            # the two calls a span cost, of the 1 us a span may cost
+            # more than it did (PERF.md section 6, PR 36 has both
+            # readings, on the chip's host)
+            self._starved_ns = clock.closed_ns if clock.since_ns is None \
+                else clock.closed_ns + self._start_ns - clock.since_ns
         return self
 
     def __exit__(self, *exc):
         end_ns = time.perf_counter_ns()
         self._annotation.__exit__(*exc)
         if self._start_ns is not None:
+            clock = self._clock
+            if clock is not None:
+                self.counts["starved_ns"] = (
+                    clock.closed_ns if clock.since_ns is None
+                    else clock.closed_ns + end_ns - clock.since_ns
+                ) - self._starved_ns
             _span_ended(self.name, self._start_ns, end_ns, self.counts)
         return False
 

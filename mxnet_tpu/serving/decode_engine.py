@@ -920,6 +920,10 @@ class GenerationEngine:
         # long-lived serving process never accumulates it
         self._admit_log = collections.deque(maxlen=4096)
         self._admit_fns = {}   # (prefill shape, cache shape) -> jitted
+        # the engine thread's: for how long the device had nothing
+        # queued (every dispatch, entered and returned, and every
+        # fetch below tells it)
+        self._starved = _profiler.StarvedClock()
         self._completer = FutureCompleter("mxt-gen-done")
         self._thread = threading.Thread(target=self._serve_loop,
                                         name="mxt-gen", daemon=True)
@@ -1151,6 +1155,7 @@ class GenerationEngine:
 
     # -- engine thread -------------------------------------------------
     def _serve_loop(self):
+        self._starved.install()
         try:
             stopping = False
             ticks = 0
@@ -1207,9 +1212,12 @@ class GenerationEngine:
         while True:
             try:
                 if block:
-                    # idle that is the traffic's, not the engine's
+                    # idle that is the traffic's, not the engine's: the
+                    # starved clock stands until the wait returns
+                    self._starved.pause()
                     with _profiler.phase("serve_idle"):
                         item = self._queue.get()
+                    self._starved.resume()
                 else:
                     item = self._queue.get_nowait()
             except queue.Empty:
@@ -1295,6 +1303,7 @@ class GenerationEngine:
                 first_logits, pk, pv = self._dispatch_prefill(
                     store, toks, lens)
             logits = np.asarray(first_logits)
+            self._starved.fetched()
         except BaseException as e:  # noqa: BLE001 — forwarded to futures
             exc = e if isinstance(e, MXNetError) \
                 else MXNetError("prefill dispatch failed: %r" % (e,))
@@ -1396,8 +1405,10 @@ class GenerationEngine:
             from .program_store import cache_donate_argnums
             fn = jax.jit(f, donate_argnums=cache_donate_argnums((0, 1)))
             self._admit_fns[key] = fn
+        self._starved.launching()
         st.cache_k, st.cache_v = fn(st.cache_k, st.cache_v, pk, pv,
                                     np.int32(slot), np.int32(row))
+        self._starved.dispatched()
 
     def _grow_slots(self, st, store, new_bb):
         grow = new_bb - len(st.slots)
@@ -1414,14 +1425,18 @@ class GenerationEngine:
             [st.keys, jnp.zeros((grow, 2), jnp.uint32)])
         if st.cache_k is not None:
             pad = ((0, 0), (0, grow), (0, 0), (0, 0), (0, 0))
+            self._starved.launching()
             st.cache_k = jnp.pad(st.cache_k, pad)
             st.cache_v = jnp.pad(st.cache_v, pad)
+            self._starved.dispatched()
         self._stats.inc("slot_grows")
 
     def _grow_cache(self, st, new_c):
         pad = ((0, 0), (0, 0), (0, 0), (0, new_c - st.C), (0, 0))
+        self._starved.launching()
         st.cache_k = jnp.pad(st.cache_k, pad)
         st.cache_v = jnp.pad(st.cache_v, pad)
+        self._starved.dispatched()
         st.C = new_c
         self._stats.inc("cache_grows")
         self._note_cache_hwm(st.store.name, st)
@@ -1829,7 +1844,9 @@ class GenerationEngine:
                 elif fork and pool.refcount(b) > 1:
                     nb = self._paged_alloc(st, c)
                     with _profiler.phase("cow_fork", blocks=1):
+                        self._starved.launching()
                         self._paged_fork(st, b, nb, c)
+                        self._starved.dispatched()
                     pool.deref(b)
                     row[j] = nb
                     # the one block more the slot was let hold for it
@@ -1893,6 +1910,7 @@ class GenerationEngine:
                 pos[live] + val[live], st.window).sum())
         if st.store.sample_mode == "graph":
             with _profiler.phase(phase, **work):
+                self._starved.launching()
                 if slots is None:
                     out = st.store.run_paged_step_sample(
                         *st.pools, tables, toks, pos, val, st.keys,
@@ -1901,11 +1919,13 @@ class GenerationEngine:
                     out = st.store.run_paged_chunk_sample(
                         *st.pools, tables, toks, pos, val, st.keys,
                         temps, top_ks, do, slots, scales=st.scales)
+                queued = self._starved.dispatched()
                 toks_dev, st.keys = st.take(out)
 
             def fetch():
                 with _profiler.phase("serve_sample"):
                     out = self._fetch_decode(toks_dev)
+                    self._starved.fetched(queued)
                 # a model's own counters ride behind the sampled
                 # tokens (store.aux_counters names them): same array,
                 # same fetch
@@ -1917,10 +1937,13 @@ class GenerationEngine:
         # the host's sampler moves the key chains itself: nothing of
         # this dispatch is left for later
         with _profiler.phase(phase, **work):
+            self._starved.launching()
             logits_dev, = st.take(st.store.run_paged_step(
                 *st.pools, tables, toks, pos, val, scales=st.scales))
+            self._starved.dispatched()
         with _profiler.phase("serve_sample"):
             logits = self._fetch_decode(logits_dev)
+            self._starved.fetched()
             from .program_store import host_sample, host_sample_chunk
             if slots is None:
                 toks_out, carry = host_sample(logits, st.keys, temps,
@@ -1954,25 +1977,26 @@ class GenerationEngine:
         the program is queued — fetch the tokens and resolve them — for
         :meth:`_paged_tick` to call after it has queued the prompt
         chunk too (None: the dispatch failed)."""
-        idx = np.asarray(dec)
-        # the write position this step: COW-fork or allocate first,
-        # for the rows that enter a block (st.ready)
-        at = st.lengths[idx] // st.store.kv_block
-        for i in idx[at != st.ready[idx]]:
-            self._paged_write_ready(st, int(i), [int(st.lengths[i])])
-        st.ready[idx] = at
-        n = len(st.slots)
-        tables = np.zeros((n, st.tb), np.int32)
-        toks = np.zeros((n, 1), np.int32)
-        pos = np.zeros((n,), np.int32)
-        val = np.ones((n,), np.int32)
-        do = np.zeros((n,), bool)
-        tables[idx] = st.tables[idx]
-        toks[idx, 0] = st.next_tok[idx]
-        pos[idx] = st.lengths[idx]
-        do[idx] = True
-        traces = [(st.slots[i].trace, st.slots[i].trace_parent)
-                  for i in dec]
+        with _profiler.phase("serve_prepare"):
+            idx = np.asarray(dec)
+            # the write position this step: COW-fork or allocate
+            # first, for the rows that enter a block (st.ready)
+            at = st.lengths[idx] // st.store.kv_block
+            for i in idx[at != st.ready[idx]]:
+                self._paged_write_ready(st, int(i), [int(st.lengths[i])])
+            st.ready[idx] = at
+            n = len(st.slots)
+            tables = np.zeros((n, st.tb), np.int32)
+            toks = np.zeros((n, 1), np.int32)
+            pos = np.zeros((n,), np.int32)
+            val = np.ones((n,), np.int32)
+            do = np.zeros((n,), bool)
+            tables[idx] = st.tables[idx]
+            toks[idx, 0] = st.next_tok[idx]
+            pos[idx] = st.lengths[idx]
+            do[idx] = True
+            traces = [(st.slots[i].trace, st.slots[i].trace_parent)
+                      for i in dec]
         try:
             with _tracing.activate_many(traces):
                 fetch = self._paged_dispatch(
@@ -2072,9 +2096,11 @@ class GenerationEngine:
                     tables[k] = st.tables[i]
                     pos[k] = base
                     val[k] = take
+                self._starved.launching()
                 st.take_draft(draft.run_paged_step(
                     *st.dpools, tables, toks, pos, val,
                     scales=st.dscales))
+                self._starved.dispatched()
                 self._stats.inc("spec_draft_steps")
         for i in dec:
             st.dlen[i] += gap[i]
@@ -2132,11 +2158,14 @@ class GenerationEngine:
                 pos[i] = idx
                 do[i] = t >= gap[i]
                 live.append(i)
+            self._starved.launching()
             t_dev, q_dev, st.dkeys = st.take_draft(
                 draft.run_paged_step_sample_p(
                     *st.dpools, tables, toks, pos, val, st.dkeys,
                     st.temps, st.top_ks, do, scales=st.dscales), head=2)
+            self._starved.dispatched()
             sampled = self._fetch_decode(t_dev)
+            self._starved.fetched()
             q_rows.append(q_dev)
             for i in live:
                 if t >= gap[i]:
@@ -2206,14 +2235,17 @@ class GenerationEngine:
                 with _profiler.phase(
                         "serve_decode", rows=len(dec),
                         kv_tokens=int((pos[dec] + val[dec]).sum())):
+                    self._starved.launching()
                     out_dev, ne_dev, st.keys = st.take(
                         st.store.run_paged_verify(
                             *st.pools, tables, vtoks, pos, val, prop_q,
                             st.keys, st.temps, st.top_ks, do,
                             scales=st.scales), head=2)
+                    self._starved.dispatched()
                 with _profiler.phase("serve_sample"):
                     out_toks = self._fetch_decode(out_dev)
                     n_emit = self._fetch_decode(ne_dev)
+                    self._starved.fetched()
         except BaseException as e:  # noqa: BLE001 — to the futures
             exc = e if isinstance(e, MXNetError) \
                 else MXNetError("speculative dispatch failed: %r"
@@ -2293,32 +2325,34 @@ class GenerationEngine:
         chunk = store.prefill_chunk
         n = store.chunk_rows(len(st.slots))
         pre, deferred = pre[:n], len(pre[n:])
-        rows = []
-        for i in pre:
-            r = st.slots[i]
-            p0 = int(st.prog[i])
-            ntok = min(chunk, len(r.prompt) - p0)
-            # new blocks only: recomputed shared positions rewrite
-            # shared blocks with identical values (same tokens, same
-            # prefix) and must not fork
-            self._paged_write_ready(st, i, range(p0, p0 + ntok),
-                                    fork=False)
-            rows.append((i, r, p0, ntok))
-        tables = np.zeros((n, st.tb), np.int32)
-        toks = np.zeros((n, chunk), np.int32)
-        pos = np.zeros((n,), np.int32)
-        val = np.ones((n,), np.int32)
-        do = np.zeros((n,), bool)
-        slots = np.zeros((n,), np.int32)
-        for k, (i, r, p0, ntok) in enumerate(rows):
-            tables[k] = st.tables[i]
-            toks[k, :ntok] = r.prompt[p0:p0 + ntok]
-            pos[k] = p0
-            val[k] = ntok
-            do[k] = (p0 + ntok == len(r.prompt))
-            slots[k] = i
-        traces = [(r.trace, r.trace_parent) for _i, r, _p, _n in rows]
-        live = [i for i, _r, _p, _n in rows]
+        with _profiler.phase("serve_prepare"):
+            rows = []
+            for i in pre:
+                r = st.slots[i]
+                p0 = int(st.prog[i])
+                ntok = min(chunk, len(r.prompt) - p0)
+                # new blocks only: recomputed shared positions rewrite
+                # shared blocks with identical values (same tokens,
+                # same prefix) and must not fork
+                self._paged_write_ready(st, i, range(p0, p0 + ntok),
+                                        fork=False)
+                rows.append((i, r, p0, ntok))
+            tables = np.zeros((n, st.tb), np.int32)
+            toks = np.zeros((n, chunk), np.int32)
+            pos = np.zeros((n,), np.int32)
+            val = np.ones((n,), np.int32)
+            do = np.zeros((n,), bool)
+            slots = np.zeros((n,), np.int32)
+            for k, (i, r, p0, ntok) in enumerate(rows):
+                tables[k] = st.tables[i]
+                toks[k, :ntok] = r.prompt[p0:p0 + ntok]
+                pos[k] = p0
+                val[k] = ntok
+                do[k] = (p0 + ntok == len(r.prompt))
+                slots[k] = i
+            traces = [(r.trace, r.trace_parent)
+                      for _i, r, _p, _n in rows]
+            live = [i for i, _r, _p, _n in rows]
         try:
             with _tracing.activate_many(traces):
                 fetch = self._paged_dispatch(
@@ -2335,9 +2369,11 @@ class GenerationEngine:
                     # mirror is skipped (zero draft cost per tick); a
                     # probe's catch-up rebuilds the draft KV from the
                     # prompt instead
+                    self._starved.launching()
                     st.take_draft(st.draft.run_paged_step(
                         *st.dpools, tables, toks, pos, val,
                         scales=st.dscales))
+                    self._starved.dispatched()
         except BaseException as e:  # noqa: BLE001 — to the futures
             self._paged_failed(model, st, live, e, "prefill")
             return None
@@ -2452,10 +2488,13 @@ class GenerationEngine:
         if st.store.sample_mode == "graph":
             toks_dev = self._dispatch_decode_sample(st, toks, lens)
             with _profiler.phase("serve_sample"):
-                return self._fetch_decode(toks_dev)
+                sampled = self._fetch_decode(toks_dev)
+                self._starved.fetched()
+                return sampled
         logits_dev = self._dispatch_decode(st, toks, lens)
         with _profiler.phase("serve_sample"):
             logits = self._fetch_decode(logits_dev)
+            self._starved.fetched()
             from .program_store import host_sample
             toks_out, st.keys = host_sample(logits, st.keys, st.temps,
                                             st.top_ks)
@@ -2465,7 +2504,9 @@ class GenerationEngine:
         """THE host fetch of the decode loop — one np conversion whose
         element count feeds ``decode_fetch_elems`` (the zero-logits-
         fetch acceptance pin reads it; tests also spy the shapes
-        here)."""
+        here).  When it returns the device is through with the
+        dispatch ``arr`` came out of and all before it: the caller
+        tells the starved clock which."""
         a = np.asarray(arr)
         self._stats.inc("decode_fetch_elems", int(a.size))
         return a
@@ -2475,7 +2516,10 @@ class GenerationEngine:
         """Enqueue-only prompt-batch dispatch (serve_prefill phase);
         the logits fetch happens on the caller side."""
         with _profiler.phase("serve_prefill"):
-            return store.run_prefill(tokens, lengths)
+            self._starved.launching()
+            out = store.run_prefill(tokens, lengths)
+            self._starved.dispatched()
+        return out
 
     @hot_path
     def _dispatch_decode(self, st, tokens, lengths):
@@ -2484,8 +2528,10 @@ class GenerationEngine:
         rebound to the program's outputs before anything can read the
         consumed buffers."""
         with _profiler.phase("serve_decode"):
+            self._starved.launching()
             logits, st.cache_k, st.cache_v = st.store.run_decode(
                 st.cache_k, st.cache_v, tokens, lengths)
+            self._starved.dispatched()
         return logits
 
     @hot_path
@@ -2494,10 +2540,12 @@ class GenerationEngine:
         tokens come out sampled in-graph; the donated caches AND the
         per-slot PRNG key state are rebound to the program's outputs."""
         with _profiler.phase("serve_decode"):
+            self._starved.launching()
             toks, st.cache_k, st.cache_v, st.keys = \
                 st.store.run_decode_sample(st.cache_k, st.cache_v, tokens,
                                            lengths, st.keys, st.temps,
                                            st.top_ks)
+            self._starved.dispatched()
         return toks
 
     # -- retirement ----------------------------------------------------
