@@ -24,6 +24,17 @@ correction bias:
   the chip's part of an expert-parallel layer, without its exchange.
 * **Head.** RMSNorm, untied head over this chip's vocabulary slice.
 
+* **Sparse attention** (a spec with the three ``index_*`` keys:
+  DeepSeek-V3.2, ``models/deepseek_v32.py``; without them none of it is
+  traced).  A lightning indexer beside MLA: ``q_idx = c_q W_iqb`` ->
+  ``index_n_heads`` x ``index_head_dim``, a head ``[rope | nope]`` (the
+  rotary part FIRST); one index key a token ``k_idx = LayerNorm(h
+  W_ik)``, split alike, kept in a second pool leaf; both rotary parts
+  turned by MLA's frequencies, as two HALVES and not adjacent pairs;
+  head weights ``w = (h W_iw) heads^-0.5 dim^-0.5``; ``I[t, s] = sum_j
+  w[t, j] relu(q_idx[t, j] . k_idx[s])`` in fp32; the ``min(index_topk,
+  t + 1)`` best positions stay and MLA's softmax runs over them alone.
+
 The multi-token-prediction module is not part of inference (report
 section 2.2) and is not here.  Norms, router scores, RoPE and the
 softmax run in fp32; products in the weights' dtype, accumulated fp32.
@@ -42,7 +53,8 @@ from .transformer_lm import _embed
 __all__ = ["serving_spec", "param_shapes", "random_params",
            "required_params", "matmul_weights", "pack_params",
            "quantize_params", "init_pool", "latent_width",
-           "paged_step_apply", "paged_step", "rope_frequencies",
+           "paged_step_apply", "paged_step_leaves", "paged_step",
+           "rope_frequencies",
            "softmax_scale", "OFFERS", "AUX_COUNTERS"]
 
 # what of the serving plane this model can be put on besides the paged
@@ -61,6 +73,8 @@ _INT_KEYS = ("num_hidden_layers", "first_k_dense_replace", "hidden_size",
              "num_experts_per_tok", "n_group", "topk_group", "vocab_size")
 _ROPE_KEYS = ("beta_fast", "beta_slow", "factor",
               "original_max_position_embeddings")
+# the indexer's LayerNorm (the released inference/model.py's own class)
+_INDEX_NORM_EPS = 1e-6
 
 
 def serving_spec(spec):
@@ -333,6 +347,45 @@ def _rope(x, cos, sin):
                      axis=-1).reshape(x.shape)
 
 
+def _rope_halves(x, cos, sin):
+    """Turn the two HALVES of the last axis against each other (value
+    ``i`` with value ``i + rope / 2``): the indexer's rotary."""
+    import jax.numpy as jnp
+    a, b = jnp.split(x, 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], axis=-1)
+
+
+def _index_parts(h, cq, p, spec, shape, cos, sin):
+    """The lightning indexer's inputs of one layer for the chunk's
+    ``shape = (B, Lq)`` rows: index queries ``(B, Lq, Hi, di)`` and the
+    fresh index keys ``(B, Lq, di)`` in fp32, both with their rotary
+    part (the FIRST ``qk_rope_head_dim`` values) turned, and the head
+    weights ``(B, Lq, Hi)`` fp32, scaled.  ``h`` the attention block's
+    normed input ``(N, D)``, ``cq`` MLA's query latent ``(N, rq)``."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    Hi, di = spec["index_n_heads"], spec["index_head_dim"]
+    dr = spec["qk_rope_head_dim"]
+    qi = _mm(cq.astype(h.dtype), p["idx_q_b_weight"]).astype(f32) \
+        .reshape(shape + (Hi, di))
+    qi = jnp.concatenate(
+        [_rope_halves(qi[..., :dr], cos[:, :, None], sin[:, :, None]),
+         qi[..., dr:]], axis=-1)
+    ki = _mm(h, p["idx_k_weight"], f32)
+    ki = ki - jnp.mean(ki, axis=-1, keepdims=True)
+    ki = ki * jax.lax.rsqrt(jnp.mean(jnp.square(ki), -1, keepdims=True)
+                            + _INDEX_NORM_EPS) \
+        * p["idx_k_norm_gamma"].astype(f32) \
+        + p["idx_k_norm_beta"].astype(f32)
+    ki = ki.reshape(shape + (di,))
+    ki = jnp.concatenate([_rope_halves(ki[..., :dr], cos, sin),
+                          ki[..., dr:]], axis=-1)
+    wi = _mm(h, p["idx_w_weight"], f32).reshape(shape + (Hi,)) \
+        * (Hi ** -0.5 * di ** -0.5)
+    return qi, ki, wi
+
+
 def _rms(x, gamma, eps):
     import jax.numpy as jnp
     from ..ops.nn import _rms_fc
@@ -388,16 +441,33 @@ def expert_layer(f, p, spec, live, eps=0.0):
 
 def paged_step_apply(params, pool, tables, tokens, positions, valid, spec,
                      block_size, all_logits=False):
-    """One PAGED step over the latent pool — ``transformer_lm.
-    paged_step_apply``'s contract with one pool leaf: tokens ``(B, Lq)``
-    (``Lq = 1`` a decode step), positions/valid ``(B,)``, tables ``(B,
-    T)`` over the pool of :func:`init_pool`; each layer writes the
-    chunk's latent rows in place (``paged.pool_write``: no scatter, no
-    slice of the pool) and attends through the ``mla_attention_paged``
-    door, ONE absorbed-form algorithm for every ``Lq``.  ``params`` is
-    a PACKED dict (:func:`pack_params`), plain or int8.
+    """:func:`paged_step_leaves` of a model whose pool is the one
+    latent leaf: ``(logits, pool, counts)``."""
+    logits, (pool,), counts = paged_step_leaves(
+        params, (pool,), tables, tokens, positions, valid, spec,
+        block_size, all_logits=all_logits)
+    return logits, pool, counts
 
-    Returns ``(logits, pool, counts)``: logits ``(B, vocab)`` fp32 at
+
+def paged_step_leaves(params, pools, tables, tokens, positions, valid,
+                      spec, block_size, all_logits=False):
+    """One PAGED step over the latent pool — ``transformer_lm.
+    paged_step_apply``'s contract: tokens ``(B, Lq)`` (``Lq = 1`` a
+    decode step), positions/valid ``(B,)``, tables ``(B, T)`` over the
+    leaves ``pools`` of the model's ``init_pool`` (the latent leaf;
+    behind it, for a spec with the ``index_*`` keys, the index keys');
+    each layer writes the chunk's fresh rows of every leaf in place
+    (``paged.pool_write``: one plan, no scatter, no slice of a pool)
+    and attends through the ``mla_attention_paged`` door, ONE
+    absorbed-form algorithm for every ``Lq`` — or, with an indexer,
+    scores the sequence's index keys (``lightning_index_scores``),
+    finds the threshold that keeps each query's ``index_topk`` best
+    positions exactly (``sparse_select``) and attends over those alone
+    (``mla_attention_sparse``: gathered rows at one query a sequence,
+    the walk under the mask for a chunk).  ``params`` is a PACKED dict
+    (:func:`pack_params`), plain or int8.
+
+    Returns ``(logits, pools, counts)``: logits ``(B, vocab)`` fp32 at
     each row's last valid position (``all_logits``: ``(B, Lq, vocab)``),
     and :data:`AUX_COUNTERS` as one int32 vector over the step's LIVE
     tokens (valid rows of sequences whose table owns a block): tokens
@@ -407,8 +477,12 @@ def paged_step_apply(params, pool, tables, tokens, positions, valid, spec,
     often the grouped product streamed an expert's weights for them
     (``ops/moe.expert_streams``; once a touched expert is the floor)."""
     import jax.numpy as jnp
-    from ..ops.attention import mla_attention_paged
+    from ..ops import attention as _att
 
+    pools = tuple(pools)
+    pool = pools[0]
+    # the indexer's selection: how many positions a query keeps
+    sparse = "index_topk" in spec
     L, D = spec["num_hidden_layers"], spec["hidden_size"]
     H = spec["num_attention_heads"]
     r, dn, dr, dv = (spec["kv_lora_rank"], spec["qk_nope_head_dim"],
@@ -432,6 +506,12 @@ def paged_step_apply(params, pool, tables, tokens, positions, valid, spec,
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     scale = softmax_scale(spec)
     counts = jnp.zeros((len(AUX_COUNTERS),), jnp.int32)
+    if sparse:
+        keep = min(spec["index_topk"], tables.shape[1] * bs)
+        # positions a live query keeps: all it sees, up to ``keep``
+        kept = jnp.where(
+            live.reshape(B, Lq),
+            jnp.minimum(positions[:, None] + rows[None] + 1, keep), 0)
 
     x = _embed(params["embed_weight"], tokens).astype(f32)   # (B, Lq, D)
     for i in range(L):
@@ -445,7 +525,12 @@ def paged_step_apply(params, pool, tables, tokens, positions, valid, spec,
             [_rms(kv[..., :r], p["kv_norm_gamma"], eps),
              _rope(kv[..., r:], cos, sin),
              jnp.zeros((B, Lq, W - r - dr), f32)], axis=-1)
-        pool, = pool_write((pool,), i, (latent[:, None],), plan, bs)
+        fresh = (latent[:, None],)
+        if sparse:
+            qi, ki, wi = _index_parts(h, cq, p, spec, (B, Lq), cos, sin)
+            fresh += (ki[:, None],)
+        pools = pool_write(pools, i, fresh, plan, bs)
+        pool = pools[0]
         wkv = _plain(p["kv_b_weight"], cdt).reshape(H, dn + dv, r)
         q_abs = jnp.einsum("blhd,hdc->bhlc", q[..., :dn], wkv[:, :dn],
                            preferred_element_type=f32)
@@ -454,8 +539,18 @@ def paged_step_apply(params, pool, tables, tokens, positions, valid, spec,
         query = jnp.concatenate(
             [q_abs, jnp.transpose(q_rope, (0, 2, 1, 3)),
              jnp.zeros((B, H, Lq, W - r - dr), f32)], axis=-1)
-        o_lat = mla_attention_paged(query.astype(pool.dtype), pool, i,
-                                    tables, positions, bs, r, scale)
+        if sparse:
+            scores = _att.lightning_index_scores(
+                qi.astype(pools[1].dtype), wi, pools[1], i, tables,
+                positions, bs)
+            thr, tie = _att.sparse_select(scores, keep)
+            o_lat = _att.mla_attention_sparse(
+                query.astype(pool.dtype), pool, i, tables, positions,
+                scores, thr, tie, kept, keep, bs, r, scale)
+        else:
+            o_lat = _att.mla_attention_paged(
+                query.astype(pool.dtype), pool, i, tables, positions, bs,
+                r, scale)
         o = jnp.einsum("bhlc,hdc->blhd", o_lat.astype(cdt), wkv[:, dn:],
                        preferred_element_type=f32)
         x = x + _mm(o.astype(cdt).reshape(N, H * dv), p["o_weight"]) \
@@ -479,15 +574,14 @@ def paged_step_apply(params, pool, tables, tokens, positions, valid, spec,
     else:
         logits = _mm(hN[jnp.arange(B), valid - 1],
                      params["head_weight"], f32)
-    return logits.astype(f32), pool, counts
+    return logits.astype(f32), pools, counts
 
 
 def paged_step(params, pools, tables, tokens, positions, valid, spec,
                block_size, scales=None, all_logits=False):
     """The program store's seam: ``(logits, pool leaves, counters)``."""
     if scales is not None:
-        raise MXNetError("deepseek_v3 has no int8 latent pool")
-    logits, pool, counts = paged_step_apply(
-        params, pools[0], tables, tokens, positions, valid, spec,
+        raise MXNetError("%s has no int8 latent pool" % spec["arch"])
+    return paged_step_leaves(
+        params, pools, tables, tokens, positions, valid, spec,
         block_size, all_logits=all_logits)
-    return logits, (pool,), counts

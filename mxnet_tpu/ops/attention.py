@@ -168,6 +168,126 @@ def mla_attention_paged(query, pool, layer, tables, positions,
                                         positions, bs, rank, scale)
 
 
+def lightning_index_scores(q, w, pool, layer, tables, positions,
+                           block_size):
+    """DeepSeek-V3.2's lightning indexer against the paged INDEX-KEY
+    leaf: ``[B, Lq, Hi, d]`` index queries and ``[B, Lq, Hi]`` head
+    weights against layer ``layer`` (a static int) of the whole stacked
+    ``(L, 1, num_blocks * block_size, d)`` leaf through the block
+    tables.  Returns ``[B, Lq, T * block_size]`` fp32 scores ``sum_j
+    w_j relu(q_j . k_s)`` by LOGICAL position, ``-inf`` past each
+    query's frontier and for a sequence outside the dispatch.
+
+    Eligible shapes route to ``dsa_index_scores`` (ReLU and the heads'
+    sum on the tile); everything else — and ``MXNET_PALLAS=0`` — lowers
+    to ``dsa_index_scores_reference``, the gather + dense twin."""
+    b, lq, hi, d = q.shape
+    bs = int(block_size)
+    from ..pallas_ops import dispatch as _pd
+    from ..pallas_ops import dsa as _dsa
+    if _pd.use_dsa_index("LightningIndexer", b, lq, hi,
+                         tables.shape[1] * bs, d, q.dtype, bs):
+        return _dsa.dsa_index_scores(q, w, pool, layer, tables, positions,
+                                     bs, interpret=_pd.interpret_mode())
+    return _dsa.dsa_index_scores_reference(q, w, pool, layer, tables,
+                                           positions, bs)
+
+
+def sparse_select(scores, k):
+    """The ``k`` best positions of each query, EXACT, as two
+    thresholds: ``[..., S]`` fp32 scores -> ``(thr, tie)``, ``[..., 1]``
+    int32 each; ``pallas_ops.dsa.selected_mask(scores, thr, tie)`` is
+    the set: the scores above the ``k``-th largest and, of those that
+    tie with it, the ones at the lowest positions, ``k`` in all (where
+    a query sees fewer than ``k`` positions, all it sees, the rest made
+    up of positions it cannot see, which every reader masks).  No
+    ``approx_max_k`` and no threshold that admits more or fewer than
+    the model says.
+
+    Eligible shapes route to ``dsa_select_threshold`` (the counting
+    passes over a tile that stays in VMEM); everything else — and
+    ``MXNET_PALLAS=0`` — to its XLA twin, the same passes."""
+    from ..pallas_ops import dispatch as _pd
+    from ..pallas_ops import dsa as _dsa
+    lead, width = scores.shape[:-1], scores.shape[-1]
+    flat = scores.reshape(-1, width)
+    if _pd.use_dsa_select("SparseSelect", flat.shape[0], width, k,
+                          scores.dtype):
+        thr, tie = _dsa.dsa_select_threshold(
+            flat, k, interpret=_pd.interpret_mode())
+    else:
+        thr, tie = _dsa.dsa_select_threshold_reference(flat, k)
+    return thr.reshape(lead + (1,)), tie.reshape(lead + (1,))
+
+
+def mla_attention_sparse(query, pool, layer, tables, positions, scores,
+                         thr, tie, counts, k, block_size, rank, scale):
+    """Absorbed-form latent attention over the SELECTED positions only:
+    ``[B, H, Lq, D]`` queries, each with its own selection (``scores``
+    ``[B, Lq, T * block_size]`` and :func:`sparse_select`'s ``thr``,
+    ``tie``), against layer ``layer`` of the whole stacked latent
+    pool.  Returns ``o_lat [B, H, Lq, rank]``.  Two forms of the same
+    mathematics, chosen by what the program can observe:
+
+    * ONE query a sequence (a decode step): the mask becomes ``k``
+      ascending positions (``compact_positions``), they go through the
+      block tables to pool rows, the rows are gathered (one gather over
+      the pool, whose layer is an index and not a slice) and
+      ``dsa_mla_attention`` reads those ``k`` rows a sequence whatever
+      the context; ``counts`` ``[B, Lq]`` says how many of them are live
+      (``min(seen, k)``; 0: a sequence outside the dispatch).
+    * a chunk of queries: ``dsa_mla_attention_masked`` walks the
+      sequence's rows once for all of them under the mask (their sets
+      mostly coincide; a gathered copy a query would move ``Lq`` times
+      what the walk reads).
+
+    Everything else — and ``MXNET_PALLAS=0`` — takes the dense twin of
+    the same form."""
+    b, h, lq, d = query.shape
+    bs = int(block_size)
+    from ..pallas_ops import dispatch as _pd
+    from ..pallas_ops import dsa as _dsa
+    if lq > 1:
+        if (_pd.interpret_mode() or lq % 8 == 0) and _pd.use_mla_paged(
+                "LatentAttentionSparse", b, h, lq, tables.shape[1] * bs, d,
+                rank, query.dtype, bs):
+            _pd._note("LatentAttentionSparse.masked")
+            return _dsa.dsa_mla_attention_masked(
+                query, pool, layer, tables, positions, scores, thr, tie,
+                bs, rank, scale, interpret=_pd.interpret_mode())
+        return _dsa.dsa_mla_attention_masked_reference(
+            query, pool, layer, tables, positions, scores, thr, tie, bs,
+            rank, scale)
+    picked = _dsa.compact_positions(
+        _dsa.selected_mask(scores, thr, tie).reshape(b, -1), k)  # (B, k)
+    tbl = jnp.asarray(tables, jnp.int32)
+    # the table's entry of each position by comparison, not by a gather
+    # of B x k single entries (a TPU gather costs by its slices, ~28 ns
+    # apiece whatever their size: my chip run, PR 41)
+    entry = jnp.arange(tbl.shape[1], dtype=jnp.int32)
+    block = jnp.sum(jnp.where((picked // bs)[..., None] == entry,
+                              tbl[:, None, :], 0), axis=-1)
+    at = block * bs + picked % bs
+    where = jnp.stack([jnp.full_like(at, int(layer)), at], axis=-1)
+    rows = jax.lax.gather(
+        pool, where,
+        jax.lax.GatherDimensionNumbers(
+            offset_dims=(2,), collapsed_slice_dims=(0, 1, 2),
+            start_index_map=(0, 2)),
+        slice_sizes=(1, 1, 1, d),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)    # (B, k, D)
+    q = query.reshape(b, h, d)
+    if _pd.use_dsa_attention("LatentAttentionSparse", b, h, int(k), d,
+                             rank, query.dtype):
+        _pd._note("LatentAttentionSparse.gathered")
+        out = _dsa.dsa_mla_attention(q, rows, counts, rank, scale,
+                                     interpret=_pd.interpret_mode())
+    else:
+        out = _dsa.dsa_mla_attention_reference(q, rows, counts, rank,
+                                               scale)
+    return out.reshape(b, h, 1, int(rank))
+
+
 def _attn_fc(attrs, query, key, value):
     if query.ndim != 4:
         raise MXNetError("DotProductAttention expects [batch, heads, "

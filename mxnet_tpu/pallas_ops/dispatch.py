@@ -50,10 +50,13 @@ from .softmax_xent import row_block
 __all__ = ["mode", "kernels_active", "interpret_mode", "block_rows",
            "block_seq", "fingerprint", "overriding", "use_rowwise",
            "use_attention", "use_attention_paged", "use_mla_paged",
+           "use_dsa_index", "use_dsa_select", "use_dsa_attention",
            "use_moe_experts", "use_dequant_matmul",
            "eligible_rowwise", "eligible_attention",
            "eligible_attention_offset", "eligible_attention_paged",
-           "eligible_mla_paged", "eligible_moe_experts",
+           "eligible_mla_paged", "eligible_dsa_index",
+           "eligible_dsa_select", "eligible_dsa_attention",
+           "eligible_moe_experts",
            "eligible_dequant_matmul", "dispatch_stats",
            "reset_dispatch_stats"]
 
@@ -282,6 +285,57 @@ def eligible_mla_paged(b, h, lq, lk, d, rank, dtype, block_size):
             and mosaic_block_ok(divisor_block(rows, 512), rows))
 
 
+def eligible_dsa_index(b, lq, heads, lk, d, dtype, block_size):
+    """May the lightning indexer's scores over a paged index-key leaf
+    run as ``dsa_index_scores``?  A Q tile is every one of ``heads``
+    heads of a divisor of ``lq`` queries (8 of them, or all), the key
+    tile one pool block of ``d`` values.  Compiled Mosaic wants ``d``
+    in whole 128-lane tiles, the heads in whole sublane tiles, a pool
+    block it can tile and whole queries it can tile (8, or the chunk)."""
+    bs = int(block_size)
+    if str(dtype) not in _FLOAT_DTYPES or bs < 1 or \
+            min(int(b), int(lq), int(heads), int(lk), int(d)) < 1:
+        return False
+    if interpret_mode():
+        return True
+    return (int(d) % 128 == 0 and int(heads) % 8 == 0 and bs % 16 == 0
+            and mosaic_block_ok(divisor_block(lq, 8), int(lq)))
+
+
+def eligible_dsa_select(rows, width, k, dtype):
+    """May the exact top-``k`` of ``rows`` rows of ``width`` fp32
+    scores run as ``dsa_select_threshold``?  A tile is 8 rows (or all)
+    of the whole width.  Compiled Mosaic wants the width in whole
+    128-lane tiles, a row tile it can tile, and the tile (with its
+    integer keys and a comparison's result beside it) within the VMEM
+    budget."""
+    if str(dtype) != "float32" or min(int(rows), int(width)) < 1 \
+            or not 1 <= int(k) <= int(width):
+        return False
+    if interpret_mode():
+        return True
+    br = divisor_block(rows, 8)
+    return (int(width) % 128 == 0 and mosaic_block_ok(br, int(rows))
+            and br * int(width) * 4 <= _VMEM_TILE_BUDGET)
+
+
+def eligible_dsa_attention(n, h, k, d, rank, dtype):
+    """May latent attention over ``k`` gathered rows a query (``n``
+    queries of ``h`` heads) run as ``dsa_mla_attention``?  The Q tile
+    is a query's heads, the row tile a divisor of ``k``.  Compiled
+    Mosaic wants both widths in whole 128-lane tiles and heads and row
+    tile it can tile."""
+    if str(dtype) not in _FLOAT_DTYPES or \
+            min(int(n), int(h), int(k)) < 1 or \
+            not 1 <= int(rank) <= int(d):
+        return False
+    if interpret_mode():
+        return True
+    return (int(d) % 128 == 0 and int(rank) % 128 == 0
+            and int(h) % 8 == 0
+            and divisor_block(k, 1024) % 16 == 0)
+
+
 def eligible_moe_experts(n, d, f, dtype):
     """May an expert layer's held part run as the sorted, grouped
     product (``ops/moe.py`` over ``grouped_matmul.py``)?  ``n`` sorted
@@ -403,6 +457,36 @@ def use_mla_paged(kind, b, h, lq, lk, d, rank, dtype, block_size):
     route when taken."""
     if not kernels_active() or not eligible_mla_paged(
             b, h, lq, lk, d, rank, dtype, block_size):
+        return False
+    _note(kind)
+    return True
+
+
+def use_dsa_index(kind, b, lq, heads, lk, d, dtype, block_size):
+    """Route decision for the lightning indexer's paged scores; counts
+    a route when taken."""
+    if not kernels_active() or not eligible_dsa_index(
+            b, lq, heads, lk, d, dtype, block_size):
+        return False
+    _note(kind)
+    return True
+
+
+def use_dsa_select(kind, rows, width, k, dtype):
+    """Route decision for the exact top-k threshold; counts a route
+    when taken."""
+    if not kernels_active() or not eligible_dsa_select(rows, width, k,
+                                                       dtype):
+        return False
+    _note(kind)
+    return True
+
+
+def use_dsa_attention(kind, n, h, k, d, rank, dtype):
+    """Route decision for latent attention over gathered rows; counts
+    a route when taken."""
+    if not kernels_active() or not eligible_dsa_attention(
+            n, h, k, d, rank, dtype):
         return False
     _note(kind)
     return True

@@ -602,6 +602,10 @@ class _PagedModelState:
         # where every class keeps every block
         self.window = min((w for w in self.windows if w is not None),
                           default=None)
+        # positions a query keeps of those its indexer scores (a model
+        # with learned sparse attention: the spans' ``index_pairs`` and
+        # ``keys_selected``), None where attention reads every key
+        self.index_topk = store.spec.get("index_topk")
         # bytes of one block of each class, over the class's leaves
         # (the int8 plane's scale pools with the one class it has)
         nb = store.pool_blocks
@@ -905,6 +909,13 @@ class GenerationEngine:
              "moe_tokens", "moe_local_assignments",
              "moe_expert_load_max", "moe_expert_steps",
              "moe_experts_touched", "moe_expert_streams",
+             # learned sparse attention (zero for a model without an
+             # indexer), a layer: dsa_queries the query rows the
+             # dispatches brought, dsa_index_pairs the (query, key)
+             # pairs their indexer scored (every position a query
+             # sees), dsa_keys_selected the pairs attention then read
+             # (min(seen, index_topk) a query)
+             "dsa_queries", "dsa_index_pairs", "dsa_keys_selected",
              "spec_steps", "spec_proposed", "spec_accepted",
              "spec_draft_steps", "spec_fallback_steps"),
             labels=self._mlabels, help="generation engine counter")
@@ -1908,6 +1919,19 @@ class GenerationEngine:
             # what a window layer's attention reads of it
             work["kv_tokens_window"] = int(np.minimum(
                 pos[live] + val[live], st.window).sum())
+        if st.index_topk is not None:
+            # what the indexer scores and what attention reads of it:
+            # query j of a row sees pos + j + 1 positions and keeps
+            # index_topk of them at most (host arithmetic, a layer)
+            j = np.arange(int(val[live].max(initial=0)))
+            seen = np.where(j < val[live][:, None],
+                            pos[live][:, None] + j + 1, 0)
+            work["index_pairs"] = int(seen.sum())
+            work["keys_selected"] = int(
+                np.minimum(seen, st.index_topk).sum())
+            self._stats.inc("dsa_queries", work["q_tokens"])
+            self._stats.inc("dsa_index_pairs", work["index_pairs"])
+            self._stats.inc("dsa_keys_selected", work["keys_selected"])
         if st.store.sample_mode == "graph":
             with _profiler.phase(phase, **work):
                 self._starved.launching()

@@ -900,7 +900,10 @@ def cache_donate_argnums(nums):
 # ``OFFERS``, ``AUX_COUNTERS``; models/transformer_lm.py, bottom; and,
 # where the pool's blocks come in more than one class, ``cache_classes``:
 # models/cohere2_moe.py) is all the store knows of an architecture.
-_ARCHS = ("transformer_lm", "deepseek_v3", "lfm2_moe", "cohere2_moe")
+# A pool may hold several leaves of ONE class (``deepseek_v32``: latent
+# rows and index keys): they ride one table and one allocator.
+_ARCHS = ("transformer_lm", "deepseek_v3", "lfm2_moe", "cohere2_moe",
+          "deepseek_v32")
 
 
 def _serving_model(arch):
@@ -1193,8 +1196,10 @@ class GenerativeProgramStore:
         self.pool_blocks = nb
         self._copy_fn = {}     # lazily jitted COW block copy, a class
         # the pool's leaves as the model shapes them: (k, v) for the
-        # LM, one latent leaf for deepseek_v3, [K | V] rows and the
-        # convolution state (one row a BLOCK) for lfm2_moe
+        # LM, one latent leaf for deepseek_v3, the latent leaf and the
+        # indexer's keys (two TOKEN leaves on one table) for
+        # deepseek_v32, [K | V] rows and the convolution state (one row
+        # a BLOCK) for lfm2_moe
         self._pool_avals = tuple(jax.eval_shape(
             lambda: self._model.init_pool(self._spec, nb, self.kv_block,
                                           dtype=self.kv_dtype)))
@@ -1441,7 +1446,9 @@ class GenerativeProgramStore:
         ``(k, v)``, each ``(num_layers, num_heads, pool_blocks *
         kv_block, head_dim)``, for the LM; one latent leaf
         ``(num_layers, 1, pool_blocks * kv_block, width)`` for
-        ``deepseek_v3`` — block 0 is the reserved trash block zero
+        ``deepseek_v3``, that leaf and the index keys' ``(num_layers,
+        1, pool_blocks * kv_block, index_head_dim)`` for
+        ``deepseek_v32`` — block 0 is the reserved trash block zero
         table entries point at."""
         return self._placed(self._model.init_pool(
             self._spec, self.pool_blocks, self.kv_block,

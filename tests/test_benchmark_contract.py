@@ -8,7 +8,8 @@ tested by the run every PR is held to.  The benchmark's slow modules
 import pytest
 
 _MODULES = ["benchmark.tests.test_%s" % m
-            for m in ("contract", "costs", "stats", "traffic", "xplane")]
+            for m in ("contract", "costs", "stats", "traffic", "xplane",
+                      "deepseek_v32")]
 pytest.register_assert_rewrite(*_MODULES)
 
 from benchmark.tests.test_contract import *  # noqa: E402,F401,F403
@@ -16,3 +17,11 @@ from benchmark.tests.test_costs import *  # noqa: E402,F401,F403
 from benchmark.tests.test_stats import *  # noqa: E402,F401,F403
 from benchmark.tests.test_traffic import *  # noqa: E402,F401,F403
 from benchmark.tests.test_xplane import *  # noqa: E402,F401,F403
+# the newest configuration's file, costs, mix and readers (seconds; its
+# rehearsals and real-size compiles stay with benchmark/tests)
+from benchmark.tests.test_deepseek_v32 import (  # noqa: E402,F401
+    test_costs_of_the_published_widths,
+    test_readers_on_a_recorded_dispatch,
+    test_reference_blocks_and_segments_do_not_change_its_answer,
+    test_reference_experts_gathered_or_masked_add_up_alike,
+    test_the_file_holds_the_catalogs_row, test_the_traffic_is_the_issues)

@@ -110,6 +110,38 @@ def _grouped(x, w, c):
     return gm.grouped_matmul(x, w, c, interpret=False)
 
 
+def _dsa_index(q, w, pool, tables, positions):
+    from mxnet_tpu.pallas_ops import dsa
+    return dsa.dsa_index_scores(q, w, pool, 1, tables, positions, BS,
+                                interpret=False)
+
+
+def _dsa_attend(q, rows, counts):
+    from mxnet_tpu.pallas_ops import dsa
+    return dsa.dsa_mla_attention(q, rows, counts, 512, 0.1,
+                                 interpret=False)
+
+
+def _dsa_select(scores):
+    from mxnet_tpu.pallas_ops import dsa
+    return dsa.dsa_select_threshold(scores, 2048, interpret=False)
+
+
+def _dsa_masked(q, pool, tables, positions, scores, thr, tie):
+    from mxnet_tpu.pallas_ops import dsa
+    return dsa.dsa_mla_attention_masked(
+        q, pool, 1, tables, positions, scores, thr, tie, BS, 512, 0.1,
+        interpret=False)
+
+
+def _dsa_index_args(s, rows, lq):
+    # deepseek-v32.serve-longdoc-backlog: 64 index heads of 128, 360
+    # table entries of 64 tokens, the leaf of 6,144 blocks
+    return (s((rows, lq, 64, 128), BF16), s((rows, lq, 64)),
+            s((2, 1, 6144 * BS, 128), BF16), s((rows, 360), I32),
+            s((rows,), I32))
+
+
 def _paged_args(s, lq, pool_dtype=F32, bs=BS, blocks=B * T + 1):
     pool = s((2, H, blocks * bs, D), pool_dtype)
     return (s((B, H, lq, D)), pool, pool, s((B, T), I32), s((B,), I32))
@@ -177,6 +209,25 @@ CASES = [
     ("grouped-decode-down", _grouped,
      lambda s: (s((512, 2048), BF16), s((16, 2048, 7168), BF16),
                 s((16,), I32)), 1),
+    # deepseek-v32.serve-longdoc-backlog's two kernels, a decode step's
+    # 64 rows and a chunk's 16 rows x 32 queries: the indexer over the
+    # paged index keys, the selection's threshold over the table's
+    # width, attention over 2,048 gathered rows a sequence (a decode
+    # step) and under the selection's mask (a chunk)
+    ("dsa-index-decode", _dsa_index,
+     lambda s: _dsa_index_args(s, 64, 1), 1),
+    ("dsa-index-chunk", _dsa_index,
+     lambda s: _dsa_index_args(s, 16, 32), 1),
+    ("dsa-select-decode", _dsa_select, lambda s: (s((64, 23040)),), 1),
+    ("dsa-select-chunk", _dsa_select, lambda s: (s((512, 23040)),), 1),
+    ("dsa-attend-decode", _dsa_attend,
+     lambda s: (s((64, 128, 640), BF16), s((64, 2048, 640), BF16),
+                s((64,), I32)), 1),
+    ("dsa-attend-chunk-masked", _dsa_masked,
+     lambda s: (s((16, 128, 32, 640), BF16),
+                s((2, 1, 6144 * BS, 640), BF16), s((16, 360), I32),
+                s((16,), I32), s((16, 32, 23040)), s((16, 32, 1), I32),
+                s((16, 32, 1), I32)), 1),
 ]
 
 
@@ -266,7 +317,7 @@ def test_flash_block_must_be_mosaic_tileable(compiled_mode, monkeypatch):
 # whole pool around the program (docs/architecture/decode_engine.md,
 # "The pool stays where it is").
 LAYERS = 2
-_MOVES_THE_POOL = ("copy", "slice", "scatter", "fusion")
+_MOVES_THE_POOL = ("copy", "slice", "scatter", "fusion", "gather")
 
 
 def _lm_program(chip):
@@ -316,6 +367,33 @@ def _deepseek_program(chip):
                 slots=64, width=104, chunk=32, kernels=LAYERS,
                 pool_shaped=r"bf16\[(?:%d,|1,)?1,%d,%d\]"
                 % ((LAYERS,) + pool.shape[2:]))
+
+
+def _deepseek32_program(chip):
+    """``_deepseek_program`` with DeepSeek-V3.2's indexer (64 heads of
+    128, 2,048 kept): TWO token leaves on the one table, the latent
+    rows and the index keys, 64 slots of 360 blocks over a pool of
+    6,144 as ``deepseek-v32.serve-longdoc-backlog`` has them."""
+    from mxnet_tpu.models import deepseek_v32 as ds
+    m = _deepseek_program(chip)
+    spec = ds.serving_spec(dict(
+        m["spec"], index_n_heads=64, index_head_dim=128,
+        index_topk=2048))
+    packed = jax.eval_shape(lambda: ds.pack_params(
+        {k: jnp.zeros(v, BF16)
+         for k, v in ds.param_shapes(spec).items()}, spec))
+    pools = tuple(chip(a.shape, a.dtype) for a in jax.eval_shape(
+        lambda: ds.init_pool(spec, 6144, 64, BF16)))
+    assert [p.shape[3] for p in pools] == [640, 128]
+    # four calls a layer: the indexer, the selection, the sparse
+    # attention, the experts' (the dense layer: three)
+    return dict(model=ds, spec=spec,
+                params={k: chip(v.shape, v.dtype)
+                        for k, v in packed.items()},
+                pools=pools, slots=64, width=360, chunk=32,
+                kernels=3 * LAYERS,
+                pool_shaped=r"bf16\[(?:%d,|1,)?1,%d,(?:640|128)\]"
+                % (LAYERS, pools[0].shape[2]))
 
 
 def _lfm2_program(chip):
@@ -411,9 +489,10 @@ def _paged_program_args(build, chip, kind):
 
 @pytest.mark.parametrize("kind", ["decode", "prefill-chunk"])
 @pytest.mark.parametrize("build", [_lm_program, _deepseek_program,
-                                   _lfm2_program, _cohere2_program],
+                                   _lfm2_program, _cohere2_program,
+                                   _deepseek32_program],
                          ids=["lm2048", "deepseek-v3", "lfm2-24b-a2b",
-                              "command-a-plus"])
+                              "command-a-plus", "deepseek-v32"])
 def test_paged_program_leaves_the_pool_in_place(chip, compiled_mode,
                                                 build, kind):
     import re
@@ -433,6 +512,23 @@ def test_paged_program_leaves_the_pool_in_place(chip, compiled_mode,
                 and pool_shaped.search(hit.group(1)):
             moved.append(line.strip()[:160])
     assert not moved, "\n".join(moved)
+    if build is _deepseek32_program:
+        # both leaves: the indexer, the selection and the sparse
+        # attention a layer and no dense latent walk; the one gather of
+        # a leaf hands back a decode step's selected rows, 2,048 a
+        # sequence, never a leaf or a layer of one; a chunk walks under
+        # the mask and gathers nothing; nothing sorts the table's width
+        form = "dsa_mla_attention" + ("" if kind == "decode"
+                                      else "_masked")
+        for name in ("dsa_index_scores", "dsa_select_threshold", form):
+            assert len(re.findall(r"%%%s[.\d]* = " % name, text)) \
+                == LAYERS, name
+        assert "mla_paged_attention" not in text
+        gathers = re.findall(r"= (bf16\[\d+,\d+,640\])\S* gather\(", text)
+        assert gathers == (["bf16[64,2048,640]"] * LAYERS
+                           if kind == "decode" else [])
+        assert not [ln for ln in text.splitlines()
+                    if " sort(" in ln and ",23040]" in ln]
     if build is _lm_program:
         pool = m["pools"][0]
         layer_bytes = pool.size // LAYERS * pool.dtype.itemsize

@@ -833,8 +833,8 @@ def test_seam_and_the_other_models_pools():
     from mxnet_tpu.models.transformer_lm import lm_spec, random_params
     from mxnet_tpu.pallas_ops.dequant_matmul import QuantizedWeight
     from mxnet_tpu.serving import program_store
-    assert program_store._ARCHS == ("transformer_lm", "deepseek_v3",
-                                    "lfm2_moe", "cohere2_moe")
+    assert program_store._ARCHS[:4] == ("transformer_lm", "deepseek_v3",
+                                        "lfm2_moe", "cohere2_moe")
     with pytest.raises(MXNetError, match="contiguous"):
         _store(paged=False)
     with pytest.raises(MXNetError, match="int8"):
