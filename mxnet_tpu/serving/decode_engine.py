@@ -130,6 +130,17 @@ __all__ = ["GenerationEngine", "GenerationResult", "TokenStream"]
 
 _STOP = object()
 
+# What the prefix cache has of a prompt and what adopting it leaves
+# (GenerationEngine._prefix_cover): ``hit`` the entries to adopt, root
+# first (the tail block's last), ``whole`` the prompt's whole blocks
+# had with them and ``last_id`` the entry of the last, ``cut`` whether
+# a window class shortened the hit, ``covered`` the prompt tokens the
+# cache stands in for, ``prog`` where the slot resumes, ``needed`` the
+# blocks it has yet to allocate, a class ``caps`` the most it holds at
+# once and ``firsts`` the first logical block it adopts
+_Cover = collections.namedtuple(
+    "_Cover", "hit whole last_id cut covered prog needed caps firsts")
+
 
 class GenerationResult:
     """One finished generation (what the request's Future resolves to).
@@ -458,48 +469,60 @@ class _PrefixStore:
         w = self._windows[c]
         return 0 if w is None else max(0, (pos - w + 1) // self._bs)
 
-    def match(self, prompt):
+    def holds(self, key):
+        """Whether the block keyed ``(parent entry's id, its tokens)``
+        is registered: the one probe a tick a slot in its prompt makes
+        for its next block (:meth:`GenerationEngine._next_key`)."""
+        return key in self._entries
+
+    def match(self, prompt, held=(0, 0)):
         """Longest usable shared prefix of ``prompt``: ``(chain, tail,
         cut)`` — the entries of the whole shared blocks, root first,
         plus the tail block's entry on an exact whole-prompt match
         (else None); an entry's ``[2]`` holds its physical block a
-        class.  In a class with a window the adopter needs the blocks
-        from :meth:`first_needed` of where it resumes on, no more;
-        where one of those is no longer pinned the hit is shortened
-        until they are (``cut`` True; the chain may come back empty).
-        Touches the walked entries' LRU position; refcounts are NOT
-        taken (the caller refs what it actually adopts)."""
+        class.  ``held``: the asker already has the prompt's first
+        ``held[0]`` whole blocks (the last under the entry ``held[1]``:
+        a slot in its prompt, asking again), and the walk and the
+        chain start behind them.  In a class with a window the adopter
+        needs the blocks from :meth:`first_needed` of where it resumes
+        on, no more; where one of those is neither held nor still
+        pinned the hit is shortened until they are (``cut`` True; the
+        chain may come back empty).  Touches the walked entries' LRU
+        position; refcounts are NOT taken (the caller refs what it
+        actually adopts)."""
         bs = self._bs
-        chain, pid = [], 0
-        while (len(chain) + 1) * bs <= len(prompt):
-            j = len(chain)
+        n0, pid = held
+        chain = []
+        while (n0 + len(chain) + 1) * bs <= len(prompt):
+            j = n0 + len(chain)
             e = self._entries.get((pid, tuple(prompt[j * bs:(j + 1) * bs])))
             if e is None:
                 break
             chain.append(e)
             pid = e[0]
         tail = None
-        if len(prompt) % bs and len(chain) == len(prompt) // bs:
+        if len(prompt) % bs and n0 + len(chain) == len(prompt) // bs:
             tail = self._entries.get(
-                (pid, tuple(prompt[len(chain) * bs:])))
+                (pid, tuple(prompt[(n0 + len(chain)) * bs:])))
         self._touch(chain + ([tail] if tail else []))
         found = (len(chain), tail)
         for c, w in enumerate(self._windows):
             if w is None:
                 continue
-            # run[i]: whole entries up to i in a row with a pin here
-            run, n = [], 0
+            # run[i]: whole blocks up to chain[i] in a row that are
+            # held, or have a pin here
+            run, n = [], n0
             for e in chain:
                 n = n + 1 if e[2][c] else 0
                 run.append(n)
             if tail is not None and not (
-                    tail[2][c] and len(chain) - self.first_needed(
+                    tail[2][c] and n0 + len(chain) - self.first_needed(
                         c, len(prompt) - 1) <= n):
                 tail = None
             if tail is None:
                 j = len(chain)
-                while j and run[j - 1] < j - self.first_needed(
-                        c, min(j * bs, len(prompt) - 1)):
+                while j and run[j - 1] < n0 + j - self.first_needed(
+                        c, min((n0 + j) * bs, len(prompt) - 1)):
                     j -= 1
                 chain = chain[:j]
         return chain, tail, (len(chain), tail) != found
@@ -641,6 +664,9 @@ class _PagedModelState:
         # slot, and the entry of the last (the parent of the next)
         self.reg_n = np.zeros(0, np.int32)
         self.reg_id = np.zeros(0, np.int64)
+        # a slot: (reg_n, the prefix cache's key of the prompt block
+        # after those), kept until reg_n moves (the engine's _next_key)
+        self.next_key = []
         # the logical block the slot's last decode write was made
         # ready in (-1: none yet): only prompt blocks are ever pinned
         # or adopted, so that block stays the slot's own and the next
@@ -861,10 +887,15 @@ class GenerationEngine:
              # prefill_rows_deferred the rows that waited a tick
              # because more slots were in their prompt than a
              # dispatch has rows; shed_pool the requests too large
-             # for the pool
+             # for the pool.  prefix_late_tokens / _blocks: what slots
+             # adopted from the prefix cache AFTER admission, in the
+             # tick (beside prefix_hit_*, not inside them);
+             # prefill_rows_waited the rows that waited a tick because
+             # a row of the dispatch was filling the block they need
              "prefix_hits", "prefix_hit_blocks", "prefix_hit_tokens",
+             "prefix_late_tokens", "prefix_late_blocks",
              "cow_forks", "prefill_chunks", "prefill_row_slots",
-             "prefill_rows_deferred", "shed_pool",
+             "prefill_rows_deferred", "prefill_rows_waited", "shed_pool",
              # paged dispatches (decode steps and prompt chunks) for
              # which the sampler drew (a row with temperature > 0) and
              # for which it also sorted the vocabulary (a sampling row
@@ -1557,15 +1588,137 @@ class GenerationEngine:
                  for c in range(len(st.windows))])
         st.reg_n[i], st.reg_id[i] = n, pid
 
+    def _prefix_cover(self, st, r, held=(0, 0)):
+        """What the prefix cache has of ``r.prompt`` behind the
+        ``held`` whole blocks its asker already has (``(count, entry
+        of the last)``: none for a request at admission, ``reg_n`` and
+        ``reg_id`` for a slot in its prompt), and what adopting it
+        leaves the slot to compute and to take from the pool: the ONE
+        place of the state, tail, last-token and window rules, for
+        admission and for the tick (:meth:`_adopt_late`)."""
+        bs = st.store.kv_block
+        plen = len(r.prompt)
+        total_blocks = -(-(plen + r.max_tokens) // bs)
+        blocks, tail, cut = st.prefix.match(r.prompt, held)
+        if st.state_rows:
+            # a state leaf holds the state after a block's LAST
+            # token: a hit restores it at a block boundary, and
+            # the prompt's last token reruns from there (no
+            # tail, and not the block that holds that token)
+            blocks = blocks[:max((plen - 1) // bs - held[0], 0)]
+            tail = None
+        whole = held[0] + len(blocks)
+        # a partially-filled last prompt block gets pinned by the
+        # prefix cache at registration, so the first decode write
+        # into it MUST copy-on-write-fork — one allocation past
+        # total_blocks.  A tail HIT already counts its fork target
+        # in total_blocks (the borrowed block is free).
+        fork_extra = int(plen % bs != 0 and tail is None)
+        # shared tokens skip recomputation, but the LAST prompt
+        # token always reruns: its logits seed the first sample
+        covered = plen if tail is not None else whole * bs
+        prog = min(covered, plen - 1)
+        # a class: the most blocks the slot holds at once (a
+        # window's keys and a dispatch's rows, and one more
+        # while a shared tail block forks), and of the hit the
+        # entries it adopts: all of them, or with a window
+        # those a query at ``prog`` still sees
+        caps, firsts = [], []
+        for c, w in enumerate(st.windows):
+            caps.append(total_blocks + fork_extra if w is None
+                        else min(total_blocks + fork_extra,
+                                 st.window_cap(c) + int(plen % bs != 0)))
+            firsts.append(st.prefix.first_needed(c, prog))
+        return _Cover(blocks + ([tail] if tail is not None else []),
+                      whole, blocks[-1][0] if blocks else held[1], cut,
+                      covered, prog,
+                      total_blocks - whole + fork_extra, caps, firsts)
+
+    def _adopt(self, st, i, cover, held=0):
+        """Hold slot i to ``cover``: its tables point at the hit's
+        blocks behind the ``held`` whole blocks it keeps (+1 refcount
+        each, in every class from the first a query at the new
+        frontier still sees; a block of its own at such a place is
+        dereferenced), its frontier, its registration and its
+        reservation move to what is left.  Returns the blocks adopted
+        a class."""
+        adopted = []
+        for c, pool in enumerate(st.pool_of):
+            row = st.class_rows(c)[i]
+            lo = max(cover.firsts[c], held)
+            for j in range(lo, held + len(cover.hit)):
+                b = int(cover.hit[j - held][2][c])
+                pool.ref(b)
+                if row[j]:
+                    pool.deref(int(row[j]))
+                row[j] = b
+            adopted.append(max(held + len(cover.hit) - lo, 0))
+        st.prog[i] = st.lengths[i] = cover.prog
+        # every block it will now never allocate goes back
+        st.resv[i] = cover.needed
+        st.cap[i] = cover.caps
+        # what the prefix cache has of this prompt already
+        st.reg_n[i] = cover.whole
+        st.reg_id[i] = cover.last_id
+        if st.draft is not None:
+            # the draft's KV frontier starts at the shared-prefix
+            # coverage like the target's (its pool was mirrored
+            # when those blocks were first prefilled).
+            # While the auto-mode fallback has the mirror off, the
+            # adopted blocks' draft rows are unwritten: claim NO
+            # coverage of them so a probe's catch-up rebuilds from the
+            # prompt instead of trusting garbage
+            st.dlen[i] = cover.prog if st.spec_mirror() \
+                else min(int(st.dlen[i]), held * st.store.kv_block)
+        # its own blocks that fell behind a window meanwhile
+        self._release_behind(st, i)
+        return adopted
+
+    def _next_key(self, st, i):
+        """The prefix cache's key of the block slot i's prompt fills
+        next — the whole block behind the ``reg_n`` it has registered
+        or adopted, or the prompt's partial tail — and None where
+        admission would adopt no such block: past the prompt, and for
+        a model with state leaves from the block that holds the last
+        prompt token on.  Built once a block (``st.next_key``)."""
+        n = int(st.reg_n[i])
+        got = st.next_key[i]
+        if got is None or got[0] != n:
+            prompt, bs = st.slots[i].prompt, st.store.kv_block
+            stop = (len(prompt) - 1) // bs if st.state_rows \
+                else -(-len(prompt) // bs)
+            got = st.next_key[i] = (n, None if n >= stop else (
+                int(st.reg_id[i]), tuple(prompt[n * bs:(n + 1) * bs])))
+        return got[1]
+
+    def _adopt_late(self, st, i):
+        """Slot i, in its prompt, finds its next block in the prefix
+        cache (another slot registered it since i was admitted): it
+        adopts what admission would adopt had it arrived now, behind
+        the blocks it has.  Returns ``(tokens, blocks)`` it is spared
+        (0, 0: a window class lost a pin the hit needs, or only the
+        last prompt token is left, which reruns anyway)."""
+        held = int(st.reg_n[i])
+        cover = self._prefix_cover(st, st.slots[i],
+                                   (held, int(st.reg_id[i])))
+        tokens = cover.prog - int(st.prog[i])
+        if tokens <= 0:
+            return 0, 0
+        self._adopt(st, i, cover, held)
+        return tokens, len(cover.hit)
+
     def _admit_paged(self, model, dq, store):
         """Paged admission: no prefill dispatch here — a slot is
         claimed, its block table seeded from the prefix cache (shared
         blocks adopted at +1 refcount each), and the prompt's
-        remaining tokens left for the tick loop to chunk through.
+        remaining tokens left for the tick loop to chunk through;
+        what the cache learns of the prompt AFTER this, the slot
+        adopts in the tick (:meth:`_paged_prefill_chunk`) and its
+        reservation shrinks with it, which is what lets the next
+        request in.
         FIFO, never overtaking: the head request waiting on pool
         space blocks everyone behind it."""
         st = self._paged_state(model, store)
-        bs = store.kv_block
         cap = store.max_slots()
         if self._max_active is not None:
             cap = min(cap, self._max_active)
@@ -1583,40 +1736,8 @@ class GenerationEngine:
                     continue
                 if len(st.active()) >= cap:
                     break
-                total_blocks = -(-(len(r.prompt) + r.max_tokens) // bs)
-                blocks, tail, cut = st.prefix.match(r.prompt)
-                if st.state_rows:
-                    # a state leaf holds the state after a block's LAST
-                    # token: a hit restores it at a block boundary, and
-                    # the prompt's last token reruns from there (no
-                    # tail, and not the block that holds that token)
-                    blocks = blocks[:(len(r.prompt) - 1) // bs]
-                    tail = None
-                # a partially-filled last prompt block gets pinned by the
-                # prefix cache at registration, so the first decode write
-                # into it MUST copy-on-write-fork — one allocation past
-                # total_blocks.  A tail HIT already counts its fork target
-                # in total_blocks (the borrowed block is free).
-                fork_extra = int(len(r.prompt) % bs != 0 and tail is None)
-                needed = total_blocks - len(blocks) + fork_extra
-                # shared tokens skip recomputation, but the LAST prompt
-                # token always reruns: its logits seed the first sample
-                covered = len(r.prompt) if tail is not None \
-                    else len(blocks) * bs
-                prog = min(covered, len(r.prompt) - 1)
-                # a class: the most blocks the slot holds at once (a
-                # window's keys and a dispatch's rows, and one more
-                # while a shared tail block forks), and of the hit the
-                # entries it adopts: all of them, or with a window
-                # those a query at ``prog`` still sees
-                caps, firsts = [], []
-                for c, w in enumerate(st.windows):
-                    caps.append(total_blocks + fork_extra if w is None
-                                else min(total_blocks + fork_extra,
-                                         st.window_cap(c)
-                                         + int(len(r.prompt) % bs != 0)))
-                    firsts.append(st.prefix.first_needed(c, prog))
-                if max(caps) > st.pool.capacity():
+                cover = self._prefix_cover(st, r)
+                if max(cover.caps) > st.pool.capacity():
                     # can never fit, even against an empty pool: shed
                     dq.popleft()
                     self._stats.inc("shed_pool")
@@ -1624,20 +1745,19 @@ class GenerationEngine:
                     self._fail_request(r, ServeOverloaded(
                         "request needs %d KV blocks, past the paged "
                         "pool's %d usable blocks — shed"
-                        % (max(caps), st.pool.capacity())))
+                        % (max(cover.caps), st.pool.capacity())))
                     continue
                 # what eviction could free (the pool keeps the count: no
                 # walk over the pins, which cost 21 ms at 2,300 of them
                 # with the device idle; PERF.md section 6, PR 31) less
                 # the adopted blocks that only their pin holds, which
                 # stop being evictable
-                hit = blocks + ([tail] if tail is not None else [])
                 fits = True
                 for c, pool in enumerate(st.pool_of):
                     budget = pool.free_count() - st.reserved(c)
-                    want = min(needed, caps[c])
+                    want = min(cover.needed, cover.caps[c])
                     if want > budget and want + pool.held_once(
-                            e[2][c] for e in hit[firsts[c]:]) > \
+                            e[2][c] for e in cover.hit[cover.firsts[c]:]) > \
                             budget + st.prefix.evictable(c):
                         fits = False
                 if not fits:
@@ -1653,20 +1773,15 @@ class GenerationEngine:
                                            store.batch_bucket(need))
                     slot = st.free_slot()
                 st.tables[slot] = 0
-                adopted = []
-                for c, pool in enumerate(st.pool_of):
-                    row = st.class_rows(c)[slot]
-                    for j in range(firsts[c], len(hit)):
-                        row[j] = hit[j][2][c]
-                        pool.ref(int(row[j]))
-                    adopted.append(max(len(hit) - firsts[c], 0))
+                st.slots[slot] = r
+                adopted = self._adopt(st, slot, cover)
+                covered = cover.covered
                 self._stats.inc("prompt_tokens_admitted", len(r.prompt))
-                if cut:
+                if cover.cut:
                     self._stats.inc("prefix_hits_cut")
                 if covered:
                     self._stats.inc("prefix_hits")
-                    self._stats.inc("prefix_hit_blocks",
-                                    len(blocks) + (tail is not None))
+                    self._stats.inc("prefix_hit_blocks", len(cover.hit))
                     self._stats.inc("prefix_hit_tokens", covered)
                     if st.state_rows:
                         self._stats.inc("state_restores")
@@ -1674,21 +1789,12 @@ class GenerationEngine:
                         "serve_prefix_hit_total",
                         help="admissions that reused shared paged-KV "
                              "prefix blocks").inc()
-                st.prog[slot] = prog
-                st.lengths[slot] = prog
                 st.decoding[slot] = False
                 st.chunks_done[slot] = 0
-                st.slots[slot] = r
                 st.next_tok[slot] = 0
                 st.temps[slot] = r.temperature
                 st.top_ks[slot] = r.top_k
-                st.resv[slot] = needed
-                st.cap[slot] = caps
-                st.passed[slot] = firsts
-                # what the prefix cache has of this prompt already
-                st.reg_n[slot] = len(blocks)
                 st.ready[slot] = -1
-                st.reg_id[slot] = blocks[-1][0] if blocks else 0
                 keys = np.array(st.keys, np.uint32)
                 if 0 <= r.seed < 2 ** 32:
                     # byte-identical to jax.random.PRNGKey(seed) for
@@ -1699,16 +1805,9 @@ class GenerationEngine:
                     keys[slot] = np.asarray(jax.random.PRNGKey(r.seed))
                 st.keys = jnp.asarray(keys)
                 if st.draft is not None:
-                    # the draft's KV frontier starts at the shared-prefix
-                    # coverage like the target's (its pool was mirrored
-                    # when those blocks were first prefilled), and its
-                    # PRNG chain is an independent fold of the request
-                    # seed — target and draft draws never correlate.
-                    # While the auto-mode fallback has the mirror off, the
-                    # adopted blocks' draft rows are unwritten: claim NO
-                    # coverage so a probe's catch-up rebuilds from the
-                    # prompt instead of trusting garbage
-                    st.dlen[slot] = prog if st.spec_mirror() else 0
+                    # the draft's PRNG chain is an independent fold of
+                    # the request seed — target and draft draws never
+                    # correlate.
                     # salted threefry key derived on HOST: the draft's
                     # constant hi word can never equal a target key's, so
                     # the chains stay decorrelated — the jax.random
@@ -1724,7 +1823,8 @@ class GenerationEngine:
                 # blocks_alloc: what admission reserved of the pool (the
                 # blocks themselves are taken as rows are written)
                 span.add(admitted=1, prefix_hit_tokens=covered,
-                         prompt_tokens=len(r.prompt), blocks_alloc=needed,
+                         prompt_tokens=len(r.prompt),
+                         blocks_alloc=cover.needed,
                          state_restored=int(bool(covered
                                                  and st.state_rows)))
                 if len(adopted) > 1:
@@ -1742,6 +1842,7 @@ class GenerationEngine:
     def _grow_paged_slots(self, st, store, new_bb):
         grow = new_bb - len(st.slots)
         st.slots.extend([None] * grow)
+        st.next_key.extend([None] * grow)
         st.tables = np.concatenate(
             [st.tables, np.zeros((grow, st.tb), np.int32)])
         for name in ("lengths", "prog", "chunks_done", "next_tok",
@@ -1786,6 +1887,7 @@ class GenerationEngine:
         st.passed[i] = 0
         st.reg_n[i] = 0
         st.reg_id[i] = 0
+        st.next_key[i] = None
         st.ready[i] = -1
         if st.draft is not None:
             st.dlen[i] = 0
@@ -1800,7 +1902,11 @@ class GenerationEngine:
         slots in their prompt than the chunk has rows, the ones
         admitted first go and the rest wait a tick: by admission, not
         by slot index, which would starve the high slots while low
-        ones refill."""
+        ones refill.  A slot in its prompt is held to the prefix cache
+        on EVERY tick, not only at admission: it computes no block the
+        cache has or another row of the dispatch is computing
+        (:meth:`_paged_prefill_chunk`), so a burst over one new
+        document prefills it once."""
         dec = [i for i in st.active() if st.decoding[i]]
         pre = sorted((i for i in st.active() if not st.decoding[i]),
                      key=lambda i: st.slots[i].t_admit)
@@ -2337,9 +2443,16 @@ class GenerationEngine:
     def _paged_prefill_chunk(self, model, st, pre):
         """Advance the first of the prefilling slots ``pre`` (oldest
         admission first) one prompt chunk (serve_prefill phase); the
-        rest wait a tick.  The dispatch is compacted:
-        ``chunk_rows(slots)`` rows, row ``k`` working for slot
-        ``pre[k]``; rows past the live ones ride as a decode step's
+        rest wait a tick.  Before the rows are chosen every slot is
+        held to the prefix cache as admission held it: one whose next
+        block the cache has by now adopts it and what follows
+        (:meth:`_adopt_late`), and one whose next block a row already
+        chosen is filling waits for it (ONE writer a block: the older
+        slot writes, :meth:`_register_filled` pins, the waiter adopts
+        next tick; chosen anew every tick, so a writer that fails or
+        retires blocks no one).  The dispatch is compacted:
+        ``chunk_rows(slots)`` rows, row ``k`` working for the ``k``-th
+        slot chosen; rows past the live ones ride as a decode step's
         dead rows do (zero table, one valid token, no sampling).  Rows
         finishing their prompt this dispatch sample their first token
         (the TTFT moment), register their blocks with the prefix cache
@@ -2348,10 +2461,33 @@ class GenerationEngine:
         store = st.store
         chunk = store.prefill_chunk
         n = store.chunk_rows(len(st.slots))
-        pre, deferred = pre[:n], len(pre[n:])
-        with _profiler.phase("serve_prepare"):
-            rows = []
+        with _profiler.phase("serve_prepare") as span:
+            # no slot computes a block the prefix cache has, or a row
+            # of this dispatch is computing: the keys of the blocks the
+            # chosen rows fill, and the slots that wait on one of them
+            chosen, writing = [], set()
+            waited = deferred = late_tokens = late_blocks = 0
             for i in pre:
+                key = self._next_key(st, i)
+                if key is not None and st.prefix.holds(key):
+                    tokens, blocks = self._adopt_late(st, i)
+                    late_tokens += tokens
+                    late_blocks += blocks
+                    key = self._next_key(st, i)
+                if key is not None and key in writing:
+                    waited += 1
+                elif len(chosen) < n:
+                    chosen.append(i)
+                    writing.add(key)
+                else:
+                    deferred += 1
+            if late_blocks or waited:
+                self._stats.inc("prefix_late_tokens", late_tokens)
+                self._stats.inc("prefix_late_blocks", late_blocks)
+                span.add(late_tokens=late_tokens, late_blocks=late_blocks,
+                         waited=waited)
+            rows = []
+            for i in chosen:
                 r = st.slots[i]
                 p0 = int(st.prog[i])
                 ntok = min(chunk, len(r.prompt) - p0)
@@ -2413,6 +2549,7 @@ class GenerationEngine:
             self._stats.inc("prefill_chunks", len(rows))
             self._stats.inc("prefill_row_slots", n)
             self._stats.inc("prefill_rows_deferred", deferred)
+            self._stats.inc("prefill_rows_waited", waited)
             with _profiler.phase("serve_resolve") as span:
                 for k, (i, r, p0, ntok) in enumerate(rows):
                     st.prog[i] = p0 + ntok

@@ -681,6 +681,46 @@ def test_prefix_match_is_cut_to_a_whole_window(gone, blocks, tail, cut):
     assert pools[0].used() == 5
 
 
+@pytest.mark.parametrize("gone,held,blocks,tail,cut", [
+    ((), 2, 3, True, False),        # the rest of the chain and the tail
+    ((0, 1), 2, 3, True, False),    # pins behind what it holds
+    ((3,), 4, 1, True, False),      # block 3 is its own: no pin needed
+    ((3,), 2, 1, False, True),      # block 3 is not: cut to block 2
+    ((4,), 4, 0, False, True)])     # nothing usable behind its own
+def test_prefix_match_behind_held_blocks(gone, held, blocks, tail, cut):
+    """``_PrefixStore.match`` for a slot in its prompt that has the
+    first ``held`` whole blocks already (its own or adopted): the walk
+    and the chain start behind them, and a window that reaches back
+    into them is whole whatever the store still pins there."""
+    pools = [_BlockPool(40), _BlockPool(40)]
+    store = _PrefixStore(pools, BS, (None, WINDOW))
+    prompt = list(range(43))
+    pid = 0
+    for j in range(6):
+        pair = [pools[0].alloc(), pools[1].alloc()]
+        pid = store.register(pid, prompt[j * BS:(j + 1) * BS], pair)
+        pools[0].deref(pair[0])
+        pools[1].deref(pair[1])
+    whole, last, _ = store.match(prompt)
+    for j in gone:
+        e = (whole + [last])[j]
+        for other in list(store._lru[1].values()):
+            if other is not e:
+                store._lru[1].move_to_end(other[0])
+        assert store.evict_one(1) and e[2][1] == 0
+    assert store.holds((whole[held - 1][0],
+                        tuple(prompt[held * BS:(held + 1) * BS])))
+    chain, got_tail, was_cut = store.match(
+        prompt, (held, whole[held - 1][0]))
+    assert chain == whole[held:held + blocks]
+    assert (got_tail is not None, was_cut) == (tail, cut)
+    at = min(43 - 1, 43 if got_tail is not None
+             else (held + len(chain)) * BS)
+    hit = chain + ([got_tail] if got_tail is not None else [])
+    assert all(e[2][1] for e in hit[max(
+        store.first_needed(1, at) - held, 0):])
+
+
 def test_engine_counts_a_hit_it_had_to_cut(ref):
     """P's window-class pins are evicted under it (as a full window
     class does): a repeat of P finds its chain whole in the full class

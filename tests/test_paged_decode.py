@@ -944,3 +944,183 @@ def test_a_fetch_that_raises_fails_its_rows_and_no_others():
         assert eng.stats()["errors"] == 1
     finally:
         eng.close()
+
+
+# ---------------------------------------------------------------------------
+# a slot in its prompt is held to the prefix cache on every tick
+# ---------------------------------------------------------------------------
+BURST_CHUNK, BURST_KV_MAX, BURST_PREFIX = 4, 64, 32     # 4 whole blocks
+# rehearsal widths of the other served architectures (their own test
+# files' widths): a state leaf beside the pool (lfm2_moe), two classes
+# of block with a window of 16 keys (cohere2_moe), two token leaves on
+# one table (deepseek_v32)
+BURST_SPECS = {
+    "deepseek_v32": dict(DS_SPEC, arch="deepseek_v32", index_n_heads=4,
+                         index_head_dim=8, index_topk=6),
+    "lfm2_moe": {
+        "arch": "lfm2_moe", "num_hidden_layers": 5, "num_dense_layers": 1,
+        "layer_types": ["conv", "full_attention", "conv", "conv",
+                        "full_attention"],
+        "hidden_size": 64, "num_attention_heads": 8,
+        "num_key_value_heads": 2, "head_dim": 8, "intermediate_size": 96,
+        "moe_intermediate_size": 32, "num_experts": 8,
+        "num_experts_per_tok": 2, "conv_L_cache": 3, "vocab_size": 96,
+        "norm_eps": 1e-5, "rope_theta": 1e6,
+        "routed_scaling_factor": 1.0},
+    "cohere2_moe": {
+        "arch": "cohere2_moe", "num_hidden_layers": 4,
+        "layer_types": ["sliding_attention", "sliding_attention",
+                        "sliding_attention", "full_attention"],
+        "hidden_size": 64, "num_attention_heads": 8,
+        "num_key_value_heads": 2, "head_dim": 8, "intermediate_size": 32,
+        "num_experts": 4, "router_width": 16, "num_experts_per_tok": 4,
+        "num_shared_experts": 2, "sliding_window": 16, "vocab_size": 96,
+        "layer_norm_eps": 1e-5, "rope_theta": 50000.0,
+        "logit_scale": 0.5}}
+
+
+@functools.lru_cache(maxsize=None)
+def _burst_registry(arch, pool_blocks=29, **kwargs):
+    """One warmed paged registry an architecture: ONE bucket of 8
+    slots, so a chunk dispatch has ``chunk_rows(8)`` = 4 rows, and a
+    pool of 28 blocks, which holds four requests of the burst below
+    that share nothing (7 blocks each) and all eight once they share
+    their prefix."""
+    import importlib
+    reg = ModelRegistry()
+    kw = dict(batch_buckets=(8,), prompt_buckets=(8,), kv_block=KV_BLOCK,
+              kv_max=BURST_KV_MAX, paged=True, prefill_chunk=BURST_CHUNK,
+              sample="graph", pool_blocks=pool_blocks)
+    kw.update(kwargs)
+    if arch == "transformer_lm":
+        reg.add_generative_model("m", PARAMS, SPEC, **kw)
+    else:
+        spec = DS_SPEC if arch == "deepseek_v3" else BURST_SPECS[arch]
+        mod = importlib.import_module("mxnet_tpu.models." + arch)
+        reg.add_generative_model(
+            "m", mod.random_params(mod.serving_spec(spec), seed=5), spec,
+            **kw)
+    return reg
+
+
+def _burst_requests(seed, vocab, n=8, **kw):
+    """``n`` requests that open with one prefix of four whole blocks
+    and go on with 3 to 10 tokens of their own (distinct first own
+    tokens: nothing else is shared)."""
+    rs = np.random.RandomState(seed)
+    prefix = [int(t) for t in rs.randint(0, vocab, BURST_PREFIX)]
+    return [dict(tokens=prefix + [i] + [int(t) for t in rs.randint(
+        0, vocab, 2 + i)], max_tokens=4, **kw) for i in range(n)]
+
+
+def _submit_at_once(eng, reqs):
+    """Every request is in the engine's queue before it admits one."""
+    import threading
+    gate, admit = threading.Event(), eng._admit_ready
+
+    def gated():
+        gate.wait(60)
+        admit()
+
+    eng._admit_ready = gated
+    futs = [eng.submit("m", **kw) for kw in reqs]
+    gate.set()
+    return futs
+
+
+def _assert_only_pins_left(st):
+    """No slot holds or reserves a block: what the pool still has
+    allocated is what the prefix cache pins, once each."""
+    assert not st.tables.any() and not st.resv.any()
+    for c, pool in enumerate(st.pool_of):
+        assert st.reserved(c) == 0
+        assert pool.shared() == 0
+        assert pool.used() == pool.pinned_once() == len(pool._pinned)
+
+
+@pytest.mark.parametrize("arch", ["transformer_lm", "deepseek_v3",
+                                  "deepseek_v32", "lfm2_moe",
+                                  "cohere2_moe"])
+def test_a_burst_over_one_new_prefix_prefills_it_once(arch):
+    """Eight requests over one prefix the engine has not seen, all
+    submitted at once, a chunk of 4 rows and a pool that holds four
+    such requests unshared: the oldest slot writes each block of the
+    prefix, the slots that need the same block wait for it and adopt it
+    the tick after (and with it what the store has learned since they
+    were admitted), and their reservations shrink as they do, which
+    lets the rest of the queue in.  Every stream equals the request's
+    served alone; the prefix is computed once, not once a slot; nothing
+    is left held or reserved."""
+    from mxnet_tpu import profiler
+    reg = _burst_registry(arch)
+    store = reg.gen_store("m")
+    assert store.chunk_rows(8) == 4
+    reqs = _burst_requests(11, store.spec["vocab_size"])
+    want = [_generate(reg, [kw])[0] for kw in reqs]
+
+    eng = GenerationEngine(reg)
+    opened = profiler.phase_totals()
+    try:
+        got = [f.result(300).tokens
+               for f in _submit_at_once(eng, reqs)]
+        stats = eng.stats()
+        spans = profiler.phase_totals(since=opened)
+        st = eng._states["m"]
+        _assert_only_pins_left(st)
+        assert [seq for _m, seq in eng._admit_log] == list(range(8))
+    finally:
+        eng.close()
+    assert got == want
+    own = sum(-(-(len(kw["tokens"]) - BURST_PREFIX) // BURST_CHUNK)
+              for kw in reqs)
+    assert stats["prefill_chunks"] <= BURST_PREFIX // BURST_CHUNK + own
+    # the seven followers took the prefix from the store: what of it
+    # was there when they were admitted counts as a hit, the rest late
+    assert stats["prefix_late_tokens"] > 0
+    assert stats["prefix_late_tokens"] + stats["prefix_hit_tokens"] \
+        == 7 * BURST_PREFIX
+    assert stats["prefix_late_blocks"] + stats["prefix_hit_blocks"] \
+        == 7 * BURST_PREFIX // KV_BLOCK
+    assert stats["prefill_rows_waited"] > 0
+    counts = spans["serve_prepare"]["counts"]
+    assert counts["late_tokens"] == stats["prefix_late_tokens"]
+    assert counts["late_blocks"] == stats["prefix_late_blocks"]
+    assert counts["waited"] == stats["prefill_rows_waited"]
+    assert stats["errors"] == stats["shed"] == 0
+
+
+def test_waiters_outlive_the_writer_of_their_block():
+    """The chunk dispatch in which the oldest slot is halfway through
+    the shared prefix fails: the rows it worked for get the error, the
+    slots that waited on the writer's block were not in it, and the
+    oldest of them writes the block the tick after; their streams are
+    what they are alone."""
+    reg = _burst_registry("transformer_lm")
+    store = reg.gen_store("m")
+    reqs = _burst_requests(12, store.spec["vocab_size"], n=6)
+    want = [_generate(reg, [kw])[0] for kw in reqs]
+    eng = GenerationEngine(reg)
+    run, calls = store.run_paged_chunk_sample, []
+
+    def flaky(*a, **kw):
+        calls.append(1)
+        if len(calls) == 4:     # the writer is in its second block
+            raise RuntimeError("lost the device")
+        return run(*a, **kw)
+
+    store.run_paged_chunk_sample = flaky
+    try:
+        futs = _submit_at_once(eng, reqs)
+        with pytest.raises(MXNetError, match="prefill dispatch failed"):
+            futs[0].result(300)
+        got = [f.result(300).tokens for f in futs[1:]]
+        stats = eng.stats()
+        _assert_only_pins_left(eng._states["m"])
+    finally:
+        store.run_paged_chunk_sample = run
+        eng.close()
+    assert got == want[1:]
+    # the writer was alone in that dispatch: no one else saw the error
+    assert stats["errors"] == 1 and stats["finished"] == 5
+    assert stats["prefill_rows_waited"] > 0
+    assert stats["prefix_late_tokens"] > 0

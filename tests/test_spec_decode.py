@@ -301,6 +301,61 @@ def test_spec_probe_rebuilds_lazily_mirrored_draft(monkeypatch):
     assert st2["spec_fallback_steps"] > st["spec_fallback_steps"]
 
 
+@pytest.mark.parametrize("mirror", [True, False],
+                         ids=["mirror-on", "mirror-off"])
+def test_spec_draft_frontier_follows_a_late_adoption(mirror, monkeypatch):
+    """Four requests over one new prefix, submitted at once: the
+    followers adopt its blocks in the tick, after admission.  With the
+    prefill mirror on, the adopted blocks hold the draft's rows too
+    (the writer's chunks were mirrored) and the draft's frontier moves
+    with the target's; with the mirror off (the fallback regime) the
+    draft claims nothing of them, and a probe's catch-up rebuilds from
+    the prompt.  Either way the streams are the undrafted engine's."""
+    from mxnet_tpu.serving import decode_engine as de
+    from test_paged_decode import _submit_at_once
+    monkeypatch.setattr(de, "_SPEC_PROBE_EVERY", 4)
+    rs = np.random.RandomState(5)
+    prefix = [int(t) for t in rs.randint(0, 50, 24)]    # 3 whole blocks
+    reqs = [dict(tokens=prefix + [i, 9 - i], max_tokens=10, seed=i)
+            for i in range(4)]
+    base, _ = _run(None, reqs=reqs)
+    reg = ModelRegistry()
+    reg.add_generative_model("m", PARAMS, SPEC, **KW)
+    if mirror:
+        reg.add_draft_model("m", PARAMS, SPEC, spec_k=3)
+    else:
+        reg.add_draft_model("m", DPARAMS, DSPEC, spec_k=3)
+    eng = GenerationEngine(reg)
+    adopt, seen = eng._adopt_late, []
+
+    def spy(st, i):
+        was, held = int(st.dlen[i]), int(st.reg_n[i]) * KW["kv_block"]
+        got = adopt(st, i)
+        if got[0]:
+            seen.append((st.spec_mirror(), min(was, held),
+                         int(st.dlen[i]), int(st.prog[i])))
+        return got
+
+    eng._adopt_late = spy
+    try:
+        if not mirror:
+            # a draft whose proposals never survive: the EMA collapses
+            # and the mirror goes off before the burst arrives
+            eng.submit("m", [7, 3, 11, 29, 4], max_tokens=24).result(180)
+            assert eng.stats()["models"]["m"]["spec_acceptance_ema"] \
+                < 0.125
+        toks = [f.result(180).tokens for f in _submit_at_once(eng, reqs)]
+        st = eng.stats()
+    finally:
+        eng.close()
+    assert toks == base
+    assert st["prefix_late_tokens"] > 0 and seen
+    for on, kept, dlen, prog in seen:
+        assert on == mirror
+        assert dlen == (prog if mirror else kept)
+    assert st["spec_steps"] > 0
+
+
 def test_spec_env_gating(monkeypatch):
     """MXNET_SERVE_SPEC=0 disables speculative decoding even with a
     draft attached — the engine runs plain paged decode, streams
