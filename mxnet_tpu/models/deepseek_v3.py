@@ -54,8 +54,8 @@ __all__ = ["serving_spec", "param_shapes", "random_params",
            "required_params", "matmul_weights", "pack_params",
            "quantize_params", "init_pool", "latent_width",
            "paged_step_apply", "paged_step_leaves", "paged_step",
-           "rope_frequencies",
-           "softmax_scale", "OFFERS", "AUX_COUNTERS"]
+           "rope_frequencies", "softmax_scale", "OFFERS",
+           "AUX_COUNTERS", "QUANTIZE_TAKES_LEAVES"]
 
 # what of the serving plane this model can be put on besides the paged
 # plane with in-graph or host sampling (program_store asks)
@@ -64,6 +64,9 @@ OFFERS = frozenset()
 AUX_COUNTERS = ("moe_tokens", "moe_local_assignments",
                 "moe_expert_load_max", "moe_expert_steps",
                 "moe_experts_touched", "moe_expert_streams")
+# ``quantize_params`` frees each plain leaf as its codes are made: the
+# store hands it the caller's only references (program_store asks)
+QUANTIZE_TAKES_LEAVES = True
 
 _INT_KEYS = ("num_hidden_layers", "first_k_dense_replace", "hidden_size",
              "num_attention_heads", "q_lora_rank", "kv_lora_rank",
@@ -92,11 +95,13 @@ def serving_spec(spec):
         "routed_scaling_factor", 1.0))
     out["rms_norm_eps"] = float(spec.get("rms_norm_eps", 1e-6))
     out["rope_theta"] = float(spec.get("rope_theta", 10000.0))
-    rope = dict(spec["rope_scaling"])
-    if [k for k in _ROPE_KEYS if k not in rope]:
+    # None: plain rotary, no YaRN (a model over this one says so)
+    rope = spec["rope_scaling"]
+    if rope is not None and [k for k in _ROPE_KEYS if k not in rope]:
         raise MXNetError("deepseek_v3 rope_scaling needs %s"
                          % (_ROPE_KEYS,))
-    out["rope_scaling"] = {k: float(rope[k]) for k in _ROPE_KEYS}
+    out["rope_scaling"] = None if rope is None else {
+        k: float(rope[k]) for k in _ROPE_KEYS}
     if out["router_width"] % out["n_group"] or \
             not 0 < out["n_routed_experts"] <= out["router_width"]:
         raise MXNetError(
@@ -119,10 +124,22 @@ def _is_dense(spec, i):
     return i < spec["first_k_dense_replace"]
 
 
+def layer_prefixes(spec):
+    """``(prefix of its leaves' names, dense?)`` of every decoder layer
+    whose leaves the spec holds: ``l<i>_`` and, behind them, ``mtp_``:
+    the prediction module's one expert layer, for a spec that loads it
+    (``draft_layers``: ``models/pangu_ultra_moe.py``)."""
+    out = [("l%d_" % i, _is_dense(spec, i))
+           for i in range(spec["num_hidden_layers"])]
+    return out + [("mtp_", False)] * bool(spec.get("draft_layers"))
+
+
 def param_shapes(spec):
     """name -> shape of the checkpoint's leaves: every matrix ``(out,
     in)``, each routed expert's three matrices leaves of their own
-    (``l<i>_e<j>_gate_weight`` ...; :func:`pack_params` stacks them)."""
+    (``l<i>_e<j>_gate_weight`` ...; :func:`pack_params` stacks them).
+    A spec with ``sandwich_norm`` has two more norms a layer; one
+    without ``router_bias`` (False) no correction bias."""
     D, H = spec["hidden_size"], spec["num_attention_heads"]
     rq, r = spec["q_lora_rank"], spec["kv_lora_rank"]
     dn, dr, dv = (spec["qk_nope_head_dim"], spec["qk_rope_head_dim"],
@@ -131,8 +148,7 @@ def param_shapes(spec):
     out = {"embed_weight": (spec["vocab_size"], D),
            "final_norm_gamma": (D,),
            "head_weight": (spec["vocab_size"], D)}
-    for i in range(spec["num_hidden_layers"]):
-        p = "l%d_" % i
+    for p, dense in layer_prefixes(spec):
         out.update({
             p + "attn_norm_gamma": (D,), p + "q_a_weight": (rq, D),
             p + "q_norm_gamma": (rq,),
@@ -140,17 +156,21 @@ def param_shapes(spec):
             p + "kv_a_weight": (r + dr, D), p + "kv_norm_gamma": (r,),
             p + "kv_b_weight": (H * (dn + dv), r),
             p + "o_weight": (D, H * dv), p + "ffn_norm_gamma": (D,)})
-        if _is_dense(spec, i):
+        if spec.get("sandwich_norm"):
+            out.update({p + "post_attn_norm_gamma": (D,),
+                        p + "post_ffn_norm_gamma": (D,)})
+        if dense:
             I = spec["intermediate_size"]
             out.update({p + "gate_weight": (I, D), p + "up_weight": (I, D),
                         p + "down_weight": (D, I)})
             continue
         S = F * spec["n_shared_experts"]
         out.update({p + "router_weight": (spec["router_width"], D),
-                    p + "router_bias": (spec["router_width"],),
                     p + "shared_gate_weight": (S, D),
                     p + "shared_up_weight": (S, D),
                     p + "shared_down_weight": (D, S)})
+        if spec.get("router_bias", True):
+            out[p + "router_bias"] = (spec["router_width"],)
         for e in range(spec["n_routed_experts"]):
             q = "%se%d_" % (p, e)
             out.update({q + "gate_weight": (F, D), q + "up_weight": (F, D),
@@ -158,18 +178,18 @@ def param_shapes(spec):
     return out
 
 
-def _packed(spec, i):
-    return ("l%d_experts_gate_up" % i, "l%d_experts_down" % i)
+def _packed(prefix):
+    return (prefix + "experts_gate_up", prefix + "experts_down")
 
 
 def required_params(spec):
     """The leaves a step reads: the checkpoint's, with each expert
     layer's routed experts as the two stacks of :func:`pack_params`."""
     names = [n for n in param_shapes(spec)
-             if not re.match(r"l\d+_e\d+_", n)]
-    for i in range(spec["num_hidden_layers"]):
-        if not _is_dense(spec, i):
-            names += _packed(spec, i)
+             if not re.match(r"(l\d+|mtp)_e\d+_", n)]
+    for prefix, dense in layer_prefixes(spec):
+        if not dense:
+            names += _packed(prefix)
     return names
 
 
@@ -200,11 +220,11 @@ def pack_params(params, spec):
         return jnp.stack([w.T for w in ws])
 
     E = spec["n_routed_experts"]
-    for i in range(spec["num_hidden_layers"]):
-        gu, down = _packed(spec, i)
-        if _is_dense(spec, i) or gu in params:
+    for prefix, dense in layer_prefixes(spec):
+        gu, down = _packed(prefix)
+        if dense or gu in params:
             continue
-        names = ["l%d_e%d_%%s_weight" % (i, e) for e in range(E)]
+        names = ["%se%d_%%s_weight" % (prefix, e) for e in range(E)]
         gate = stack_t(*[params.pop(n % "gate") for n in names])
         up = stack_t(*[params.pop(n % "up") for n in names])
         params[gu] = jnp.concatenate([gate, up], axis=2)
@@ -229,7 +249,14 @@ def quantize_params(params, spec):
 
 
 def quantize_leaves(params, which):
-    """:func:`quantize_params` of the leaves named ``which``."""
+    """:func:`quantize_params` of the leaves named ``which``.  TAKES
+    the leaves out of ``params`` as it goes and waits for each: a leaf's
+    plain values are freed before the next leaf's codes are made, so a
+    model whose plain leaves nearly fill the chip (12.1 GB of
+    ``pangu_ultra_moe``'s 16) can still be loaded as 6 GB of codes
+    (:data:`QUANTIZE_TAKES_LEAVES`: the store empties the caller's dict
+    for such a model, as :func:`pack_params` empties it of what it
+    restacks)."""
     import jax
     import jax.numpy as jnp
     from ..pallas_ops.dequant_matmul import QuantizedWeight
@@ -244,8 +271,14 @@ def quantize_leaves(params, which):
         return codes, (scale[:, 0] if w.ndim == 2 else scale)
 
     which = set(which)
-    return {k: QuantizedWeight(*quant(jnp.asarray(v))) if k in which
-            else v for k, v in params.items()}
+    out = {}
+    for k in list(params):
+        v = params.pop(k)
+        if k in which:
+            v = QuantizedWeight(*jax.block_until_ready(
+                quant(jnp.asarray(v))))
+        out[k] = v
+    return out
 
 
 def _plain(w, dtype):
@@ -313,8 +346,10 @@ def rope_frequencies(spec):
     2,)`` float32."""
     dim = spec["qk_rope_head_dim"]
     base, sc = spec["rope_theta"], spec["rope_scaling"]
-    orig = sc["original_max_position_embeddings"]
     freqs = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if sc is None:
+        return freqs.astype(np.float32)
+    orig = sc["original_max_position_embeddings"]
 
     def correction(rotations):
         return dim * math.log(orig / (rotations * 2 * math.pi)) / (
@@ -331,10 +366,13 @@ def rope_frequencies(spec):
 
 
 def softmax_scale(spec):
-    """``(nope + rope)^-0.5 m^2``, ``m = 0.1 ln(factor) + 1``."""
+    """``(nope + rope)^-0.5 m^2``, ``m = 0.1 ln(factor) + 1`` (1 for
+    a spec without ``rope_scaling``)."""
+    scale = (spec["qk_nope_head_dim"] + spec["qk_rope_head_dim"]) ** -0.5
+    if spec["rope_scaling"] is None:
+        return scale
     m = 0.1 * math.log(spec["rope_scaling"]["factor"]) + 1.0
-    return (spec["qk_nope_head_dim"] + spec["qk_rope_head_dim"]) ** -0.5 \
-        * m * m
+    return scale * m * m
 
 
 def _rope(x, cos, sin):
@@ -426,8 +464,11 @@ def expert_layer(f, p, spec, live, eps=0.0):
     from ..ops.moe import expert_streams, moe_experts, route_grouped
     f32, cdt = jnp.float32, f.dtype
     scores = jax.nn.sigmoid(_mm(f, p["router_weight"], f32))
+    # a router without a correction bias: a zero one changes no choice
+    bias = p["router_bias"].astype(f32) if "router_bias" in p \
+        else jnp.zeros((spec["router_width"],), f32)
     experts, weights = route_grouped(
-        scores, p["router_bias"].astype(f32),
+        scores, bias,
         spec["num_experts_per_tok"], spec["n_group"],
         spec["topk_group"], spec["routed_scaling_factor"], eps)
     y, per = moe_experts(
@@ -449,8 +490,134 @@ def paged_step_apply(params, pool, tables, tokens, positions, valid, spec,
     return logits, pool, counts
 
 
+class _Step:
+    """What every layer of one paged step shares: the write plan, the
+    live rows, the rotary angles, the softmax scale."""
+
+    def __init__(self, tables, shape, positions, valid, spec, block_size):
+        import jax.numpy as jnp
+        f32 = jnp.float32
+        self.spec, self.bs = spec, int(block_size)
+        self.B, self.Lq = B, Lq = shape
+        self.tables = jnp.asarray(tables, jnp.int32)
+        self.positions = jnp.asarray(positions, jnp.int32)
+        self.valid = jnp.asarray(valid, jnp.int32)
+        self.plan = write_plan(self.tables, self.positions, self.valid,
+                               Lq, self.bs)
+        self.rows = rows = jnp.arange(Lq, dtype=jnp.int32)
+        self.live = ((self.tables[:, :1] != 0)
+                     & (rows[None] < self.valid[:, None])).reshape(B * Lq)
+        angle = (self.positions[:, None] + rows[None]).astype(f32)[
+            ..., None] * jnp.asarray(rope_frequencies(spec))  # (B,Lq,dr/2)
+        self.cos, self.sin = jnp.cos(angle), jnp.sin(angle)
+        self.scale = softmax_scale(spec)
+        # the indexer's selection: how many positions a query keeps
+        self.sparse = "index_topk" in spec
+        if self.sparse:
+            self.keep = min(spec["index_topk"],
+                            self.tables.shape[1] * self.bs)
+            # positions a live query keeps: all it sees, up to ``keep``
+            self.kept = jnp.where(
+                self.live.reshape(B, Lq), jnp.minimum(
+                    self.positions[:, None] + rows[None] + 1, self.keep),
+                0)
+
+
+def decoder_layer(x, p, pools, layer, dense, st, first=0):
+    """One decoder layer of a paged step over ``x`` ``(B, Lq, D)`` fp32:
+    ``p`` the layer's leaves (their names without the layer's prefix),
+    ``layer`` its index on the pool leaves' first axis, ``st`` the
+    step's :class:`_Step`.  Writes the chunk's fresh rows of every leaf
+    and attends (keys before ``first`` seen by no query).  A spec with
+    ``sandwich_norm`` norms each sublayer's OUTPUT too before it joins
+    the residual (``post_attn_norm_gamma``, ``post_ffn_norm_gamma``).
+    Returns ``(x, pools, the layer's AUX_COUNTERS or None)``."""
+    import jax.numpy as jnp
+    from ..ops import attention as _att
+
+    spec, bs = st.spec, st.bs
+    B, Lq = st.B, st.Lq
+    N, D = B * Lq, spec["hidden_size"]
+    H = spec["num_attention_heads"]
+    r, dn, dr, dv = (spec["kv_lora_rank"], spec["qk_nope_head_dim"],
+                     spec["qk_rope_head_dim"], spec["v_head_dim"])
+    eps = spec["rms_norm_eps"]
+    sandwich = spec.get("sandwich_norm", False)
+    f32 = jnp.float32
+    cdt = p["attn_norm_gamma"].dtype            # the weights' dtype
+    cos, sin = st.cos, st.sin
+    pool = pools[0]
+    W = pool.shape[3]
+
+    h = _rms(x, p["attn_norm_gamma"], eps).astype(cdt).reshape(N, D)
+    cq = _rms(_mm(h, p["q_a_weight"]), p["q_norm_gamma"], eps)
+    q = _mm(cq.astype(cdt), p["q_b_weight"]).reshape(B, Lq, H, dn + dr)
+    kv = _mm(h, p["kv_a_weight"]).astype(f32).reshape(B, Lq, r + dr)
+    latent = jnp.concatenate(
+        [_rms(kv[..., :r], p["kv_norm_gamma"], eps),
+         _rope(kv[..., r:], cos, sin),
+         jnp.zeros((B, Lq, W - r - dr), f32)], axis=-1)
+    fresh = (latent[:, None],)
+    if st.sparse:
+        qi, ki, wi = _index_parts(h, cq, p, spec, (B, Lq), cos, sin)
+        fresh += (ki[:, None],)
+    pools = pool_write(pools, layer, fresh, st.plan, bs)
+    pool = pools[0]
+    wkv = _plain(p["kv_b_weight"], cdt).reshape(H, dn + dv, r)
+    q_abs = jnp.einsum("blhd,hdc->bhlc", q[..., :dn], wkv[:, :dn],
+                       preferred_element_type=f32)
+    q_rope = _rope(q[..., dn:].astype(f32), cos[:, :, None],
+                   sin[:, :, None])
+    query = jnp.concatenate(
+        [q_abs, jnp.transpose(q_rope, (0, 2, 1, 3)),
+         jnp.zeros((B, H, Lq, W - r - dr), f32)], axis=-1)
+    if st.sparse:
+        scores = _att.lightning_index_scores(
+            qi.astype(pools[1].dtype), wi, pools[1], layer, st.tables,
+            st.positions, bs)
+        thr, tie = _att.sparse_select(scores, st.keep)
+        o_lat = _att.mla_attention_sparse(
+            query.astype(pool.dtype), pool, layer, st.tables,
+            st.positions, scores, thr, tie, st.kept, st.keep, bs, r,
+            st.scale)
+    else:
+        o_lat = _att.mla_attention_paged(
+            query.astype(pool.dtype), pool, layer, st.tables,
+            st.positions, bs, r, st.scale, first=first)
+    o = jnp.einsum("bhlc,hdc->blhd", o_lat.astype(cdt), wkv[:, dn:],
+                   preferred_element_type=f32)
+    a = _mm(o.astype(cdt).reshape(N, H * dv), p["o_weight"]) \
+        .astype(f32).reshape(B, Lq, D)
+    if sandwich:
+        a = _rms(a, p["post_attn_norm_gamma"], eps)
+    x = x + a
+
+    f = _rms(x, p["ffn_norm_gamma"], eps).astype(cdt).reshape(N, D)
+    step = None
+    if dense:
+        y = _swiglu_ffn(f, p["gate_weight"], p["up_weight"],
+                        p["down_weight"])
+    else:
+        y, step = expert_layer(f, p, spec, st.live)
+        y = y + _swiglu_ffn(f, p["shared_gate_weight"],
+                            p["shared_up_weight"],
+                            p["shared_down_weight"])
+    y = y.reshape(B, Lq, D)
+    if sandwich:
+        # on the chip's PARTIAL sum (shared expert + held experts): in
+        # a deployment this norm follows the exchange's combine
+        y = _rms(y, p["post_ffn_norm_gamma"], eps)
+    return x + y, pools, step
+
+
+def layer_leaves(params, prefix):
+    """The leaves of ``params`` under ``prefix``, named without it."""
+    return {k[len(prefix):]: v for k, v in params.items()
+            if k.startswith(prefix)}
+
+
 def paged_step_leaves(params, pools, tables, tokens, positions, valid,
-                      spec, block_size, all_logits=False):
+                      spec, block_size, all_logits=False, hidden=False):
     """One PAGED step over the latent pool — ``transformer_lm.
     paged_step_apply``'s contract: tokens ``(B, Lq)`` (``Lq = 1`` a
     decode step), positions/valid ``(B,)``, tables ``(B, T)`` over the
@@ -475,106 +642,38 @@ def paged_step_leaves(params, pools, tables, tokens, positions, valid,
     fullest held expert's count summed over the layers, expert layers,
     held experts that got a token summed over the layers, and how
     often the grouped product streamed an expert's weights for them
-    (``ops/moe.expert_streams``; once a touched expert is the floor)."""
+    (``ops/moe.expert_streams``; once a touched expert is the floor).
+    ``hidden``: a fourth result, the last layer's output BEFORE the
+    final norm, ``(B, Lq, D)`` fp32 (what a prediction module drafts
+    from: ``models/pangu_ultra_moe.py``)."""
     import jax.numpy as jnp
-    from ..ops import attention as _att
 
     pools = tuple(pools)
-    pool = pools[0]
-    # the indexer's selection: how many positions a query keeps
-    sparse = "index_topk" in spec
     L, D = spec["num_hidden_layers"], spec["hidden_size"]
-    H = spec["num_attention_heads"]
-    r, dn, dr, dv = (spec["kv_lora_rank"], spec["qk_nope_head_dim"],
-                     spec["qk_rope_head_dim"], spec["v_head_dim"])
-    eps = spec["rms_norm_eps"]
-    bs = int(block_size)
     B, Lq = tokens.shape
     N = B * Lq
-    W = pool.shape[3]
     f32 = jnp.float32
     cdt = params["final_norm_gamma"].dtype      # the weights' dtype
-    tables = jnp.asarray(tables, jnp.int32)
-    positions = jnp.asarray(positions, jnp.int32)
-    valid = jnp.asarray(valid, jnp.int32)
-    plan = write_plan(tables, positions, valid, Lq, bs)
-    rows = jnp.arange(Lq, dtype=jnp.int32)
-    live = ((tables[:, :1] != 0) & (rows[None] < valid[:, None])) \
-        .reshape(N)
-    angle = (positions[:, None] + rows[None]).astype(f32)[..., None] \
-        * jnp.asarray(rope_frequencies(spec))                # (B, Lq, dr/2)
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    scale = softmax_scale(spec)
+    st = _Step(tables, (B, Lq), positions, valid, spec, block_size)
     counts = jnp.zeros((len(AUX_COUNTERS),), jnp.int32)
-    if sparse:
-        keep = min(spec["index_topk"], tables.shape[1] * bs)
-        # positions a live query keeps: all it sees, up to ``keep``
-        kept = jnp.where(
-            live.reshape(B, Lq),
-            jnp.minimum(positions[:, None] + rows[None] + 1, keep), 0)
 
     x = _embed(params["embed_weight"], tokens).astype(f32)   # (B, Lq, D)
     for i in range(L):
-        p = {k[len("l%d_" % i):]: v for k, v in params.items()
-             if k.startswith("l%d_" % i)}
-        h = _rms(x, p["attn_norm_gamma"], eps).astype(cdt).reshape(N, D)
-        cq = _rms(_mm(h, p["q_a_weight"]), p["q_norm_gamma"], eps)
-        q = _mm(cq.astype(cdt), p["q_b_weight"]).reshape(B, Lq, H, dn + dr)
-        kv = _mm(h, p["kv_a_weight"]).astype(f32).reshape(B, Lq, r + dr)
-        latent = jnp.concatenate(
-            [_rms(kv[..., :r], p["kv_norm_gamma"], eps),
-             _rope(kv[..., r:], cos, sin),
-             jnp.zeros((B, Lq, W - r - dr), f32)], axis=-1)
-        fresh = (latent[:, None],)
-        if sparse:
-            qi, ki, wi = _index_parts(h, cq, p, spec, (B, Lq), cos, sin)
-            fresh += (ki[:, None],)
-        pools = pool_write(pools, i, fresh, plan, bs)
-        pool = pools[0]
-        wkv = _plain(p["kv_b_weight"], cdt).reshape(H, dn + dv, r)
-        q_abs = jnp.einsum("blhd,hdc->bhlc", q[..., :dn], wkv[:, :dn],
-                           preferred_element_type=f32)
-        q_rope = _rope(q[..., dn:].astype(f32), cos[:, :, None],
-                       sin[:, :, None])
-        query = jnp.concatenate(
-            [q_abs, jnp.transpose(q_rope, (0, 2, 1, 3)),
-             jnp.zeros((B, H, Lq, W - r - dr), f32)], axis=-1)
-        if sparse:
-            scores = _att.lightning_index_scores(
-                qi.astype(pools[1].dtype), wi, pools[1], i, tables,
-                positions, bs)
-            thr, tie = _att.sparse_select(scores, keep)
-            o_lat = _att.mla_attention_sparse(
-                query.astype(pool.dtype), pool, i, tables, positions,
-                scores, thr, tie, kept, keep, bs, r, scale)
-        else:
-            o_lat = _att.mla_attention_paged(
-                query.astype(pool.dtype), pool, i, tables, positions, bs,
-                r, scale)
-        o = jnp.einsum("bhlc,hdc->blhd", o_lat.astype(cdt), wkv[:, dn:],
-                       preferred_element_type=f32)
-        x = x + _mm(o.astype(cdt).reshape(N, H * dv), p["o_weight"]) \
-            .astype(f32).reshape(B, Lq, D)
-
-        f = _rms(x, p["ffn_norm_gamma"], eps).astype(cdt).reshape(N, D)
-        if _is_dense(spec, i):
-            y = _swiglu_ffn(f, p["gate_weight"], p["up_weight"],
-                            p["down_weight"])
-        else:
-            y, step = expert_layer(f, p, spec, live)
-            y = y + _swiglu_ffn(f, p["shared_gate_weight"],
-                                p["shared_up_weight"],
-                                p["shared_down_weight"])
+        x, pools, step = decoder_layer(
+            x, layer_leaves(params, "l%d_" % i), pools, i,
+            _is_dense(spec, i), st)
+        if step is not None:
             counts = counts + step
-        x = x + y.reshape(B, Lq, D)
-    hN = _rms(x, params["final_norm_gamma"], eps).astype(cdt)
+    hN = _rms(x, params["final_norm_gamma"], spec["rms_norm_eps"]) \
+        .astype(cdt)
     if all_logits:
         logits = _mm(hN.reshape(N, D), params["head_weight"], f32).reshape(
             B, Lq, spec["vocab_size"])
     else:
-        logits = _mm(hN[jnp.arange(B), valid - 1],
+        logits = _mm(hN[jnp.arange(B), st.valid - 1],
                      params["head_weight"], f32)
-    return logits.astype(f32), pools, counts
+    out = (logits.astype(f32), pools, counts)
+    return out + (x,) if hidden else out
 
 
 def paged_step(params, pools, tables, tokens, positions, valid, spec,

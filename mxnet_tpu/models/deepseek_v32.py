@@ -29,8 +29,8 @@ from __future__ import annotations
 
 from ..base import MXNetError
 from . import deepseek_v3 as _v3
-from .deepseek_v3 import (AUX_COUNTERS, OFFERS, pack_params,  # noqa: F401
-                          paged_step)
+from .deepseek_v3 import (AUX_COUNTERS, OFFERS,  # noqa: F401
+                          QUANTIZE_TAKES_LEAVES, pack_params, paged_step)
 
 __all__ = ["serving_spec", "param_shapes", "random_params",
            "required_params", "matmul_weights", "pack_params",

@@ -144,12 +144,13 @@ def sdp_attention_paged(query, k_pool, v_pool, layer, tables, positions,
 
 
 def mla_attention_paged(query, pool, layer, tables, positions,
-                        block_size, rank, scale):
+                        block_size, rank, scale, first=0):
     """Paged multi-head LATENT attention in the absorbed form: ``[B, H,
     Lq, D]`` queries ``[q_abs | q_rope]`` against layer ``layer`` (a
     static int) of the whole stacked latent pool ``(L, 1, num_blocks *
     block_size, D)``, whose row is key and — its first ``rank`` values
     — value for every head alike.  Returns ``o_lat [B, H, Lq, rank]``.
+    ``first`` (static): the lowest position a query sees (0: all).
 
     Eligible shapes route to ``mla_paged_attention`` (all heads of a
     sequence in one Q tile, a latent tile fetched once a sequence);
@@ -163,9 +164,9 @@ def mla_attention_paged(query, pool, layer, tables, positions,
                          tables.shape[1] * bs, d, rank, query.dtype, bs):
         return _mla.mla_paged_attention(
             query, pool, layer, tables, positions, bs, rank, scale,
-            interpret=_pd.interpret_mode())
+            interpret=_pd.interpret_mode(), first=first)
     return _mla.mla_attention_reference(query, pool, layer, tables,
-                                        positions, bs, rank, scale)
+                                        positions, bs, rank, scale, first)
 
 
 def lightning_index_scores(q, w, pool, layer, tables, positions,

@@ -50,10 +50,11 @@ _NEG = -1e30  # flash_attention._NEG: shared mask constant for parity
 
 
 def _mla_kernel(tbl_ref, pos_ref, q_ref, *refs, scale, rank, lq, block_q,
-                block_size, group, nk):
+                block_size, group, nk, first):
     """One (sequence, Q tile, group of logical blocks) grid cell.  Row
     ``i`` of the flattened ``(H * Lq)`` query axis is head ``i // lq``,
-    query ``i % lq``, at global position ``pos[b] + i % lq``."""
+    query ``i % lq``, at global position ``pos[b] + i % lq``; keys
+    before position ``first`` (static) are seen by no query."""
     kv_refs = refs[:group]
     o_ref, m_ref, l_ref, acc_ref = refs[group:]
     b = pl.program_id(0)
@@ -86,6 +87,8 @@ def _mla_kernel(tbl_ref, pos_ref, q_ref, *refs, scale, rank, lq, block_q,
         kpos = ki * span + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, span), 1)
         seen = qpos >= kpos
+        if first:
+            seen = seen & (kpos >= first)
         s = jnp.where(seen, s, _NEG)
         m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -106,7 +109,7 @@ def _mla_kernel(tbl_ref, pos_ref, q_ref, *refs, scale, rank, lq, block_q,
 
 def mla_paged_attention(q, pool, layer, tables, positions, block_size,
                         rank, scale, block_q=512, group=8,
-                        interpret=None):
+                        interpret=None, first=0):
     """Absorbed-form latent attention against the PAGED latent pool.
 
     q: ``(B, H, Lq, D)`` — ``[q_abs | q_rope]``, query row r of
@@ -115,7 +118,10 @@ def mla_paged_attention(q, pool, layer, tables, positions, block_size,
     place at the static index ``layer``; tables ``(B, T)`` int32,
     positions ``(B,)`` int32 as for ``flash_attention_paged``.  Returns
     ``o_lat (B, H, Lq, rank)``: the softmax-weighted sum of the rows'
-    first ``rank`` values, to be up-projected by the caller."""
+    first ``rank`` values, to be up-projected by the caller.  ``first``
+    (static): the lowest position any query sees — 1 for a sequence
+    whose row 0 is never written (a prediction module's cache,
+    ``models/pangu_ultra_moe.py``); 0 traces what it always traced."""
     B, H, Lq, D = q.shape
     T = tables.shape[1]
     bs = int(block_size)
@@ -133,7 +139,8 @@ def mla_paged_attention(q, pool, layer, tables, positions, block_size,
 
     kernel = functools.partial(
         _mla_kernel, scale=float(scale), rank=rank, lq=Lq,
-        block_q=block_q, block_size=bs, group=group, nk=nk)
+        block_q=block_q, block_size=bs, group=group, nk=nk,
+        first=int(first))
     q_map = lambda b, i, j, *_: (b, i, 0)
 
     def kv_map(g):
@@ -162,7 +169,7 @@ def mla_paged_attention(q, pool, layer, tables, positions, block_size,
 
 
 def mla_attention_reference(q, pool, layer, tables, positions,
-                            block_size, rank, scale):
+                            block_size, rank, scale, first=0):
     """Dense XLA twin of :func:`mla_paged_attention`: gather layer
     ``layer``'s latent rows through the same block-table arithmetic,
     then masked softmax attention with the same ``-1e30`` constant and
@@ -181,7 +188,10 @@ def mla_attention_reference(q, pool, layer, tables, positions,
     qpos = pos[:, None, None] + jax.lax.broadcasted_iota(
         jnp.int32, (Lq, T * bs), 0)
     kpos = jax.lax.broadcasted_iota(jnp.int32, (Lq, T * bs), 1)
-    s = jnp.where((qpos >= kpos[None])[:, None], s, _NEG)
+    seen = qpos >= kpos[None]
+    if first:
+        seen = seen & (kpos[None] >= int(first))
+    s = jnp.where(seen[:, None], s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkd->bhqd", p.astype(kv.dtype),
                       kv[..., :int(rank)],

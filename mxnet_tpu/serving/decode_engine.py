@@ -682,6 +682,13 @@ class _PagedModelState:
         # independent per-slot PRNG chains
         self.draft = draft
         self.spec_k = int(spec_k)
+        # a SELF-drafting store: the proposal each generating slot
+        # carries to its next step (the module's, for the position
+        # after its pending token; -1: none), no second pool
+        self.self_draft = bool(store.self_draft)
+        self.prop = np.zeros(0, np.int32)
+        # the proposals the newest prompt chunk's fetch brought, a row
+        self.chunk_props = None
         if draft is not None:
             self.dpools = draft.new_pool()
             self.dscales = (draft.new_scale_pool() if draft.kv_int8
@@ -807,6 +814,9 @@ class _PagedModelState:
             # pool_bytes too: one pool, one allocator)
             d["state_rows_live"] = self.pool.used() * self.state_rows
             d["state_bytes"] = self.state_bytes
+        if self.self_draft:
+            d["spec_k"] = self.spec_k
+            d["self_draft"] = True
         if self.draft is not None:
             dbytes = sum(a.size * a.dtype.itemsize
                          for a in self.dpools + (self.dscales or ()))
@@ -948,7 +958,10 @@ class GenerationEngine:
              # (min(seen, index_topk) a query)
              "dsa_queries", "dsa_index_pairs", "dsa_keys_selected",
              "spec_steps", "spec_proposed", "spec_accepted",
-             "spec_draft_steps", "spec_fallback_steps"),
+             "spec_draft_steps", "spec_fallback_steps",
+             # rows a self-drafting model's prediction module wrote
+             # into its layer of the pool (prompt chunks and steps)
+             "draft_rows"),
             labels=self._mlabels, help="generation engine counter")
         self._g_inflight = _metrics.gauge(
             "serve_gen_inflight", labels=self._mlabels,
@@ -1487,30 +1500,11 @@ class GenerationEngine:
     def _paged_state(self, model, store):
         st = self._states.get(model)
         if st is None:
-            # speculative decoding gate, resolved ONCE at state
-            # creation: a draft attached via registry.add_draft_model
-            # + in-graph sampling + MXNET_SERVE_SPEC != 0.  Attach
-            # drafts before the model's first request — a draft added
-            # under traffic is picked up at the next engine (or the
-            # next state, once the engine restarts).
-            draft, spec_k = None, 0
-            spec = str(get_env("MXNET_SERVE_SPEC") or "auto").lower()
-            if spec not in ("0", "off", "false") \
-                    and store.sample_mode == "graph":
-                draft = getattr(self._registry, "draft_store",
-                                lambda _m: None)(model)
-                if draft is not None:
-                    # the window the draft's verify programs were
-                    # warmed for (add_draft_model's spec_k)
-                    spec_k = int(getattr(
-                        draft, "spec_k",
-                        int(get_env("MXNET_SERVE_SPEC_K"))))
+            draft, spec_k, forced = self._draft_gate(model, store)
             st = self._states[model] = _PagedModelState(
                 store, draft=draft, spec_k=spec_k)
             if draft is not None:
-                # auto (default) degrades to plain decode when the
-                # rolling acceptance collapses; on/force always drafts
-                st.spec_forced = spec in ("1", "on", "force", "always")
+                st.spec_forced = forced
             store.cache_state = st
             lbl = dict(self._mlabels, model=model)
             st.g_used = _metrics.gauge(
@@ -1524,6 +1518,34 @@ class GenerationEngine:
                 help="dtype-aware bytes backing the allocated paged "
                      "KV pool blocks (int8 counts codes + scales)")
         return st
+
+    def _draft_gate(self, model, store):
+        """Who drafts for ``model``, resolved ONCE at state creation,
+        for both speculative planes: ``(the draft's store or None, the
+        proposals a step verifies, whether it always drafts)``.  A
+        store with a prediction module of its own (``self_draft``)
+        drafts with it on every decode step: the configuration decides,
+        no variable does, and it takes no second store.  Otherwise a
+        draft attached via registry.add_draft_model + in-graph sampling
+        + MXNET_SERVE_SPEC != 0: auto (default) degrades to plain
+        decode when the rolling acceptance collapses, on/force always
+        drafts.  Attach drafts before the model's first request — a
+        draft added under traffic is picked up at the next engine (or
+        the next state, once the engine restarts)."""
+        if store.self_draft:
+            return None, store.self_draft, True
+        spec = str(get_env("MXNET_SERVE_SPEC") or "auto").lower()
+        if spec in ("0", "off", "false") or store.sample_mode != "graph":
+            return None, 0, False
+        draft = getattr(self._registry, "draft_store",
+                        lambda _m: None)(model)
+        if draft is None:
+            return None, 0, False
+        # the window the draft's verify programs were warmed for
+        # (add_draft_model's spec_k)
+        spec_k = int(getattr(draft, "spec_k",
+                             int(get_env("MXNET_SERVE_SPEC_K"))))
+        return draft, spec_k, spec in ("1", "on", "force", "always")
 
     def _paged_gauges(self, st):
         st.g_used.set(st.pool.used())
@@ -1606,6 +1628,10 @@ class GenerationEngine:
             # the prompt's last token reruns from there (no
             # tail, and not the block that holds that token)
             blocks = blocks[:max((plen - 1) // bs - held[0], 0)]
+        if st.state_rows or st.self_draft:
+            # (a self-draft: the module's row after a hit needs the
+            # target's hidden state of the hit's LAST token: whole
+            # blocks only, and that token reruns, below)
             tail = None
         whole = held[0] + len(blocks)
         # a partially-filled last prompt block gets pinned by the
@@ -1618,6 +1644,8 @@ class GenerationEngine:
         # token always reruns: its logits seed the first sample
         covered = plen if tail is not None else whole * bs
         prog = min(covered, plen - 1)
+        if st.self_draft and whole > held[0]:
+            prog = min(prog, covered - 1)
         # a class: the most blocks the slot holds at once (a
         # window's keys and a dispatch's rows, and one more
         # while a shared tail block forks), and of the hit the
@@ -1795,6 +1823,7 @@ class GenerationEngine:
                 st.temps[slot] = r.temperature
                 st.top_ks[slot] = r.top_k
                 st.ready[slot] = -1
+                st.prop[slot] = -1
                 keys = np.array(st.keys, np.uint32)
                 if 0 <= r.seed < 2 ** 32:
                     # byte-identical to jax.random.PRNGKey(seed) for
@@ -1847,7 +1876,7 @@ class GenerationEngine:
             [st.tables, np.zeros((grow, st.tb), np.int32)])
         for name in ("lengths", "prog", "chunks_done", "next_tok",
                      "top_ks", "resv", "cap", "passed", "reg_n",
-                     "reg_id", "ready"):
+                     "reg_id", "ready", "prop"):
             arr = getattr(st, name)
             setattr(st, name, np.concatenate(
                 [arr, np.zeros((grow,) + arr.shape[1:], arr.dtype)]))
@@ -1889,6 +1918,7 @@ class GenerationEngine:
         st.reg_id[i] = 0
         st.next_key[i] = None
         st.ready[i] = -1
+        st.prop[i] = -1
         if st.draft is not None:
             st.dlen[i] = 0
 
@@ -1919,7 +1949,9 @@ class GenerationEngine:
         # resolves the first one's tokens, and not after it.
         resolve = []
         if dec:
-            if st.draft is not None and self._spec_active(st):
+            if st.self_draft:
+                resolve.append(self._paged_self_draft_step(model, st, dec))
+            elif st.draft is not None and self._spec_active(st):
                 self._paged_spec_step(model, st, dec)
             else:
                 resolve.append(self._paged_decode_step(model, st, dec))
@@ -1987,7 +2019,7 @@ class GenerationEngine:
                                               scales=st.dscales), head=0)
 
     def _paged_dispatch(self, st, tables, toks, pos, val, do, phase,
-                        live, slots=None, **counts):
+                        live, slots=None, after=None, **counts):
         """Queue one unified paged step (decode OR prompt chunk —
         ``phase`` names it for the profiler/traces) and hand back the
         FETCH of its one sampled token per ``do`` row (a call that
@@ -2004,7 +2036,11 @@ class GenerationEngine:
         caller's ``counts`` ride beside them, and ``sample_draw`` /
         ``sample_topk``: whether the sampler draws, and sorts, for
         this dispatch (``sample_tokens``' two predicates, taken from
-        the host's copy of its inputs)."""
+        the host's copy of its inputs).  A SELF-DRAFTING store's chunk
+        (``after``: the prompt token behind each row's chunk) queues the
+        prediction module's program behind the target's, unfetched; the
+        one array fetched carries the module's proposals too, left in
+        ``st.chunk_props`` a row."""
         temps, top_ks = st.temps, st.top_ks
         if slots is not None:
             temps, top_ks = temps[slots], top_ks[slots]
@@ -2045,6 +2081,16 @@ class GenerationEngine:
                     out = st.store.run_paged_step_sample(
                         *st.pools, tables, toks, pos, val, st.keys,
                         temps, top_ks, do, scales=st.scales)
+                elif after is not None:
+                    packed, mtoks, hid, st.keys = st.take(
+                        st.store.run_paged_self_chunk(
+                            *st.pools, tables, toks, pos, val, st.keys,
+                            temps, top_ks, do, slots, after), head=3)
+                    self._starved.dispatched()
+                    self._starved.launching()
+                    out = st.store.run_paged_draft_chunk(
+                        *st.pools, tables, mtoks, pos, val, hid, packed,
+                        slots=len(st.slots)) + (st.keys,)
                 else:
                     out = st.store.run_paged_chunk_sample(
                         *st.pools, tables, toks, pos, val, st.keys,
@@ -2058,11 +2104,14 @@ class GenerationEngine:
                     self._starved.fetched(queued)
                 # a model's own counters ride behind the sampled
                 # tokens (store.aux_counters names them): same array,
-                # same fetch
-                for name, n in zip(st.store.aux_counters,
-                                   out[len(tables):]):
-                    self._stats.inc(name, int(n))
-                return out[:len(tables)]
+                # same fetch; behind them a self-draft's proposals and
+                # its module's counters
+                rows, aux = len(tables), st.store.aux_counters
+                self._count_aux(aux, out[rows:rows + len(aux)])
+                if after is not None:
+                    st.chunk_props = out[rows + len(aux):][:rows]
+                    self._count_aux(aux, out[2 * rows + len(aux):])
+                return out[:rows]
             return fetch
         # the host's sampler moves the key chains itself: nothing of
         # this dispatch is left for later
@@ -2085,6 +2134,10 @@ class GenerationEngine:
                     logits, st.keys, temps, top_ks, do, slots)
             sampled = np.asarray(toks_out)
         return lambda: sampled
+
+    def _count_aux(self, names, values):
+        for name, n in zip(names, values):
+            self._stats.inc(name, int(n))
 
     def _paged_failed(self, model, st, slots, e, what):
         """A dispatch (or its fetch) raised: to the futures of the
@@ -2388,6 +2441,31 @@ class GenerationEngine:
                 self._release_paged_slot(st, i)
                 self._fail_request(r, exc, running=True)
             return
+        def survived(i):
+            # draft KV is valid only while its tokens match the
+            # accepted stream: clamp to the new frontier after a
+            # rejection (full accept leaves a 1-token catch-up gap
+            # for the bonus token)
+            st.dlen[i] = min(int(st.dlen[i]), int(st.lengths[i]))
+
+        proposed, accepted = self._spec_resolve(st, dec, out_toks, n_emit,
+                                                win, survived)
+        if proposed:
+            st.spec_ema = (_SPEC_EMA_DECAY * st.spec_ema +
+                           (1.0 - _SPEC_EMA_DECAY) *
+                           (accepted / proposed))
+
+    def _spec_resolve(self, st, dec, out_toks, n_emit, win, survived):
+        """What BOTH speculative planes do with a verify's result: slot
+        ``i`` of ``dec`` emits ``out_toks[i, :n_emit[i]]`` (the accepted
+        proposals of the ``win[i]`` it offered, then the corrected or
+        bonus token), its frontier moves by as many, a sequence that
+        ends inside its window is retired there and the rest of the
+        window discarded with the slot, and ``survived(i)`` is called
+        for each slot that goes on.  Rejected positions roll back by
+        table arithmetic alone: the frontier does not pass the emitted
+        count.  Counts the step (``spec_*``); returns ``(proposed,
+        accepted)``."""
         emitted = 0
         proposed = 0
         accepted = 0
@@ -2414,23 +2492,16 @@ class GenerationEngine:
                         span.add(finished=1)
                         break
                 else:
-                    # draft KV is valid only while its tokens match the
-                    # accepted stream: clamp to the new frontier after a
-                    # rejection (full accept leaves a 1-token catch-up gap
-                    # for the bonus token)
-                    st.dlen[i] = min(int(st.dlen[i]), int(st.lengths[i]))
+                    survived(i)
                     if st.window is not None:
                         self._release_behind(st, i)
-            span.add(tokens=emitted)
+            span.add(tokens=emitted, proposed=proposed,
+                     accepted=accepted)
         self._stats.inc("decode_steps")
         self._stats.inc("spec_steps")
         self._stats.inc("spec_proposed", proposed)
         self._stats.inc("spec_accepted", accepted)
         self._stats.inc("generated_tokens", emitted)
-        if proposed:
-            st.spec_ema = (_SPEC_EMA_DECAY * st.spec_ema +
-                           (1.0 - _SPEC_EMA_DECAY) *
-                           (accepted / proposed))
         _metrics.cached_counter(
             "serve_spec_proposed_total",
             help="draft tokens offered to speculative verify").inc(
@@ -2439,6 +2510,112 @@ class GenerationEngine:
             "serve_spec_accept_total",
             help="draft tokens accepted by speculative verify").inc(
                 accepted)
+        return proposed, accepted
+
+    def _note_draft(self, st, i, r, position, token):
+        """Slot i's pending proposal: the module's token for
+        ``position``, told to a stream that asks (``drafted``) whether
+        or not the next step takes it."""
+        st.prop[i] = token
+        told = getattr(r.stream, "drafted", None)
+        if told is not None:
+            told(position, token)
+
+    def _paged_self_draft_step(self, model, st, dec):
+        """One SELF-DRAFTING decode tick: every generating slot's
+        pending token and the proposal its slot carries go through the
+        target in ONE dispatch of two positions a row, the rejection
+        rule in the graph beside them, and the model's prediction
+        module, in a program of its own queued behind it unfetched,
+        writes its rows for the emitted tokens (one or two a row) and
+        proposes for the position after them from the hidden state of
+        the last one accepted.  One fetch: emitted tokens, counts, next
+        proposals, both programs' counters.  The module's cache is one
+        more layer of the pool's leaf on the SAME tables: nothing to
+        mirror, fork or catch up.  Returns what is left once both
+        programs are queued, as :meth:`_paged_decode_step` does."""
+        K = st.spec_k
+        bs = st.store.kv_block
+        with _profiler.phase("serve_prepare"):
+            n = len(st.slots)
+            tables = np.zeros((n, st.tb), np.int32)
+            vtoks = np.zeros((n, K + 1), np.int32)
+            pos = np.zeros((n,), np.int32)
+            val = np.ones((n,), np.int32)
+            do = np.zeros((n,), bool)
+            win = {}
+            for i in dec:
+                r = st.slots[i]
+                L = int(st.lengths[i])
+                # never propose past the request's budget, and nothing
+                # where the slot carries no proposal
+                left = r.max_tokens - len(r.tokens) - 1
+                win[i] = w = max(0, min(K, left)) if st.prop[i] >= 0 else 0
+                # the target writes L .. L + w, the module the rows of
+                # the tokens emitted, L + 1 .. L + 1 + w: COW-fork or
+                # allocate first, for the rows that enter a block
+                top = min(L + w + 1,
+                          len(r.prompt) + r.max_tokens - 1) // bs
+                if top > st.ready[i]:
+                    self._paged_write_ready(
+                        st, i, range(L, min(L + w + 2, (top + 1) * bs)))
+                    st.ready[i] = top
+                tables[i] = st.tables[i]
+                vtoks[i, 0] = st.next_tok[i]
+                if w:
+                    vtoks[i, 1] = st.prop[i]
+                pos[i] = L
+                val[i] = w + 1
+                do[i] = True
+            traces = [(st.slots[i].trace, st.slots[i].trace_parent)
+                      for i in dec]
+        try:
+            with _tracing.activate_many(traces):
+                with _profiler.phase(
+                        "serve_decode", rows=len(dec),
+                        kv_tokens=int((pos[dec] + val[dec]).sum()),
+                        q_tokens=int(val[dec].sum()),
+                        proposed=sum(win.values())):
+                    self._starved.launching()
+                    packed, out_dev, ne_dev, hid, st.keys = st.take(
+                        st.store.run_paged_self_verify(
+                            *st.pools, tables, vtoks, pos, val, st.keys,
+                            st.temps, st.top_ks, do), head=4)
+                    self._starved.dispatched()
+                    self._starved.launching()
+                    got_dev, = st.take(st.store.run_paged_draft_step(
+                        *st.pools, tables, out_dev, pos, ne_dev, hid,
+                        packed))
+                    queued = self._starved.dispatched()
+        except BaseException as e:  # noqa: BLE001 — to the futures
+            self._paged_failed(model, st, dec, e, "self-draft")
+            return None
+
+        def finish():
+            try:
+                with _tracing.activate_many(traces), \
+                        _profiler.phase("serve_sample"):
+                    got = self._fetch_decode(got_dev)
+                    self._starved.fetched(queued)
+            except BaseException as e:  # noqa: BLE001
+                self._paged_failed(model, st, dec, e, "self-draft")
+                return
+            aux = st.store.aux_counters
+            at = (K + 2) * n
+            out_toks = got[:(K + 1) * n].reshape(n, K + 1)
+            n_emit = got[(K + 1) * n:at]
+            self._count_aux(aux, got[at:at + len(aux)])
+            props = got[at + len(aux):][:n]
+            self._count_aux(aux, got[at + len(aux) + n:])
+            self._stats.inc("draft_rows", int(n_emit[dec].sum()))
+
+            def survived(i):
+                r = st.slots[i]
+                self._note_draft(st, i, r, len(r.prompt) + len(r.tokens),
+                                 int(props[i]))
+
+            self._spec_resolve(st, dec, out_toks, n_emit, win, survived)
+        return finish
 
     def _paged_prefill_chunk(self, model, st, pre):
         """Advance the first of the prefilling slots ``pre`` (oldest
@@ -2493,9 +2670,11 @@ class GenerationEngine:
                 ntok = min(chunk, len(r.prompt) - p0)
                 # new blocks only: recomputed shared positions rewrite
                 # shared blocks with identical values (same tokens,
-                # same prefix) and must not fork
-                self._paged_write_ready(st, i, range(p0, p0 + ntok),
-                                        fork=False)
+                # same prefix) and must not fork.  A self-draft's
+                # module writes its row one position further
+                self._paged_write_ready(
+                    st, i, range(p0, p0 + ntok + st.self_draft),
+                    fork=False)
                 rows.append((i, r, p0, ntok))
             tables = np.zeros((n, st.tb), np.int32)
             toks = np.zeros((n, chunk), np.int32)
@@ -2503,6 +2682,10 @@ class GenerationEngine:
             val = np.ones((n,), np.int32)
             do = np.zeros((n,), bool)
             slots = np.zeros((n,), np.int32)
+            # a self-drafting store's chunk: the prompt token behind
+            # each row's chunk, which the module's last row takes
+            more = {"after": np.zeros((n,), np.int32)} \
+                if st.self_draft else {}
             for k, (i, r, p0, ntok) in enumerate(rows):
                 tables[k] = st.tables[i]
                 toks[k, :ntok] = r.prompt[p0:p0 + ntok]
@@ -2510,6 +2693,8 @@ class GenerationEngine:
                 val[k] = ntok
                 do[k] = (p0 + ntok == len(r.prompt))
                 slots[k] = i
+                if more and not do[k]:
+                    more["after"][k] = r.prompt[p0 + ntok]
             traces = [(r.trace, r.trace_parent)
                       for _i, r, _p, _n in rows]
             live = [i for i, _r, _p, _n in rows]
@@ -2518,7 +2703,7 @@ class GenerationEngine:
                 fetch = self._paged_dispatch(
                     st, tables, toks, pos, val, do, "serve_prefill",
                     np.arange(len(rows)), slots, width=n,
-                    deferred=deferred)
+                    deferred=deferred, **more)
                 if st.draft is not None and st.spec_mirror():
                     # mirror the chunk into the draft's KV plane
                     # (logits unfetched, discarded): same tables, same
@@ -2575,8 +2760,16 @@ class GenerationEngine:
                     else:
                         st.decoding[i] = True
                         st.next_tok[i] = tok
+                        if st.self_draft:
+                            # the module's first proposal, for the
+                            # position after the sampled token's
+                            self._note_draft(st, i, r, len(r.prompt) + 1,
+                                             int(st.chunk_props[k]))
                         if st.window is not None:
                             self._release_behind(st, i)
+            if st.self_draft:
+                self._stats.inc("draft_rows",
+                                sum(row[3] for row in rows))
             self._note_cache_hwm(model, st)
         return finish
 

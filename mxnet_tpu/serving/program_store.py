@@ -283,43 +283,61 @@ def spec_verify(logits_all, prop_toks, prop_q, keys, temps, top_ks,
     # split per verify keeps the chain counter-based like sample_tokens)
     allk = jax.vmap(lambda kk: jax.random.split(kk, K + 2))(keys)
     carry, res_keys = allk[:, 0], allk[:, K + 1]
-    p_full = _masked_dist(
-        logits_all.reshape(B * K1, V), jnp.repeat(temps, K1),
-        jnp.repeat(top_ks, K1)).reshape(B, K1, V)
     greedy_all = jnp.argmax(logits_all, axis=-1).astype(jnp.int32)
     rows = jnp.arange(B)
-    if K:
-        acc_keys = allk[:, 1:K + 1].reshape(B * K, 2)
-        u = jax.vmap(jax.random.uniform)(acc_keys).reshape(B, K)
-        pd = jnp.take_along_axis(p_full[:, :K], prop_toks[..., None],
-                                 -1)[..., 0]
-        qd = jnp.take_along_axis(prop_q, prop_toks[..., None],
-                                 -1)[..., 0]
-        acc = jnp.where((temps <= 0.0)[:, None],
-                        prop_toks == greedy_all[:, :K],
-                        u * qd <= pd)
-        acc = acc & (jnp.arange(K, dtype=jnp.int32)[None, :] + 1 <
-                     valid[:, None])
-        a = jnp.sum(jnp.cumprod(acc.astype(jnp.int32), axis=1), axis=1)
-    else:  # pragma: no cover - K=0 degenerates to a plain sample
-        a = jnp.zeros((B,), jnp.int32)
-    p_a = p_full[rows, a]                                   # (B, V)
-    q_ext = jnp.concatenate(
-        [prop_q, jnp.zeros((B, 1, V), jnp.float32)], axis=1)
-    # the bonus position (full accept, a == valid-1) has no proposal:
-    # its residual is p itself
-    q_a = jnp.where((a >= valid - 1)[:, None], 0.0, q_ext[rows, a])
-    res = jnp.maximum(p_a - q_a, 0.0)
-    tot = jnp.sum(res, axis=-1, keepdims=True)
-    res = jnp.where(tot > 0.0, res / jnp.where(tot > 0.0, tot, 1.0),
-                    p_a)
-    sampled = jax.vmap(jax.random.categorical)(
-        res_keys, jnp.log(jnp.maximum(res, 1e-30))).astype(jnp.int32)
-    corrected = jnp.where(temps <= 0.0, greedy_all[rows, a], sampled)
-    out = jnp.concatenate([prop_toks, jnp.zeros((B, 1), jnp.int32)],
-                          axis=1)
-    out = out.at[rows, a].set(corrected)
-    return out, (a + 1).astype(jnp.int32), carry
+    in_window = jnp.arange(K, dtype=jnp.int32)[None, :] + 1 < \
+        valid[:, None]
+
+    def accepted(acc):
+        return jnp.sum(jnp.cumprod(
+            (acc & in_window).astype(jnp.int32), axis=1), axis=1)
+
+    def emit(a, corrected):
+        out = jnp.concatenate(
+            [prop_toks, jnp.zeros((B, 1), jnp.int32)], axis=1)
+        return out.at[rows, a].set(corrected), (a + 1).astype(jnp.int32)
+
+    def greedy():
+        # every row greedy: no density is needed (``sampled`` sorts
+        # every position's logits for one, and its ``where`` on the
+        # temperatures then throws the draw away)
+        a = accepted(prop_toks == greedy_all[:, :K])
+        return emit(a, greedy_all[rows, a])
+
+    def sampled():
+        p_full = _masked_dist(
+            logits_all.reshape(B * K1, V), jnp.repeat(temps, K1),
+            jnp.repeat(top_ks, K1)).reshape(B, K1, V)
+        if K:
+            acc_keys = allk[:, 1:K + 1].reshape(B * K, 2)
+            u = jax.vmap(jax.random.uniform)(acc_keys).reshape(B, K)
+            pd = jnp.take_along_axis(p_full[:, :K], prop_toks[..., None],
+                                     -1)[..., 0]
+            qd = jnp.take_along_axis(prop_q, prop_toks[..., None],
+                                     -1)[..., 0]
+            a = accepted(jnp.where((temps <= 0.0)[:, None],
+                                   prop_toks == greedy_all[:, :K],
+                                   u * qd <= pd))
+        else:  # pragma: no cover - K=0 degenerates to a plain sample
+            a = jnp.zeros((B,), jnp.int32)
+        p_a = p_full[rows, a]                               # (B, V)
+        q_ext = jnp.concatenate(
+            [prop_q, jnp.zeros((B, 1, V), jnp.float32)], axis=1)
+        # the bonus position (full accept, a == valid-1) has no
+        # proposal: its residual is p itself
+        q_a = jnp.where((a >= valid - 1)[:, None], 0.0, q_ext[rows, a])
+        res = jnp.maximum(p_a - q_a, 0.0)
+        tot = jnp.sum(res, axis=-1, keepdims=True)
+        res = jnp.where(tot > 0.0, res / jnp.where(tot > 0.0, tot, 1.0),
+                        p_a)
+        drawn = jax.vmap(jax.random.categorical)(
+            res_keys, jnp.log(jnp.maximum(res, 1e-30))).astype(jnp.int32)
+        return emit(a, jnp.where(temps <= 0.0, greedy_all[rows, a],
+                                 drawn))
+
+    out, n_emit = jax.lax.cond(jnp.all(temps <= 0.0), greedy, sampled) \
+        if K else sampled()
+    return out, n_emit, carry
 
 
 class _Program:
@@ -903,7 +921,7 @@ def cache_donate_argnums(nums):
 # A pool may hold several leaves of ONE class (``deepseek_v32``: latent
 # rows and index keys): they ride one table and one allocator.
 _ARCHS = ("transformer_lm", "deepseek_v3", "lfm2_moe", "cohere2_moe",
-          "deepseek_v32")
+          "deepseek_v32", "pangu_ultra_moe")
 
 
 def _serving_model(arch):
@@ -927,6 +945,19 @@ def chunk_rows(bb):
 
 PAGED_KINDS = ("paged_step", "paged_step_sample", "paged_step_sample_p",
                "paged_chunk_sample", "paged_verify")
+# a SELF-DRAFTING store's four (``self_draft``): the target's verify of
+# K + 1 positions a row and its prompt chunk, each handing its hidden
+# states to the prediction module's own program behind it
+SELF_DRAFT_KINDS = ("paged_self_verify", "paged_self_chunk",
+                    "paged_draft_step", "paged_draft_chunk")
+# on the profiler's module line a kind goes by its own name (``jit_``
+# in front), but the two chunk programs keep the chunk's prefix: what
+# reads prefill's share of the device by module name reads it
+_SELF_DRAFT_NAMES = {"paged_self_chunk": "paged_prefill_chunk_self",
+                     "paged_draft_chunk": "paged_prefill_chunk_draft"}
+# results in front of the pool's leaves in a kind's flat return
+_HEADS = {"paged_step_sample_p": 2, "paged_verify": 2,
+          "paged_self_verify": 4, "paged_self_chunk": 3}
 
 
 def paged_program(model, spec, kind, lq, kv_block, nleaf, int8=False):
@@ -949,9 +980,10 @@ def paged_program(model, spec, kind, lq, kv_block, nleaf, int8=False):
     (``_serving_model``), ``spec`` its serving spec."""
     npool = nleaf + (2 if int8 else 0)
     pool_donate = tuple(range(1, 1 + npool))
-    name = "paged_verify" if kind == "paged_verify" else \
-        "paged_decode" if int(lq) == 1 and kind != "paged_chunk_sample" \
-        else "paged_prefill_chunk"
+    name = _SELF_DRAFT_NAMES.get(kind, kind) \
+        if kind in SELF_DRAFT_KINDS + ("paged_verify",) \
+        else "paged_decode" if int(lq) == 1 \
+        and kind != "paged_chunk_sample" else "paged_prefill_chunk"
 
     def step(params, pls, tables, tokens, positions, valid,
              all_logits=False):
@@ -1025,6 +1057,67 @@ def paged_program(model, spec, kind, lq, kv_block, nleaf, int8=False):
             return (out, n_emit) + new_pools + (new_keys,)
 
         donate = pool_donate + (1 + npool + 5,)
+    elif kind in ("paged_self_verify", "paged_self_chunk"):
+        # the TARGET's half of a self-drafting dispatch.  The verify:
+        # tokens[:, 0] the slot's pending token, tokens[:, 1] the
+        # module's proposal (its argmax: a one-hot density, so a
+        # sampling row accepts it with probability p(d)); the same
+        # rejection rule, and the step's hidden states, emitted tokens
+        # and counts stay on the device for the module's program.  The
+        # chunk: the compacted prompt chunk, and beside its hidden
+        # states the token AFTER each row (the next prompt token,
+        # ``after`` behind the chunk's last, the sampled one where the
+        # prompt ends), which is what the module's row there takes.
+        verify = kind == "paged_self_verify"
+
+        def fn(params, *rest):
+            pls = rest[:npool]
+            (tables, tokens, positions, valid, keys, temps, top_ks,
+             do_sample) = rest[npool:npool + 8]
+            logits, new_pools, aux, hid = model.paged_step(
+                params, pls[:nleaf], tables, tokens, positions, valid,
+                spec, kv_block, all_logits=verify, hidden=True)
+            if verify:
+                out, n_emit, carry = spec_verify(
+                    logits, tokens[:, 1:], jax.nn.one_hot(
+                        tokens[:, 1:], logits.shape[-1],
+                        dtype=jnp.float32),
+                    keys, temps, top_ks, valid)
+                new_keys = jnp.where(do_sample[:, None], carry, keys)
+                head = (jnp.concatenate([out.reshape(-1), n_emit,
+                                         aux.astype(jnp.int32)]),
+                        out, n_emit, hid)
+            else:
+                slots, after = rest[npool + 8:]
+                toks, new_keys = sample_chunk_rows(
+                    logits, keys, temps, top_ks, do_sample, slots)
+                nxt = jnp.concatenate(
+                    [tokens[:, 1:], jnp.zeros_like(tokens[:, :1])], 1)
+                nxt = nxt.at[jnp.arange(tokens.shape[0]), valid - 1].set(
+                    jnp.where(do_sample, toks, after))
+                head = (jnp.concatenate([toks, aux.astype(toks.dtype)]),
+                        nxt, hid)
+            return head + new_pools + (new_keys,)
+
+        donate = pool_donate + (1 + npool + 4,)
+    elif kind in ("paged_draft_step", "paged_draft_chunk"):
+        # the prediction MODULE behind either: its row at position p + 1
+        # from the target's hidden state at p and the token at p + 1
+        # (``positions`` are the target's); the proposal is its argmax
+        # at each sequence's last valid row.  ``head``, what the
+        # target's program packed for the host, rides through: one
+        # array, one fetch a dispatch.
+        def fn(params, *rest):
+            pls = rest[:npool]
+            tables, tokens, positions, valid, hid, head = rest[npool:]
+            logits, new_pools, aux = model.draft_step(
+                params, pls[:nleaf], tables, hid, tokens, positions + 1,
+                valid, spec, kv_block)
+            prop = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (jnp.concatenate(
+                [head, prop, aux.astype(jnp.int32)]),) + new_pools
+
+        donate = pool_donate
     else:   # paged_step (logits out — the host-sampling hatch)
         def fn(params, *rest):
             pls = rest[:npool]
@@ -1054,7 +1147,9 @@ class GenerativeProgramStore:
         ``"lfm2_moe"``: ``models/lfm2_moe.serving_spec``; paged plane
         only, no int8 pool).  A model that restacks leaves at
         load (``deepseek_v3``'s routed experts) pops them from
-        ``params`` as it goes: hand it a copy to keep yours.
+        ``params`` as it goes, and with ``compute_dtype="int8"`` its
+        family takes every leaf (each is freed as its codes are made):
+        hand it a copy to keep yours.
     batch_buckets / prompt_buckets : iterable of int, optional
         Bucket edges; default ``MXNET_SERVE_BUCKETS`` /
         ``MXNET_SERVE_PROMPT_BUCKETS``.
@@ -1104,13 +1199,20 @@ class GenerativeProgramStore:
         (never smaller than ``MXNET_SERVE_PROGRAM_CACHE``).
     device : jax.Device, optional
         Pin params (and hence programs + cache) to this device.
+    self_draft : int, optional
+        Tokens the model's OWN prediction module drafts a decode step
+        (0/None: the module is not loaded; 1: every decode step
+        verifies one proposal and yields one or two tokens).  The
+        module's cache is one more layer of the model's pool leaf, on
+        the same block tables; the configuration decides, no
+        environment variable does (``models/pangu_ultra_moe.py``).
     """
 
     def __init__(self, params, spec, name="lm", batch_buckets=None,
                  prompt_buckets=None, kv_block=None, kv_max=None,
                  compute_dtype=None, kv_dtype=None, sample=None,
                  paged=None, prefill_chunk=None, pool_blocks=None,
-                 max_programs=None, device=None):
+                 max_programs=None, device=None, self_draft=None):
         spec = dict(spec)
         self._model = _serving_model(spec.pop("arch", None))
         self._spec = self._model.serving_spec(spec)  # validates
@@ -1175,6 +1277,16 @@ class GenerativeProgramStore:
                 "kv_dtype='int8' needs the paged KV plane (the scales "
                 "are per pool block); set MXNET_SERVE_PAGED=1 or use "
                 "'float32'/'bfloat16' on the contiguous plane")
+        self.self_draft = int(self_draft or 0)
+        if self.self_draft:
+            self._need("self_draft", "a self-drafting decode step "
+                       "(self_draft=%d)" % self.self_draft)
+            if not self.paged or sm != "graph":
+                raise MXNetError(
+                    "self_draft needs the paged plane with in-graph "
+                    "sampling (paged=True, sample='graph')")
+            self._spec = self._model.with_draft(self._spec,
+                                                self.self_draft)
         chunk = int(prefill_chunk if prefill_chunk is not None
                     else get_env("MXNET_SERVE_PREFILL_CHUNK"))
         if chunk < 1:
@@ -1226,7 +1338,7 @@ class GenerativeProgramStore:
         # paged plane's warm set is two step programs a batch bucket:
         # the decode step and the compacted prompt chunk.
         if self.paged:
-            n_warm = 2 * len(self._batch_edges)
+            n_warm = (4 if self.self_draft else 2) * len(self._batch_edges)
         else:
             n_warm = (len(self._batch_edges) * len(self._prompt_edges) +
                       len(self._batch_edges) *
@@ -1277,9 +1389,15 @@ class GenerativeProgramStore:
 
         if self._compute == "int8":
             out = {}
+            leaves = {k: _as_device_array(v) for k, v in params.items()}
+            if getattr(self._model, "QUANTIZE_TAKES_LEAVES", False):
+                # a quantizer that frees each plain leaf as its codes
+                # are made gets the ONLY references: the caller's dict
+                # is emptied, as ``pack_params`` empties it of what it
+                # restacks (hand over a copy to keep yours)
+                params.clear()
             for k, v in self._model.quantize_params(
-                    {k: _as_device_array(v) for k, v in params.items()},
-                    self._spec).items():
+                    leaves, self._spec).items():
                 if isinstance(v, QuantizedWeight):
                     c, s = jnp.asarray(v.codes), jnp.asarray(v.scales)
                     if device is not None:
@@ -1400,6 +1518,16 @@ class GenerativeProgramStore:
         if self.sample_mode == "graph":
             return ("paged_chunk_sample", bb, self.prefill_chunk)
         return ("paged_step", self.chunk_rows(bb), self.prefill_chunk)
+
+    def step_programs(self, bb):
+        """``(kind, bucket, lq)`` of the four programs a SELF-DRAFTING
+        store dispatches for slot bucket ``bb``, and the only ones it
+        warms: the verify of ``self_draft + 1`` positions a row and the
+        prompt chunk, each with the module's program behind it."""
+        k1 = self.self_draft + 1
+        return (("paged_self_verify", bb, k1), ("paged_draft_step", bb, k1),
+                ("paged_self_chunk", bb, self.prefill_chunk),
+                ("paged_draft_chunk", bb, self.prefill_chunk))
 
     def class_width(self):
         """Table entries of ONE class of block: logical blocks needed
@@ -1561,11 +1689,21 @@ class GenerativeProgramStore:
         rows wide but the compacted prompt chunk: ``chunk_rows(bb)``
         rows beside the bb slots' key chains, and which slot each row
         works for."""
-        compact = kind == "paged_chunk_sample"
+        compact = kind in ("paged_chunk_sample", "paged_self_chunk",
+                           "paged_draft_chunk")
         rows = self.chunk_rows(bb) if compact else bb
         avals = [((rows, self.table_width()), np.int32),
                  ((rows, int(lq)), np.int32),
                  ((rows,), np.int32), ((rows,), np.int32)]
+        if kind in ("paged_draft_step", "paged_draft_chunk"):
+            # the target's hidden states, and what it packed for the
+            # host: the verify's tokens, counts and counters, or the
+            # chunk's sampled tokens and counters
+            packed = (rows if compact else (int(lq) + 1) * rows) \
+                + len(self.aux_counters)
+            return avals + [
+                ((rows, int(lq), self._spec["hidden_size"]), np.float32),
+                ((packed,), np.int32)]
         if kind == "paged_verify":     # the draft's proposal densities
             avals.append(((bb, int(lq) - 1, self._spec["vocab_size"]),
                           np.float32))
@@ -1573,6 +1711,8 @@ class GenerativeProgramStore:
             avals += [((bb, 2), np.uint32), ((rows,), np.float32),
                       ((rows,), np.int32), ((rows,), np.bool_)]
         if compact:
+            avals.append(((rows,), np.int32))
+        if kind == "paged_self_chunk":  # the token after the chunk
             avals.append(((rows,), np.int32))
         return avals
 
@@ -1592,7 +1732,7 @@ class GenerativeProgramStore:
         tic = time.perf_counter()
         spec = self._spec
         kv = self.kv_dtype
-        if kind in PAGED_KINDS:
+        if kind in PAGED_KINDS + SELF_DRAFT_KINDS:
             args = (self._param_spec(),) + self._pool_spec() + tuple(
                 self._sds(shape, dtype)
                 for shape, dtype in self._paged_avals(kind, bb, lb))
@@ -1714,7 +1854,8 @@ class GenerativeProgramStore:
                      else "paged_step")
             pools = None        # ONE throwaway pool through all of them
             for bb in self._batch_edges:
-                for key in ((pkind, bb, 1), self.chunk_program(bb)):
+                for key in self.step_programs(bb) if self.self_draft \
+                        else ((pkind, bb, 1), self.chunk_program(bb)):
                     if key in out:      # two buckets, one chunk width
                         continue
                     prog = self._acquire(*key)
@@ -1782,7 +1923,7 @@ class GenerativeProgramStore:
         own[3][:] = 1       # one valid token a row
         out = jax.block_until_ready(
             prog.fn(self._params, *pools, *own))
-        head = 2 if kind in ("paged_step_sample_p", "paged_verify") else 1
+        head = _HEADS.get(kind, 1)
         return tuple(out[head:head + len(pools)])
 
     def warm_spec_programs(self, spec_k, draft=False, execute=True):
@@ -1864,16 +2005,17 @@ class GenerativeProgramStore:
             return tuple(pools) + tuple(scales)
         return tuple(pools)
 
-    def _run_paged(self, kind, args, scales):
+    def _run_paged(self, kind, args, scales, bb=None):
         """Dispatch program ``kind`` on ``args``: the pool's leaves
         (``pool_leaves`` of them) and then the program's own arguments,
-        tables first and tokens second."""
+        tables first and tokens second.  ``bb``: the slot bucket of a
+        compacted program that takes no key chains to tell it by."""
         n = self.pool_leaves
-        bb, lq = args[n + 1].shape
-        if kind == "paged_chunk_sample":
+        rows, lq = args[n + 1].shape
+        if kind in ("paged_chunk_sample", "paged_self_chunk"):
             # a program of its slot bucket: the key chains' axis
             bb = args[n + 4].shape[0]
-        prog = self._acquire(kind, int(bb), int(lq))
+        prog = self._acquire(kind, int(bb or rows), int(lq))
         return prog.fn(self._params, *(self._pool_args(args[:n], scales)
                                        + tuple(args[n:])))
 
@@ -1943,6 +2085,43 @@ class GenerativeProgramStore:
         (donated) — callers rebind."""
         return self._run_paged('paged_verify', args, scales)
 
+    @hot_path
+    def run_paged_self_verify(self, *args):
+        """The TARGET's half of a self-drafting decode step:
+        ``run_paged_self_verify(*pool leaves, tables, tokens, positions,
+        valid, keys, temps, top_ks, do_sample)``, ``tokens`` (bb, K+1)
+        each slot's pending token and the module's proposals behind it,
+        ``valid`` = proposals to verify + 1.  Returns ``(packed, out
+        (bb, K+1), n_emit (bb,), hidden (bb, K+1, D), *pool leaves,
+        new_keys)``; ``packed`` is ``[out, n_emit, the model's
+        counters]`` in one int32 vector and, like ``out``, ``n_emit``
+        and ``hidden``, goes to :meth:`run_paged_draft_step` unfetched."""
+        return self._run_paged("paged_self_verify", args, None)
+
+    @hot_path
+    def run_paged_self_chunk(self, *args):
+        """:meth:`run_paged_chunk_sample` of a self-drafting store, one
+        argument more (``after`` (rows,): the prompt token behind each
+        row's chunk).  Returns ``(packed, tokens for the module (rows,
+        lq), hidden (rows, lq, D), *pool leaves, new_keys)``, ``packed``
+        the sampled tokens and the model's counters."""
+        return self._run_paged("paged_self_chunk", args, None)
+
+    @hot_path
+    def run_paged_draft_step(self, *args):
+        """The prediction MODULE behind a verify: ``run_paged_draft_step(
+        *pool leaves, tables, out, positions, n_emit, hidden, packed)``
+        (``positions`` the verify's).  Returns ``(packed + proposals
+        (bb,) + the module's counters, *pool leaves)``: the one array a
+        self-drafting decode tick fetches."""
+        return self._run_paged("paged_draft_step", args, None)
+
+    @hot_path
+    def run_paged_draft_chunk(self, *args, slots):
+        """The module behind a prompt chunk of a ``slots``-slot bucket:
+        as :meth:`run_paged_draft_step`, ``chunk_rows(slots)`` rows."""
+        return self._run_paged("paged_draft_chunk", args, None, bb=slots)
+
     def pad_prompts(self, prompts):
         """Host-side canonicalization: a list of token id sequences ->
         bucket-shaped ``(tokens (bb, pb) int32, lengths (bb,) int32)``.
@@ -1983,6 +2162,7 @@ class GenerativeProgramStore:
         out["kv_dtype"] = str(self.kv_dtype)
         out["sample_mode"] = self.sample_mode
         out["paged"] = self.paged
+        out["self_draft"] = self.self_draft
         if self.paged:
             out["prefill_chunk"] = self.prefill_chunk
             out["pool_blocks"] = self.pool_blocks

@@ -9,7 +9,7 @@ import pytest
 
 _MODULES = ["benchmark.tests.test_%s" % m
             for m in ("contract", "costs", "stats", "traffic", "xplane",
-                      "deepseek_v32")]
+                      "deepseek_v32", "openpangu_ultra_moe")]
 pytest.register_assert_rewrite(*_MODULES)
 
 from benchmark.tests.test_contract import *  # noqa: E402,F401,F403
@@ -17,8 +17,15 @@ from benchmark.tests.test_costs import *  # noqa: E402,F401,F403
 from benchmark.tests.test_stats import *  # noqa: E402,F401,F403
 from benchmark.tests.test_traffic import *  # noqa: E402,F401,F403
 from benchmark.tests.test_xplane import *  # noqa: E402,F401,F403
-# the newest configuration's file, costs, mix and readers (seconds; its
-# rehearsals and real-size compiles stay with benchmark/tests)
+# the two newest configurations' files, costs, mixes and readers
+# (seconds; their rehearsals and real-size compiles stay with
+# benchmark/tests)
+from benchmark.tests.test_openpangu_ultra_moe import (  # noqa: E402,F401
+    test_a_program_without_the_model_fails_at_once,
+    test_openpangu_costs_of_the_published_widths,
+    test_openpangu_file_holds_the_catalogs_row,
+    test_openpangu_readers_on_a_recorded_dispatch,
+    test_openpangu_traffic_is_the_issues)
 from benchmark.tests.test_deepseek_v32 import (  # noqa: E402,F401
     test_costs_of_the_published_widths,
     test_readers_on_a_recorded_dispatch,

@@ -134,6 +134,21 @@ def _dsa_masked(q, pool, tables, positions, scores, thr, tie):
         interpret=False)
 
 
+def _mla(first):
+    from mxnet_tpu.pallas_ops import mla_attention as mla
+    return lambda q, pool, tables, positions: mla.mla_paged_attention(
+        q, pool, 5, tables, positions, BS, 512, 0.07, interpret=False,
+        first=first)
+
+
+def _mla_args(s, rows, lq):
+    # openpangu-ultra-moe.serve-reason-backlog: 128 heads, 64 table
+    # entries of 64 tokens, the leaf of 2,048 blocks x (5 + 1) layers
+    return (s((rows, 128, lq, 640), BF16),
+            s((6, 1, 2048 * BS, 640), BF16), s((rows, 64), I32),
+            s((rows,), I32))
+
+
 def _dsa_index_args(s, rows, lq):
     # deepseek-v32.serve-longdoc-backlog: 64 index heads of 128, 360
     # table entries of 64 tokens, the leaf of 6,144 blocks
@@ -223,6 +238,14 @@ CASES = [
     ("dsa-attend-decode", _dsa_attend,
      lambda s: (s((64, 128, 640), BF16), s((64, 2048, 640), BF16),
                 s((64,), I32)), 1),
+    # openpangu-ultra-moe.serve-reason-backlog: the latent kernel at TWO
+    # queries a row (a self-drafting step's verify) and, for the
+    # prediction module's layer, with row 0 of the cache seen by no
+    # query (first=1), a step's rows and a chunk's
+    ("mla-verify-two-queries", _mla(0), lambda s: _mla_args(s, 64, 2), 1),
+    ("mla-module-step-first-1", _mla(1), lambda s: _mla_args(s, 64, 2), 1),
+    ("mla-module-chunk-first-1", _mla(1),
+     lambda s: _mla_args(s, 16, 32), 1),
     ("dsa-attend-chunk-masked", _dsa_masked,
      lambda s: (s((16, 128, 32, 640), BF16),
                 s((2, 1, 6144 * BS, 640), BF16), s((16, 360), I32),

@@ -478,7 +478,7 @@ def test_the_store_knows_the_arch_by_its_own_name():
     spec names it, with nothing of the contiguous, int8-KV or draft
     planes."""
     from mxnet_tpu.serving import program_store
-    assert program_store._ARCHS[-1] == "deepseek_v32"
+    assert program_store._ARCHS[4] == "deepseek_v32"
     assert program_store._serving_model("deepseek_v32") is ds32
     assert ds32.OFFERS == frozenset()
     with pytest.raises(MXNetError, match="contiguous"):
