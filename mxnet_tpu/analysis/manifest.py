@@ -68,6 +68,8 @@ SPAN_ENTRY_POINTS = (
     ("mxnet_tpu/serving/decode_engine.py",
      "GenerationEngine._paged_dispatch"),
     ("mxnet_tpu/serving/decode_engine.py",
+     "GenerationEngine._paged_one_pass"),
+    ("mxnet_tpu/serving/decode_engine.py",
      "GenerationEngine._paged_prefill_chunk"),
     ("mxnet_tpu/serving/decode_engine.py",
      "GenerationEngine._paged_tick"),

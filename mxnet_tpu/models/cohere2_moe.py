@@ -49,15 +49,16 @@ import numpy as np
 
 from ..base import MXNetError
 from .deepseek_v3 import (_mm, _rope, _swiglu_ffn, expert_layer,
-                          pack_params, quantize_leaves, random_leaves)
-from .paged import pool_write, write_plan
+                          layer_leaves, pack_params, quantize_leaves,
+                          random_leaves)
+from .paged import cat, last_logits, pool_write, row_groups
 from .transformer_lm import _embed
 
 __all__ = ["serving_spec", "param_shapes", "random_params",
            "required_params", "matmul_weights", "pack_params",
            "quantize_params", "init_pool", "cache_classes",
-           "paged_step_apply", "paged_step", "OFFERS", "AUX_COUNTERS",
-           "WINDOW_KERNEL"]
+           "paged_step_apply", "paged_step", "paged_step_groups",
+           "OFFERS", "AUX_COUNTERS", "WINDOW_KERNEL"]
 
 # what of the serving plane this model can be put on besides the paged
 # plane with in-graph or host sampling (program_store asks)
@@ -235,104 +236,134 @@ def _ln(x, gamma, eps):
                              + eps) * gamma.astype(jnp.float32)
 
 
-def paged_step_apply(params, pools, tables, tokens, positions, valid,
-                     spec, block_size, all_logits=False):
-    """One PAGED step — ``transformer_lm.paged_step_apply``'s contract
-    over the leaves of :func:`init_pool`: tokens ``(B, Lq)`` (``Lq =
-    1`` a decode step), positions/valid ``(B,)``, tables ``(B, classes
-    * T)``: the ``T`` entries of each class of :func:`cache_classes`
-    side by side.  A layer writes the chunk's ``K`` and ``V`` rows into
-    its class's leaves through its class's table (``paged.pool_write``)
-    and attends through the ``sdp_attention_paged`` door, the query
-    heads of a KV head in one tile; a window layer's call masks and
-    skips what lies behind the window and is named
-    :data:`WINDOW_KERNEL`.  ``params`` is a PACKED dict
-    (``pack_params``), plain or int8.
+def paged_step_groups(params, pools, groups, spec, block_size,
+                      all_logits=False):
+    """One PAGED step over the leaves of :func:`init_pool` for a tuple
+    of ROW GROUPS, each ``(tables (B, classes * T), tokens (B, Lq),
+    positions (B,), valid (B,))`` with a ``B`` and an ``Lq`` of its own
+    (a tick's decode rows, ``Lq = 1``, and its prompt chunk's); a
+    group's tables hold the ``T`` entries of each class of
+    :func:`cache_classes` side by side.  What works on a TOKEN (the
+    norm, the projections, the shared and the routed experts, the
+    head) runs ONCE over all the groups' rows laid end to end, so a
+    weight is read once a step; what works on a SEQUENCE runs a group,
+    in the order given, as that many one-group steps would: a layer
+    writes the group's ``K`` and ``V`` rows into its class's leaves
+    through its class's table (``paged.pool_write``) and attends through
+    the ``sdp_attention_paged`` door, the query heads of a KV head in one
+    tile; a window layer's call masks and skips what lies behind the
+    window and is named :data:`WINDOW_KERNEL`.  ``params`` is a PACKED
+    dict (``pack_params``), plain or int8.  The program store takes a
+    model that has this name to offer a step over more than one group
+    (``program_store.paged_program``).
 
-    Returns ``(logits, pools, counts)``: logits ``(B, vocab)`` fp32 at
-    each row's last valid position (``all_logits``: ``(B, Lq,
-    vocab)``), and :data:`AUX_COUNTERS` summed over the expert layers
-    (``deepseek_v3.paged_step_apply`` tells them)."""
+    Returns ``(logits a group, pools, counts)``: a group's logits ``(B,
+    vocab)`` fp32 at each row's last valid position (``all_logits``:
+    ``(B, Lq, vocab)``), and :data:`AUX_COUNTERS` summed over the
+    expert layers, all groups together (``deepseek_v3.
+    paged_step_groups`` tells them)."""
     import jax.numpy as jnp
     from ..ops.attention import sdp_attention_paged
 
-    D, dh = spec["hidden_size"], spec["head_dim"]
+    dh = spec["head_dim"]
     H, Hkv = spec["num_attention_heads"], spec["num_key_value_heads"]
     eps = spec["layer_norm_eps"]
     bs = int(block_size)
-    B, Lq = tokens.shape
-    N = B * Lq
     f32 = jnp.float32
     cdt = params["final_norm_gamma"].dtype      # the weights' dtype
-    positions = jnp.asarray(positions, jnp.int32)
-    valid = jnp.asarray(valid, jnp.int32)
-    kinds = _kinds(spec)
-    T = tables.shape[1] // len(kinds)
-    tables = jnp.asarray(tables, jnp.int32)
-    # by layer type: the class's table, where its fresh rows go, its
-    # window, its leaves' place in the pool and the next layer of them
-    cls = {}
-    for c, (window, leaves) in enumerate(cache_classes(spec)):
-        tbl = tables[:, c * T:(c + 1) * T]
-        cls[kinds[c]] = [tbl, write_plan(tbl, positions, valid, Lq, bs),
-                         window, leaves, 0]
-    pools = list(pools)
-    rows = jnp.arange(Lq, dtype=jnp.int32)
+    gs, tokens = row_groups(groups, bs)
     # a released block leaves a zero in a window table's first entry:
-    # the first class's table says which rows are in the dispatch
-    live = ((tables[:, :1] != 0) & (rows[None] < valid[:, None])) \
-        .reshape(N)
+    # the first class's table, first in the array, says which rows are
+    # in the dispatch (``RowGroup.live``)
+    live = cat([g.live for g in gs])
+    kinds = _kinds(spec)
     freqs = 1.0 / spec["rope_theta"] ** (
         np.arange(0, dh, 2, dtype=np.float64) / dh)
-    angle = (positions[:, None] + rows[None]).astype(f32)[..., None] \
-        * jnp.asarray(freqs, f32)                        # (B, Lq, dh/2)
-    cos, sin = jnp.cos(angle)[:, :, None], jnp.sin(angle)[:, :, None]
+    for g in gs:
+        # by layer type: the class's table, and where its fresh rows go
+        T = g.tables.shape[1] // len(kinds)
+        g.cls = {}
+        for c, kind in enumerate(kinds):
+            tbl = g.tables[:, c * T:(c + 1) * T]
+            g.cls[kind] = (tbl, g.write_plan(tbl))
+        angle = g.angles(freqs)[:, :, None]             # (B, Lq, 1, dh/2)
+        g.cos, g.sin = jnp.cos(angle), jnp.sin(angle)
+    # by layer type: the class's window, its leaves' place in the pool
+    # and the next layer of them
+    cls = {kind: [window, leaves, 0] for kind, (window, leaves)
+           in zip(kinds, cache_classes(spec))}
+    pools = list(pools)
     counts = jnp.zeros((len(AUX_COUNTERS),), jnp.int32)
     no_bias = jnp.zeros((spec["router_width"],), f32)
     share = 1.0 / spec["num_shared_experts"]
 
     embed = params["embed_tokens_weight"]       # the head too: tied
-    x = _embed(embed, tokens).astype(f32)                    # (B, Lq, D)
+    x = _embed(embed, tokens).astype(f32)                    # (N, D)
     for i, kind in enumerate(spec["layer_types"]):
-        p = {k[len("l%d_" % i):]: v for k, v in params.items()
-             if k.startswith("l%d_" % i)}
-        tbl, plan, window, (ik, iv), n = cls[kind]
-        cls[kind][4] += 1
-        h = _ln(x, p["norm_gamma"], eps).astype(cdt).reshape(N, D)
-        q = _mm(h, p["q_weight"]).reshape(B, Lq, H, dh)
-        k = _mm(h, p["k_weight"]).reshape(B, Lq, Hkv, dh)
-        v = _mm(h, p["v_weight"]).reshape(B, Lq, Hkv, dh)
-        if window is not None:
-            q = _rope(q.astype(f32), cos, sin)
-            k = _rope(k.astype(f32), cos, sin)
-        pools[ik], pools[iv] = pool_write(
-            (pools[ik], pools[iv]), n,
-            (jnp.transpose(k, (0, 2, 1, 3)),
-             jnp.transpose(v, (0, 2, 1, 3))), plan, bs)
-        att = sdp_attention_paged(
-            jnp.transpose(q, (0, 2, 1, 3)).astype(pools[ik].dtype),
-            pools[ik], pools[iv], n, tbl, positions, bs,
-            scale=dh ** -0.5, group=KV_GROUP, window=window,
-            name="paged_attention" if window is None else WINDOW_KERNEL,
-            block_q=Q_TILE)
-        out = _mm(jnp.transpose(att, (0, 2, 1, 3)).astype(cdt)
-                  .reshape(N, H * dh), p["o_weight"]).astype(f32)
+        p = layer_leaves(params, "l%d_" % i)
+        window, (ik, iv), n = cls[kind]
+        cls[kind][2] += 1
+        h = _ln(x, p["norm_gamma"], eps).astype(cdt)
+        q_all = _mm(h, p["q_weight"])
+        k_all = _mm(h, p["k_weight"])
+        v_all = _mm(h, p["v_weight"])
+        outs = []
+        for g in gs:
+            B, Lq = g.B, g.Lq
+            tbl, plan = g.cls[kind]
+            q = q_all[g.span].reshape(B, Lq, H, dh)
+            k = k_all[g.span].reshape(B, Lq, Hkv, dh)
+            v = v_all[g.span].reshape(B, Lq, Hkv, dh)
+            if window is not None:
+                q = _rope(q.astype(f32), g.cos, g.sin)
+                k = _rope(k.astype(f32), g.cos, g.sin)
+            pools[ik], pools[iv] = pool_write(
+                (pools[ik], pools[iv]), n,
+                (jnp.transpose(k, (0, 2, 1, 3)),
+                 jnp.transpose(v, (0, 2, 1, 3))), plan, bs)
+            att = sdp_attention_paged(
+                jnp.transpose(q, (0, 2, 1, 3)).astype(pools[ik].dtype),
+                pools[ik], pools[iv], n, tbl, g.positions, bs,
+                scale=dh ** -0.5, group=KV_GROUP, window=window,
+                name="paged_attention" if window is None
+                else WINDOW_KERNEL, block_q=Q_TILE)
+            outs.append(jnp.transpose(att, (0, 2, 1, 3)).astype(cdt)
+                        .reshape(B * Lq, H * dh))
+        out = _mm(cat(outs), p["o_weight"]).astype(f32)
         routed, step = expert_layer(h, dict(p, router_bias=no_bias), spec,
                                     live)
         counts = counts + step
         shared = _swiglu_ffn(h, p["shared_gate_weight"],
                              p["shared_up_weight"],
                              p["shared_down_weight"])
-        x = x + (out + routed + share * shared).reshape(B, Lq, D)
+        x = x + (out + routed + share * shared)
     hN = _ln(x, params["final_norm_gamma"], eps).astype(cdt)
+
+    def head(rows):
+        logits = _mm(rows, embed, f32)
+        if spec["logit_scale"] != 1.0:
+            logits = logits * spec["logit_scale"]
+        return logits.astype(f32)
+
     if all_logits:
-        logits = _mm(hN.reshape(N, D), embed, f32).reshape(
-            B, Lq, spec["vocab_size"])
+        every = head(hN)
+        logits = tuple(every[g.span].reshape(g.B, g.Lq, -1) for g in gs)
     else:
-        logits = _mm(hN[jnp.arange(B), valid - 1], embed, f32)
-    if spec["logit_scale"] != 1.0:
-        logits = logits * spec["logit_scale"]
-    return logits.astype(f32), tuple(pools), counts
+        logits = last_logits(hN, gs, head)
+    return logits, tuple(pools), counts
+
+
+def paged_step_apply(params, pools, tables, tokens, positions, valid,
+                     spec, block_size, all_logits=False):
+    """:func:`paged_step_groups` of ONE group — ``transformer_lm.
+    paged_step_apply``'s contract over the leaves of :func:`init_pool`:
+    tokens ``(B, Lq)`` (``Lq = 1`` a decode step), positions/valid
+    ``(B,)``, tables ``(B, classes * T)``.  Returns ``(logits, pools,
+    counts)``."""
+    (logits,), pools, counts = paged_step_groups(
+        params, pools, ((tables, tokens, positions, valid),), spec,
+        block_size, all_logits=all_logits)
+    return logits, pools, counts
 
 
 def paged_step(params, pools, tables, tokens, positions, valid, spec,

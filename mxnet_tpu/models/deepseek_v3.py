@@ -47,14 +47,15 @@ import re
 import numpy as np
 
 from ..base import MXNetError
-from .paged import pool_write, write_plan
+from .paged import RowGroup, cat, last_logits, pool_write, row_groups
 from .transformer_lm import _embed
 
 __all__ = ["serving_spec", "param_shapes", "random_params",
            "required_params", "matmul_weights", "pack_params",
            "quantize_params", "init_pool", "latent_width",
            "paged_step_apply", "paged_step_leaves", "paged_step",
-           "rope_frequencies", "softmax_scale", "OFFERS",
+           "paged_step_groups", "rope_frequencies", "softmax_scale",
+           "OFFERS",
            "AUX_COUNTERS", "QUANTIZE_TAKES_LEAVES"]
 
 # what of the serving plane this model can be put on besides the paged
@@ -393,35 +394,44 @@ def _rope_halves(x, cos, sin):
     return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], axis=-1)
 
 
-def _index_parts(h, cq, p, spec, shape, cos, sin):
-    """The lightning indexer's inputs of one layer for the chunk's
-    ``shape = (B, Lq)`` rows: index queries ``(B, Lq, Hi, di)`` and the
-    fresh index keys ``(B, Lq, di)`` in fp32, both with their rotary
-    part (the FIRST ``qk_rope_head_dim`` values) turned, and the head
-    weights ``(B, Lq, Hi)`` fp32, scaled.  ``h`` the attention block's
-    normed input ``(N, D)``, ``cq`` MLA's query latent ``(N, rq)``."""
+def _index_project(h, cq, p, spec):
+    """The lightning indexer's projections of one layer, a TOKEN each,
+    over the step's rows ``h`` ``(N, D)`` (the attention block's normed
+    input) and ``cq`` ``(N, rq)`` (MLA's query latent): index queries
+    ``(N, Hi, di)`` and index keys ``(N, di)`` in fp32, rotary not yet
+    turned, and the head weights ``(N, Hi)`` fp32, scaled."""
     import jax
     import jax.numpy as jnp
     f32 = jnp.float32
     Hi, di = spec["index_n_heads"], spec["index_head_dim"]
-    dr = spec["qk_rope_head_dim"]
     qi = _mm(cq.astype(h.dtype), p["idx_q_b_weight"]).astype(f32) \
-        .reshape(shape + (Hi, di))
-    qi = jnp.concatenate(
-        [_rope_halves(qi[..., :dr], cos[:, :, None], sin[:, :, None]),
-         qi[..., dr:]], axis=-1)
+        .reshape(-1, Hi, di)
     ki = _mm(h, p["idx_k_weight"], f32)
     ki = ki - jnp.mean(ki, axis=-1, keepdims=True)
     ki = ki * jax.lax.rsqrt(jnp.mean(jnp.square(ki), -1, keepdims=True)
                             + _INDEX_NORM_EPS) \
         * p["idx_k_norm_gamma"].astype(f32) \
         + p["idx_k_norm_beta"].astype(f32)
-    ki = ki.reshape(shape + (di,))
+    wi = _mm(h, p["idx_w_weight"], f32) * (Hi ** -0.5 * di ** -0.5)
+    return qi, ki, wi
+
+
+def _index_turn(qi, ki, wi, st):
+    """:func:`_index_project`'s rows of ONE group ``st``, by sequence:
+    queries ``(B, Lq, Hi, di)``, fresh keys ``(B, Lq, di)``, head
+    weights ``(B, Lq, Hi)``, the rotary part of queries and keys (the
+    FIRST ``qk_rope_head_dim`` values) turned by the group's angles."""
+    import jax.numpy as jnp
+    shape, dr = (st.B, st.Lq), st.spec["qk_rope_head_dim"]
+    cos, sin = st.cos, st.sin
+    qi = qi.reshape(shape + qi.shape[1:])
+    ki = ki.reshape(shape + ki.shape[1:])
+    qi = jnp.concatenate(
+        [_rope_halves(qi[..., :dr], cos[:, :, None], sin[:, :, None]),
+         qi[..., dr:]], axis=-1)
     ki = jnp.concatenate([_rope_halves(ki[..., :dr], cos, sin),
                           ki[..., dr:]], axis=-1)
-    wi = _mm(h, p["idx_w_weight"], f32).reshape(shape + (Hi,)) \
-        * (Hi ** -0.5 * di ** -0.5)
-    return qi, ki, wi
+    return qi, ki, wi.reshape(shape + wi.shape[1:])
 
 
 def _rms(x, gamma, eps):
@@ -490,25 +500,18 @@ def paged_step_apply(params, pool, tables, tokens, positions, valid, spec,
     return logits, pool, counts
 
 
-class _Step:
-    """What every layer of one paged step shares: the write plan, the
-    live rows, the rotary angles, the softmax scale."""
+class _Step(RowGroup):
+    """What every layer of a paged step shares for ONE group of its
+    rows (``paged.RowGroup``): the write plan, the rotary angles, the
+    softmax scale, the indexer's selection."""
 
-    def __init__(self, tables, shape, positions, valid, spec, block_size):
+    def __init__(self, tables, shape, positions, valid, spec, block_size,
+                 at=0):
         import jax.numpy as jnp
-        f32 = jnp.float32
-        self.spec, self.bs = spec, int(block_size)
-        self.B, self.Lq = B, Lq = shape
-        self.tables = jnp.asarray(tables, jnp.int32)
-        self.positions = jnp.asarray(positions, jnp.int32)
-        self.valid = jnp.asarray(valid, jnp.int32)
-        self.plan = write_plan(self.tables, self.positions, self.valid,
-                               Lq, self.bs)
-        self.rows = rows = jnp.arange(Lq, dtype=jnp.int32)
-        self.live = ((self.tables[:, :1] != 0)
-                     & (rows[None] < self.valid[:, None])).reshape(B * Lq)
-        angle = (self.positions[:, None] + rows[None]).astype(f32)[
-            ..., None] * jnp.asarray(rope_frequencies(spec))  # (B,Lq,dr/2)
+        super().__init__(tables, shape, positions, valid, block_size, at)
+        self.spec = spec
+        self.plan = self.write_plan()
+        angle = self.angles(rope_frequencies(spec))     # (B, Lq, dr/2)
         self.cos, self.sin = jnp.cos(angle), jnp.sin(angle)
         self.scale = softmax_scale(spec)
         # the indexer's selection: how many positions a query keeps
@@ -518,26 +521,29 @@ class _Step:
                             self.tables.shape[1] * self.bs)
             # positions a live query keeps: all it sees, up to ``keep``
             self.kept = jnp.where(
-                self.live.reshape(B, Lq), jnp.minimum(
-                    self.positions[:, None] + rows[None] + 1, self.keep),
+                self.live.reshape(shape), jnp.minimum(
+                    self.positions[:, None] + self.rows[None] + 1,
+                    self.keep),
                 0)
 
 
-def decoder_layer(x, p, pools, layer, dense, st, first=0):
-    """One decoder layer of a paged step over ``x`` ``(B, Lq, D)`` fp32:
-    ``p`` the layer's leaves (their names without the layer's prefix),
-    ``layer`` its index on the pool leaves' first axis, ``st`` the
-    step's :class:`_Step`.  Writes the chunk's fresh rows of every leaf
-    and attends (keys before ``first`` seen by no query).  A spec with
+def decoder_layer(x, p, pools, layer, dense, steps, first=0):
+    """One decoder layer of a paged step over ``x`` ``(N, D)`` fp32, the
+    rows of the step's groups laid end to end: ``p`` the layer's leaves
+    (their names without the layer's prefix), ``layer`` its index on the
+    pool leaves' first axis, ``steps`` a :class:`_Step` a group.  What
+    works on a TOKEN (norms, projections, the feed-forward, the experts)
+    runs ONCE over all the rows, so a weight is read once a step however
+    many groups it has; what works on a SEQUENCE runs a group, with the
+    group's shapes: each writes its fresh rows of every leaf and attends
+    (keys before ``first`` seen by no query).  A spec with
     ``sandwich_norm`` norms each sublayer's OUTPUT too before it joins
     the residual (``post_attn_norm_gamma``, ``post_ffn_norm_gamma``).
     Returns ``(x, pools, the layer's AUX_COUNTERS or None)``."""
     import jax.numpy as jnp
     from ..ops import attention as _att
 
-    spec, bs = st.spec, st.bs
-    B, Lq = st.B, st.Lq
-    N, D = B * Lq, spec["hidden_size"]
+    spec, bs = steps[0].spec, steps[0].bs
     H = spec["num_attention_heads"]
     r, dn, dr, dv = (spec["kv_lora_rank"], spec["qk_nope_head_dim"],
                      spec["qk_rope_head_dim"], spec["v_head_dim"])
@@ -545,64 +551,68 @@ def decoder_layer(x, p, pools, layer, dense, st, first=0):
     sandwich = spec.get("sandwich_norm", False)
     f32 = jnp.float32
     cdt = p["attn_norm_gamma"].dtype            # the weights' dtype
-    cos, sin = st.cos, st.sin
-    pool = pools[0]
-    W = pool.shape[3]
+    W = pools[0].shape[3]
 
-    h = _rms(x, p["attn_norm_gamma"], eps).astype(cdt).reshape(N, D)
+    h = _rms(x, p["attn_norm_gamma"], eps).astype(cdt)
     cq = _rms(_mm(h, p["q_a_weight"]), p["q_norm_gamma"], eps)
-    q = _mm(cq.astype(cdt), p["q_b_weight"]).reshape(B, Lq, H, dn + dr)
-    kv = _mm(h, p["kv_a_weight"]).astype(f32).reshape(B, Lq, r + dr)
-    latent = jnp.concatenate(
-        [_rms(kv[..., :r], p["kv_norm_gamma"], eps),
-         _rope(kv[..., r:], cos, sin),
-         jnp.zeros((B, Lq, W - r - dr), f32)], axis=-1)
-    fresh = (latent[:, None],)
-    if st.sparse:
-        qi, ki, wi = _index_parts(h, cq, p, spec, (B, Lq), cos, sin)
-        fresh += (ki[:, None],)
-    pools = pool_write(pools, layer, fresh, st.plan, bs)
-    pool = pools[0]
+    q_all = _mm(cq.astype(cdt), p["q_b_weight"])
+    kv_all = _mm(h, p["kv_a_weight"]).astype(f32)
+    if steps[0].sparse:
+        index = _index_project(h, cq, p, spec)
     wkv = _plain(p["kv_b_weight"], cdt).reshape(H, dn + dv, r)
-    q_abs = jnp.einsum("blhd,hdc->bhlc", q[..., :dn], wkv[:, :dn],
+    outs = []
+    for st in steps:
+        B, Lq, cos, sin = st.B, st.Lq, st.cos, st.sin
+        q = q_all[st.span].reshape(B, Lq, H, dn + dr)
+        kv = kv_all[st.span].reshape(B, Lq, r + dr)
+        latent = jnp.concatenate(
+            [_rms(kv[..., :r], p["kv_norm_gamma"], eps),
+             _rope(kv[..., r:], cos, sin),
+             jnp.zeros((B, Lq, W - r - dr), f32)], axis=-1)
+        fresh = (latent[:, None],)
+        if st.sparse:
+            qi, ki, wi = _index_turn(*(a[st.span] for a in index), st)
+            fresh += (ki[:, None],)
+        pools = pool_write(pools, layer, fresh, st.plan, bs)
+        pool = pools[0]
+        q_abs = jnp.einsum("blhd,hdc->bhlc", q[..., :dn], wkv[:, :dn],
+                           preferred_element_type=f32)
+        q_rope = _rope(q[..., dn:].astype(f32), cos[:, :, None],
+                       sin[:, :, None])
+        query = jnp.concatenate(
+            [q_abs, jnp.transpose(q_rope, (0, 2, 1, 3)),
+             jnp.zeros((B, H, Lq, W - r - dr), f32)], axis=-1)
+        if st.sparse:
+            scores = _att.lightning_index_scores(
+                qi.astype(pools[1].dtype), wi, pools[1], layer, st.tables,
+                st.positions, bs)
+            thr, tie = _att.sparse_select(scores, st.keep)
+            o_lat = _att.mla_attention_sparse(
+                query.astype(pool.dtype), pool, layer, st.tables,
+                st.positions, scores, thr, tie, st.kept, st.keep, bs, r,
+                st.scale)
+        else:
+            o_lat = _att.mla_attention_paged(
+                query.astype(pool.dtype), pool, layer, st.tables,
+                st.positions, bs, r, st.scale, first=first)
+        o = jnp.einsum("bhlc,hdc->blhd", o_lat.astype(cdt), wkv[:, dn:],
                        preferred_element_type=f32)
-    q_rope = _rope(q[..., dn:].astype(f32), cos[:, :, None],
-                   sin[:, :, None])
-    query = jnp.concatenate(
-        [q_abs, jnp.transpose(q_rope, (0, 2, 1, 3)),
-         jnp.zeros((B, H, Lq, W - r - dr), f32)], axis=-1)
-    if st.sparse:
-        scores = _att.lightning_index_scores(
-            qi.astype(pools[1].dtype), wi, pools[1], layer, st.tables,
-            st.positions, bs)
-        thr, tie = _att.sparse_select(scores, st.keep)
-        o_lat = _att.mla_attention_sparse(
-            query.astype(pool.dtype), pool, layer, st.tables,
-            st.positions, scores, thr, tie, st.kept, st.keep, bs, r,
-            st.scale)
-    else:
-        o_lat = _att.mla_attention_paged(
-            query.astype(pool.dtype), pool, layer, st.tables,
-            st.positions, bs, r, st.scale, first=first)
-    o = jnp.einsum("bhlc,hdc->blhd", o_lat.astype(cdt), wkv[:, dn:],
-                   preferred_element_type=f32)
-    a = _mm(o.astype(cdt).reshape(N, H * dv), p["o_weight"]) \
-        .astype(f32).reshape(B, Lq, D)
+        outs.append(o.astype(cdt).reshape(B * Lq, H * dv))
+    a = _mm(cat(outs), p["o_weight"]).astype(f32)
     if sandwich:
         a = _rms(a, p["post_attn_norm_gamma"], eps)
     x = x + a
 
-    f = _rms(x, p["ffn_norm_gamma"], eps).astype(cdt).reshape(N, D)
+    f = _rms(x, p["ffn_norm_gamma"], eps).astype(cdt)
     step = None
     if dense:
         y = _swiglu_ffn(f, p["gate_weight"], p["up_weight"],
                         p["down_weight"])
     else:
-        y, step = expert_layer(f, p, spec, st.live)
+        y, step = expert_layer(f, p, spec, cat([st.live for st in steps]))
         y = y + _swiglu_ffn(f, p["shared_gate_weight"],
                             p["shared_up_weight"],
                             p["shared_down_weight"])
-    y = y.reshape(B, Lq, D)
     if sandwich:
         # on the chip's PARTIAL sum (shared expert + held experts): in
         # a deployment this norm follows the exchange's combine
@@ -616,14 +626,22 @@ def layer_leaves(params, prefix):
             if k.startswith(prefix)}
 
 
-def paged_step_leaves(params, pools, tables, tokens, positions, valid,
-                      spec, block_size, all_logits=False, hidden=False):
-    """One PAGED step over the latent pool — ``transformer_lm.
-    paged_step_apply``'s contract: tokens ``(B, Lq)`` (``Lq = 1`` a
-    decode step), positions/valid ``(B,)``, tables ``(B, T)`` over the
-    leaves ``pools`` of the model's ``init_pool`` (the latent leaf;
-    behind it, for a spec with the ``index_*`` keys, the index keys');
-    each layer writes the chunk's fresh rows of every leaf in place
+def paged_step_groups(params, pools, groups, spec, block_size,
+                      all_logits=False, hidden=False):
+    """One PAGED step over the latent pool for a tuple of ROW GROUPS,
+    each ``(tables (B, T), tokens (B, Lq), positions (B,), valid
+    (B,))`` with a ``B`` and an ``Lq`` of its own (a tick's decode rows,
+    ``Lq = 1``, and its prompt chunk's): every layer reads its weights
+    ONCE for all the groups' rows (:func:`decoder_layer`), and the
+    groups write and attend one after the other, in the order given, as
+    that many one-group steps in that order would.  ``params`` is a
+    PACKED dict (:func:`pack_params`), plain or int8; ``pools`` the
+    leaves of the model's ``init_pool`` (the latent leaf; behind it, for
+    a spec with the ``index_*`` keys, the index keys').  The program
+    store takes a model that has this name to offer a step over more
+    than one group (``program_store.paged_program``).
+
+    Each layer writes a group's fresh rows of every leaf in place
     (``paged.pool_write``: one plan, no scatter, no slice of a pool)
     and attends through the ``mla_attention_paged`` door, ONE
     absorbed-form algorithm for every ``Lq`` — or, with an indexer,
@@ -631,49 +649,64 @@ def paged_step_leaves(params, pools, tables, tokens, positions, valid,
     finds the threshold that keeps each query's ``index_topk`` best
     positions exactly (``sparse_select``) and attends over those alone
     (``mla_attention_sparse``: gathered rows at one query a sequence,
-    the walk under the mask for a chunk).  ``params`` is a PACKED dict
-    (:func:`pack_params`), plain or int8.
+    the walk under the mask for a chunk).
 
-    Returns ``(logits, pools, counts)``: logits ``(B, vocab)`` fp32 at
-    each row's last valid position (``all_logits``: ``(B, Lq, vocab)``),
-    and :data:`AUX_COUNTERS` as one int32 vector over the step's LIVE
-    tokens (valid rows of sequences whose table owns a block): tokens
-    routed x expert layers, picks that fell on held experts, the
-    fullest held expert's count summed over the layers, expert layers,
-    held experts that got a token summed over the layers, and how
-    often the grouped product streamed an expert's weights for them
-    (``ops/moe.expert_streams``; once a touched expert is the floor).
-    ``hidden``: a fourth result, the last layer's output BEFORE the
-    final norm, ``(B, Lq, D)`` fp32 (what a prediction module drafts
-    from: ``models/pangu_ultra_moe.py``)."""
+    Returns ``(logits a group, pools, counts)``: a group's logits ``(B,
+    vocab)`` fp32 at each row's last valid position (``all_logits``:
+    ``(B, Lq, vocab)``), and :data:`AUX_COUNTERS` as one int32 vector
+    over the step's LIVE tokens (valid rows of sequences whose table
+    owns a block), all groups together: tokens routed x expert layers,
+    picks that fell on held experts, the fullest held expert's count
+    summed over the layers, expert layers, held experts that got a
+    token summed over the layers, and how often the grouped product
+    streamed an expert's weights for them (``ops/moe.expert_streams``;
+    once a touched expert is the floor).  ``hidden``: a fourth result,
+    a group's last layer output BEFORE the final norm, ``(B, Lq, D)``
+    fp32 (what a prediction module drafts from:
+    ``models/pangu_ultra_moe.py``)."""
     import jax.numpy as jnp
 
     pools = tuple(pools)
     L, D = spec["num_hidden_layers"], spec["hidden_size"]
-    B, Lq = tokens.shape
-    N = B * Lq
     f32 = jnp.float32
     cdt = params["final_norm_gamma"].dtype      # the weights' dtype
-    st = _Step(tables, (B, Lq), positions, valid, spec, block_size)
+    steps, tokens = row_groups(groups, block_size, _Step, spec=spec)
     counts = jnp.zeros((len(AUX_COUNTERS),), jnp.int32)
 
-    x = _embed(params["embed_weight"], tokens).astype(f32)   # (B, Lq, D)
+    x = _embed(params["embed_weight"], tokens).astype(f32)    # (N, D)
     for i in range(L):
         x, pools, step = decoder_layer(
             x, layer_leaves(params, "l%d_" % i), pools, i,
-            _is_dense(spec, i), st)
+            _is_dense(spec, i), steps)
         if step is not None:
             counts = counts + step
     hN = _rms(x, params["final_norm_gamma"], spec["rms_norm_eps"]) \
         .astype(cdt)
     if all_logits:
-        logits = _mm(hN.reshape(N, D), params["head_weight"], f32).reshape(
-            B, Lq, spec["vocab_size"])
+        every = _mm(hN, params["head_weight"], f32).astype(f32)
+        logits = tuple(every[st.span].reshape(st.B, st.Lq, -1)
+                       for st in steps)
     else:
-        logits = _mm(hN[jnp.arange(B), st.valid - 1],
-                     params["head_weight"], f32)
-    out = (logits.astype(f32), pools, counts)
-    return out + (x,) if hidden else out
+        logits = last_logits(hN, steps, lambda last: _mm(
+            last, params["head_weight"], f32).astype(f32))
+    out = (logits, pools, counts)
+    if hidden:
+        out += (tuple(x[st.span].reshape(st.B, st.Lq, D)
+                      for st in steps),)
+    return out
+
+
+def paged_step_leaves(params, pools, tables, tokens, positions, valid,
+                      spec, block_size, all_logits=False, hidden=False):
+    """:func:`paged_step_groups` of ONE group — ``transformer_lm.
+    paged_step_apply``'s contract: tokens ``(B, Lq)`` (``Lq = 1`` a
+    decode step), positions/valid ``(B,)``, tables ``(B, T)``.  Returns
+    ``(logits, pools, counts)`` and, with ``hidden``, the hidden states
+    behind them, the one group's own."""
+    out = paged_step_groups(
+        params, pools, ((tables, tokens, positions, valid),), spec,
+        block_size, all_logits=all_logits, hidden=hidden)
+    return (out[0][0], out[1], out[2]) + tuple(h[0] for h in out[3:])
 
 
 def paged_step(params, pools, tables, tokens, positions, valid, spec,

@@ -30,12 +30,13 @@ from __future__ import annotations
 from ..base import MXNetError
 from . import deepseek_v3 as _v3
 from .deepseek_v3 import (AUX_COUNTERS, OFFERS,  # noqa: F401
-                          QUANTIZE_TAKES_LEAVES, pack_params, paged_step)
+                          QUANTIZE_TAKES_LEAVES, pack_params, paged_step,
+                          paged_step_groups)
 
 __all__ = ["serving_spec", "param_shapes", "random_params",
            "required_params", "matmul_weights", "pack_params",
-           "quantize_params", "init_pool", "paged_step", "OFFERS",
-           "AUX_COUNTERS"]
+           "quantize_params", "init_pool", "paged_step",
+           "paged_step_groups", "OFFERS", "AUX_COUNTERS"]
 
 _INDEX_KEYS = ("index_n_heads", "index_head_dim", "index_topk")
 

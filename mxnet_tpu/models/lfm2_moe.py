@@ -43,14 +43,17 @@ import numpy as np
 
 from ..base import MXNetError
 from .deepseek_v3 import (_mm, _rms, _swiglu_ffn, expert_layer,
-                          pack_params, quantize_leaves, random_leaves)
-from .paged import pool_write, state_read, state_write, write_plan
+                          layer_leaves, pack_params, quantize_leaves,
+                          random_leaves)
+from .paged import (cat, last_logits, pool_write, row_groups, state_read,
+                    state_write)
 from .transformer_lm import _embed
 
 __all__ = ["serving_spec", "param_shapes", "random_params",
            "required_params", "matmul_weights", "pack_params",
            "quantize_params", "init_pool", "paged_step_apply",
-           "paged_step", "OFFERS", "AUX_COUNTERS", "ROUTE_EPS"]
+           "paged_step", "paged_step_groups", "OFFERS", "AUX_COUNTERS",
+           "ROUTE_EPS"]
 
 # what of the serving plane this model can be put on besides the paged
 # plane with in-graph or host sampling (program_store asks)
@@ -234,24 +237,32 @@ def short_conv(z, taps):
     return sum(taps[:, j] * z[:, j:j + Lq] for j in range(n))
 
 
-def paged_step_apply(params, kv, state, tables, tokens, positions, valid,
-                     spec, block_size, all_logits=False):
-    """One PAGED step — ``transformer_lm.paged_step_apply``'s contract
-    over the two leaves of :func:`init_pool`: tokens ``(B, Lq)`` (``Lq
-    = 1`` a decode step), positions/valid ``(B,)``, tables ``(B, T)``.
-    An attention layer writes the chunk's ``[K | V]`` rows in place
+def paged_step_groups(params, pools, groups, spec, block_size,
+                      all_logits=False):
+    """One PAGED step over the two leaves of :func:`init_pool` for a
+    tuple of ROW GROUPS, each ``(tables (B, T), tokens (B, Lq),
+    positions (B,), valid (B,))`` with a ``B`` and an ``Lq`` of its own
+    (a tick's decode rows, ``Lq = 1``, and its prompt chunk's).  What
+    works on a TOKEN (norms, projections, the feed-forward, the experts,
+    the head) runs ONCE over all the groups' rows laid end to end, so a
+    weight is read once a step; what works on a SEQUENCE runs a group,
+    in the order given, as that many one-group steps would: an
+    attention layer writes the group's ``[K | V]`` rows in place
     (``paged.pool_write``) and attends through the ``sdp_attention_
     paged`` door, the query heads of a KV head in one tile; a
     convolution layer takes the state its sequences bring from the row
     of the block before (``paged.state_read``), runs the filter over
     the chunk and leaves the state after each written block's last
     token in that block's row (``paged.state_write``).  ``params`` is a
-    PACKED dict (``pack_params``), plain or int8.
+    PACKED dict (``pack_params``), plain or int8.  The program store
+    takes a model that has this name to offer a step over more than one
+    group (``program_store.paged_program``).
 
-    Returns ``(logits, kv, state, counts)``: logits ``(B, vocab)`` fp32
-    at each row's last valid position (``all_logits``: ``(B, Lq,
-    vocab)``), and :data:`AUX_COUNTERS` summed over the expert layers
-    (``deepseek_v3.paged_step_apply`` tells them)."""
+    Returns ``(logits a group, (kv, state), counts)``: a group's logits
+    ``(B, vocab)`` fp32 at each row's last valid position
+    (``all_logits``: ``(B, Lq, vocab)``), and :data:`AUX_COUNTERS`
+    summed over the expert layers, all groups together
+    (``deepseek_v3.paged_step_groups`` tells them)."""
     import jax.numpy as jnp
     from ..ops.attention import sdp_attention_paged
 
@@ -260,81 +271,99 @@ def paged_step_apply(params, kv, state, tables, tokens, positions, valid,
     eps = spec["norm_eps"]
     past = spec["conv_L_cache"] - 1
     bs = int(block_size)
-    B, Lq = tokens.shape
-    N = B * Lq
     f32 = jnp.float32
     cdt = params["final_norm_gamma"].dtype      # the weights' dtype
-    tables = jnp.asarray(tables, jnp.int32)
-    positions = jnp.asarray(positions, jnp.int32)
-    valid = jnp.asarray(valid, jnp.int32)
-    plan = write_plan(tables, positions, valid, Lq, bs)
-    rows = jnp.arange(Lq, dtype=jnp.int32)
-    live = ((tables[:, :1] != 0) & (rows[None] < valid[:, None])) \
-        .reshape(N)
+    kv, state = pools
+    gs, tokens = row_groups(groups, bs)
+    live = cat([g.live for g in gs])
     freqs = 1.0 / spec["rope_theta"] ** (
         np.arange(0, dh, 2, dtype=np.float64) / dh)
-    angle = (positions[:, None] + rows[None]).astype(f32)[..., None] \
-        * jnp.asarray(freqs, f32)                        # (B, Lq, dh/2)
-    cos, sin = jnp.cos(angle)[:, :, None], jnp.sin(angle)[:, :, None]
+    for g in gs:
+        angle = g.angles(freqs)[:, :, None]             # (B, Lq, 1, dh/2)
+        g.plan, g.cos, g.sin = g.write_plan(), jnp.cos(angle), jnp.sin(angle)
     counts = jnp.zeros((len(AUX_COUNTERS),), jnp.int32)
     n_att = n_conv = 0
 
     embed = params["embed_tokens_weight"]       # the head too: tied
-    x = _embed(embed, tokens).astype(f32)                    # (B, Lq, D)
+    x = _embed(embed, tokens).astype(f32)                    # (N, D)
     for i, kind in enumerate(spec["layer_types"]):
-        p = {k[len("l%d_" % i):]: v for k, v in params.items()
-             if k.startswith("l%d_" % i)}
-        h = _rms(x, p["op_norm_gamma"], eps).astype(cdt).reshape(N, D)
+        p = layer_leaves(params, "l%d_" % i)
+        h = _rms(x, p["op_norm_gamma"], eps).astype(cdt)
+        outs = []
         if kind == "conv":
-            bcu = _mm(h, p["in_weight"]).astype(f32).reshape(B, Lq, 3, D)
-            # rounded as the state leaf holds it, whatever row of a
-            # chunk it is read back in
-            z = (bcu[:, :, 0] * bcu[:, :, 2]).astype(state.dtype)
-            trail = jnp.concatenate(
-                [state_read(state, n_conv, tables, positions, bs)
-                 .reshape(B, past, D), z], axis=1)
-            y = bcu[:, :, 1] * short_conv(trail, p["conv_weight"])
-            out = _mm(y.astype(cdt).reshape(N, D), p["out_weight"])
-            state = state_write(state, n_conv, trail, plan, Lq, bs)
+            bcu_all = _mm(h, p["in_weight"]).astype(f32)
+            for g in gs:
+                B, Lq = g.B, g.Lq
+                bcu = bcu_all[g.span].reshape(B, Lq, 3, D)
+                # rounded as the state leaf holds it, whatever row of a
+                # chunk it is read back in
+                z = (bcu[:, :, 0] * bcu[:, :, 2]).astype(state.dtype)
+                trail = jnp.concatenate(
+                    [state_read(state, n_conv, g.tables, g.positions, bs)
+                     .reshape(B, past, D), z], axis=1)
+                y = bcu[:, :, 1] * short_conv(trail, p["conv_weight"])
+                outs.append(y.astype(cdt).reshape(B * Lq, D))
+                state = state_write(state, n_conv, trail, g.plan, Lq, bs)
+            out = _mm(cat(outs), p["out_weight"])
             n_conv += 1
         else:
-            q = _mm(h, p["q_weight"]).reshape(B, Lq, H, dh)
-            k = _mm(h, p["k_weight"]).reshape(B, Lq, Hkv, dh)
-            v = _mm(h, p["v_weight"]).reshape(B, Lq, Hkv, dh)
-            q = _rope(_rms(q, p["q_norm_gamma"], eps), cos, sin)
-            k = _rope(_rms(k, p["k_norm_gamma"], eps), cos, sin)
-            fresh = jnp.concatenate([k, v.astype(f32)], axis=-1)
-            kv, = pool_write((kv,), n_att,
-                             (jnp.transpose(fresh, (0, 2, 1, 3)),), plan,
-                             bs)
-            # the row is key and value: a query that is zero over the
-            # value half scores the key half alone, and the value half
-            # of the result is the attention's output
-            query = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
-            att = sdp_attention_paged(
-                jnp.transpose(query, (0, 2, 1, 3)).astype(kv.dtype), kv,
-                None, n_att, tables, positions, bs, scale=dh ** -0.5,
-                group=KV_GROUP)[..., dh:]
-            out = _mm(jnp.transpose(att, (0, 2, 1, 3)).astype(cdt)
-                      .reshape(N, H * dh), p["o_weight"])
+            q_all = _mm(h, p["q_weight"])
+            k_all = _mm(h, p["k_weight"])
+            v_all = _mm(h, p["v_weight"])
+            for g in gs:
+                B, Lq = g.B, g.Lq
+                q = q_all[g.span].reshape(B, Lq, H, dh)
+                k = k_all[g.span].reshape(B, Lq, Hkv, dh)
+                v = v_all[g.span].reshape(B, Lq, Hkv, dh)
+                q = _rope(_rms(q, p["q_norm_gamma"], eps), g.cos, g.sin)
+                k = _rope(_rms(k, p["k_norm_gamma"], eps), g.cos, g.sin)
+                fresh = jnp.concatenate([k, v.astype(f32)], axis=-1)
+                kv, = pool_write((kv,), n_att,
+                                 (jnp.transpose(fresh, (0, 2, 1, 3)),),
+                                 g.plan, bs)
+                # the row is key and value: a query that is zero over
+                # the value half scores the key half alone, and the
+                # value half of the result is the attention's output
+                query = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
+                att = sdp_attention_paged(
+                    jnp.transpose(query, (0, 2, 1, 3)).astype(kv.dtype),
+                    kv, None, n_att, g.tables, g.positions, bs,
+                    scale=dh ** -0.5, group=KV_GROUP)[..., dh:]
+                outs.append(jnp.transpose(att, (0, 2, 1, 3)).astype(cdt)
+                            .reshape(B * Lq, H * dh))
+            out = _mm(cat(outs), p["o_weight"])
             n_att += 1
-        x = x + out.astype(f32).reshape(B, Lq, D)
+        x = x + out.astype(f32)
 
-        f = _rms(x, p["ffn_norm_gamma"], eps).astype(cdt).reshape(N, D)
+        f = _rms(x, p["ffn_norm_gamma"], eps).astype(cdt)
         if _is_dense(spec, i):
             y = _swiglu_ffn(f, p["gate_weight"], p["up_weight"],
                             p["down_weight"])
         else:
             y, step = expert_layer(f, p, spec, live, ROUTE_EPS)
             counts = counts + step
-        x = x + y.reshape(B, Lq, D)
+        x = x + y
     hN = _rms(x, params["final_norm_gamma"], eps).astype(cdt)
     if all_logits:
-        logits = _mm(hN.reshape(N, D), embed, f32).reshape(
-            B, Lq, spec["vocab_size"])
+        every = _mm(hN, embed, f32).astype(f32)
+        logits = tuple(every[g.span].reshape(g.B, g.Lq, -1) for g in gs)
     else:
-        logits = _mm(hN[jnp.arange(B), valid - 1], embed, f32)
-    return logits.astype(f32), kv, state, counts
+        logits = last_logits(hN, gs, lambda last: _mm(
+            last, embed, f32).astype(f32))
+    return logits, (kv, state), counts
+
+
+def paged_step_apply(params, kv, state, tables, tokens, positions, valid,
+                     spec, block_size, all_logits=False):
+    """:func:`paged_step_groups` of ONE group — ``transformer_lm.
+    paged_step_apply``'s contract over the two leaves of
+    :func:`init_pool`: tokens ``(B, Lq)`` (``Lq = 1`` a decode step),
+    positions/valid ``(B,)``, tables ``(B, T)``.  Returns ``(logits,
+    kv, state, counts)``."""
+    (logits,), (kv, state), counts = paged_step_groups(
+        params, (kv, state), ((tables, tokens, positions, valid),), spec,
+        block_size, all_logits=all_logits)
+    return logits, kv, state, counts
 
 
 def paged_step(params, pools, tables, tokens, positions, valid, spec,

@@ -20,6 +20,82 @@ copy-on-write fork carries a block's row with its tokens
 """
 
 
+class RowGroup:
+    """One ROW GROUP of a paged step: ``B`` sequences of ``Lq`` rows
+    each, with their block tables ``(B, T)``, first positions and valid
+    counts ``(B,)``.  A step may take several (a tick's decode rows,
+    ``Lq = 1``, and its prompt chunk's): what a layer does to a TOKEN
+    runs over all the groups' rows laid end to end, and ``span`` says
+    where this group's ``B * Lq`` lie among them (``at``: the rows of
+    the groups before it); what it does to a SEQUENCE runs a group.
+    ``live``: the rows that are real tokens, ``(B * Lq,)`` (valid rows
+    of sequences whose table owns a block)."""
+
+    def __init__(self, tables, shape, positions, valid, block_size, at=0):
+        import jax.numpy as jnp
+        self.B, self.Lq = B, Lq = shape
+        self.bs = int(block_size)
+        self.span = slice(at, at + B * Lq)
+        self.tables = jnp.asarray(tables, jnp.int32)
+        self.positions = jnp.asarray(positions, jnp.int32)
+        self.valid = jnp.asarray(valid, jnp.int32)
+        self.rows = jnp.arange(Lq, dtype=jnp.int32)
+        self.live = ((self.tables[:, :1] != 0)
+                     & (self.rows[None] < self.valid[:, None])) \
+            .reshape(B * Lq)
+
+    def write_plan(self, tables=None):
+        """:func:`write_plan` of the group's rows through ``tables``
+        (the group's own; a model with classes of block: a class's)."""
+        return write_plan(self.tables if tables is None else tables,
+                          self.positions, self.valid, self.Lq, self.bs)
+
+    def angles(self, freqs):
+        """The rotary angles of the group's rows, ``(B, Lq, len(freqs))``
+        float32: row ``r`` of a sequence sits at ``positions + r``."""
+        import jax.numpy as jnp
+        at = (self.positions[:, None] + self.rows[None]).astype(jnp.float32)
+        return at[..., None] * jnp.asarray(freqs, jnp.float32)
+
+    def last(self, h):
+        """Each sequence's last valid row of the group's span of ``h``
+        ``(N, D)``: ``(B, D)``."""
+        import jax.numpy as jnp
+        return h[self.span].reshape(self.B, self.Lq, -1)[
+            jnp.arange(self.B), self.valid - 1]
+
+
+def row_groups(groups, block_size, cls=RowGroup, **more):
+    """A step's ``groups`` ``(tables, tokens, positions, valid)`` as
+    :class:`RowGroup`s (``cls``: a model's own, ``more`` its further
+    arguments) laid end to end, and their tokens that way: ``(N,)``."""
+    import jax.numpy as jnp
+    out, at = [], 0
+    for tables, tokens, positions, valid in groups:
+        out.append(cls(tables, tuple(tokens.shape), positions, valid,
+                       block_size=block_size, at=at, **more))
+        at = out[-1].span.stop
+    return out, cat([jnp.asarray(g[1]).reshape(-1) for g in groups])
+
+
+def cat(parts, axis=0):
+    """The groups' ``parts`` laid end to end; ONE group's as it is (a
+    one-group step traces what it always traced)."""
+    import jax.numpy as jnp
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis)
+
+
+def last_logits(hN, groups, head):
+    """``head(the groups' last valid rows of hN, laid end to end)``
+    ONCE, and handed back a group: a tuple of ``(B, vocab)``."""
+    every = head(cat([g.last(hN) for g in groups]))
+    out, at = [], 0
+    for g in groups:
+        out.append(every[at:at + g.B])
+        at += g.B
+    return tuple(out)
+
+
 def write_plan(tables, positions, valid, Lq, block_size):
     """Where a step's fresh cache rows go in a layer of the pool: the
     same for every layer, so computed once a program.

@@ -46,12 +46,13 @@ from __future__ import annotations
 from ..base import MXNetError
 from . import deepseek_v3 as _v3
 from .deepseek_v3 import (AUX_COUNTERS,  # noqa: F401
-                          QUANTIZE_TAKES_LEAVES, pack_params)
+                          QUANTIZE_TAKES_LEAVES, pack_params,
+                          paged_step_groups)
 
 __all__ = ["serving_spec", "with_draft", "param_shapes", "random_params",
            "required_params", "matmul_weights", "pack_params",
-           "quantize_params", "init_pool", "paged_step", "draft_step",
-           "OFFERS", "AUX_COUNTERS"]
+           "quantize_params", "init_pool", "paged_step",
+           "paged_step_groups", "draft_step", "OFFERS", "AUX_COUNTERS"]
 
 # a decode step that verifies the module's proposal and yields one or
 # two tokens (program_store: ``self_draft``)
@@ -174,10 +175,10 @@ def draft_step(params, pools, tables, hidden, tokens, positions, valid,
         [_v3._rms(hidden, params["mtp_h_norm_gamma"], eps),
          _v3._rms(e, params["mtp_e_norm_gamma"], eps)], axis=-1)
     u = _v3._mm(u.astype(cdt).reshape(B * Lq, 2 * D),
-                params["mtp_eh_weight"]).astype(f32).reshape(B, Lq, D)
+                params["mtp_eh_weight"]).astype(f32)
     v, pools, counts = _v3.decoder_layer(
         u, _v3.layer_leaves(params, "mtp_"), tuple(pools),
-        spec["num_hidden_layers"], False, st, first=1)
-    last = v[jnp.arange(B), st.valid - 1]
+        spec["num_hidden_layers"], False, (st,), first=1)
+    last = v.reshape(B, Lq, D)[jnp.arange(B), st.valid - 1]
     vN = _v3._rms(last, params["mtp_final_norm_gamma"], eps).astype(cdt)
     return _v3._mm(vN, params["head_weight"], f32), pools, counts

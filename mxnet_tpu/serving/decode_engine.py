@@ -70,10 +70,12 @@ with :class:`~.scheduler.ServeClosed`.
 from __future__ import annotations
 
 import collections
+import contextlib
 import queue
 import sys
 import threading
 import time
+import types
 from concurrent.futures import Future
 
 import jax
@@ -912,6 +914,13 @@ class GenerationEngine:
              # whose top_k cuts it): what sample_tokens' two conds
              # took, counted from the host's copy of their inputs
              "sample_draw_dispatches", "sample_topk_dispatches",
+             # step programs of the store a paged tick queued (a decode
+             # step, a prompt chunk, a self-draft's two each; not a
+             # speculative draft's own, spec_draft_steps), and the
+             # ticks queued as ONE program for decode rows and chunk
+             # rows alike (store.one_pass): tick_programs over the
+             # ticks is "programs a tick"
+             "tick_programs", "tick_one_pass",
              # admissions whose per-sequence state (a model with state
              # leaves) came from the prefix cache with the blocks;
              # prompt_tokens_admitted is what prefix_hit_tokens is a
@@ -1936,7 +1945,17 @@ class GenerationEngine:
         on EVERY tick, not only at admission: it computes no block the
         cache has or another row of the dispatch is computing
         (:meth:`_paged_prefill_chunk`), so a burst over one new
-        document prefills it once."""
+        document prefills it once.
+
+        A tick has one of three shapes.  Decode rows only: the decode
+        program.  Prompt rows, with or without decode rows, on a store
+        whose model steps over row groups (``store.one_pass``): ONE
+        program and ONE fetch, the decode rows and the chunk's rows as
+        two groups of one step that reads the weights once
+        (:meth:`_paged_one_pass`; without decode rows that group rides
+        dead).  Prompt rows on any other store (``transformer_lm``,
+        host sampling, an int8 pool, a speculative draft, a self-draft):
+        the decode program, then the chunk program, as below."""
         dec = [i for i in st.active() if st.decoding[i]]
         pre = sorted((i for i in st.active() if not st.decoding[i]),
                      key=lambda i: st.slots[i].t_admit)
@@ -1948,14 +1967,17 @@ class GenerationEngine:
         # the device goes from one program to the next while the host
         # resolves the first one's tokens, and not after it.
         resolve = []
-        if dec:
+        one_pass = bool(pre) and st.store.one_pass and st.draft is None
+        if one_pass:
+            resolve.append(self._paged_one_pass(model, st, dec, pre))
+        elif dec:
             if st.self_draft:
                 resolve.append(self._paged_self_draft_step(model, st, dec))
             elif st.draft is not None and self._spec_active(st):
                 self._paged_spec_step(model, st, dec)
             else:
                 resolve.append(self._paged_decode_step(model, st, dec))
-        if pre:
+        if pre and not one_pass:
             resolve.append(self._paged_prefill_chunk(model, st, pre))
         for finish in resolve:
             if finish is not None:
@@ -2018,29 +2040,17 @@ class GenerationEngine:
             st.take_draft(st.draft.copy_block(*st.dpools, b, nb,
                                               scales=st.dscales), head=0)
 
-    def _paged_dispatch(self, st, tables, toks, pos, val, do, phase,
-                        live, slots=None, after=None, **counts):
-        """Queue one unified paged step (decode OR prompt chunk —
-        ``phase`` names it for the profiler/traces) and hand back the
-        FETCH of its one sampled token per ``do`` row (a call that
-        blocks until the program is through and returns the host-side
-        np result): the caller may queue the next program before it
-        asks.  Same graph/host sampling
-        split as the contiguous plane's ``_decode_and_sample``.  A
-        decode step's rows are the slots; a prompt chunk's are
-        compacted, row ``k`` working for slot ``slots[k]``.  The span
-        carries what attention has to read: ``rows`` (``live``, the
-        rows of the arrays this dispatch works for), ``kv_tokens``,
-        the sum of their frontiers after the step, and ``q_tokens``,
-        the query rows they bring (one each in a decode step); the
-        caller's ``counts`` ride beside them, and ``sample_draw`` /
-        ``sample_topk``: whether the sampler draws, and sorts, for
-        this dispatch (``sample_tokens``' two predicates, taken from
-        the host's copy of its inputs).  A SELF-DRAFTING store's chunk
-        (``after``: the prompt token behind each row's chunk) queues the
-        prediction module's program behind the target's, unfetched; the
-        one array fetched carries the module's proposals too, left in
-        ``st.chunk_props`` a row."""
+    def _paged_work(self, st, pos, val, live, slots=None, **counts):
+        """What one group of a dispatch (a decode step's rows or a
+        prompt chunk's) has to do, as its span tells it, and the
+        sampler's inputs for its rows: ``(temps, top_ks, work)``.
+        ``work``: ``rows`` (``live``, the rows of the arrays the
+        dispatch works for), ``kv_tokens``, the sum of their frontiers
+        after the step, and ``q_tokens``, the query rows they bring
+        (one each in a decode step); the caller's ``counts`` beside
+        them, and ``sample_draw`` / ``sample_topk``: whether the
+        sampler draws, and sorts, for this group (``sample_tokens``'
+        two predicates, taken from the host's copy of its inputs)."""
         temps, top_ks = st.temps, st.top_ks
         if slots is not None:
             temps, top_ks = temps[slots], top_ks[slots]
@@ -2074,6 +2084,28 @@ class GenerationEngine:
             self._stats.inc("dsa_queries", work["q_tokens"])
             self._stats.inc("dsa_index_pairs", work["index_pairs"])
             self._stats.inc("dsa_keys_selected", work["keys_selected"])
+        return temps, top_ks, work
+
+    def _paged_dispatch(self, st, tables, toks, pos, val, do, phase,
+                        live, slots=None, after=None, **counts):
+        """Queue one unified paged step (decode OR prompt chunk —
+        ``phase`` names it for the profiler/traces) and hand back the
+        FETCH of its one sampled token per ``do`` row (a call that
+        blocks until the program is through and returns the host-side
+        np result): the caller may queue the next program before it
+        asks.  Same graph/host sampling
+        split as the contiguous plane's ``_decode_and_sample``.  A
+        decode step's rows are the slots; a prompt chunk's are
+        compacted, row ``k`` working for slot ``slots[k]``.  The span
+        carries what attention has to read (:meth:`_paged_work`, the
+        caller's ``counts`` beside it).  A SELF-DRAFTING store's chunk
+        (``after``: the prompt token behind each row's chunk) queues the
+        prediction module's program behind the target's, unfetched; the
+        one array fetched carries the module's proposals too, left in
+        ``st.chunk_props`` a row."""
+        temps, top_ks, work = self._paged_work(st, pos, val, live, slots,
+                                               **counts)
+        self._stats.inc("tick_programs", 1 if after is None else 2)
         if st.store.sample_mode == "graph":
             with _profiler.phase(phase, **work):
                 self._starved.launching()
@@ -2152,38 +2184,66 @@ class GenerationEngine:
             self._release_paged_slot(st, i)
             self._fail_request(r, exc, running=True)
 
+    def _decode_rows(self, st, dec):
+        """Lay out a tick's decode group (inside the caller's
+        ``serve_prepare``): every generating slot of ``dec`` brings its
+        pending token at its frontier, the write position made ready
+        first.  Slots mid-prefill (and empty slots) ride with all-zero
+        tables — they reach only the trash block and their outputs are
+        discarded.  Returns ``(idx, (tables, toks, pos, val, do),
+        traces)``."""
+        idx = np.asarray(dec, np.intp)
+        # the write position this step: COW-fork or allocate first,
+        # for the rows that enter a block (st.ready)
+        at = st.lengths[idx] // st.store.kv_block
+        for i in idx[at != st.ready[idx]]:
+            self._paged_write_ready(st, int(i), [int(st.lengths[i])])
+        st.ready[idx] = at
+        n = len(st.slots)
+        tables = np.zeros((n, st.tb), np.int32)
+        toks = np.zeros((n, 1), np.int32)
+        pos = np.zeros((n,), np.int32)
+        val = np.ones((n,), np.int32)
+        do = np.zeros((n,), bool)
+        tables[idx] = st.tables[idx]
+        toks[idx, 0] = st.next_tok[idx]
+        pos[idx] = st.lengths[idx]
+        do[idx] = True
+        traces = [(st.slots[i].trace, st.slots[i].trace_parent)
+                  for i in dec]
+        return idx, (tables, toks, pos, val, do), traces
+
+    def _decode_resolve(self, st, dec, idx, sampled):
+        """The decode group's tokens, fetched, to their requests."""
+        with _profiler.phase("serve_resolve",
+                             tokens=len(dec)) as span:
+            st.lengths[idx] += 1
+            st.next_tok[idx] = sampled[idx]
+            for i, tok in zip(dec, sampled[idx].tolist()):
+                r = st.slots[i]
+                self._push_token(r, tok)
+                reason = self._finished_reason(r, tok)
+                if reason:
+                    self._release_paged_slot(st, i)
+                    self._finish(r, reason)
+                    span.add(finished=1)
+                elif st.window is not None:
+                    self._release_behind(st, i)
+        self._stats.inc("decode_steps")
+        self._stats.inc("generated_tokens", len(dec))
+
     def _paged_decode_step(self, model, st, dec):
         """Advance every generating slot one token (serve_decode
-        phase).  Slots mid-prefill (and empty slots) ride the dispatch
-        with all-zero tables — they reach only the trash block and
-        their outputs are discarded.  Returns what is left to do once
-        the program is queued — fetch the tokens and resolve them — for
-        :meth:`_paged_tick` to call after it has queued the prompt
-        chunk too (None: the dispatch failed)."""
+        phase).  Returns what is left to do once the program is queued
+        — fetch the tokens and resolve them — for :meth:`_paged_tick`
+        to call after it has queued the prompt chunk too (None: the
+        dispatch failed)."""
         with _profiler.phase("serve_prepare"):
-            idx = np.asarray(dec)
-            # the write position this step: COW-fork or allocate
-            # first, for the rows that enter a block (st.ready)
-            at = st.lengths[idx] // st.store.kv_block
-            for i in idx[at != st.ready[idx]]:
-                self._paged_write_ready(st, int(i), [int(st.lengths[i])])
-            st.ready[idx] = at
-            n = len(st.slots)
-            tables = np.zeros((n, st.tb), np.int32)
-            toks = np.zeros((n, 1), np.int32)
-            pos = np.zeros((n,), np.int32)
-            val = np.ones((n,), np.int32)
-            do = np.zeros((n,), bool)
-            tables[idx] = st.tables[idx]
-            toks[idx, 0] = st.next_tok[idx]
-            pos[idx] = st.lengths[idx]
-            do[idx] = True
-            traces = [(st.slots[i].trace, st.slots[i].trace_parent)
-                      for i in dec]
+            idx, group, traces = self._decode_rows(st, dec)
         try:
             with _tracing.activate_many(traces):
                 fetch = self._paged_dispatch(
-                    st, tables, toks, pos, val, do, "serve_decode", dec)
+                    st, *group, "serve_decode", dec)
         except BaseException as e:  # noqa: BLE001 — to the futures
             self._paged_failed(model, st, dec, e, "decode")
             return None
@@ -2195,22 +2255,69 @@ class GenerationEngine:
             except BaseException as e:  # noqa: BLE001
                 self._paged_failed(model, st, dec, e, "decode")
                 return
-            with _profiler.phase("serve_resolve",
-                                 tokens=len(dec)) as span:
-                st.lengths[idx] += 1
-                st.next_tok[idx] = sampled[idx]
-                for i, tok in zip(dec, sampled[idx].tolist()):
-                    r = st.slots[i]
-                    self._push_token(r, tok)
-                    reason = self._finished_reason(r, tok)
-                    if reason:
-                        self._release_paged_slot(st, i)
-                        self._finish(r, reason)
-                        span.add(finished=1)
-                    elif st.window is not None:
-                        self._release_behind(st, i)
-            self._stats.inc("decode_steps")
-            self._stats.inc("generated_tokens", len(dec))
+            self._decode_resolve(st, dec, idx, sampled)
+        return finish
+
+    def _paged_one_pass(self, model, st, dec, pre):
+        """A tick with prompt rows as ONE program and ONE fetch
+        (``store.one_pass``): the decode group of ``dec`` and the chunk
+        of ``pre`` are laid out as the two-program tick lays them out,
+        in its order (:meth:`_decode_rows`, then :meth:`_chunk_rows`
+        with its late adoption and its one writer a block), queued as
+        the two row groups of ``paged_tick_sample``, fetched as one
+        array and resolved in the two-program tick's order: decode
+        rows, then chunk rows.  The tokens are the two-program tick's.
+        With ``dec`` empty the decode group rides dead, as a decode
+        step's dead rows do.  The dispatch is told by BOTH spans, each
+        with its own group's counts, the one launch inside them: what
+        reads a kernel's required work from ``serve_decode`` and
+        ``serve_prefill`` reads what it read (no ``serve_decode`` where
+        no row decodes).  A dispatch or a fetch that raises fails the
+        slots of both groups.  Returns what is left once the program
+        is queued, as :meth:`_paged_decode_step` does."""
+        with _profiler.phase("serve_prepare") as span:
+            idx, (dtables, dtoks, dpos, dval, ddo), dtraces = \
+                self._decode_rows(st, dec)
+            c = self._chunk_rows(st, pre, span)
+        both = list(dec) + c.live
+        try:
+            dwork = self._paged_work(st, dpos, dval, dec)[2] if dec else None
+            temps, top_ks, work = self._paged_work(
+                st, c.pos, c.val, np.arange(len(c.rows)), c.slots,
+                width=c.n, deferred=c.deferred)
+            with _tracing.activate_many(dtraces), \
+                    _profiler.phase("serve_decode", **dwork) if dec \
+                    else contextlib.nullcontext(), \
+                    _tracing.activate_many(c.traces), \
+                    _profiler.phase("serve_prefill", **work):
+                self._starved.launching()
+                out = st.store.run_paged_tick_sample(
+                    *st.pools, c.tables, c.toks, c.pos, c.val, st.keys,
+                    temps, top_ks, c.do, c.slots, dtables, dtoks, dpos,
+                    dval, st.temps, st.top_ks, ddo)
+                queued = self._starved.dispatched()
+                toks_dev, st.keys = st.take(out)
+            self._stats.inc("tick_programs")
+            self._stats.inc("tick_one_pass")
+        except BaseException as e:  # noqa: BLE001 — to the futures
+            self._paged_failed(model, st, both, e, "tick")
+            return None
+
+        def finish():
+            try:
+                with _tracing.activate_many(dtraces + c.traces), \
+                        _profiler.phase("serve_sample"):
+                    out = self._fetch_decode(toks_dev)
+                    self._starved.fetched(queued)
+            except BaseException as e:  # noqa: BLE001
+                self._paged_failed(model, st, both, e, "tick")
+                return
+            # decode rows' tokens, chunk rows', the model's counters
+            n = len(dtables)
+            self._count_aux(st.store.aux_counters, out[n + c.n:])
+            if dec:
+                self._decode_resolve(st, dec, idx, out[:n])
+            self._chunk_resolve(model, st, c, out[n:n + c.n])
         return finish
 
     def _spec_active(self, st):
@@ -2425,6 +2532,7 @@ class GenerationEngine:
                             st.keys, st.temps, st.top_ks, do,
                             scales=st.scales), head=2)
                     self._starved.dispatched()
+                self._stats.inc("tick_programs")
                 with _profiler.phase("serve_sample"):
                     out_toks = self._fetch_decode(out_dev)
                     n_emit = self._fetch_decode(ne_dev)
@@ -2587,6 +2695,7 @@ class GenerationEngine:
                         *st.pools, tables, out_dev, pos, ne_dev, hid,
                         packed))
                     queued = self._starved.dispatched()
+            self._stats.inc("tick_programs", 2)
         except BaseException as e:  # noqa: BLE001 — to the futures
             self._paged_failed(model, st, dec, e, "self-draft")
             return None
@@ -2617,12 +2726,13 @@ class GenerationEngine:
             self._spec_resolve(st, dec, out_toks, n_emit, win, survived)
         return finish
 
-    def _paged_prefill_chunk(self, model, st, pre):
-        """Advance the first of the prefilling slots ``pre`` (oldest
-        admission first) one prompt chunk (serve_prefill phase); the
-        rest wait a tick.  Before the rows are chosen every slot is
-        held to the prefix cache as admission held it: one whose next
-        block the cache has by now adopts it and what follows
+    def _chunk_rows(self, st, pre, span):
+        """Choose and lay out a tick's prompt chunk (inside the caller's
+        ``serve_prepare``, ``span``): the first of the prefilling slots ``pre``
+        (oldest admission first) advance one chunk; the rest wait a
+        tick.  Before the rows are chosen every slot is held to the
+        prefix cache as admission held it: one whose next block the
+        cache has by now adopts it and what follows
         (:meth:`_adopt_late`), and one whose next block a row already
         chosen is filling waits for it (ONE writer a block: the older
         slot writes, :meth:`_register_filled` pins, the waiter adopts
@@ -2630,80 +2740,137 @@ class GenerationEngine:
         retires blocks no one).  The dispatch is compacted:
         ``chunk_rows(slots)`` rows, row ``k`` working for the ``k``-th
         slot chosen; rows past the live ones ride as a decode step's
-        dead rows do (zero table, one valid token, no sampling).  Rows
-        finishing their prompt this dispatch sample their first token
-        (the TTFT moment), register their blocks with the prefix cache
-        and flip to decoding.  Returns what is left once the program
-        is queued, as :meth:`_paged_decode_step` does."""
+        dead rows do (zero table, one valid token, no sampling).
+        Returns the chunk: its arrays, ``rows`` (slot, request, start,
+        tokens a live row), ``live`` (their slots), the rows it has
+        (``n``) and the slots that wait."""
         store = st.store
         chunk = store.prefill_chunk
         n = store.chunk_rows(len(st.slots))
-        with _profiler.phase("serve_prepare") as span:
-            # no slot computes a block the prefix cache has, or a row
-            # of this dispatch is computing: the keys of the blocks the
-            # chosen rows fill, and the slots that wait on one of them
-            chosen, writing = [], set()
-            waited = deferred = late_tokens = late_blocks = 0
-            for i in pre:
+        # no slot computes a block the prefix cache has, or a row
+        # of this dispatch is computing: the keys of the blocks the
+        # chosen rows fill, and the slots that wait on one of them
+        chosen, writing = [], set()
+        waited = deferred = late_tokens = late_blocks = 0
+        for i in pre:
+            key = self._next_key(st, i)
+            if key is not None and st.prefix.holds(key):
+                tokens, blocks = self._adopt_late(st, i)
+                late_tokens += tokens
+                late_blocks += blocks
                 key = self._next_key(st, i)
-                if key is not None and st.prefix.holds(key):
-                    tokens, blocks = self._adopt_late(st, i)
-                    late_tokens += tokens
-                    late_blocks += blocks
-                    key = self._next_key(st, i)
-                if key is not None and key in writing:
-                    waited += 1
-                elif len(chosen) < n:
-                    chosen.append(i)
-                    writing.add(key)
-                else:
-                    deferred += 1
-            if late_blocks or waited:
-                self._stats.inc("prefix_late_tokens", late_tokens)
-                self._stats.inc("prefix_late_blocks", late_blocks)
-                span.add(late_tokens=late_tokens, late_blocks=late_blocks,
-                         waited=waited)
-            rows = []
-            for i in chosen:
-                r = st.slots[i]
-                p0 = int(st.prog[i])
-                ntok = min(chunk, len(r.prompt) - p0)
-                # new blocks only: recomputed shared positions rewrite
-                # shared blocks with identical values (same tokens,
-                # same prefix) and must not fork.  A self-draft's
-                # module writes its row one position further
-                self._paged_write_ready(
-                    st, i, range(p0, p0 + ntok + st.self_draft),
-                    fork=False)
-                rows.append((i, r, p0, ntok))
-            tables = np.zeros((n, st.tb), np.int32)
-            toks = np.zeros((n, chunk), np.int32)
-            pos = np.zeros((n,), np.int32)
-            val = np.ones((n,), np.int32)
-            do = np.zeros((n,), bool)
-            slots = np.zeros((n,), np.int32)
+            if key is not None and key in writing:
+                waited += 1
+            elif len(chosen) < n:
+                chosen.append(i)
+                writing.add(key)
+            else:
+                deferred += 1
+        if late_blocks or waited:
+            self._stats.inc("prefix_late_tokens", late_tokens)
+            self._stats.inc("prefix_late_blocks", late_blocks)
+            span.add(late_tokens=late_tokens, late_blocks=late_blocks,
+                     waited=waited)
+        rows = []
+        for i in chosen:
+            r = st.slots[i]
+            p0 = int(st.prog[i])
+            ntok = min(chunk, len(r.prompt) - p0)
+            # new blocks only: recomputed shared positions rewrite
+            # shared blocks with identical values (same tokens,
+            # same prefix) and must not fork.  A self-draft's
+            # module writes its row one position further
+            self._paged_write_ready(
+                st, i, range(p0, p0 + ntok + st.self_draft),
+                fork=False)
+            rows.append((i, r, p0, ntok))
+        c = types.SimpleNamespace(
+            rows=rows, n=n, deferred=deferred, waited=waited,
+            tables=np.zeros((n, st.tb), np.int32),
+            toks=np.zeros((n, chunk), np.int32),
+            pos=np.zeros((n,), np.int32), val=np.ones((n,), np.int32),
+            do=np.zeros((n,), bool), slots=np.zeros((n,), np.int32),
             # a self-drafting store's chunk: the prompt token behind
             # each row's chunk, which the module's last row takes
-            more = {"after": np.zeros((n,), np.int32)} \
-                if st.self_draft else {}
+            more={"after": np.zeros((n,), np.int32)}
+            if st.self_draft else {},
+            traces=[(r.trace, r.trace_parent)
+                    for _i, r, _p, _n in rows],
+            live=[i for i, _r, _p, _n in rows])
+        for k, (i, r, p0, ntok) in enumerate(rows):
+            c.tables[k] = st.tables[i]
+            c.toks[k, :ntok] = r.prompt[p0:p0 + ntok]
+            c.pos[k] = p0
+            c.val[k] = ntok
+            c.do[k] = (p0 + ntok == len(r.prompt))
+            c.slots[k] = i
+            if c.more and not c.do[k]:
+                c.more["after"][k] = r.prompt[p0 + ntok]
+        return c
+
+    def _chunk_resolve(self, model, st, c, sampled):
+        """The chunk's rows, through the device, to their slots: each
+        moves by its tokens and registers the blocks it filled with the
+        prefix cache; rows finishing their prompt take their first
+        token (the TTFT moment) from ``sampled`` and flip to
+        decoding."""
+        rows = c.rows
+        self._stats.inc("prefills")
+        self._stats.inc("prefill_chunks", len(rows))
+        self._stats.inc("prefill_row_slots", c.n)
+        self._stats.inc("prefill_rows_deferred", c.deferred)
+        self._stats.inc("prefill_rows_waited", c.waited)
+        with _profiler.phase("serve_resolve") as span:
             for k, (i, r, p0, ntok) in enumerate(rows):
-                tables[k] = st.tables[i]
-                toks[k, :ntok] = r.prompt[p0:p0 + ntok]
-                pos[k] = p0
-                val[k] = ntok
-                do[k] = (p0 + ntok == len(r.prompt))
-                slots[k] = i
-                if more and not do[k]:
-                    more["after"][k] = r.prompt[p0 + ntok]
-            traces = [(r.trace, r.trace_parent)
-                      for _i, r, _p, _n in rows]
-            live = [i for i, _r, _p, _n in rows]
+                st.prog[i] = p0 + ntok
+                st.lengths[i] = p0 + ntok
+                if st.draft is not None and st.spec_mirror():
+                    st.dlen[i] = p0 + ntok
+                st.chunks_done[i] += 1
+                self._register_filled(st, i)
+                if p0 + ntok < len(r.prompt):
+                    if st.window is not None:
+                        self._release_behind(st, i)
+                    continue
+                if _metrics.phase_on():
+                    _H_CHUNKS.observe(int(st.chunks_done[i]))
+                tok = int(sampled[k])
+                self._push_token(r, tok)
+                span.add(tokens=1)
+                reason = self._finished_reason(r, tok)
+                if reason:
+                    self._release_paged_slot(st, i)
+                    self._finish(r, reason)
+                    span.add(finished=1)
+                else:
+                    st.decoding[i] = True
+                    st.next_tok[i] = tok
+                    if st.self_draft:
+                        # the module's first proposal, for the
+                        # position after the sampled token's
+                        self._note_draft(st, i, r, len(r.prompt) + 1,
+                                         int(st.chunk_props[k]))
+                    if st.window is not None:
+                        self._release_behind(st, i)
+        if st.self_draft:
+            self._stats.inc("draft_rows",
+                            sum(row[3] for row in rows))
+        self._note_cache_hwm(model, st)
+
+    def _paged_prefill_chunk(self, model, st, pre):
+        """Advance the first of the prefilling slots ``pre`` one
+        prompt chunk (serve_prefill phase; :meth:`_chunk_rows` chooses
+        and lays them out, :meth:`_chunk_resolve` takes the result to
+        the slots).  Returns what is left once the program is queued,
+        as :meth:`_paged_decode_step` does."""
+        with _profiler.phase("serve_prepare") as span:
+            c = self._chunk_rows(st, pre, span)
         try:
-            with _tracing.activate_many(traces):
+            with _tracing.activate_many(c.traces):
                 fetch = self._paged_dispatch(
-                    st, tables, toks, pos, val, do, "serve_prefill",
-                    np.arange(len(rows)), slots, width=n,
-                    deferred=deferred, **more)
+                    st, c.tables, c.toks, c.pos, c.val, c.do,
+                    "serve_prefill", np.arange(len(c.rows)), c.slots,
+                    width=c.n, deferred=c.deferred, **c.more)
                 if st.draft is not None and st.spec_mirror():
                     # mirror the chunk into the draft's KV plane
                     # (logits unfetched, discarded): same tables, same
@@ -2716,61 +2883,21 @@ class GenerationEngine:
                     # prompt instead
                     self._starved.launching()
                     st.take_draft(st.draft.run_paged_step(
-                        *st.dpools, tables, toks, pos, val,
+                        *st.dpools, c.tables, c.toks, c.pos, c.val,
                         scales=st.dscales))
                     self._starved.dispatched()
         except BaseException as e:  # noqa: BLE001 — to the futures
-            self._paged_failed(model, st, live, e, "prefill")
+            self._paged_failed(model, st, c.live, e, "prefill")
             return None
 
         def finish():
             try:
-                with _tracing.activate_many(traces):
+                with _tracing.activate_many(c.traces):
                     sampled = fetch()
             except BaseException as e:  # noqa: BLE001
-                self._paged_failed(model, st, live, e, "prefill")
+                self._paged_failed(model, st, c.live, e, "prefill")
                 return
-            self._stats.inc("prefills")
-            self._stats.inc("prefill_chunks", len(rows))
-            self._stats.inc("prefill_row_slots", n)
-            self._stats.inc("prefill_rows_deferred", deferred)
-            self._stats.inc("prefill_rows_waited", waited)
-            with _profiler.phase("serve_resolve") as span:
-                for k, (i, r, p0, ntok) in enumerate(rows):
-                    st.prog[i] = p0 + ntok
-                    st.lengths[i] = p0 + ntok
-                    if st.draft is not None and st.spec_mirror():
-                        st.dlen[i] = p0 + ntok
-                    st.chunks_done[i] += 1
-                    self._register_filled(st, i)
-                    if p0 + ntok < len(r.prompt):
-                        if st.window is not None:
-                            self._release_behind(st, i)
-                        continue
-                    if _metrics.phase_on():
-                        _H_CHUNKS.observe(int(st.chunks_done[i]))
-                    tok = int(sampled[k])
-                    self._push_token(r, tok)
-                    span.add(tokens=1)
-                    reason = self._finished_reason(r, tok)
-                    if reason:
-                        self._release_paged_slot(st, i)
-                        self._finish(r, reason)
-                        span.add(finished=1)
-                    else:
-                        st.decoding[i] = True
-                        st.next_tok[i] = tok
-                        if st.self_draft:
-                            # the module's first proposal, for the
-                            # position after the sampled token's
-                            self._note_draft(st, i, r, len(r.prompt) + 1,
-                                             int(st.chunk_props[k]))
-                        if st.window is not None:
-                            self._release_behind(st, i)
-            if st.self_draft:
-                self._stats.inc("draft_rows",
-                                sum(row[3] for row in rows))
-            self._note_cache_hwm(model, st)
+            self._chunk_resolve(model, st, c, sampled)
         return finish
 
     # -- decode --------------------------------------------------------

@@ -919,7 +919,11 @@ def cache_donate_argnums(nums):
 # where the pool's blocks come in more than one class, ``cache_classes``:
 # models/cohere2_moe.py) is all the store knows of an architecture.
 # A pool may hold several leaves of ONE class (``deepseek_v32``: latent
-# rows and index keys): they ride one table and one allocator.
+# rows and index keys): they ride one table and one allocator.  A module
+# that also has ``paged_step_groups`` offers ONE step over several row
+# groups, each with a row count and a query length of its own, reading
+# its weights once for all of them (the expert models:
+# ``GenerativeProgramStore.one_pass``).
 _ARCHS = ("transformer_lm", "deepseek_v3", "lfm2_moe", "cohere2_moe",
           "deepseek_v32", "pangu_ultra_moe")
 
@@ -944,17 +948,23 @@ def chunk_rows(bb):
 
 
 PAGED_KINDS = ("paged_step", "paged_step_sample", "paged_step_sample_p",
-               "paged_chunk_sample", "paged_verify")
+               "paged_chunk_sample", "paged_verify", "paged_tick_sample")
 # a SELF-DRAFTING store's four (``self_draft``): the target's verify of
 # K + 1 positions a row and its prompt chunk, each handing its hidden
 # states to the prediction module's own program behind it
 SELF_DRAFT_KINDS = ("paged_self_verify", "paged_self_chunk",
                     "paged_draft_step", "paged_draft_chunk")
-# on the profiler's module line a kind goes by its own name (``jit_``
-# in front), but the two chunk programs keep the chunk's prefix: what
-# reads prefill's share of the device by module name reads it
-_SELF_DRAFT_NAMES = {"paged_self_chunk": "paged_prefill_chunk_self",
-                     "paged_draft_chunk": "paged_prefill_chunk_draft"}
+# on the profiler's module line a self-draft's kind goes by its own name
+# (``jit_`` in front), but every program that carries a prompt chunk
+# keeps the chunk's prefix: what counts the step programs' executions,
+# or reads prefill's share of the device, by module name goes on
+# counting them
+_CHUNK_NAMES = {"paged_self_chunk": "paged_prefill_chunk_self",
+                "paged_draft_chunk": "paged_prefill_chunk_draft",
+                "paged_tick_sample": "paged_prefill_chunk_tick"}
+# where the decode group's arguments start among ``paged_tick_sample``'s
+# own: behind the compacted chunk's nine
+_TICK_DECODE_AT = 9
 # results in front of the pool's leaves in a kind's flat return
 _HEADS = {"paged_step_sample_p": 2, "paged_verify": 2,
           "paged_self_verify": 4, "paged_self_chunk": 3}
@@ -980,10 +990,10 @@ def paged_program(model, spec, kind, lq, kv_block, nleaf, int8=False):
     (``_serving_model``), ``spec`` its serving spec."""
     npool = nleaf + (2 if int8 else 0)
     pool_donate = tuple(range(1, 1 + npool))
-    name = _SELF_DRAFT_NAMES.get(kind, kind) \
-        if kind in SELF_DRAFT_KINDS + ("paged_verify",) \
-        else "paged_decode" if int(lq) == 1 \
-        and kind != "paged_chunk_sample" else "paged_prefill_chunk"
+    name = _CHUNK_NAMES.get(kind) or (
+        kind if kind in SELF_DRAFT_KINDS + ("paged_verify",)
+        else "paged_decode" if int(lq) == 1
+        and kind != "paged_chunk_sample" else "paged_prefill_chunk")
 
     def step(params, pls, tables, tokens, positions, valid,
              all_logits=False):
@@ -1033,6 +1043,35 @@ def paged_program(model, spec, kind, lq, kv_block, nleaf, int8=False):
                 toks = jnp.concatenate([toks, aux.astype(toks.dtype)])
             head = (toks, q) if with_q else (toks,)
             return head + new_pools + (new_keys,)
+
+        donate = pool_donate + (1 + npool + 4,)
+    elif kind == "paged_tick_sample":
+        # the ONE-PASS tick of a model with a step over row groups: the
+        # bucket's decode rows (one query each) and its compacted
+        # prompt chunk as two groups of ONE step, so the experts and
+        # the dense weights are read once for both.  The chunk's nine
+        # arguments as ``paged_chunk_sample`` takes them, then the
+        # decode group's tables, tokens, positions, valid, temps,
+        # top_ks and do_sample; the slots' key chains once.  A slot is
+        # in one group, so the two samplers' chains never meet.  ONE
+        # int32 array comes back: the decode rows' tokens, the chunk
+        # rows', the model's counters once.
+        def fn(params, *rest):
+            pls = rest[:npool]
+            (tables, tokens, positions, valid, keys, temps, top_ks,
+             do_sample, slots) = rest[npool:npool + _TICK_DECODE_AT]
+            (dtables, dtokens, dpositions, dvalid, dtemps, dtop_ks,
+             ddo) = rest[npool + _TICK_DECODE_AT:]
+            (dlogits, logits), new_pools, aux = model.paged_step_groups(
+                params, pls, ((dtables, dtokens, dpositions, dvalid),
+                              (tables, tokens, positions, valid)),
+                spec, kv_block)
+            dtoks, carry = sample_tokens(dlogits, keys, dtemps, dtop_ks)
+            toks, new_keys = sample_chunk_rows(
+                logits, jnp.where(ddo[:, None], carry, keys), temps,
+                top_ks, do_sample, slots)
+            return (jnp.concatenate([dtoks, toks, aux.astype(toks.dtype)]),
+                    ) + tuple(new_pools) + (new_keys,)
 
         donate = pool_donate + (1 + npool + 4,)
     elif kind == "paged_verify":
@@ -1287,6 +1326,15 @@ class GenerativeProgramStore:
                     "sampling (paged=True, sample='graph')")
             self._spec = self._model.with_draft(self._spec,
                                                 self.self_draft)
+        # a tick's decode rows and its prompt chunk's rows as ONE
+        # program (``paged_tick_sample``, in the chunk program's place):
+        # where the model offers a step over row groups and the tick is
+        # the plain two-program one.  Host sampling, an int8 pool and a
+        # self-drafting store keep their sequence of programs
+        self.one_pass = bool(
+            self.paged and sm == "graph" and not self.kv_int8
+            and not self.self_draft
+            and hasattr(self._model, "paged_step_groups"))
         chunk = int(prefill_chunk if prefill_chunk is not None
                     else get_env("MXNET_SERVE_PREFILL_CHUNK"))
         if chunk < 1:
@@ -1336,7 +1384,8 @@ class GenerativeProgramStore:
         # one warm sweep must fit the LRU or AOT is a lie (the forward
         # store logs the same hazard; here we just size for it).  The
         # paged plane's warm set is two step programs a batch bucket:
-        # the decode step and the compacted prompt chunk.
+        # the decode step and the compacted prompt chunk (a one-pass
+        # store's: the chunk WITH the decode rows, in its place).
         if self.paged:
             n_warm = (4 if self.self_draft else 2) * len(self._batch_edges)
         else:
@@ -1513,8 +1562,11 @@ class GenerativeProgramStore:
         """``(kind, bucket, lq)`` of the prompt-chunk program of slot
         bucket ``bb``, the one chunk program that bucket dispatches: in
         graph mode a program of the bucket itself (it takes the
-        bucket's key chains), in host mode the logits-out step at the
-        chunk's width."""
+        bucket's key chains; a :attr:`one_pass` store's carries the
+        bucket's decode rows too, and is the whole tick's), in host
+        mode the logits-out step at the chunk's width."""
+        if self.one_pass:
+            return ("paged_tick_sample", bb, self.prefill_chunk)
         if self.sample_mode == "graph":
             return ("paged_chunk_sample", bb, self.prefill_chunk)
         return ("paged_step", self.chunk_rows(bb), self.prefill_chunk)
@@ -1690,7 +1742,7 @@ class GenerativeProgramStore:
         rows beside the bb slots' key chains, and which slot each row
         works for."""
         compact = kind in ("paged_chunk_sample", "paged_self_chunk",
-                           "paged_draft_chunk")
+                           "paged_draft_chunk", "paged_tick_sample")
         rows = self.chunk_rows(bb) if compact else bb
         avals = [((rows, self.table_width()), np.int32),
                  ((rows, int(lq)), np.int32),
@@ -1714,6 +1766,14 @@ class GenerativeProgramStore:
             avals.append(((rows,), np.int32))
         if kind == "paged_self_chunk":  # the token after the chunk
             avals.append(((rows,), np.int32))
+        if kind == "paged_tick_sample":
+            # the decode group behind the chunk: bb rows of one query,
+            # and their temps, top_ks, do_sample
+            assert len(avals) == _TICK_DECODE_AT
+            avals += [((bb, self.table_width()), np.int32),
+                      ((bb, 1), np.int32), ((bb,), np.int32),
+                      ((bb,), np.int32), ((bb,), np.float32),
+                      ((bb,), np.int32), ((bb,), np.bool_)]
         return avals
 
     def _key(self, kind, bb, lb):
@@ -1845,7 +1905,9 @@ class GenerativeProgramStore:
         if self.paged:
             # the paged plane's whole program space: two step
             # programs a batch bucket — the bb-wide lq=1 decode step
-            # and the prompt chunk over chunk_rows(bb) rows.  kv_depth
+            # and the prompt chunk over chunk_rows(bb) rows (a one-pass
+            # store's with the bb decode rows beside them: the tick
+            # that has prompt rows, and no chunk program apart).  kv_depth
             # is moot: the table width is a store constant, so cache
             # depth never changes the program.  Warmup executes on a
             # throwaway zero pool with all-zero tables (every write
@@ -1921,6 +1983,8 @@ class GenerativeProgramStore:
         own = [np.zeros(shape, dtype)
                for shape, dtype in self._paged_avals(kind, bb, lq)]
         own[3][:] = 1       # one valid token a row
+        if kind == "paged_tick_sample":
+            own[_TICK_DECODE_AT + 3][:] = 1     # and a decode row
         out = jax.block_until_ready(
             prog.fn(self._params, *pools, *own))
         head = _HEADS.get(kind, 1)
@@ -2012,7 +2076,8 @@ class GenerativeProgramStore:
         compacted program that takes no key chains to tell it by."""
         n = self.pool_leaves
         rows, lq = args[n + 1].shape
-        if kind in ("paged_chunk_sample", "paged_self_chunk"):
+        if kind in ("paged_chunk_sample", "paged_self_chunk",
+                    "paged_tick_sample"):
             # a program of its slot bucket: the key chains' axis
             bb = args[n + 4].shape[0]
         prog = self._acquire(kind, int(bb or rows), int(lq))
@@ -2059,6 +2124,22 @@ class GenerativeProgramStore:
         (:func:`sample_chunk_rows`).  Pools and keys are consumed
         (donated) — callers rebind."""
         return self._run_paged('paged_chunk_sample', args, scales)
+
+    @hot_path
+    def run_paged_tick_sample(self, *args):
+        """Dispatch one ONE-PASS tick (:attr:`one_pass`): the compacted
+        prompt chunk and the slots' decode step as two row groups of
+        one program.  ``run_paged_tick_sample(*pool leaves,
+        *run_paged_chunk_sample's own nine, tables, tokens, positions,
+        valid, temps, top_ks, do_sample)``, the last seven the decode
+        group's ``(S, ...)`` arrays (``tokens`` ``(S, 1)``; the chains
+        ``keys (S, 2)`` go in once, with the chunk's).  Returns
+        ``(tokens (S + rows,) int32 + the model's counters, *pool
+        leaves, new_keys (S, 2))``: the decode rows' tokens first; a
+        chain advances where its slot's row of either group has
+        ``do_sample`` set.  Pools and keys are consumed (donated) —
+        callers rebind."""
+        return self._run_paged('paged_tick_sample', args, None)
 
     @hot_path
     def run_paged_step_sample_p(self, *args, scales=None):
@@ -2163,6 +2244,7 @@ class GenerativeProgramStore:
         out["sample_mode"] = self.sample_mode
         out["paged"] = self.paged
         out["self_draft"] = self.self_draft
+        out["one_pass"] = self.one_pass
         if self.paged:
             out["prefill_chunk"] = self.prefill_chunk
             out["pool_blocks"] = self.pool_blocks

@@ -487,7 +487,8 @@ def _cohere2_program(chip):
 
 def _paged_program_args(build, chip, kind):
     """``(the build, operands, program, donated)`` of a store's decode
-    or compacted prompt-chunk program for the described chip."""
+    or compacted prompt-chunk program for the described chip, or
+    (``one-pass``) of the tick that runs both as two row groups."""
     from mxnet_tpu.serving.program_store import chunk_rows, paged_program
 
     m = build(chip)
@@ -495,8 +496,9 @@ def _paged_program_args(build, chip, kind):
     if kind == "decode":
         pkind, rows, lq = "paged_step_sample", slots, 1
     else:
-        pkind, rows, lq = "paged_chunk_sample", chunk_rows(slots), \
-            m["chunk"]
+        pkind = "paged_tick_sample" if kind == "one-pass" \
+            else "paged_chunk_sample"
+        rows, lq = chunk_rows(slots), m["chunk"]
         assert rows == slots // 4
     fn, donate = paged_program(m["model"], m["spec"], pkind, lq, BS,
                                len(m["pools"]))
@@ -507,6 +509,11 @@ def _paged_program_args(build, chip, kind):
         chip((rows,), jnp.bool_))
     if kind != "decode":
         args += (chip((rows,), I32),)
+    if kind == "one-pass":      # the decode group behind the chunk
+        args += (chip((slots, m["width"]), I32), chip((slots, 1), I32),
+                 chip((slots,), I32), chip((slots,), I32),
+                 chip((slots,)), chip((slots,), I32),
+                 chip((slots,), jnp.bool_))
     return m, args, fn, donate
 
 
@@ -587,6 +594,67 @@ def test_expert_layer_runs_the_repos_grouped_product(chip, compiled_mode,
              if hit and hit.group(2) != "parameter"
              and stack.search(hit.group(1))]
     assert not moved, "\n".join(moved)
+
+
+@pytest.mark.parametrize("build,attention,scratch_gb", [
+    (_deepseek_program, {"mla_paged_attention": LAYERS}, 1.0),
+    (_lfm2_program, {"paged_attention": 2}, 0.25),
+    (_cohere2_program, {"paged_attention": 1,
+                        "window_paged_attention": 3}, 0.45),
+], ids=["deepseek-v3", "lfm2-24b-a2b", "command-a-plus"])
+def test_one_pass_tick_reads_the_experts_once(chip, compiled_mode, build,
+                                              attention, scratch_gb):
+    """The one-pass tick of the three expert model modules
+    (``paged_tick_sample``: the slots' decode rows and the compacted
+    prompt chunk as two row groups of one step) compiled for the
+    described v5e at each cell's whole size: named so that what counts
+    step programs by ``jit_paged_prefill_chunk`` counts it; the grouped
+    product twice an expert layer, as in EACH of the two programs it
+    stands for, so the experts are streamed once a tick; every
+    attention kernel twice a layer, once a group, with the shapes the
+    two programs call it with; no pool leaf moved; and the chunk
+    program's scratch with the decode group's rows beside it, far from
+    the chip's 16 GB."""
+    import re
+    m, args, fn, donate = _paged_program_args(build, chip, "one-pass")
+    assert fn.__name__ == "paged_prefill_chunk_tick"
+    slots, rows, lq = m["slots"], m["slots"] // 4, m["chunk"]
+    layers = m["spec"]["num_hidden_layers"] \
+        - m["spec"]["first_k_dense_replace"]
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    text = compiled.as_text()
+    named = [ln for ln in text.splitlines()
+             if re.match(r"\s*(?:ROOT )?%ragged-dot\S* = ", ln)]
+    assert len(named) == 2 * layers, "\n".join(named)
+    assert all("ragged-dot_grouped_matmul" in ln
+               and "tpu_custom_call" in ln for ln in named)
+    # the sorted rows of both groups in one product
+    picks = m["spec"]["num_experts_per_tok"]
+    assert all("[%d," % ((slots + rows * lq) * picks) in ln
+               for ln in named)
+    for name, calls in attention.items():
+        attn = [ln for ln in text.splitlines() if re.match(
+            r"\s*(?:ROOT )?%%%s\S* = " % name, ln)]
+        assert len(attn) == 2 * calls, (name, len(attn))
+        # a group each: the decode rows' call and the chunk rows'
+        firsts = sorted(int(re.search(r"= \w+\[(\d+),", ln).group(1))
+                        for ln in attn)
+        assert firsts == [rows] * calls + [slots] * calls, (name, firsts)
+    pool_shaped = re.compile(m["pool_shaped"])
+    moved = []
+    for line in text.splitlines():
+        hit = re.match(r"\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) ([a-z-]+)\(",
+                       line)
+        if hit and hit.group(2) in _MOVES_THE_POOL \
+                and pool_shaped.search(hit.group(1)):
+            moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)
+    mem = compiled.memory_analysis()
+    print("one-pass %s: arguments %.2f GB, scratch %.2f GB"
+          % (build.__name__, mem.argument_size_in_bytes / 1e9,
+             mem.temp_size_in_bytes / 1e9))
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
+    assert mem.temp_size_in_bytes < scratch_gb * 1e9
 
 
 @pytest.mark.parametrize("kind", ["decode", "prefill-chunk"])

@@ -691,27 +691,38 @@ def test_chunk_dispatch_runs_over_the_slots_in_their_prompt(
         want = [_generate(reg, [kw])[0] for kw in reqs]
 
     eng = GenerationEngine(reg)
-    seen = []
-    dispatch = eng._paged_dispatch
+    seen, last = [], {}
+    chunk_rows = eng._chunk_rows
 
-    def spy(st, tables, toks, pos, val, do, phase, live, slots=None,
-            **counts):
-        if phase != "serve_prefill":
-            return dispatch(st, tables, toks, pos, val, do, phase, live,
-                            slots, **counts)
-        before = np.array(st.keys)
-        waiting = [i for i in st.active() if not st.decoding[i]]
-        out = dispatch(st, tables, toks, pos, val, do, phase, live,
-                       slots, **counts)
-        seen.append(dict(
-            rows=[int(i) for i in slots[:len(live)]],
-            sampled=[int(i) for i in slots[:len(live)][do[:len(live)]]],
-            waiting={i: st.slots[i].seq for i in waiting},
-            shape=tables.shape, counts=counts, before=before,
-            after=np.array(st.keys)))
-        return out
+    def spy_rows(st, pre, span):
+        last["waiting"] = [i for i in st.active() if not st.decoding[i]]
+        last["c"] = chunk_rows(st, pre, span)
+        return last["c"]
 
-    eng._paged_dispatch = spy
+    def spy(method):
+        # the method that queues a tick's prompt chunk: the chunk
+        # program's, or (a store whose model steps over row groups) the
+        # one-pass tick's, which advances its decode rows' chains too
+        queue = getattr(eng, method)
+
+        def queued(model, st, *groups):
+            before = np.array(st.keys)
+            finish = queue(model, st, *groups)
+            c, dec = last["c"], list(groups[0]) if len(groups) > 1 else []
+            seen.append(dict(
+                rows=[int(i) for i in c.live],
+                sampled=[int(i) for i in c.slots[:len(c.rows)][
+                    c.do[:len(c.rows)]]] + dec,
+                waiting={i: st.slots[i].seq for i in last["waiting"]},
+                shape=c.tables.shape,
+                counts={"width": c.n, "deferred": c.deferred},
+                before=before, after=np.array(st.keys)))
+            return finish
+        setattr(eng, method, queued)
+
+    assert store.one_pass == (arch != "lm")
+    eng._chunk_rows = spy_rows
+    spy("_paged_one_pass" if store.one_pass else "_paged_prefill_chunk")
     opened = profiler.phase_totals()
     try:
         futs = [eng.submit("m", **kw) for kw in reqs]
@@ -1124,3 +1135,299 @@ def test_waiters_outlive_the_writer_of_their_block():
     assert stats["errors"] == 1 and stats["finished"] == 5
     assert stats["prefill_rows_waited"] > 0
     assert stats["prefix_late_tokens"] > 0
+
+
+# ---------------------------------------------------------------------------
+# one program a tick: a step over row groups, and the tick that takes it
+# ---------------------------------------------------------------------------
+GROUP_ARCHS = ["deepseek_v3", "deepseek_v32", "lfm2_moe", "cohere2_moe"]
+
+
+def _arch(arch):
+    """``(model module, validated toy spec)`` of a served architecture."""
+    import importlib
+    mod = importlib.import_module("mxnet_tpu.models." + arch)
+    return mod, mod.serving_spec(
+        DS_SPEC if arch == "deepseek_v3" else BURST_SPECS[arch])
+
+
+@pytest.mark.parametrize("arch", GROUP_ARCHS)
+def test_a_step_over_two_groups_is_the_two_steps(arch):
+    """``paged_step_groups`` over a decode group (two sequences with 20
+    and 9 tokens behind them, the first past ``cohere2_moe``'s window,
+    and a dead row) and a chunk group (a fresh sequence, one in its
+    second chunk with a ragged end, which reads ``lfm2_moe``'s state
+    row, and a dead row) gives, group by group, the logits and, leaf
+    by leaf, the pool of the two one-group steps in that order, bit
+    for bit; the counters that add up are their sum, the expert steps
+    count ONE pass a layer, and an expert both groups touch is touched
+    once."""
+    import jax
+    mod, spec = _arch(arch)
+    bs, T = 8, 6
+    classes = len(mod.cache_classes(spec)) \
+        if hasattr(mod, "cache_classes") else 1
+    params = {k: jax.numpy.asarray(v) for k, v in mod.pack_params(
+        mod.random_params(spec, seed=5), spec).items()}
+    rs = np.random.RandomState(4)
+    draw = lambda *shape: rs.randint(  # noqa: E731
+        0, spec["vocab_size"], shape).astype(np.int32)
+
+    def table(*blocks):
+        row = np.zeros(T, np.int32)
+        row[:len(blocks)] = blocks
+        return np.tile(row, classes)
+
+    a, b, c, d = table(1, 2, 3), table(4, 5), table(6), table(7, 8)
+    dead = table()
+    one = jax.jit(lambda pools, *group: mod.paged_step(
+        params, pools, *group, spec, bs))
+    two = jax.jit(lambda pools, *groups: mod.paged_step_groups(
+        params, pools, groups, spec, bs))
+
+    # what the decode rows and the second chunk have behind them
+    pools = mod.init_pool(spec, 9, bs)
+    for pos, valid in ((0, [8, 8, 8]), (8, [8, 1, 1]), (16, [4, 1, 1])):
+        rows = np.stack([a, b if pos < 16 else dead,
+                         d if pos < 8 else dead])
+        _, pools, _ = one(pools, rows, draw(3, 8),
+                          np.full(3, pos, np.int32),
+                          np.asarray(valid, np.int32))
+    decode = (np.stack([a, dead, b]), draw(3, 1),
+              np.array([20, 0, 9], np.int32), np.ones(3, np.int32))
+    chunk = (np.stack([c, dead, d]), draw(3, 8),
+             np.array([0, 0, 8], np.int32), np.array([8, 1, 5], np.int32))
+
+    want_d, mid, counts_d = one(pools, *decode)
+    want_c, want_pools, counts_c = one(mid, *chunk)
+    (got_d, got_c), got_pools, counts = two(pools, decode, chunk)
+    assert np.array_equal(np.asarray(got_d), np.asarray(want_d))
+    assert np.array_equal(np.asarray(got_c), np.asarray(want_c))
+    assert len(got_pools) == len(want_pools)
+    for got, want in zip(got_pools, want_pools):
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+    counts, apart = np.asarray(counts), np.asarray(
+        [counts_d, counts_c])
+    names = list(mod.AUX_COUNTERS)
+    for name in ("moe_tokens", "moe_local_assignments"):
+        k = names.index(name)
+        assert counts[k] == apart[:, k].sum() > 0
+    k = names.index("moe_expert_steps")
+    assert counts[k] == apart[0, k] == apart[1, k] > 0
+    for name in ("moe_expert_load_max", "moe_experts_touched"):
+        k = names.index(name)
+        assert apart[:, k].max() <= counts[k] <= apart[:, k].sum()
+    # ONE group through the same function is the seam's own step
+    (alone,), _, _ = two(pools, decode)
+    assert np.array_equal(np.asarray(alone), np.asarray(want_d))
+
+
+def _without_groups(monkeypatch):
+    """From here on a store sees its model WITHOUT the step over row
+    groups: what switches the one-pass tick off, and nothing else."""
+    import types
+    from mxnet_tpu.serving import program_store
+    find = program_store._serving_model
+    monkeypatch.setattr(
+        program_store, "_serving_model",
+        lambda arch: types.SimpleNamespace(**{
+            k: v for k, v in vars(find(arch)).items()
+            if k != "paged_step_groups"}))
+
+
+def _mixed_requests(seed, vocab, n=14):
+    """A seeded mix: one prefix of two whole blocks under most of the
+    prompts, prompts of 3 to 30 tokens, one to six tokens out, greedy
+    and seeded draws: slots refill while others decode, so ticks carry
+    decode rows and prompt rows together."""
+    rs = np.random.RandomState(seed)
+    prefix = [int(t) for t in rs.randint(0, vocab, 16)]
+    reqs = []
+    for i in range(n):
+        own = [int(t) for t in rs.randint(0, vocab, 3 + (5 * i) % 14)]
+        reqs.append(dict(
+            tokens=(prefix if i % 3 else []) + [i] + own,
+            max_tokens=1 + (3 * i) % 6,
+            temperature=0.0 if i % 2 else 0.8, top_k=4 * (i % 3),
+            seed=900 + i))
+    return reqs
+
+
+def _watch_ticks(eng):
+    """Record, a tick, how many rows decode and how many are in their
+    prompt when it starts, and what it retires and finishes."""
+    ticks, tick = [], eng._paged_tick
+    decode, chunk = eng._decode_resolve, eng._chunk_resolve
+
+    def watched(model, st):
+        ticks.append(dict(
+            dec=sum(bool(st.decoding[i]) for i in st.active()),
+            pre=sum(not st.decoding[i] for i in st.active()),
+            retired=0, prompts_done=0))
+        return tick(model, st)
+
+    def decoded(st, dec, idx, sampled):
+        before = len(st.active())
+        decode(st, dec, idx, sampled)
+        ticks[-1]["retired"] += before - len(st.active())
+
+    def chunked(model, st, c, sampled):
+        ticks[-1]["prompts_done"] += int(c.do.sum())
+        return chunk(model, st, c, sampled)
+
+    eng._paged_tick, eng._decode_resolve, eng._chunk_resolve = \
+        watched, decoded, chunked
+    return ticks
+
+
+@pytest.mark.parametrize("arch", GROUP_ARCHS)
+def test_one_pass_tick_serves_the_two_program_ticks_tokens(
+        arch, monkeypatch):
+    """A seeded mix over a shared prefix through a store that takes the
+    one-pass tick and through the same store built from the model
+    without its step over row groups: the same tokens, request by
+    request; among the one-pass ticks one in which a row retires while
+    a prompt finishes; and ``tick_one_pass``, ``tick_programs`` and
+    ``decode_steps`` read what the ticks did: one program where a tick
+    had prompt rows, two a tick with both kinds of row on the other
+    store."""
+    reg = _burst_registry(arch, pool_blocks=0)
+    assert reg.gen_store("m").one_pass
+    reqs = _mixed_requests(21, reg.gen_store("m").spec["vocab_size"])
+    runs = {}
+    for path in ("one_pass", "two_programs"):
+        if path == "two_programs":
+            _without_groups(monkeypatch)
+            # (not the cached registry: its store took the path)
+            reg = _burst_registry.__wrapped__(arch, pool_blocks=0)
+            assert not reg.gen_store("m").one_pass
+        eng = GenerationEngine(reg)
+        ticks = _watch_ticks(eng)
+        try:
+            got = [f.result(300).tokens
+                   for f in _submit_at_once(eng, reqs)]
+            stats = eng.stats()
+            _assert_only_pins_left(eng._states["m"])
+        finally:
+            eng.close()
+        runs[path] = got
+        assert [len(t) for t in got] == [kw["max_tokens"] for kw in reqs]
+        busy = [t for t in ticks if t["dec"] or t["pre"]]
+        both = [t for t in busy if t["dec"] and t["pre"]]
+        assert both and stats["errors"] == 0
+        assert stats["decode_steps"] == sum(1 for t in busy if t["dec"])
+        if path == "one_pass":
+            assert any(t["retired"] and t["prompts_done"] for t in both)
+            assert stats["tick_one_pass"] == stats["prefills"] \
+                == sum(1 for t in busy if t["pre"])
+            assert stats["tick_programs"] == len(busy)
+        else:
+            assert stats["tick_one_pass"] == 0
+            assert stats["tick_programs"] == len(busy) + len(both)
+    assert runs["one_pass"] == runs["two_programs"]
+
+
+def test_chunk_only_and_decode_only_ticks():
+    """One request alone on a one-pass store: its prompt's ticks have
+    no decode row (the decode group rides dead: no ``serve_decode``
+    span, no decode step counted), its generation's ticks are the
+    decode program's; a program a tick either way, and the stream is
+    the two-program store's (``test_a_burst_...`` holds every
+    architecture's to that)."""
+    from mxnet_tpu import profiler
+    reg = _burst_registry("deepseek_v3")
+    rs = np.random.RandomState(6)
+    prompt = [int(t) for t in rs.randint(0, 96, 11)]
+    eng = GenerationEngine(reg)
+    ticks = _watch_ticks(eng)
+    opened = profiler.phase_totals()
+    try:
+        got = eng.submit("m", prompt, max_tokens=5).result(300).tokens
+        stats = eng.stats()
+    finally:
+        eng.close()
+    spans = profiler.phase_totals(since=opened)
+    assert len(got) == 5
+    busy = [t for t in ticks if t["dec"] or t["pre"]]
+    assert not [t for t in busy if t["dec"] and t["pre"]]
+    chunks = -(-len(prompt) // BURST_CHUNK)
+    assert stats["tick_one_pass"] == stats["prefills"] == chunks \
+        == spans["serve_prefill"]["spans"]
+    assert stats["decode_steps"] == 4 == spans["serve_decode"]["spans"]
+    assert stats["tick_programs"] == chunks + 4 == len(busy)
+    assert spans["serve_decode"]["counts"]["rows"] == 4
+    assert spans["serve_prepare"]["spans"] == chunks + 4
+
+
+def test_warmup_keeps_two_programs_a_bucket(monkeypatch):
+    """``warmup()`` of an expert store returns two programs a bucket,
+    the decode step and the one-pass tick IN the chunk program's place;
+    ``transformer_lm``'s, an expert store without the step over row
+    groups, and one that samples on the host return what they returned
+    (a self-drafting store's four: ``tests/test_pangu_ultra_moe.py``)."""
+    store = _burst_registry("lfm2_moe").gen_store("m")
+    assert store.one_pass and store.stats()["one_pass"]
+    assert sorted(store.warmup()) == [
+        ("paged_step_sample", 8, 1), ("paged_tick_sample", 8, BURST_CHUNK)]
+    assert store.chunk_program(8) == ("paged_tick_sample", 8, BURST_CHUNK)
+    assert store.stats()["compiles"] == 2
+    lm = _burst_registry("transformer_lm").gen_store("m")
+    assert not lm.one_pass
+    assert sorted(lm.warmup()) == [
+        ("paged_chunk_sample", 8, BURST_CHUNK), ("paged_step_sample", 8, 1)]
+    assert lm.stats()["compiles"] == 2
+    host = _burst_registry("lfm2_moe", sample="host").gen_store("m")
+    assert not host.one_pass
+    assert sorted(host.warmup()) == [("paged_step", 4, BURST_CHUNK),
+                                     ("paged_step", 8, 1)]
+    _without_groups(monkeypatch)
+    off = _burst_registry.__wrapped__("lfm2_moe").gen_store("m")
+    assert not off.one_pass
+    assert sorted(off.warmup()) == sorted(lm.warmup())
+
+
+def test_a_failed_one_pass_dispatch_fails_both_groups():
+    """The one-pass dispatch of a tick with decode rows AND prompt rows
+    raises: the requests of both groups get the error and their blocks
+    go back; the slots that were in neither (waiting on a sibling's
+    block) serve on."""
+    reg = _burst_registry("cohere2_moe")
+    store = reg.gen_store("m")
+    reqs = _burst_requests(13, store.spec["vocab_size"], n=4)
+    # one that generates by the time the burst is in its prompt
+    first = dict(tokens=[95, 3, 7], max_tokens=40)
+    eng = GenerationEngine(reg)
+    ticks = _watch_ticks(eng)
+    run, lost = store.run_paged_tick_sample, []
+
+    def flaky(*args):
+        if ticks[-1]["dec"] and ticks[-1]["pre"] and not lost:
+            lost.append(dict(ticks[-1]))
+            raise RuntimeError("lost the device")
+        return run(*args)
+
+    store.run_paged_tick_sample = flaky
+    try:
+        a = eng.submit("m", **first)
+        while not eng.stats()["decode_steps"]:
+            pass
+        futs = _submit_at_once(eng, reqs)
+        with pytest.raises(MXNetError, match="tick dispatch failed"):
+            a.result(300)
+        done = []
+        for f in futs:
+            try:
+                done.append(len(f.result(300).tokens))
+            except MXNetError as e:
+                assert "tick dispatch failed" in str(e)
+                done.append(None)
+        stats = eng.stats()
+        _assert_only_pins_left(eng._states["m"])
+    finally:
+        store.run_paged_tick_sample = run
+        eng.close()
+    # the decoding request and the one writer of the shared prefix
+    # were in the dispatch; its three siblings waited and were not
+    assert len(lost) == 1 and lost[0]["dec"] == 1 and lost[0]["pre"] > 1
+    assert done == [None, 4, 4, 4]
+    assert stats["errors"] == 2 and stats["finished"] == 3

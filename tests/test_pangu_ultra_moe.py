@@ -191,8 +191,12 @@ def test_store_warms_exactly_the_four_self_draft_programs():
     off = GenerativeProgramStore(
         {k: v for k, v in PARAMS.items() if not k.startswith("mtp_")},
         SPEC_IN, **STORE_KW)
-    assert sorted(off.warmup()) == [("paged_chunk_sample", 4, CHUNK),
-                                    ("paged_step_sample", 4, 1)]
+    # without the module the store is an expert store like another:
+    # the decode step, and the one-pass tick in the chunk program's
+    # place (the self-drafting store keeps its sequence of programs)
+    assert not st.one_pass and off.one_pass
+    assert sorted(off.warmup()) == [("paged_step_sample", 4, 1),
+                                    ("paged_tick_sample", 4, CHUNK)]
     assert off.new_pool()[0].shape[0] == 3
 
 

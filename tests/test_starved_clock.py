@@ -263,6 +263,60 @@ def test_paged_engine_accounts_for_its_starved_time():
                        "sample_topk": 0, "kv_tokens_window": 136}
 
 
+def test_a_one_pass_tick_is_one_launch_and_one_fetch():
+    """A mix that keeps decode rows and prompt rows in the same ticks,
+    through a store that takes the one-pass tick: every busy tick is
+    ONE preparation, ONE dispatch call the clock counted and ONE fetch,
+    whatever rows it had; a tick with both kinds of row closes a
+    ``serve_decode`` AND a ``serve_prefill`` span around that one call,
+    each with its own group's rows; and the leaves' starved time still
+    nests inside the ticks'."""
+    from test_paged_decode import (_burst_registry, _mixed_requests,
+                                   _submit_at_once, _watch_ticks)
+    reg = _burst_registry("cohere2_moe", pool_blocks=0)
+    assert reg.gen_store("m").one_pass
+    reqs = _mixed_requests(23, 96)
+    opened = profiler.phase_totals()
+    eng = GenerationEngine(reg)
+    ticks = _watch_ticks(eng)
+    fetches, fetch = [], eng._fetch_decode
+
+    def counted(arr):
+        fetches.append(1)
+        return fetch(arr)
+
+    eng._fetch_decode = counted
+    try:
+        for f in _submit_at_once(eng, reqs):
+            f.result(300)
+        stats = eng.stats()
+    finally:
+        eng.close()
+    got = profiler.phase_totals(since=opened)
+    clock = eng._starved
+    busy = [t for t in ticks if t["dec"] or t["pre"]]
+    both = [t for t in busy if t["dec"] and t["pre"]]
+    assert both and stats["tick_one_pass"] >= len(both)
+    assert stats["tick_programs"] == len(busy) == len(fetches) \
+        == got["serve_sample"]["spans"] == got["serve_prepare"]["spans"]
+    assert clock.through == clock.queued \
+        == len(busy) + stats["cow_forks"]
+    decode, prefill = got["serve_decode"], got["serve_prefill"]
+    assert decode["spans"] == stats["decode_steps"] \
+        == sum(1 for t in busy if t["dec"])
+    assert prefill["spans"] == stats["prefills"] \
+        == sum(1 for t in busy if t["pre"])
+    assert decode["spans"] + prefill["spans"] == len(busy) + len(both)
+    assert decode["counts"]["rows"] == decode["counts"]["q_tokens"] \
+        == sum(t["dec"] for t in busy)
+    assert prefill["counts"]["rows"] == stats["prefill_chunks"]
+    assert stats["generated_tokens"] == decode["counts"]["rows"]
+    inside = sum(got[n]["counts"]["starved_ns"] for n in LEAVES)
+    assert inside <= got["serve_tick"]["counts"]["starved_ns"] \
+        <= got["device_starved"]["ns"]
+    assert 0 < got["device_launch"]["spans"] <= clock.queued
+
+
 # ---------------------------------------------------------------------------
 # (c) two engines, two clocks
 # ---------------------------------------------------------------------------
