@@ -206,11 +206,26 @@ def memory_peak_bytes(devices):
 # ---------------------------------------------------------------------------
 class DeviceTrace:
     """Starts and stops ``jax.profiler`` around a short part of the
-    window and reduces the ``.xplane.pb`` it leaves (under TMPDIR)."""
+    window and reduces the ``.xplane.pb`` it leaves (under TMPDIR).
+
+    The traced window is a SPAN inside the file, not the file:
+    ``start()`` opens a host span (``window_span`` of
+    ``reduce/trace_names.json``) where it stamps ``t_start`` and
+    ``stop()`` closes it where it stamps ``t_stop``, before
+    ``stop_trace``; the reducer clips every device event to that span
+    and ``window_s`` is its length.  A serving engine goes on ticking
+    while its ``bench-trace`` thread sits in ``stop_trace``, so the file
+    holds device work from after the stamp, which is not the window's.
+    Both calls are made on one thread (a span ends on the thread it
+    began on)."""
 
     def __init__(self):
         self.dir = tempfile.mkdtemp(prefix="bench_trace_")
         self.t_start = self.t_stop = None
+        self._names = load_json(find_file(
+            load_json(os.path.join(ROOT, "BENCHMARK.json")),
+            "reduce/trace_names.json"))
+        self._span = None
 
     def start(self):
         import jax
@@ -219,25 +234,43 @@ class DeviceTrace:
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         jax.profiler.start_trace(self.dir, profiler_options=options)
+        # (a TraceAnnotation runs from its construction)
+        self._span = jax.profiler.TraceAnnotation(
+            self._names["window_span"])
         self.t_start = time.perf_counter()
 
     def stop(self):
         import jax
         self.t_stop = time.perf_counter()
+        self._span.__exit__(None, None, None)
         jax.profiler.stop_trace()
 
     def reduce(self, bench):
         """The reduced trace (``reduce/xplane.py``), or None when the
-        window was never opened; the raw trace is deleted."""
+        window was never opened; the raw trace is deleted.  Says the
+        window's two lengths (the span's, which is ``window_s``, and
+        the host stamps') and the busy seconds inside the span, or why
+        nothing was read."""
         try:
             if self.t_stop is None:
                 return None
             xplane = load_module(bench, "reduce/xplane.py")
-            names = load_json(find_file(bench, "reduce/trace_names.json"))
-            return xplane.reduce(xplane.find_xplane(self.dir), names,
-                                 window_s=self.t_stop - self.t_start)
+            got = xplane.reduce(xplane.find_xplane(self.dir), self._names,
+                                window_s=self.t_stop - self.t_start)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
+        if not got["busy_s"]:
+            say(trace_unread=got.get(
+                "reason", "no device operation inside the window span"))
+            return got
+        host_s = got["window_host_s"]
+        say(trace_window_s=got["window_s"], trace_host_stamps_s=host_s,
+            trace_busy_s=got["busy_s"])
+        if abs(got["window_s"] - host_s) > 1e-3:
+            say(trace_window_disagrees="the window span is %.6f s and "
+                "the host stamps are %.6f s apart"
+                % (got["window_s"], host_s))
+        return got
 
 
 # ---------------------------------------------------------------------------

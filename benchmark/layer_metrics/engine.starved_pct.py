@@ -13,10 +13,16 @@ above), and after a copy-on-write fork's copy it lies idle unseen
 (PERF.md section 6, PR 36 splits one trace).
 
 Read from ``host["traced_phases"]``, the spans' totals over the TRACED
-seconds alone; never the process's lifetime.  None without a trace
-(a rehearsal), without ``traced_phases`` (a driver that does not take
-them), without ``device_starved`` (a program from before the clock) or
-without ``serve_tick`` spans.  The five readers of the parts
+seconds alone; never the process's lifetime.  That the clock RAN is the
+key ``device_starved`` among them (the lifetime's totals carry it from
+the engine's first launch on, whatever the traced seconds gained)
+together with ``serve_tick`` spans in those seconds: a clock that ran
+and opened no interval, a program that keeps the next tick queued
+ahead of the fetch, reads 0.0 here and in the five parts, not nothing.
+None without a trace (a rehearsal), without ``traced_phases`` (a driver
+that does not take them), without the key ``device_starved`` (a
+program from before the clock) or without ``serve_tick`` spans.  The
+five readers of the parts
 (``engine.starved_*``) take ``traced``, ``launch_ns`` and
 ``starved_ms_a_tick`` from here.  Layer: serving planes
 (``decode_engine.py``)."""
@@ -30,11 +36,10 @@ LEAVES = ("serve_resolve", "serve_admit", "serve_prepare", "serve_decode",
 def traced(run):
     """The traced seconds' totals where the clock ran, else None."""
     phases = run["host"].get("traced_phases") if run["trace"] else None
-    if not phases:
+    if not phases or "device_starved" not in phases:
         return None
-    for name in ("device_starved", "serve_tick"):
-        if not phases.get(name) or not phases[name]["spans"]:
-            return None
+    if not phases.get("serve_tick") or not phases["serve_tick"]["spans"]:
+        return None
     return phases
 
 

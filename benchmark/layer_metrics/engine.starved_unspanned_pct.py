@@ -8,7 +8,8 @@ their ``starved_ns`` counts).  The rest, over the account, over the
 TRACED seconds alone (``host["traced_phases"]``): the engine's loop
 between ticks, ``_pump``, ``_has_work``, the gauges, the tail of
 ``serve_sample``, each span's own opening and closing.  With the four
-``engine.starved_*_ms`` it sums to the account.  None where
+``engine.starved_*_ms`` it sums to the account.  An empty account (the
+clock ran and opened nothing) has nothing unnamed: 0.0.  None where
 ``engine.starved_pct`` is.  Layer: serving planes
 (``decode_engine.py``)."""
 
@@ -16,8 +17,11 @@ between ticks, ``_pump``, ``_has_work``, the gauges, the tail of
 def read(run):
     base = run["cell"].module("layer_metrics", "engine.starved_pct")
     phases = base.traced(run)
-    if phases is None or not phases["device_starved"]["ns"]:
+    if phases is None:
         return None
     starved = phases["device_starved"]["ns"]
+    account = starved + base.launch_ns(phases)
+    if not account:
+        return 0.0
     return 100.0 * (starved - base.starved_ns(phases, base.LEAVES)) \
-        / (starved + base.launch_ns(phases))
+        / account

@@ -23,6 +23,27 @@ PROGRAM = harness.load_json(harness.find_file(
     BENCH, "reduce/program_names.json"))
 NEW = {"engine.tick_host_ms", "engine.prefill_share_pct",
        "kernel.paged_attn_roofline_pct", "input.h2d_stage_ms"}
+AS_OF_PR46 = [
+    "input.data_wait_pct", "step.device_ms", "engine.decode_batch_mean",
+    "engine.decode_steps_per_s", "kernel.mfu_pct",
+    "kernel.paged_attn_time_pct", "device.collective_exposed_pct",
+    "engine.tick_host_ms", "engine.prefill_share_pct",
+    "kernel.paged_attn_roofline_pct", "input.h2d_stage_ms",
+    "kernel.mla_attn_time_pct", "kernel.mla_attn_roofline_pct",
+    "kernel.moe_ffn_time_pct", "kernel.moe_ffn_roofline_pct",
+    "moe.tokens_per_expert", "moe.load_max_over_mean",
+    "kernel.gqa_attn_time_pct", "kernel.gqa_attn_roofline_pct",
+    "engine.prefix_hit_pct", "engine.state_rows_live",
+    "kernel.swa_attn_time_pct", "kernel.swa_attn_roofline_pct",
+    "engine.window_cache_pct", "engine.starved_pct",
+    "engine.starved_resolve_ms", "engine.starved_admit_ms",
+    "engine.starved_prepare_ms", "engine.starved_dispatch_ms",
+    "engine.starved_unspanned_pct", "kernel.dsa_index_time_pct",
+    "kernel.dsa_index_roofline_pct", "kernel.dsa_attn_time_pct",
+    "kernel.dsa_attn_roofline_pct", "kernel.dsa_select_time_pct",
+    "kernel.dsa_select_roofline_pct", "dsa.selected_pct",
+    "mtp.accept_pct", "mtp.draft_time_pct",
+    "kernel.mla_verify_roofline_pct", "kernel.moe_verify_roofline_pct"]
 US = 1e-6
 # what the engine of the recorded trace would have totalled (by hand
 # from the textproto's engine line; kv_tokens made up)
@@ -58,10 +79,13 @@ def totals(monkeypatch):
 def test_every_new_metric_is_listed_with_its_cells():
     entries = {m["name"]: m for m in BENCH["per_layer"]}
     assert NEW <= set(entries)
-    # appended: the seven entries PR 23 accepted come first, unchanged
-    assert [m["name"] for m in BENCH["per_layer"][7:]] == [
+    # appended, and every PR since has appended behind: the list as it
+    # stood at PR 46 is the head of the list, in its order
+    names = [m["name"] for m in BENCH["per_layer"]]
+    assert names[7:11] == [
         "engine.tick_host_ms", "engine.prefill_share_pct",
         "kernel.paged_attn_roofline_pct", "input.h2d_stage_ms"]
+    assert names[:len(AS_OF_PR46)] == AS_OF_PR46
     assert entries["input.h2d_stage_ms"]["workloads"] == [
         "resnet50.fit-b128", "resnet50.fit-dp4-b512"]
 

@@ -1,6 +1,8 @@
 """reduce/xplane.py on a small recorded trace (data/small_trace.textproto,
 worked by hand): busy union, per-op totals, collective exposure, idle
-gaps named by the host span over each."""
+gaps named by the host span over each; and on one with the traced window
+marked in it (data/window_trace.textproto): everything clipped to the
+span, the two edge gaps, no reading from a run's file without the span."""
 import os
 
 import pytest
@@ -15,12 +17,15 @@ NAMES = harness.load_json(harness.find_file(BENCH,
 US = 1e-6
 
 
+def _profile(name):
+    from jax.profiler import ProfileData
+    with open(os.path.join(HERE, "data", name)) as f:
+        return ProfileData.from_text_proto(f.read())
+
+
 @pytest.fixture(scope="module")
 def reduced():
-    from jax.profiler import ProfileData
-    with open(os.path.join(HERE, "data", "small_trace.textproto")) as f:
-        profile = ProfileData.from_text_proto(f.read())
-    return xplane.reduce_profile(profile, NAMES)
+    return xplane.reduce_profile(_profile("small_trace.textproto"), NAMES)
 
 
 def test_interval_arithmetic():
@@ -90,3 +95,103 @@ def test_no_device_plane_reads_nothing():
         'planes { id: 1 name: "/host:CPU" }')
     out = xplane.reduce_profile(profile, NAMES, window_s=1.0)
     assert out["busy_s"] is None and out["devices"] == []
+
+
+# ---------------------------------------------------------------------------
+# the traced window is the span "bench.window", not the file
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def cut():
+    # the host stamps' difference, a few microseconds off the span's
+    return xplane.reduce_profile(_profile("window_trace.textproto"), NAMES,
+                                 window_s=2998 * US)
+
+
+def test_the_window_is_the_span_and_busy_lies_inside_it(cut):
+    assert NAMES["window_span"] == "bench.window"
+    assert cut["window_s"] == pytest.approx(3000 * US)
+    assert cut["window_host_s"] == pytest.approx(2998 * US)
+    d0, d1 = cut["devices"]
+    # device 0: 1200..2200 + 3000..3600 us; what ran before 1000 and
+    # after 4000 us is not the window's
+    assert d0["busy_s"] == pytest.approx(1600 * US)
+    # device 1: 1000..1500 + 2000..2500 + 3800..4000 us, both edges cut
+    # (700..1000 us before, 4000..4600 us after)
+    assert d1["busy_s"] == pytest.approx(1200 * US)
+    assert cut["busy_s"] == pytest.approx(1400 * US)
+    for d in cut["devices"]:
+        assert 0 < d["busy_s"] <= cut["window_s"]
+    assert cut["busy_s"] <= cut["window_s"]
+
+
+def test_op_totals_and_modules_are_the_clipped_ones(cut):
+    d0, d1 = cut["devices"]
+    assert d0["ops"] == {"fusion.1": pytest.approx(600 * US),
+                         "all-reduce.3": pytest.approx(500 * US),
+                         "convolution.7": pytest.approx(600 * US)}
+    assert d1["ops"] == {"fusion.1": pytest.approx(400 * US),
+                         "all-reduce.3": pytest.approx(600 * US),
+                         "convolution.7": pytest.approx(500 * US)}
+    # an execution counts where it STARTS inside the window; its
+    # seconds are clipped whichever edge it crosses
+    assert d0["modules"]["jit_step(1)"] == (2, pytest.approx(1600 * US))
+    assert d1["modules"]["jit_step(1)"] == (2, pytest.approx(1200 * US))
+    assert dict(cut["device_ops"]) == {
+        "fusion.1": pytest.approx(600 * US),
+        "convolution.7": pytest.approx(600 * US),
+        "all-reduce.3": pytest.approx(500 * US)}
+
+
+def test_collective_exposure_is_clipped(cut):
+    d0, d1 = cut["devices"]
+    assert d0["collective_s"] == pytest.approx(500 * US)
+    assert d0["collective_exposed_s"] == pytest.approx(400 * US)
+    # 1300..1500 and 3800..3900 us: the fusions hide the rest, and
+    # 4000..4300 us is past the window
+    assert d1["collective_s"] == pytest.approx(600 * US)
+    assert d1["collective_exposed_s"] == pytest.approx(300 * US)
+
+
+def test_the_edge_gaps_make_the_gaps_add_up(cut):
+    gaps = dict(cut["idle_gaps"])
+    assert gaps == {
+        "engine.submit": pytest.approx(200 * US),      # 1000..1200 us
+        "no benchmark span": pytest.approx(800 * US),  # 2200..3000 us
+        "loadgen.sleep": pytest.approx(400 * US)}      # 3600..4000 us
+    first = cut["devices"][0]
+    assert sum(gaps.values()) == pytest.approx(
+        cut["window_s"] - first["busy_s"])
+
+
+def test_a_runs_file_without_the_span_reads_nothing():
+    # a caller with a window of its own (window_s) and no span in the
+    # file: no busy_s and the reason, never the whole file's sum
+    out = xplane.reduce_profile(_profile("small_trace.textproto"), NAMES,
+                                window_s=2000 * US)
+    assert out["busy_s"] is None and out["devices"] == []
+    assert "bench.window" in out["reason"]
+
+
+def test_device_trace_marks_its_window(capsys):
+    """``DeviceTrace`` on the CPU: the span is in the file and as long
+    as the host stamps are apart (to a millisecond on the chip, where
+    ``DeviceTrace.reduce`` says any disagreement; this shared CPU may
+    take a thread off between two lines, so 20 ms here); with no device
+    plane the reduction reads nothing and says why."""
+    import time
+    from jax.profiler import ProfileData
+    trace = harness.DeviceTrace()
+    trace.start()
+    time.sleep(0.05)
+    trace.stop()
+    profile = ProfileData.from_file(xplane.find_xplane(trace.dir))
+    spans = [ev.duration_ns * 1e-9 for plane in profile.planes
+             for line in plane.lines for ev in line.events
+             if ev.name == NAMES["window_span"]]
+    assert len(spans) == 1
+    assert spans[0] == pytest.approx(trace.t_stop - trace.t_start,
+                                     abs=2e-2)
+    out = trace.reduce(BENCH)
+    assert out["busy_s"] is None and not os.path.exists(trace.dir)
+    assert '"trace_unread": "no device plane in the trace"' in \
+        capsys.readouterr().out
