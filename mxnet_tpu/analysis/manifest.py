@@ -68,11 +68,11 @@ SPAN_ENTRY_POINTS = (
     ("mxnet_tpu/serving/decode_engine.py",
      "GenerationEngine._paged_dispatch"),
     ("mxnet_tpu/serving/decode_engine.py",
-     "GenerationEngine._paged_one_pass"),
-    ("mxnet_tpu/serving/decode_engine.py",
      "GenerationEngine._paged_prefill_chunk"),
     ("mxnet_tpu/serving/decode_engine.py",
      "GenerationEngine._paged_tick"),
+    ("mxnet_tpu/serving/decode_engine.py",
+     "GenerationEngine._queue_tick"),
     ("mxnet_tpu/serving/frontdoor.py", "_Handler._serve_generate"),
     ("mxnet_tpu/serving/frontdoor.py", "_Handler._serve_predict"),
     ("mxnet_tpu/serving/replica_set.py", "ReplicaSet._dispatch"),

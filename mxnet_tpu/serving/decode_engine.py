@@ -144,6 +144,16 @@ _Cover = collections.namedtuple(
     "_Cover", "hit whole last_id cut covered prog needed caps firsts")
 
 
+def _seed_key(seed):
+    """The threefry key data a request's chain starts from, ``(2,)
+    uint32``: byte-identical to ``jax.random.PRNGKey(seed)``, for
+    32-bit seeds without paying a threefry dispatch on the host's hot
+    path."""
+    if 0 <= seed < 2 ** 32:
+        return np.array((0, seed), np.uint32)
+    return np.asarray(jax.random.PRNGKey(seed), np.uint32)
+
+
 class GenerationResult:
     """One finished generation (what the request's Future resolves to).
 
@@ -253,6 +263,10 @@ class _GenRequest:
 class _ModelState:
     """Live decode batch of one model: slot table + the KV cache +
     per-slot sampling state (PRNG key chain, temperature, top-k)."""
+
+    # no tick of this plane is ever queued ahead of its fetch
+    # (:class:`_PagedModelState`, ``flight``)
+    flight = None
 
     def __init__(self, store):
         self.store = store
@@ -529,7 +543,7 @@ class _PrefixStore:
                 chain = chain[:j]
         return chain, tail, (len(chain), tail) != found
 
-    def _pin(self, e, blocks):
+    def _pin(self, e, blocks, pins):
         """Pin ``blocks`` (a block a class, 0: none) under entry ``e``
         in the classes where it has no pin."""
         for c, b in enumerate(blocks):
@@ -537,8 +551,26 @@ class _PrefixStore:
                 self._pools[c].ref(b, pin=True)
                 e[2][c] = b
                 self._lru[c][e[0]] = e
+                if pins is not None:
+                    pins.append((e, c))
 
-    def register(self, parent, tokens, blocks):
+    def unpin(self, pins):
+        """Take back ``pins`` (``(entry, class)``, as :meth:`register`
+        noted them): what a program that then FAILED was to fill.  An
+        entry left without a pin goes.  Returns the blocks unpinned, a
+        set a class."""
+        blocks = [set() for _ in self._pools]
+        for e, c in pins:
+            if e[2][c]:
+                blocks[c].add(e[2][c])
+                self._pools[c].deref(e[2][c], pin=True)
+                e[2][c] = 0
+                del self._lru[c][e[0]]
+            if not any(e[2]) and self._entries.get(e[1]) is e:
+                del self._entries[e[1]]
+        return blocks
+
+    def register(self, parent, tokens, blocks, pins=None):
         """Pin one block of a prefill for future sharing under the
         entry ``parent`` (an id; 0: the prompt's first block):
         ``tokens`` the block's own (fewer than a block's: the prompt's
@@ -546,14 +578,16 @@ class _PrefixStore:
         refcount a class a NEW pin; a prefix already registered —
         possibly against other physical blocks — keeps them, and gets
         back the pins of a class with a window that eviction took).
-        Returns the entry's id, the parent of the block after."""
+        ``pins``: a list that takes ``(entry, class)`` of every NEW pin,
+        for :meth:`unpin`.  Returns the entry's id, the parent of the
+        block after."""
         key = (parent, tuple(tokens))
         e = self._entries.get(key)
         if e is None:
             e = self._entries[key] = [self._next_id, key,
                                       [0] * len(self._pools)]
             self._next_id += 1
-        self._pin(e, blocks)
+        self._pin(e, blocks, pins)
         return e[0]
 
     def evictable(self, cls=0):
@@ -588,6 +622,20 @@ class _PrefixStore:
         if not any(found[2]):
             del self._entries[found[1]]
         return True
+
+
+class _Tick:
+    """One tick of a loop that runs a tick AHEAD of its fetches
+    (:meth:`GenerationEngine._tick_ahead`), queued and not yet
+    fetched: ``rows`` every ``(slot, request)`` it works for, the
+    decode group's first (``dec`` their slots), ``chunk`` its prompt
+    chunk (None: the tick had no prompt row), ``slots`` how many slots
+    the state had (the fetched array's layout), ``pins`` what it
+    registered with the prefix cache, ``toks_dev`` the array to fetch
+    and ``queued`` the starved clock's number of its dispatch."""
+
+    __slots__ = ("rows", "dec", "chunk", "slots", "traces", "pins",
+                 "toks_dev", "queued")
 
 
 class _PagedModelState:
@@ -675,6 +723,17 @@ class _PagedModelState:
         # write into it has nothing to allocate or fork
         self.ready = np.zeros(0, np.int32)
         self.keys = jnp.zeros((0, 2), jnp.uint32)
+        # a ONE-PASS store's loop runs a tick ahead of its fetches
+        # (GenerationEngine._tick_ahead): the slots' pending tokens
+        # stay on the device beside their key chains (``next_tok`` -1:
+        # the device has it), and ``flight`` is the tick queued and
+        # not yet fetched.  (No model that steps over row groups offers
+        # the draft plane, so such a store never has a ``draft``.)
+        self.ahead = store.one_pass
+        # (a host array until the first slots exist: a store that does
+        # not run ahead never puts it on the device)
+        self.pending = np.zeros(0, np.int32)
+        self.flight = None
         self.g_used = None                     # pool gauges (engine)
         self.g_hwm = None
         self.g_bytes = None
@@ -921,6 +980,12 @@ class GenerationEngine:
              # rows alike (store.one_pass): tick_programs over the
              # ticks is "programs a tick"
              "tick_programs", "tick_one_pass",
+             # a store whose loop runs a tick ahead of its fetches
+             # (_tick_ahead): the ticks queued while the one before was
+             # still unfetched (over tick_programs: 1.0 in a backlog),
+             # and the decode rows computed for a request that had
+             # ended by eos_id a tick before (thrown away)
+             "tick_ahead", "decode_rows_wasted",
              # admissions whose per-sequence state (a model with state
              # leaves) came from the prefix cache with the blocks;
              # prompt_tokens_admitted is what prefix_hit_tokens is a
@@ -1265,7 +1330,9 @@ class GenerationEngine:
     def _has_work(self):
         if any(self._waiting.values()):
             return True
-        return any(st.active() for st in self._states.values())
+        # (a tick in flight is work: its tokens are yet to deliver)
+        return any(st.active() or st.flight is not None
+                   for st in self._states.values())
 
     def _pump(self, stopping):
         """Move queued requests into the per-model FIFO waiting deques.
@@ -1597,11 +1664,12 @@ class GenerationEngine:
                     self._stats.inc("window_blocks_released")
             st.passed[i, c] = first
 
-    def _register_filled(self, st, i):
+    def _register_filled(self, st, i, pins=None):
         """Pin the whole prompt blocks slot i has filled since its last
         registration (and, at the prompt's end, its partial tail) in
         the prefix cache: as they fill, so that a class with a window
-        is pinned before :meth:`_release_behind` lets the block go."""
+        is pinned before :meth:`_release_behind` lets the block go.
+        ``pins``: as :meth:`_PrefixStore.register` takes it."""
         r = st.slots[i]
         bs = st.store.kv_block
         n, pid = int(st.reg_n[i]), int(st.reg_id[i])
@@ -1610,13 +1678,13 @@ class GenerationEngine:
             pid = st.prefix.register(
                 pid, r.prompt[n * bs:(n + 1) * bs],
                 [int(st.class_rows(c)[i, n])
-                 for c in range(len(st.windows))])
+                 for c in range(len(st.windows))], pins)
             n += 1
         if done == len(r.prompt) and n * bs < done:
             st.prefix.register(
                 pid, r.prompt[n * bs:],
                 [int(st.class_rows(c)[i, n])
-                 for c in range(len(st.windows))])
+                 for c in range(len(st.windows))], pins)
         st.reg_n[i], st.reg_id[i] = n, pid
 
     def _prefix_cover(self, st, r, held=(0, 0)):
@@ -1833,15 +1901,13 @@ class GenerationEngine:
                 st.top_ks[slot] = r.top_k
                 st.ready[slot] = -1
                 st.prop[slot] = -1
-                keys = np.array(st.keys, np.uint32)
-                if 0 <= r.seed < 2 ** 32:
-                    # byte-identical to jax.random.PRNGKey(seed) for
-                    # 32-bit seeds, without paying a threefry dispatch
-                    # on the admission hot path
-                    keys[slot] = (0, r.seed)
-                else:
-                    keys[slot] = np.asarray(jax.random.PRNGKey(r.seed))
-                st.keys = jnp.asarray(keys)
+                if not st.ahead:
+                    # (a tick ahead: the chain starts in the program
+                    # that draws the first token, _queue_tick; a fetch
+                    # of st.keys here would wait for the tick in flight)
+                    keys = np.array(st.keys, np.uint32)
+                    keys[slot] = _seed_key(r.seed)
+                    st.keys = jnp.asarray(keys)
                 if st.draft is not None:
                     # the draft's PRNG chain is an independent fold of
                     # the request seed — target and draft draws never
@@ -1895,6 +1961,9 @@ class GenerationEngine:
             [st.temps, np.zeros(grow, np.float32)])
         st.keys = jnp.concatenate(
             [st.keys, jnp.zeros((grow, 2), jnp.uint32)])
+        if st.ahead:
+            st.pending = jnp.concatenate(
+                [st.pending, np.zeros(grow, np.int32)])
         if st.draft is not None:
             st.dlen = np.concatenate(
                 [st.dlen, np.zeros(grow, np.int32)])
@@ -1947,42 +2016,41 @@ class GenerationEngine:
         (:meth:`_paged_prefill_chunk`), so a burst over one new
         document prefills it once.
 
-        A tick has one of three shapes.  Decode rows only: the decode
-        program.  Prompt rows, with or without decode rows, on a store
-        whose model steps over row groups (``store.one_pass``): ONE
-        program and ONE fetch, the decode rows and the chunk's rows as
-        two groups of one step that reads the weights once
-        (:meth:`_paged_one_pass`; without decode rows that group rides
-        dead).  Prompt rows on any other store (``transformer_lm``,
-        host sampling, an int8 pool, a speculative draft, a self-draft):
-        the decode program, then the chunk program, as below."""
-        dec = [i for i in st.active() if st.decoding[i]]
-        pre = sorted((i for i in st.active() if not st.decoding[i]),
-                     key=lambda i: st.slots[i].t_admit)
-        # both programs are dispatched before either's tokens are
-        # fetched: the chunk takes the pool and the key chains the
-        # decode step returns (arrays not yet computed), which rows
-        # are in their prompt does not hang on what the decode step
-        # samples, and a slot's blocks are reserved at admission.  So
-        # the device goes from one program to the next while the host
-        # resolves the first one's tokens, and not after it.
-        resolve = []
-        one_pass = bool(pre) and st.store.one_pass and st.draft is None
-        if one_pass:
-            resolve.append(self._paged_one_pass(model, st, dec, pre))
-        elif dec:
-            if st.self_draft:
-                resolve.append(self._paged_self_draft_step(model, st, dec))
-            elif st.draft is not None and self._spec_active(st):
-                self._paged_spec_step(model, st, dec)
-            else:
-                resolve.append(self._paged_decode_step(model, st, dec))
-        if pre and not one_pass:
-            resolve.append(self._paged_prefill_chunk(model, st, pre))
-        for finish in resolve:
-            if finish is not None:
-                finish()
-        if dec or pre:
+        A store whose model steps over row groups (``store.one_pass``)
+        takes ONE program and ONE fetch a tick, whatever rows it has,
+        and its loop runs a tick AHEAD of its fetches
+        (:meth:`_tick_ahead`).  Every other store (``transformer_lm``,
+        host sampling, an int8 pool, a speculative draft, a self-draft)
+        queues the decode program, then the chunk program, as below."""
+        if st.ahead:
+            busy = self._tick_ahead(model, st)
+        else:
+            dec = [i for i in st.active() if st.decoding[i]]
+            pre = sorted((i for i in st.active() if not st.decoding[i]),
+                         key=lambda i: st.slots[i].t_admit)
+            busy = bool(dec or pre)
+            # both programs are dispatched before either's tokens are
+            # fetched: the chunk takes the pool and the key chains the
+            # decode step returns (arrays not yet computed), which rows
+            # are in their prompt does not hang on what the decode step
+            # samples, and a slot's blocks are reserved at admission.
+            # So the device goes from one program to the next while the
+            # host resolves the first one's tokens, and not after it.
+            resolve = []
+            if dec:
+                if st.self_draft:
+                    resolve.append(
+                        self._paged_self_draft_step(model, st, dec))
+                elif st.draft is not None and self._spec_active(st):
+                    self._paged_spec_step(model, st, dec)
+                else:
+                    resolve.append(self._paged_decode_step(model, st, dec))
+            if pre:
+                resolve.append(self._paged_prefill_chunk(model, st, pre))
+            for finish in resolve:
+                if finish is not None:
+                    finish()
+        if busy:
             self._paged_gauges(st)
             if len(st.windows) > 1:
                 # the bytes the live sequences' tables hold a tick (a
@@ -2220,14 +2288,8 @@ class GenerationEngine:
             st.lengths[idx] += 1
             st.next_tok[idx] = sampled[idx]
             for i, tok in zip(dec, sampled[idx].tolist()):
-                r = st.slots[i]
-                self._push_token(r, tok)
-                reason = self._finished_reason(r, tok)
-                if reason:
-                    self._release_paged_slot(st, i)
-                    self._finish(r, reason)
-                    span.add(finished=1)
-                elif st.window is not None:
+                if not self._deliver(st, i, st.slots[i], tok, span) \
+                        and st.window is not None:
                     self._release_behind(st, i)
         self._stats.inc("decode_steps")
         self._stats.inc("generated_tokens", len(dec))
@@ -2258,67 +2320,188 @@ class GenerationEngine:
             self._decode_resolve(st, dec, idx, sampled)
         return finish
 
-    def _paged_one_pass(self, model, st, dec, pre):
-        """A tick with prompt rows as ONE program and ONE fetch
-        (``store.one_pass``): the decode group of ``dec`` and the chunk
-        of ``pre`` are laid out as the two-program tick lays them out,
-        in its order (:meth:`_decode_rows`, then :meth:`_chunk_rows`
-        with its late adoption and its one writer a block), queued as
-        the two row groups of ``paged_tick_sample``, fetched as one
-        array and resolved in the two-program tick's order: decode
-        rows, then chunk rows.  The tokens are the two-program tick's.
-        With ``dec`` empty the decode group rides dead, as a decode
-        step's dead rows do.  The dispatch is told by BOTH spans, each
+    def _tick_ahead(self, model, st):
+        """One tick of a ONE-PASS store (``st.ahead``), whose loop
+        runs ONE tick ahead of its fetches: this tick's program is
+        queued BEFORE the tick before's tokens are fetched, so the
+        device goes from one program to the next, and the fetch's
+        return, the delivery, admission, the next preparation and its
+        launch all lie under a running program and not between two.
+
+        What makes it possible: the token a decode row feeds does not
+        pass through the host (the slots' pending tokens live on the
+        device, ``st.pending``: the program that samples one writes it
+        to its slot's place and the next reads it there), and a tick is
+        split into what needs POSITIONS and what needs token VALUES.
+        At queue time, right behind the launch (:meth:`_queue_tick`),
+        everything the host knows without the tokens moves: frontiers,
+        prompt progress, the prefix cache's registrations (a block
+        registered now is read only by programs queued later, and the
+        device runs them in order: what a two-program tick leans on for
+        the pool), window releases, and a slot whose prompt ends turns
+        to decoding.  At fetch time, one program later
+        (:meth:`_deliver_tick`), what needs values: tokens to their
+        requests, EOS, retirement.
+
+        A request that ends by ``max_tokens`` is known at queue time
+        and not laid out again.  One that ends by ``eos_id`` is known
+        only at its fetch: its row in the tick queued meanwhile is
+        computed and thrown away (``decode_rows_wasted``; it wrote into
+        the request's own block, forked as every generation write is,
+        which went back to the pool when the request ended; whoever
+        takes the block next writes it in a LATER program).  A freed
+        slot is refilled by the admission that follows its delivery:
+        it rides dead for one tick.  Returns whether the tick did
+        anything."""
+        dec, pre = [], []
+        for i in st.active():
+            r = st.slots[i]
+            if not st.decoding[i]:
+                pre.append(i)
+            elif st.lengths[i] + 1 < len(r.prompt) + r.max_tokens:
+                dec.append(i)       # (else: its last token is queued)
+        pre.sort(key=lambda i: st.slots[i].t_admit)
+        tick = self._queue_tick(model, st, dec, pre) if dec or pre \
+            else None
+        # (a launch that failed took the tick in flight with it)
+        before, st.flight = st.flight, tick
+        if before is not None:
+            self._deliver_tick(model, st, before)
+        return bool(dec or pre or before)
+
+    def _queue_tick(self, model, st, dec, pre):
+        """Lay out, launch and ADVANCE one tick of :meth:`_tick_ahead`:
+        the decode group of ``dec`` and the chunk of ``pre`` laid out
+        as the two-program tick lays them out, in its order
+        (:meth:`_decode_rows`, then :meth:`_chunk_rows` with its late
+        adoption and its one writer a block), queued as ONE program
+        (``paged_tick_sample``: two row groups of one step; without
+        prompt rows ``paged_step_sample``), then everything moved that
+        needs no token value.  The dispatch is told by BOTH spans, each
         with its own group's counts, the one launch inside them: what
         reads a kernel's required work from ``serve_decode`` and
-        ``serve_prefill`` reads what it read (no ``serve_decode`` where
-        no row decodes).  A dispatch or a fetch that raises fails the
-        slots of both groups.  Returns what is left once the program
-        is queued, as :meth:`_paged_decode_step` does."""
+        ``serve_prefill`` reads what it read (neither where its group
+        has no row).  Returns the tick in flight (None: the launch
+        raised, and the slots of this tick and of the one in flight
+        have failed)."""
         with _profiler.phase("serve_prepare") as span:
             idx, (dtables, dtoks, dpos, dval, ddo), dtraces = \
                 self._decode_rows(st, dec)
-            c = self._chunk_rows(st, pre, span)
-        both = list(dec) + c.live
+            # a decode row whose token the host does not have (-1)
+            # reads the device's
+            host = dtoks[:, 0] >= 0
+            c = self._chunk_rows(st, pre, span) if pre else None
+            if c is not None:
+                # a row that samples starts its request's chain
+                row_keys = np.zeros((c.n, 2), np.uint32)
+                for k, (_i, r, _p0, _ntok) in enumerate(c.rows):
+                    if c.do[k]:
+                        row_keys[k] = _seed_key(r.seed)
+        tick = _Tick()
+        tick.dec, tick.chunk, tick.slots = dec, c, len(dtables)
+        tick.rows = [(i, st.slots[i]) for i in dec] + (
+            [(i, r) for i, r, _p0, _ntok in c.rows] if c else [])
+        tick.traces = dtraces + (c.traces if c else [])
+        tick.pins = []
         try:
-            dwork = self._paged_work(st, dpos, dval, dec)[2] if dec else None
-            temps, top_ks, work = self._paged_work(
-                st, c.pos, c.val, np.arange(len(c.rows)), c.slots,
-                width=c.n, deferred=c.deferred)
-            with _tracing.activate_many(dtraces), \
-                    _profiler.phase("serve_decode", **dwork) if dec \
-                    else contextlib.nullcontext(), \
-                    _tracing.activate_many(c.traces), \
-                    _profiler.phase("serve_prefill", **work):
+            with contextlib.ExitStack() as spans:
+                if dec:
+                    spans.enter_context(_tracing.activate_many(dtraces))
+                    spans.enter_context(_profiler.phase(
+                        "serve_decode",
+                        **self._paged_work(st, dpos, dval, dec)[2]))
+                if c is not None:
+                    temps, top_ks, work = self._paged_work(
+                        st, c.pos, c.val, np.arange(len(c.rows)), c.slots,
+                        width=c.n, deferred=c.deferred)
+                    spans.enter_context(_tracing.activate_many(c.traces))
+                    spans.enter_context(
+                        _profiler.phase("serve_prefill", **work))
                 self._starved.launching()
-                out = st.store.run_paged_tick_sample(
-                    *st.pools, c.tables, c.toks, c.pos, c.val, st.keys,
-                    temps, top_ks, c.do, c.slots, dtables, dtoks, dpos,
-                    dval, st.temps, st.top_ks, ddo)
-                queued = self._starved.dispatched()
-                toks_dev, st.keys = st.take(out)
-            self._stats.inc("tick_programs")
-            self._stats.inc("tick_one_pass")
+                if c is None:
+                    out = st.store.run_paged_step_sample(
+                        *st.pools, dtables, dtoks, dpos, dval, st.keys,
+                        st.temps, st.top_ks, ddo, st.pending, host)
+                else:
+                    out = st.store.run_paged_tick_sample(
+                        *st.pools, c.tables, c.toks, c.pos, c.val,
+                        st.keys, temps, top_ks, c.do, c.slots, dtables,
+                        dtoks, dpos, dval, st.temps, st.top_ks, ddo,
+                        row_keys, st.pending, host)
+                tick.queued = self._starved.dispatched()
+                tick.toks_dev, st.keys, st.pending = st.take(out)
         except BaseException as e:  # noqa: BLE001 — to the futures
-            self._paged_failed(model, st, both, e, "tick")
+            self._ahead_failed(model, st, tick, e)
             return None
+        self._stats.inc("tick_programs")
+        if st.flight is not None:
+            self._stats.inc("tick_ahead")
+        with _profiler.phase("serve_resolve"):
+            st.lengths[idx] += 1
+            if st.window is not None:
+                for i in dec:
+                    self._release_behind(st, i)
+            if c is not None:
+                self._stats.inc("tick_one_pass")
+                self._chunk_advance(model, st, c, tick.pins)
+        return tick
 
-        def finish():
-            try:
-                with _tracing.activate_many(dtraces + c.traces), \
-                        _profiler.phase("serve_sample"):
-                    out = self._fetch_decode(toks_dev)
-                    self._starved.fetched(queued)
-            except BaseException as e:  # noqa: BLE001
-                self._paged_failed(model, st, both, e, "tick")
-                return
-            # decode rows' tokens, chunk rows', the model's counters
-            n = len(dtables)
-            self._count_aux(st.store.aux_counters, out[n + c.n:])
-            if dec:
-                self._decode_resolve(st, dec, idx, out[:n])
-            self._chunk_resolve(model, st, c, out[n:n + c.n])
-        return finish
+    def _deliver_tick(self, model, st, tick):
+        """Fetch ``tick``'s ONE array (decode rows' tokens, chunk rows',
+        the model's counters) and do what needed the values: every
+        token to its request, in the two-program tick's order (decode
+        rows, then the chunk rows that finished their prompt: the TTFT
+        moment), and the requests that end with it retire.  A decode
+        row whose request ended a tick before is thrown away.  A fetch
+        that raises fails this tick's slots and those of the tick
+        queued behind it."""
+        try:
+            with _tracing.activate_many(tick.traces), \
+                    _profiler.phase("serve_sample"):
+                out = self._fetch_decode(tick.toks_dev)
+                self._starved.fetched(tick.queued)
+        except BaseException as e:  # noqa: BLE001 — to the futures
+            self._ahead_failed(model, st, tick, e)
+            return
+        c, n = tick.chunk, tick.slots
+        self._count_aux(st.store.aux_counters,
+                        out[n + (c.n if c else 0):])
+        with _profiler.phase("serve_resolve") as span:
+            given = 0
+            for i, r in tick.rows[:len(tick.dec)]:
+                if st.slots[i] is r:
+                    self._deliver(st, i, r, int(out[i]), span)
+                    given += 1
+            firsts = 0
+            for k, (i, r, _p0, _ntok) in enumerate(c.rows if c else ()):
+                if c.do[k]:
+                    self._deliver(st, i, r, int(out[n + k]), span)
+                    firsts += 1
+            span.add(tokens=given + firsts)
+        if tick.dec:
+            self._stats.inc("decode_steps")
+            self._stats.inc("generated_tokens", given)
+            self._stats.inc("decode_rows_wasted", len(tick.dec) - given)
+
+    def _ahead_failed(self, model, st, tick, e):
+        """``tick``'s launch or fetch raised: its slots fail, and those
+        of the tick in flight beside it (queued on what this one was to
+        return, or before it on the same device: neither's results are
+        trusted).  What they registered with the prefix cache at queue
+        time goes, and a slot of neither tick that has ADOPTED such a
+        block since fails with them; every other slot serves on."""
+        ticks = [tick]
+        if st.flight is not None and st.flight is not tick:
+            ticks.append(st.flight)
+        st.flight = None
+        lost = {i for t in ticks for i, r in t.rows if st.slots[i] is r}
+        blocks = st.prefix.unpin([p for t in ticks for p in t.pins])
+        for i in st.active():
+            if i not in lost and any(
+                    bad and np.isin(st.class_rows(c)[i], list(bad)).any()
+                    for c, bad in enumerate(blocks)):
+                lost.add(i)
+        self._paged_failed(model, st, sorted(lost), e, "tick")
 
     def _spec_active(self, st):
         """The MXNET_SERVE_SPEC=auto degradation gate, checked once
@@ -2808,54 +2991,66 @@ class GenerationEngine:
                 c.more["after"][k] = r.prompt[p0 + ntok]
         return c
 
-    def _chunk_resolve(self, model, st, c, sampled):
-        """The chunk's rows, through the device, to their slots: each
-        moves by its tokens and registers the blocks it filled with the
-        prefix cache; rows finishing their prompt take their first
-        token (the TTFT moment) from ``sampled`` and flip to
-        decoding."""
-        rows = c.rows
+    def _chunk_advance(self, model, st, c, pins=None):
+        """The chunk's rows, once their program is queued, move by
+        their tokens and register the blocks they fill with the prefix
+        cache (``pins``: as :meth:`_PrefixStore.register` takes it); a
+        row finishing its prompt turns to decoding, its first token not
+        the host's yet (``next_tok`` -1).  Nothing here needs a token's
+        value."""
         self._stats.inc("prefills")
-        self._stats.inc("prefill_chunks", len(rows))
+        self._stats.inc("prefill_chunks", len(c.rows))
         self._stats.inc("prefill_row_slots", c.n)
         self._stats.inc("prefill_rows_deferred", c.deferred)
         self._stats.inc("prefill_rows_waited", c.waited)
-        with _profiler.phase("serve_resolve") as span:
-            for k, (i, r, p0, ntok) in enumerate(rows):
-                st.prog[i] = p0 + ntok
-                st.lengths[i] = p0 + ntok
-                if st.draft is not None and st.spec_mirror():
-                    st.dlen[i] = p0 + ntok
-                st.chunks_done[i] += 1
-                self._register_filled(st, i)
-                if p0 + ntok < len(r.prompt):
-                    if st.window is not None:
-                        self._release_behind(st, i)
-                    continue
+        for k, (i, _r, p0, ntok) in enumerate(c.rows):
+            st.prog[i] = st.lengths[i] = p0 + ntok
+            if st.draft is not None and st.spec_mirror():
+                st.dlen[i] = p0 + ntok
+            st.chunks_done[i] += 1
+            self._register_filled(st, i, pins)
+            if c.do[k]:
                 if _metrics.phase_on():
                     _H_CHUNKS.observe(int(st.chunks_done[i]))
+                st.decoding[i] = True
+                st.next_tok[i] = -1
+            if st.window is not None:
+                self._release_behind(st, i)
+        self._note_cache_hwm(model, st)
+
+    def _deliver(self, st, i, r, tok, span):
+        """Slot i's fetched token to its request, which retires with it
+        where it ends (True)."""
+        self._push_token(r, tok)
+        reason = self._finished_reason(r, tok)
+        if reason:
+            self._release_paged_slot(st, i)
+            self._finish(r, reason)
+            span.add(finished=1)
+        return bool(reason)
+
+    def _chunk_resolve(self, model, st, c, sampled):
+        """The chunk's rows, through the device, to their slots
+        (:meth:`_chunk_advance`); rows finishing their prompt take
+        their first token (the TTFT moment) from ``sampled``."""
+        with _profiler.phase("serve_resolve") as span:
+            self._chunk_advance(model, st, c)
+            for k, (i, r, _p0, _ntok) in enumerate(c.rows):
+                if not c.do[k]:
+                    continue
                 tok = int(sampled[k])
-                self._push_token(r, tok)
                 span.add(tokens=1)
-                reason = self._finished_reason(r, tok)
-                if reason:
-                    self._release_paged_slot(st, i)
-                    self._finish(r, reason)
-                    span.add(finished=1)
-                else:
-                    st.decoding[i] = True
-                    st.next_tok[i] = tok
-                    if st.self_draft:
-                        # the module's first proposal, for the
-                        # position after the sampled token's
-                        self._note_draft(st, i, r, len(r.prompt) + 1,
-                                         int(st.chunk_props[k]))
-                    if st.window is not None:
-                        self._release_behind(st, i)
+                if self._deliver(st, i, r, tok, span):
+                    continue
+                st.next_tok[i] = tok
+                if st.self_draft:
+                    # the module's first proposal, for the position
+                    # after the sampled token's
+                    self._note_draft(st, i, r, len(r.prompt) + 1,
+                                     int(st.chunk_props[k]))
         if st.self_draft:
             self._stats.inc("draft_rows",
-                            sum(row[3] for row in rows))
-        self._note_cache_hwm(model, st)
+                            sum(row[3] for row in c.rows))
 
     def _paged_prefill_chunk(self, model, st, pre):
         """Advance the first of the prefilling slots ``pre`` one
@@ -3080,6 +3275,15 @@ class GenerationEngine:
                 self._fail_request(dq.popleft(), exc)
         self._waiting.clear()
         for model, st in list(self._states.items()):
+            if st.flight is not None:
+                # the tick in flight first: its tokens are computed.
+                # (This runs in the loop's ``finally`` too: whatever a
+                # delivery raises there, the slots below still fail)
+                tick, st.flight = st.flight, None
+                try:
+                    self._deliver_tick(model, st, tick)
+                except Exception:  # noqa: BLE001 — to the futures, below
+                    pass
             for i in st.active():
                 r = st.slots[i]
                 st.slots[i] = None

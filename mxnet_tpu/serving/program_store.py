@@ -182,7 +182,8 @@ def sample_tokens(logits, keys, temps, top_ks):
     return toks, carry
 
 
-def sample_chunk_rows(logits, keys, temps, top_ks, do_sample, slots):
+def sample_chunk_rows(logits, keys, temps, top_ks, do_sample, slots,
+                      row_keys=None):
     """:func:`sample_tokens` for the ``R`` rows of a compacted
     prompt-chunk dispatch.  Row ``k`` works for slot ``slots[k]``: it
     draws with that slot's chain out of ``keys (S, 2)``, and the chain
@@ -191,10 +192,16 @@ def sample_chunk_rows(logits, keys, temps, top_ks, do_sample, slots):
     that does not sample is sent past the slot axis and dropped by the
     scatter, so a padding row (slot 0, ``do_sample`` False) cannot
     collide with the live row of slot 0.  ``temps`` and ``top_ks`` are
-    per row.  Returns ``(tokens (R,) int32, new_keys (S, 2))``."""
+    per row.  ``row_keys (R, 2)``: the chains the rows draw with, from
+    a caller that has them (a row that samples draws its request's
+    FIRST token, so its chain is the request's seed key, which the host
+    knows), in place of ``keys[slots]``.  Returns ``(tokens (R,) int32,
+    new_keys (S, 2))``."""
     keys = jnp.asarray(keys, jnp.uint32)
     slots = jnp.asarray(slots, jnp.int32)
-    toks, carry = sample_tokens(logits, keys[slots], temps, top_ks)
+    toks, carry = sample_tokens(
+        logits, keys[slots] if row_keys is None else row_keys, temps,
+        top_ks)
     dest = jnp.where(jnp.asarray(do_sample), slots, keys.shape[0])
     return toks, keys.at[dest].set(carry, mode="drop")
 
@@ -963,14 +970,18 @@ _CHUNK_NAMES = {"paged_self_chunk": "paged_prefill_chunk_self",
                 "paged_draft_chunk": "paged_prefill_chunk_draft",
                 "paged_tick_sample": "paged_prefill_chunk_tick"}
 # where the decode group's arguments start among ``paged_tick_sample``'s
-# own: behind the compacted chunk's nine
+# own: behind the compacted chunk's nine.  Behind the decode group's
+# seven: the chunk rows' own chains, then the slots' pending tokens and
+# which decode rows take the host's token instead
 _TICK_DECODE_AT = 9
+_TICK_ROW_KEYS_AT = _TICK_DECODE_AT + 7
 # results in front of the pool's leaves in a kind's flat return
 _HEADS = {"paged_step_sample_p": 2, "paged_verify": 2,
           "paged_self_verify": 4, "paged_self_chunk": 3}
 
 
-def paged_program(model, spec, kind, lq, kv_block, nleaf, int8=False):
+def paged_program(model, spec, kind, lq, kv_block, nleaf, int8=False,
+                  pending=False):
     """The function one paged step program compiles, named as the
     profiler's module line shows it, and the positions of the
     arguments it donates: ``(fn, donate)``.
@@ -987,8 +998,21 @@ def paged_program(model, spec, kind, lq, kv_block, nleaf, int8=False):
     *the kind's own)``; on the int8 plane every kind gains the two
     donated scale pools right after the ``nleaf`` code pools, in
     arguments AND returns.  ``model`` is a decode-mode model module
-    (``_serving_model``), ``spec`` its serving spec."""
+    (``_serving_model``), ``spec`` its serving spec.
+
+    ``pending`` (a :attr:`GenerativeProgramStore.one_pass` store's
+    ``paged_step_sample``; its ``paged_tick_sample`` always): the
+    slots' PENDING TOKENS live on the device as their key chains do.
+    The program takes two arguments more, ``pending (S,) int32``
+    (donated, handed back behind the chains) and ``host (S,) bool``: a
+    decode row reads its input token from ``pending`` unless ``host``
+    says the host owns it (then ``tokens``, as every other store's),
+    and a row that samples (a decode row that ``do``es, a chunk row
+    that finishes its prompt) writes its token to its slot's place, so
+    the next program can be queued before this one's tokens are
+    fetched."""
     npool = nleaf + (2 if int8 else 0)
+    pending = pending and kind == "paged_step_sample"
     pool_donate = tuple(range(1, 1 + npool))
     name = _CHUNK_NAMES.get(kind) or (
         kind if kind in SELF_DRAFT_KINDS + ("paged_verify",)
@@ -1023,6 +1047,9 @@ def paged_program(model, spec, kind, lq, kv_block, nleaf, int8=False):
             pls = rest[:npool]
             (tables, tokens, positions, valid, keys, temps, top_ks,
              do_sample) = rest[npool:npool + 8]
+            if pending:
+                held, host = rest[npool + 8:]
+                tokens = jnp.where(host, tokens[:, 0], held)[:, None]
             logits, new_pools, aux = step(
                 params, pls, tables, tokens, positions, valid)
             if compact:
@@ -1037,14 +1064,18 @@ def paged_program(model, spec, kind, lq, kv_block, nleaf, int8=False):
                     toks, carry = sample_tokens(logits, keys, temps,
                                                 top_ks)
                 new_keys = jnp.where(do_sample[:, None], carry, keys)
+            tail = (new_keys,)
+            if pending:
+                tail += (jnp.where(do_sample, toks, held),)
             if aux is not None:
                 # the model's counters ride behind the tokens: one
                 # small array, one fetch
                 toks = jnp.concatenate([toks, aux.astype(toks.dtype)])
             head = (toks, q) if with_q else (toks,)
-            return head + new_pools + (new_keys,)
+            return head + new_pools + tail
 
-        donate = pool_donate + (1 + npool + 4,)
+        donate = pool_donate + (1 + npool + 4,) + (
+            (1 + npool + 8,) if pending else ())
     elif kind == "paged_tick_sample":
         # the ONE-PASS tick of a model with a step over row groups: the
         # bucket's decode rows (one query each) and its compacted
@@ -1053,7 +1084,11 @@ def paged_program(model, spec, kind, lq, kv_block, nleaf, int8=False):
         # arguments as ``paged_chunk_sample`` takes them, then the
         # decode group's tables, tokens, positions, valid, temps,
         # top_ks and do_sample; the slots' key chains once.  A slot is
-        # in one group, so the two samplers' chains never meet.  ONE
+        # in one group, so the two samplers' chains never meet.  Then
+        # the chunk rows' OWN chains (a row that samples starts its
+        # request's chain: the host sends the seed key, and admission
+        # writes nothing to the device), the slots' pending tokens and
+        # the decode rows' ``host`` flags (``pending``, above).  ONE
         # int32 array comes back: the decode rows' tokens, the chunk
         # rows', the model's counters once.
         def fn(params, *rest):
@@ -1061,7 +1096,9 @@ def paged_program(model, spec, kind, lq, kv_block, nleaf, int8=False):
             (tables, tokens, positions, valid, keys, temps, top_ks,
              do_sample, slots) = rest[npool:npool + _TICK_DECODE_AT]
             (dtables, dtokens, dpositions, dvalid, dtemps, dtop_ks,
-             ddo) = rest[npool + _TICK_DECODE_AT:]
+             ddo) = rest[npool + _TICK_DECODE_AT:npool + _TICK_ROW_KEYS_AT]
+            row_keys, held, host = rest[npool + _TICK_ROW_KEYS_AT:]
+            dtokens = jnp.where(host, dtokens[:, 0], held)[:, None]
             (dlogits, logits), new_pools, aux = model.paged_step_groups(
                 params, pls, ((dtables, dtokens, dpositions, dvalid),
                               (tables, tokens, positions, valid)),
@@ -1069,11 +1106,18 @@ def paged_program(model, spec, kind, lq, kv_block, nleaf, int8=False):
             dtoks, carry = sample_tokens(dlogits, keys, dtemps, dtop_ks)
             toks, new_keys = sample_chunk_rows(
                 logits, jnp.where(ddo[:, None], carry, keys), temps,
-                top_ks, do_sample, slots)
+                top_ks, do_sample, slots, row_keys)
+            # a chunk row that samples hands its slot its first token
+            # (rows that do not are sent past the slot axis, as their
+            # chains are)
+            dest = jnp.where(do_sample, slots, held.shape[0])
+            held = jnp.where(ddo, dtoks, held).at[dest].set(
+                toks, mode="drop")
             return (jnp.concatenate([dtoks, toks, aux.astype(toks.dtype)]),
-                    ) + tuple(new_pools) + (new_keys,)
+                    ) + tuple(new_pools) + (new_keys, held)
 
-        donate = pool_donate + (1 + npool + 4,)
+        donate = pool_donate + (1 + npool + 4,
+                                1 + npool + _TICK_ROW_KEYS_AT + 1)
     elif kind == "paged_verify":
         # speculative verify: all lq=K+1 positions' logits stay
         # in-graph, the rejection rule runs beside them (spec_verify),
@@ -1768,12 +1812,19 @@ class GenerativeProgramStore:
             avals.append(((rows,), np.int32))
         if kind == "paged_tick_sample":
             # the decode group behind the chunk: bb rows of one query,
-            # and their temps, top_ks, do_sample
+            # and their temps, top_ks, do_sample; the chunk rows' own
+            # chains
             assert len(avals) == _TICK_DECODE_AT
             avals += [((bb, self.table_width()), np.int32),
                       ((bb, 1), np.int32), ((bb,), np.int32),
                       ((bb,), np.int32), ((bb,), np.float32),
-                      ((bb,), np.int32), ((bb,), np.bool_)]
+                      ((bb,), np.int32), ((bb,), np.bool_),
+                      ((rows, 2), np.uint32)]
+        if kind == "paged_tick_sample" or (
+                kind == "paged_step_sample" and self.one_pass):
+            # the slots' pending tokens, and the rows that take the
+            # host's instead
+            avals += [((bb,), np.int32), ((bb,), np.bool_)]
         return avals
 
     def _key(self, kind, bb, lb):
@@ -1798,7 +1849,7 @@ class GenerativeProgramStore:
                 for shape, dtype in self._paged_avals(kind, bb, lb))
             fn, donate = paged_program(model, spec, kind, lb,
                                        self.kv_block, self.pool_leaves,
-                                       self.kv_int8)
+                                       self.kv_int8, pending=self.one_pass)
             compiled = jax.jit(
                 fn, donate_argnums=cache_donate_argnums(donate)) \
                 .lower(*args).compile()
@@ -2108,7 +2159,10 @@ class GenerativeProgramStore:
         len(aux_counters),)``: one array, one fetch).  Rows with
         ``do_sample`` False keep their PRNG keys (their sampled token
         is garbage the caller discards); pools and keys are consumed
-        (donated) — callers rebind."""
+        (donated) — callers rebind.  A :attr:`one_pass` store's takes
+        ``pending (S,) int32`` and ``host (S,) bool`` behind
+        ``do_sample`` and returns the new ``pending`` behind
+        ``new_keys`` (:func:`paged_program`, ``pending``)."""
         return self._run_paged('paged_step_sample', args, scales)
 
     @hot_path
@@ -2131,13 +2185,17 @@ class GenerativeProgramStore:
         prompt chunk and the slots' decode step as two row groups of
         one program.  ``run_paged_tick_sample(*pool leaves,
         *run_paged_chunk_sample's own nine, tables, tokens, positions,
-        valid, temps, top_ks, do_sample)``, the last seven the decode
-        group's ``(S, ...)`` arrays (``tokens`` ``(S, 1)``; the chains
-        ``keys (S, 2)`` go in once, with the chunk's).  Returns
-        ``(tokens (S + rows,) int32 + the model's counters, *pool
-        leaves, new_keys (S, 2))``: the decode rows' tokens first; a
-        chain advances where its slot's row of either group has
-        ``do_sample`` set.  Pools and keys are consumed (donated) —
+        valid, temps, top_ks, do_sample, row_keys, pending, host)``:
+        seven ``(S, ...)`` arrays of the decode group (``tokens`` ``(S,
+        1)``; the chains ``keys (S, 2)`` go in once, with the chunk's),
+        the chunk rows' own chains ``(rows, 2)`` (the seed key of a
+        row that samples), the slots' pending tokens ``(S,) int32`` and
+        the decode rows that take ``tokens`` instead ``(S,) bool``.
+        Returns ``(tokens (S + rows,) int32 + the model's counters,
+        *pool leaves, new_keys (S, 2), new_pending (S,))``: the decode
+        rows' tokens first; a chain advances, and a pending token is
+        written, where its slot's row of either group has ``do_sample``
+        set.  Pools, keys and pending tokens are consumed (donated) —
         callers rebind."""
         return self._run_paged('paged_tick_sample', args, None)
 

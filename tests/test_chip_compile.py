@@ -493,6 +493,9 @@ def _paged_program_args(build, chip, kind):
 
     m = build(chip)
     slots = m["slots"]
+    # a one-pass store's two programs keep the slots' pending tokens on
+    # the device (what its engine dispatches: the decode step too)
+    pending = hasattr(m["model"], "paged_step_groups")
     if kind == "decode":
         pkind, rows, lq = "paged_step_sample", slots, 1
     else:
@@ -501,7 +504,7 @@ def _paged_program_args(build, chip, kind):
         rows, lq = chunk_rows(slots), m["chunk"]
         assert rows == slots // 4
     fn, donate = paged_program(m["model"], m["spec"], pkind, lq, BS,
-                               len(m["pools"]))
+                               len(m["pools"]), pending=pending)
     args = (m["params"],) + m["pools"] + (
         chip((rows, m["width"]), I32), chip((rows, lq), I32),
         chip((rows,), I32), chip((rows,), I32),
@@ -509,11 +512,14 @@ def _paged_program_args(build, chip, kind):
         chip((rows,), jnp.bool_))
     if kind != "decode":
         args += (chip((rows,), I32),)
-    if kind == "one-pass":      # the decode group behind the chunk
+    if kind == "one-pass":      # the decode group behind the chunk,
+        # then the chunk rows' own chains
         args += (chip((slots, m["width"]), I32), chip((slots, 1), I32),
                  chip((slots,), I32), chip((slots,), I32),
                  chip((slots,)), chip((slots,), I32),
-                 chip((slots,), jnp.bool_))
+                 chip((slots,), jnp.bool_), chip((rows, 2), jnp.uint32))
+    if kind == "one-pass" or (kind == "decode" and pending):
+        args += (chip((slots,), I32), chip((slots,), jnp.bool_))
     return m, args, fn, donate
 
 

@@ -706,6 +706,8 @@ def test_chunk_dispatch_runs_over_the_slots_in_their_prompt(
         queue = getattr(eng, method)
 
         def queued(model, st, *groups):
+            if not groups[-1]:      # a one-pass tick without prompt rows
+                return queue(model, st, *groups)
             before = np.array(st.keys)
             finish = queue(model, st, *groups)
             c, dec = last["c"], list(groups[0]) if len(groups) > 1 else []
@@ -722,7 +724,7 @@ def test_chunk_dispatch_runs_over_the_slots_in_their_prompt(
 
     assert store.one_pass == (arch != "lm")
     eng._chunk_rows = spy_rows
-    spy("_paged_one_pass" if store.one_pass else "_paged_prefill_chunk")
+    spy("_queue_tick" if store.one_pass else "_paged_prefill_chunk")
     opened = profiler.phase_totals()
     try:
         futs = [eng.submit("m", **kw) for kw in reqs]
@@ -1235,36 +1237,47 @@ def _without_groups(monkeypatch):
             if k != "paged_step_groups"}))
 
 
-def _mixed_requests(seed, vocab, n=14):
+def _mixed_requests(seed, vocab, n=14, rows="mixed"):
     """A seeded mix: one prefix of two whole blocks under most of the
     prompts, prompts of 3 to 30 tokens, one to six tokens out, greedy
-    and seeded draws: slots refill while others decode, so ticks carry
-    decode rows and prompt rows together."""
+    and seeded draws (``rows``: every second row of each, or all of
+    one): slots refill while others decode, so ticks carry decode rows
+    and prompt rows together."""
     rs = np.random.RandomState(seed)
     prefix = [int(t) for t in rs.randint(0, vocab, 16)]
     reqs = []
     for i in range(n):
         own = [int(t) for t in rs.randint(0, vocab, 3 + (5 * i) % 14)]
+        greedy = i % 2 if rows == "mixed" else rows == "greedy"
         reqs.append(dict(
             tokens=(prefix if i % 3 else []) + [i] + own,
             max_tokens=1 + (3 * i) % 6,
-            temperature=0.0 if i % 2 else 0.8, top_k=4 * (i % 3),
+            temperature=0.0 if greedy else 0.8, top_k=4 * (i % 3),
             seed=900 + i))
     return reqs
 
 
 def _watch_ticks(eng):
-    """Record, a tick, how many rows decode and how many are in their
-    prompt when it starts, and what it retires and finishes."""
-    ticks, tick = [], eng._paged_tick
-    decode, chunk = eng._decode_resolve, eng._chunk_resolve
+    """Record, a tick, how many rows it lays out to decode and how many
+    slots are in their prompt, and what the tick retires and finishes
+    once its tokens are fetched (a tick ahead: at the NEXT tick's
+    delivery, booked to the tick that queued the rows)."""
+    ticks, queued = [], {}
+    tick, rows, chunk = eng._paged_tick, eng._decode_rows, eng._chunk_rows
+    decode, resolve = eng._decode_resolve, eng._chunk_resolve
+    queue, deliver = eng._queue_tick, eng._deliver_tick
 
     def watched(model, st):
-        ticks.append(dict(
-            dec=sum(bool(st.decoding[i]) for i in st.active()),
-            pre=sum(not st.decoding[i] for i in st.active()),
-            retired=0, prompts_done=0))
+        ticks.append(dict(dec=0, pre=0, retired=0, prompts_done=0))
         return tick(model, st)
+
+    def decode_rows(st, dec):
+        ticks[-1]["dec"] = len(dec)
+        return rows(st, dec)
+
+    def chunk_rows(st, pre, span):
+        ticks[-1]["pre"] = len(pre)
+        return chunk(st, pre, span)
 
     def decoded(st, dec, idx, sampled):
         before = len(st.active())
@@ -1273,27 +1286,47 @@ def _watch_ticks(eng):
 
     def chunked(model, st, c, sampled):
         ticks[-1]["prompts_done"] += int(c.do.sum())
-        return chunk(model, st, c, sampled)
+        return resolve(model, st, c, sampled)
 
-    eng._paged_tick, eng._decode_resolve, eng._chunk_resolve = \
-        watched, decoded, chunked
+    def queue_tick(model, st, dec, pre):
+        t = queue(model, st, dec, pre)
+        if t is not None:
+            queued[id(t)] = ticks[-1]
+        return t
+
+    def deliver_tick(model, st, t):
+        mine, before = queued.pop(id(t)), len(st.active())
+        deliver(model, st, t)
+        mine["retired"] += before - len(st.active())
+        if t.chunk is not None:
+            mine["prompts_done"] += int(t.chunk.do.sum())
+
+    eng._paged_tick, eng._decode_rows, eng._chunk_rows = \
+        watched, decode_rows, chunk_rows
+    eng._decode_resolve, eng._chunk_resolve = decoded, chunked
+    eng._queue_tick, eng._deliver_tick = queue_tick, deliver_tick
     return ticks
 
 
+@pytest.mark.parametrize("rows", ["mixed", "greedy", "sampled"])
 @pytest.mark.parametrize("arch", GROUP_ARCHS)
 def test_one_pass_tick_serves_the_two_program_ticks_tokens(
-        arch, monkeypatch):
-    """A seeded mix over a shared prefix through a store that takes the
-    one-pass tick and through the same store built from the model
-    without its step over row groups: the same tokens, request by
-    request; among the one-pass ticks one in which a row retires while
-    a prompt finishes; and ``tick_one_pass``, ``tick_programs`` and
-    ``decode_steps`` read what the ticks did: one program where a tick
-    had prompt rows, two a tick with both kinds of row on the other
-    store."""
+        arch, rows, monkeypatch):
+    """A seeded mix over a shared prefix (greedy rows, seeded draws, or
+    both in one batch) through a store that takes the one-pass tick a
+    tick AHEAD of its fetches, the pending tokens handed on in the
+    device, and through its un-pipelined twin, the same store built
+    from the model without its step over row groups: the same tokens,
+    request by request (a latent pool, two token leaves, a state leaf
+    beside the pool, two classes of block with a window); among the
+    one-pass ticks one in which a row retires while a prompt finishes;
+    and ``tick_one_pass``, ``tick_programs``, ``tick_ahead`` and
+    ``decode_steps`` read what the ticks did: one program a tick, two a
+    tick with both kinds of row on the other store."""
     reg = _burst_registry(arch, pool_blocks=0)
     assert reg.gen_store("m").one_pass
-    reqs = _mixed_requests(21, reg.gen_store("m").spec["vocab_size"])
+    reqs = _mixed_requests(21, reg.gen_store("m").spec["vocab_size"],
+                           rows=rows)
     runs = {}
     for path in ("one_pass", "two_programs"):
         if path == "two_programs":
@@ -1321,8 +1354,12 @@ def test_one_pass_tick_serves_the_two_program_ticks_tokens(
             assert stats["tick_one_pass"] == stats["prefills"] \
                 == sum(1 for t in busy if t["pre"])
             assert stats["tick_programs"] == len(busy)
+            # every tick but the first of a run of them was queued on
+            # the one before, unfetched; no request ended by eos_id
+            assert 0 < stats["tick_ahead"] < len(busy)
+            assert stats["decode_rows_wasted"] == 0
         else:
-            assert stats["tick_one_pass"] == 0
+            assert stats["tick_one_pass"] == stats["tick_ahead"] == 0
             assert stats["tick_programs"] == len(busy) + len(both)
     assert runs["one_pass"] == runs["two_programs"]
 
@@ -1431,3 +1468,212 @@ def test_a_failed_one_pass_dispatch_fails_both_groups():
     assert len(lost) == 1 and lost[0]["dec"] == 1 and lost[0]["pre"] > 1
     assert done == [None, 4, 4, 4]
     assert stats["errors"] == 2 and stats["finished"] == 3
+
+
+# ---------------------------------------------------------------------------
+# a tick ahead: the next tick is queued before this one's tokens are fetched
+# ---------------------------------------------------------------------------
+def _spy_order(eng, store):
+    """Log every step program the store launches and every fetch."""
+    log = []
+
+    def spied(name, fn):
+        def call(*a, **kw):
+            log.append(name)
+            return fn(*a, **kw)
+        return call
+
+    for name in ("run_paged_step_sample", "run_paged_tick_sample",
+                 "run_paged_chunk_sample"):
+        setattr(store, name, spied("launch", getattr(store, name)))
+    eng._fetch_decode = spied("fetch", eng._fetch_decode)
+    return log
+
+
+def test_a_one_pass_store_queues_the_next_tick_before_this_ones_fetch(
+        monkeypatch):
+    """One request alone, three chunks of prompt and six tokens out, on
+    a one-pass store: the launch of tick t + 1 precedes the fetch of
+    tick t from the first tick to the last, prompt ticks and decode
+    ticks alike, one launch and one fetch a tick; the seventh token is
+    not laid out (``max_tokens`` is known at queue time), so the last
+    fetch finds nothing queued behind it.  ``tick_ahead`` counts the
+    ticks queued on an unfetched one.  The same store without its
+    model's step over row groups fetches each tick before it launches
+    the next, as it did."""
+    rs = np.random.RandomState(8)
+    prompt = [int(t) for t in rs.randint(0, 96, 11)]
+    chunks, out = -(-len(prompt) // BURST_CHUNK), 6
+    runs = {}
+    for path in ("ahead", "twin"):
+        if path == "twin":
+            _without_groups(monkeypatch)
+        reg = _burst_registry.__wrapped__("lfm2_moe")
+        store = reg.gen_store("m")
+        assert store.one_pass == (path == "ahead")
+        eng = GenerationEngine(reg)
+        log = _spy_order(eng, store)
+        try:
+            runs[path] = eng.submit(
+                "m", prompt, max_tokens=out, temperature=0.7, top_k=5,
+                seed=3).result(300).tokens
+            stats = eng.stats()
+        finally:
+            eng.close()
+        ticks = chunks + out - 1
+        assert stats["tick_programs"] == ticks
+        if path == "ahead":
+            assert log == ["launch"] + ["launch", "fetch"] * (ticks - 1) \
+                + ["fetch"]
+            assert stats["tick_ahead"] == ticks - 1
+        else:
+            assert log == ["launch", "fetch"] * ticks
+            assert stats["tick_ahead"] == 0
+    assert runs["ahead"] == runs["twin"] and len(runs["ahead"]) == out
+
+
+def test_a_decode_row_reads_the_devices_token_or_the_hosts():
+    """A one-pass store's decode step on two live rows and a dead one:
+    with the pending tokens on the device (``host`` False, junk in
+    ``tokens``) it samples what it samples from the same tokens sent by
+    the host (``host`` True, junk in ``pending``), bit for bit in the
+    pool too; a row that ``do``es leaves its token in its slot's place,
+    the others' places are untouched."""
+    store = _burst_registry("lfm2_moe").gen_store("m")
+    assert store.one_pass
+    n, width = 8, store.table_width()
+    tables = np.zeros((n, width), np.int32)
+    tables[0, 0], tables[2, 0] = 1, 2
+    feed = np.array([5, 0, 9, 0, 0, 0, 0, 0], np.int32)
+    junk = np.full(n, 77, np.int32)
+    do = np.zeros(n, bool)
+    do[[0, 2]] = True
+    keys = np.tile(np.array([[0, 3]], np.uint32), (n, 1))
+
+    def step(tokens, pending, host):
+        out = store.run_paged_step_sample(
+            *store.new_pool(), tables, tokens[:, None],
+            np.zeros(n, np.int32), np.ones(n, np.int32), keys,
+            np.full(n, 0.8, np.float32), np.zeros(n, np.int32), do,
+            pending, host)
+        toks, *pools = out[:1 + store.pool_leaves]
+        return [np.asarray(a) for a in (toks[:n], *pools, *out[-2:])]
+
+    on_device = step(junk, feed, np.zeros(n, bool))
+    from_host = step(feed, junk, np.ones(n, bool))
+    for got, want in zip(on_device[:-1], from_host[:-1]):
+        assert np.array_equal(got, want)
+    toks, pending = on_device[0], on_device[-1]
+    assert np.array_equal(pending[do], toks[do])
+    assert np.array_equal(pending[~do], feed[~do])
+    assert np.array_equal(from_host[-1][~do], junk[~do])
+
+
+@pytest.mark.parametrize("arch", ["cohere2_moe", "lfm2_moe"])
+def test_eos_ends_a_request_whose_next_row_is_already_queued(arch):
+    """A request hits its ``eos_id`` mid-stream on a store that runs a
+    tick ahead: it ends AT that token, the row queued for it meanwhile
+    delivers nothing (``decode_rows_wasted`` 1), its blocks go back,
+    and the request admitted into the freed slot while that row is
+    still in flight (one slot: ``max_active`` 1) samples the tokens it
+    samples alone: its chain starts from its own seed, not from what
+    the wasted row left in the slot, and neither do its window's blocks
+    (two classes of block) nor its state rows (a state leaf beside the
+    pool), which the wasted row wrote behind the request's end."""
+    reg = _burst_registry(arch)
+    rs = np.random.RandomState(15)
+    a = dict(tokens=[int(t) for t in rs.randint(0, 96, 13)], max_tokens=12,
+             temperature=0.9, top_k=0, seed=71)
+    b = dict(tokens=[int(t) for t in rs.randint(0, 96, 9)], max_tokens=5,
+             temperature=0.9, top_k=7, seed=72)
+    (whole,), (b_alone,) = _generate(reg, [a]), _generate(reg, [b])
+    # the first token of the stream's middle that did not occur before
+    k = next(k for k in range(3, 10) if whole[k] not in whole[:k])
+    eng = GenerationEngine(reg, max_active=1)
+    try:
+        fa = eng.submit("m", eos_id=whole[k], **a)
+        fb = eng.submit("m", **b)
+        got = fa.result(300)
+        assert got.tokens == whole[:k + 1] and got.finish_reason == "eos"
+        assert fb.result(300).tokens == b_alone
+        stats = eng.stats()
+        st = eng._states["m"]
+        assert st.flight is None
+        _assert_only_pins_left(st)
+    finally:
+        eng.close()
+    assert stats["decode_rows_wasted"] == 1
+    assert stats["generated_tokens"] == k + len(b_alone) - 1
+    assert stats["finished"] == 2 and stats["errors"] == 0
+    assert [seq for _m, seq in eng._admit_log] == [0, 1]
+
+
+def test_a_fetch_that_raises_fails_both_ticks_in_flight():
+    """Two ticks are in flight when a fetch raises.  One request
+    decodes; a writer W and two siblings over its prefix, and four
+    prompts of their own, are in their prompt: four rows a chunk, so
+    the fourth of those waits its turn.  The fetch fails once the
+    siblings have adopted the block W registered when ITS tick was
+    queued: the rows of both ticks fail, the siblings fail with them
+    (what they adopted was never seen computed), nothing those ticks
+    registered stays in the prefix cache, and the one slot that was in
+    neither tick and adopted nothing serves on: its stream is what it
+    is alone, and so is a newcomer's over W's prefix."""
+    reg = _burst_registry("deepseek_v3", pool_blocks=0)
+    store = reg.gen_store("m")
+    shared = _burst_requests(14, 96, n=3)
+    rs = np.random.RandomState(16)
+    own = [dict(tokens=[40 + i] + [int(t) for t in rs.randint(0, 96, 19)],
+                max_tokens=3) for i in range(4)]
+    want_last, want_new = _generate(reg, [own[-1]])[0], \
+        _generate(reg, [shared[1]])[0]
+    eng = GenerationEngine(reg)
+    fetch, lost = eng._fetch_decode, []
+
+    def flaky(arr):
+        st = eng._states["m"]
+        if not lost and eng.stats()["prefix_late_blocks"]:
+            lost.append((st.flight is not None, len(st.prefix)))
+            raise RuntimeError("lost the device")
+        return fetch(arr)
+
+    eng._fetch_decode = flaky
+    try:
+        first = eng.submit("m", [95, 3, 7], max_tokens=40)
+        while not eng.stats()["decode_steps"]:
+            pass
+        futs = _submit_at_once(eng, shared + own)
+        for f in [first] + futs[:-1]:
+            with pytest.raises(MXNetError, match="tick dispatch failed"):
+                f.result(300)
+        assert futs[-1].result(300).tokens == want_last
+        st = eng._states["m"]
+        # what is registered is what fetched ticks filled: the decoding
+        # request's prompt (long before) and the survivor's, 2 whole
+        # blocks and a tail; nothing of W's or the other prompts'
+        last = own[-1]["tokens"]
+        assert {key[1] for key in st.prefix._entries} == {
+            (95, 3, 7), tuple(last[:8]), tuple(last[8:16]),
+            tuple(last[16:])}
+        assert eng.submit("m", **shared[1]).result(300).tokens == want_new
+        stats = eng.stats()
+        _assert_only_pins_left(st)
+    finally:
+        eng.close()
+    # a tick was queued behind the one whose fetch raised, and the
+    # prefix cache held W's block by then
+    assert lost == [(True, lost[0][1])] and lost[0][1] > 0
+    assert stats["errors"] == 7 and stats["finished"] == 2
+
+
+def test_a_draining_close_delivers_the_tick_in_flight():
+    """``close(drain=True)`` right behind the submits, on a store that
+    runs a tick ahead: every token of every request is delivered, the
+    last tick's too."""
+    reg = _burst_registry("lfm2_moe")
+    reqs = _mixed_requests(25, 96, n=6)
+    want = [_generate(reg, [kw])[0] for kw in reqs]
+    eng = GenerationEngine(reg)
+    futs = _submit_at_once(eng, reqs)
+    eng.close()
+    assert [f.result(0).tokens for f in futs] == want

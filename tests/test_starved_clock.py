@@ -317,6 +317,63 @@ def test_a_one_pass_tick_is_one_launch_and_one_fetch():
     assert 0 < got["device_launch"]["spans"] <= clock.queued
 
 
+def test_a_tick_ahead_leaves_the_device_nothing_to_wait_for(monkeypatch):
+    """One request alone on a one-pass store whose fetch is SLOW (the
+    program's time, on the host's clock): once the loop runs a tick
+    ahead every fetch is of the OLDER of two dispatches, so the clock
+    opens no interval between the first launch and the last fetch: two
+    ``device_starved`` spans (before the first launch, after the last
+    fetch) and one launch into an idle device, however many ticks.  Its
+    twin, the same store without its model's step over row groups,
+    starves once a tick.  Either way the six readers' parts sum to the
+    host's account of the gap."""
+    from test_paged_decode import _burst_registry, _without_groups
+    rs = np.random.RandomState(7)
+    prompt = [int(t) for t in rs.randint(0, 96, 11)]
+    readers = {name: harness.load_module(
+        BENCH, "layer_metrics/%s.py" % name) for name in READERS}
+    for path in ("ahead", "twin"):
+        if path == "twin":
+            _without_groups(monkeypatch)
+        reg = _burst_registry.__wrapped__("deepseek_v3")
+        opened = profiler.phase_totals()
+        eng = GenerationEngine(reg)
+        fetch = eng._fetch_decode
+
+        def slow(arr, fetch=fetch):
+            time.sleep(0.005)
+            return fetch(arr)
+
+        eng._fetch_decode = slow
+        try:
+            eng.submit("m", prompt, max_tokens=12).result(300)
+            stats = eng.stats()
+        finally:
+            eng.close()
+        got = profiler.phase_totals(since=opened)
+        clock = eng._starved
+        ticks = stats["tick_programs"]
+        # (the one fork: the first write behind a registered tail)
+        assert ticks == 3 + 11 and stats["cow_forks"] == 1
+        assert clock.queued == clock.through == ticks + 1
+        starved, launch = got["device_starved"], got["device_launch"]
+        if path == "ahead":
+            assert stats["tick_ahead"] == ticks - 1
+            assert (starved["spans"], launch["spans"]) == (2, 1)
+        else:
+            assert (starved["spans"], launch["spans"]) == (ticks + 1, ticks)
+            assert got["serve_resolve"]["counts"]["starved_ns"] > 0
+        run = {"cell": harness.Cell(CELLS[0], rehearse=True),
+               "trace": {"window_s": 1.0, "devices": []},
+               "host": {"window_s": 1.0, "traced_phases": got}}
+        read = {name: r.read(run) for name, r in readers.items()}
+        account_ms = 1e-6 * (starved["ns"] + launch["ns"]) \
+            / got["serve_tick"]["spans"]
+        parts = sum(read[n] for n in READERS if n.endswith("_ms"))
+        assert parts + read["engine.starved_unspanned_pct"] / 100 \
+            * account_ms == pytest.approx(account_ms, rel=1e-9)
+
+
 # ---------------------------------------------------------------------------
 # (c) two engines, two clocks
 # ---------------------------------------------------------------------------
