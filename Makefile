@@ -62,10 +62,10 @@ dist-smoke:
 		-k "seeded or wire_bytes"
 
 # elastic-async PS gate (docs/architecture/elastic_ps.md): the
-# straggler scenario (dist_async s=4 >= 2x dist_sync under one
-# injected straggler + the staleness-bound property + s=0 sync
-# parity), elastic membership (heartbeat death epochs, worker join at
-# the frontier) and live bucket rebalancing under traffic (exactly-
+# straggler scenario (dist_async s=4 runs ahead of one injected
+# straggler to the bound, dist_sync waits for it every round, by
+# counts + the staleness-bound property + s=0 sync parity), elastic
+# membership (heartbeat death epochs, worker join at the frontier) and live bucket rebalancing under traffic (exactly-
 # once across the migration, capacity add/remove).  MXNET_LOCK_CHECK=1
 # arms the lock-order race detector over the new staleness/membership/
 # migration lock paths; hard timeout like dist-smoke
@@ -109,9 +109,11 @@ frontdoor-smoke:
 decode-smoke:
 	timeout -k 10 1200 env JAX_PLATFORMS=cpu \
 		$(PY) -m pytest tests/test_decode_engine.py \
-		tests/test_paged_decode.py \
+		tests/test_paged_decode.py tests/test_paged_kernels.py \
+		tests/test_paged_pool.py tests/test_paged_one_pass.py \
 		tests/test_quant_serving.py \
-		tests/test_spec_decode.py -q -m quick
+		tests/test_spec_decode.py tests/test_spec_decode_policy.py \
+		-q -m quick
 
 # one-SPMD-step-program gate under 8 fake host devices: numerical
 # equivalence (dp8 vs single device, dp2xmp2 vs dp4, closed-form SGD),
@@ -124,8 +126,9 @@ spmd-smoke:
 
 # collectives-kvstore gate under 8 fake host devices: dist_mesh
 # push/pull closed forms, the SAME-Module.fit-script PS/mesh parity,
-# bucket-reduce bit-exactness vs the fused step, live overlap >= 1.3x
-# barrier under injected collective latency, the dist_mesh program-
+# bucket-reduce bit-exactness vs the fused step, every bucket's reduce
+# in flight at once when overlapped and one at a time when barriered,
+# under injected collective latency, the dist_mesh program-
 # cache key, launch.py --mesh end-to-end (multi-process leg skips on
 # CPU jaxlib)
 mesh-smoke:

@@ -57,15 +57,43 @@ def _seed_rngs():
     yield
 
 
+@pytest.fixture
+def throttle_ticks():
+    """``throttle_ticks(engine, seconds)`` slows a GenerationEngine so a
+    generation provably outlives what the test does to it: it wraps
+    ``_paged_tick``, what the serve loop calls every tick on the plane
+    every default caller takes, with a sleep.  Returns the list of the
+    models it slowed, one entry a tick: the caller asserts it is not
+    empty, so a rename of the tick cannot make the throttle dead
+    again (as ``_decode_and_sample``, which only the contiguous
+    ``_decode_tick`` calls, was for three tests until PR 48)."""
+    def throttle(engine, seconds):
+        tick = engine._paged_tick     # AttributeError if it is renamed
+        entered = []
+
+        def slow_tick(model, st):
+            entered.append(model)
+            time.sleep(seconds)
+            return tick(model, st)
+
+        engine._paged_tick = slow_tick
+        return entered
+    return throttle
+
+
 # ---------------------------------------------------------------------------
 # Test tiers (reference: Jenkinsfile stages split quick sanity from the
 # full matrix).  Every test gets exactly one tier marker:
-#   quick       -- every subsystem, < 5 min single-core (inner loop / CI
-#                  per-change)
+#   quick       -- every subsystem: every file that is in no other tier
+#                  (1,156 of tier-1's 1,230 tests at PR 48, most of its
+#                  seconds: the CI's per-change stages run it by file)
 #   convergence -- example workloads + training-to-accuracy tiers
 #   build       -- compiles the native C++ runtime / C ABI
 #   dist        -- multi-process parameter-server protocol
 # Selection: pytest -m quick | -m "not quick" | -m "convergence or dist"
+# (ci.yaml and the Makefile select on these).  Tier-1 itself is
+# `-m 'not slow'`, all four tiers: pytest.ini says what may carry `slow`.
+# A file that is split keeps its tier: a tier goes by file NAME below.
 # ---------------------------------------------------------------------------
 _TIER_BY_FILE = {
     "test_train_tier.py": "convergence",
@@ -94,7 +122,7 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         fname = os.path.basename(str(item.fspath))
         base = item.name.split("[")[0]
-        if fname == "test_examples.py":
+        if fname.startswith("test_examples"):   # seven files by family
             tier = "quick" if base in _QUICK_EXAMPLES else "convergence"
         elif base in _CONVERGENCE_TESTS:
             tier = "convergence"

@@ -269,7 +269,7 @@ def test_close_without_drain_resolves_inflight_with_replica_index():
 # ---------------------------------------------------------------------------
 # satellite: hot swap races /metrics scrape and in-flight generation
 # ---------------------------------------------------------------------------
-def test_swap_races_metrics_scrape_and_inflight_generation():
+def test_swap_races_metrics_scrape_and_inflight_generation(throttle_ticks):
     """swap_params under a concurrent Prometheus scrape loop AND an
     in-flight generation on the same replica: the rolling swap's drain
     window expires (the generation outlives drain_timeout), the store
@@ -309,19 +309,13 @@ def test_swap_races_metrics_scrape_and_inflight_generation():
     try:
         # slow the decode steps so the generation provably spans the
         # swaps (same throttle as the frontdoor replica-death test)
-        gen_eng = rset.replicas()[0].gen_engine
-        orig_decode = gen_eng._decode_and_sample
-
-        def slow_decode(st, toks, lens):
-            time.sleep(0.01)
-            return orig_decode(st, toks, lens)
-
-        gen_eng._decode_and_sample = slow_decode
+        slowed = throttle_ticks(rset.replicas()[0].gen_engine, 0.01)
         gen_fut = rset.submit_gen("lm", [1, 2, 3], max_tokens=48)
         for _ in range(3):   # three rolls while the generation runs
             rset.swap_params("m", args2, drain_timeout=0.05)
         res = gen_fut.result(60)
         assert len(res.tokens) > 0
+        assert slowed, "the throttle was never entered"
         x = _x()
         out = np.asarray(rset.submit("m", data=x).result(30)[0])
         assert np.array_equal(out, _ref_forward(args2, x))

@@ -1,9 +1,12 @@
 """Decode-plane tests: offset flash kernel parity, decode-vs-one-shot
 logits parity (Pallas routed AND escape hatch), cache-pad -1e30 mask
 pins, the generative program store's bucket/warmup machinery, and the
-continuous-batching GenerationEngine (greedy == reference, seeded
-loadgen FIFO admission, close-mid-generation drain, KV growth, seeded
-sampling) (docs/architecture/decode_engine.md)."""
+continuous-batching GenerationEngine on the plane every default caller
+takes, the paged tick (greedy == the one-shot forward, seeded loadgen
+FIFO admission, close-mid-generation drain, growth across KV blocks,
+seeded sampling) (docs/architecture/decode_engine.md).  The contiguous
+plane is the parity oracle of ``tests/test_paged_decode.py`` and is
+tested nowhere else."""
 import json
 import time
 
@@ -23,19 +26,21 @@ SPEC = lm_spec(num_layers=2, num_hidden=32, num_heads=4, vocab_size=50)
 PARAMS = random_params(SPEC, seed=3)
 BATCH_BUCKETS = (1, 2, 4)
 PROMPT_BUCKETS = (4, 8)
-KV_BLOCK, KV_MAX = 8, 40
+KV_BLOCK, KV_MAX, PREFILL_CHUNK = 8, 40, 8
 
 
 @pytest.fixture(scope="module")
 def registry():
-    """One warmed generative registry for every engine test (warmup
-    compiles the full program set once; ~10s on CPU)."""
+    """One warmed generative registry for every engine test, on the
+    default (paged) plane: warmup compiles the decode step and the
+    prompt chunk of each batch bucket once."""
     reg = ModelRegistry()
     reg.add_generative_model("m", PARAMS, SPEC,
                              batch_buckets=BATCH_BUCKETS,
                              prompt_buckets=PROMPT_BUCKETS,
                              kv_block=KV_BLOCK, kv_max=KV_MAX,
-                             warmup_kv_depth=KV_MAX, paged=False)
+                             warmup_kv_depth=KV_MAX,
+                             prefill_chunk=PREFILL_CHUNK)
     return reg
 
 
@@ -160,21 +165,6 @@ def test_cache_pad_positions_never_leak():
     assert np.array_equal(np.asarray(clean), np.asarray(dirty))
 
 
-def test_prefill_pad_rows_inert(registry):
-    """Bucket padding: a 3-prompt batch padded to bucket 4 gives each
-    real row the same first-token logits as serving it alone."""
-    store = registry.gen_store("m")
-    rs = np.random.RandomState(5)
-    prompts = [list(rs.randint(0, 50, n)) for n in (3, 4, 2)]
-    toks, lens = store.pad_prompts(prompts)
-    assert toks.shape == (4, 4) and list(lens[:3]) == [3, 4, 2]
-    batch_first = np.asarray(store.run_prefill(toks, lens)[0])
-    for i, p in enumerate(prompts):
-        t1, l1 = store.pad_prompts([p])
-        solo = np.asarray(store.run_prefill(t1, l1)[0])
-        assert np.allclose(batch_first[i], solo[0], atol=1e-6)
-
-
 # ---------------------------------------------------------------------------
 # generative program store
 # ---------------------------------------------------------------------------
@@ -190,23 +180,41 @@ def test_store_bucket_geometry(registry):
     with pytest.raises(MXNetError):
         store.validate_request(8, KV_MAX)  # 8 + KV_MAX > KV_MAX
     store.validate_request(8, KV_MAX - 8)
+    # the paged plane chunks prompts: one past every prompt bucket is
+    # bounded by kv_max and the pool alone
+    store.validate_request(9, KV_MAX - 9)
+    assert store.class_width() == store.table_width() == KV_MAX // KV_BLOCK
 
 
 def test_store_warmup_covers_the_served_programs(registry):
     """Every program the engine dispatches in these tests was compiled
-    at warmup — steady-state serving never compiles (AOT promise).
-    The decode kind tracks the store's sample mode: in-graph sampling
-    (the default) serves ``decode_sample`` programs."""
+    at warmup — steady-state serving never compiles (AOT promise): the
+    decode step and the prompt chunk of every batch bucket, and no
+    program of the contiguous plane.  The step's kind tracks the
+    store's sample mode: in-graph sampling (the default) serves
+    ``paged_step_sample`` programs."""
     store = registry.gen_store("m")
     st = store.stats()
-    assert st["generative"] is True
-    dkind = "decode_sample" if st["sample_mode"] == "graph" else "decode"
+    assert st["generative"] is True and st["paged"] is True
+    dkind = ("paged_step_sample" if st["sample_mode"] == "graph"
+             else "paged_step")
     kinds = {(k, b, c) for k, b, c in st["programs_resident"]}
     for bb in BATCH_BUCKETS:
-        for pb in PROMPT_BUCKETS:
-            assert ("prefill", bb, pb) in kinds
-        for cb in range(KV_BLOCK, store.kv_bucket(KV_MAX) + 1, KV_BLOCK):
-            assert (dkind, bb, cb) in kinds
+        assert (dkind, bb, 1) in kinds
+        assert store.chunk_program(bb) in kinds
+    compiles = st["compiles"]
+    eng = GenerationEngine(registry)
+    try:
+        futs = [eng.submit("m", [1 + i, 2, 3], max_tokens=4)
+                for i in range(BATCH_BUCKETS[-1])]
+        for f in futs:
+            f.result(60)
+    finally:
+        eng.close()
+    st = store.stats()
+    assert st["compiles"] == compiles
+    assert not {k for k, _, _ in st["programs_resident"]} \
+        & {"prefill", "decode", "decode_sample"}
 
 
 def test_store_missing_params_rejected():
@@ -233,68 +241,64 @@ def test_registry_gen_namespace(registry):
 # ---------------------------------------------------------------------------
 # generation engine
 # ---------------------------------------------------------------------------
-def _ref_generate(store, prompt, max_tokens, cache_len=KV_MAX):
-    """Host-side greedy reference loop over the same programs."""
-    toks, lens = store.pad_prompts([prompt])
-    first, ck, cv = store.run_prefill(toks, lens)
-    import jax.numpy as jnp
-    # re-house the prefill cache in a full-depth cache so growth never
-    # changes the reference's numbers
-    pad = cache_len - int(np.asarray(ck).shape[3])
-    ck = jnp.pad(ck, ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0)))
-    cv = jnp.pad(cv, ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0)))
-    out = [int(np.argmax(np.asarray(first)[0]))]
-    lens = np.array([len(prompt)], np.int32)
-    while len(out) < max_tokens:
-        lg, ck, cv = store.run_decode(
-            ck, cv, np.array([out[-1]], np.int32), lens)
-        lens = lens + 1
-        out.append(int(np.argmax(np.asarray(lg)[0])))
-    return out
+def _greedy_continuations(prompt, tokens):
+    """What greedy decoding emits after each prefix of ``prompt +
+    tokens``, read off ONE dense one-shot forward of the sequence
+    padded to KV_MAX (the model is causal: a position's logits do not
+    see what follows it).  ``tokens == _greedy_continuations(prompt,
+    tokens)`` holds exactly for the greedy stream, by induction, and
+    asks nothing of either serving plane."""
+    seq = np.zeros((1, KV_MAX), np.int32)
+    seq[0, :len(prompt) + len(tokens)] = list(prompt) + list(tokens)
+    probs = _one_shot_logits(seq)[0]
+    first = len(prompt) - 1
+    return [int(t) for t in
+            np.argmax(probs[first:first + len(tokens)], axis=-1)]
 
 
 def test_engine_greedy_matches_reference(registry):
-    store = registry.gen_store("m")
     rs = np.random.RandomState(0)
     prompts = [list(rs.randint(0, 50, rs.randint(2, 7)))
                for _ in range(6)]
-    refs = [_ref_generate(store, p, 10) for p in prompts]
     eng = GenerationEngine(registry)
     try:
         futs = [eng.submit("m", p, max_tokens=10) for p in prompts]
         results = [f.result(120) for f in futs]
     finally:
         eng.close()
-    for r, ref, p in zip(results, refs, prompts):
-        assert r.tokens == ref
+    for r, p in zip(results, prompts):
+        assert r.tokens == _greedy_continuations(p, r.tokens)
+        assert len(r.tokens) == 10
         assert r.finish_reason == "length"
         assert r.prompt_len == len(p)
         assert len(r.token_times) == len(r.tokens)
 
 
 def test_engine_kv_growth_matches_reference(registry):
-    """A generation crossing several kv blocks (cache grows 8->16->24->
-    32 under the engine) matches the fixed-full-depth reference."""
-    store = registry.gen_store("m")
+    """A generation crossing several kv blocks (its table grows to four
+    blocks of the pool under the engine, 4 + 28 tokens of 8 a block)
+    matches the dense full-depth reference."""
     prompt = [7, 3, 19, 4]
-    ref = _ref_generate(store, prompt, 28)
     eng = GenerationEngine(registry)
     try:
         got = eng.submit("m", prompt, max_tokens=28).result(120)
-        grows = eng.stats()["cache_grows"]
+        pool = eng.stats()["models"]["m"]
     finally:
         eng.close()
-    assert got.tokens == ref
-    assert grows >= 1
+    assert got.tokens == _greedy_continuations(prompt, got.tokens)
+    assert len(got.tokens) == 28
+    blocks = -(-(len(prompt) + 28) // KV_BLOCK)
+    assert pool["pool_blocks_hwm"] >= blocks
+    assert pool["pool_blocks_used"] < blocks      # retired: given back
 
 
 def test_engine_eos_stops_early(registry):
-    store = registry.gen_store("m")
     prompt = [1, 2, 3]
-    ref = _ref_generate(store, prompt, 12)
-    k = ref.index(ref[0])   # first occurrence of the eventual eos token
     eng = GenerationEngine(registry)
     try:
+        ref = eng.submit("m", prompt, max_tokens=12).result(60).tokens
+        assert ref == _greedy_continuations(prompt, ref)
+        k = ref.index(ref[0])   # first occurrence of the eventual eos token
         hit = eng.submit("m", prompt, max_tokens=12,
                          eos_id=ref[0]).result(60)
         miss_eos = next(t for t in range(SPEC["vocab_size"])
@@ -393,20 +397,14 @@ def test_close_nodrain_fails_fast(registry):
         eng.submit("m", [1], max_tokens=2)
 
 
-def test_timeout_expires_in_queue(registry):
+def test_timeout_expires_in_queue(registry, throttle_ticks):
     from mxnet_tpu.serving import ServeTimeout
     eng = GenerationEngine(registry, max_active=1)
-    # throttle decode steps so the slot-occupying generation is STILL
+    # throttle the ticks so the slot-occupying generation is STILL
     # active when the queued request's deadline is checked (on a warm
     # process 30 unthrottled steps can finish inside the sleep below,
     # letting the queued request admit instead of timing out)
-    orig_decode = eng._decode_and_sample
-
-    def slow_decode(st, toks, lens):
-        time.sleep(0.01)
-        return orig_decode(st, toks, lens)
-
-    eng._decode_and_sample = slow_decode
+    slowed = throttle_ticks(eng, 0.01)
     try:
         slow = eng.submit("m", [1, 2], max_tokens=30)
         time.sleep(0.05)   # occupy the single slot
@@ -416,6 +414,7 @@ def test_timeout_expires_in_queue(registry):
         slow.result(120)
     finally:
         eng.close()
+    assert slowed, "the throttle was never entered"
 
 
 def test_submit_validation(registry):
@@ -425,8 +424,10 @@ def test_submit_validation(registry):
             eng.submit("m", [], max_tokens=4)          # empty prompt
         with pytest.raises(MXNetError):
             eng.submit("m", [999], max_tokens=4)       # out of vocab
-        with pytest.raises(MXNetError):
-            eng.submit("m", [1] * 9, max_tokens=4)     # > prompt bucket
+        # past every prompt bucket AND the chunk: the paged plane
+        # chunks it, where the contiguous plane refused it
+        long = eng.submit("m", [1] * 9, max_tokens=4).result(60)
+        assert long.prompt_len == 9 and len(long.tokens) == 4
         with pytest.raises(MXNetError):
             eng.submit("m", [1, 2], max_tokens=KV_MAX)  # cache overflow
         with pytest.raises(MXNetError):

@@ -27,7 +27,7 @@ per-change stage).
 """
 import os
 import sys
-import time
+import threading
 
 import numpy as np
 import pytest
@@ -262,11 +262,13 @@ def test_bucket_reduce_bitexact_vs_fused(monkeypatch):
 
 
 def test_overlap_beats_barrier_live(monkeypatch):
-    """Overlap against barrier, live: with per-collective latency
-    injected at the ``mesh.collective`` seam, launching each bucket's
-    reduce as soon as it is ready must beat the serialized barrier
-    variant >= 1.3x (the barrier pays n_buckets × delay, overlap pays
-    ~max(delay))."""
+    """Overlap against barrier, live, held by counts and not by the
+    clock: with per-collective latency injected at the
+    ``mesh.collective`` seam, the overlapped launcher has EVERY
+    bucket's reduce of a step inside its delay at once (each waits, in
+    the seam, for all the others: it pays ~max(delay)), and the barrier
+    variant never has two (it pays n_buckets x delay); both cross the
+    seam once a bucket a step."""
     monkeypatch.setenv("MXNET_KVSTORE_BUCKET_BYTES", "256")
     sym = _mlp()
     tr = _trainer(sym, make_mesh({"dp": 8}), reduce_mode="bucket")
@@ -276,20 +278,42 @@ def test_overlap_beats_barrier_live(monkeypatch):
             np.zeros((BATCH,), np.float32))
     tr.step(X, y)                     # compile outside the fault window
 
-    def timed(overlap, steps=3):
+    lock = threading.Lock()
+    inside, most, crossed = [0], [0], [0]
+    together = [None]                 # overlap: the step's rendezvous
+    hook = faultinject.hook
+
+    def counted(seam, **meta):
+        with lock:
+            inside[0] += 1
+            crossed[0] += 1
+            most[0] = max(most[0], inside[0])
+        try:
+            if together[0] is not None:
+                together[0].wait(60)  # BrokenBarrierError fails drain()
+            return hook(seam, **meta)
+        finally:
+            with lock:
+                inside[0] -= 1
+
+    monkeypatch.setattr(faultinject, "hook", counted)
+
+    def run(overlap, steps=3):
         tr._launcher = MeshCollectiveLauncher(overlap=overlap)
-        tic = time.perf_counter()
+        together[0] = threading.Barrier(n_buckets) if overlap else None
+        most[0] = crossed[0] = 0
         for _ in range(steps):
             tr.step(X, y)
-        return (time.perf_counter() - tic) / steps
+        return most[0], crossed[0]
 
     faultinject.install({"rules": [
         {"seam": "mesh.collective", "nth": 1, "count": "inf",
-         "action": "delay", "seconds": 0.02}]})
-    t_overlap = timed(True)
-    t_barrier = timed(False)
-    faultinject.install(None)
-    assert t_barrier >= 1.3 * t_overlap, (t_barrier, t_overlap, n_buckets)
+         "action": "delay", "seconds": 0.002}]})
+    try:
+        assert run(True) == (n_buckets, 3 * n_buckets)
+        assert run(False) == (1, 3 * n_buckets)
+    finally:
+        faultinject.install(None)
 
 
 def test_comm_overlap_phase_recorded(monkeypatch):

@@ -211,7 +211,7 @@ def test_server_snapshot_roundtrip_with_updater(tmp_path, monkeypatch):
     monkeypatch.setenv("MXNET_KVSTORE_SNAPSHOT_INTERVAL", "5")
     s = ksd.Server()
     try:
-        s.rank = 0
+        s._reg.rank = 0
         conn = _FakeConn()
         s._serve_one(("init", 3, np.zeros(4, np.float32)), conn)
         s._serve_one(
@@ -225,7 +225,7 @@ def test_server_snapshot_roundtrip_with_updater(tmp_path, monkeypatch):
 
         t = ksd.Server()
         try:
-            t.rank = 0
+            t._reg.rank = 0
             assert t.restore_snapshot()
             np.testing.assert_array_equal(t.store[3], s.store[3])
             assert t.sync_mode == s.sync_mode
@@ -557,7 +557,7 @@ def test_bucketed_compressed_snapshot_restore_roundtrip(monkeypatch,
     from mxnet_tpu import kvstore_codec as codec
     s = ksd.Server()
     try:
-        s.rank = 0
+        s._reg.rank = 0
         conn = _FakeConn()
         s._serve_one(("init", (3, 0), np.zeros(64, np.float32)), conn)
         s._serve_one(("init", (4, 0), np.zeros(64, np.float32)), conn)
@@ -574,7 +574,7 @@ def test_bucketed_compressed_snapshot_restore_roundtrip(monkeypatch,
         assert s.save_snapshot()
         t = ksd.Server()
         try:
-            t.rank = 0
+            t._reg.rank = 0
             assert t.restore_snapshot()
             for key in ((3, 0), (4, 0)):
                 np.testing.assert_array_equal(t.store[key], s.store[key])

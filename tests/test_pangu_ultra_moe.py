@@ -1,13 +1,9 @@
-"""openPangu-Ultra-MoE on the serving plane, at toy sizes on the CPU:
-the sandwich-normed layers and the prediction module against the plain
-reference through the cache, the self-drafting engine against the same
-store with the module off (block boundaries, a copy-on-write fork, a
-pool at capacity, adoption of a shared prefix), the ACCEPT path with
-weights under which the module is right every time, the shares' parts
-before the post-feed-forward norm, and what the change must leave as it
-was (docs/architecture/decode_engine.md, "A step that yields more than
-one token").
-"""
+"""openPangu-Ultra-MoE's model functions at toy sizes on the CPU: target
+and multi-token-prediction module through the cache against the
+reference's full forward, the seam and what it offers, the verify
+rule's two branches, the shares' parts, and what stays as it was for
+``deepseek_v3`` (its store and engine are
+tests/test_pangu_ultra_moe_store.py's)."""
 import importlib.util
 import os
 
@@ -17,46 +13,11 @@ import pytest
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.models import deepseek_v3 as ds
 from mxnet_tpu.models import pangu_ultra_moe as pm
-from mxnet_tpu.serving import GenerationEngine, ModelRegistry
 from mxnet_tpu.serving.program_store import (GenerativeProgramStore,
                                              spec_verify)
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-SPEC_IN = dict(
-    arch="pangu_ultra_moe", num_hidden_layers=3, first_k_dense_replace=1,
-    hidden_size=64, num_attention_heads=4, q_lora_rank=32,
-    kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
-    v_head_dim=16, intermediate_size=128, moe_intermediate_size=32,
-    n_routed_experts=4, router_width=8, n_shared_experts=1,
-    num_experts_per_tok=2, vocab_size=97, routed_scaling_factor=2.5,
-    rms_norm_eps=1e-5, rope_theta=25600000.0, num_nextn_predict_layers=1,
-    sandwich_norm=True, norm_topk_prob=True)
-SPEC = pm.serving_spec(dict(SPEC_IN, draft_layers=1))
-CFG = {"spec": SPEC_IN, "deploy": {"self_draft": 1}}
-PARAMS = pm.random_params(SPEC, seed=3)
-BS, CHUNK, KV_MAX = 8, 8, 96
-LOGIT_TOL = 2e-4
-STORE_KW = dict(batch_buckets=(4,), prompt_buckets=(64,), kv_block=BS,
-                kv_max=KV_MAX, paged=True, prefill_chunk=CHUNK,
-                sample="graph")
-
-
-@pytest.fixture(scope="module")
-def ref():
-    """The benchmark's plain reference (imports nothing of the
-    program), loaded by path."""
-    spec = importlib.util.spec_from_file_location(
-        "openpangu_reference", os.path.join(
-            ROOT, "benchmark", "reference", "openpangu-ultra-moe.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _jnp(params):
-    import jax.numpy as jnp
-    return {k: jnp.asarray(v) for k, v in params.items()}
+from _pangu_ultra_moe_common import (BS, CFG, CHUNK, LOGIT_TOL, PARAMS,
+                                     ROOT, SPEC, SPEC_IN, _jnp, ref)
 
 
 # ---------------------------------------------------------------------------
@@ -157,7 +118,7 @@ def test_spec_seam_and_offers():
     assert pm.init_pool(SPEC, 3, BS)[0].shape[0] == 4
     assert ds.softmax_scale(SPEC) == (16 + 8) ** -0.5
     # deepseek_v3's own spec keeps its groups, bias and YaRN
-    from test_deepseek_v3 import SPEC as V3
+    from _deepseek_v3_common import SPEC as V3
     assert V3["rope_scaling"] is not None
     assert [n for n in ds.param_shapes(V3) if "router_bias" in n]
     assert not [n for n in ds.param_shapes(V3) if "post_attn" in n]
@@ -169,220 +130,10 @@ def test_other_models_refuse_self_draft(arch):
     """A state that cannot roll back (``lfm2_moe``), and the models
     without a prediction module: asking is refused in the store's
     wording, before a weight is touched."""
-    mod = importlib.import_module("test_" + arch)
+    mod = importlib.import_module("_%s_common" % arch)
     with pytest.raises(MXNetError, match="does not offer a self-drafting"):
         GenerativeProgramStore({}, mod.SPEC_IN, name=arch, self_draft=1,
                                **mod.STORE_KW)
-
-
-def test_self_draft_needs_the_paged_plane_in_graph_mode():
-    with pytest.raises(MXNetError, match="in-graph"):
-        GenerativeProgramStore(dict(PARAMS), SPEC_IN, self_draft=1,
-                               **dict(STORE_KW, sample="host"))
-
-
-def test_store_warms_exactly_the_four_self_draft_programs():
-    st = GenerativeProgramStore(dict(PARAMS), SPEC_IN, self_draft=1,
-                                **STORE_KW)
-    assert sorted(st.warmup()) == sorted(st.step_programs(4)) == [
-        ("paged_draft_chunk", 4, CHUNK), ("paged_draft_step", 4, 2),
-        ("paged_self_chunk", 4, CHUNK), ("paged_self_verify", 4, 2)]
-    assert st.stats()["compiles"] == 4 and st.stats()["self_draft"] == 1
-    off = GenerativeProgramStore(
-        {k: v for k, v in PARAMS.items() if not k.startswith("mtp_")},
-        SPEC_IN, **STORE_KW)
-    # without the module the store is an expert store like another:
-    # the decode step, and the one-pass tick in the chunk program's
-    # place (the self-drafting store keeps its sequence of programs)
-    assert not st.one_pass and off.one_pass
-    assert sorted(off.warmup()) == [("paged_step_sample", 4, 1),
-                                    ("paged_tick_sample", 4, CHUNK)]
-    assert off.new_pool()[0].shape[0] == 3
-
-
-# ---------------------------------------------------------------------------
-# (c): the engine, module on against module off
-# ---------------------------------------------------------------------------
-class _Drafted:
-    """A stream that keeps what the engine says its module proposed."""
-
-    def __init__(self):
-        self.drafts = []
-
-    def push(self, token):
-        pass
-
-    def close(self):
-        pass
-
-    def drafted(self, position, token):
-        self.drafts.append((position, token))
-
-
-def _serve(params, draft, waves, told=None, **kw):
-    """``waves`` of (prompt, max_tokens[, eos]) through an engine; a
-    wave is submitted when the one before has finished.  Returns
-    (results by wave, stats); ``told`` gains, a wave, each request's
-    ``(position, token)`` of every proposal the engine told its
-    stream."""
-    reg = ModelRegistry()
-    reg.add_generative_model("lm", dict(params), SPEC_IN, self_draft=draft,
-                             **dict(STORE_KW, **kw))
-    eng = GenerationEngine(reg)
-    try:
-        out = []
-        for wave in waves:
-            streams = [_Drafted() for _ in wave]
-            futs = [eng.submit("lm", w[0], max_tokens=w[1], stream=s,
-                               eos_id=w[2] if len(w) > 2 else None)
-                    for w, s in zip(wave, streams)]
-            out.append([f.result(timeout=300) for f in futs])
-            if told is not None:
-                told.append([s.drafts for s in streams])
-        stats = eng.stats()
-    finally:
-        eng.close()
-    return out, stats
-
-
-def test_served_tokens_are_the_same_with_the_module_on_and_off():
-    """Greedy tokens module on == module off, over block boundaries
-    (blocks of 8, prompts and outputs of every remainder), a
-    copy-on-write fork (every partial prompt tail is pinned and forked
-    at the first decode write), a pool at capacity (13 usable blocks:
-    admission waits for retirements) and adoption of a shared prefix
-    (the second wave adopts the first's two whole blocks: the module's
-    rows come with them, and the hit's last token reruns)."""
-    rng = np.random.default_rng(0)
-    shared = rng.integers(0, 97, 2 * BS).tolist()
-    first = [(shared + rng.integers(0, 97, n).tolist(), m)
-             for n, m in ((5, 12), (9, 7), (0, 9))] \
-        + [(rng.integers(0, 97, 11).tolist(), 10)]
-    second = [(shared + rng.integers(0, 97, n).tolist(), m)
-              for n, m in ((3, 20), (8, 5), (1, 16))]
-    kw = dict(pool_blocks=14)
-    told_on, told_off = [], []
-    on, s_on = _serve(PARAMS, 1, [first, second], told_on, **kw)
-    off, s_off = _serve(PARAMS, 0, [first, second], told_off, **kw)
-    for wave_on, wave_off, drafts_on, drafts_off in zip(
-            on, off, told_on, told_off):
-        for a, b, drafts, none in zip(wave_on, wave_off, drafts_on,
-                                      drafts_off):
-            assert a.tokens == b.tokens and a.finish_reason == "length"
-            assert not none
-            # a proposal a step that goes on, for the position after
-            # the pending token's
-            at = [p for p, _ in drafts]
-            assert at[0] == a.prompt_len + 1 and at == sorted(set(at)) \
-                and at[-1] < a.prompt_len + len(a.tokens)
-    assert s_on["prefix_hits"] >= 3 and s_on["cow_forks"] >= 5
-    assert s_on["prefix_hit_tokens"] == s_off["prefix_hit_tokens"]
-    assert s_on["spec_steps"] == s_on["decode_steps"] > 0
-    # seeded weights: nearly every proposal is rejected
-    assert s_on["spec_accepted"] < s_on["spec_proposed"] // 4
-    assert s_on["generated_tokens"] == s_off["generated_tokens"] \
-        == sum(len(r.tokens) - 1 for wave in on for r in wave)
-    assert s_off["spec_steps"] == 0 and s_off["draft_rows"] == 0
-    # the module wrote a row a prompt token computed and a row a token
-    # emitted by a step
-    assert s_on["draft_rows"] >= s_on["generated_tokens"] - 7
-    assert s_on["models"]["lm"]["self_draft"] is True
-    assert s_on["models"]["lm"]["spec_k"] == 1
-    assert "draft_pool_bytes" not in s_on["models"]["lm"]
-
-
-def test_the_env_variable_does_not_gate_a_self_draft(monkeypatch):
-    monkeypatch.setenv("MXNET_SERVE_SPEC", "0")
-    rng = np.random.default_rng(1)
-    (res,), stats = _serve(PARAMS, 1,
-                           [[(rng.integers(0, 97, 9).tolist(), 6)]])
-    assert stats["spec_steps"] > 0 and len(res[0].tokens) == 6
-
-
-def test_sampling_rows_go_through_the_rejection_rule():
-    """Temperature > 0 through the self-drafting tick (the module
-    proposes its argmax, a one-hot density: ``spec_verify`` accepts it
-    with probability ``p(d)`` and resamples without it): requests
-    finish at their budgets with tokens of the vocabulary, the same
-    seed gives the same stream, and under the agreeing weights a
-    sampling row both accepts and rejects."""
-    reg = ModelRegistry()
-    reg.add_generative_model("lm", _agreeing_params(), SPEC_IN,
-                             self_draft=1, **STORE_KW)
-    eng = GenerationEngine(reg)
-    try:
-        rng = np.random.default_rng(5)
-        prompt = rng.integers(0, 97, 9).tolist()
-        runs = [eng.submit("lm", prompt, max_tokens=24, temperature=0.9,
-                           seed=seed).result(timeout=300)
-                for seed in (7, 7, 8)]
-        stats = eng.stats()
-    finally:
-        eng.close()
-    assert runs[0].tokens == runs[1].tokens != runs[2].tokens
-    for r in runs:
-        assert len(r.tokens) == 24 and 0 <= min(r.tokens) \
-            and max(r.tokens) < 97
-    assert 0 < stats["spec_accepted"] < stats["spec_proposed"]
-    assert stats["sample_draw_dispatches"] > 0
-
-
-# ---------------------------------------------------------------------------
-# (d): the ACCEPT path
-# ---------------------------------------------------------------------------
-def _agreeing_params():
-    """Weights under which the module is right every time: with every
-    output projection zero a layer adds nothing to the residual, so the
-    target's next token is a function of its last token alone,
-    ``g(t) = argmax Head(norm(Emb(t)))``; the module, reading only the
-    embedding of the token at its row through ``W_eh = [0 | I]`` and
-    the target's final norm, computes ``g`` one token on."""
-    p = {k: np.array(v) for k, v in PARAMS.items()}
-    for name in p:
-        if name.endswith(("o_weight", "down_weight")):
-            p[name][:] = 0
-    D = SPEC["hidden_size"]
-    p["mtp_eh_weight"] = np.concatenate(
-        [np.zeros((D, D), np.float32), np.eye(D, dtype=np.float32)], 1)
-    p["mtp_e_norm_gamma"][:] = 1
-    p["mtp_final_norm_gamma"] = p["final_norm_gamma"].copy()
-    return p
-
-
-def test_accept_path_two_tokens_a_step():
-    """Every proposal accepted: two tokens a step, ``max_tokens`` odd
-    and even (the last step of an even budget verifies nothing: one
-    token left), and a request that ENDS on the first of a pair (its
-    second token is discarded with the slot).  Tokens equal the
-    module-off store's throughout."""
-    params = _agreeing_params()
-    rng = np.random.default_rng(2)
-    prompts = [rng.integers(0, 97, n).tolist() for n in (5, 8, 13, 21)]
-    budgets = [9, 10, 1, 2]
-    wave = list(zip(prompts, budgets))
-    (on,), s_on = _serve(params, 1, [wave])
-    (off,), s_off = _serve(params, 0, [wave])
-    for a, b, m in zip(on, off, budgets):
-        assert a.tokens == b.tokens and len(a.tokens) == m
-    assert s_on["spec_accepted"] == s_on["spec_proposed"] > 0
-    # the first token comes from the prompt's chunk, then pairs: a
-    # tick steps every generating row, the longest budget sets the count
-    assert s_on["decode_steps"] == max(budgets) // 2
-    assert s_off["decode_steps"] == max(budgets) - 1
-
-    # end on the first of a pair: token 1, 3, 5, ... of a stream
-    stream = off[1].tokens
-    k = next((k for k in range(1, len(stream), 2)
-              if stream[k] not in stream[:k]), None)
-    assert k is not None, stream
-    (cut,), s_cut = _serve(params, 1,
-                           [[(prompts[1], budgets[1], stream[k])]])
-    assert cut[0].tokens == stream[:k + 1]
-    assert cut[0].finish_reason == "eos"
-    # the steps emitted k tokens behind the chunk's one; the last
-    # step's proposal had been ACCEPTED and its token went with the slot
-    assert s_cut["generated_tokens"] == k
-    assert s_cut["spec_accepted"] == s_cut["spec_proposed"] == (k + 1) // 2
 
 
 def test_spec_verify_greedy_branch_equals_the_sampled_one():
@@ -454,7 +205,7 @@ def test_deepseek_v3_golden_step_through_the_refactored_layer(monkeypatch):
     gives the parent's logits bit for bit, and with ``hidden`` the same
     logits beside the hidden state."""
     import jax
-    from test_deepseek_v3 import PARAMS as V3_PARAMS, SPEC as V3_SPEC
+    from _deepseek_v3_common import PARAMS as V3_PARAMS, SPEC as V3_SPEC
     monkeypatch.setenv("MXNET_PALLAS", "0")
     gold = np.load(os.path.join(ROOT, "tests",
                                 "golden_deepseek_v3_step.npz"))

@@ -3,7 +3,8 @@ dequant-matmul kernel vs its dense XLA twin, >= 99% greedy top-1
 agreement, ~4x resident weight bytes), the bf16 KV decode plane
 (relaxed-tol parity incl. ragged prefill lengths, halved cache bytes
 per slot) and in-graph sampling (byte-identical token streams vs the
-MXNET_SERVE_SAMPLE=host hatch, the zero-logits-fetch pin)
+``sample="host"`` keyword, the zero-logits-fetch pin), on the
+contiguous plane by the ``paged=False`` keyword where a test holds it
 (docs/architecture/serving.md dtype matrix)."""
 
 import jax

@@ -46,6 +46,30 @@ def _classic_forward(net, args, x):
     return pred.forward(data=x)[0].asnumpy()
 
 
+def _assert_matches_classic(net, args, x, got, bucket):
+    """What "pad/unpad changes nothing" means for ``x``'s rows served out
+    of ``bucket``: BIT-equal to the classic forward of the same padded
+    batch (zero rows, as ``ProgramStore.run`` pads), so padding rows do
+    not leak into live rows.  Against the classic forward of the
+    UNPADDED batch bit equality holds only where the batch is a bucket:
+    XLA's CPU convolution is not bit-stable across batch sizes.  Over 300
+    seeded inputs of 3 rows in a bucket of 4 the outputs differ by 1-8
+    ulp (2 the mode, 5 at this file's seed 5); 5-7 rows in a bucket of 8
+    by none.  A padded batch is held to the 8 that was seen."""
+    n = x.shape[0]
+    assert got.shape[0] == n
+    ref = _classic_forward(net, args, x)
+    if n == bucket:
+        assert np.array_equal(got, ref), "n=%d not bit-equal" % n
+        return
+    padded = np.zeros((bucket,) + x.shape[1:], x.dtype)
+    padded[:n] = x
+    assert np.array_equal(got, _classic_forward(net, args, padded)[:n]), \
+        "n=%d in bucket %d not bit-equal to the padded classic forward" \
+        % (n, bucket)
+    np.testing.assert_array_max_ulp(got, ref, maxulp=8)
+
+
 def _mkstore(net, args, **kw):
     kw.setdefault("buckets", BUCKETS)
     return ProgramStore(net, args, {}, {"data": (1, 3, 8, 8)}, **kw)
@@ -71,8 +95,9 @@ def test_bucket_edges_and_lookup():
 
 
 def test_bucket_pad_unpad_bit_equal_fp32():
-    """Padded bucketed outputs must be BIT-equal to the classic
-    unbatched Predictor for every size across the bucket range."""
+    """Bucketed outputs must be BIT-equal to the classic unbatched
+    Predictor at every bucket-sized batch, and to the classic forward of
+    the same padded batch in between (``_assert_matches_classic``)."""
     net, args = _conv_model()
     store = _mkstore(net, args)
     store.warmup()
@@ -81,10 +106,7 @@ def test_bucket_pad_unpad_bit_equal_fp32():
         x = rs.uniform(-1, 1, (n, 3, 8, 8)).astype("float32")
         outs, bucket, bm = store.run({"data": x})
         assert bucket == bucket_for(n, BUCKETS) and bm == (True,)
-        got = np.asarray(outs[0])
-        assert got.shape[0] == n
-        ref = _classic_forward(net, args, x)
-        assert np.array_equal(got, ref), "n=%d not bit-equal" % n
+        _assert_matches_classic(net, args, x, np.asarray(outs[0]), bucket)
 
 
 def test_store_oversize_and_bad_inputs():
@@ -237,7 +259,7 @@ def test_serving_predictor_matches_classic_bit_equal():
         sp.forward(data=x)
         got = sp.get_output(0)
         assert sp.get_output_shape(0) == got.shape
-        assert np.array_equal(got, _classic_forward(net, args, x))
+        _assert_matches_classic(net, args, x, got, bucket_for(n, BUCKETS))
     st = sp.serving_stats()
     assert st["compiles"] == len(BUCKETS)  # warmup-at-load, then hits
     assert st["hits"] >= 3
@@ -278,8 +300,11 @@ def test_from_checkpoint_serving_kwargs(tmp_path):
         prefix, 1, {"data": (1, 3, 8, 8)}, serving=True, buckets=(1, 4))
     x = np.random.RandomState(7).uniform(
         -1, 1, (3, 3, 8, 8)).astype("float32")
-    assert np.array_equal(pred.forward(data=x)[0].asnumpy(),
-                          _classic_forward(net, args, x))
+    _assert_matches_classic(net, args, x,
+                            pred.forward(data=x)[0].asnumpy(), 4)
+    x = x[:1]                               # a bucket-sized batch
+    _assert_matches_classic(net, args, x,
+                            pred.forward(data=x)[0].asnumpy(), 1)
 
 
 # ---------------------------------------------------------------------------
@@ -605,8 +630,10 @@ def test_to_serving_artifact_roundtrip(tmp_path):
     x = rs.uniform(-1, 1, (3, 3, 8, 8)).astype("float32")
     outs, bucket, _ = store.run({"data": x})
     assert bucket == 4
-    assert np.array_equal(np.asarray(outs[0]),
-                          _classic_forward(net, args, x))
+    _assert_matches_classic(net, args, x, np.asarray(outs[0]), bucket)
+    x = rs.uniform(-1, 1, (4, 3, 8, 8)).astype("float32")
+    outs, bucket, _ = store.run({"data": x})
+    _assert_matches_classic(net, args, x, np.asarray(outs[0]), bucket)
 
 
 def test_to_serving_checkpoint_and_overrides(tmp_path):

@@ -1,71 +1,26 @@
 """Speculative decoding: the in-graph accept/reject rule (greedy =
 longest-matching-prefix, seeded = rejection sampling with the
-corrected-distribution resample — statistically pinned against the
+corrected-distribution resample, statistically pinned against the
 target density), draft/verify program warm sets, engine-level greedy
 byte-identity vs non-speculative decoding on the XLA path AND
 MXNET_PALLAS=2, counters + acceptance evidence (target steps per token
-<= 0.6x with a perfect draft), EOS/budget clamps, MXNET_SERVE_SPEC
-gating, registry validation, and the int8 paged KV plane riding the
-same pool update (docs/architecture/decode_engine.md)."""
+<= 0.6x with a perfect draft), EOS/budget clamps, registry validation,
+and the int8 paged KV plane riding the same pool update (the fallback
+policy and gating are tests/test_spec_decode_policy.py's;
+docs/architecture/decode_engine.md)."""
 import numpy as np
 import pytest
-
 import jax
 import jax.numpy as jnp
 
 from mxnet_tpu.base import MXNetError
-from mxnet_tpu.models.transformer_lm import lm_spec, random_params
 from mxnet_tpu.pallas_ops.flash_attention import pltpu
-from mxnet_tpu.serving import GenerationEngine, ModelRegistry
+from mxnet_tpu.serving import ModelRegistry
 from mxnet_tpu.serving.program_store import (GenerativeProgramStore,
                                              _masked_dist, spec_verify)
 
-SPEC = lm_spec(num_layers=2, num_hidden=32, num_heads=4, vocab_size=50)
-PARAMS = random_params(SPEC, seed=3)
-DSPEC = lm_spec(num_layers=1, num_hidden=16, num_heads=2, vocab_size=50)
-DPARAMS = random_params(DSPEC, seed=7)
-
-KW = dict(batch_buckets=(1, 2, 4), prompt_buckets=(4, 8, 24),
-          kv_block=8, kv_max=64, paged=True, prefill_chunk=8,
-          sample="graph")
-
-REQS = [dict(tokens=[7, 3, 11, 29, 4], max_tokens=12, seed=1),
-        dict(tokens=[7, 3, 11, 29, 4], max_tokens=9, seed=2),
-        dict(tokens=[2, 5], max_tokens=14, seed=3),
-        dict(tokens=[1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11], max_tokens=7,
-             seed=4)]
-
-
-def _run(draft, kv_dtype="float32", temp=0.0, reqs=REQS, spec_k=3,
-         **submit_kw):
-    """One engine lifecycle: register, optionally attach a draft,
-    generate, return (streams, stats)."""
-    reg = ModelRegistry()
-    reg.add_generative_model("m", PARAMS, SPEC, kv_dtype=kv_dtype,
-                             **KW)
-    if draft == "self":
-        reg.add_draft_model("m", PARAMS, SPEC, spec_k=spec_k)
-    elif draft == "rand":
-        reg.add_draft_model("m", DPARAMS, DSPEC, spec_k=spec_k)
-    eng = GenerationEngine(reg)
-    try:
-        futs = [eng.submit("m", temperature=temp, **submit_kw, **kw)
-                for kw in reqs]
-        toks = [f.result(180).tokens for f in futs]
-        stats = eng.stats()
-    finally:
-        eng.close()
-    return toks, stats
-
-
-@pytest.fixture(scope="module")
-def greedy_runs():
-    """The three greedy engine runs every byte-identity/counters test
-    reads: no draft (oracle), a random small draft (acceptance may
-    collapse — graceful degradation), and a self-draft (acceptance
-    100% — the steps-per-token upper bound)."""
-    return {tag: _run(d) for tag, d in
-            (("base", None), ("rand", "rand"), ("self", "self"))}
+from _spec_decode_common import (DPARAMS, DSPEC, KW, PARAMS, REQS, SPEC,
+                                 _run, greedy_runs)
 
 
 # ---------------------------------------------------------------------------
@@ -256,121 +211,6 @@ def test_spec_eos_mid_window():
     assert spec[0][-1] == eos and len(spec[0]) < 12
 
 
-def test_spec_auto_fallback_on_acceptance_collapse(monkeypatch):
-    """MXNET_SERVE_SPEC=auto degrades gracefully: a draft whose
-    proposals never survive verification drives the rolling acceptance
-    EMA under the floor, after which ticks run plain decode (cheap)
-    with occasional speculative probes — token streams stay
-    byte-identical throughout.  =force keeps drafting regardless."""
-    reqs = [dict(tokens=[7, 3, 11, 29, 4], max_tokens=48, seed=1)]
-    base, _ = _run(None, reqs=reqs)
-    spec, st = _run("rand", reqs=reqs)
-    assert spec == base
-    assert st["spec_fallback_steps"] > 0
-    assert st["models"]["m"]["spec_acceptance_ema"] < 0.125
-    monkeypatch.setenv("MXNET_SERVE_SPEC", "force")
-    forced, fst = _run("rand", reqs=reqs)
-    assert forced == base
-    assert fst["spec_fallback_steps"] == 0
-    assert fst["spec_steps"] > st["spec_steps"]
-
-
-def test_spec_probe_rebuilds_lazily_mirrored_draft(monkeypatch):
-    """While fallback is active the draft prefill mirror is skipped
-    (zero draft cost per tick); a request admitted entirely inside the
-    fallback regime gets its draft KV rebuilt from the PROMPT by the
-    probe's chunked catch-up — and the stream stays byte-identical."""
-    from mxnet_tpu.serving import decode_engine as de
-    monkeypatch.setattr(de, "_SPEC_PROBE_EVERY", 4)
-    reg = ModelRegistry()
-    reg.add_generative_model("m", PARAMS, SPEC, **KW)
-    reg.add_draft_model("m", DPARAMS, DSPEC, spec_k=3)
-    eng = GenerationEngine(reg)
-    try:
-        eng.submit("m", [7, 3, 11, 29, 4], max_tokens=24).result(180)
-        st = eng.stats()
-        assert st["models"]["m"]["spec_acceptance_ema"] < 0.125
-        toks = eng.submit("m", [2, 5], max_tokens=20).result(180).tokens
-        st2 = eng.stats()
-    finally:
-        eng.close()
-    base, _ = _run(None, reqs=[dict(tokens=[2, 5], max_tokens=20,
-                                    seed=0)])
-    assert toks == base[0]
-    assert st2["spec_steps"] > st["spec_steps"]   # probes fired
-    assert st2["spec_fallback_steps"] > st["spec_fallback_steps"]
-
-
-@pytest.mark.parametrize("mirror", [True, False],
-                         ids=["mirror-on", "mirror-off"])
-def test_spec_draft_frontier_follows_a_late_adoption(mirror, monkeypatch):
-    """Four requests over one new prefix, submitted at once: the
-    followers adopt its blocks in the tick, after admission.  With the
-    prefill mirror on, the adopted blocks hold the draft's rows too
-    (the writer's chunks were mirrored) and the draft's frontier moves
-    with the target's; with the mirror off (the fallback regime) the
-    draft claims nothing of them, and a probe's catch-up rebuilds from
-    the prompt.  Either way the streams are the undrafted engine's."""
-    from mxnet_tpu.serving import decode_engine as de
-    from test_paged_decode import _submit_at_once
-    monkeypatch.setattr(de, "_SPEC_PROBE_EVERY", 4)
-    rs = np.random.RandomState(5)
-    prefix = [int(t) for t in rs.randint(0, 50, 24)]    # 3 whole blocks
-    reqs = [dict(tokens=prefix + [i, 9 - i], max_tokens=10, seed=i)
-            for i in range(4)]
-    base, _ = _run(None, reqs=reqs)
-    reg = ModelRegistry()
-    reg.add_generative_model("m", PARAMS, SPEC, **KW)
-    if mirror:
-        reg.add_draft_model("m", PARAMS, SPEC, spec_k=3)
-    else:
-        reg.add_draft_model("m", DPARAMS, DSPEC, spec_k=3)
-    eng = GenerationEngine(reg)
-    adopt, seen = eng._adopt_late, []
-
-    def spy(st, i):
-        was, held = int(st.dlen[i]), int(st.reg_n[i]) * KW["kv_block"]
-        got = adopt(st, i)
-        if got[0]:
-            seen.append((st.spec_mirror(), min(was, held),
-                         int(st.dlen[i]), int(st.prog[i])))
-        return got
-
-    eng._adopt_late = spy
-    try:
-        if not mirror:
-            # a draft whose proposals never survive: the EMA collapses
-            # and the mirror goes off before the burst arrives
-            eng.submit("m", [7, 3, 11, 29, 4], max_tokens=24).result(180)
-            assert eng.stats()["models"]["m"]["spec_acceptance_ema"] \
-                < 0.125
-        toks = [f.result(180).tokens for f in _submit_at_once(eng, reqs)]
-        st = eng.stats()
-    finally:
-        eng.close()
-    assert toks == base
-    assert st["prefix_late_tokens"] > 0 and seen
-    for on, kept, dlen, prog in seen:
-        assert on == mirror
-        assert dlen == (prog if mirror else kept)
-    assert st["spec_steps"] > 0
-
-
-def test_spec_env_gating(monkeypatch):
-    """MXNET_SERVE_SPEC=0 disables speculative decoding even with a
-    draft attached — the engine runs plain paged decode, streams
-    unchanged."""
-    monkeypatch.setenv("MXNET_SERVE_SPEC", "0")
-    reqs = [dict(tokens=[7, 3, 11, 29, 4], max_tokens=8, seed=1)]
-    spec, st = _run("self", reqs=reqs)
-    base, _ = _run(None, reqs=reqs)
-    assert spec == base
-    assert st["spec_steps"] == 0 and st["spec_draft_steps"] == 0
-
-
-# ---------------------------------------------------------------------------
-# int8 paged KV riding the same pool update
-# ---------------------------------------------------------------------------
 def test_spec_int8_greedy_and_pool_bytes():
     """Speculative decoding over the int8 paged pool: greedy streams
     byte-identical to the int8 non-speculative engine, and the

@@ -193,7 +193,7 @@ def test_paged_engine_accounts_for_its_starved_time():
     after the other, so every count is the same in every run.  The
     counts the four rooflines read are the parent's (commit 77cc4be,
     the same three requests: its ``phase_totals``)."""
-    from test_cohere2_moe import PARAMS, SPEC_IN, STORE_KW
+    from _cohere2_moe_common import PARAMS, SPEC_IN, STORE_KW
     rs = np.random.RandomState(2)
     P = [int(t) for t in rs.randint(0, 96, 43)]
     Q = P[:40] + [int(t) for t in rs.randint(0, 96, 9)]
@@ -271,8 +271,8 @@ def test_a_one_pass_tick_is_one_launch_and_one_fetch():
     ``serve_decode`` AND a ``serve_prefill`` span around that one call,
     each with its own group's rows; and the leaves' starved time still
     nests inside the ticks'."""
-    from test_paged_decode import (_burst_registry, _mixed_requests,
-                                   _submit_at_once, _watch_ticks)
+    from _paged_common import (_burst_registry, _mixed_requests,
+                               _submit_at_once, _watch_ticks)
     reg = _burst_registry("cohere2_moe", pool_blocks=0)
     assert reg.gen_store("m").one_pass
     reqs = _mixed_requests(23, 96)
@@ -327,7 +327,7 @@ def test_a_tick_ahead_leaves_the_device_nothing_to_wait_for(monkeypatch):
     twin, the same store without its model's step over row groups,
     starves once a tick.  Either way the six readers' parts sum to the
     host's account of the gap."""
-    from test_paged_decode import _burst_registry, _without_groups
+    from _paged_common import _burst_registry, _without_groups
     rs = np.random.RandomState(7)
     prompt = [int(t) for t in rs.randint(0, 96, 11)]
     readers = {name: harness.load_module(
@@ -378,7 +378,7 @@ def test_a_tick_ahead_leaves_the_device_nothing_to_wait_for(monkeypatch):
 # (c) two engines, two clocks
 # ---------------------------------------------------------------------------
 def test_two_engines_in_one_process_keep_two_clocks():
-    from test_paged_decode import _add_model
+    from _paged_common import _add_model
     reg = ModelRegistry()
     _add_model(reg, paged=True, prefill_chunk=8)
     opened = profiler.phase_totals()
